@@ -1,0 +1,582 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that paddle_tpu still starts on the chip.
+
+One process, the entry points a user would call, published widths, random
+weights from a fixed seed. Each phase prints one JSON info line (compile
+seconds, run seconds, what it checked); the LAST line of stdout is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+Any failed phase, or a platform other than "tpu", ends the run with
+`"ok": false` in that shape and a non-zero exit code: there is no CPU or
+interpreter fallback anywhere below, and no phase's exception is passed
+over. Numbers printed here are information, not benchmark results.
+
+    python chip_smoke.py            one chip: device, program, train,
+                                    long_seq, serve
+    python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
+                                    sharded dp x tp=2 vs the same batch on
+                                    one device, then the dp/tp/sp/pp/ep
+                                    compositions with kernels compiled
+
+JAX's persistent compilation cache sits where JAX_COMPILATION_CACHE_DIR
+says, else at <checkout>/.jax_cache (core/compile_cache.place_jax_cache),
+so a second run in the same checkout skips XLA; each phase line carries
+the cache's hits and misses. The script starts no child process and takes
+no lock: a chip belongs to one process at a time.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import contextlib
+import gc
+import json
+import sys
+import threading
+import time
+
+SEED = 0
+
+# jax.monitoring feed: how many programs JAX was asked to compile, and how
+# many of those its persistent cache answered (a hit still counts as a
+# request — "zero compiles after warm-up" means zero NEW programs)
+_COUNTS: collections.Counter = collections.Counter()
+_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+           "/jax/compilation_cache/cache_misses": "cache_misses"}
+_COMPILE_REQUEST = "/jax/core/compile/backend_compile_duration"
+
+
+def _listen():
+    import jax
+
+    def on_event(event, **kw):
+        if event in _EVENTS:
+            _COUNTS[_EVENTS[event]] += 1
+
+    def on_duration(event, secs, **kw):
+        if event == _COMPILE_REQUEST:
+            _COUNTS["compile_requests"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """Yield the phase's info dict; print it as the phase's JSON line once
+    the body has filled it in — only when the body did not raise."""
+    import jax
+
+    info: dict = {}
+    before = collections.Counter(_COUNTS)
+    t0 = time.perf_counter()
+    yield info
+    print(json.dumps(dict(
+        phase=name, **info, wall_s=round(time.perf_counter() - t0, 2),
+        **{k: _COUNTS[k] - before[k] for k in
+           ("compile_requests", "cache_hits", "cache_misses")})), flush=True)
+    # the next phase gets the whole chip: drop executables and dead arrays
+    jax.clear_caches()
+    gc.collect()
+
+
+def _timed(fn):
+    """(result, seconds) with the device drained inside the timed region."""
+    import jax
+
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn())
+    return out, time.perf_counter() - t0
+
+
+def _init(model, cfg):
+    """(params, axes) of `model.init(key, cfg)` as ONE compiled program:
+    called eagerly, init costs a compile per distinct parameter shape."""
+    import jax
+
+    axes: dict = {}
+
+    def init(key):
+        params, a = model.init(key, cfg)
+        axes.update(a)  # static: filled once, while tracing
+        return params
+
+    return jax.jit(init)(jax.random.key(SEED)), axes
+
+
+# ---------------------------------------------------------------------------
+# phases — each returns facts; what must hold on the chip is asserted by
+# the caller, what must hold anywhere is asserted here
+# ---------------------------------------------------------------------------
+
+
+def device_phase(info: dict) -> dict:
+    """jax.devices() as the driver reads it; the attached device_kind
+    must be a row of the peaks table by itself, not via DEFAULT_PEAK."""
+    import jax
+
+    from paddle_tpu.observability import device_peaks
+
+    devs = jax.devices()
+    info.update(platform=devs[0].platform, kind=devs[0].device_kind,
+                count=len(devs))
+    info["peaks_row"] = next(
+        (key for key, _ in device_peaks.PEAKS
+         if key in devs[0].device_kind.lower()), None)
+    return info
+
+
+def program_phase(info: dict, place, steps: int = 40) -> dict:
+    """The README quickstart surface: a LeNet Program through
+    fluid.Executor(place), numpy in, a falling loss out."""
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import lenet
+
+    # batch 64: at 256 the TPU compiler alone takes ~90 s over this
+    # step (conv1's weight gradient, Cin=1; PERF.md) — the phase is about
+    # the Program/Executor surface, not about a batch size
+    rng = np.random.RandomState(SEED)
+    feed = {"img": rng.rand(64, 1, 28, 28).astype("float32"),
+            "label": rng.randint(0, 10, (64, 1)).astype("int64")}
+    main, startup, _, loss, _ = lenet.build_program(pt, lr=2e-3)
+    exe = pt.Executor(place)
+
+    def run_once():
+        return float(np.asarray(
+            exe.run(main, feed=feed, fetch_list=[loss])[0]).reshape(()))
+
+    with pt.scope_guard(pt.Scope()):
+        exe.run(startup)
+        t0 = time.perf_counter()
+        losses = [run_once()]
+        info["compile_s"] = round(time.perf_counter() - t0, 2)
+        t0 = time.perf_counter()
+        losses += [run_once() for _ in range(steps)]
+        info["run_s"] = round(time.perf_counter() - t0, 2)
+    assert all(np.isfinite(losses)), losses
+    assert losses[-1] < losses[0], f"LeNet loss did not fall: {losses}"
+    info["checked"] = {"steps": steps, "loss_first": round(losses[0], 4),
+                       "loss_last": round(losses[-1], 4)}
+    return info
+
+
+def _bert_driver(cfg, batch_size, seq_len, mesh, deterministic):
+    """(state, step, batch) built exactly as bench.py's BERT cells build
+    them: make_train_step + ZeRO-1 optimizer state under the mesh."""
+    import jax
+    import optax
+
+    from paddle_tpu.models import bert
+    from paddle_tpu.parallel.train import TrainStrategy, make_train_step
+
+    params, axes = _init(bert, cfg)
+
+    def loss_fn(p, b, r):
+        return bert.pretrain_loss(p, cfg, b, rng=r,
+                                  deterministic=deterministic)
+
+    init_state, step = make_train_step(
+        loss_fn, optax.adamw(1e-4), mesh, axes,
+        strategy=TrainStrategy(shard_optimizer_states=True))
+    state = init_state(params)
+    batch = bert.make_batch(jax.random.key(SEED + 1), cfg,
+                            batch_size=batch_size, seq_len=seq_len)
+    return state, step, batch
+
+
+def train_phase(info: dict, cfg, batch_size: int, seq_len: int, mesh,
+                steps: int = 5, deterministic: bool = False,
+                want_text: bool = False):
+    """A few optimizer steps on one fixed batch: one warm step (compile),
+    `steps` timed steps ended by block_until_ready, finite and falling
+    loss. Returns (info, losses, state, compiled_text_or_None)."""
+    import jax
+    import numpy as np
+
+    from paddle_tpu.ops.pallas import attention
+    from paddle_tpu.parallel import mesh_guard
+
+    attention.GATE_COUNTS.clear()
+    with mesh_guard(mesh):
+        state, step, batch = _bert_driver(cfg, batch_size, seq_len, mesh,
+                                          deterministic)
+        rng = jax.random.key(SEED + 2)
+        (state, loss0), compile_s = _timed(lambda: step(state, batch, rng))
+        losses = [loss0]
+
+        def run():
+            nonlocal state
+            for _ in range(steps):
+                state, loss = step(state, batch, rng)
+                losses.append(loss)
+            return state, loss
+
+        _, run_s = _timed(run)
+        # the very program the steps ran, read back from its lowering
+        # (the persistent cache answers this compile)
+        text = step.lower(state, batch, rng).compile().as_text() \
+            if want_text else None
+    losses = [float(x) for x in losses]
+    assert all(np.isfinite(losses)), losses
+    assert losses[-1] < losses[0], f"loss did not fall: {losses}"
+    info.update(compile_s=round(compile_s, 2), run_s=round(run_s, 2),
+                step_ms=round(1000 * run_s / steps, 2),
+                checked={"batch_size": batch_size, "seq_len": seq_len,
+                         "steps": steps, "losses": [round(x, 4)
+                                                    for x in losses],
+                         "gate": dict(attention.GATE_COUNTS)})
+    return info, losses, state, text
+
+
+def _post_generate(port: int, ids, max_new: int, out: dict, key):
+    """One streamed POST /v1/generate; the parsed ndjson records land in
+    out[key] (or the exception does — the caller raises it)."""
+    import http.client
+
+    try:
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=600)
+        try:
+            conn.request("POST", "/v1/generate",
+                         body=json.dumps({"ids": [int(t) for t in ids],
+                                          "max_new_tokens": max_new}),
+                         headers={"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            if resp.status != 200:
+                raise RuntimeError(f"HTTP {resp.status}: {resp.read()!r}")
+            out[key] = [json.loads(ln) for ln in resp if ln.strip()]
+        finally:
+            conn.close()
+    except Exception as e:  # re-raised on the main thread by the caller
+        out[key] = e
+
+
+def _reference_gaps(params, cfg, prompts, streams):
+    """Teacher-forced check of every request against a plain float32
+    full-forward `gpt.apply` of the same params, one unbatched row at a
+    time: for each generated token, how far its reference logit lies
+    below the reference argmax's (0 = the reference picks the same
+    token). No KV cache, no engine code, no kernel (the flag is `off`
+    while it runs). Rows are padded to one length — causal attention
+    keeps the padding out of every position that is read."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.core.flags import set_flags
+    from paddle_tpu.models import gpt
+
+    ref_cfg = dataclasses.replace(cfg, dtype="float32")
+    n_new = len(streams[0])
+    width = max(len(p) for p in prompts) + n_new
+
+    @jax.jit
+    def forward(params, ids, first):
+        # rows first..first+n_new-1: row len(prompt)-1+i predicts token i
+        return jax.lax.dynamic_slice_in_dim(
+            gpt.apply(params, ref_cfg, ids)[0], first, n_new)
+
+    gaps, exact = [], 0
+    set_flags({"FLAGS_flash_attention": "off"})
+    try:
+        with jax.default_matmul_precision("highest"):
+            for prompt, generated in zip(prompts, streams):
+                ids = np.zeros((1, width), np.int32)
+                ids[0, :len(prompt) + n_new] = prompt + generated
+                rows = np.asarray(forward(params, jnp.asarray(ids),
+                                          len(prompt) - 1), np.float32)
+                picked = rows[np.arange(n_new), generated]
+                gaps.append(float((rows.max(axis=-1) - picked).max()))
+                exact += int((rows.argmax(axis=-1) == generated).sum())
+    finally:
+        set_flags({"FLAGS_flash_attention": "auto"})
+    return max(gaps), exact
+
+
+def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
+                logit_tol: float) -> dict:
+    """DecodeEngine -> warmup -> Server.start -> concurrent streamed
+    POST /v1/generate from threads of this process. Every stream must end
+    `done` and is checked against the float32 reference; nothing may
+    compile after warm-up."""
+    import jax
+    import numpy as np
+
+    from paddle_tpu.models import gpt
+    from paddle_tpu.serving import Server, ServingConfig
+    from paddle_tpu.serving.decode import DecodeEngine
+
+    params, _ = _init(gpt, cfg)
+    engine = DecodeEngine(params, cfg, decode_cfg)
+    n_phases = len(engine.decode_slots) + len(engine.prefill_buckets)
+    ready, compile_s = _timed(engine.warmup)
+    assert ready == n_phases, f"only {ready}/{n_phases} phases compiled"
+    server = Server(ServingConfig(), decode=engine)
+    port = server.start(0)
+    try:
+        after_warmup = _COUNTS["compile_requests"]
+        out: dict = {}
+        threads = [threading.Thread(target=_post_generate,
+                                    args=(port, p, max_new, out, i))
+                   for i, p in enumerate(prompts)]
+        t0 = time.perf_counter()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=900)
+        run_s = time.perf_counter() - t0
+        late_compiles = _COUNTS["compile_requests"] - after_warmup
+        status = engine.status()
+    finally:
+        server.stop()
+        engine.stop()
+    streams = []
+    for i in range(len(prompts)):
+        recs = out.get(i)
+        if isinstance(recs, Exception):
+            raise recs
+        assert recs, f"request {i} never answered"
+        done = recs[-1]
+        assert done.get("done") and "error" not in done, done
+        toks = [r["token"] for r in recs if "token" in r]
+        assert len(toks) == done["tokens"] == max_new, (i, done)
+        assert all(0 <= t < cfg.vocab_size for t in toks)
+        streams.append(toks)
+    assert late_compiles == 0, \
+        f"{late_compiles} programs compiled after warm-up"
+    gap, exact = _reference_gaps(params, cfg, prompts, streams)
+    assert gap <= logit_tol, (
+        f"engine tokens leave the float32 reference's argmax by up to "
+        f"{gap:.4f} logits (tolerance {logit_tol})")
+    info.update(
+        compile_s=round(compile_s, 2), run_s=round(run_s, 2),
+        checked={"requests": len(prompts),
+                 "prompt_lens": [len(p) for p in prompts],
+                 "max_new_tokens": max_new, "phases_warmed": ready,
+                 "compiles_after_warmup": late_compiles,
+                 "finished": status["requests"],
+                 "ref_exact_tokens": f"{exact}/{len(prompts) * max_new}",
+                 "ref_max_logit_gap": round(gap, 5),
+                 "ref_logit_tol": logit_tol})
+    return info
+
+
+def reuse_phase(info: dict, cfg, decode_cfg, prompt, max_new: int) -> dict:
+    """A second engine with chunked prefill + the prefix cache on, so the
+    synchronous scheduler (`_loop_sync`) runs too: the same prompt twice;
+    the second resolves its prefix from the cache and must stream the
+    same tokens as the first, which recomputed it."""
+    import jax
+
+    from paddle_tpu.models import gpt
+    from paddle_tpu.serving.decode import DecodeEngine
+
+    params, _ = _init(gpt, cfg)
+    engine = DecodeEngine(params, cfg, decode_cfg)
+    ready, compile_s = _timed(engine.warmup)
+    assert ready == 1 + len(engine.decode_slots), ready
+    try:
+        after_warmup = _COUNTS["compile_requests"]
+        t0 = time.perf_counter()
+        first = engine.submit(prompt, max_new_tokens=max_new).result(
+            timeout_s=600)
+        second = engine.submit(prompt, max_new_tokens=max_new).result(
+            timeout_s=600)
+        run_s = time.perf_counter() - t0
+        late_compiles = _COUNTS["compile_requests"] - after_warmup
+        kv = engine.status()["kv"]
+    finally:
+        engine.stop()
+    assert len(first) == max_new and first == second, (first, second)
+    assert kv["prefix_hits_total"] >= 1, kv
+    assert late_compiles == 0, \
+        f"{late_compiles} programs compiled after warm-up"
+    info.update(compile_s=round(compile_s, 2), run_s=round(run_s, 2),
+                checked={"prompt_len": len(prompt),
+                         "prefill_chunk": engine.prefill_chunk,
+                         "prefix_hits": kv["prefix_hits_total"],
+                         "blocks_reused": kv["blocks_reused_total"],
+                         "reused_equals_recomputed": True,
+                         "compiles_after_warmup": late_compiles})
+    return info
+
+
+def sharded_phase(info: dict, cfg, batch_size: int, seq_len: int,
+                  devices, steps: int = 5, rel_tol: float = 1e-2) -> dict:
+    """BERT under make_mesh(dp=-1, tp=2) over `devices` against the same
+    batch on a one-device mesh in this process: per-step losses agree
+    within `rel_tol` (bf16 activations: 2^-8 per rounding, averaged over
+    the batch's tokens), and the sharded state really is spread."""
+    from paddle_tpu.parallel import MeshConfig, make_mesh
+
+    mesh = make_mesh(MeshConfig(dp=-1, tp=2), devices=devices)
+    sub, sharded, state, text = train_phase({}, cfg, batch_size, seq_len,
+                                            mesh, steps=steps - 1,
+                                            want_text=True)
+    assert "all-reduce" in text, "no collective in the sharded step"
+    holders = set()
+    for leaf in state.params.values():
+        holders |= {s.device for s in leaf.addressable_shards}
+    assert holders == set(devices), \
+        f"parameters live on {holders}, not on all of {devices}"
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+              for d in devices]
+    del state
+    one = make_mesh(MeshConfig(dp=-1), devices=devices[:1])
+    _, single, state, _ = train_phase({}, cfg, batch_size, seq_len, one,
+                                      steps=steps - 1)
+    del state
+    worst = max(abs(a - b) / abs(b) for a, b in zip(sharded, single))
+    assert worst <= rel_tol, (
+        f"sharded vs one-device loss differs by {worst:.2e} relative "
+        f"(tolerance {rel_tol}): {sharded} vs {single}")
+    info.update(compile_s=sub["compile_s"], run_s=sub["run_s"],
+                step_ms=sub["step_ms"],
+                checked={"mesh": {a: int(n) for a, n in mesh.shape.items()
+                                  if n > 1},
+                         "losses_sharded": [round(x, 4) for x in sharded],
+                         "losses_one_device": [round(x, 4)
+                                               for x in single],
+                         "loss_rel_diff_max": float(f"{worst:.3g}"),
+                         "loss_rel_tol": rel_tol,
+                         "param_shard_devices": len(holders),
+                         "bytes_in_use": in_use})
+    return info
+
+
+# ---------------------------------------------------------------------------
+# the two runs
+# ---------------------------------------------------------------------------
+
+
+def run_one_chip() -> None:
+    import jax
+    import numpy as np
+
+    import paddle_tpu as pt
+    from paddle_tpu.models import bert, gpt
+    from paddle_tpu.parallel import MeshConfig, make_mesh
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    mesh = make_mesh(MeshConfig(dp=-1), devices=jax.devices()[:1])
+
+    with phase("program") as info:
+        program_phase(info, pt.TPUPlace(0))
+        assert info["checked"]["loss_last"] < \
+            0.7 * info["checked"]["loss_first"], info
+
+    with phase("train") as info:
+        # BERT-base, bs 256 (no batch ladder), seq 128: the bert_base cell
+        train_phase(info, bert.BertConfig.base(), 256, 128, mesh, steps=5)
+
+    with phase("long_seq") as info:
+        # the bert_long_seq4096 cell, flag `auto`: the gate must pick the
+        # splash kernel and the kernel must be IN the compiled step
+        _, _, _, text = train_phase(
+            info, bert.BertConfig(max_len=4096, dropout=0.0), 8, 4096,
+            mesh, steps=4, deterministic=True, want_text=True)
+        gate = info["checked"]["gate"]
+        assert gate.get("splash", 0) >= 1 and gate.get("xla", 0) == 0, gate
+        assert "tpu_custom_call" in text, \
+            "no Pallas kernel in the compiled long-sequence step"
+        info["checked"]["tpu_custom_call"] = True
+
+    # GPT at its dataclass defaults (768/12/12/3072, vocab 50304, context
+    # 1024, bf16); 16 slots x 64 blocks of 16 tokens = every slot can hold
+    # a full context. Prompt buckets are the default pow2 grid to 1024.
+    cfg = gpt.GPTConfig()
+    rng = np.random.RandomState(SEED)
+    # 600 tokens pad to the 1024 bucket, the one whole-prompt prefill
+    # shape where the auto gate takes the splash kernel
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (12, 509, 5, 512, 40, 498, 600)]
+    with phase("serve") as info:
+        # tolerance: the engine computes in bf16 (2^-8 per rounding) and
+        # logits here are O(1) sums over 768 features and 12 layers of
+        # such roundings; 0.25 is far below the ~2.4 logits that separate
+        # the argmax from a wrong token
+        serve_phase(info, cfg, DecodeConfig(
+            block_size=16, num_blocks=16 * 64 + 1, decode_slots=(8, 16)),
+            prompts, max_new=24, logit_tol=0.25)
+
+    with phase("serve_reuse") as info:
+        reuse_phase(info, cfg, DecodeConfig(
+            block_size=16, num_blocks=4 * 64 + 1, decode_slots=(4,),
+            prefill_chunk=128, prefix_cache=True),
+            rng.randint(0, cfg.vocab_size, 300).tolist(), max_new=16)
+
+
+def run_four_chips() -> None:
+    import jax
+
+    from paddle_tpu.models import bert
+
+    devices = jax.devices()
+    assert len(devices) == 4, f"--chips 4 needs four devices: {devices}"
+    with phase("sharded_train") as info:
+        sharded_phase(info, bert.BertConfig.base(), 256, 128, devices)
+        assert all(info["checked"]["bytes_in_use"]), \
+            f"a chip holds nothing: {info['checked']['bytes_in_use']}"
+
+    with phase("compositions") as info:
+        # dp x tp x sp BERT, ring-splash under sp (kernels compiled: the
+        # devices are TPUs), pp x ep MoE, conv+BN dp parity
+        import __graft_entry__ as graft
+
+        from paddle_tpu.ops.pallas import attention
+
+        _, seconds = _timed(lambda: graft._dryrun_multichip_impl(4))
+        gate = dict(attention.GATE_COUNTS)
+        assert gate.get("ring_splash", 0) >= 1, gate
+        info.update(compile_s=None, run_s=round(seconds, 2),
+                    checked={"gate": gate})
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the cross-chip path (needs a "
+                         "four-chip host); default 1")
+    args = ap.parse_args(argv)
+
+    ok = False
+    device = {"platform": None, "kind": None, "count": 0}
+    try:
+        import jax
+
+        from paddle_tpu.core.compile_cache import place_jax_cache
+
+        cache_dir = place_jax_cache()
+        _listen()
+        with phase("device") as info:
+            device_phase(info)
+            info["jax_cache_dir"] = cache_dir
+            info["jax"] = jax.__version__
+        device = {k: info[k] for k in ("platform", "kind", "count")}
+        if device["platform"] != "tpu":
+            raise RuntimeError(
+                f"no TPU: JAX's devices are {device} — this script "
+                "proves the chip path and has no other")
+        if info["peaks_row"] is None:
+            raise RuntimeError(
+                f"device_kind {device['kind']!r} matches no row of "
+                "observability/device_peaks.PEAKS")
+        if args.chips == 4:
+            run_four_chips()
+        else:
+            run_one_chip()
+        ok = True
+    finally:
+        # the one line the driver reads; a failure's traceback follows it
+        # on stderr and the exit code is non-zero
+        print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

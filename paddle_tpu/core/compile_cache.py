@@ -55,6 +55,11 @@ Env surface (documented in PROFILE.md §Compile-cache):
 
 Retention sweeps oldest-mtime-first after each store; a load hit bumps
 the entry's mtime, making the sweep LRU in practice.
+
+JAX's OWN persistent compilation cache is a separate thing, placed once
+per process by `place_jax_cache()` below: it serves every jit in the
+process (this module's cache serves `_JitDispatch` only) and is what
+lets a second chip run in the same checkout skip XLA.
 """
 
 from __future__ import annotations
@@ -71,13 +76,33 @@ from ..observability import telemetry as _telemetry
 
 __all__ = ["enabled", "cache_dir", "fingerprint", "load", "store",
            "serialize_executable", "deserialize_executable",
-           "entry_path", "sweep", "environment_meta"]
+           "entry_path", "sweep", "environment_meta", "place_jax_cache"]
 
 _SUFFIX = ".jex"
 _FORMAT = "paddle_tpu-compile-cache-v1"
 
 _DEFAULT_MAX_BYTES = 1 << 30  # 1 GiB
 _DEFAULT_MAX_ENTRIES = 512
+
+
+def place_jax_cache() -> str:
+    """Place JAX's persistent compilation cache for this process; call
+    before the first compile (chip_smoke.py, bench.py's child mode and
+    serving/replica.py do, at the top). Where JAX_COMPILATION_CACHE_DIR
+    is set, JAX's own handling of it is all there is and no other
+    directory is set here. Where it is not, the cache goes to
+    `<checkout>/.jax_cache` — a fixed path, because the path is part of
+    the cache's key and a directory that moves never hits. Programs that
+    compile in under a second (most of the decode phase grid) are kept
+    too; JAX's minimum entry size is already 0. Returns the directory in
+    use."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def cache_dir() -> Optional[str]:
@@ -164,24 +189,34 @@ def entry_path(key: str, d: Optional[str] = None) -> str:
 
 
 def serialize_executable(compiled) -> bytes:
-    """One opaque blob for a `jax.stages.Compiled`: the pjrt payload
-    plus the in/out pytree defs it needs to be callable again. Raises
-    when the backend doesn't support serialization (caller falls back
-    to leaving the plain compile in place)."""
+    """One opaque blob for a `jax.stages.Compiled`: the pjrt payload,
+    the in/out pytree defs it needs to be callable again, and the ids of
+    the devices it executes on. Raises when the backend doesn't support
+    serialization (caller falls back to leaving the plain compile in
+    place)."""
     from jax.experimental import serialize_executable as _se
 
     payload, in_tree, out_tree = _se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree),
+    device_ids = [d.id for d in
+                  compiled.runtime_executable().local_devices()]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids),
                         protocol=pickle.HIGHEST_PROTOCOL)
 
 
 def deserialize_executable(blob: bytes):
     """Inverse of serialize_executable: a loaded, callable executable
-    bound to this process's devices."""
+    bound to the same devices (by id) it was compiled for. The devices
+    are passed explicitly: left to its default, deserialize_and_load
+    loads for ALL of the backend's devices, and a one-device executable
+    on a many-device host then refuses its arguments ("expected N
+    shards")."""
     from jax.experimental import serialize_executable as _se
 
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,8 @@ from .core import lowering
 from .core import precision as _precision
 from .core.executor import Executor, Scope, _JitDispatch, scope_guard
 from .core.ir import normalize_dtype
-from .core.places import CPUPlace, Place, TPUPlace, default_place
+from .core.places import (CPUPlace, Place, TPUPlace, default_place,
+                          is_compiled_with_tpu)
 
 
 class AnalysisConfig:
@@ -33,7 +34,10 @@ class AnalysisConfig:
 
     def __init__(self, model_dir: Optional[str] = None):
         self.model_dir = model_dir
-        self._use_tpu = True
+        # None = the chip if this process has one, else the host (as
+        # default_place()); enable_use_gpu()/disable_gpu() make it a
+        # request that TPUPlace/CPUPlace then hold the process to
+        self._use_tpu: Optional[bool] = None
         self._device_id = 0
         self._memory_optim = True       # XLA buffer assignment
         self._ir_optim = True           # XLA fusion
@@ -137,7 +141,9 @@ class Predictor:
                                                              "float32")
             return
         self._native = None
-        place = TPUPlace(config._device_id) if config._use_tpu else CPUPlace()
+        use_tpu = is_compiled_with_tpu() if config._use_tpu is None \
+            else config._use_tpu
+        place = TPUPlace(config._device_id) if use_tpu else CPUPlace()
         self._exe = Executor(place)
         self._scope = Scope()
         with scope_guard(self._scope):

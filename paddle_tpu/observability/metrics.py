@@ -28,8 +28,8 @@ __all__ = [
     "exponential_buckets", "bucket_quantile",
 ]
 
-# Seconds-scale latency buckets: 50us .. 60s covers a jit dispatch on a
-# local backend through a cold compile on a tunneled one.
+# Seconds-scale latency buckets: 50us .. 60s covers a jit dispatch
+# through a cold compile of a large program.
 DEFAULT_BUCKETS = (
     50e-6, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2, 2.5e-2,
     5e-2, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,

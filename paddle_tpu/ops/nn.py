@@ -42,7 +42,7 @@ def _conv_nd(x, w, attrs, nd, feature_group_count=None, f32_accum=True):
     dn_str = ("NCHW", "OIHW", "NCHW") if nd == 2 else ("NCDHW", "OIDHW", "NCDHW")
     dn = jax.lax.conv_dimension_numbers(x.shape, w.shape, dn_str)
     # f32_accum (inference only): explicit f32 accumulation for bf16
-    # convs. The TRAINING path must not request it — jax 0.4.x's conv
+    # convs. The TRAINING path must not request it — jax's (0.9.0) conv
     # transpose rule feeds the f32-typed cotangent back into a conv
     # against the bf16 filter and rejects the dtype mix, so the
     # differentiable path accumulates at the input width (the TPU MXU

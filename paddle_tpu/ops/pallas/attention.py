@@ -2,13 +2,16 @@
 
 Reference: no TPU counterpart — the reference computes attention from
 unfused matmul/softmax ops (e.g. the BERT graph in
-inference/tests/api/analyzer_bert_tester.cc). TPU-native: a Pallas
-flash-attention kernel (online softmax, O(T) memory) on TPU backends, an
-XLA einsum+softmax fallback elsewhere. The f32 fallback is semantically
-identical to the flash kernel, so tests run on CPU; for bf16 inputs the
-fallback stores the T x T logits in bf16 (f32-accumulated, f32 softmax —
-halves score-buffer HBM traffic; see PROFILE.md), which rounds logits to
-bf16 precision relative to the kernel's f32 score pipeline.
+inference/tests/api/analyzer_bert_tester.cc). TPU-native: the gate
+(_use_splash / _multichip_splash_route / _use_pallas) picks a Pallas
+kernel route or the XLA einsum+softmax path from the shape, the mesh,
+the platform and FLAGS_flash_attention — and the pick is final: a
+selected kernel that fails to trace or compile raises, it is never
+replaced by another path. The f32 XLA path is semantically identical to
+the kernels, so tests run on CPU; for bf16 inputs it stores the T x T
+logits in bf16 (f32-accumulated, f32 softmax — halves score-buffer HBM
+traffic; see PROFILE.md), which rounds logits to bf16 precision relative
+to the kernel's f32 score pipeline.
 """
 
 from __future__ import annotations
@@ -31,7 +34,8 @@ GATE_COUNTS: collections.Counter = collections.Counter()
 
 
 def _xla_mha(q, k, v, mask, scale):
-    """[B,T,N,H] attention via plain XLA ops (fallback + reference).
+    """[B,T,N,H] attention via plain XLA ops (the gate's non-kernel
+    route, and the tests' reference).
 
     bf16 inputs keep the T x T score tensor in bf16 (the einsum still
     accumulates in f32 on the MXU; softmax upcasts to f32 after the
@@ -61,13 +65,9 @@ def _xla_mha(q, k, v, mask, scale):
 def _platform(q) -> str:
     """Where this computation will actually run. Tracers carry no devices;
     the active mesh (if any) decides — it may be a CPU mesh even when the
-    default backend is TPU (dryrun_multichip's in-process mode)."""
-    try:
-        dev = q.devices() if hasattr(q, "devices") else None
-    except Exception:
-        dev = None
-    if dev:
-        return next(iter(dev)).platform
+    default backend is TPU."""
+    if isinstance(q, jax.Array) and not isinstance(q, jax.core.Tracer):
+        return next(iter(q.devices())).platform
     from paddle_tpu.parallel.mesh import current_mesh
     m = current_mesh()
     if m is not None:
@@ -75,31 +75,38 @@ def _platform(q) -> str:
     return jax.default_backend()
 
 
-try:  # private but the only trace-time manual-region signal (jax 0.9)
-    from jax._src.core import get_axis_env as _get_axis_env
-except ImportError:  # jax moved the symbol: detection unavailable
-    _get_axis_env = None
-    import warnings
+def _flag_mode() -> str:
+    from ...core.flags import get_flag
 
-    warnings.warn(
-        "jax._src.core.get_axis_env unavailable: pallas attention kernels "
-        "are disabled under >1-device meshes (cannot detect shard_map "
-        "manual regions); update _mesh_partitionable for this jax version")
+    return str(get_flag("FLAGS_flash_attention")).lower()
+
+
+def _interpret_requested(q) -> bool:
+    """The ONE request for the pallas interpreter:
+    FLAGS_flash_attention=splash where the computation will not run on a
+    TPU — how CPU-mesh tests and the CPU dry run execute the real kernel
+    bodies. mha() asks once and hands the answer down as an argument;
+    no kernel wrapper works it out from the platform on its own, and
+    without the request an off-chip gate picks the XLA path."""
+    return _flag_mode() == "splash" and _platform(q) != "tpu"
 
 
 def _mesh_partitionable(q) -> bool:
-    """A pallas_call has no GSPMD partitioning rule: under a >1-device
-    mesh outside a shard_map manual region, XLA would all-gather the
-    operands (defeating dp/sp/tp sharding) or fail at lowering — which
-    the trace-time try/except in mha() cannot catch. Inside a manual
-    region shapes are already per-device local, so the kernel is safe."""
+    """A Mosaic kernel cannot be partitioned: under a >1-device mesh it
+    lowers only inside a shard_map region that is manual over EVERY mesh
+    axis, where shapes are already per-device local. Outside one XLA
+    would have to all-gather the operands; inside a partly manual one
+    (the 'pp' pipeline keeps tp automatic) the TPU lowering refuses it
+    ("Mosaic kernels cannot be automatically partitioned") — which the
+    interpreter never checks."""
     from paddle_tpu.parallel.mesh import current_mesh
+
     m = current_mesh()
     if m is None or m.devices.size == 1:
         return True
-    if _get_axis_env is None:
-        return False  # conservative: warned once at import above
-    return bool(_get_axis_env().axis_sizes)  # inside shard_map
+    abstract = jax.sharding.get_abstract_mesh()
+    return (not abstract.empty
+            and set(abstract.manual_axes) == set(abstract.axis_names))
 
 
 def _use_pallas(q) -> bool:
@@ -111,9 +118,7 @@ def _use_pallas(q) -> bool:
 def _gate_allows(T: int) -> bool:
     """Mode dispatch of the flash gate, separated from the platform check
     so the decision logic is unit-testable off-TPU."""
-    from ...core.flags import get_flag
-
-    mode = str(get_flag("FLAGS_flash_attention")).lower()
+    mode = _flag_mode()
     if mode in ("on", "1", "true"):
         return True
     if mode in ("off", "0", "false"):
@@ -152,19 +157,16 @@ def _multichip_splash_route(q, k, mask, causal):
     Returns None (no reroute), "shardmap", "ring", or "ring_xla".
     """
     from paddle_tpu.parallel.mesh import current_mesh
-    from paddle_tpu.parallel.sharding import current_rules
+    from paddle_tpu.parallel.sharding import current_rules, in_manual_region
 
     m = current_mesh()
     if m is None or m.devices.size == 1 or q.ndim != 4 or mask is not None:
         return None
-    if _get_axis_env is not None and bool(_get_axis_env().axis_sizes):
+    if in_manual_region():
         return None  # already inside a manual region: _use_splash applies
-    from ...core.flags import get_flag
-
-    mode = str(get_flag("FLAGS_flash_attention")).lower()
-    platform = m.devices.flat[0].platform
+    mode = _flag_mode()
     force = mode == "splash"
-    if platform != "tpu" and not force:
+    if _platform(q) != "tpu" and not force:
         return None  # interpret-mode execution is explicit opt-in
     if not (force or (mode == "auto" and q.shape[1] >= _SPLASH_MIN_T)):
         return None
@@ -192,10 +194,12 @@ def _multichip_splash_route(q, k, mask, causal):
     return "shardmap"
 
 
-def _shardmap_splash_mha(q, k, v, scale, causal):
+def _shardmap_splash_mha(q, k, v, scale, causal, interpret):
     """Splash composed with dp/tp: attention is independent across batch
-    and heads, so manualizing those axes feeds the tuned kernel
-    per-device local blocks with NO collectives."""
+    and heads, so splitting those axes feeds the tuned kernel per-device
+    local blocks with NO collectives. The region is manual over every
+    mesh axis (_mesh_partitionable); q/k/v are replicated over the axes
+    the spec does not name."""
     from paddle_tpu.parallel.mesh import current_mesh
     from paddle_tpu.parallel.sharding import current_rules
 
@@ -206,13 +210,13 @@ def _shardmap_splash_mha(q, k, v, scale, causal):
     spec = jax.sharding.PartitionSpec(
         b_ax if b_ax in axes else None, None,
         h_ax if h_ax in axes else None, None)
-    interpret = m.devices.flat[0].platform != "tpu"
     from .ring_attention import _shard_map_mesh
 
     sm_mesh = _shard_map_mesh(m)
 
     @functools.partial(jax.shard_map, mesh=sm_mesh, in_specs=(spec,) * 3,
-                       out_specs=spec, axis_names=axes, check_vma=False)
+                       out_specs=spec, axis_names=set(m.axis_names),
+                       check_vma=False)
     def run(ql, kl, vl):
         return _splash_mha(ql, kl, vl, scale, causal, interpret=interpret)
 
@@ -226,57 +230,42 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array,
 
     mask: additive [B, 1, 1, T] or [B, N, T, T] (float, -inf style), or None.
     """
-    import warnings
-
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    interpret = _interpret_requested(q)
     if _use_splash(q, k, mask, causal):
-        try:
-            out = _splash_mha(q, k, v, scale, causal,
-                              interpret=_platform(q) != "tpu")
-            GATE_COUNTS["splash"] += 1
-            return out
-        except Exception as e:  # unsupported shape: fall back, but say so
-            warnings.warn(f"splash_attention failed at trace time "
-                          f"({type(e).__name__}: {str(e)[:200]}); falling "
-                          f"back to the XLA path — which may not fit at "
-                          f"this shape")
+        out = _splash_mha(q, k, v, scale, causal, interpret=interpret)
+        GATE_COUNTS["splash"] += 1
+        return out
     route = _multichip_splash_route(q, k, mask, causal)
+    if route == "shardmap":
+        out = _shardmap_splash_mha(q, k, v, scale, causal, interpret)
+        GATE_COUNTS["splash_shardmap"] += 1
+        return out
     if route is not None:
-        try:
-            if route == "shardmap":
-                out = _shardmap_splash_mha(q, k, v, scale, causal)
-            else:
-                from paddle_tpu.parallel.mesh import current_mesh
-                from paddle_tpu.parallel.sharding import current_rules
-                from . import ring_attention as ra
+        from paddle_tpu.parallel.mesh import current_mesh
+        from paddle_tpu.parallel.sharding import current_rules
+        from . import ring_attention as ra
 
-                m = current_mesh()
-                rules = current_rules()
-                if route == "ring":
-                    out = ra.ring_splash(
-                        q, k, v, m, s_axis=rules.mesh_axis("seq"),
-                        b_axis=rules.mesh_axis("batch"),
-                        h_axis=rules.mesh_axis("heads"), scale=scale)
-                else:  # "ring_xla": exact ring, XLA blocks
-                    out = ra.ring_attention(
-                        q, k, v, m, axis=rules.mesh_axis("seq"),
-                        causal=causal, scale=scale)
-            GATE_COUNTS[{"shardmap": "splash_shardmap",
-                         "ring": "ring_splash",
-                         "ring_xla": "ring_xla"}[route]] += 1
-            return out
-        except Exception as e:
-            warnings.warn(f"multi-chip splash route '{route}' failed at "
-                          f"trace time ({type(e).__name__}: "
-                          f"{str(e)[:200]}); falling back to GSPMD XLA")
+        m = current_mesh()
+        rules = current_rules()
+        if route == "ring":
+            out = ra.ring_splash(
+                q, k, v, m, s_axis=rules.mesh_axis("seq"),
+                b_axis=rules.mesh_axis("batch"),
+                h_axis=rules.mesh_axis("heads"), scale=scale,
+                interpret=interpret)
+            GATE_COUNTS["ring_splash"] += 1
+        else:  # "ring_xla": exact ring, XLA blocks
+            out = ra.ring_attention(
+                q, k, v, m, axis=rules.mesh_axis("seq"),
+                causal=causal, scale=scale)
+            GATE_COUNTS["ring_xla"] += 1
+        return out
     if _use_pallas(q):
-        try:
-            out = _pallas_mha(q, k, v, mask, scale, causal)
-            GATE_COUNTS["pallas_flash"] += 1
-            return out
-        except Exception:  # fall back if kernel unsupported on this shape  # lint-exempt:swallow: gated fallback: unsupported shape routes to XLA
-            pass
+        out = _pallas_mha(q, k, v, mask, scale, causal)
+        GATE_COUNTS["pallas_flash"] += 1
+        return out
     out = _xla_mha(q, k, v, mask if not causal else _merge_causal(mask, q.shape[1]), scale)
     GATE_COUNTS["xla"] += 1
     return out.astype(q.dtype)
@@ -300,7 +289,7 @@ _SPLASH_MIN_T = 1024
 
 def _use_splash(q, k, mask, causal) -> bool:
     """Splash handles the padding-free (mask=None) and causal cases; an
-    arbitrary additive mask falls back to the XLA/legacy paths."""
+    arbitrary additive mask takes the XLA/legacy paths."""
     if q.ndim != 4 or mask is not None:
         return False  # additive masks (padding) take the XLA path
     T, Tk, hd = q.shape[1], k.shape[1], q.shape[-1]
@@ -308,12 +297,11 @@ def _use_splash(q, k, mask, causal) -> bool:
         return False
     if not _mesh_partitionable(q):
         return False
-    from ...core.flags import get_flag
-
-    mode = str(get_flag("FLAGS_flash_attention")).lower()
+    mode = _flag_mode()
     if mode == "splash":
-        # explicit opt-in ALSO runs off-TPU, via the pallas interpreter —
-        # this is how CPU-mesh tests execute the real kernel
+        # explicit opt-in ALSO runs off-TPU, via the pallas interpreter
+        # (_interpret_requested) — how CPU-mesh tests execute the real
+        # kernel
         return True
     if _platform(q) != "tpu":
         return False

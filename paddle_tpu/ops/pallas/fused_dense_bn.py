@@ -24,10 +24,11 @@ backward; fusing the backward is the remaining half of the line-item).
 
 Reference analogue: none — the reference computes conv, BN-stats and
 BN-apply as separate C++/cuDNN ops (batch_norm_op.cc, conv_op.cc); this
-fusion is TPU-native ground. Off-TPU the kernels run under the pallas
-interpreter, so CPU tests execute the real kernel bodies. Like every
-pallas op here, the kernels require a single device or a shard_map
-manual region (pallas_call has no GSPMD partitioning rule).
+fusion is TPU-native ground. The kernels compile for the TPU unless a
+caller passes `interpret=True` (the pallas interpreter: same kernel
+bodies on CPU — how the tests run them; the model path never asks).
+Like every pallas op here, the kernels require a single device or a
+shard_map manual region (pallas_call has no GSPMD partitioning rule).
 """
 
 from __future__ import annotations
@@ -61,7 +62,7 @@ def _mm_stats_kernel(x_ref, w_ref, y_ref, ps_ref, pss_ref):
                 preferred_element_type=ps_ref.dtype)
     y_ref[...] = y.astype(y_ref.dtype)
     # per-(row-block, col-block) partial channel sums; finished by a tiny
-    # [gm, N] reduction outside the kernel
+    # [gm, 1, N] reduction outside the kernel (_finish_stats)
     ps_ref[...] = jnp.sum(y, axis=0, keepdims=True)
     pss_ref[...] = jnp.sum(y * y, axis=0, keepdims=True)
 
@@ -70,31 +71,45 @@ def _acc_dt(x):
     return jnp.promote_types(x.dtype, jnp.float32)
 
 
+def _stats_out(M, N, bm, bn, y_dtype, acc):
+    """out_specs/out_shape of (y, partial sums, partial sums of squares).
+    The per-(row-block, col-block) partials are [gm, 1, N] with the row
+    block squeezed: the kernel sees a (1, bn) tile whose second-to-last
+    dim SPANS the array's, which is what the TPU lowering's (8, 128)
+    block rule requires of a one-row block (a (1, bn) block of a
+    [gm, N] array is refused)."""
+    gm = M // bm
+    part = pl.BlockSpec((None, 1, bn), lambda i, j: (i, 0, j))
+    specs = [pl.BlockSpec((bm, bn), lambda i, j: (i, j)), part, part]
+    shapes = [jax.ShapeDtypeStruct((M, N), y_dtype),
+              jax.ShapeDtypeStruct((gm, 1, N), acc),
+              jax.ShapeDtypeStruct((gm, 1, N), acc)]
+    return specs, shapes
+
+
+def _finish_stats(ps, pss, M):
+    """Partial sums [gm, 1, N] -> (mean, biased var), one-pass form."""
+    mean = jnp.sum(ps, axis=(0, 1)) / M
+    var = jnp.maximum(jnp.sum(pss, axis=(0, 1)) / M - mean * mean, 0.0)
+    return mean, var
+
+
 def _mm_stats_pallas(x, w, interpret):
     M, K = x.shape
     K2, N = w.shape
-    acc = _acc_dt(x)
     bm = _block(M, 512)
     bn = _block(N, 512)
-    gm, gn = M // bm, N // bn
+    out_specs, out_shape = _stats_out(M, N, bm, bn, x.dtype, _acc_dt(x))
     y, ps, pss = pl.pallas_call(
         _mm_stats_kernel,
-        grid=(gm, gn),
+        grid=(M // bm, N // bn),
         in_specs=[pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
                   pl.BlockSpec((K, bn), lambda i, j: (0, j))],
-        out_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, bn), lambda i, j: (i, j))],
-        out_shape=[jax.ShapeDtypeStruct((M, N), x.dtype),
-                   jax.ShapeDtypeStruct((gm, N), acc),
-                   jax.ShapeDtypeStruct((gm, N), acc)],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(x, w)
-    s = jnp.sum(ps, axis=0)
-    ss = jnp.sum(pss, axis=0)
-    mean = s / M
-    var = jnp.maximum(ss / M - mean * mean, 0.0)
-    return y, mean, var
+    return (y,) + _finish_stats(ps, pss, M)
 
 
 def _mm_stats_ref(x, w):
@@ -107,26 +122,31 @@ def _mm_stats_ref(x, w):
     return y, mean, var
 
 
-@jax.custom_vjp
-def matmul_stats(x, w):
+def matmul_stats(x, w, interpret=False):
     """y = x @ w plus per-output-channel (mean, biased var), with the
     stats accumulated in the matmul's epilogue — the BN-stats pass over
     y never touches HBM. x: [M, K]; w: [K, N] -> (y [M,N], mean [N],
     var [N], both f32)."""
-    return _mm_stats_pallas(x, w, interpret=_interpret())
+    return _matmul_stats(bool(interpret), x, w)
 
 
-def _mm_stats_fwd(x, w):
-    return matmul_stats(x, w), (x, w)
+# custom_vjp takes positional args only; static flags lead
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _matmul_stats(interpret, x, w):
+    return _mm_stats_pallas(x, w, interpret)
 
 
-def _mm_stats_bwd(res, cts):
+def _mm_stats_fwd(interpret, x, w):
+    return _matmul_stats(interpret, x, w), (x, w)
+
+
+def _mm_stats_bwd(interpret, res, cts):
     x, w = res
     _, pull = jax.vjp(_mm_stats_ref, x, w)
     return pull(cts)
 
 
-matmul_stats.defvjp(_mm_stats_fwd, _mm_stats_bwd)
+_matmul_stats.defvjp(_mm_stats_fwd, _mm_stats_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -170,26 +190,25 @@ def _bn_mm_ref(x, scale, shift, w, relu):
                    preferred_element_type=scale.dtype).astype(x.dtype)
 
 
-def bn_act_matmul(x, scale, shift, w, relu=True):
+def bn_act_matmul(x, scale, shift, w, relu=True, interpret=False):
     """y = act(x * scale + shift) @ w, the normalization applied in the
     matmul prologue — the normalized tensor never reaches HBM. Callers
     fold BN into (scale, shift): scale = gamma * rsqrt(var + eps),
     shift = beta - mean * scale (both [K], f32). x: [M, K]; w: [K, N]."""
-    return _bn_act_matmul(bool(relu), x, scale, shift, w)
+    return _bn_act_matmul(bool(relu), bool(interpret), x, scale, shift, w)
 
 
-# custom_vjp takes positional args only; the static relu flag leads
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _bn_act_matmul(relu, x, scale, shift, w):
-    return _bn_mm_pallas(x, scale, shift, w, relu,
-                         interpret=_interpret())
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _bn_act_matmul(relu, interpret, x, scale, shift, w):
+    return _bn_mm_pallas(x, scale, shift, w, relu, interpret)
 
 
-def _bn_mm_fwd(relu, x, scale, shift, w):
-    return _bn_act_matmul(relu, x, scale, shift, w), (x, scale, shift, w)
+def _bn_mm_fwd(relu, interpret, x, scale, shift, w):
+    return (_bn_act_matmul(relu, interpret, x, scale, shift, w),
+            (x, scale, shift, w))
 
 
-def _bn_mm_bwd(relu, res, ct):
+def _bn_mm_bwd(relu, interpret, res, ct):
     x, scale, shift, w = res
     _, pull = jax.vjp(
         lambda x, s, b, w: _bn_mm_ref(x, s, b, w, relu), x, scale,
@@ -234,51 +253,47 @@ def _bn_mm_stats_ref(x, scale, shift, w, relu):
     return y, mean, var
 
 
-def bn_act_matmul_stats(x, scale, shift, w, relu=True):
+def bn_act_matmul_stats(x, scale, shift, w, relu=True, interpret=False):
     """The full producer/consumer fusion: y = act(x*scale+shift) @ w with
     (mean, var) of y accumulated in the same kernel — the previous BN's
     apply AND this conv's stats pass both disappear from HBM traffic.
     This is ResNet's conv3 shape: bn2-apply+relu in the prologue, bn3
     stats in the epilogue."""
-    return _bn_act_matmul_stats(bool(relu), x, scale, shift, w)
+    return _bn_act_matmul_stats(bool(relu), bool(interpret), x, scale,
+                                shift, w)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _bn_act_matmul_stats(relu, x, scale, shift, w):
+def _bn_mm_stats_pallas(x, scale, shift, w, relu, interpret):
     M, K = x.shape
     K2, N = w.shape
     bm = _block(M, 512)
     bn = _block(N, 512)
-    gm = M // bm
-    acc = _acc_dt(x)
+    out_specs, out_shape = _stats_out(M, N, bm, bn, x.dtype, _acc_dt(x))
     y, ps, pss = pl.pallas_call(
         functools.partial(_bn_mm_stats_kernel, relu=relu),
-        grid=(gm, N // bn),
+        grid=(M // bm, N // bn),
         in_specs=[pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
                   pl.BlockSpec((1, K), lambda i, j: (0, 0)),
                   pl.BlockSpec((1, K), lambda i, j: (0, 0)),
                   pl.BlockSpec((K, bn), lambda i, j: (0, j))],
-        out_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, bn), lambda i, j: (i, j))],
-        out_shape=[jax.ShapeDtypeStruct((M, N), x.dtype),
-                   jax.ShapeDtypeStruct((gm, N), acc),
-                   jax.ShapeDtypeStruct((gm, N), acc)],
-        interpret=_interpret(),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
     )(x, scale.reshape(1, K), shift.reshape(1, K), w)
-    s = jnp.sum(ps, axis=0)
-    ss = jnp.sum(pss, axis=0)
-    mean = s / M
-    var = jnp.maximum(ss / M - mean * mean, 0.0)
-    return y, mean, var
+    return (y,) + _finish_stats(ps, pss, M)
 
 
-def _bn_mm_stats_fwd(relu, x, scale, shift, w):
-    return (_bn_act_matmul_stats(relu, x, scale, shift, w),
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _bn_act_matmul_stats(relu, interpret, x, scale, shift, w):
+    return _bn_mm_stats_pallas(x, scale, shift, w, relu, interpret)
+
+
+def _bn_mm_stats_fwd(relu, interpret, x, scale, shift, w):
+    return (_bn_act_matmul_stats(relu, interpret, x, scale, shift, w),
             (x, scale, shift, w))
 
 
-def _bn_mm_stats_bwd(relu, res, cts):
+def _bn_mm_stats_bwd(relu, interpret, res, cts):
     x, scale, shift, w = res
     _, pull = jax.vjp(
         lambda x, s, b, w: _bn_mm_stats_ref(x, s, b, w, relu), x, scale,
@@ -287,17 +302,6 @@ def _bn_mm_stats_bwd(relu, res, cts):
 
 
 _bn_act_matmul_stats.defvjp(_bn_mm_stats_fwd, _bn_mm_stats_bwd)
-
-
-def _interpret() -> bool:
-    """Run under the pallas interpreter off-TPU (same kernel body, CPU
-    execution) — how the tests drive these kernels."""
-    from paddle_tpu.parallel.mesh import current_mesh
-
-    m = current_mesh()
-    if m is not None:
-        return m.devices.flat[0].platform != "tpu"
-    return jax.default_backend() != "tpu"
 
 
 def fold_bn(mean, var, gamma, beta, eps=1e-5):

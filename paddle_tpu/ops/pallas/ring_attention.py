@@ -31,7 +31,6 @@ def _shard_map_mesh(mesh):
         else mesh
 
 
-
 def _block_attn(q, k, v, scale, q_off, k_off, causal, Tq, Tk):
     """Partial (unnormalized) attention of local q against one k/v block.
     q: [B,Tq,N,H]; k,v: [B,Tk,N,H]. Returns (acc, m, l) contributions."""
@@ -112,13 +111,16 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def ring_splash(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
                 s_axis: str = "sp", b_axis: Optional[str] = "dp",
                 h_axis: Optional[str] = "tp",
-                scale: Optional[float] = None) -> jax.Array:
+                scale: Optional[float] = None,
+                interpret: bool = False) -> jax.Array:
     """Full-mask ring attention whose per-block attention is the tuned
     splash kernel (VERDICT r5 item 4: T>=1024 splash speedups must
     compose with dp/sp/tp).
 
-    The manual region covers (batch, seq, heads) so the pallas kernel
-    sees fully local blocks; the ring rotates K/V over `s_axis` via
+    The manual region covers EVERY mesh axis (a Mosaic kernel lowers in
+    no other; attention._mesh_partitionable) and splits (batch, seq,
+    heads), so the pallas kernel sees fully local blocks; the ring
+    rotates K/V over `s_axis` via
     ppermute while normalized block outputs are merged through their
     logsumexp residuals (save_residuals=True), which is numerically the
     same online-softmax combine as ring_attention's unnormalized form:
@@ -126,9 +128,10 @@ def ring_splash(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
 
     Full (bidirectional) masks only — a splash mask is static per trace
     and cannot track the rotating block's causal diagonal; causal ring
-    stays on ring_attention's exact XLA blocks. Off-TPU the kernel runs
-    under the pallas interpreter, so CPU-mesh tests execute (not just
-    compile) this path.
+    stays on ring_attention's exact XLA blocks. `interpret` is mha()'s
+    one interpreter request handed down (attention._interpret_requested)
+    — with it CPU-mesh tests execute (not just compile) this path;
+    without it the block kernel compiles for the TPU.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -138,7 +141,6 @@ def ring_splash(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
 
         return mha(q, k, v, scale=scale, causal=False)
     perm = [(i, (i + 1) % S) for i in range(S)]
-    interpret = mesh.devices.flat[0].platform != "tpu"
     axes = {s_axis} | {a for a in (b_axis, h_axis)
                        if a and mesh.shape.get(a, 1) > 1}
     spec = P(b_axis if b_axis in axes else None, s_axis,
@@ -147,7 +149,7 @@ def ring_splash(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
 
     @functools.partial(
         jax.shard_map, mesh=sm_mesh, in_specs=(spec,) * 3, out_specs=spec,
-        axis_names=axes, check_vma=False)
+        axis_names=set(mesh.axis_names), check_vma=False)
     def run(q, k, v):
         return _ring_splash_local(float(scale), s_axis, S, tuple(perm),
                                   interpret, q, k, v)

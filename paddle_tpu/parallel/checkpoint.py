@@ -253,7 +253,7 @@ def restore_train_state(path: str, template: TrainState,
             abstract["loss_scale"] = jax.tree.map(
                 lambda m: jax.ShapeDtypeStruct(
                     tuple(m.shape), np.dtype(str(m.dtype))),
-                ckptr.metadata(path)["loss_scale"])
+                _saved_tree_metadata(ckptr, path)["loss_scale"])
         restored = ckptr.restore(path, abstract)
     if drop_saved_ls:
         restored.pop("loss_scale", None)
@@ -287,6 +287,13 @@ def restore_train_state(path: str, template: TrainState,
                       restored["step"], loss_scale)
 
 
+def _saved_tree_metadata(ckptr, path: str) -> dict:
+    """The saved pytree's per-leaf ArrayMetadata (shape, dtype), as the
+    installed orbax (0.11) hands it out: StepMetadata -> item_metadata
+    (TreeMetadata) -> tree."""
+    return ckptr.metadata(path).item_metadata.tree
+
+
 def _check_reshardable(path: str, target) -> None:
     """Refusal path for cross-mesh restores: every leaf's GLOBAL shape
     in the checkpoint must match the template's. Sharding may differ
@@ -296,7 +303,7 @@ def _check_reshardable(path: str, target) -> None:
     import orbax.checkpoint as ocp
 
     with ocp.StandardCheckpointer() as ckptr:
-        meta = ckptr.metadata(path)
+        meta = _saved_tree_metadata(ckptr, path)
     bad = []
     tgt_leaves = {jax.tree_util.keystr(p): l for p, l in
                   jax.tree_util.tree_flatten_with_path(target)[0]}

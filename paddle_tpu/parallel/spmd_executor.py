@@ -63,21 +63,6 @@ def _repatriate(v, mesh, mesh_devs):
     return jax.device_put(v, NamedSharding(mesh, P()))
 
 
-def _shard_map(f, mesh, in_specs, out_specs, axis_names, check_vma):
-    """jax.shard_map with a fallback to the pre-0.5 experimental API
-    (jax 0.4.x ships it as jax.experimental.shard_map without the
-    axis_names/check_vma kwargs; check_rep is the old name for the
-    replication check we disable)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _esm
-
-    return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=bool(check_vma))
-
-
 class SPMDRunner:
     """Run a (collective-transpiled) Program with the 'dp' axis manualized.
 
@@ -234,7 +219,7 @@ class SPMDRunner:
         feed_specs = {n: P(axis) for n in feed_names}
         fetch_specs = [P() if scalar_fetch[n] else P(axis)
                        for n in fetch_names]
-        sm = _shard_map(
+        sm = jax.shard_map(
             device_step,
             mesh=self.mesh,
             in_specs=(feed_specs,

@@ -357,7 +357,7 @@ def make_train_step(
 
     state_shardings_cache = {}
 
-    def jitted_step(state: TrainState, batch, rng):
+    def _jit_for(state: TrainState, batch):
         key = id(mesh)
         if key not in state_shardings_cache:
             st_sh = TrainState(
@@ -381,7 +381,16 @@ def make_train_step(
                 out_shardings=(st_sh, repl),
                 donate_argnums=(0,),
             )
-        return state_shardings_cache[key](state, batch, rng)
+        return state_shardings_cache[key]
+
+    def jitted_step(state: TrainState, batch, rng):
+        return _jit_for(state, batch)(state, batch, rng)
+
+    # the step's jax.stages.Lowered for the same arguments (arrays or
+    # ShapeDtypeStructs): what compiles can be read — kernels present,
+    # memory_analysis() — without running a step
+    jitted_step.lower = lambda state, batch, rng: _jit_for(
+        state, batch).lower(state, batch, rng)
 
     return init_state, jitted_step
 

@@ -742,13 +742,19 @@ class DecodeEngine:
     def warmup(self) -> int:
         """AOT-compile (or adopt from the persistent compile cache /
         a loaded warmstart artifact) every phase-grid executable.
-        Returns how many phases are ready. Idempotent."""
-        ready = 0
-        for key in self._phase_keys():
-            if self._phase_dispatch(key).warm(*self._phase_avals(key)):
-                ready += 1
+        Returns how many phases are ready — all of them: the grid is
+        closed and the engine's own, so a phase that does not compile
+        raises here, at boot, with the compiler's error, instead of
+        failing its first request. Idempotent."""
+        keys = self._phase_keys()
+        for key in keys:
+            disp = self._phase_dispatch(key)
+            if not disp.warm(*self._phase_avals(key)):
+                raise RuntimeError(
+                    f"decode phase {key[0]}@{key[1]} failed to "
+                    f"compile") from disp.aot_error
         self.warmed = True
-        return ready
+        return len(keys)
 
     def _model_digest(self) -> str:
         """Binds warmstart artifacts to THIS model + grid: params

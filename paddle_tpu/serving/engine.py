@@ -74,7 +74,7 @@ class ServingConfig:
                  warmup: bool = True,
                  aot: bool = True,
                  warmstart: Optional[str] = None,
-                 use_tpu: bool = True,
+                 use_tpu: Optional[bool] = None,
                  device_id: int = 0,
                  host: Optional[str] = None,
                  port: int = 0,
@@ -93,7 +93,10 @@ class ServingConfig:
         self.warmup = bool(warmup)
         self.aot = bool(aot)
         self.warmstart = warmstart
-        self.use_tpu = bool(use_tpu)
+        # None = the chip if the process has one, else the host
+        # (default_place); True/False are requests: True refuses to
+        # serve from a process without a TPU backend
+        self.use_tpu = None if use_tpu is None else bool(use_tpu)
         self.device_id = int(device_id)
         self.host = host
         self.port = int(port)
@@ -129,6 +132,12 @@ class ServingConfig:
         self.qos = qos
         self.model_id = str(model_id)
 
+    def apply_device(self, acfg: AnalysisConfig) -> None:
+        """Carry use_tpu/device_id onto a predictor config (both are
+        tri-state the same way)."""
+        acfg._use_tpu = self.use_tpu
+        acfg._device_id = self.device_id
+
 
 class Engine:
     """Predictor + BucketPolicy with warmup and per-bucket accounting.
@@ -151,9 +160,7 @@ class Engine:
             if self.precision == "int8":
                 self._served_dir = self._prepare_int8_model()
             acfg = AnalysisConfig(self._served_dir)
-            if not config.use_tpu:
-                acfg.disable_gpu()
-            acfg._device_id = config.device_id
+            config.apply_device(acfg)
             if config.aot:
                 acfg.enable_aot()
             # ALWAYS pin the policy: an explicit ServingConfig precision
@@ -327,9 +334,7 @@ class Engine:
             if not batches:
                 return
             acfg = AnalysisConfig(cfg.model_dir)
-            if not cfg.use_tpu:
-                acfg.disable_gpu()
-            acfg._device_id = cfg.device_id
+            cfg.apply_device(acfg)
             # the reference MUST be f32 — without the pin it would
             # resolve the same program-attr/env policy as the engine
             # and the reported delta would be reduced-vs-reduced

@@ -74,8 +74,9 @@ def _build_args(argv=None):
     ap.add_argument("--drain-timeout-s", type=float, default=30.0)
     ap.add_argument("--heartbeat-s", type=float, default=0.5)
     ap.add_argument("--cpu", action="store_true",
-                    help="pin JAX_PLATFORMS=cpu before jax loads "
-                    "(fleet simulation / tests)")
+                    help="pin jax to the CPU platform (fleet simulation "
+                    "/ tests; a chip belongs to ONE process, so N "
+                    "replicas on one chip is not a deployment)")
     ap.add_argument("--model-id", default="default",
                     help="model id this replica's default slot serves "
                     "(advertised in /v1/load for the router's "
@@ -94,8 +95,16 @@ def _build_args(argv=None):
 
 def main(argv=None) -> int:
     args = _build_args(argv)
+    import jax
+
     if args.cpu:
-        os.environ["JAX_PLATFORMS"] = "cpu"
+        # the package import above already loaded jax, which reads
+        # JAX_PLATFORMS only then: pin through the config, before any
+        # backend initializes
+        jax.config.update("jax_platforms", "cpu")
+    from ..core.compile_cache import place_jax_cache
+
+    place_jax_cache()
 
     from .engine import ServingConfig
     from .httpd import Server
@@ -107,8 +116,6 @@ def main(argv=None) -> int:
         return 2
     decode = None
     if args.decode_tiny is not None:
-        import jax
-
         from ..models import gpt
         from .decode import DecodeConfig, DecodeEngine
 

@@ -1,0 +1,212 @@
+"""chip_smoke.py's contract, as far as a machine without a chip can hold
+it: it fails off the chip, its phases are wired right (tiny configs, CPU,
+virtual devices — the rehearsal), the compile cache goes where it is told,
+and nothing between the attention gate and a kernel swaps a failing kernel
+for another path. The chip run itself is `python chip_smoke.py`.
+"""
+
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    mod._listen()
+    return mod
+
+
+def _last_json(stdout: str) -> dict:
+    return json.loads(stdout.strip().splitlines()[-1])
+
+
+def test_fails_without_a_chip():
+    """The no-fallback contract: on the CPU the script exits non-zero and
+    its last line says ok=false and names the device it found."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode != 0, proc.stdout
+    last = _last_json(proc.stdout)
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    assert set(last) == {"ok", "device"}
+    assert "no TPU" in proc.stderr
+    # the device phase ran and printed before the verdict; no other did
+    phases = [json.loads(ln)["phase"] for ln in
+              proc.stdout.strip().splitlines()[:-1]]
+    assert phases == ["device"]
+
+
+def test_bench_parent_stays_off_the_backend(tmp_path):
+    """bench.py's parent hands the chip to one child per metric, so it
+    must never initialize a jax backend itself (a parent that has touched
+    JAX holds the chip and its children then fail or hang)."""
+    code = (
+        "import sys; sys.argv = ['bench.py']\n"
+        "import bench\n"
+        "bench._run_bounded = lambda argv, t, env=None: (0, '', '')\n"
+        "bench.main()\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, xla_bridge._backends\n"
+        "print('parent-clean')\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 PADDLE_TPU_BENCH_FORCE_CPU="1",
+                 PADDLE_TPU_LOCK_FILE=str(tmp_path / "chip.lock")))
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "parent-clean" in proc.stdout
+
+
+# -- the rehearsal: every phase function, tiny configs, in this process ------
+
+
+def test_rehearse_device_and_program(smoke, capsys):
+    import paddle_tpu as pt
+
+    with smoke.phase("device") as info:
+        smoke.device_phase(info)
+    assert info["platform"] == "cpu" and info["peaks_row"] is None
+    with smoke.phase("program") as info:
+        smoke.program_phase(info, pt.CPUPlace(), steps=8)
+    lines = [json.loads(ln) for ln in
+             capsys.readouterr().out.strip().splitlines()]
+    assert [ln["phase"] for ln in lines] == ["device", "program"]
+    assert lines[1]["compile_requests"] >= 1
+    assert lines[1]["checked"]["loss_last"] < lines[1]["checked"][
+        "loss_first"]
+
+
+def test_rehearse_train_and_sharded(smoke):
+    """train_phase through sharded_phase: a dp x tp=2 mesh of four virtual
+    devices against one device, same batch, losses within tolerance; the
+    sharded step's compiled text (read back from `step.lower`) holds a
+    collective."""
+    from paddle_tpu.models import bert
+
+    cfg = bert.BertConfig(vocab_size=256, hidden=32, layers=1, heads=2,
+                          mlp_dim=64, max_len=32, dropout=0.1)
+    info = smoke.sharded_phase({}, cfg, 8, 32, jax.devices()[:4], steps=2)
+    checked = info["checked"]
+    assert checked["mesh"] == {"dp": 2, "tp": 2}
+    assert checked["param_shard_devices"] == 4
+    assert len(checked["losses_sharded"]) == 2
+    assert checked["loss_rel_diff_max"] <= checked["loss_rel_tol"]
+
+
+def test_rehearse_serve(smoke):
+    """serve_phase (HTTP, threads, float32 reference, zero compiles after
+    warm-up) and reuse_phase (chunked prefill + prefix cache on the
+    synchronous scheduler)."""
+    from paddle_tpu.models import gpt
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = gpt.GPTConfig.tiny()
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 15, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(8, 16), max_len=32), prompts, max_new=4,
+        logit_tol=0.25)
+    assert info["checked"]["finished"]["length"] == 3
+    assert info["checked"]["compiles_after_warmup"] == 0
+    info = smoke.reuse_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(2,), prefill_chunk=8,
+        prefix_cache=True, max_len=64),
+        rng.randint(0, cfg.vocab_size, 20).tolist(), max_new=3)
+    assert info["checked"]["prefix_hits"] >= 1
+
+
+# -- compile cache placement -------------------------------------------------
+
+
+@pytest.fixture
+def jax_cache_config():
+    """Restore JAX's cache settings: tier-1 runs with the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("outside", [None, "/somewhere/outside"])
+def test_cache_placement(monkeypatch, jax_cache_config, outside):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's handling of it is all
+    there is: the helper sets no directory. Without it the cache sits at
+    <checkout>/.jax_cache — fixed, and git-ignored."""
+    from paddle_tpu.core.compile_cache import place_jax_cache
+
+    if outside is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(REPO, ".jax_cache")
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        # what JAX itself did with the variable at import, stood in for
+        jax.config.update("jax_compilation_cache_dir", outside)
+        want = outside
+    assert place_jax_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+# -- a selected kernel route that fails, raises ------------------------------
+
+
+def test_selected_kernel_failure_propagates(monkeypatch):
+    """mha() with the splash route selected and the kernel raising: the
+    error reaches the caller; _xla_mha is never tried in its place."""
+    from paddle_tpu.core.flags import set_flags
+    from paddle_tpu.ops.pallas import attention as A
+
+    def boom(*a, **k):
+        raise RuntimeError("kernel refused")
+
+    def never(*a, **k):
+        raise AssertionError("fell through to the XLA path")
+
+    monkeypatch.setattr(A, "_splash_mha", boom)
+    monkeypatch.setattr(A, "_xla_mha", never)
+    q = jax.numpy.ones((1, 128, 2, 64), jax.numpy.float32)
+    set_flags({"FLAGS_flash_attention": "splash"})
+    try:
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            A.mha(q, q, q)
+    finally:
+        set_flags({"FLAGS_flash_attention": "auto"})
+
+
+def test_tpu_place_does_not_mean_default_backend():
+    """TPUPlace resolves on the "tpu" backend or not at all; choosing the
+    host when there is no chip is default_place()'s visible choice."""
+    import paddle_tpu as pt
+
+    with pytest.raises(RuntimeError):
+        pt.TPUPlace(0).jax_device()
+    assert not pt.is_compiled_with_tpu()
+    assert isinstance(pt.core.places.default_place(), pt.CPUPlace)
+    assert pt.CPUPlace().jax_device().platform == "cpu"

@@ -3,13 +3,22 @@ half of the ResNet byte-floor line-item (PROFILE.md round 5). Executed
 on CPU via the pallas interpreter (the real kernel bodies, not a
 fallback): value + gradient parity vs the XLA reference, and an
 end-to-end fused "bottleneck slice" (1x1 -> BN -> relu -> 1x1) vs its
-unfused equivalent."""
+unfused equivalent. The interpreter is ASKED for here (interpret=True);
+the kernels never pick it from the platform, and their TPU compiles are
+checked by tests/test_tpu_aot_compile.py."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.ops.pallas import fused_dense_bn as F
+
+matmul_stats = functools.partial(F.matmul_stats, interpret=True)
+bn_act_matmul = functools.partial(F.bn_act_matmul, interpret=True)
+bn_act_matmul_stats = functools.partial(F.bn_act_matmul_stats,
+                                        interpret=True)
 
 
 def _xw(rng, M=256, K=128, N=256, dtype=jnp.float32):
@@ -20,7 +29,7 @@ def _xw(rng, M=256, K=128, N=256, dtype=jnp.float32):
 
 def test_matmul_stats_parity(rng):
     x, w = _xw(rng)
-    y, mean, var = jax.jit(F.matmul_stats)(x, w)
+    y, mean, var = jax.jit(matmul_stats)(x, w)
     yr, mr, vr = F._mm_stats_ref(x, w)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=1e-5, atol=1e-5)
@@ -40,7 +49,7 @@ def test_matmul_stats_grads(rng):
         y, m, v = fn(x, w)
         return (y * cty).sum() + (m * ctm).sum() + (v * ctv).sum()
 
-    gx, gw = jax.grad(lambda x, w: loss(F.matmul_stats, x, w),
+    gx, gw = jax.grad(lambda x, w: loss(matmul_stats, x, w),
                       argnums=(0, 1))(x, w)
     gxr, gwr = jax.grad(lambda x, w: loss(F._mm_stats_ref, x, w),
                         argnums=(0, 1))(x, w)
@@ -55,7 +64,7 @@ def test_bn_act_matmul_parity_and_grads(rng):
     scale = jnp.asarray(rng.rand(128) + 0.5, jnp.float32)
     shift = jnp.asarray(rng.randn(128) * 0.1, jnp.float32)
     for relu in (True, False):
-        y = jax.jit(lambda *a: F.bn_act_matmul(*a, relu=relu))(
+        y = jax.jit(lambda *a: bn_act_matmul(*a, relu=relu))(
             x, scale, shift, w)
         yr = F._bn_mm_ref(x, scale, shift, w, relu)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
@@ -65,7 +74,7 @@ def test_bn_act_matmul_parity_and_grads(rng):
     def loss(fn):
         return lambda x, s, b, w: (fn(x, s, b, w) * ct).sum()
 
-    g = jax.grad(loss(lambda *a: F.bn_act_matmul(*a, relu=True)),
+    g = jax.grad(loss(lambda *a: bn_act_matmul(*a, relu=True)),
                  argnums=(0, 1, 2, 3))(x, scale, shift, w)
     gr = jax.grad(loss(lambda *a: F._bn_mm_ref(*a, True)),
                   argnums=(0, 1, 2, 3))(x, scale, shift, w)
@@ -86,9 +95,9 @@ def test_fused_bottleneck_slice_matches_unfused(rng):
     beta = jnp.asarray(rng.randn(C2) * 0.1, jnp.float32)
 
     def fused(x, w1, gamma, beta, w2):
-        y, mean, var = F.matmul_stats(x, w1)
+        y, mean, var = matmul_stats(x, w1)
         scale, shift = F.fold_bn(mean, var, gamma, beta)
-        return F.bn_act_matmul(y, scale, shift, w2, relu=True)
+        return bn_act_matmul(y, scale, shift, w2, relu=True)
 
     def unfused(x, w1, gamma, beta, w2):
         y = x @ w1
@@ -112,7 +121,7 @@ def test_fused_bottleneck_slice_matches_unfused(rng):
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_resnet_fused_1x1_matches_unfused(rng):
+def test_resnet_fused_1x1_matches_unfused(rng, monkeypatch):
     """ResNetConfig(fused_1x1=True): same loss and same BN running-stat
     updates as the XLA path on a single device. f64: conv-vs-matmul
     reduction-order noise at f32 gets amplified to percent level by
@@ -123,20 +132,27 @@ def test_resnet_fused_1x1_matches_unfused(rng):
 
     from paddle_tpu.models import resnet
 
+    # the model path compiles the kernels for the chip; here the test
+    # steers it onto the interpreter
+    monkeypatch.setattr(F, "matmul_stats", matmul_stats)
+    monkeypatch.setattr(F, "bn_act_matmul_stats", bn_act_matmul_stats)
     base = dataclasses.replace(resnet.ResNetConfig.tiny(),
                                dtype="float64")
     batch = resnet.make_batch(jax.random.key(1), base, 8, hw=32,
                               data_format="NHWC")
     out = {}
+    params, _ = resnet.init(jax.random.key(0), base)  # same for both
     for tag, fused in (("xla", False), ("fused", True)):
         cfg = dataclasses.replace(base, fused_1x1=fused)
-        params, _ = resnet.init(jax.random.key(0), cfg)
 
         def fwd(p):
             return resnet.loss_fn(p, cfg, batch, None,
                                   data_format="NHWC")
 
-        (l, aux), grads = jax.value_and_grad(fwd, has_aux=True)(params)
+        # jitted: op-by-op dispatch of ~50 interpreted kernel calls and
+        # the eager backward cost 4x the one compile
+        (l, aux), grads = jax.jit(
+            jax.value_and_grad(fwd, has_aux=True))(params)
         out[tag] = (float(l), aux, grads)
     l_x, upd_x, g_x = out["xla"]
     l_f, upd_f, g_f = out["fused"]

@@ -1,0 +1,148 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for
+a DESCRIBED v5e (no chip attached, `interpret=False`) at the widths the
+models run them at. Interpret-mode tests cannot see what the Mosaic
+lowering refuses (block tiling, VMEM, partitioning): `_mm_stats_pallas`
+passed every interpreter test while its (1, bn) stats blocks were
+refused at every ResNet-50 shape. A pass here is a compile, not a chip
+run — `chip_smoke.py` is the chip run.
+
+Kernels only (~0.1-2 s each); whole-step compiles stay out of tests/.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs land in /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import attention as A
+from paddle_tpu.ops.pallas import fused_dense_bn as F
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The described 2x2 v5e; module-scoped because describing it loads
+    the TPU compiler. JAX's persistent compilation cache is switched off
+    around these compiles: an entry written for a described device
+    cannot be read back without a chip and only produces warnings. So is
+    conftest's x64 mode: the program never enables it, and under it the
+    kernels' index maps turn i64, which Mosaic refuses."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    with jax.enable_x64(False):
+        yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _splash(causal, grad):
+    def fwd(q, k, v):
+        return A._splash_mha(q, k, v, 0.125, causal)
+
+    if not grad:
+        return fwd
+    return jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+
+
+# ResNet-50's 1x1-conv matmuls at bs=256, (M, K, N): stage-1 conv3,
+# stage-3 conv1, stage-4 conv3
+_RN50 = [(802816, 64, 256), (50176, 1024, 256), (12544, 512, 2048)]
+
+
+def _fused(kernel):
+    fn = getattr(F, kernel)
+    if kernel == "matmul_stats":
+        return lambda x, s, b, w: fn(x, w)
+    return lambda x, s, b, w: fn(x, s, b, w)
+
+
+_QKV = "qkv"
+_CASES = [
+    # BERT long-seq cell (bs 8, T 4096, 12 heads of 64, bf16), full mask
+    pytest.param(_splash(False, False), _QKV, (8, 4096, 12, 64),
+                 id="splash-fwd-T4096"),
+    pytest.param(_splash(False, True), _QKV, (8, 4096, 12, 64),
+                 id="splash-fwdbwd-T4096"),
+    # the auto gate's threshold for full masks
+    pytest.param(_splash(False, True), _QKV, (8, 1024, 12, 64),
+                 id="splash-fwdbwd-T1024"),
+    # causal: auto takes splash at EVERY 128-aligned causal shape on the
+    # chip, so GPT training reaches it at short T ...
+    pytest.param(_splash(True, True), _QKV, (8, 128, 12, 64),
+                 id="splash-causal-fwdbwd-T128"),
+    pytest.param(_splash(True, True), _QKV, (8, 1024, 12, 64),
+                 id="splash-causal-fwdbwd-T1024"),
+    # ... and the decode engine's whole-prompt prefill at [1, bucket]
+    pytest.param(_splash(True, False), _QKV, (1, 512, 12, 64),
+                 id="splash-causal-prefill-T512"),
+    # one ring shard's block (T 4096 over sp=2, 12 heads over tp=2)
+    pytest.param(lambda q, k, v: A._splash_block_with_lse(q, k, v), _QKV,
+                 (8, 2048, 6, 64), id="splash-block-lse-ringshard"),
+] + [
+    pytest.param(_fused(kernel), "xsbw", shape,
+                 id=f"{kernel}-{'x'.join(map(str, shape))}")
+    for kernel in ("matmul_stats", "bn_act_matmul", "bn_act_matmul_stats")
+    for shape in _RN50
+]
+
+
+@pytest.mark.parametrize("fn,kind,shape", _CASES)
+def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(s, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one)
+
+    if kind == _QKV:
+        args = (sds(shape),) * 3
+    else:
+        M, K, N = shape
+        args = (sds((M, K)), sds((K,), jnp.float32),
+                sds((K,), jnp.float32), sds((K, N)))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tp,sp,counter", [
+    (2, 1, "splash_shardmap"),   # seq unsharded: dp x tp shard_map wrapper
+    (2, 2, "ring_splash"),       # seq sharded: ring with splash blocks
+])
+def test_multichip_route_compiles_for_v5e_2x2(v5e, tp, sp, counter):
+    """mha() under a mesh of the four described chips takes the route the
+    gate picks on the real host (the mesh's platform is "tpu", so no
+    interpreter and no flag), and forward + backward partition and
+    compile. Both routes were refused ("Mosaic kernels cannot be
+    automatically partitioned") while their shard_map regions were
+    manual over only the axes they split."""
+    from paddle_tpu.parallel import MeshConfig, make_mesh, mesh_guard
+
+    mesh = make_mesh(MeshConfig(dp=-1, tp=tp, sp=sp), devices=v5e)
+    spec = P("dp", "sp" if sp > 1 else None, "tp", None)
+    qkv = jax.ShapeDtypeStruct((8, 4096, 12, 64), jnp.bfloat16,
+                               sharding=NamedSharding(mesh, spec))
+    A.GATE_COUNTS.clear()
+    def loss(q, k, v):
+        return A.mha(q, k, v).astype(jnp.float32).sum()
+
+    with mesh_guard(mesh):
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))
+                           ).lower(qkv, qkv, qkv).compile()
+    assert A.GATE_COUNTS[counter] == 1, dict(A.GATE_COUNTS)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if sp > 1:
+        assert "collective-permute" in text  # the ring's ppermute
