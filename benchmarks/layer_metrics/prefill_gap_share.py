@@ -1,0 +1,25 @@
+"""Share of the streams' token gaps in the window that hold an admission's
+prefill: decode dispatch intervals with a prefill span inside them, each
+weighted by the sequences live in the dispatch that opens it. It says on
+which side of the two-mode gap distribution (a bare decode step, or a step
+plus prefills) `itl_p95_ms` stands: in the upper mode while this is over
+0.05."""
+
+
+def read(rec):
+    if rec.get("kind") != "serve":
+        return None
+    steps = sorted((s[1], s[3]["live"]) for s in rec["spans"]
+                   if s[0] == "engine_dispatch")
+    fills = sorted(s[1] for s in rec["spans"] if s[0] == "prefill")
+    if len(steps) < 3:
+        return None
+    held = total = 0
+    i = 0
+    for (a, live), (b, _) in zip(steps, steps[1:]):
+        while i < len(fills) and fills[i] < a:
+            i += 1
+        total += live
+        if i < len(fills) and fills[i] < b:
+            held += live
+    return held / total if total else None
