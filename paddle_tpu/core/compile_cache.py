@@ -105,6 +105,26 @@ def place_jax_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
+def key_on_metadata() -> None:
+    """Make JAX's persistent cache key on HLO metadata too. The layer
+    scopes of models/ and parallel/train.py are metadata, which JAX
+    leaves out of the key by default: an executable cached before a
+    scope was added or renamed is then loaded again with the OLD labels
+    ("executables loaded from the cache may have stale metadata, which
+    may show up in profiles"), and a profile reduced by scope reads
+    nothing or the wrong layer. `DecodeEngine` calls this before it
+    compiles its phase grid, whose scopes the benchmark reads in every
+    traced run. The price: a change that only moves source lines of a
+    traced function recompiles the grid once, and a cache holds one set
+    of entries per version of the source. `make_train_step` does NOT
+    call it: one set of a BERT-base step's entries is 131 MiB, and a
+    192 MiB cache that alternates between two versions then compiles
+    every run (PERF.md, PR 24); whoever profiles a training step by
+    scope sets the option for that run (`benchmarks/trace_run.py`
+    does)."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
+
 def cache_dir() -> Optional[str]:
     d = os.environ.get("PADDLE_TPU_COMPILE_CACHE")
     # expand a literal "~" ourselves: docker ENV / env_file / systemd

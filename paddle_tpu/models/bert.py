@@ -97,19 +97,23 @@ def _attention(params: Params, prefix: str, x: jax.Array, mask: jax.Array,
                cfg: BertConfig, rng, deterministic: bool) -> jax.Array:
     B, T, H = x.shape
     nh, hd = cfg.heads, cfg.head_dim
-    q = dense(params, f"{prefix}.q", x).reshape(B, T, nh, hd)
-    k = dense(params, f"{prefix}.k", x).reshape(B, T, nh, hd)
-    v = dense(params, f"{prefix}.v", x).reshape(B, T, nh, hd)
-    q = shard(q, ("batch", "seq", "heads", None))
-    k = shard(k, ("batch", "seq", "heads", None))
-    v = shard(v, ("batch", "seq", "heads", None))
+    # layer scopes as in models/gpt.py: HLO metadata a profile reduces by
+    with jax.named_scope("qkv"):
+        q = dense(params, f"{prefix}.q", x).reshape(B, T, nh, hd)
+        k = dense(params, f"{prefix}.k", x).reshape(B, T, nh, hd)
+        v = dense(params, f"{prefix}.v", x).reshape(B, T, nh, hd)
+        q = shard(q, ("batch", "seq", "heads", None))
+        k = shard(k, ("batch", "seq", "heads", None))
+        v = shard(v, ("batch", "seq", "heads", None))
 
     from ..ops.pallas import attention as pallas_attention
 
-    ctx = pallas_attention.mha(q, k, v, mask=mask, scale=1.0 / math.sqrt(hd))
-    ctx = ctx.reshape(B, T, H)
-    out = dense(params, f"{prefix}.o", ctx)
-    return dropout(rng, out, cfg.dropout, deterministic)
+    with jax.named_scope("attention"):
+        ctx = pallas_attention.mha(q, k, v, mask=mask,
+                                   scale=1.0 / math.sqrt(hd))
+    with jax.named_scope("proj"):
+        out = dense(params, f"{prefix}.o", ctx.reshape(B, T, H))
+        return dropout(rng, out, cfg.dropout, deterministic)
 
 
 def encode(params: Params, cfg: BertConfig, input_ids: jax.Array,
@@ -123,10 +127,11 @@ def encode(params: Params, cfg: BertConfig, input_ids: jax.Array,
     if token_type_ids is None:
         token_type_ids = jnp.zeros_like(input_ids)
 
-    emb = (params["embeddings.word.w"][input_ids]
-           + params["embeddings.position.w"][:T][None, :, :]
-           + params["embeddings.type.w"][token_type_ids])
-    x = layer_norm(params, "embeddings.ln", emb).astype(adt)
+    with jax.named_scope("embed"):
+        emb = (params["embeddings.word.w"][input_ids]
+               + params["embeddings.position.w"][:T][None, :, :]
+               + params["embeddings.type.w"][token_type_ids])
+        x = layer_norm(params, "embeddings.ln", emb).astype(adt)
     x = shard(x, ("batch", "seq", "embed"))
     rngs = (jax.random.split(rng, cfg.layers * 2)
             if rng is not None else [None] * (cfg.layers * 2))
@@ -138,21 +143,24 @@ def encode(params: Params, cfg: BertConfig, input_ids: jax.Array,
         neg = jnp.asarray(-1e9 if adt == jnp.float32 else -3e4, jnp.float32)
         amask = jnp.where(attention_mask[:, None, None, :] > 0, 0.0, neg)
 
-    for i in range(cfg.layers):
-        p = f"layer{i}"
-        a = _attention(params, f"{p}.attn", x, amask, cfg, rngs[2 * i],
-                       deterministic)
-        x = layer_norm(params, f"{p}.attn.ln", x + a)
-        x = shard(x, ("batch", "seq", "embed"))
-        h = dense(params, f"{p}.mlp.up", x, act=gelu)
-        h = shard(h, ("batch", "seq", "mlp"))
-        h = dense(params, f"{p}.mlp.down", h)
-        h = dropout(rngs[2 * i + 1], h, cfg.dropout, deterministic)
-        x = layer_norm(params, f"{p}.mlp.ln", x + h)
-        x = shard(x, ("batch", "seq", "embed"))
+    with jax.named_scope("layers"):
+        for i in range(cfg.layers):
+            p = f"layer{i}"
+            a = _attention(params, f"{p}.attn", x, amask, cfg, rngs[2 * i],
+                           deterministic)
+            x = layer_norm(params, f"{p}.attn.ln", x + a)
+            x = shard(x, ("batch", "seq", "embed"))
+            with jax.named_scope("mlp"):
+                h = dense(params, f"{p}.mlp.up", x, act=gelu)
+                h = shard(h, ("batch", "seq", "mlp"))
+                h = dense(params, f"{p}.mlp.down", h)
+                h = dropout(rngs[2 * i + 1], h, cfg.dropout, deterministic)
+            x = layer_norm(params, f"{p}.mlp.ln", x + h)
+            x = shard(x, ("batch", "seq", "embed"))
     return x
 
 
+@jax.named_scope("mlm_head")
 def mlm_logits(params: Params, cfg: BertConfig, seq_out: jax.Array) -> jax.Array:
     h = dense(params, "mlm.transform", seq_out, act=gelu)
     h = layer_norm(params, "mlm.ln", h)
@@ -184,17 +192,23 @@ def pretrain_loss(params: Params, cfg: BertConfig, batch: Dict[str, jax.Array],
     else:
         labels = batch["mlm_labels"]  # [B, T], -100 = unmasked
         logits = mlm_logits(params, cfg, seq).astype(jnp.float32)
-    valid = labels >= 0
-    lab = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    tok_ll = jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-    mlm = -(tok_ll * valid).sum() / jnp.maximum(valid.sum(), 1)
+    with jax.named_scope("loss"):
+        valid = labels >= 0
+        lab = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        tok_ll = jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
+        mlm = -(tok_ll * valid).sum() / jnp.maximum(valid.sum(), 1)
 
     if "nsp_labels" in batch:
-        cls = jnp.tanh(dense(params, "pooler", seq[:, 0]).astype(jnp.float32))
-        nsp_logits = dense(params, "nsp", cls.astype(seq.dtype)).astype(jnp.float32)
-        nsp_lp = jax.nn.log_softmax(nsp_logits, axis=-1)
-        nsp = -jnp.take_along_axis(nsp_lp, batch["nsp_labels"][:, None], 1).mean()
+        with jax.named_scope("nsp_head"):
+            cls = jnp.tanh(
+                dense(params, "pooler", seq[:, 0]).astype(jnp.float32))
+            nsp_logits = dense(
+                params, "nsp", cls.astype(seq.dtype)).astype(jnp.float32)
+        with jax.named_scope("loss"):
+            nsp_lp = jax.nn.log_softmax(nsp_logits, axis=-1)
+            nsp = -jnp.take_along_axis(
+                nsp_lp, batch["nsp_labels"][:, None], 1).mean()
         return mlm + nsp
     return mlm
 

@@ -90,6 +90,7 @@ def dense(params: Params, name: str, x: jax.Array, act=None) -> jax.Array:
     return y
 
 
+@jax.named_scope("ln")
 def raw_layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
                    eps: float = 1e-12) -> jax.Array:
     # compute in fp32 for stability under bf16 activations

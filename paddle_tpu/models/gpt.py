@@ -95,11 +95,43 @@ def _ln(x, scale, bias, eps=1e-5):
     return raw_layer_norm(x, scale, bias, eps)
 
 
+# Layer scopes (`jax.named_scope`: HLO metadata, no op, no run-time cost).
+# Every step function below is built from these helpers, so a profile's
+# device time reduces by `embed`, `layers` and inside them `ln`, `qkv`,
+# `kv_write`, `kv_gather` (serving/kv_cache.py), `attention`, `proj`,
+# `mlp`, then `head`, whatever the shapes are (PERF.md section 3;
+# tests/test_layer_scopes.py holds the list).
+
+
+@jax.named_scope("qkv")
+def _qkv(lp, y):
+    qkv = y @ lp["blk.wqkv"].astype(y.dtype) + \
+        lp["blk.bqkv"].astype(y.dtype)
+    return jnp.split(qkv, 3, axis=-1)
+
+
+@jax.named_scope("proj")
+def _proj(lp, ctx, res=None):
+    """Output projection, added to the residual stream `res` if given."""
+    out = ctx @ lp["blk.wo"].astype(ctx.dtype)
+    if res is not None:
+        out = res + out
+    return out + lp["blk.bo"].astype(ctx.dtype)
+
+
+@jax.named_scope("head")
+def _head(params: Params, x, prev_ids, eos_id: int):
+    """Final LayerNorm, tied-embedding logits and the greedy pick for the
+    rows `x` [N, H]; `prev_ids` [N] are the tokens that led to them."""
+    x = _ln_named(params, "ln_f", x)
+    logits = x @ params["wte.w"].T.astype(x.dtype)
+    return _beam_top1(prev_ids.astype(jnp.int32), logits, eos_id)
+
+
 def _attention(lp, x, cfg: GPTConfig, mesh=None):
     B, T, H = x.shape
     nh, hd = cfg.heads, cfg.head_dim
-    qkv = x @ lp["blk.wqkv"].astype(x.dtype) + lp["blk.bqkv"].astype(x.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    q, k, v = _qkv(lp, x)
     q = q.reshape(B, T, nh, hd)
     k = k.reshape(B, T, nh, hd)
     v = v.reshape(B, T, nh, hd)
@@ -113,13 +145,13 @@ def _attention(lp, x, cfg: GPTConfig, mesh=None):
     # explicit ring attention over 'sp' — except inside an already-manual
     # region (the 'pp' pipeline): XLA cannot nest manual subregions, so
     # there GSPMD shards the sequence from the shard() constraints instead
-    if mesh is not None and mesh.shape.get("sp", 1) > 1 \
-            and not in_manual_region():
-        ctx = ra.ring_attention(q, k, v, mesh, axis="sp", causal=True)
-    else:
-        ctx = pa.mha(q, k, v, causal=True, scale=1.0 / math.sqrt(hd))
-    ctx = ctx.reshape(B, T, H)
-    return ctx @ lp["blk.wo"].astype(x.dtype) + lp["blk.bo"].astype(x.dtype)
+    with jax.named_scope("attention"):
+        if mesh is not None and mesh.shape.get("sp", 1) > 1 \
+                and not in_manual_region():
+            ctx = ra.ring_attention(q, k, v, mesh, axis="sp", causal=True)
+        else:
+            ctx = pa.mha(q, k, v, causal=True, scale=1.0 / math.sqrt(hd))
+    return _proj(lp, ctx.reshape(B, T, H))
 
 
 def _moe_mlp(lp, x, cfg: GPTConfig):
@@ -155,12 +187,15 @@ def _block(lp, x, cfg: GPTConfig, mesh=None):
     x = x + _attention(lp, h, cfg, mesh)
     x = shard(x, ("batch", "seq", "embed"))
     h = _ln(x, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
-    if cfg.n_experts:
-        x = x + _moe_mlp(lp, h, cfg)
-    else:
-        h = gelu(h @ lp["blk.w1"].astype(x.dtype) + lp["blk.b1"].astype(x.dtype))
-        h = shard(h, ("batch", "seq", "mlp"))
-        x = x + (h @ lp["blk.w2"].astype(x.dtype) + lp["blk.b2"].astype(x.dtype))
+    with jax.named_scope("mlp"):
+        if cfg.n_experts:
+            x = x + _moe_mlp(lp, h, cfg)
+        else:
+            h = gelu(h @ lp["blk.w1"].astype(x.dtype)
+                     + lp["blk.b1"].astype(x.dtype))
+            h = shard(h, ("batch", "seq", "mlp"))
+            x = x + (h @ lp["blk.w2"].astype(x.dtype)
+                     + lp["blk.b2"].astype(x.dtype))
     return shard(x, ("batch", "seq", "embed"))
 
 
@@ -179,7 +214,8 @@ def apply(params: Params, cfg: GPTConfig, ids: jax.Array,
 
     B, T = ids.shape
     adt = jnp.dtype(cfg.dtype)
-    x = (params["wte.w"][ids] + params["wpe.w"][:T][None]).astype(adt)
+    with jax.named_scope("embed"):
+        x = (params["wte.w"][ids] + params["wpe.w"][:T][None]).astype(adt)
     x = shard(x, ("batch", "seq", "embed"))
     lp_stacked = _layer_params(params)
     mesh = current_mesh()
@@ -208,10 +244,12 @@ def apply(params: Params, cfg: GPTConfig, ids: jax.Array,
         def layer_body(h, lp):
             return _block(lp, h, cfg, mesh), None
 
-        x, _ = jax.lax.scan(layer_body, x, lp_stacked)
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(layer_body, x, lp_stacked)
 
-    x = _ln_named(params, "ln_f", x)
-    logits = x @ params["wte.w"].T.astype(x.dtype)
+    with jax.named_scope("head"):
+        x = _ln_named(params, "ln_f", x)
+        logits = x @ params["wte.w"].T.astype(x.dtype)
     return shard(logits, ("batch", "seq", "vocab"))
 
 
@@ -256,6 +294,7 @@ def _beam_top1(prev_ids: jax.Array, logits: jax.Array,
     return out["selected_ids"][:, 0].astype(jnp.int32)
 
 
+@jax.named_scope("mlp")
 def _decode_mlp(lp, x):
     h = gelu(x @ lp["blk.w1"].astype(x.dtype) + lp["blk.b1"].astype(x.dtype))
     return h @ lp["blk.w2"].astype(x.dtype) + lp["blk.b2"].astype(x.dtype)
@@ -280,37 +319,34 @@ def apply_prefill(params: Params, cfg: GPTConfig, ids: jax.Array,
     B, T = ids.shape
     nh, hd = cfg.heads, cfg.head_dim
     adt = k_pool.dtype
-    x = (params["wte.w"][ids] + params["wpe.w"][:T][None]).astype(adt)
+    with jax.named_scope("embed"):
+        x = (params["wte.w"][ids] + params["wpe.w"][:T][None]).astype(adt)
 
     lp_stacked = _layer_params(params)
 
     def layer_body(h, per_layer):
         lp, kp, vp = per_layer
         y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        qkv = y @ lp["blk.wqkv"].astype(y.dtype) + \
-            lp["blk.bqkv"].astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = _qkv(lp, y)
         q = q.reshape(B, T, nh, hd)
         k = k.reshape(B, T, nh, hd)
         v = v.reshape(B, T, nh, hd)
         kp = kvc.write_prefill_kv(kp, k[0], block_table, block_size)
         vp = kvc.write_prefill_kv(vp, v[0], block_table, block_size)
-        ctx = pa.mha(q, k, v, causal=True, scale=1.0 / math.sqrt(hd))
+        with jax.named_scope("attention"):
+            ctx = pa.mha(q, k, v, causal=True, scale=1.0 / math.sqrt(hd))
         ctx = ctx.reshape(B, T, cfg.hidden)
-        h = h + ctx @ lp["blk.wo"].astype(h.dtype) + \
-            lp["blk.bo"].astype(h.dtype)
+        h = _proj(lp, ctx, h)
         y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
         h = h + _decode_mlp(lp, y)
         return h, (kp, vp)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        layer_body, x, (lp_stacked, k_pool, v_pool))
-    x = _ln_named(params, "ln_f", x)
+    with jax.named_scope("layers"):
+        x, (k_pool, v_pool) = jax.lax.scan(
+            layer_body, x, (lp_stacked, k_pool, v_pool))
+    # LayerNorm is per row: the last real position alone goes through it
     last = jnp.maximum(length, 1) - 1
-    x_last = x[0, last]                                   # [H]
-    logits = (x_last @ params["wte.w"].T.astype(x.dtype))[None]
-    prev = ids[0, last][None].astype(jnp.int32)
-    tok = _beam_top1(prev, logits, eos_id)
+    tok = _head(params, x[0, last][None], ids[0, last][None], eos_id)
     return tok, k_pool, v_pool
 
 
@@ -332,7 +368,8 @@ def apply_decode_step(params: Params, cfg: GPTConfig, ids: jax.Array,
     S = ids.shape[0]
     nh, hd = cfg.heads, cfg.head_dim
     adt = k_pool.dtype
-    x = (params["wte.w"][ids] + params["wpe.w"][positions]).astype(adt)
+    with jax.named_scope("embed"):
+        x = (params["wte.w"][ids] + params["wpe.w"][positions]).astype(adt)
 
     lp_stacked = _layer_params(params)
     scale = 1.0 / math.sqrt(hd)
@@ -340,9 +377,7 @@ def apply_decode_step(params: Params, cfg: GPTConfig, ids: jax.Array,
     def layer_body(h, per_layer):
         lp, kp, vp = per_layer
         y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        qkv = y @ lp["blk.wqkv"].astype(y.dtype) + \
-            lp["blk.bqkv"].astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = _qkv(lp, y)
         q = q.reshape(S, nh, hd)
         k = k.reshape(S, nh, hd)
         v = v.reshape(S, nh, hd)
@@ -350,24 +385,24 @@ def apply_decode_step(params: Params, cfg: GPTConfig, ids: jax.Array,
         vp = kvc.write_token_kv(vp, v, block_tables, positions, block_size)
         keys = kvc.gather_kv(kp, block_tables)        # [S, M, nh, hd]
         vals = kvc.gather_kv(vp, block_tables)
-        scores = jnp.einsum("snd,smnd->snm", q, keys) * scale
-        m = keys.shape[1]
-        mask = jnp.arange(m, dtype=jnp.int32)[None, :] <= positions[:, None]
-        scores = jnp.where(mask[:, None, :], scores, -1e9)
-        att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        ctx = jnp.einsum("snm,smnd->snd", att.astype(adt), vals)
+        with jax.named_scope("attention"):
+            scores = jnp.einsum("snd,smnd->snm", q, keys) * scale
+            m = keys.shape[1]
+            mask = jnp.arange(m, dtype=jnp.int32)[None, :] \
+                <= positions[:, None]
+            scores = jnp.where(mask[:, None, :], scores, -1e9)
+            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            ctx = jnp.einsum("snm,smnd->snd", att.astype(adt), vals)
         ctx = ctx.reshape(S, cfg.hidden)
-        h = h + ctx @ lp["blk.wo"].astype(h.dtype) + \
-            lp["blk.bo"].astype(h.dtype)
+        h = _proj(lp, ctx, h)
         y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
         h = h + _decode_mlp(lp, y)
         return h, (kp, vp)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        layer_body, x, (lp_stacked, k_pool, v_pool))
-    x = _ln_named(params, "ln_f", x)
-    logits = x @ params["wte.w"].T.astype(x.dtype)         # [S, vocab]
-    tok = _beam_top1(ids.astype(jnp.int32), logits, eos_id)
+    with jax.named_scope("layers"):
+        x, (k_pool, v_pool) = jax.lax.scan(
+            layer_body, x, (lp_stacked, k_pool, v_pool))
+    tok = _head(params, x, ids, eos_id)
     return tok, k_pool, v_pool
 
 
@@ -402,8 +437,9 @@ def apply_prefill_chunk(params: Params, cfg: GPTConfig, ids: jax.Array,
     # the final slice's padded tail can run past the positional table;
     # clamp (those rows' outputs are never consumed, their KV lands in
     # the null block / overwritten slots)
-    x = (params["wte.w"][ids[0]] +
-         params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
+    with jax.named_scope("embed"):
+        x = (params["wte.w"][ids[0]] +
+             params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
 
     lp_stacked = _layer_params(params)
     scale = 1.0 / math.sqrt(hd)
@@ -411,9 +447,7 @@ def apply_prefill_chunk(params: Params, cfg: GPTConfig, ids: jax.Array,
     def layer_body(h, per_layer):
         lp, kp, vp = per_layer
         y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        qkv = y @ lp["blk.wqkv"].astype(y.dtype) + \
-            lp["blk.bqkv"].astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = _qkv(lp, y)
         q = q.reshape(C, nh, hd)
         k = k.reshape(C, nh, hd)
         v = v.reshape(C, nh, hd)
@@ -421,26 +455,24 @@ def apply_prefill_chunk(params: Params, cfg: GPTConfig, ids: jax.Array,
         vp = kvc.write_chunk_kv(vp, v, block_table, start, block_size)
         keys = kvc.gather_kv(kp, block_table[None])[0]  # [M, nh, hd]
         vals = kvc.gather_kv(vp, block_table[None])[0]
-        scores = jnp.einsum("cnd,mnd->cnm", q, keys) * scale
-        m = keys.shape[0]
-        mask = jnp.arange(m, dtype=jnp.int32)[None, :] <= pos[:, None]
-        scores = jnp.where(mask[:, None, :], scores, -1e9)
-        att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        ctx = jnp.einsum("cnm,mnd->cnd", att.astype(adt), vals)
+        with jax.named_scope("attention"):
+            scores = jnp.einsum("cnd,mnd->cnm", q, keys) * scale
+            m = keys.shape[0]
+            mask = jnp.arange(m, dtype=jnp.int32)[None, :] <= pos[:, None]
+            scores = jnp.where(mask[:, None, :], scores, -1e9)
+            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            ctx = jnp.einsum("cnm,mnd->cnd", att.astype(adt), vals)
         ctx = ctx.reshape(C, cfg.hidden)
-        h = h + ctx @ lp["blk.wo"].astype(h.dtype) + \
-            lp["blk.bo"].astype(h.dtype)
+        h = _proj(lp, ctx, h)
         y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
         h = h + _decode_mlp(lp, y)
         return h, (kp, vp)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        layer_body, x, (lp_stacked, k_pool, v_pool))
-    x = _ln_named(params, "ln_f", x)
+    with jax.named_scope("layers"):
+        x, (k_pool, v_pool) = jax.lax.scan(
+            layer_body, x, (lp_stacked, k_pool, v_pool))
     last = jnp.clip(length - 1 - start, 0, C - 1)
-    logits = (x[last] @ params["wte.w"].T.astype(x.dtype))[None]
-    prev = ids[0, last][None].astype(jnp.int32)
-    tok = _beam_top1(prev, logits, eos_id)
+    tok = _head(params, x[last][None], ids[0, last][None], eos_id)
     return tok, k_pool, v_pool
 
 
@@ -471,8 +503,9 @@ def apply_verify_step(params: Params, cfg: GPTConfig, ids: jax.Array,
     nh, hd = cfg.heads, cfg.head_dim
     adt = k_pool.dtype
     pos = positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-    x = (params["wte.w"][ids] +
-         params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
+    with jax.named_scope("embed"):
+        x = (params["wte.w"][ids] +
+             params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
 
     lp_stacked = _layer_params(params)
     scale = 1.0 / math.sqrt(hd)
@@ -480,9 +513,7 @@ def apply_verify_step(params: Params, cfg: GPTConfig, ids: jax.Array,
     def layer_body(h, per_layer):
         lp, kp, vp = per_layer
         y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        qkv = y @ lp["blk.wqkv"].astype(y.dtype) + \
-            lp["blk.bqkv"].astype(y.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = _qkv(lp, y)
         q = q.reshape(S, W, nh, hd)
         k = k.reshape(S, W, nh, hd)
         v = v.reshape(S, W, nh, hd)
@@ -492,26 +523,25 @@ def apply_verify_step(params: Params, cfg: GPTConfig, ids: jax.Array,
                                block_size)
         keys = kvc.gather_kv(kp, block_tables)        # [S, M, nh, hd]
         vals = kvc.gather_kv(vp, block_tables)
-        scores = jnp.einsum("swnd,smnd->swnm", q, keys) * scale
-        m = keys.shape[1]
-        mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
-            <= pos[:, :, None]
-        scores = jnp.where(mask[:, :, None, :], scores, -1e9)
-        att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        ctx = jnp.einsum("swnm,smnd->swnd", att.astype(adt), vals)
+        with jax.named_scope("attention"):
+            scores = jnp.einsum("swnd,smnd->swnm", q, keys) * scale
+            m = keys.shape[1]
+            mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
+                <= pos[:, :, None]
+            scores = jnp.where(mask[:, :, None, :], scores, -1e9)
+            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            ctx = jnp.einsum("swnm,smnd->swnd", att.astype(adt), vals)
         ctx = ctx.reshape(S, W, cfg.hidden)
-        h = h + ctx @ lp["blk.wo"].astype(h.dtype) + \
-            lp["blk.bo"].astype(h.dtype)
+        h = _proj(lp, ctx, h)
         y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
         h = h + _decode_mlp(lp, y)
         return h, (kp, vp)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        layer_body, x, (lp_stacked, k_pool, v_pool))
-    x = _ln_named(params, "ln_f", x)
-    logits = x @ params["wte.w"].T.astype(x.dtype)     # [S, W, vocab]
-    tok = _beam_top1(ids.reshape(S * W).astype(jnp.int32),
-                     logits.reshape(S * W, -1), eos_id).reshape(S, W)
+    with jax.named_scope("layers"):
+        x, (k_pool, v_pool) = jax.lax.scan(
+            layer_body, x, (lp_stacked, k_pool, v_pool))
+    tok = _head(params, x.reshape(S * W, cfg.hidden), ids.reshape(S * W),
+                eos_id).reshape(S, W)
     return tok, k_pool, v_pool
 
 

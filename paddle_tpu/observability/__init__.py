@@ -76,6 +76,17 @@ from .health import NumericsError, check_numerics  # noqa: F401
 # the event log's trace join key: emit() asks this for the active
 # sampled trace id (injected so events.py stays file-path importable)
 events.set_trace_provider(tracing.current_trace_id)
+
+
+def _trace_annotation():
+    import jax
+
+    return jax.profiler.TraceAnnotation
+
+
+# a recorded span is a TraceAnnotation while it is open (injected the
+# same way: tools/obsdump.py loads tracing.py by path, without JAX)
+tracing.set_annotation_provider(_trace_annotation)
 from .httpd import (  # noqa: F401
     maybe_start_http_server, start_http_server, stop_http_server,
 )

@@ -40,6 +40,7 @@ import contextlib
 import contextvars
 import glob
 import gzip
+import itertools
 import json
 import os
 import random
@@ -49,6 +50,9 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
 __all__ = ["Span", "span", "record_span", "get_spans", "clear_spans",
            "dropped_spans", "save_spans", "export_trace",
+           "OpenSpan", "open_span", "record", "current_span",
+           "start_recording", "stop_recording", "recorded",
+           "add_record", "get_records", "set_annotation_provider",
            "merge_chrome_traces",
            "TraceContext", "parse_traceparent", "sample_rate",
            "start_trace", "current_trace", "current_trace_id",
@@ -427,21 +431,17 @@ def trace_span(name: str, cat: str = "trace",
 
 @contextlib.contextmanager
 def span(name: str, cat: str = "host", **args):
-    """Context-manager span recorded into the unified store. When a
-    sampled trace context is active, the span additionally joins the
-    distributed trace (child ids + JSONL sink) — the executor's step
-    spans gain the active trace id through exactly this path."""
-    cur = _current.get()
-    if cur is not None and cur.sampled:
-        with trace_span(name, cat=cat, ctx=cur, **args):
-            yield
-        return
-    t0 = time.perf_counter()
+    """Context-manager form of `open_span` for coarse sites (a training
+    step, a compiled program's run): always kept in the unified store,
+    whether or not recording is on. When a sampled trace context is
+    active, the span additionally joins the distributed trace (child ids
+    + JSONL sink) — the executor's step spans gain the active trace id
+    through exactly this path."""
+    sp = open_span(name, cat, ctx=_current.get(), keep=True)
     try:
         yield
     finally:
-        record_span(name, t0, time.perf_counter() - t0, cat,
-                    args or None)
+        sp.close(**args)
 
 
 @contextlib.contextmanager
@@ -462,6 +462,184 @@ def step_span(name: str, cat: str = "step", **args):
             _current.reset(token)
 
 
+# ---------------------------------------------------------------------------
+# Recorded spans: the program's own instrumentation of its hot loops
+# ---------------------------------------------------------------------------
+#
+# One primitive, `open_span()` ... `.close()`, serves the benchmark (the
+# ring, read after a run), the operator (a `jax.profiler.TraceAnnotation`
+# of the same name while it is open, so that in a profiler trace the span
+# lies on `/host:CPU`, on the device trace's clock) and the distributed
+# trace (a child of the request's sampled `TraceContext`, into the sink).
+# A hot-loop site is written
+#
+#     sp = _tracing.open_span("decode.dispatch", "decode") \
+#         if _tracing.recording else None
+#     ...
+#     if sp is not None:
+#         sp.close(slots=C)
+#
+# so that with recording off (the default) it costs one branch on this
+# module's flag: no clock read, no allocation, no lock. Request-level
+# sites add `or req.traced` (the request's context is sampled), which is
+# how the old `record_trace_span` names stay in `obsdump trace`.
+#
+# Times are CLOCK_MONOTONIC seconds (`time.monotonic`; on Linux
+# `perf_counter` reads the same clock, so RecordEvent's spans share the
+# axis), the clock the serving code stamps its requests with.
+
+recording = False
+clock = time.monotonic
+MAX_RECORDS = 200_000
+
+_annotation_provider = None     # () -> jax.profiler.TraceAnnotation
+_annotation = None              # resolved by start_recording()
+_stack = threading.local()
+_next_sid = itertools.count(1)
+_records: Dict[str, "collections.deque"] = {}
+
+
+def set_annotation_provider(fn):
+    """Inject where `TraceAnnotation` comes from (observability/__init__
+    wires `jax.profiler`), so that this file imports no JAX."""
+    global _annotation_provider, _annotation
+    _annotation_provider, _annotation = fn, None
+
+
+def start_recording(clear: bool = True):
+    """Turn the program's span sites and record lists on (a `--trace 1`
+    benchmark run, a `POST /v1/profile` capture); `clear` empties the
+    ring and the record lists first."""
+    global recording, _annotation
+    if clear:
+        clear_spans()
+    if _annotation is None and _annotation_provider is not None:
+        _annotation = _annotation_provider()
+    recording = True
+
+
+def stop_recording():
+    global recording
+    recording = False
+
+
+@contextlib.contextmanager
+def recorded(clear: bool = True):
+    """`with tracing.recorded():` records for the body; a recording that
+    was already on is left on."""
+    was = recording
+    start_recording(clear=clear and not was)
+    try:
+        yield
+    finally:
+        if not was:
+            stop_recording()
+
+
+class OpenSpan:
+    """A span between `open_span()` and `close()`. `sid` names it in the
+    `parent` field of what it causes; `rid` is the request it serves."""
+
+    __slots__ = ("name", "cat", "sid", "parent", "rid", "t0", "ctx",
+                 "_token", "_ann", "_keep")
+
+    def close(self, **facts):
+        t1 = clock()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = getattr(_stack, "spans", None)
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif stack and self in stack:   # an exception skipped the close
+            del stack[stack.index(self):]   # of a child
+        facts["sid"] = self.sid
+        if self.parent is not None:
+            facts["parent"] = self.parent
+        if self.rid is not None:
+            facts["rid"] = self.rid
+        if self._token is not None:
+            _current.reset(self._token)
+            record_span_ctx(self.ctx, self.name, t1 - self.t0,
+                            cat=self.cat, t0_perf=self.t0, **facts)
+        elif self._keep:
+            record_span(self.name, self.t0, t1 - self.t0, self.cat, facts)
+
+
+def _parent_sid(parent) -> Optional[int]:
+    return parent.sid if isinstance(parent, OpenSpan) else parent
+
+
+def open_span(name: str, cat: str = "host", parent=None, rid=None,
+              ctx: Optional[TraceContext] = None, keep: bool = False
+              ) -> OpenSpan:
+    """Open a span on this thread. `parent` (an OpenSpan or its `sid`)
+    defaults to the span open on this thread, `rid` to that span's.
+    With a sampled `ctx` the span is a child of it in the distributed
+    trace and the ambient context while it is open, whether or not
+    recording is on; `keep` puts it into the ring either way."""
+    sp = OpenSpan()
+    stack = getattr(_stack, "spans", None)
+    if stack is None:
+        stack = _stack.spans = []
+    top = stack[-1] if stack else None
+    sp.name, sp.cat, sp.sid = name, cat, next(_next_sid)
+    sp.parent = _parent_sid(parent) if parent is not None \
+        else (top.sid if top is not None else None)
+    sp.rid = rid if rid is not None or top is None else top.rid
+    sp._keep = recording or keep
+    sp.ctx = sp._token = sp._ann = None
+    if ctx is not None and ctx.sampled:
+        sp.ctx = ctx.child()
+        sp._token = _current.set(sp.ctx)
+    if recording and _annotation is not None:
+        sp._ann = _annotation(name)
+        sp._ann.__enter__()
+    stack.append(sp)
+    sp.t0 = clock()
+    return sp
+
+
+def current_span() -> Optional[OpenSpan]:
+    """The innermost span open on this thread (a request captures it as
+    the parent of the spans other threads record for it)."""
+    stack = getattr(_stack, "spans", None)
+    return stack[-1] if stack else None
+
+
+def record(name: str, t0: float, t1: float, cat: str = "host",
+           parent=None, rid=None, ctx: Optional[TraceContext] = None,
+           **facts):
+    """A span whose ends are known after the fact (a request's queue
+    wait, its time to the first token): into the ring when recording,
+    into the distributed trace when `ctx` is sampled."""
+    facts["sid"] = next(_next_sid)
+    if parent is not None:
+        facts["parent"] = _parent_sid(parent)
+    if rid is not None:
+        facts["rid"] = rid
+    if ctx is not None and ctx.sampled:
+        record_span_ctx(ctx.child(), name, t1 - t0, cat=cat, t0_perf=t0,
+                        **facts)
+    elif recording:
+        record_span(name, t0, t1 - t0, cat, facts)
+
+
+def add_record(kind: str, row: Dict[str, Any]):
+    """Append `row` to the record list `kind` (the decode engine's
+    `decode.steps` and `decode.requests`), kept with the spans, bounded
+    like them, read with `get_records` when a run ends."""
+    with _lock:
+        rows = _records.get(kind)
+        if rows is None:
+            rows = _records[kind] = collections.deque(maxlen=MAX_RECORDS)
+        rows.append(row)
+
+
+def get_records(kind: str) -> List[Dict[str, Any]]:
+    with _lock:
+        return list(_records.get(kind, ()))
+
+
 def get_spans(cat: Optional[str] = None) -> List[Span]:
     with _lock:
         out = list(_spans)
@@ -479,6 +657,7 @@ def clear_spans():
     global _dropped
     with _lock:
         _spans.clear()
+        _records.clear()
         _dropped = 0
 
 

@@ -197,8 +197,14 @@ def make_train_step(
 
     tx = optimizer
     if strategy.clip_global_norm:
-        tx = optax.chain(optax.clip_by_global_norm(strategy.clip_global_norm),
-                         optimizer)
+        clip = optax.clip_by_global_norm(strategy.clip_global_norm)
+
+        def clip_update(updates, state, params=None):
+            with jax.named_scope("clip"):
+                return clip.update(updates, state, params)
+
+        tx = optax.chain(
+            optax.GradientTransformation(clip.init, clip_update), optimizer)
 
     def mask_fn(params):
         return {k: is_trainable(k) for k in params}
@@ -288,9 +294,12 @@ def make_train_step(
         if not use_amp:
             loss, grads, aux = microbatch_grads(loss_fn, state.params,
                                                 batch, rng)
-            updates, opt_state = tx.update(grads, state.opt_state,
-                                           state.params)
-            params = optax.apply_updates(state.params, updates)
+            # layer scope (HLO metadata): a profile's device time under
+            # `optimizer` is the optax update and the parameter write
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, state.opt_state,
+                                               state.params)
+                params = optax.apply_updates(state.params, updates)
             # aux = non-trainable state updates keyed like params (BN stats)
             for k, v in aux.items():
                 params[k] = v.astype(params[k].dtype)
@@ -323,8 +332,10 @@ def make_train_step(
         for g in jax.tree.leaves(grads):
             finite = finite & jnp.all(jnp.isfinite(g))
 
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
         for k, v in aux.items():
             new_params[k] = v.astype(new_params[k].dtype)
         # overflow skips the whole update: params AND optimizer state

@@ -187,7 +187,10 @@ def capture_profile(seconds: float,
             raise ProfilerBusyError(str(e)) from e
         t0 = time.time()
         try:
-            time.sleep(seconds)
+            # the program's own spans (the decode loop's, the front's)
+            # record for the window and lie in the trace as annotations
+            with _tracing.recorded(clear=False):
+                time.sleep(seconds)
         finally:
             # stop directly rather than via stop_profiler(): the
             # aggregate host-event table printing belongs to the

@@ -85,12 +85,20 @@ SLOTS = _m.gauge(
 KV_BLOCKS = _m.gauge(
     "paddle_tpu_decode_kv_blocks",
     "KV-cache pool blocks (state=used|free)", labelnames=("state",))
+# a fifth apart from 1 ms to 1 s, so that a bucket quantile of a 10 ms or a
+# 100 ms step is good to 20% (the default buckets step from 0.1 to 0.25 s);
+# the tail keeps a stall's size readable
+_LATENCY_BUCKETS = _m.exponential_buckets(1e-3, 1.2, 39) + (
+    2.5, 5.0, 10.0, 30.0, 60.0)
 TTFT_SECONDS = _m.histogram(
     "paddle_tpu_decode_ttft_seconds",
-    "Submit-to-first-token latency (prefill completion)")
+    "Submit-to-first-token latency (prefill completion)",
+    buckets=_LATENCY_BUCKETS)
 STEP_SECONDS = _m.histogram(
     "paddle_tpu_decode_step_seconds",
-    "Wall seconds per decode step (dispatch N to dispatch N+1)")
+    "Wall seconds from a decode step's dispatch to its tokens on the "
+    "host (under the lazy loop's overlap: about two device steps)",
+    buckets=_LATENCY_BUCKETS)
 TOKENS = _m.counter(
     "paddle_tpu_decode_tokens_total",
     "Tokens sampled (phase=prefill|decode)", labelnames=("phase",))
@@ -205,6 +213,15 @@ class DecodeHandle:
         self._req = req
 
     @property
+    def rid(self) -> int:
+        return self._req.rid
+
+    @property
+    def t_first(self) -> Optional[float]:
+        """CLOCK_MONOTONIC time of the first token's emission."""
+        return self._req.t_first
+
+    @property
     def info(self) -> Dict:
         r = self._req
         return {
@@ -240,7 +257,7 @@ class _Request:
                  "error", "cancelled", "last_token", "pos", "blocks",
                  "admitted_at", "tctx", "enqueued_at",
                  "prefill_pos", "draft_pos", "n_reused", "hashes",
-                 "tenant")
+                 "tenant", "traced", "parent", "arrival", "preempted")
 
     def __init__(self, rid: int, prompt: np.ndarray, max_new: int,
                  tenant: str = "default"):
@@ -249,6 +266,18 @@ class _Request:
         # captured on the submitter's thread; the scheduler thread
         # records queue-wait/prefill/TTFT spans against it later
         self.tctx = _tracing.current_trace()
+        # request-level span sites record when recording is on OR the
+        # request's context is sampled (the distributed trace)
+        self.traced = self.tctx is not None and self.tctx.sampled
+        # the span open on the submitter's thread (the handler's
+        # http.generate) is the parent of every span recorded for this
+        # request; its start is the request's arrival at the front
+        self.parent = self.arrival = None
+        if _tracing.recording:
+            cause = _tracing.current_span()
+            if cause is not None:
+                self.parent, self.arrival = cause.sid, cause.t0
+        self.preempted = 0
         self.prompt = prompt                   # grows on preempt-replay
         self.prompt_len0 = len(prompt)         # original, for reporting
         self.max_new = int(max_new)
@@ -301,6 +330,7 @@ class DecodeEngine:
 
         self.config = config or DecodeConfig()
         self.model_cfg = model_cfg
+        _cc.key_on_metadata()   # the phase grid's scopes are read in profiles
         self.prefill_chunk = int(getattr(self.config, "prefill_chunk",
                                          0))
         self.spec_k = int(getattr(self.config, "spec_k", 0))
@@ -560,6 +590,11 @@ class DecodeEngine:
         self._thread: Optional[threading.Thread] = None
         self._rid = 0
         self._last_slot_config: Optional[int] = None
+        # start times of the last decode dispatches: status() reads the
+        # exact median and p95 step time that STEP_SECONDS' buckets
+        # cannot give
+        self._step_starts: "collections.deque[float]" = \
+            collections.deque(maxlen=257)
         self._counts = {k: 0 for k in
                         ("eos", "length", "rejected", "cancelled",
                          "error", "preempted")}
@@ -1099,6 +1134,7 @@ class DecodeEngine:
             "analysis": self.analysis,
             "kv": self._alloc.stats(live_tokens=live_tokens),
             "requests": counts,
+            "step_ms": self._step_ms(),
         }
         if self._qos is not None:
             out["qos"] = {
@@ -1120,6 +1156,19 @@ class DecodeEngine:
                 if self._spec_proposed else None,
             }
         return out
+
+    def _step_ms(self) -> Optional[Dict]:
+        """Start-to-start times of the last (up to 256) decode steps:
+        an admission's prefill lies inside the gap it delays, so the
+        median is the bare step and the p95 a step plus a prefill."""
+        starts = list(self._step_starts)
+        gaps = sorted(b - a for a, b in zip(starts, starts[1:]))
+        if not gaps:
+            return None
+        return {"n": len(gaps),
+                "p50": round(1e3 * gaps[len(gaps) // 2], 3),
+                "p95": round(1e3 * gaps[min(len(gaps) - 1,
+                                            int(0.95 * len(gaps)))], 3)}
 
     # -- scheduler internals (single thread owns everything below) -----
 
@@ -1148,11 +1197,12 @@ class DecodeEngine:
             if self._qos is not None:
                 self._qosm.TENANT_TTFT_SECONDS.observe(
                     req.t_first - req.t_submit, tenant=req.tenant)
-            # per-request TTFT span: submit -> first sampled token
-            _tracing.record_trace_span(
-                "decode.ttft", req.tctx, req.t_first - req.t_submit,
-                cat="decode", rid=req.rid, prompt_len=req.prompt_len0,
-                tenant=req.tenant)
+            if _tracing.recording or req.traced:
+                # per-request TTFT span: submit -> first sampled token
+                _tracing.record(
+                    "decode.ttft", req.t_submit, req.t_first, "decode",
+                    parent=req.parent, rid=req.rid, ctx=req.tctx,
+                    prompt_len=req.prompt_len0, tenant=req.tenant)
         req.events.put(int(tok))
 
     def _finished_reason(self, req: _Request) -> Optional[str]:
@@ -1164,18 +1214,8 @@ class DecodeEngine:
 
     def _finish(self, req: _Request, reason: str):
         req.finish_reason = reason
-        now = time.monotonic()
-        if req.t_first is not None and len(req.generated) > 1:
-            # decode-phase span: first token -> last token (the
-            # prefill/TTFT spans cover everything before it)
-            _tracing.record_trace_span(
-                "decode.decode", req.tctx, now - req.t_first,
-                cat="decode", rid=req.rid,
-                tokens=len(req.generated) - 1)
-        _tracing.record_trace_span(
-            "decode.generate", req.tctx, now - req.t_submit,
-            cat="decode", rid=req.rid, tokens=len(req.generated),
-            reason=reason, tenant=req.tenant)
+        if _tracing.recording or req.traced:
+            self._record_finish(req, reason, time.monotonic())
         if req.blocks:
             self._alloc.free(req.blocks)   # reuse allocator: decref;
             req.blocks = []                # cached blocks go to LRU
@@ -1186,6 +1226,44 @@ class DecodeEngine:
         self._count(reason, req.tenant)
         req.events.put(None)
         self._kv_gauges()
+
+    def _record_finish(self, req: _Request, reason: str, now: float):
+        if req.t_first is not None and len(req.generated) > 1:
+            # decode-phase span: first token -> last token (the
+            # prefill/TTFT spans cover everything before it)
+            _tracing.record(
+                "decode.decode", req.t_first, now, "decode",
+                parent=req.parent, rid=req.rid, ctx=req.tctx,
+                tokens=len(req.generated) - 1)
+        _tracing.record(
+            "decode.generate", req.t_submit, now, "decode",
+            parent=req.parent, rid=req.rid, ctx=req.tctx,
+            tokens=len(req.generated), reason=reason, tenant=req.tenant)
+        if _tracing.recording:
+            # the request record: what a reader needs of _Request
+            # without reaching into it (times are CLOCK_MONOTONIC)
+            _tracing.add_record("decode.requests", {
+                "rid": req.rid,
+                "trace_id": req.tctx.trace_id if req.tctx else None,
+                "arrival": req.arrival if req.arrival is not None
+                else req.t_submit,
+                "t_submit": req.t_submit,
+                "enqueued_at": req.enqueued_at,
+                "admitted_at": req.admitted_at or None,
+                "t_first": req.t_first, "t_finish": now,
+                "prompt_len": req.prompt_len0,
+                "n_tokens": len(req.generated), "outcome": reason,
+                "preemptions": req.preempted, "tenant": req.tenant})
+
+    def _step_record(self, kind: str, t: float, slots: int, live: int,
+                     live_tokens: int):
+        """One row per dispatched program (recording on): what ran, how
+        full it was, and the allocator's own count of blocks."""
+        _tracing.add_record("decode.steps", {
+            "t": t, "kind": kind, "slots": slots, "live": live,
+            "live_tokens": live_tokens,
+            "blocks_used": self._alloc.used_blocks(),
+            "blocks_usable": self.kv_cfg.usable_blocks})
 
     def _kv_gauges(self):
         KV_BLOCKS.set(self._alloc.used_blocks(), state="used")
@@ -1247,8 +1325,11 @@ class DecodeEngine:
         each admission runs its prefill (the admission boundary is the
         one place the scheduler syncs with the device). Returns whether
         the batch composition changed."""
-        changed = False
+        admitted = 0
         max_slots = self.decode_slots[-1]
+        waiting = len(self._waiting)
+        sp = _tracing.open_span("decode.admit", "decode") \
+            if _tracing.recording and waiting else None
         while True:
             with self._cv:
                 if not self._waiting or self._closed:
@@ -1265,15 +1346,34 @@ class DecodeEngine:
                 del self._waiting[idx]
                 QUEUE_DEPTH.set(len(self._waiting))
             self._prefill_one(req)
-            changed = True
-        return changed
+            admitted += 1
+        if sp is not None:
+            sp.close(waiting=waiting, admitted=admitted)
+        return bool(admitted)
 
     def _prefill_one(self, req: _Request):
         # the admission boundary: everything since (re-)enqueue was wait
-        _tracing.record_trace_span(
-            "decode.queue_wait", req.tctx,
-            time.monotonic() - req.enqueued_at, cat="decode",
-            rid=req.rid, tenant=req.tenant)
+        req.admitted_at = time.monotonic()
+        sp = None
+        if _tracing.recording or req.traced:
+            _tracing.record(
+                "decode.queue_wait", req.enqueued_at, req.admitted_at,
+                "decode", parent=req.parent, rid=req.rid, ctx=req.tctx,
+                tenant=req.tenant)
+            sp = _tracing.open_span("decode.prefill", "decode",
+                                    parent=req.parent, rid=req.rid,
+                                    ctx=req.tctx)
+        bucket = None
+        try:
+            bucket = self._prefill_admitted(req)
+        finally:
+            if sp is not None:
+                sp.close(bucket=bucket, prompt_len=len(req.prompt),
+                         queue_wait_s=req.admitted_at - req.enqueued_at)
+
+    def _prefill_admitted(self, req: _Request) -> Optional[int]:
+        """The prompt work of one admitted request; returns its bucket
+        (None: the replay outgrew the bucket set)."""
         if self._wfq is not None:
             # prefill service charge: a long prompt is real work even
             # before its first decode token
@@ -1285,7 +1385,7 @@ class DecodeEngine:
                 f"prompt+generated length {plen} exceeds the largest "
                 f"prefill bucket {self.prefill_buckets[-1]}")
             self._finish(req, "error")
-            return
+            return None
         need = -(-plen // self.kv_cfg.block_size)
         req.blocks = self._alloc.alloc(need)
         bt = build_block_table(req.blocks, self.kv_cfg.max_blocks_per_seq)
@@ -1294,15 +1394,18 @@ class DecodeEngine:
         ids[0, plen:] = req.prompt[-1]         # edge-pad (in-distribution)
         kp, vp = self._pools
         t0 = time.perf_counter()
+        wait = None
+        if _tracing.recording:
+            self._step_record("prefill", t0, 1, 1, plen)
+            wait = _tracing.open_span("decode.prefill.wait", "decode")
         tok, kp, vp = self._prefill[bucket](
             self.params, ids, np.int32(plen), kp, vp, bt)
+        t_called = time.perf_counter() if wait is not None else 0.0
         self._pools = (kp, vp)
         tok0 = int(np.asarray(tok)[0])         # admission-boundary sync
+        if wait is not None:
+            wait.close(call_s=t_called - t0)
         STEPS.inc(phase="prefill")
-        _tracing.record_trace_span(
-            "decode.prefill", req.tctx, time.perf_counter() - t0,
-            cat="decode", t0_perf=t0, rid=req.rid, bucket=int(bucket),
-            prompt_len=plen)
         _telemetry.record_dispatch_ready(
             "decode:prefill", time.perf_counter() - t0)
         # live-MFU sample: the bucket executable's retained
@@ -1324,13 +1427,13 @@ class DecodeEngine:
             req.draft_pos = plen
             STEPS.inc(phase="draft")
         req.pos = plen
-        req.admitted_at = time.monotonic()
         self._active.append(req)
         self._emit_token(req, tok0, phase="prefill")
         reason = self._finished_reason(req)
         if reason:
             self._finish(req, reason)
         self._kv_gauges()
+        return bucket
 
     def _grow_blocks(self, pending: Optional[_Pending]
                      ) -> Optional[_Pending]:
@@ -1338,6 +1441,9 @@ class DecodeEngine:
         lands in. On pool exhaustion: resolve the in-flight step (its
         finishes may free blocks), retry, then preempt the youngest
         active sequence until the step fits."""
+        sp = _tracing.open_span("decode.grow", "decode") \
+            if _tracing.recording else None
+        taken = preempted = 0
         while True:
             short = None
             for req in self._active:
@@ -1345,18 +1451,23 @@ class DecodeEngine:
                 while bi >= len(req.blocks):
                     try:
                         req.blocks.extend(self._alloc.alloc(1))
+                        taken += 1
                     except NoBlocksError:
                         short = req
                         break
                 if short is not None:
                     break
             if short is None:
-                return pending
+                break
             if pending is not None:
                 pending = self._resolve(pending)
                 continue  # finishes may have freed enough
             victim = max(self._active, key=self._victim_key)
             self._preempt(victim)
+            preempted += 1
+        if sp is not None:
+            sp.close(blocks=taken, preempted=preempted)
+        return pending
 
     def _preempt(self, req: _Request):
         """vLLM-style recompute preemption: free the victim's blocks
@@ -1378,6 +1489,7 @@ class DecodeEngine:
             [req.prompt[:req.prompt_len0],
              np.asarray(req.generated, np.int32)])
         req.enqueued_at = time.monotonic()
+        req.preempted += 1
         with self._cv:
             self._waiting.appendleft(req)
             QUEUE_DEPTH.set(len(self._waiting))
@@ -1388,9 +1500,11 @@ class DecodeEngine:
         _events.emit("decode", action="preempt", rid=req.rid,
                      generated=len(req.generated), tenant=req.tenant,
                      **extra)
-        _tracing.record_trace_span(
-            "decode.preempt", req.tctx, 0.0, cat="decode", rid=req.rid,
-            generated=len(req.generated))
+        if _tracing.recording or req.traced:
+            _tracing.record(
+                "decode.preempt", req.enqueued_at, req.enqueued_at,
+                "decode", parent=req.parent, rid=req.rid, ctx=req.tctx,
+                generated=len(req.generated))
         self._kv_gauges()
 
     def _snapshot(self, C: int) -> Tuple[Tuple[int, ...],
@@ -1401,6 +1515,8 @@ class DecodeEngine:
         return tuple(r.rid if r else -1 for r in slots), slots
 
     def _dispatch(self, ids_arg, C: int) -> _Pending:
+        sp = _tracing.open_span("decode.dispatch", "decode") \
+            if _tracing.recording else None
         kp, vp = self._pools
         positions = np.zeros((C,), np.int32)
         bts = np.zeros((C, self.kv_cfg.max_blocks_per_seq), np.int32)
@@ -1420,15 +1536,26 @@ class DecodeEngine:
         STEPS.inc(phase="decode")
         OCCUPANCY.observe(sum(1 for r in slots if r is not None) / C)
         self._last_slot_config = C
-        return _Pending(FetchHandle([tok], site="decode"), tok, sig, slots)
+        pending = _Pending(FetchHandle([tok], site="decode"), tok, sig,
+                           slots)
+        self._step_starts.append(pending.t_dispatch)
+        if sp is not None:
+            self._close_dispatch(sp, "decode", C, slots)
+        return pending
 
     def _resolve(self, pending: _Pending) -> None:
         """Consume one in-flight step's tokens: stream them, detect
         finishes, retire (freeing blocks). Tokens for slots that were
         already retired/preempted after dispatch are discarded."""
+        sp = wait = None
+        if _tracing.recording:
+            sp = _tracing.open_span("decode.resolve", "decode")
+            wait = _tracing.open_span("decode.resolve.wait", "decode")
         t_wait = time.perf_counter()
         toks = np.asarray(pending.handle.result()[0])
         now = time.perf_counter()
+        if wait is not None:
+            wait.close()
         wall = now - pending.t_dispatch
         STEP_SECONDS.observe(wall)
         # live-MFU sample: the slot-config executable's retained FLOPs
@@ -1441,14 +1568,60 @@ class DecodeEngine:
             tokens=sum(1 for r in pending.slots if r is not None),
             host_blocked=min(now - t_wait, wall),
             device_kind=self._device_kind)
+        emitted = finished = 0
         for i, req in enumerate(pending.slots):
             if req is None or req not in self._active:
                 continue
             self._emit_token(req, int(toks[i]), phase="decode")
+            emitted += 1
             reason = self._finished_reason(req)
             if reason:
                 self._finish(req, reason)
+                finished += 1
+        if sp is not None:
+            sp.close(tokens=emitted, finished=finished)
         return None
+
+    def _turn(self, pending: Optional[_Pending]) -> Optional[_Pending]:
+        """One turn of the lazy loop: admit, grow, dispatch step N,
+        resolve step N-1. Returns the step left in flight."""
+        self._sweep_cancelled()
+        self._admit()
+        if not self._active:
+            if pending is not None:
+                pending = self._resolve(pending)
+            return pending
+        pending = self._grow_blocks(pending)
+        if not self._active:  # growth preempted everything
+            return pending
+        C = self._slot_config()
+        sig, slots = self._snapshot(C)
+        if pending is not None and pending.snapshot == sig:
+            # steady state: feed the previous step's tokens
+            # back on DEVICE — the host never touched them
+            ids_arg = pending.tok_dev
+        else:
+            if pending is not None:
+                pending = self._resolve(pending)
+                self._admit()  # retirements freed slots
+                # a request admitted HERE whose prompt length
+                # is an exact block multiple needs its next
+                # block before this dispatch, or its first
+                # decode write lands in the null block
+                self._grow_blocks(None)
+                if not self._active:
+                    return pending
+                C = self._slot_config()
+                sig, slots = self._snapshot(C)
+            ids_arg = np.zeros((C,), np.int32)
+            for i, req in enumerate(slots):
+                if req is not None:
+                    ids_arg[i] = req.last_token
+        new_pending = self._dispatch(ids_arg, C)
+        if pending is not None:
+            # overlap: resolve step N-1 while step N runs
+            self._resolve(pending)
+        return new_pending
 
     def _loop(self):
         pending: Optional[_Pending] = None
@@ -1460,43 +1633,14 @@ class DecodeEngine:
                         self._cv.wait(timeout=0.5)
                     if self._closed:
                         break
-                self._sweep_cancelled()
-                self._admit()
-                if not self._active:
-                    if pending is not None:
-                        pending = self._resolve(pending)
-                    continue
-                pending = self._grow_blocks(pending)
-                if not self._active:  # growth preempted everything
-                    continue
-                C = self._slot_config()
-                sig, slots = self._snapshot(C)
-                if pending is not None and pending.snapshot == sig:
-                    # steady state: feed the previous step's tokens
-                    # back on DEVICE — the host never touched them
-                    ids_arg = pending.tok_dev
-                else:
-                    if pending is not None:
-                        pending = self._resolve(pending)
-                        self._admit()  # retirements freed slots
-                        # a request admitted HERE whose prompt length
-                        # is an exact block multiple needs its next
-                        # block before this dispatch, or its first
-                        # decode write lands in the null block
-                        self._grow_blocks(None)
-                        if not self._active:
-                            continue
-                        C = self._slot_config()
-                        sig, slots = self._snapshot(C)
-                    ids_arg = np.zeros((C,), np.int32)
-                    for i, req in enumerate(slots):
-                        if req is not None:
-                            ids_arg[i] = req.last_token
-                new_pending = self._dispatch(ids_arg, C)
-                if pending is not None:
-                    # overlap: resolve step N-1 while step N runs
-                    pending = self._resolve(pending)
-                pending = new_pending
+                # the idle wait above lies outside the turn's span
+                sp = _tracing.open_span("decode.turn", "decode") \
+                    if _tracing.recording else None
+                try:
+                    pending = self._turn(pending)
+                finally:
+                    if sp is not None:
+                        sp.close(loop="lazy")
         except BaseException as e:  # scheduler death must not hang clients
             with self._cv:
                 reqs = list(self._active) + list(self._waiting)
@@ -1575,41 +1719,49 @@ class DecodeEngine:
         spread over later iterations); without chunking (spec-only
         engines) the whole-prompt prefill runs here as in _admit."""
         max_slots = self.decode_slots[-1]
+        waiting = len(self._waiting)
+        sp = _tracing.open_span("decode.admit", "decode") \
+            if _tracing.recording and waiting else None
+        admitted = 0
         while True:
             chunked = False
             with self._cv:
                 if not self._waiting or self._closed:
-                    return
+                    break
                 if self.config.static_batching and \
                         (self._active or self._prefilling):
-                    return
+                    break
                 if len(self._active) + len(self._prefilling) \
                         >= max_slots:
-                    return
+                    break
                 idx = self._pick_waiting_locked()
                 req = self._waiting[idx]
                 if self.prefill_chunk:
                     if not self._reserve_chunked(req):
-                        return
+                        break
                     chunked = True
                 else:
                     need = -(-len(req.prompt) // self.kv_cfg.block_size)
                     if not self._alloc.can_alloc(need):
-                        return
+                        break
                 del self._waiting[idx]
                 QUEUE_DEPTH.set(len(self._waiting))
+            admitted += 1
             if chunked:
-                _tracing.record_trace_span(
-                    "decode.queue_wait", req.tctx,
-                    time.monotonic() - req.enqueued_at, cat="decode",
-                    rid=req.rid, tenant=req.tenant)
+                req.admitted_at = time.monotonic()
+                if _tracing.recording or req.traced:
+                    _tracing.record(
+                        "decode.queue_wait", req.enqueued_at,
+                        req.admitted_at, "decode", parent=req.parent,
+                        rid=req.rid, ctx=req.tctx, tenant=req.tenant)
                 if self._wfq is not None:
                     self._wfq.charge(req.tenant, len(req.prompt))
-                req.admitted_at = time.monotonic()
                 self._prefilling.append(req)
                 self._kv_gauges()
             else:
                 self._prefill_one(req)
+        if sp is not None:
+            sp.close(waiting=waiting, admitted=admitted)
 
     def _pump_chunk(self):
         """Advance the FRONT prefilling request by one chunk (both
@@ -1619,6 +1771,22 @@ class DecodeEngine:
         if not self._prefilling:
             return
         req = self._prefilling[0]
+        sp = None
+        if _tracing.recording or req.traced:
+            sp = _tracing.open_span("decode.prefill", "decode",
+                                    parent=req.parent, rid=req.rid,
+                                    ctx=req.tctx)
+        try:
+            self._pump_front(req)
+        finally:
+            if sp is not None:
+                sp.close(chunk=self.prefill_chunk,
+                         prompt_len=len(req.prompt),
+                         prefill_pos=req.prefill_pos,
+                         reused_blocks=req.n_reused,
+                         queue_wait_s=req.admitted_at - req.enqueued_at)
+
+    def _pump_front(self, req: _Request):
         Ck = self.prefill_chunk
         bs = self.kv_cfg.block_size
         plen = len(req.prompt)
@@ -1630,6 +1798,10 @@ class DecodeEngine:
         bt = build_block_table(req.blocks, self.kv_cfg.max_blocks_per_seq)
         kp, vp = self._pools
         t0 = time.perf_counter()
+        wait = None
+        if _tracing.recording:
+            self._step_record("chunk", t0, 1, 1, start + len(seg))
+            wait = _tracing.open_span("decode.prefill.wait", "decode")
         tok, kp, vp = self._chunk[Ck](
             self.params, cid, np.int32(start), np.int32(plen), kp, vp,
             bt)
@@ -1644,24 +1816,24 @@ class DecodeEngine:
             STEPS.inc(phase="draft")
         req.prefill_pos = start + Ck
         done = req.prefill_pos >= plen
+        t_called = time.perf_counter()
         _perfwatch.record_step(
-            "prefill", time.perf_counter() - t0,
+            "prefill", t_called - t0,
             flops=(self._chunk[Ck].current_cost() or {}).get("flops"),
             tokens=1 if done else 0, device_kind=self._device_kind)
+        # only the slice that ends the prompt fetches a token: the wait
+        # of an earlier one ends with the call
+        tok0 = int(np.asarray(tok)[0]) if done else None
+        if wait is not None:
+            wait.close(call_s=t_called - t0)
         if not done:
             return
-        tok0 = int(np.asarray(tok)[0])         # end-of-prefill sync
         if self.config.prefix_cache and req.hashes:
             # contents are final: full prompt blocks are never written
             # again (decode/verify writes land at positions >= plen)
             for j, h in enumerate(req.hashes):
                 if (j + 1) * bs <= plen - 1:
                     self._alloc.register(req.blocks[j], h)
-        _tracing.record_trace_span(
-            "decode.prefill", req.tctx,
-            time.monotonic() - req.admitted_at, cat="decode",
-            rid=req.rid, chunk=int(Ck), prompt_len=plen,
-            reused_blocks=req.n_reused)
         req.pos = plen
         req.draft_pos = plen
         self._prefilling.popleft()
@@ -1702,6 +1874,9 @@ class DecodeEngine:
         admitted sequence — active or still prefilling — is preempted
         until the round fits."""
         bs = self.kv_cfg.block_size
+        sp = _tracing.open_span("decode.grow", "decode") \
+            if _tracing.recording else None
+        taken = preempted = 0
         while True:
             short = None
             try:
@@ -1710,16 +1885,20 @@ class DecodeEngine:
                     hi = (req.pos + span - 1) // bs
                     while hi >= len(req.blocks):
                         req.blocks.extend(self._alloc.alloc(1))
+                        taken += 1
                     self._cow_guard(req, lo, hi)
             except NoBlocksError:
                 short = req
             if short is None:
-                return
+                break
             candidates = list(self._active) + list(self._prefilling)
             victim = max(candidates, key=self._victim_key)
             self._preempt(victim)
+            preempted += 1
             if not self._active:
-                return
+                break
+        if sp is not None:
+            sp.close(blocks=taken, preempted=preempted)
 
     def _step_plain_sync(self):
         """One synchronous decode round: every active slot advances
@@ -1729,6 +1908,8 @@ class DecodeEngine:
         self._grow_blocks_sync(1)
         if not self._active:
             return
+        sp = _tracing.open_span("decode.dispatch", "decode") \
+            if _tracing.recording else None
         C = self._slot_config()
         sig, slots = self._snapshot(C)
         ids = np.zeros((C,), np.int32)
@@ -1742,6 +1923,7 @@ class DecodeEngine:
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
         t0 = time.perf_counter()
+        self._step_starts.append(t0)
         kp, vp = self._pools
         tok, kp, vp = self._decode[C](self.params, ids, positions, kp,
                                       vp, bts)
@@ -1753,7 +1935,10 @@ class DecodeEngine:
                 self._draft_params, ids, positions, dkp, dvp, bts)
             self._draft_pools = (dkp, dvp)
             STEPS.inc(phase="draft")
+        res, wait = self._sync_resolve_spans(sp, "decode", C, slots)
         toks = np.asarray(tok)                 # synchronous resolve
+        if wait is not None:
+            wait.close()
         wall = time.perf_counter() - t0
         STEP_SECONDS.observe(wall)
         STEPS.inc(phase="decode")
@@ -1764,6 +1949,7 @@ class DecodeEngine:
             "decode", wall,
             flops=(self._decode[C].current_cost() or {}).get("flops"),
             tokens=occupied, device_kind=self._device_kind)
+        emitted = finished = 0
         for i, req in enumerate(slots):
             if req is None or req not in self._active:
                 continue
@@ -1771,9 +1957,32 @@ class DecodeEngine:
             if self._draft is not None:
                 req.draft_pos = req.pos
             self._emit_token(req, int(toks[i]), phase="decode")
+            emitted += 1
             reason = self._finished_reason(req)
             if reason:
                 self._finish(req, reason)
+                finished += 1
+        if res is not None:
+            res.close(tokens=emitted, finished=finished)
+
+    def _close_dispatch(self, sp, kind: str, C: int, slots):
+        """Recording on: the step's record and the end of its open
+        decode.dispatch span `sp`."""
+        live = [r for r in slots if r is not None]
+        tokens = sum(r.pos for r in live)
+        self._step_record(kind, sp.t0, C, len(live), tokens)
+        sp.close(slots=C, live=len(live), live_tokens=tokens,
+                 blocks_used=self._alloc.used_blocks())
+
+    def _sync_resolve_spans(self, sp, kind: str, C: int, slots):
+        """A synchronous round has dispatched: close its decode.dispatch
+        span `sp` and open the (decode.resolve, decode.resolve.wait) pair
+        that follows it; (None, None) with recording off."""
+        if sp is None:
+            return None, None
+        self._close_dispatch(sp, kind, C, slots)
+        return (_tracing.open_span("decode.resolve", "decode"),
+                _tracing.open_span("decode.resolve.wait", "decode"))
 
     def _draft_catch_up(self):
         """After a fully-accepted spec round the draft's KV trails the
@@ -1822,6 +2031,8 @@ class DecodeEngine:
         self._grow_blocks_sync(k + 1)
         if not self._active:
             return
+        sp = _tracing.open_span("decode.dispatch", "decode") \
+            if _tracing.recording else None
         self._draft_catch_up()
         C = self._slot_config()
         sig, slots = self._snapshot(C)
@@ -1836,6 +2047,7 @@ class DecodeEngine:
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
         t0 = time.perf_counter()
+        self._step_starts.append(t0)
         # k draft steps, each feeding the previous step's DEVICE token
         # — the chain dispatches without a host sync
         dkp, dvp = self._draft_pools
@@ -1857,13 +2069,16 @@ class DecodeEngine:
                                        kp, vp, bts)
         self._pools = (kp, vp)
         STEPS.inc(phase="verify")
+        res, wait = self._sync_resolve_spans(sp, "verify", C, slots)
         outs = np.asarray(vtok)                # [C, k+1]
+        if wait is not None:
+            wait.close()
         wall = time.perf_counter() - t0
         STEP_SECONDS.observe(wall)
         occupied = sum(1 for r in slots if r is not None)
         OCCUPANCY.observe(occupied / C)
         self._last_slot_config = C
-        emitted = 0
+        emitted = finished = 0
         for i, req in enumerate(slots):
             if req is None or req not in self._active:
                 continue
@@ -1890,6 +2105,9 @@ class DecodeEngine:
             reason = self._finished_reason(req)
             if reason:
                 self._finish(req, reason)
+                finished += 1
+        if res is not None:
+            res.close(tokens=emitted, finished=finished)
         if self._spec_proposed:
             _kvr.SPEC_ACCEPT_RATE.set(
                 self._spec_accepted / self._spec_proposed)
@@ -1897,6 +2115,17 @@ class DecodeEngine:
             "decode", wall,
             flops=(self._verify[C].current_cost() or {}).get("flops"),
             tokens=emitted, device_kind=self._device_kind)
+
+    def _turn_sync(self):
+        self._sweep_cancelled()
+        self._admit_sync()
+        self._pump_chunk()             # one slice per iteration
+        if not self._active:
+            return
+        if self.spec_k:
+            self._step_spec()
+        else:
+            self._step_plain_sync()
 
     def _loop_sync(self):
         try:
@@ -1908,15 +2137,13 @@ class DecodeEngine:
                         self._cv.wait(timeout=0.5)
                     if self._closed:
                         break
-                self._sweep_cancelled()
-                self._admit_sync()
-                self._pump_chunk()             # one slice per iteration
-                if not self._active:
-                    continue
-                if self.spec_k:
-                    self._step_spec()
-                else:
-                    self._step_plain_sync()
+                sp = _tracing.open_span("decode.turn", "decode") \
+                    if _tracing.recording else None
+                try:
+                    self._turn_sync()
+                finally:
+                    if sp is not None:
+                        sp.close(loop="sync")
         except BaseException as e:  # scheduler death must not hang clients
             with self._cv:
                 reqs = (list(self._active) + list(self._prefilling) +

@@ -179,14 +179,21 @@ class _ServingHandler(_base.QuietHandler):
             self._json_reply(404, {"error": "no decode engine attached "
                                             "to this server"})
             return
-        # the request-root span: decode.submit below captures the child
-        # context, so queue-wait/prefill/TTFT spans recorded later by
-        # the scheduler thread land under this request's trace
-        with _tracing.trace_span("http.generate", cat="serve",
-                                 ctx=self._tctx):
-            self._generate_traced(payload, decode)
+        # the request-root span, handler entry to last byte written:
+        # decode.submit below captures it (and, sampled, its child
+        # context), so the queue-wait/prefill/TTFT spans recorded later
+        # by the scheduler thread land under this request
+        sp = None
+        if _tracing.recording or self._tctx.sampled:
+            sp = _tracing.open_span("http.generate", "serve",
+                                    ctx=self._tctx)
+        try:
+            self._generate_traced(payload, decode, sp)
+        finally:
+            if sp is not None:
+                sp.close(trace_id=self._tctx.trace_id)
 
-    def _generate_traced(self, payload: Dict, decode):
+    def _generate_traced(self, payload: Dict, decode, sp=None):
         ids = payload.get("ids")
         if not isinstance(ids, (list, tuple)) or not ids:
             self._json_reply(400, {"error": 'missing/empty "ids" list'})
@@ -207,6 +214,8 @@ class _ServingHandler(_base.QuietHandler):
         except (ValueError, TypeError) as e:
             self._json_reply(400, {"error": str(e)})
             return
+        if sp is not None:
+            sp.rid = handle.rid
         if not stream:
             try:
                 toks = handle.result(timeout_s=timeout)
@@ -235,6 +244,12 @@ class _ServingHandler(_base.QuietHandler):
         try:
             for tok in handle.tokens(timeout_s=timeout):
                 self._chunk(json.dumps({"token": int(tok)}) + "\n")
+                if n == 0 and _tracing.recording:
+                    # the engine's first token to its bytes on the socket
+                    _tracing.record(
+                        "http.first_write", handle.t_first,
+                        _tracing.clock(), "serve", parent=sp,
+                        rid=handle.rid)
                 n += 1
             info = handle.info
             self._chunk(json.dumps(_json_safe({

@@ -153,7 +153,9 @@ class BlockAllocator:
 
 
 # ---------------------------------------------------------------------------
-# Pure pool helpers (traced into the decode/prefill executables)
+# Pure pool helpers (traced into the decode/prefill executables). Their
+# ops carry the layer scopes `kv_write` / `kv_gather` (HLO metadata: a
+# profile's device time reduces by them, PERF.md section 3).
 # ---------------------------------------------------------------------------
 
 
@@ -165,6 +167,7 @@ def init_pools(cfg: KVCacheConfig) -> Tuple[jax.Array, jax.Array]:
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
+@jax.named_scope("kv_write")
 def write_token_kv(pool_l: jax.Array, kv: jax.Array,
                    block_tables: jax.Array, positions: jax.Array,
                    block_size: int) -> jax.Array:
@@ -178,6 +181,7 @@ def write_token_kv(pool_l: jax.Array, kv: jax.Array,
     return pool_l.at[blk, slot].set(kv)
 
 
+@jax.named_scope("kv_write")
 def write_prefill_kv(pool_l: jax.Array, kv: jax.Array,
                      block_table: jax.Array, block_size: int) -> jax.Array:
     """Scatter a whole prompt's K (or V) into one layer's pool slice.
@@ -193,6 +197,7 @@ def write_prefill_kv(pool_l: jax.Array, kv: jax.Array,
     return pool_l.at[blk, slot].set(kv)
 
 
+@jax.named_scope("kv_write")
 def write_chunk_kv(pool_l: jax.Array, kv: jax.Array,
                    block_table: jax.Array, start: jax.Array,
                    block_size: int) -> jax.Array:
@@ -212,6 +217,7 @@ def write_chunk_kv(pool_l: jax.Array, kv: jax.Array,
     return pool_l.at[blk, t % block_size].set(kv)
 
 
+@jax.named_scope("kv_write")
 def write_span_kv(pool_l: jax.Array, kv: jax.Array,
                   block_tables: jax.Array, positions: jax.Array,
                   block_size: int) -> jax.Array:
@@ -231,6 +237,7 @@ def write_span_kv(pool_l: jax.Array, kv: jax.Array,
     return pool_l.at[blk, t % block_size].set(kv)
 
 
+@jax.named_scope("kv_gather")
 def gather_kv(pool_l: jax.Array, block_tables: jax.Array) -> jax.Array:
     """Gather every slot's full (padded) context from one layer's pool
     slice: `[NB, BS, H, D]` × `[S, MB]` → `[S, MB*BS, H, D]`. The

@@ -1,0 +1,106 @@
+"""The layer scopes of the device programs (PERF.md section 3): every name
+a profile is reduced by is in the HLO of the step functions, forward and
+backward, so a refactoring that drops one fails here, on the CPU. A scope
+is HLO metadata (`op_name`): it adds no op."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from paddle_tpu.models import bert, gpt
+
+LAYER = {"ln", "qkv", "attention", "proj", "mlp"}
+SERVE = LAYER | {"embed", "layers", "head", "kv_write"}
+S, MB, NB, BS = 4, 8, 17, 16
+
+
+def _scopes(text: str):
+    """Every path component of every op_name, unwrapped:
+    `transpose(jvp(mlp))` counts as `mlp`."""
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        for part in op_name.split("/")[:-1]:
+            found.update(re.findall(r"[A-Za-z_][A-Za-z0-9_.]*", part))
+    return found
+
+
+@pytest.fixture(scope="module")
+def tiny_gpt():
+    cfg = gpt.GPTConfig.tiny()
+    cfg.dtype = "float32"
+    params, _ = gpt.init(jax.random.key(0), cfg)
+    pool = jnp.zeros((cfg.layers, NB, BS, cfg.heads, cfg.head_dim))
+    return cfg, params, pool
+
+
+def _lower_serve(kind, cfg, params, pool):
+    kw = dict(block_size=BS, eos_id=1)
+    i32 = jnp.int32
+    if kind == "decode":
+        return jax.jit(lambda p, i, po, k, v, b: gpt.apply_decode_step(
+            p, cfg, i, po, k, v, b, **kw)).lower(
+            params, jnp.zeros((S,), i32), jnp.zeros((S,), i32), pool, pool,
+            jnp.zeros((S, MB), i32))
+    if kind == "verify":
+        return jax.jit(lambda p, i, po, k, v, b: gpt.apply_verify_step(
+            p, cfg, i, po, k, v, b, **kw)).lower(
+            params, jnp.zeros((S, 3), i32), jnp.zeros((S,), i32), pool,
+            pool, jnp.zeros((S, MB), i32))
+    if kind == "prefill":
+        return jax.jit(lambda p, i, n, k, v, b: gpt.apply_prefill(
+            p, cfg, i, n, k, v, b, **kw)).lower(
+            params, jnp.zeros((1, 32), i32), i32(5), pool, pool,
+            jnp.zeros((MB,), i32))
+    return jax.jit(lambda p, i, s, n, k, v, b: gpt.apply_prefill_chunk(
+        p, cfg, i, s, n, k, v, b, **kw)).lower(
+        params, jnp.zeros((1, 16), i32), i32(0), i32(5), pool, pool,
+        jnp.zeros((MB,), i32))
+
+
+@pytest.mark.parametrize("kind,extra", [
+    ("decode", {"kv_gather"}), ("verify", {"kv_gather"}),
+    ("chunk", {"kv_gather"}), ("prefill", set())])
+def test_serving_step_functions_carry_every_scope(tiny_gpt, kind, extra):
+    text = _lower_serve(kind, *tiny_gpt).compile().as_text()
+    missing = (SERVE | extra) - _scopes(text)
+    assert not missing, (kind, missing)
+
+
+def test_gpt_training_forward_carries_the_scopes(tiny_gpt):
+    cfg, params, _ = tiny_gpt
+    text = jax.jit(lambda p, i: gpt.apply(p, cfg, i)).lower(
+        params, jnp.zeros((2, 16), jnp.int32)).compile().as_text()
+    missing = (LAYER | {"embed", "layers", "head"}) - _scopes(text)
+    assert not missing, missing
+
+
+def test_bert_train_step_carries_the_scopes_forward_and_backward():
+    from paddle_tpu.parallel import MeshConfig, make_mesh, mesh_guard
+    from paddle_tpu.parallel.train import TrainStrategy, make_train_step
+
+    cfg = bert.BertConfig(vocab_size=128, hidden=32, layers=2, heads=2,
+                          mlp_dim=64, max_len=32)
+    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    with mesh_guard(mesh):
+        params, axes = bert.init(jax.random.key(0), cfg)
+        init_state, step = make_train_step(
+            lambda p, b, r: bert.pretrain_loss(p, cfg, b, r),
+            optax.adamw(1e-4), mesh, axes,
+            strategy=TrainStrategy(clip_global_norm=1.0))
+        state = init_state(params)
+        batch = bert.make_batch(jax.random.key(1), cfg, 4, 16)
+        text = step.lower(state, batch, jax.random.key(2)).compile(
+            ).as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    missing = (LAYER | {"embed", "layers", "mlm_head", "nsp_head", "loss",
+                        "clip", "optimizer"}) - _scopes(text)
+    assert not missing, missing
+    # the backward pass keeps the scopes; autodiff wraps the outermost:
+    # transpose(jvp(layers))/attention/..., transpose(jvp(mlm_head))/...
+    backward = _scopes("\n".join(f'op_name="{n}"' for n in names
+                                 if "transpose(jvp(" in n))
+    missing = (LAYER | {"layers", "mlm_head"}) - backward
+    assert not missing, missing

@@ -823,7 +823,13 @@ def test_drain_rejects_with_retry_after_and_finishes_inflight(
     # for drain() to start while it is pending)
     th = threading.Thread(target=fire)
     th.start()
-    time.sleep(0.005)
+    # wait until the request IS queued or in flight (or already answered):
+    # a fixed sleep raced the request thread against drain() under load,
+    # and a request that arrives after the drain gets the 503
+    deadline = time.monotonic() + 20.0
+    while time.monotonic() < deadline and not results \
+            and not server.load()["load"]:
+        time.sleep(0.001)
     server.drain(timeout=30.0)
     th.join(timeout=30)
     assert results and results[0][0] == 200
