@@ -275,6 +275,22 @@ def apply(params: Params, cfg: GPTConfig, ids: jax.Array,
 # slot whose previous token is end_id keeps emitting end_id without any
 # host-side branching. MoE configs are refused by the engine (expert
 # dispatch needs its own decode kernel — ROADMAP item 4).
+#
+# Where the pools live. A pool is `[L, NB, BS, heads*head_dim]`
+# (kv_cache.KVCacheConfig.pool_shape): the last dimension fills whole
+# lanes, BS fills the sublanes, so the TPU stores it as written and a
+# block is contiguous. All four programs run ONE layer loop,
+# `_serve_layers`, which holds `(h, k_pool, v_pool)` in the scan's CARRY
+# (the stacked weights and the layer index are its `xs`): a layer
+# writes `pool.at[l, blk, slot].set(kv)` and reads
+# `pool[l, block_tables]` in the donated buffer itself. The pools must
+# not be the scan's `xs`/`ys`: a scan's `ys` is a new stacked buffer, so
+# every layer's slice is taken out, re-laid-out and written back and
+# the donated pool copied whole, each step (three quarters of a decode
+# step's device time when measured: PERF.md section 6, PR 25). How a
+# token is stored is read from `pool.shape[3:]`, so a pool
+# `[L, NB, BS, heads, head_dim]` is still served — slowly: the TPU pads
+# and re-lays it out; the engine makes none.
 # ---------------------------------------------------------------------------
 
 
@@ -300,6 +316,34 @@ def _decode_mlp(lp, x):
     return h @ lp["blk.w2"].astype(x.dtype) + lp["blk.b2"].astype(x.dtype)
 
 
+def _serve_layers(params: Params, x: jax.Array, k_pool: jax.Array,
+                  v_pool: jax.Array, attend):
+    """The serve programs' layer loop: `x` through every block with the
+    pools in the loop's carry. `attend(l, q, k, v, kp, vp)` is the one
+    part the programs differ in: it gets the layer index, the layer's
+    projections (x's leading shape, `[..., hidden]` each) and the WHOLE
+    pools, writes k/v at (l, block, slot), and returns
+    `(ctx [..., hidden], kp, vp)`. Returns (x, k_pool, v_pool)."""
+
+    def layer_body(carry, per_layer):
+        h, kp, vp = carry
+        lp, l = per_layer
+        y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
+        q, k, v = _qkv(lp, y)
+        ctx, kp, vp = attend(l, q, k, v, kp, vp)
+        h = _proj(lp, ctx, h)
+        y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
+        h = h + _decode_mlp(lp, y)
+        return (h, kp, vp), None
+
+    layers = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
+    with jax.named_scope("layers"):
+        (x, k_pool, v_pool), _ = jax.lax.scan(
+            layer_body, (x, k_pool, v_pool),
+            (_layer_params(params), layers))
+    return x, k_pool, v_pool
+
+
 def apply_prefill(params: Params, cfg: GPTConfig, ids: jax.Array,
                   length: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                   block_table: jax.Array, *, block_size: int,
@@ -319,31 +363,23 @@ def apply_prefill(params: Params, cfg: GPTConfig, ids: jax.Array,
     B, T = ids.shape
     nh, hd = cfg.heads, cfg.head_dim
     adt = k_pool.dtype
+    stored = k_pool.shape[3:]     # how the pool stores one token
     with jax.named_scope("embed"):
         x = (params["wte.w"][ids] + params["wpe.w"][:T][None]).astype(adt)
 
-    lp_stacked = _layer_params(params)
-
-    def layer_body(h, per_layer):
-        lp, kp, vp = per_layer
-        y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        q, k, v = _qkv(lp, y)
+    def attend(l, q, k, v, kp, vp):
+        kp = kvc.write_prefill_kv(kp, l, k[0].reshape(T, *stored),
+                                  block_table, block_size)
+        vp = kvc.write_prefill_kv(vp, l, v[0].reshape(T, *stored),
+                                  block_table, block_size)
         q = q.reshape(B, T, nh, hd)
         k = k.reshape(B, T, nh, hd)
         v = v.reshape(B, T, nh, hd)
-        kp = kvc.write_prefill_kv(kp, k[0], block_table, block_size)
-        vp = kvc.write_prefill_kv(vp, v[0], block_table, block_size)
         with jax.named_scope("attention"):
             ctx = pa.mha(q, k, v, causal=True, scale=1.0 / math.sqrt(hd))
-        ctx = ctx.reshape(B, T, cfg.hidden)
-        h = _proj(lp, ctx, h)
-        y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
-        h = h + _decode_mlp(lp, y)
-        return h, (kp, vp)
+        return ctx.reshape(B, T, cfg.hidden), kp, vp
 
-    with jax.named_scope("layers"):
-        x, (k_pool, v_pool) = jax.lax.scan(
-            layer_body, x, (lp_stacked, k_pool, v_pool))
+    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
     # LayerNorm is per row: the last real position alone goes through it
     last = jnp.maximum(length, 1) - 1
     tok = _head(params, x[0, last][None], ids[0, last][None], eos_id)
@@ -368,42 +404,34 @@ def apply_decode_step(params: Params, cfg: GPTConfig, ids: jax.Array,
     S = ids.shape[0]
     nh, hd = cfg.heads, cfg.head_dim
     adt = k_pool.dtype
+    stored = k_pool.shape[3:]     # how the pool stores one token
     with jax.named_scope("embed"):
         x = (params["wte.w"][ids] + params["wpe.w"][positions]).astype(adt)
 
-    lp_stacked = _layer_params(params)
     scale = 1.0 / math.sqrt(hd)
 
-    def layer_body(h, per_layer):
-        lp, kp, vp = per_layer
-        y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        q, k, v = _qkv(lp, y)
+    def attend(l, q, k, v, kp, vp):
+        kp = kvc.write_token_kv(kp, l, k.reshape(S, *stored), block_tables,
+                                positions, block_size)
+        vp = kvc.write_token_kv(vp, l, v.reshape(S, *stored), block_tables,
+                                positions, block_size)
+        keys = kvc.gather_kv(kp, l, block_tables)       # [S, M, *stored]
+        vals = kvc.gather_kv(vp, l, block_tables)
+        m = keys.shape[1]
         q = q.reshape(S, nh, hd)
-        k = k.reshape(S, nh, hd)
-        v = v.reshape(S, nh, hd)
-        kp = kvc.write_token_kv(kp, k, block_tables, positions, block_size)
-        vp = kvc.write_token_kv(vp, v, block_tables, positions, block_size)
-        keys = kvc.gather_kv(kp, block_tables)        # [S, M, nh, hd]
-        vals = kvc.gather_kv(vp, block_tables)
+        keys = keys.reshape(S, m, nh, hd)
+        vals = vals.reshape(S, m, nh, hd)
         with jax.named_scope("attention"):
             scores = jnp.einsum("snd,smnd->snm", q, keys) * scale
-            m = keys.shape[1]
             mask = jnp.arange(m, dtype=jnp.int32)[None, :] \
                 <= positions[:, None]
             scores = jnp.where(mask[:, None, :], scores, -1e9)
             att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
             ctx = jnp.einsum("snm,smnd->snd", att.astype(adt), vals)
-        ctx = ctx.reshape(S, cfg.hidden)
-        h = _proj(lp, ctx, h)
-        y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
-        h = h + _decode_mlp(lp, y)
-        return h, (kp, vp)
+        return ctx.reshape(S, cfg.hidden), kp, vp
 
-    with jax.named_scope("layers"):
-        x, (k_pool, v_pool) = jax.lax.scan(
-            layer_body, x, (lp_stacked, k_pool, v_pool))
-    tok = _head(params, x, ids, eos_id)
-    return tok, k_pool, v_pool
+    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
+    return _head(params, x, ids, eos_id), k_pool, v_pool
 
 
 def apply_prefill_chunk(params: Params, cfg: GPTConfig, ids: jax.Array,
@@ -433,6 +461,7 @@ def apply_prefill_chunk(params: Params, cfg: GPTConfig, ids: jax.Array,
     _, C = ids.shape
     nh, hd = cfg.heads, cfg.head_dim
     adt = k_pool.dtype
+    stored = k_pool.shape[3:]     # how the pool stores one token
     pos = start + jnp.arange(C, dtype=jnp.int32)
     # the final slice's padded tail can run past the positional table;
     # clamp (those rows' outputs are never consumed, their KV lands in
@@ -441,36 +470,28 @@ def apply_prefill_chunk(params: Params, cfg: GPTConfig, ids: jax.Array,
         x = (params["wte.w"][ids[0]] +
              params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
 
-    lp_stacked = _layer_params(params)
     scale = 1.0 / math.sqrt(hd)
 
-    def layer_body(h, per_layer):
-        lp, kp, vp = per_layer
-        y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        q, k, v = _qkv(lp, y)
+    def attend(l, q, k, v, kp, vp):
+        kp = kvc.write_chunk_kv(kp, l, k.reshape(C, *stored), block_table,
+                                start, block_size)
+        vp = kvc.write_chunk_kv(vp, l, v.reshape(C, *stored), block_table,
+                                start, block_size)
+        keys = kvc.gather_kv(kp, l, block_table[None])[0]   # [M, *stored]
+        vals = kvc.gather_kv(vp, l, block_table[None])[0]
+        m = keys.shape[0]
         q = q.reshape(C, nh, hd)
-        k = k.reshape(C, nh, hd)
-        v = v.reshape(C, nh, hd)
-        kp = kvc.write_chunk_kv(kp, k, block_table, start, block_size)
-        vp = kvc.write_chunk_kv(vp, v, block_table, start, block_size)
-        keys = kvc.gather_kv(kp, block_table[None])[0]  # [M, nh, hd]
-        vals = kvc.gather_kv(vp, block_table[None])[0]
+        keys = keys.reshape(m, nh, hd)
+        vals = vals.reshape(m, nh, hd)
         with jax.named_scope("attention"):
             scores = jnp.einsum("cnd,mnd->cnm", q, keys) * scale
-            m = keys.shape[0]
             mask = jnp.arange(m, dtype=jnp.int32)[None, :] <= pos[:, None]
             scores = jnp.where(mask[:, None, :], scores, -1e9)
             att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
             ctx = jnp.einsum("cnm,mnd->cnd", att.astype(adt), vals)
-        ctx = ctx.reshape(C, cfg.hidden)
-        h = _proj(lp, ctx, h)
-        y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
-        h = h + _decode_mlp(lp, y)
-        return h, (kp, vp)
+        return ctx.reshape(C, cfg.hidden), kp, vp
 
-    with jax.named_scope("layers"):
-        x, (k_pool, v_pool) = jax.lax.scan(
-            layer_body, x, (lp_stacked, k_pool, v_pool))
+    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
     last = jnp.clip(length - 1 - start, 0, C - 1)
     tok = _head(params, x[last][None], ids[0, last][None], eos_id)
     return tok, k_pool, v_pool
@@ -502,47 +523,38 @@ def apply_verify_step(params: Params, cfg: GPTConfig, ids: jax.Array,
     S, W = ids.shape
     nh, hd = cfg.heads, cfg.head_dim
     adt = k_pool.dtype
+    stored = k_pool.shape[3:]     # how the pool stores one token
     pos = positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     with jax.named_scope("embed"):
         x = (params["wte.w"][ids] +
              params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
 
-    lp_stacked = _layer_params(params)
     scale = 1.0 / math.sqrt(hd)
 
-    def layer_body(h, per_layer):
-        lp, kp, vp = per_layer
-        y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        q, k, v = _qkv(lp, y)
+    def attend(l, q, k, v, kp, vp):
+        kp = kvc.write_span_kv(kp, l, k.reshape(S, W, *stored), block_tables,
+                               positions, block_size)
+        vp = kvc.write_span_kv(vp, l, v.reshape(S, W, *stored), block_tables,
+                               positions, block_size)
+        keys = kvc.gather_kv(kp, l, block_tables)       # [S, M, *stored]
+        vals = kvc.gather_kv(vp, l, block_tables)
+        m = keys.shape[1]
         q = q.reshape(S, W, nh, hd)
-        k = k.reshape(S, W, nh, hd)
-        v = v.reshape(S, W, nh, hd)
-        kp = kvc.write_span_kv(kp, k, block_tables, positions,
-                               block_size)
-        vp = kvc.write_span_kv(vp, v, block_tables, positions,
-                               block_size)
-        keys = kvc.gather_kv(kp, block_tables)        # [S, M, nh, hd]
-        vals = kvc.gather_kv(vp, block_tables)
+        keys = keys.reshape(S, m, nh, hd)
+        vals = vals.reshape(S, m, nh, hd)
         with jax.named_scope("attention"):
             scores = jnp.einsum("swnd,smnd->swnm", q, keys) * scale
-            m = keys.shape[1]
             mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
                 <= pos[:, :, None]
             scores = jnp.where(mask[:, :, None, :], scores, -1e9)
             att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
             ctx = jnp.einsum("swnm,smnd->swnd", att.astype(adt), vals)
-        ctx = ctx.reshape(S, W, cfg.hidden)
-        h = _proj(lp, ctx, h)
-        y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
-        h = h + _decode_mlp(lp, y)
-        return h, (kp, vp)
+        return ctx.reshape(S, W, cfg.hidden), kp, vp
 
-    with jax.named_scope("layers"):
-        x, (k_pool, v_pool) = jax.lax.scan(
-            layer_body, x, (lp_stacked, k_pool, v_pool))
-    tok = _head(params, x.reshape(S * W, cfg.hidden), ids.reshape(S * W),
-                eos_id).reshape(S, W)
-    return tok, k_pool, v_pool
+    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
+    tokens = _head(params, x.reshape(S * W, cfg.hidden), ids.reshape(S * W),
+                   eos_id).reshape(S, W)
+    return tokens, k_pool, v_pool
 
 
 def lm_loss(params: Params, cfg: GPTConfig, batch: Dict[str, jax.Array],

@@ -502,10 +502,12 @@ class DecodeEngine:
             if self.config.prefix_cache else BlockAllocator(self.kv_cfg)
         # COW device copy: src block's contents into dst across both
         # pools (shape-cached jit; src/dst are traced scalars so every
-        # copy reuses one executable per pool geometry)
+        # copy reuses one executable per pool geometry). The pools are
+        # donated: one block is written in place, not two pools copied.
         self._copy_block_fn = jax.jit(
             lambda kp, vp, src, dst: (kp.at[:, dst].set(kp[:, src]),
-                                      vp.at[:, dst].set(vp[:, src])))
+                                      vp.at[:, dst].set(vp[:, src])),
+            donate_argnums=(0, 1))
         self._device_kind = getattr(jax.devices()[0], "device_kind",
                                     "unknown")
         # HBM owner attribution: providers hand memwatch the CURRENT
@@ -756,8 +758,7 @@ class DecodeEngine:
         p_sds = jax.tree_util.tree_map(
             lambda a: sds(a.shape, a.dtype), params)
         kv = self._draft_kv_cfg if draft else self.kv_cfg
-        pool = sds((kv.layers, kv.num_blocks, kv.block_size,
-                    kv.kv_heads, kv.head_dim), np.dtype(kv.dtype))
+        pool = sds(kv.pool_shape, np.dtype(kv.dtype))
         mb = kv.max_blocks_per_seq
         base = kind[6:] if draft else kind
         if base == "prefill":

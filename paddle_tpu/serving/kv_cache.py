@@ -4,9 +4,11 @@ The classic serving memory problem (vLLM SOSP'23): a contiguous
 per-sequence KV buffer must be sized for max_seq_len, so HBM scales
 with max_len × batch even when most sequences are short — and XLA's
 static shapes make "grow the buffer" a recompile. The paged design
-keeps ONE preallocated device pool of fixed-size blocks per layer
-(`[L, num_blocks, block_size, kv_heads, head_dim]`) plus a tiny
-per-sequence *block table* mapping logical positions to pool blocks.
+keeps ONE preallocated device pool of fixed-size blocks for all layers
+(`[L, num_blocks, block_size, kv_heads*head_dim]`: a token's heads side
+by side in the lane dimension, so nothing pads and a block is
+contiguous on the TPU) plus a tiny per-sequence *block table* mapping
+logical positions to pool blocks.
 Memory then scales with LIVE TOKENS (rounded up to the block size),
 sequences grow by appending a block id to their table — a host-side
 int, never a new executable — and the decode executable's shapes stay
@@ -14,8 +16,10 @@ fixed no matter which sequences are resident.
 
 Layering: this module owns the host-side `BlockAllocator` (free-list,
 alloc/free, fragmentation accounting) and the pure jnp pool helpers
-(`init_pools`, `write_token_kv`, `write_prefill_kv`, `gather_kv`)
-that `models/gpt.py` composes into its decode-step attention. The
+(`init_pools`, `write_token_kv`, `write_prefill_kv`, `write_chunk_kv`,
+`write_span_kv`, `gather_kv`) that `models/gpt.py` composes into its
+decode-step attention; they take the whole pool and a layer index, and
+the layer loop carries the pools and addresses them in place. The
 scheduler that decides WHICH sequences own which blocks lives in
 `serving/decode.py`.
 
@@ -28,6 +32,7 @@ already guarantees nothing read from the null block ever contributes.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -70,11 +75,20 @@ class KVCacheConfig:
     def usable_blocks(self) -> int:
         return int(self.num_blocks) - 1  # block 0 is the null block
 
+    @property
+    def pool_shape(self) -> Tuple[int, int, int, int]:
+        """`[L, NB, BS, kv_heads*head_dim]`: a token's heads lie side by
+        side in the minor-most (lane) dimension and the `BS` slots of a
+        block in the sublanes above it, so on the TPU a block is one
+        contiguous, unpadded run of tiles and (layer, block, slot)
+        address it without a re-layout."""
+        return (int(self.layers), int(self.num_blocks),
+                int(self.block_size), int(self.kv_heads * self.head_dim))
+
     def pool_bytes(self) -> int:
         """Device bytes of BOTH pools (K and V)."""
-        per = (self.layers * self.num_blocks * self.block_size *
-               self.kv_heads * self.head_dim)
-        return 2 * per * jnp.dtype(self.dtype).itemsize
+        return 2 * math.prod(self.pool_shape) * \
+            jnp.dtype(self.dtype).itemsize
 
 
 class BlockAllocator:
@@ -156,36 +170,43 @@ class BlockAllocator:
 # Pure pool helpers (traced into the decode/prefill executables). Their
 # ops carry the layer scopes `kv_write` / `kv_gather` (HLO metadata: a
 # profile's device time reduces by them, PERF.md section 3).
+#
+# Every helper takes the WHOLE pool `[L, NB, BS, *tok]` and a (traced)
+# layer index, and addresses it in place by (layer, block, slot): the
+# layer loop of models/gpt.py holds the pools in its carry, so a write
+# is one scatter into the donated buffer and no layer's slice is ever
+# taken out or put back. `tok` = `pool.shape[3:]` is how one token is
+# stored: `[kv_heads*head_dim]` in the engine's pools
+# (`KVCacheConfig.pool_shape`); the helpers are generic over it and
+# `kv`'s trailing dimensions match it.
 # ---------------------------------------------------------------------------
 
 
 def init_pools(cfg: KVCacheConfig) -> Tuple[jax.Array, jax.Array]:
-    """Zeroed K and V pools, `[L, NB, BS, kv_heads, head_dim]`."""
-    shape = (cfg.layers, cfg.num_blocks, cfg.block_size, cfg.kv_heads,
-             cfg.head_dim)
+    """Zeroed K and V pools, `cfg.pool_shape` each."""
     dt = jnp.dtype(cfg.dtype)
-    return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+    return jnp.zeros(cfg.pool_shape, dt), jnp.zeros(cfg.pool_shape, dt)
 
 
 @jax.named_scope("kv_write")
-def write_token_kv(pool_l: jax.Array, kv: jax.Array,
+def write_token_kv(pool: jax.Array, layer: jax.Array, kv: jax.Array,
                    block_tables: jax.Array, positions: jax.Array,
                    block_size: int) -> jax.Array:
-    """Scatter one token's K (or V) per slot into a single layer's pool
-    slice. pool_l `[NB, BS, H, D]`, kv `[S, H, D]`, block_tables
+    """Scatter one token's K (or V) per slot into layer `layer` of the
+    pool. pool `[L, NB, BS, *tok]`, kv `[S, *tok]`, block_tables
     `[S, MB]`, positions `[S]`. Inactive slots carry all-zero tables,
     so their writes land in the null block."""
     blk = jnp.take_along_axis(
         block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
     slot = positions % block_size
-    return pool_l.at[blk, slot].set(kv)
+    return pool.at[layer, blk, slot].set(kv)
 
 
 @jax.named_scope("kv_write")
-def write_prefill_kv(pool_l: jax.Array, kv: jax.Array,
+def write_prefill_kv(pool: jax.Array, layer: jax.Array, kv: jax.Array,
                      block_table: jax.Array, block_size: int) -> jax.Array:
-    """Scatter a whole prompt's K (or V) into one layer's pool slice.
-    pool_l `[NB, BS, H, D]`, kv `[T, H, D]` (positions 0..T-1),
+    """Scatter a whole prompt's K (or V) into layer `layer` of the pool.
+    pool `[L, NB, BS, *tok]`, kv `[T, *tok]` (positions 0..T-1),
     block_table `[MB]`. Positions past the sequence's allocated blocks
     hit table entries that are still 0 and land in the null block;
     positions inside the last allocated block but past the true length
@@ -194,36 +215,36 @@ def write_prefill_kv(pool_l: jax.Array, kv: jax.Array,
     t = jnp.arange(kv.shape[0], dtype=jnp.int32)
     blk = block_table[t // block_size]
     slot = t % block_size
-    return pool_l.at[blk, slot].set(kv)
+    return pool.at[layer, blk, slot].set(kv)
 
 
 @jax.named_scope("kv_write")
-def write_chunk_kv(pool_l: jax.Array, kv: jax.Array,
+def write_chunk_kv(pool: jax.Array, layer: jax.Array, kv: jax.Array,
                    block_table: jax.Array, start: jax.Array,
                    block_size: int) -> jax.Array:
-    """Scatter one prompt SLICE's K (or V) into one layer's pool slice
-    (chunked prefill). pool_l `[NB, BS, H, D]`, kv `[C, H, D]` holding
-    positions start..start+C-1, block_table `[MB]`. Positions past the
-    table width are redirected to the null block (the final chunk's
-    edge-padded tail can run past max_len); positions inside allocated
-    blocks but past the true prompt length write garbage slots that
-    later writes overwrite before any mask lets them be read — the
-    same contract as write_prefill_kv."""
+    """Scatter one prompt SLICE's K (or V) into layer `layer` of the
+    pool (chunked prefill). pool `[L, NB, BS, *tok]`, kv `[C, *tok]`
+    holding positions start..start+C-1, block_table `[MB]`. Positions
+    past the table width are redirected to the null block (the final
+    chunk's edge-padded tail can run past max_len); positions inside
+    allocated blocks but past the true prompt length write garbage
+    slots that later writes overwrite before any mask lets them be
+    read — the same contract as write_prefill_kv."""
     t = jnp.arange(kv.shape[0], dtype=jnp.int32) + start
     bi = t // block_size
     mb = block_table.shape[0]
     blk = jnp.where(bi < mb, block_table[jnp.minimum(bi, mb - 1)],
                     NULL_BLOCK)
-    return pool_l.at[blk, t % block_size].set(kv)
+    return pool.at[layer, blk, t % block_size].set(kv)
 
 
 @jax.named_scope("kv_write")
-def write_span_kv(pool_l: jax.Array, kv: jax.Array,
+def write_span_kv(pool: jax.Array, layer: jax.Array, kv: jax.Array,
                   block_tables: jax.Array, positions: jax.Array,
                   block_size: int) -> jax.Array:
-    """Scatter a W-token span per slot into one layer's pool slice
-    (speculative verification). pool_l `[NB, BS, H, D]`, kv
-    `[S, W, H, D]` holding each slot's positions p..p+W-1, block_tables
+    """Scatter a W-token span per slot into layer `layer` of the pool
+    (speculative verification). pool `[L, NB, BS, *tok]`, kv
+    `[S, W, *tok]` holding each slot's positions p..p+W-1, block_tables
     `[S, MB]`, positions `[S]` = each slot's span start. Slots with
     all-zero tables (inactive / masked out) write the null block; span
     positions past the table width are redirected there too."""
@@ -234,18 +255,19 @@ def write_span_kv(pool_l: jax.Array, kv: jax.Array,
     blk = jnp.take_along_axis(block_tables, jnp.minimum(bi, mb - 1),
                               axis=1)
     blk = jnp.where(bi < mb, blk, NULL_BLOCK)
-    return pool_l.at[blk, t % block_size].set(kv)
+    return pool.at[layer, blk, t % block_size].set(kv)
 
 
 @jax.named_scope("kv_gather")
-def gather_kv(pool_l: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Gather every slot's full (padded) context from one layer's pool
-    slice: `[NB, BS, H, D]` × `[S, MB]` → `[S, MB*BS, H, D]`. The
+def gather_kv(pool: jax.Array, layer: jax.Array,
+              block_tables: jax.Array) -> jax.Array:
+    """Gather every slot's full (padded) context from layer `layer` of
+    the pool: `[L, NB, BS, *tok]` × `[S, MB]` → `[S, MB*BS, *tok]`. The
     caller masks positions `> position` (unwritten tail + null-block
-    reads of inactive slots)."""
+    reads of inactive slots) and views `tok` as heads."""
     s, mb = block_tables.shape
-    ctx = pool_l[block_tables]                       # [S, MB, BS, H, D]
-    return ctx.reshape(s, mb * pool_l.shape[1], *pool_l.shape[2:])
+    ctx = pool[layer, block_tables]                  # [S, MB, BS, *tok]
+    return ctx.reshape(s, mb * pool.shape[2], *pool.shape[3:])
 
 
 def build_block_table(blocks: Sequence[int], max_blocks: int) -> np.ndarray:

@@ -120,24 +120,26 @@ def test_allocator_rejects_degenerate_pool():
 
 
 def test_kv_pool_write_gather_roundtrip():
-    cfg = KVCacheConfig(layers=1, kv_heads=2, head_dim=3, max_len=16,
+    cfg = KVCacheConfig(layers=2, kv_heads=2, head_dim=3, max_len=16,
                         block_size=4, num_blocks=5, dtype="float32")
-    kp, _ = init_pools(cfg)
-    pool = kp[0]                                   # one layer's slice
-    # prefill a 6-token sequence into blocks [1, 2]
-    kv = np.arange(6 * 2 * 3, dtype=np.float32).reshape(6, 2, 3)
+    pool, _ = init_pools(cfg)
+    assert pool.shape == (2, 5, 4, 2 * 3)          # [L, NB, BS, H*D]
+    layer = np.int32(1)
+    # prefill a 6-token sequence into blocks [1, 2] of layer 1
+    kv = np.arange(6 * 6, dtype=np.float32).reshape(6, 6)
     bt = build_block_table([1, 2], cfg.max_blocks_per_seq)
-    pool = write_prefill_kv(pool, kv, bt, cfg.block_size)
-    ctx = gather_kv(pool, bt[None])                # [1, MB*BS, H, D]
+    pool = write_prefill_kv(pool, layer, kv, bt, cfg.block_size)
+    ctx = gather_kv(pool, layer, bt[None])         # [1, MB*BS, H*D]
     np.testing.assert_array_equal(np.asarray(ctx)[0, :6], kv)
     # decode-step write at position 6 (block 1 of the table, slot 2)
-    tok = np.full((1, 2, 3), 7.0, np.float32)
-    pool = write_token_kv(pool, tok, bt[None],
+    tok = np.full((1, 6), 7.0, np.float32)
+    pool = write_token_kv(pool, layer, tok, bt[None],
                           np.array([6], np.int32), cfg.block_size)
-    ctx = gather_kv(pool, bt[None])
+    ctx = gather_kv(pool, layer, bt[None])
     np.testing.assert_array_equal(np.asarray(ctx)[0, 6], tok[0])
-    # untouched tail stays zero
+    # untouched tail stays zero, and so does the other layer
     assert float(np.abs(np.asarray(ctx)[0, 7:8]).sum()) == 0.0
+    assert float(np.abs(np.asarray(pool)[0]).sum()) == 0.0
 
 
 def test_build_block_table_bounds():

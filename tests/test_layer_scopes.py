@@ -32,7 +32,7 @@ def tiny_gpt():
     cfg = gpt.GPTConfig.tiny()
     cfg.dtype = "float32"
     params, _ = gpt.init(jax.random.key(0), cfg)
-    pool = jnp.zeros((cfg.layers, NB, BS, cfg.heads, cfg.head_dim))
+    pool = jnp.zeros((cfg.layers, NB, BS, cfg.heads * cfg.head_dim))
     return cfg, params, pool
 
 

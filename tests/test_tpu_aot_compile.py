@@ -6,15 +6,19 @@ passed every interpreter test while its (1, bn) stats blocks were
 refused at every ResNet-50 shape. A pass here is a compile, not a chip
 run — `chip_smoke.py` is the chip run.
 
-Kernels only (~0.1-2 s each); whole-step compiles stay out of tests/.
+Kernels (~0.1-2 s each), and the serve programs of GPT-2-large (the
+benchmark's serving width, ~3 s each): what the compiler PLANS for the KV
+pool is the one thing about them a CPU run cannot show.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs land in /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
@@ -146,3 +150,108 @@ def test_multichip_route_compiles_for_v5e_2x2(v5e, tp, sp, counter):
     assert "tpu_custom_call" in text
     if sp > 1:
         assert "collective-permute" in text  # the ring's ppermute
+
+
+# ---------------------------------------------------------------------------
+# The serve programs and the KV pool (PERF.md section 6, PR 25). With the
+# pools as the layer scan's xs/ys, the compiled decode step sliced every
+# layer out of a [36,1025,16,20,64] pool, re-laid it out, wrote it back and
+# copied a pool whole: 3.80 GB of temporaries, three quarters of the step's
+# device time. Lane-dense pools in the loop's carry are written and read in
+# place; these compiles hold the programs to that.
+# ---------------------------------------------------------------------------
+
+_BLOCK, _CONTEXT = 16, 1024
+_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+          "dynamic-update-slice")
+
+
+@pytest.fixture(scope="module")
+def gpt2_large(v5e):
+    from paddle_tpu.models import gpt
+
+    cfg = gpt.GPTConfig(vocab_size=50257, hidden=1280, layers=36, heads=20,
+                        mlp_dim=5120, max_len=_CONTEXT, dtype="bfloat16")
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = jax.eval_shape(lambda k: gpt.init(k, cfg)[0], jax.random.key(0))
+    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    return gpt, cfg, params, sds
+
+
+def _pool(cfg, sds, slots):
+    """The pool as the ENGINE makes it: KVCacheConfig's shape and dtype."""
+    from paddle_tpu.serving.kv_cache import KVCacheConfig
+
+    kv = KVCacheConfig(layers=cfg.layers, kv_heads=cfg.heads,
+                       head_dim=cfg.head_dim, max_len=_CONTEXT,
+                       block_size=_BLOCK,
+                       num_blocks=slots * (_CONTEXT // _BLOCK) + 1)
+    return sds(kv.pool_shape, jnp.dtype(kv.dtype))
+
+
+def _compile_decode(gpt2_large, slots):
+    gpt, cfg, params, sds = gpt2_large
+    pool = _pool(cfg, sds, slots)
+    return pool, jax.jit(
+        lambda p, ids, pos, kp, vp, bts: gpt.apply_decode_step(
+            p, cfg, ids, pos, kp, vp, bts, block_size=_BLOCK, eos_id=-1),
+        donate_argnums=(3, 4)).lower(
+        params, sds((slots,), np.int32), sds((slots,), np.int32), pool, pool,
+        sds((slots, _CONTEXT // _BLOCK), np.int32)).compile()
+
+
+def _compile_prefill(gpt2_large, bucket):
+    gpt, cfg, params, sds = gpt2_large
+    pool = _pool(cfg, sds, 16)
+    return pool, jax.jit(
+        lambda p, ids, n, kp, vp, bt: gpt.apply_prefill(
+            p, cfg, ids, n, kp, vp, bt, block_size=_BLOCK, eos_id=-1),
+        donate_argnums=(3, 4)).lower(
+        params, sds((1, bucket), np.int32), sds((), np.int32), pool, pool,
+        sds((_CONTEXT // _BLOCK,), np.int32)).compile()
+
+
+def _pool_movers(text, pool_shape):
+    """Ops of `_MOVES` whose result is a whole pool `[L, NB, ...]` or one
+    layer's slice of it `[1, NB, ...]` / `[NB, ...]`, as (op, dims)."""
+    whole = ",".join(map(str, pool_shape))
+    rest = ",".join(map(str, pool_shape[1:]))
+    shapes = {whole, "1," + rest, rest}
+    found = []
+    for dims, op in re.findall(
+            r"= \(?\w+\[([\d,]+)\]\S* ([a-z-]+)\(", text):
+        if op in _MOVES and dims in shapes:
+            found.append((op, dims))
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode@16", "prefill@512",
+                                     "prefill@1024"])
+def test_gpt2_large_serve_program_addresses_the_pool_in_place(
+        gpt2_large, program):
+    kind, n = program.split("@")
+    pool, compiled = (_compile_decode if kind == "decode"
+                      else _compile_prefill)(gpt2_large, int(n))
+    assert pool.shape == (36, 1025, 16, 1280)
+    ma = compiled.memory_analysis()
+    # parent (pools in xs/ys, [.., 20, 64]): 3.80 GB decode, 3.60 prefill
+    assert ma.temp_size_in_bytes < 0.5e9, ma
+    # both pools are written where they lie: the outputs alias the inputs
+    assert ma.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2, ma
+    movers = _pool_movers(compiled.as_text(), pool.shape)
+    assert not movers, movers
+
+
+def test_gpt2_large_decode_step_at_32_slots_leaves_room_on_v5e(gpt2_large):
+    """Weights 1.55 + pools 6.04 + temporaries: 7.8 GB planned, half the
+    chip (the parent planned 15.59 GB and left no room for a prefill)."""
+    pool, compiled = _compile_decode(gpt2_large, 32)
+    assert pool.shape == (36, 2049, 16, 1280)
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert planned < 9e9, ma
