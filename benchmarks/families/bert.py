@@ -20,10 +20,10 @@ def make_config(model: Dict):
     return bert.BertConfig(**model)
 
 
-def init(cfg, seed: int):
+def init(cfg, seed: int, dtype=None):
     from paddle_tpu.models import bert
 
-    return device.init_on_device(bert.init, cfg, seed)
+    return device.init_on_device(bert.init, cfg, seed, dtype)
 
 
 def loss_fn(cfg):

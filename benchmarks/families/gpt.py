@@ -16,10 +16,10 @@ def make_config(model: Dict):
     return gpt.GPTConfig(**model)
 
 
-def init(cfg, seed: int):
+def init(cfg, seed: int, dtype=None):
     from paddle_tpu.models import gpt
 
-    return device.init_on_device(gpt.init, cfg, seed)
+    return device.init_on_device(gpt.init, cfg, seed, dtype)
 
 
 def decode_step_min_bytes(model: Dict, live_tokens: float) -> float:

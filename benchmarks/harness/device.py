@@ -83,32 +83,56 @@ def start(chips: int, allow_cpu: bool = False):
                  else load_peaks(dev["kind"]))
 
 
-def init_on_device(init, cfg, seed: int):
+def init_on_device(init, cfg, seed: int, dtype=None):
     """(params, axes) of the program's `init(key, cfg)` as ONE jitted
     program, made on the device from the seed (called eagerly, init costs a
-    compile per distinct parameter shape)."""
+    compile per distinct parameter shape). With `dtype`, every floating
+    parameter is cast to it INSIDE that program: the values are the float32
+    ones rounded once, and the float32 set is never whole on the device."""
     import jax
+    import jax.numpy as jnp
 
     axes: Dict = {}
 
     def _init(key):
         params, a = init(key, cfg)
         axes.update(a)  # static: filled once, while tracing
+        if dtype is not None:
+            params = jax.tree_util.tree_map(
+                lambda v: v.astype(dtype)
+                if jnp.issubdtype(v.dtype, jnp.floating) else v, params)
         return params
 
     return jax.jit(_init)(jax.random.key(seed % (2 ** 31))), axes
 
 
 def resident_bytes(devices) -> int:
-    """Bytes of live arrays on the fullest of `devices`."""
+    """Bytes of live arrays on the fullest of `devices`, from shapes and
+    shardings alone: asking an array for its shards caches them on it in a
+    cycle that the collector does not free, and would keep every array
+    counted here alive for good. A single-device buffer counts once
+    however many array objects share it (a compiled call hands its
+    arguments on under new array objects)."""
+    import math
+
     import jax
 
     per = collections.Counter()
     ids = {d.id for d in devices}
+    seen = set()
     for arr in jax.live_arrays():
-        for sh in arr.addressable_shards:
-            if sh.device.id in ids:
-                per[sh.device.id] += sh.data.nbytes
+        if arr.is_deleted():
+            continue
+        on = [d.id for d in arr.sharding.device_set if d.id in ids]
+        if len(arr.sharding.device_set) == 1:
+            buf = arr.unsafe_buffer_pointer()
+            if buf in seen:
+                continue
+            seen.add(buf)
+        nbytes = math.prod(arr.sharding.shard_shape(arr.shape)) \
+            * arr.dtype.itemsize
+        for i in on:
+            per[i] += nbytes
     return max(per.values()) if per else 0
 
 

@@ -5,8 +5,6 @@ and record lists of `paddle_tpu.observability.tracing`, and the layer scopes
 - `collect()` takes the recorded spans, step records and request records out
   of the program's store; a program without that store (an older commit)
   gives None and every reader of it leaves its metric out.
-- `attribute_innermost()` gives each second of a device idle gap to the
-  innermost host span that covers it, totals preserved.
 - `reduce_scopes()` sums device time by layer scope. The scope of an op is
   the innermost of `SCOPES` in its `op_name` (backward ops carry it inside
   `transpose(jvp(...))`), which the profiler keeps as the stat `tf_op` of the
@@ -19,15 +17,11 @@ hold the program to them."""
 
 from __future__ import annotations
 
-import bisect
 import collections
-import glob
-import os
 import re
 from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 from . import trace_reduce
-from .manifest import ROOT
 
 # innermost first where they nest: an op under layers/attention is attention's
 SCOPES = ("ln", "qkv", "kv_write", "kv_gather", "attention", "proj", "mlp",
@@ -104,38 +98,6 @@ def loop_host_seconds(spans: Sequence[Span], lo: float, hi: float) -> float:
         (a, b) for n, a, b, _, _ in spans
         if n.startswith("decode.") and n.endswith(".wait"))
     return trace_reduce.total(trace_reduce.subtract(turns, waits))
-
-
-def attribute_innermost(gaps: Sequence[Tuple[float, float]],
-                        host: Sequence[Tuple[str, float, float]],
-                        other: str) -> Dict[str, Dict[str, float]]:
-    """Each second of each idle gap goes to the INNERMOST host span that
-    covers it (of the spans covering a moment, the one that started last;
-    spans of one thread nest), what no span covers to `other`. The gaps'
-    total is preserved, unlike trace_reduce.attribute_gaps, which gives an
-    overlap to every span that has it."""
-    out: Dict[str, Dict[str, float]] = collections.defaultdict(
-        lambda: {"seconds": 0.0, "longest": 0.0})
-    host = sorted(host, key=lambda s: s[1])
-    starts = [s[1] for s in host]
-    longest = max((b - a for _, a, b in host), default=0.0)
-    for ga, gb in gaps:
-        # a span that reaches into the gap starts before the gap ends and
-        # no earlier than the longest span before it begins
-        inside = [s for s in host[bisect.bisect_left(starts, ga - longest):
-                                  bisect.bisect_left(starts, gb)]
-                  if s[2] > ga]
-        cuts = sorted({ga, gb, *(min(max(t, ga), gb)
-                                 for _, a, b in inside for t in (a, b))})
-        runs: Dict[str, float] = collections.defaultdict(float)
-        for a, b in zip(cuts, cuts[1:]):
-            cover = [s for s in inside if s[1] <= a and s[2] >= b]
-            name = max(cover, key=lambda s: s[1])[0] if cover else other
-            runs[name] += b - a
-        for name, secs in runs.items():
-            out[name]["seconds"] += secs
-            out[name]["longest"] = max(out[name]["longest"], secs)
-    return dict(out)
 
 
 # -- the .xplane.pb wire format, as far as the scopes need it --------------
@@ -290,21 +252,8 @@ def reduce_scopes(path: str) -> Dict:
 
 
 def device_scopes(rec: Dict) -> Optional[Dict]:
-    """The scope reduction of a traced run's records: `rec["scopes"]` where
-    a runner put it, else (the runners of PR 23 do not) reduced once from
-    the newest trace under `bench_out/`, which is this process's own: the
-    readers run right after the run that wrote it. None untraced, or where
-    no device op carries a scope (a program without scopes)."""
-    if not rec.get("trace"):
-        return None
-    if "scopes" not in rec:
-        files = glob.glob(os.path.join(
-            ROOT, "bench_out", "*", "*-trace1", "trace", "plugins",
-            "profile", "*", "*.xplane.pb"))
-        try:
-            rec["scopes"] = reduce_scopes(
-                max(files, key=os.path.getmtime)) if files else None
-        except (OSError, ValueError, IndexError, KeyError, StopIteration):
-            rec["scopes"] = None    # a reader leaves out, it never raises
-    scopes = rec["scopes"]
+    """The scope reduction a traced run's runner put into its records
+    (`rec["scopes"]`), or None: untraced, or a recorded trace in which no
+    device op carries a scope."""
+    scopes = rec.get("scopes") if rec.get("trace") else None
     return scopes if scopes and scopes.get("scoped_ops") else None

@@ -12,6 +12,7 @@ spans (`jax.profiler.TraceAnnotation`) are events on the thread lines of
 
 from __future__ import annotations
 
+import bisect
 import collections
 import glob
 import os
@@ -133,6 +134,38 @@ def attribute_gaps(gaps: Sequence[Interval],
     return dict(out)
 
 
+def attribute_innermost(gaps: Sequence[Interval],
+                        host: Sequence[Tuple[str, float, float]],
+                        other: str) -> Dict[str, Dict[str, float]]:
+    """Each second of each idle gap goes to the INNERMOST host span that
+    covers it (of the spans covering a moment, the one that started last;
+    spans of one thread nest), what no span covers to `other`. The gaps'
+    total is preserved, unlike attribute_gaps, which gives an overlap to
+    every span that has it."""
+    out: Dict[str, Dict[str, float]] = collections.defaultdict(
+        lambda: {"seconds": 0.0, "longest": 0.0})
+    host = sorted(host, key=lambda s: s[1])
+    starts = [s[1] for s in host]
+    longest = max((b - a for _, a, b in host), default=0.0)
+    for ga, gb in gaps:
+        # a span that reaches into the gap starts before the gap ends and
+        # no earlier than the longest span before it begins
+        inside = [s for s in host[bisect.bisect_left(starts, ga - longest):
+                                  bisect.bisect_left(starts, gb)]
+                  if s[2] > ga]
+        cuts = sorted({ga, gb, *(min(max(t, ga), gb)
+                                 for _, a, b in inside for t in (a, b))})
+        runs: Dict[str, float] = collections.defaultdict(float)
+        for a, b in zip(cuts, cuts[1:]):
+            cover = [s for s in inside if s[1] <= a and s[2] >= b]
+            name = max(cover, key=lambda s: s[1])[0] if cover else other
+            runs[name] += b - a
+        for name, secs in runs.items():
+            out[name]["seconds"] += secs
+            out[name]["longest"] = max(out[name]["longest"], secs)
+    return dict(out)
+
+
 def load_xplane(path: str, host_spans: Sequence[str]) -> Dict:
     """{"devices": {index: {"ops": [(name, start_s, end_s)], "modules":
     [...]}}, "host": [(name, start_s, end_s)]} of one `.xplane.pb`."""
@@ -163,9 +196,12 @@ def load_xplane(path: str, host_spans: Sequence[str]) -> Dict:
     return {"devices": devices, "host": host}
 
 
-def reduce_loaded(loaded: Dict, other: str) -> Dict:
+def reduce_loaded(loaded: Dict, other: str, innermost: bool = False
+                  ) -> Dict:
     """The reduced trace the per-layer readers and `breakdown` read. The
-    window is from the first to the last device op over all devices."""
+    window is from the first to the last device op over all devices.
+    `innermost`: the host spans nest (the engine loop's own), so each idle
+    second goes to the innermost one; else to every span that overlaps."""
     devs = {i: d for i, d in loaded["devices"].items() if d["ops"]}
     if not devs:
         return {"devices_seen": 0}
@@ -182,8 +218,8 @@ def reduce_loaded(loaded: Dict, other: str) -> Dict:
     for d in devs.values():
         for n, a, b in d["modules"]:
             modules[re.sub(r"\(\d+\)$", "", n)].append(b - a)
-    gaps = attribute_gaps(per_dev[worst]["idle_gaps"], loaded["host"],
-                          other)
+    gaps = (attribute_innermost if innermost else attribute_gaps)(
+        per_dev[worst]["idle_gaps"], loaded["host"], other)
     window = hi - lo
     return {
         "devices_seen": len(per_dev),
@@ -212,12 +248,6 @@ def find_xplane(trace_dir: str) -> str:
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     return files[-1]
-
-
-def reduce_dir(trace_dir: str, host_spans: Sequence[str], other: str
-               ) -> Dict:
-    return reduce_loaded(load_xplane(find_xplane(trace_dir), host_spans),
-                         other)
 
 
 def profile_options():

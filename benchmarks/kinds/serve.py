@@ -1,73 +1,40 @@
 """Runner of every traffic file of kind `serve`: the program's
 `DecodeEngine` behind its `serving.Server` in this process (which holds the
 chip), the load generator in a child that never imports JAX, a window on
-the clients' clock, and the records the per-layer readers read. Nothing
-here names a configuration or a cell."""
+the clients' clock, and the records the per-layer readers read. A traced
+run turns the PROGRAM's own recording on (its spans, step and request
+records: PERF.md section 3) and reads the layer scopes of its device
+programs; an untraced run records nothing and wraps nothing. The weights
+are on the device once at a time: in the served precision while the engine
+lives, in float32 for the reference only after engine, server and pools
+are gone. Nothing here names a configuration or a cell."""
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import subprocess
 import sys
 import time
-from typing import Dict, List
+from typing import Dict
 
-from ..harness import device, manifest, trace_reduce, traffic as traffic_mod
-from ..harness import window
+from ..harness import device, manifest, program_trace, trace_reduce
+from ..harness import traffic as traffic_mod, window
 
 LOADGEN = os.path.join(manifest.BENCH_DIR, "harness", "loadgen.py")
-HOST_SPANS = ("prefill", "engine_dispatch", "engine_resolve")
+# the engine loop's own spans (`paddle_tpu.observability.tracing`, on while
+# a traced run records), innermost last where they nest: a device idle gap
+# goes to the innermost of them that covers it
+LOOP_SPANS = ("decode.turn", "decode.admit", "decode.grow", "decode.prefill",
+              "decode.prefill.wait", "decode.dispatch", "decode.resolve",
+              "decode.resolve.wait")
 TAIL_S = 2.0    # load past the window: a request due at its end can still
                 # show its first token
 TRACE_S = 4.0   # traced sub-window, under continuing load
-
-
-def instrument(engine, spans: List) -> None:
-    """Harness-side spans around the engine's calls into its layers: the
-    prefill entry, the decode dispatch and the resolve of a step's tokens.
-    Each is a (name, t0, t1, facts) row on CLOCK_MONOTONIC and, while a
-    trace is taken, a TraceAnnotation on the profiler's clock."""
-    import jax
-
-    ann = jax.profiler.TraceAnnotation
-    missing = [n for n in ("_prefill_one", "_dispatch", "_resolve")
-               if not callable(getattr(engine, n, None))]
-    if missing:  # a rename in serving/decode.py must not go unseen
-        raise RuntimeError(f"DecodeEngine has no {missing}: the harness "
-                           "spans of benchmarks/kinds/serve.py wrap them")
-    prefill_one, dispatch, resolve = (
-        engine._prefill_one, engine._dispatch, engine._resolve)
-
-    def traced_prefill(req):
-        t0 = time.monotonic()
-        facts = {"queue_wait_s": t0 - req.enqueued_at,
-                 "prompt_len": len(req.prompt)}
-        with ann("prefill"):
-            out = prefill_one(req)
-        spans.append(("prefill", t0, time.monotonic(), facts))
-        return out
-
-    def traced_dispatch(ids_arg, C):
-        t0 = time.monotonic()
-        with ann("engine_dispatch"):
-            out = dispatch(ids_arg, C)
-        live = [r for r in out.slots if r is not None]
-        spans.append(("engine_dispatch", t0, time.monotonic(),
-                      {"slots": C, "live": len(live),
-                       "live_tokens": sum(r.pos for r in live)}))
-        return out
-
-    def traced_resolve(pending):
-        t0 = time.monotonic()
-        with ann("engine_resolve"):
-            out = resolve(pending)
-        spans.append(("engine_resolve", t0, time.monotonic(), {}))
-        return out
-
-    engine._prefill_one = traced_prefill
-    engine._dispatch = traced_dispatch
-    engine._resolve = traced_resolve
+# `serve.precision` of a configuration file -> the dtype the weights are
+# made in (the engine serves these two and casts nothing that already fits)
+SERVED_DTYPE = {"bf16": "bfloat16", "f32": "float32"}
 
 
 def _sleep_until(t: float) -> None:
@@ -101,10 +68,41 @@ def _warm_request(port: int, ids, max_new: int) -> None:
         conn.close()
 
 
+def _write_program(program: Dict, w0: float, out_dir: str) -> None:
+    """The program's recording of the window, times from its opening."""
+    with open(os.path.join(out_dir, "program_spans.jsonl"), "w") as f:
+        for name, a, b, tid, facts in program["spans"]:
+            f.write(json.dumps({"name": name, "t0_s": a - w0,
+                                "seconds": b - a, "tid": tid, **facts},
+                               default=str) + "\n")
+    with open(os.path.join(out_dir, "engine_steps.jsonl"), "w") as f:
+        for s in program["steps"]:
+            f.write(json.dumps(dict(s, t=s["t"] - w0)) + "\n")
+    stamps = ("arrival", "t_submit", "enqueued_at", "admitted_at",
+              "t_first", "t_finish")
+    with open(os.path.join(out_dir, "engine_requests.jsonl"), "w") as f:
+        for r in program["requests"]:
+            f.write(json.dumps({k: (v - w0 if k in stamps and v else v)
+                                for k, v in r.items()}) + "\n")
+
+
+def _served_plans(engine) -> Dict:
+    """The compiler's plan of every served program the engine compiled."""
+    plans = {}
+    for kind, table in (("decode", engine._decode),
+                        ("prefill", engine._prefill)):
+        for size, disp in table.items():
+            aot = getattr(disp, "_aot", None)
+            if aot is not None:
+                plans[f"{kind}@{size}"] = device.planned_bytes(aot)
+    return plans
+
+
 def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
     import jax
     import numpy as np
 
+    from paddle_tpu.observability import tracing
     from paddle_tpu.serving import Server, ServingConfig
     from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
 
@@ -117,35 +115,41 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
     model, serve = config["model"], config["serve"]
     cfg = family.make_config(model)
     devices = jax.devices()[:cell["chips"]]
+    resident_at_start = device.resident_bytes(devices)
 
-    params, _ = family.init(cfg, args.seed)
+    if args.trace:
+        # the program's spans and records, from before the engine exists
+        # (it keys its phase grid's compile cache on metadata itself, so
+        # the scopes a traced run reads are never stale)
+        tracing.start_recording()
     slots = max(serve["decode_slots"])
     pool_tokens = slots * serve["kv_context_per_slot"]
-    engine = DecodeEngine(params, cfg, DecodeConfig(
-        block_size=serve["block_size"],
-        num_blocks=slots * (serve["kv_context_per_slot"]
-                            // serve["block_size"]) + 1,
-        decode_slots=tuple(serve["decode_slots"]),
-        prefill_buckets=tuple(traffic["prefill_buckets"]),
-        max_queue=int(serve["max_queue"]), precision=serve["precision"],
-        eos_id=serve["eos_id"]))
-    # the engine serves its own cast of the weights: the float32 ones are
-    # made again from the seed for the reference, after the window, so that
-    # the chip holds what a deployment holds while it is measured
-    del params
-    engine.warmup()
-    spans: List = []
-    instrument(engine, spans)
-    server = Server(ServingConfig(), decode=engine)
-    port = server.start(0)
-    child = None
+    engine = server = child = None
     try:
+        # the weights in the precision they are served in, cast inside the
+        # one program that makes them: float32 is never whole on the device
+        params, _ = family.init(cfg, args.seed,
+                                dtype=SERVED_DTYPE[serve["precision"]])
+        weight_bytes = sum(int(v.nbytes)
+                           for v in jax.tree_util.tree_leaves(params))
+        engine = DecodeEngine(params, cfg, DecodeConfig(
+            block_size=serve["block_size"],
+            num_blocks=slots * (serve["kv_context_per_slot"]
+                                // serve["block_size"]) + 1,
+            decode_slots=tuple(serve["decode_slots"]),
+            prefill_buckets=tuple(traffic["prefill_buckets"]),
+            max_queue=int(serve["max_queue"]),
+            precision=serve["precision"], eos_id=serve["eos_id"]))
+        del params      # the engine holds them now: one copy
+        resident_after_build = device.resident_bytes(devices)
+        engine.warmup()
+        server = Server(ServingConfig(), decode=engine)
+        port = server.start(0)
         # one request per prefill bucket through the whole served path
         for b in engine.prefill_buckets:
             _warm_request(port, traffic_mod.prompt_ids(
                 args.seed, 10 ** 6 + b, min(b, model["max_len"] - 4),
                 model["vocab_size"]), 3)
-        del spans[:]
 
         t0 = time.monotonic() + 1.0
         w0 = t0 + float(traffic["lead_s"])
@@ -167,8 +171,8 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
         c0 = dict(_counters(), load=engine.load())
         _sleep_until(w1)
         c1 = dict(_counters(), load=engine.load())
+        kv_close = engine.status()["kv"]
 
-        trace = None
         if args.trace:
             trace_dir = os.path.join(out_dir, "trace")
             jax.profiler.start_trace(
@@ -183,40 +187,62 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
         if child is not None and child.poll() is None:
             child.kill()
             child.wait()
-        server.stop()
-        engine.stop()
+        if server is not None:
+            server.stop()
+        if engine is not None:
+            engine.stop()
+        if args.trace:
+            tracing.stop_recording()
 
-    if args.trace:
-        trace = trace_reduce.reduce_dir(trace_dir, HOST_SPANS,
-                                        "engine_other")
-        live = [s[3]["live_tokens"] for s in spans
-                if s[0] == "engine_dispatch" and ta <= s[1] < tb]
-        if live:  # least bytes of a decode step at the traced load
-            trace["live_tokens_mean"] = float(np.mean(live))
-            trace["decode_min_bytes"] = family.decode_step_min_bytes(
-                model, trace["live_tokens_mean"])
-
-    in_win = [s for s in spans if w0 <= s[1] < w1]
-    unseen = [n for n in HOST_SPANS if not any(s[0] == n for s in in_win)]
-    if unseen:  # wrapped, but the engine's loop no longer calls them
-        raise RuntimeError(f"no {unseen} span in the window: the engine "
-                           "loop has changed under the harness's spans")
-
-    # memory, before the reference's float32 parameters are made: the
-    # compiler's plan of the largest served program plus what is resident
-    plans = {}
-    for kind, table in (("decode", engine._decode),
-                        ("prefill", engine._prefill)):
-        for size, disp in table.items():
-            aot = getattr(disp, "_aot", None)
-            if aot is not None:
-                plans[f"{kind}@{size}"] = device.planned_bytes(aot)
+    # memory, while what served the window is still there: the compiler's
+    # plan of the largest served program plus what is resident
+    plans = _served_plans(engine)
     resident = device.resident_bytes(devices)
     planned_total = resident + max(
         (p.get("temp", 0) + max(0, p.get("output", 0) - p.get("alias", 0))
          for p in plans.values()), default=0)
     dev["memory_peak_bytes"] = int(max(
         planned_total, device.runtime_peak_bytes(devices)))
+    # ... and gone before the reference's float32 parameters are made
+    del engine, server
+    gc.collect()
+    resident_dropped = device.resident_bytes(devices)
+
+    trace = program = scopes = None
+    checks: Dict = {}
+    if args.trace:
+        program = program_trace.collect(w0, w1)
+        if program is None or not any(
+                s[0] == "decode.turn" for s in program["spans"]):
+            raise RuntimeError("the program recorded no decode.turn span "
+                               "in the window: its recording is off, or "
+                               "the engine loop lost its spans")
+        _write_program(program, w0, out_dir)
+        checks["recording"] = {
+            "spans_per_s": len(program["spans"]) / (w1 - w0),
+            "records_per_s": (len(program["steps"])
+                              + len(program["requests"])) / (w1 - w0),
+            "dropped_spans": program["dropped"]}
+        xplane = trace_reduce.find_xplane(trace_dir)
+        trace = trace_reduce.reduce_loaded(
+            trace_reduce.load_xplane(xplane, LOOP_SPANS), "engine_other",
+            innermost=True)
+        scopes = program_trace.reduce_scopes(xplane)
+        with open(os.path.join(out_dir, "device_scopes.json"), "w") as f:
+            json.dump(scopes, f, indent=1)
+        if not scopes.get("scoped_ops"):
+            raise RuntimeError(
+                "no device op of the trace carries a layer scope: the "
+                "executables predate the scopes (a compile cache keyed "
+                "without metadata?)")
+        # least bytes of a decode step at the traced load, from the
+        # program's step records of the traced sub-window
+        live = [s["live_tokens"] for s in program_trace.collect(
+            ta, tb)["steps"] if s["kind"] == "decode"]
+        if live:
+            trace["live_tokens_mean"] = float(np.mean(live))
+            trace["decode_min_bytes"] = family.decode_step_min_bytes(
+                model, trace["live_tokens_mean"])
 
     with open(job["out"]) as f:
         requests = [json.loads(line) for line in f if line.strip()]
@@ -234,51 +260,57 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
     rng = np.random.default_rng([args.seed, 0xC0FFEE])
     sample = [pool[i] for i in sorted(rng.choice(
         len(pool), size=min(n_check, len(pool)), replace=False))]
-    gap = exact = None
+    gap = exact = resident_at_reference = None
     if len(sample) == n_check:
-        params, _ = family.init(cfg, args.seed)
+        params, _ = family.init(cfg, args.seed)     # float32, the only set
+        resident_at_reference = device.resident_bytes(devices)
         gap, exact = family.reference_gaps(
             params, model,
             [traffic_mod.prompt_ids(args.seed, r["idx"], r["prompt_len"],
                                     model["vocab_size"]) for r in sample],
             [r["tokens"][:n_tok] for r in sample], model["max_len"])
+        del params
     window_compiles = c1["compile_requests"] - c0["compile_requests"]
-    checks = {"finished": len(finished), "short_streams": short[:8],
-              "sampled": [r["idx"] for r in sample],
-              "ref_max_logit_gap": gap, "ref_exact_tokens": exact,
-              "ref_tokens": n_check * n_tok,
-              "logit_gap_tol": config["logit_gap_tol"],
-              "compiles_in_window": window_compiles,
-              "engine_requests": status["requests"],
-              # (waiting, active) at the window's edges: a queue that
-              # grows through the window means the rate is past the knee
-              "load_open": c0["load"], "load_close": c1["load"],
-              "ttft_p95_ms": None if not win["ttft_s"]
-              else 1000.0 * window.percentile(win["ttft_s"], 95),
-              "requests_in_window": win["attempted"],
-              "tokens_in_window": win["tokens"]}
+    checks.update({
+        "finished": len(finished), "short_streams": short[:8],
+        "sampled": [r["idx"] for r in sample],
+        "ref_max_logit_gap": gap, "ref_exact_tokens": exact,
+        "ref_tokens": n_check * n_tok,
+        "logit_gap_tol": config["logit_gap_tol"],
+        "compiles_in_window": window_compiles,
+        "engine_requests": status["requests"],
+        # (waiting, active) at the window's edges: a queue that
+        # grows through the window means the rate is past the knee
+        "load_open": c0["load"], "load_close": c1["load"],
+        "ttft_p95_ms": None if not win["ttft_s"]
+        else 1000.0 * window.percentile(win["ttft_s"], 95),
+        "requests_in_window": win["attempted"],
+        "tokens_in_window": win["tokens"]})
     correct = (gap is not None and gap <= config["logit_gap_tol"]
                and not short and window_compiles == 0)
 
-    live = [s[3]["live_tokens"] for s in in_win if s[0] == "engine_dispatch"]
-    kv_token_bytes = family.kv_bytes_per_token(model)
     checks["memory"] = {
         "resident_bytes": resident, "plans": plans,
-        "kv_pool_tokens": pool_tokens, "kv_bytes_per_token": kv_token_bytes,
-        "kv_live_tokens_mean": float(np.mean(live)),
-        "kv_live_tokens_max": int(max(live))}
+        "weight_bytes": weight_bytes, "kv_pool_bytes": kv_close["pool_bytes"],
+        "resident_at_start": resident_at_start,
+        "resident_after_build": resident_after_build,
+        "resident_dropped": resident_dropped,
+        "resident_at_reference": resident_at_reference,
+        "kv_pool_tokens": pool_tokens,
+        "kv_bytes_per_token": family.kv_bytes_per_token(model),
+        # one reading at the window's close; the mean over the window is
+        # the traced run's `kv_block_used_share`
+        "kv_live_tokens_close": kv_close["live_tokens"],
+        "kv_blocks_used_close": kv_close["blocks_used"]}
 
     records = {
         "kind": "serve", "chips": cell["chips"], "peaks": peaks,
         "model": model, "kv_pool_tokens": pool_tokens,
-        "window_s": w1 - w0, "window": win, "spans": in_win,
+        "window_s": w1 - w0, "window": win,
         "counters": {"open": c0, "close": c1},
         "planned_bytes": planned_total, "trace": trace,
+        "program": program, "scopes": scopes,
     }
-    with open(os.path.join(out_dir, "engine_spans.jsonl"), "w") as f:
-        for name, a, b, facts in in_win:
-            f.write(json.dumps({"name": name, "t0_s": a - w0,
-                                "seconds": b - a, **facts}) + "\n")
     ms = 1000.0
     end_to_end = {
         "serve_tokens_per_s": win["tokens_per_s"],

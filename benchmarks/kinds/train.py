@@ -1,7 +1,8 @@
 """Runner of every traffic file of kind `train`: the program's
 `make_train_step` under the cell's mesh, a window of whole chunks, the
 rate over all of it, and the records the per-layer readers take their
-numbers from. Nothing here names a configuration or a cell."""
+numbers from; a traced run also reads the layer scopes of the step's
+device ops. Nothing here names a configuration or a cell."""
 
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ import os
 import time
 from typing import Dict
 
-from ..harness import device, manifest, trace_reduce, window
+from ..harness import device, manifest, program_trace, trace_reduce, window
 
 PREFETCH = 2            # batches resident on the device ahead of the step
 REF_MICROBATCH = 64     # sequences per pass of the float32 reference
@@ -93,6 +94,15 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
             with ann("sync"):
                 jax.block_until_ready(out)
 
+        if args.trace:
+            # a traced run reads the step's layer scopes, which are HLO
+            # metadata: from here on (the step's first compile) the cache
+            # keys on metadata, so an executable cached before a scope
+            # changed is never loaded (for the rest of this process, which
+            # run.py ends with the run). The reference's programs above,
+            # untraced runs and `setup_s` keep the keys they have.
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key", True)
         # warm-up: one whole chunk runs every program of the window (step,
         # key split, slices), so that nothing compiles inside it
         run_chunk(0)
@@ -115,7 +125,7 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
         window_compiles = device.COUNTS["compile_requests"] - compiles0
         input_wait_s = input_s[0]
 
-        trace = None
+        trace = scopes = None
         if args.trace:
             trace_dir = os.path.join(out_dir, "trace")
             jax.profiler.start_trace(
@@ -124,10 +134,18 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
             run_chunk(len(chunks) + 1)
             traced_s = time.perf_counter() - t0
             jax.profiler.stop_trace()
-            trace = trace_reduce.reduce_dir(
-                trace_dir, host_spans=("dispatch", "sync", "input_next"),
-                other="harness_other")
+            xplane = trace_reduce.find_xplane(trace_dir)
+            trace = trace_reduce.reduce_loaded(trace_reduce.load_xplane(
+                xplane, ("dispatch", "sync", "input_next")), "harness_other")
             trace["traced_wall_s"] = traced_s
+            scopes = program_trace.reduce_scopes(xplane)
+            with open(os.path.join(out_dir, "device_scopes.json"),
+                      "w") as f:
+                json.dump(scopes, f, indent=1)
+            if not scopes.get("scoped_ops"):
+                raise RuntimeError(
+                    "no device op of the trace carries a layer scope: no "
+                    "device in the trace, or the step lost its scopes")
 
         loss_host = [float(x) for x in losses]
         for c, lo in zip(chunks, range(k, len(loss_host), k)):
@@ -169,6 +187,7 @@ def run(cell: Dict, args, out_dir: str, allow_cpu: bool = False) -> Dict:
         "tokens_per_step": tokens_per_step,
         "flops_per_token": family.train_flops_per_token(model, traffic),
         "planned_bytes": planned_total, "trace": trace,
+        "scopes": scopes,
     }
     dev["memory_peak_bytes"] = int(max(planned_total,
                                        device.runtime_peak_bytes(devices)))

@@ -1,6 +1,6 @@
 """Engine-loop wall time inside the program's `decode.prefill` spans (one
 request's prompt work: a whole-prompt prefill, or one chunk of it) over the
-window: the twin of `prefill_share`."""
+window (the span holds the wait for the step in flight)."""
 from benchmarks.harness import program_trace
 
 

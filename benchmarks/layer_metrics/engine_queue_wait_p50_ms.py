@@ -1,7 +1,6 @@
 """Median wait from a request's arrival in the engine's queue to its
 admission, from the program's request records (`enqueued_at` to
-`admitted_at`) of the requests that finished in the window: the twin of
-`queue_wait_p50_ms`."""
+`admitted_at`) of the requests that finished in the window."""
 import statistics
 
 

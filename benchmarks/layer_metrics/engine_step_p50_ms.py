@@ -1,6 +1,7 @@
 """Median time from the start of one decode step to the start of the next,
-inside the window, from the PROGRAM's step records (kind `decode`): the twin
-of `decode_step_p50_ms`, which wraps a private method for the same number."""
+inside the window, from the PROGRAM's step records (kind `decode`): the
+decode step as the engine loop lives it, prefill interruptions being the
+minority the median passes over."""
 import statistics
 
 

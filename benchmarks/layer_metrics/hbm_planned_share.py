@@ -1,6 +1,6 @@
 """The compiler's plan (temporaries of the window's largest program) plus
 the arrays resident during the window on the fullest chip, over the chip's
-HBM: what is ALLOCATED, the reserved KV pool whole (`kv_used_share` says
+HBM: what is ALLOCATED, the reserved KV pool whole (`kv_block_used_share` says
 how much of it holds tokens). Not the runtime's peak_bytes_in_use, which
 leaves temporaries out. One reader for `.train` and `.serve`."""
 

@@ -1,6 +1,7 @@
 """Blocks the allocator has handed out over the blocks it can hand out,
 mean over the window's decode step records (`BlockAllocator` itself, not a
-sum over requests): `kv_used_share` plus block rounding and reservations."""
+sum over requests): how much of the reserved pool the traffic fills, block
+rounding and reservations included."""
 
 
 def read(rec):

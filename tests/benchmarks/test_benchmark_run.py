@@ -148,19 +148,40 @@ def test_tiny_train_rehearsal(tmp_path, chips):
         rec) == pytest.approx(len(kept) * chunks[0]["tokens"] / sum(kept))
 
 
+SERVE_MIX = {"kind": "serve", "loop": "closed", "rate_per_s": 6.0,
+             "clients": 3, "table_size": 24,
+             "prompt_len": {"dist": "loguniform", "lo": 4, "hi": 60},
+             "output_len": {"dist": "loguniform", "lo": 16, "hi": 32},
+             "prefill_buckets": [32, 64], "lead_s": 0.5}
+
+
+def _serve_cell(**mix):
+    return {"name": "tiny.serve", "chips": 1, "config_file": TINY_GPT,
+            "traffic_file": dict(SERVE_MIX, **mix)}
+
+
+def weights_held_once(mem) -> bool:
+    """The serve kind's live device bytes (`checks.memory`), against one
+    copy of the weights at a time: the served set plus the pools while the
+    engine lives, nothing of them once it is dropped, and then the
+    reference's float32 set alone. The slack is a quarter of the served
+    weights: a second copy of them, in any precision, does not fit it."""
+    slack = mem["weight_bytes"] // 4
+    base = mem["resident_at_start"]
+    served = mem["weight_bytes"] + mem["kv_pool_bytes"]
+    return (mem["resident_after_build"] - base <= served + slack
+            and mem["resident_bytes"] - base <= served + slack
+            and mem["resident_dropped"] - base <= slack
+            and mem["resident_at_reference"] - base
+            <= 2 * mem["weight_bytes"] + slack)   # float32 of a bf16 set
+
+
 @pytest.mark.parametrize("loop", ["open", "closed"])
 def test_tiny_serve_rehearsal(tmp_path, loop):
     from benchmarks.kinds import serve
 
-    mix = {"kind": "serve", "loop": loop, "rate_per_s": 6.0, "clients": 3,
-           "table_size": 24,
-           "prompt_len": {"dist": "loguniform", "lo": 4, "hi": 60},
-           "output_len": {"dist": "loguniform", "lo": 16, "hi": 32},
-           "prefill_buckets": [32, 64], "lead_s": 0.5}
-    cell = {"name": "tiny.serve", "chips": 1, "config_file": TINY_GPT,
-            "traffic_file": mix}
-    res = serve.run(cell, _args(tmp_path, seconds=2.0), str(tmp_path),
-                    allow_cpu=True)
+    res = serve.run(_serve_cell(loop=loop), _args(tmp_path, seconds=2.0),
+                    str(tmp_path), allow_cpu=True)
     assert res["correct"], res["checks"]
     assert res["failed"] == 0 and res["attempted"] >= 3
     assert res["checks"]["compiles_in_window"] == 0
@@ -171,26 +192,91 @@ def test_tiny_serve_rehearsal(tmp_path, loop):
     assert win["tokens"] == round(
         res["end_to_end"]["serve_tokens_per_s"] * 2.0)
     assert win["tokens"] > len([r for r in recs if r["done"]])
-    assert (tmp_path / "engine_spans.jsonl").exists()
-    bench = manifest.load_manifest()
-    for name in ("decode_step_p50_ms", "prefill_share", "slot_occupancy",
-                 "queue_wait_p50_ms", "gen_late_p95_ms", "ttft_p50_ms",
-                 "prefill_gap_share", "kv_used_share"):
+    # an untraced run records nothing of the program and wraps nothing
+    assert res["records"]["program"] is None
+    assert not (tmp_path / "program_spans.jsonl").exists()
+    for name in ("slot_occupancy", "gen_late_p95_ms", "ttft_p50_ms"):
         assert manifest.layer_metric_reader(name)(res["records"]) \
             is not None, name
-    assert {m["name"] for m in bench["per_layer"]} >= {"slot_occupancy"}
-    assert 0.0 < manifest.layer_metric_reader("kv_used_share")(
-        res["records"]) <= 1.0
+    for name in ("engine_step_p50_ms", "prefill_gap_share",
+                 "kv_block_used_share", "decode_compute_share"):
+        assert manifest.layer_metric_reader(name)(res["records"]) is None
     mem = res["checks"]["memory"]
-    assert mem["kv_live_tokens_max"] <= mem["kv_pool_tokens"] == 4 * 128
+    assert mem["kv_live_tokens_close"] <= mem["kv_pool_tokens"] == 4 * 128
+    # the weights once at a time: bf16 under the engine, float32 for the
+    # reference only after engine, server and pools are gone
+    assert mem["weight_bytes"] > 0 and mem["kv_pool_bytes"] > 0
+    assert weights_held_once(mem), mem
 
 
-def test_the_serve_harness_fails_loudly_when_its_spans_lose_their_hold():
+def test_a_second_copy_of_the_weights_fails_the_rehearsal(tmp_path,
+                                                          monkeypatch):
+    """What the kind did until PR 26: float32 parameters on the device
+    while the engine casts its own copy."""
+    from benchmarks.families import gpt as gpt_family
     from benchmarks.kinds import serve
 
-    class Renamed:  # serving/decode.py renamed a method the harness wraps
-        def _prefill_one(self, req): ...
-        def _dispatch(self, ids, C): ...
+    init, kept = gpt_family.init, []
 
-    with pytest.raises(RuntimeError, match="_resolve"):
-        serve.instrument(Renamed(), [])
+    def init_keeping_float32(cfg, seed, dtype=None):
+        if dtype is not None:
+            kept.append(init(cfg, seed)[0])
+        return init(cfg, seed, dtype)
+
+    monkeypatch.setattr(gpt_family, "init", init_keeping_float32)
+    res = serve.run(_serve_cell(), _args(tmp_path, seconds=1.0),
+                    str(tmp_path), allow_cpu=True)
+    assert res["correct"] and kept
+    assert not weights_held_once(res["checks"]["memory"])
+
+
+def test_init_on_device_casts_inside_its_one_program():
+    """The served weights are the float32 ones rounded once: bit-identical
+    to casting the float32 set afterwards, so the engine's tokens do not
+    move; integer leaves are left alone."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.families import gpt as gpt_family
+
+    cfg = gpt_family.make_config(TINY_GPT["model"])
+    f32, axes = gpt_family.init(cfg, 2 ** 31 + 5)
+    b16, axes16 = gpt_family.init(cfg, 2 ** 31 + 5, dtype="bfloat16")
+    assert axes == axes16 and set(f32) == set(b16)
+    for k, v in f32.items():
+        assert v.dtype == jnp.float32 and b16[k].dtype == jnp.bfloat16, k
+        assert np.array_equal(np.asarray(v.astype(jnp.bfloat16)),
+                              np.asarray(b16[k])), k
+
+
+def _traced_args(tmp_path):
+    return _args(tmp_path, trace=1, seconds=1.0)
+
+
+def test_a_traced_serve_run_fails_loudly_without_a_decode_turn_span(
+        tmp_path, monkeypatch):
+    """The program's recording off (or an engine loop that lost its spans)
+    under a traced run: no metric is silently left out."""
+    from benchmarks.kinds import serve
+    from paddle_tpu.observability import tracing
+
+    monkeypatch.setattr(serve, "TRACE_S", 0.2)
+    monkeypatch.setattr(tracing, "start_recording", lambda clear=True: None)
+    with pytest.raises(RuntimeError, match="decode.turn"):
+        serve.run(_serve_cell(), _traced_args(tmp_path), str(tmp_path),
+                  allow_cpu=True)
+
+
+def test_a_traced_run_fails_loudly_without_a_scoped_device_op(tmp_path,
+                                                              monkeypatch):
+    """On the CPU the profiler's trace holds no device op at all: the
+    traced run raises instead of reporting per-layer metrics without the
+    scopes (on the chip: executables that predate the scopes)."""
+    from benchmarks.kinds import serve
+
+    monkeypatch.setattr(serve, "TRACE_S", 0.2)
+    with pytest.raises(RuntimeError, match="layer scope"):
+        serve.run(_serve_cell(), _traced_args(tmp_path), str(tmp_path),
+                  allow_cpu=True)
+    from paddle_tpu.observability import tracing
+    assert not tracing.recording     # stopped on the way out
