@@ -38,6 +38,7 @@ import threading
 import time
 
 SEED = 0
+OLMOE_LOGIT_TOL = 0.25   # benchmarks/configs/olmoe_1b_7b.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
 # many of those its persistent cache answered (a hit still counts as a
@@ -298,12 +299,30 @@ def _reference_gaps(params, cfg, prompts, streams):
     return max(gaps), exact
 
 
+def _olmoe_reference_gaps(params, cfg, prompts, streams):
+    """As `_reference_gaps`, against the benchmark's plain float32 OLMoE
+    (benchmarks/reference/olmoe_ref.py: written from the published
+    description, every expert computed for every token, no code of
+    models/olmoe.py)."""
+    from benchmarks.reference import olmoe_ref
+
+    ref = {"layers": cfg.layers, "heads": cfg.heads, "top_k": cfg.top_k,
+           "rope_theta": cfg.rope_theta, "rms_eps": cfg.rms_eps}
+    top = {k: v for k, v in params.items() if not k.startswith("blk.")}
+    width = max(len(p) for p in prompts) + len(streams[0])
+    return olmoe_ref.stream_gaps(
+        top, lambda i: olmoe_ref.layer_of(params, i), ref, prompts,
+        streams, width)
+
+
 def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
-                logit_tol: float) -> dict:
+                logit_tol: float, model=None,
+                reference_gaps=_reference_gaps) -> dict:
     """DecodeEngine -> warmup -> Server.start -> concurrent streamed
     POST /v1/generate from threads of this process. Every stream must end
     `done` and is checked against the float32 reference; nothing may
-    compile after warm-up."""
+    compile after warm-up. `model` is the module `cfg` belongs to
+    (models.gpt unless given), `reference_gaps` its plain reference."""
     import jax
     import numpy as np
 
@@ -311,7 +330,7 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
     from paddle_tpu.serving import Server, ServingConfig
     from paddle_tpu.serving.decode import DecodeEngine
 
-    params, _ = _init(gpt, cfg)
+    params, _ = _init(model or gpt, cfg)
     engine = DecodeEngine(params, cfg, decode_cfg)
     n_phases = len(engine.decode_slots) + len(engine.prefill_buckets)
     ready, compile_s = _timed(engine.warmup)
@@ -349,7 +368,7 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
         streams.append(toks)
     assert late_compiles == 0, \
         f"{late_compiles} programs compiled after warm-up"
-    gap, exact = _reference_gaps(params, cfg, prompts, streams)
+    gap, exact = reference_gaps(params, cfg, prompts, streams)
     assert gap <= logit_tol, (
         f"engine tokens leave the float32 reference's argmax by up to "
         f"{gap:.4f} logits (tolerance {logit_tol})")
@@ -459,7 +478,7 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import bert, gpt
+    from paddle_tpu.models import bert, gpt, olmoe
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
 
@@ -509,6 +528,23 @@ def run_one_chip() -> None:
             block_size=16, num_blocks=4 * 64 + 1, decode_slots=(4,),
             prefill_chunk=128, prefix_cache=True),
             rng.randint(0, cfg.vocab_size, 300).tolist(), max_new=16)
+
+    # OLMoE-1B-7B at its published widths (2048 wide, 16 heads of 128, 64
+    # experts of 1024, 8 a token, vocab 50304), 2 of its 16 layers so that
+    # the float32 set for the reference (4.2 GB) sits beside the served
+    # one: the same engine, loop, allocator and pool through the model
+    # interface (models/decoder.py), 16 slots of 1024 tokens
+    ocfg = olmoe.OlmoeConfig(layers=2, max_len=1024)
+    prompts = [rng.randint(0, ocfg.vocab_size, n).tolist()
+               for n in (12, 200, 5, 64, 40, 250, 129)]
+    with phase("serve_olmoe") as info:
+        # tolerance: that of the benchmark's cell (benchmarks/configs/
+        # olmoe_1b_7b.json says what it tells apart)
+        serve_phase(info, ocfg, DecodeConfig(
+            block_size=16, num_blocks=16 * 64 + 1, decode_slots=(16,),
+            prefill_buckets=(64, 128, 256)), prompts, max_new=24,
+            logit_tol=OLMOE_LOGIT_TOL, model=olmoe,
+            reference_gaps=_olmoe_reference_gaps)
 
 
 def run_four_chips() -> None:

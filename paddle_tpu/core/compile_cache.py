@@ -120,8 +120,8 @@ def key_on_metadata() -> None:
     call it: one set of a BERT-base step's entries is 131 MiB, and a
     192 MiB cache that alternates between two versions then compiles
     every run (PERF.md, PR 24); whoever profiles a training step by
-    scope sets the option for that run (`benchmarks/trace_run.py`
-    does)."""
+    scope sets the option for that run (`benchmarks/kinds/train.py`
+    does, for a traced run's step)."""
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import shard
+from . import decoder as _decoder
 from .common import ParamStore, Params, layer_norm as _ln_named, gelu
 
 
@@ -41,6 +42,11 @@ class GPTConfig:
     @property
     def head_dim(self):
         return self.hidden // self.heads
+
+    def serve_model(self) -> "GPTServe":
+        """This configuration behind the interface the decode engine
+        drives (models/decoder.py)."""
+        return GPTServe(self)
 
     def train_flops_per_token(self, seq_len: int) -> float:
         H, M, L = self.hidden, self.mlp_dim, self.layers
@@ -125,7 +131,7 @@ def _head(params: Params, x, prev_ids, eos_id: int):
     rows `x` [N, H]; `prev_ids` [N] are the tokens that led to them."""
     x = _ln_named(params, "ln_f", x)
     logits = x @ params["wte.w"].T.astype(x.dtype)
-    return _beam_top1(prev_ids.astype(jnp.int32), logits, eos_id)
+    return _decoder.beam_top1(prev_ids.astype(jnp.int32), logits, eos_id)
 
 
 def _attention(lp, x, cfg: GPTConfig, mesh=None):
@@ -273,15 +279,21 @@ def apply(params: Params, cfg: GPTConfig, ids: jax.Array,
 # the engine) and sample through ops/beam.beam_search with beam_size=1:
 # greedy selection with the beam op's finished-freeze semantics, so a
 # slot whose previous token is end_id keeps emitting end_id without any
-# host-side branching. MoE configs are refused by the engine (expert
-# dispatch needs its own decode kernel — ROADMAP item 4).
+# host-side branching.
+#
+# The programs themselves are `models/decoder.py`'s, shared with every
+# model the engine serves; GPT-2 hands them its block's pieces as a
+# `ServeModel` (`GPTConfig.serve_model()`), and the `apply_*` functions
+# below are those programs with GPT-2's pieces. A Switch top-1 config
+# (`n_experts > 0`) is refused there: `_moe_mlp` drops tokens over an
+# expert's capacity, so a row's result depends on what shares its batch.
 #
 # Where the pools live. A pool is `[L, NB, BS, heads*head_dim]`
 # (kv_cache.KVCacheConfig.pool_shape): the last dimension fills whole
 # lanes, BS fills the sublanes, so the TPU stores it as written and a
 # block is contiguous. All four programs run ONE layer loop,
-# `_serve_layers`, which holds `(h, k_pool, v_pool)` in the scan's CARRY
-# (the stacked weights and the layer index are its `xs`): a layer
+# `decoder.serve_layers`, which holds `(h, k_pool, v_pool)` in the scan's
+# CARRY (the stacked weights and the layer index are its `xs`): a layer
 # writes `pool.at[l, blk, slot].set(kv)` and reads
 # `pool[l, block_tables]` in the donated buffer itself. The pools must
 # not be the scan's `xs`/`ys`: a scan's `ys` is a new stacked buffer, so
@@ -293,268 +305,73 @@ def apply(params: Params, cfg: GPTConfig, ids: jax.Array,
 # and re-lays it out; the engine makes none.
 # ---------------------------------------------------------------------------
 
-
-def _beam_top1(prev_ids: jax.Array, logits: jax.Array,
-               eos_id: int) -> jax.Array:
-    """Greedy next-token selection through the beam_search op (K=1).
-    prev_ids [S] int32, logits [S, vocab] → [S] int32."""
-    from ..ops.beam import beam_search
-
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    out = beam_search(
-        {"pre_ids": [prev_ids[:, None].astype(jnp.int32)],
-         "pre_scores": [jnp.zeros((logp.shape[0], 1), jnp.float32)],
-         "scores": [logp[:, None, :]]},
-        {"beam_size": 1, "end_id": int(eos_id), "is_accumulated": True},
-        None)
-    return out["selected_ids"][:, 0].astype(jnp.int32)
-
-
 @jax.named_scope("mlp")
 def _decode_mlp(lp, x):
     h = gelu(x @ lp["blk.w1"].astype(x.dtype) + lp["blk.b1"].astype(x.dtype))
     return h @ lp["blk.w2"].astype(x.dtype) + lp["blk.b2"].astype(x.dtype)
 
 
-def _serve_layers(params: Params, x: jax.Array, k_pool: jax.Array,
-                  v_pool: jax.Array, attend):
-    """The serve programs' layer loop: `x` through every block with the
-    pools in the loop's carry. `attend(l, q, k, v, kp, vp)` is the one
-    part the programs differ in: it gets the layer index, the layer's
-    projections (x's leading shape, `[..., hidden]` each) and the WHOLE
-    pools, writes k/v at (l, block, slot), and returns
-    `(ctx [..., hidden], kp, vp)`. Returns (x, k_pool, v_pool)."""
+class GPTServe(_decoder.ServeModel):
+    """GPT-2's block for the serve programs: learned positions added at
+    the embedding, LayerNorm, fused QKV with bias, dense GELU MLP, the
+    tied embedding as the head."""
 
-    def layer_body(carry, per_layer):
-        h, kp, vp = carry
-        lp, l = per_layer
-        y = _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
-        q, k, v = _qkv(lp, y)
-        ctx, kp, vp = attend(l, q, k, v, kp, vp)
-        h = _proj(lp, ctx, h)
-        y = _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
-        h = h + _decode_mlp(lp, y)
-        return (h, kp, vp), None
+    def __init__(self, cfg: GPTConfig):
+        self.cfg = cfg
+        self.layers, self.heads = cfg.layers, cfg.heads
+        self.head_dim = cfg.head_dim
+        self.vocab_size, self.max_len = cfg.vocab_size, cfg.max_len
+        if cfg.n_experts:
+            self.refusal = (
+                "MoE decode is unsupported for models/gpt.py: its Switch "
+                "top-1 router drops tokens over an expert's capacity, so "
+                "a row's result depends on what shares its batch — serve "
+                "a dense config, or a dropless model (models/olmoe.py)")
 
-    layers = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
-    with jax.named_scope("layers"):
-        (x, k_pool, v_pool), _ = jax.lax.scan(
-            layer_body, (x, k_pool, v_pool),
-            (_layer_params(params), layers))
-    return x, k_pool, v_pool
+    def layer_params(self, params):
+        return _layer_params(params)
 
+    def embed(self, params, ids, positions):
+        return params["wte.w"][ids] + params["wpe.w"][positions]
 
-def apply_prefill(params: Params, cfg: GPTConfig, ids: jax.Array,
-                  length: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                  block_table: jax.Array, *, block_size: int,
-                  eos_id: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One prompt through the stack, filling its KV blocks.
+    def norm_attn(self, lp, h):
+        return _ln(h, lp["blk.ln1.scale"], lp["blk.ln1.bias"])
 
-    ids [1, T] (edge-padded to the prefill bucket T), length = true
-    prompt length, block_table [MB] (the sequence's row). Returns
-    (first sampled token [1], k_pool, v_pool). Padded tail positions
-    write to the null block / soon-overwritten slots (see
-    kv_cache.write_prefill_kv) and, being causally AFTER every real
-    position, never contribute to the last real position's logits.
-    """
-    from ..ops.pallas import attention as pa
-    from ..serving import kv_cache as kvc
+    def qkv(self, lp, y, positions):
+        return _qkv(lp, y)
 
-    B, T = ids.shape
-    nh, hd = cfg.heads, cfg.head_dim
-    adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
-    with jax.named_scope("embed"):
-        x = (params["wte.w"][ids] + params["wpe.w"][:T][None]).astype(adt)
+    def proj(self, lp, ctx, res):
+        return _proj(lp, ctx, res)
 
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_prefill_kv(kp, l, k[0].reshape(T, *stored),
-                                  block_table, block_size)
-        vp = kvc.write_prefill_kv(vp, l, v[0].reshape(T, *stored),
-                                  block_table, block_size)
-        q = q.reshape(B, T, nh, hd)
-        k = k.reshape(B, T, nh, hd)
-        v = v.reshape(B, T, nh, hd)
-        with jax.named_scope("attention"):
-            ctx = pa.mha(q, k, v, causal=True, scale=1.0 / math.sqrt(hd))
-        return ctx.reshape(B, T, cfg.hidden), kp, vp
+    def norm_mlp(self, lp, h):
+        return _ln(h, lp["blk.ln2.scale"], lp["blk.ln2.bias"])
 
-    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
-    # LayerNorm is per row: the last real position alone goes through it
-    last = jnp.maximum(length, 1) - 1
-    tok = _head(params, x[0, last][None], ids[0, last][None], eos_id)
-    return tok, k_pool, v_pool
+    def mlp(self, lp, y, params, l):
+        return _decode_mlp(lp, y), None
+
+    def head(self, params, x, prev_ids, eos_id):
+        return _head(params, x, prev_ids, eos_id)
 
 
-def apply_decode_step(params: Params, cfg: GPTConfig, ids: jax.Array,
-                      positions: jax.Array, k_pool: jax.Array,
-                      v_pool: jax.Array, block_tables: jax.Array, *,
-                      block_size: int, eos_id: int
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step for S resident slots.
-
-    ids [S] (each slot's previous token), positions [S] (where this
-    token's K/V lands = current sequence length), block_tables [S, MB].
-    Every row's math touches only that row's activations and its own
-    blocks, so a slot's tokens are bit-identical whatever else shares
-    the batch — the property test_decode's admit-mid-decode test pins.
-    Returns (next tokens [S], k_pool, v_pool)."""
-    from ..serving import kv_cache as kvc
-
-    S = ids.shape[0]
-    nh, hd = cfg.heads, cfg.head_dim
-    adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
-    with jax.named_scope("embed"):
-        x = (params["wte.w"][ids] + params["wpe.w"][positions]).astype(adt)
-
-    scale = 1.0 / math.sqrt(hd)
-
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_token_kv(kp, l, k.reshape(S, *stored), block_tables,
-                                positions, block_size)
-        vp = kvc.write_token_kv(vp, l, v.reshape(S, *stored), block_tables,
-                                positions, block_size)
-        keys = kvc.gather_kv(kp, l, block_tables)       # [S, M, *stored]
-        vals = kvc.gather_kv(vp, l, block_tables)
-        m = keys.shape[1]
-        q = q.reshape(S, nh, hd)
-        keys = keys.reshape(S, m, nh, hd)
-        vals = vals.reshape(S, m, nh, hd)
-        with jax.named_scope("attention"):
-            scores = jnp.einsum("snd,smnd->snm", q, keys) * scale
-            mask = jnp.arange(m, dtype=jnp.int32)[None, :] \
-                <= positions[:, None]
-            scores = jnp.where(mask[:, None, :], scores, -1e9)
-            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("snm,smnd->snd", att.astype(adt), vals)
-        return ctx.reshape(S, cfg.hidden), kp, vp
-
-    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
-    return _head(params, x, ids, eos_id), k_pool, v_pool
+def apply_prefill(params: Params, cfg: GPTConfig, *args, **kw):
+    """`decoder.prefill` with GPT-2's block."""
+    return _decoder.prefill(cfg.serve_model(), params, *args, **kw)
 
 
-def apply_prefill_chunk(params: Params, cfg: GPTConfig, ids: jax.Array,
-                        start: jax.Array, length: jax.Array,
-                        k_pool: jax.Array, v_pool: jax.Array,
-                        block_table: jax.Array, *, block_size: int,
-                        eos_id: int
-                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One fixed-size SLICE of a prompt through the stack (chunked
-    prefill — serving/kv_reuse.py).
-
-    ids [1, C] = the tokens at positions start..start+C-1 (edge-padded
-    past `length`), start = the slice's first position, length = the
-    true prompt length. Writes the slice's K/V into the sequence's
-    blocks and attends gather-style over the block table with mask
-    `key_pos <= start + i`, so earlier slices' — and prefix-cache
-    reused blocks' — K/V participate exactly as in a whole-prompt
-    prefill. Per-position results are independent of where the chunk
-    boundaries fall (each row's math reads only pool state + its own
-    activations), which is what makes chunked == whole prefill and
-    reused == recomputed prefixes hold at the token level. Returns
-    (tok [1], k_pool, v_pool); tok is meaningful only on the slice
-    containing position length-1 (the scheduler ignores it earlier).
-    """
-    from ..serving import kv_cache as kvc
-
-    _, C = ids.shape
-    nh, hd = cfg.heads, cfg.head_dim
-    adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
-    pos = start + jnp.arange(C, dtype=jnp.int32)
-    # the final slice's padded tail can run past the positional table;
-    # clamp (those rows' outputs are never consumed, their KV lands in
-    # the null block / overwritten slots)
-    with jax.named_scope("embed"):
-        x = (params["wte.w"][ids[0]] +
-             params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
-
-    scale = 1.0 / math.sqrt(hd)
-
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_chunk_kv(kp, l, k.reshape(C, *stored), block_table,
-                                start, block_size)
-        vp = kvc.write_chunk_kv(vp, l, v.reshape(C, *stored), block_table,
-                                start, block_size)
-        keys = kvc.gather_kv(kp, l, block_table[None])[0]   # [M, *stored]
-        vals = kvc.gather_kv(vp, l, block_table[None])[0]
-        m = keys.shape[0]
-        q = q.reshape(C, nh, hd)
-        keys = keys.reshape(m, nh, hd)
-        vals = vals.reshape(m, nh, hd)
-        with jax.named_scope("attention"):
-            scores = jnp.einsum("cnd,mnd->cnm", q, keys) * scale
-            mask = jnp.arange(m, dtype=jnp.int32)[None, :] <= pos[:, None]
-            scores = jnp.where(mask[:, None, :], scores, -1e9)
-            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("cnm,mnd->cnd", att.astype(adt), vals)
-        return ctx.reshape(C, cfg.hidden), kp, vp
-
-    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
-    last = jnp.clip(length - 1 - start, 0, C - 1)
-    tok = _head(params, x[last][None], ids[0, last][None], eos_id)
-    return tok, k_pool, v_pool
+def apply_decode_step(params: Params, cfg: GPTConfig, *args, **kw):
+    """`decoder.decode_step` with GPT-2's block: (next tokens [S],
+    k_pool, v_pool); a dense block has no counters to return."""
+    return _decoder.decode_step(cfg.serve_model(), params, *args, **kw)[:3]
 
 
-def apply_verify_step(params: Params, cfg: GPTConfig, ids: jax.Array,
-                      positions: jax.Array, k_pool: jax.Array,
-                      v_pool: jax.Array, block_tables: jax.Array, *,
-                      block_size: int, eos_id: int
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Speculative verification: W = k+1 tokens per slot in ONE step
-    (serving/kv_reuse.py).
+def apply_prefill_chunk(params: Params, cfg: GPTConfig, *args, **kw):
+    """`decoder.prefill_chunk` with GPT-2's block."""
+    return _decoder.prefill_chunk(cfg.serve_model(), params, *args, **kw)
 
-    ids [S, W] = each slot's [last_token, d_1..d_k] (the previous real
-    token followed by the draft model's k proposals), positions [S] =
-    each slot's next KV write position. Row j writes its K/V at
-    position positions+j and attends `key_pos <= positions + j`, so
-    output j is bit-identical to the token a plain apply_decode_step
-    sequence would produce after feeding ids[:, :j+1] one at a time —
-    the exact greedy accept/reject in kv_reuse.accept_length compares
-    drafts against these outputs. Rejected positions' K/V stays in the
-    pool but is overwritten by the next real write before any mask
-    lets it be read (the standard paged-decode invariant). Sampling
-    routes through the same beam_search op as decode, so an eos in the
-    fed window freezes the remaining outputs to eos. Returns
-    (tokens [S, W], k_pool, v_pool)."""
-    from ..serving import kv_cache as kvc
 
-    S, W = ids.shape
-    nh, hd = cfg.heads, cfg.head_dim
-    adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
-    pos = positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
-    with jax.named_scope("embed"):
-        x = (params["wte.w"][ids] +
-             params["wpe.w"][jnp.minimum(pos, cfg.max_len - 1)]).astype(adt)
-
-    scale = 1.0 / math.sqrt(hd)
-
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_span_kv(kp, l, k.reshape(S, W, *stored), block_tables,
-                               positions, block_size)
-        vp = kvc.write_span_kv(vp, l, v.reshape(S, W, *stored), block_tables,
-                               positions, block_size)
-        keys = kvc.gather_kv(kp, l, block_tables)       # [S, M, *stored]
-        vals = kvc.gather_kv(vp, l, block_tables)
-        m = keys.shape[1]
-        q = q.reshape(S, W, nh, hd)
-        keys = keys.reshape(S, m, nh, hd)
-        vals = vals.reshape(S, m, nh, hd)
-        with jax.named_scope("attention"):
-            scores = jnp.einsum("swnd,smnd->swnm", q, keys) * scale
-            mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
-                <= pos[:, :, None]
-            scores = jnp.where(mask[:, :, None, :], scores, -1e9)
-            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("swnm,smnd->swnd", att.astype(adt), vals)
-        return ctx.reshape(S, W, cfg.hidden), kp, vp
-
-    x, k_pool, v_pool = _serve_layers(params, x, k_pool, v_pool, attend)
-    tokens = _head(params, x.reshape(S * W, cfg.hidden), ids.reshape(S * W),
-                   eos_id).reshape(S, W)
-    return tokens, k_pool, v_pool
+def apply_verify_step(params: Params, cfg: GPTConfig, *args, **kw):
+    """`decoder.verify_step` with GPT-2's block."""
+    return _decoder.verify_step(cfg.serve_model(), params, *args, **kw)
 
 
 def lm_loss(params: Params, cfg: GPTConfig, batch: Dict[str, jax.Array],

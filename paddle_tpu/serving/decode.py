@@ -305,7 +305,8 @@ class _Pending:
     """One in-flight decode step: the lazy token fetch plus the exact
     batch composition it was dispatched with."""
 
-    __slots__ = ("handle", "tok_dev", "snapshot", "slots", "t_dispatch")
+    __slots__ = ("handle", "tok_dev", "snapshot", "slots", "t_dispatch",
+                 "stats")
 
     def __init__(self, handle, tok_dev, snapshot, slots):
         self.handle = handle
@@ -313,23 +314,30 @@ class _Pending:
         self.snapshot = snapshot               # tuple of rids (padded -1)
         self.slots = slots                     # list of Optional[_Request]
         self.t_dispatch = time.perf_counter()
+        # recording on, and the model counts: (the step's record, its
+        # counters still on the device)
+        self.stats = None
 
 
 class DecodeEngine:
     """Continuous-batching token generation over a paged KV cache.
 
-    Built from in-memory model state: `params`/`model_cfg` from
-    `models.gpt` (dense configs only). `submit()` is thread-safe and
-    reject-not-block (QueueFullError when `max_queue` prompts wait);
+    Built from in-memory model state: `params` and a `model_cfg` whose
+    `serve_model()` is the `models/decoder.ServeModel` of its block
+    (`models.gpt` dense configs, `models.olmoe`). `submit()` is
+    thread-safe and reject-not-block (QueueFullError when `max_queue` prompts wait);
     one scheduler thread owns the device pools, the allocator, and
     every phase dispatch."""
 
     def __init__(self, params, model_cfg, config: Optional[DecodeConfig]
                  = None, draft=None):
-        from ..models import gpt as _gpt
+        from ..models import decoder as _decoder
 
         self.config = config or DecodeConfig()
         self.model_cfg = model_cfg
+        # the model as the engine drives it (models/decoder.ServeModel):
+        # the cache's shape and the block's pieces of the four programs
+        self._model = model = model_cfg.serve_model()
         _cc.key_on_metadata()   # the phase grid's scopes are read in profiles
         self.prefill_chunk = int(getattr(self.config, "prefill_chunk",
                                          0))
@@ -355,10 +363,10 @@ class DecodeEngine:
         self.params = {
             k: _precision.cast_floating(v, self._compute_dtype)
             for k, v in params.items()}
-        max_len = int(self.config.max_len or model_cfg.max_len)
+        max_len = int(self.config.max_len or model.max_len)
         self.kv_cfg = KVCacheConfig(
-            layers=model_cfg.layers, kv_heads=model_cfg.heads,
-            head_dim=model_cfg.head_dim, max_len=max_len,
+            layers=model.layers, kv_heads=model.kv_heads,
+            head_dim=model.head_dim, max_len=max_len,
             block_size=self.config.block_size,
             num_blocks=self.config.num_blocks,
             dtype=str(np.dtype(self._compute_dtype)))
@@ -379,16 +387,18 @@ class DecodeEngine:
         self._draft = draft
         self._draft_params = None
         self._draft_cfg = None
+        self._draft_model = None
         self._draft_kv_cfg = None
         if draft is not None:
             draft_params, draft_cfg = draft
             self._draft_cfg = draft_cfg
+            self._draft_model = dmodel = draft_cfg.serve_model()
             self._draft_params = {
                 k: _precision.cast_floating(v, self._compute_dtype)
                 for k, v in draft_params.items()}
             self._draft_kv_cfg = KVCacheConfig(
-                layers=draft_cfg.layers, kv_heads=draft_cfg.heads,
-                head_dim=draft_cfg.head_dim, max_len=max_len,
+                layers=dmodel.layers, kv_heads=dmodel.kv_heads,
+                head_dim=dmodel.head_dim, max_len=max_len,
                 block_size=self.config.block_size,
                 num_blocks=self.config.num_blocks,
                 dtype=str(np.dtype(self._compute_dtype)))
@@ -399,18 +409,18 @@ class DecodeEngine:
             else self.config.precision
 
         def _prefill_fn(p, ids, length, kp, vp, bt):
-            return _gpt.apply_prefill(p, model_cfg, ids, length, kp, vp,
-                                      bt, block_size=bs,
-                                      eos_id=self.eos_id)
+            return _decoder.prefill(model, p, ids, length, kp, vp, bt,
+                                    block_size=bs, eos_id=self.eos_id)
 
+        # (tokens, k_pool, v_pool, the model's per-layer counters or None)
         def _decode_fn(p, ids, positions, kp, vp, bts):
-            return _gpt.apply_decode_step(p, model_cfg, ids, positions,
-                                          kp, vp, bts, block_size=bs,
-                                          eos_id=self.eos_id)
+            return _decoder.decode_step(model, p, ids, positions, kp, vp,
+                                        bts, block_size=bs,
+                                        eos_id=self.eos_id)
 
         def _chunk_fn(p, ids, start, length, kp, vp, bt):
-            return _gpt.apply_prefill_chunk(
-                p, model_cfg, ids, start, length, kp, vp, bt,
+            return _decoder.prefill_chunk(
+                model, p, ids, start, length, kp, vp, bt,
                 block_size=bs, eos_id=self.eos_id)
 
         # chunked prefill COLLAPSES the prompt-length bucket dimension:
@@ -441,26 +451,24 @@ class DecodeEngine:
         self._draft_decode: Dict[int, _JitDispatch] = {}
         self._verify: Dict[int, _JitDispatch] = {}
         if draft is not None:
-            dcfg = self._draft_cfg
-
             def _dprefill_fn(p, ids, length, kp, vp, bt):
-                return _gpt.apply_prefill(p, dcfg, ids, length, kp, vp,
-                                          bt, block_size=bs,
-                                          eos_id=self.eos_id)
+                return _decoder.prefill(dmodel, p, ids, length, kp, vp,
+                                        bt, block_size=bs,
+                                        eos_id=self.eos_id)
 
             def _ddecode_fn(p, ids, positions, kp, vp, bts):
-                return _gpt.apply_decode_step(
-                    p, dcfg, ids, positions, kp, vp, bts, block_size=bs,
-                    eos_id=self.eos_id)
+                return _decoder.decode_step(
+                    dmodel, p, ids, positions, kp, vp, bts, block_size=bs,
+                    eos_id=self.eos_id)[:3]
 
             def _dchunk_fn(p, ids, start, length, kp, vp, bt):
-                return _gpt.apply_prefill_chunk(
-                    p, dcfg, ids, start, length, kp, vp, bt,
+                return _decoder.prefill_chunk(
+                    dmodel, p, ids, start, length, kp, vp, bt,
                     block_size=bs, eos_id=self.eos_id)
 
             def _verify_fn(p, ids, positions, kp, vp, bts):
-                return _gpt.apply_verify_step(
-                    p, model_cfg, ids, positions, kp, vp, bts,
+                return _decoder.verify_step(
+                    model, p, ids, positions, kp, vp, bts,
                     block_size=bs, eos_id=self.eos_id)
 
             if self.prefill_chunk:
@@ -597,6 +605,9 @@ class DecodeEngine:
         # cannot give
         self._step_starts: "collections.deque[float]" = \
             collections.deque(maxlen=257)
+        # what the model counted in the last recorded decode step
+        # (`ServeModel.step_facts`), for status()
+        self._step_facts: Optional[Dict] = None
         self._counts = {k: 0 for k in
                         ("eos", "length", "rejected", "cancelled",
                          "error", "preempted")}
@@ -623,11 +634,9 @@ class DecodeEngine:
                 severity=sev, pass_name="decode_config", message=msg,
                 var=var))
 
-        kv, mc = self.kv_cfg, self.model_cfg
-        if getattr(mc, "n_experts", 0):
-            add(_an.ERROR, "MoE decode is unsupported: the paged decode "
-                "step has no expert-dispatch path (ROADMAP item 4) — "
-                "serve a dense config")
+        kv, mc = self.kv_cfg, self._model
+        if mc.refusal:
+            add(_an.ERROR, mc.refusal)
         if kv.usable_blocks < kv.max_blocks_per_seq:
             add(_an.ERROR,
                 f"KV pool cannot hold ONE full sequence: "
@@ -643,8 +652,8 @@ class DecodeEngine:
                 "preemptions under full-length load", var="num_blocks")
         if kv.max_len > mc.max_len:
             add(_an.ERROR,
-                f"max_len {kv.max_len} exceeds the model's positional "
-                f"table ({mc.max_len})", var="max_len")
+                f"max_len {kv.max_len} exceeds the positions the model "
+                f"addresses ({mc.max_len})", var="max_len")
         if not (-1 <= self.eos_id < mc.vocab_size):
             add(_an.ERROR,
                 f"eos_id {self.eos_id} outside vocab [0, "
@@ -673,8 +682,8 @@ class DecodeEngine:
                     "bucket set fails that request — extend "
                     "prefill_buckets to max_len if preemptions are "
                     "expected", var="prefill_buckets")
-        if self._draft_cfg is not None:
-            dc = self._draft_cfg
+        if self._draft_model is not None:
+            dc = self._draft_model
             if dc.vocab_size != mc.vocab_size:
                 add(_an.ERROR,
                     f"draft vocab_size {dc.vocab_size} != target "
@@ -685,9 +694,8 @@ class DecodeEngine:
                     f"draft max_len {dc.max_len} < serving max_len "
                     f"{kv.max_len}: the draft runs every position the "
                     "target does", var="draft")
-            if getattr(dc, "n_experts", 0):
-                add(_an.ERROR, "MoE draft is unsupported (same "
-                    "constraint as the target model)", var="draft")
+            if dc.refusal:
+                add(_an.ERROR, f"draft model: {dc.refusal}", var="draft")
         if self.spec_k and self.spec_k >= kv.max_len:
             add(_an.ERROR, f"spec_k {self.spec_k} >= max_len "
                 f"{kv.max_len}", var="spec_k")
@@ -951,10 +959,10 @@ class DecodeEngine:
                 f"prompt length {prompt.size} exceeds the largest "
                 f"prefill bucket {self.prefill_buckets[-1]}")
         if int(prompt.min()) < 0 or \
-                int(prompt.max()) >= self.model_cfg.vocab_size:
+                int(prompt.max()) >= self._model.vocab_size:
             raise ValueError(
                 f"prompt token ids must be in [0, "
-                f"{self.model_cfg.vocab_size})")
+                f"{self._model.vocab_size})")
         room = self.kv_cfg.max_len - int(prompt.size)
         if room < 1:
             raise ValueError(
@@ -1136,6 +1144,7 @@ class DecodeEngine:
             "kv": self._alloc.stats(live_tokens=live_tokens),
             "requests": counts,
             "step_ms": self._step_ms(),
+            "step_facts": self._step_facts,
         }
         if self._qos is not None:
             out["qos"] = {
@@ -1257,14 +1266,23 @@ class DecodeEngine:
                 "preemptions": req.preempted, "tenant": req.tenant})
 
     def _step_record(self, kind: str, t: float, slots: int, live: int,
-                     live_tokens: int):
+                     live_tokens: int) -> Dict:
         """One row per dispatched program (recording on): what ran, how
         full it was, and the allocator's own count of blocks."""
-        _tracing.add_record("decode.steps", {
-            "t": t, "kind": kind, "slots": slots, "live": live,
-            "live_tokens": live_tokens,
-            "blocks_used": self._alloc.used_blocks(),
-            "blocks_usable": self.kv_cfg.usable_blocks})
+        row = {"t": t, "kind": kind, "slots": slots, "live": live,
+               "live_tokens": live_tokens,
+               "blocks_used": self._alloc.used_blocks(),
+               "blocks_usable": self.kv_cfg.usable_blocks}
+        _tracing.add_record("decode.steps", row)
+        return row
+
+    def _note_step_stats(self, row: Dict, stats) -> None:
+        """Recording on: what the model counted in the decode step that
+        `row` records (an expert layer's `experts_hit`,
+        `expert_load_max`) joins the row, fetched once the step's
+        tokens are on the host."""
+        self._step_facts = self._model.step_facts(jax.device_get(stats))
+        row.update(self._step_facts)
 
     def _kv_gauges(self):
         KV_BLOCKS.set(self._alloc.used_blocks(), state="used")
@@ -1528,8 +1546,8 @@ class DecodeEngine:
             positions[i] = req.pos
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
-        tok, kp, vp = self._decode[C](self.params, ids_arg, positions,
-                                      kp, vp, bts)
+        tok, kp, vp, stats = self._decode[C](self.params, ids_arg,
+                                             positions, kp, vp, bts)
         self._pools = (kp, vp)
         for req in slots:
             if req is not None:
@@ -1541,7 +1559,12 @@ class DecodeEngine:
                            slots)
         self._step_starts.append(pending.t_dispatch)
         if sp is not None:
-            self._close_dispatch(sp, "decode", C, slots)
+            row = self._close_dispatch(sp, "decode", C, slots)
+            if stats is not None:
+                # on their way to the host while the step runs on
+                for a in jax.tree_util.tree_leaves(stats):
+                    a.copy_to_host_async()
+                pending.stats = (row, stats)
         return pending
 
     def _resolve(self, pending: _Pending) -> None:
@@ -1557,6 +1580,8 @@ class DecodeEngine:
         now = time.perf_counter()
         if wait is not None:
             wait.close()
+        if pending.stats is not None:
+            self._note_step_stats(*pending.stats)
         wall = now - pending.t_dispatch
         STEP_SECONDS.observe(wall)
         # live-MFU sample: the slot-config executable's retained FLOPs
@@ -1926,8 +1951,8 @@ class DecodeEngine:
         t0 = time.perf_counter()
         self._step_starts.append(t0)
         kp, vp = self._pools
-        tok, kp, vp = self._decode[C](self.params, ids, positions, kp,
-                                      vp, bts)
+        tok, kp, vp, stats = self._decode[C](self.params, ids, positions,
+                                             kp, vp, bts)
         self._pools = (kp, vp)
         if self._draft is not None:
             self._draft_catch_up()
@@ -1936,10 +1961,12 @@ class DecodeEngine:
                 self._draft_params, ids, positions, dkp, dvp, bts)
             self._draft_pools = (dkp, dvp)
             STEPS.inc(phase="draft")
-        res, wait = self._sync_resolve_spans(sp, "decode", C, slots)
+        res, wait, row = self._sync_resolve_spans(sp, "decode", C, slots)
         toks = np.asarray(tok)                 # synchronous resolve
         if wait is not None:
             wait.close()
+        if row is not None and stats is not None:
+            self._note_step_stats(row, stats)
         wall = time.perf_counter() - t0
         STEP_SECONDS.observe(wall)
         STEPS.inc(phase="decode")
@@ -1966,24 +1993,26 @@ class DecodeEngine:
         if res is not None:
             res.close(tokens=emitted, finished=finished)
 
-    def _close_dispatch(self, sp, kind: str, C: int, slots):
-        """Recording on: the step's record and the end of its open
-        decode.dispatch span `sp`."""
+    def _close_dispatch(self, sp, kind: str, C: int, slots) -> Dict:
+        """Recording on: the step's record (returned) and the end of its
+        open decode.dispatch span `sp`."""
         live = [r for r in slots if r is not None]
         tokens = sum(r.pos for r in live)
-        self._step_record(kind, sp.t0, C, len(live), tokens)
+        row = self._step_record(kind, sp.t0, C, len(live), tokens)
         sp.close(slots=C, live=len(live), live_tokens=tokens,
                  blocks_used=self._alloc.used_blocks())
+        return row
 
     def _sync_resolve_spans(self, sp, kind: str, C: int, slots):
         """A synchronous round has dispatched: close its decode.dispatch
         span `sp` and open the (decode.resolve, decode.resolve.wait) pair
-        that follows it; (None, None) with recording off."""
+        that follows it, with the step's record; (None, None, None) with
+        recording off."""
         if sp is None:
-            return None, None
-        self._close_dispatch(sp, kind, C, slots)
+            return None, None, None
+        row = self._close_dispatch(sp, kind, C, slots)
         return (_tracing.open_span("decode.resolve", "decode"),
-                _tracing.open_span("decode.resolve.wait", "decode"))
+                _tracing.open_span("decode.resolve.wait", "decode"), row)
 
     def _draft_catch_up(self):
         """After a fully-accepted spec round the draft's KV trails the
@@ -2070,7 +2099,7 @@ class DecodeEngine:
                                        kp, vp, bts)
         self._pools = (kp, vp)
         STEPS.inc(phase="verify")
-        res, wait = self._sync_resolve_spans(sp, "verify", C, slots)
+        res, wait, _ = self._sync_resolve_spans(sp, "verify", C, slots)
         outs = np.asarray(vtok)                # [C, k+1]
         if wait is not None:
             wait.close()

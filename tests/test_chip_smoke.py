@@ -133,6 +133,25 @@ def test_rehearse_serve(smoke):
     assert info["checked"]["prefix_hits"] >= 1
 
 
+def test_rehearse_serve_olmoe(smoke):
+    """The serve_olmoe phase at a tiny size: OLMoE through the same
+    engine and front, its tokens against the benchmark's plain reference."""
+    from paddle_tpu.models import olmoe
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = olmoe.OlmoeConfig.tiny()
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 15, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(8, 16), max_len=32), prompts, max_new=4,
+        logit_tol=smoke.OLMOE_LOGIT_TOL, model=olmoe,
+        reference_gaps=smoke._olmoe_reference_gaps)
+    assert info["checked"]["finished"]["length"] == 3
+    assert info["checked"]["compiles_after_warmup"] == 0
+
+
 # -- compile cache placement -------------------------------------------------
 
 

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import optax
 import pytest
 
-from paddle_tpu.models import bert, gpt
+from paddle_tpu.models import bert, decoder, gpt, olmoe
 
 LAYER = {"ln", "qkv", "attention", "proj", "mlp"}
 SERVE = LAYER | {"embed", "layers", "head", "kv_write"}
@@ -67,6 +67,56 @@ def test_serving_step_functions_carry_every_scope(tiny_gpt, kind, extra):
     text = _lower_serve(kind, *tiny_gpt).compile().as_text()
     missing = (SERVE | extra) - _scopes(text)
     assert not missing, (kind, missing)
+
+
+# OLMoE's own parts nest INSIDE the shared names, so that a reduction by
+# the innermost of the harness's fixed scopes lands the expert layer under
+# `mlp` and RoPE / QK-norm under `qkv`
+OLMOE_NESTED = {"mlp": {"router", "moe_route", "experts"},
+                "qkv": {"qk_norm", "rope"}}
+
+
+@pytest.fixture(scope="module")
+def tiny_olmoe():
+    cfg = olmoe.OlmoeConfig.tiny()
+    cfg.dtype = "float32"
+    params, _ = olmoe.init(jax.random.key(0), cfg)
+    pool = jnp.zeros((cfg.layers, NB, BS, cfg.heads * cfg.head_dim))
+    return cfg, params, pool
+
+
+def _lower_olmoe(kind, cfg, params, pool):
+    sm = cfg.serve_model()
+    kw = dict(block_size=BS, eos_id=1)
+    i32 = jnp.int32
+    slots = (jnp.zeros((S,), i32), pool, pool, jnp.zeros((S, MB), i32))
+    one = (pool, pool, jnp.zeros((MB,), i32))
+    fn, args = {
+        "decode": (decoder.decode_step, (jnp.zeros((S,), i32),) + slots),
+        "verify": (decoder.verify_step, (jnp.zeros((S, 3), i32),) + slots),
+        "prefill": (decoder.prefill, (jnp.zeros((1, 32), i32), i32(5)) + one),
+        "chunk": (decoder.prefill_chunk,
+                  (jnp.zeros((1, 16), i32), i32(0), i32(5)) + one)}[kind]
+    return jax.jit(lambda p, *a: fn(sm, p, *a, **kw)).lower(params, *args)
+
+
+@pytest.mark.parametrize("kind,extra", [
+    ("decode", {"kv_gather"}), ("verify", {"kv_gather"}),
+    ("chunk", {"kv_gather"}), ("prefill", set())])
+def test_olmoe_serve_programs_carry_every_scope(tiny_olmoe, kind, extra):
+    text = _lower_olmoe(kind, *tiny_olmoe).compile().as_text()
+    nested = set().union(*OLMOE_NESTED.values())
+    missing = (SERVE | extra | nested) - _scopes(text)
+    assert not missing, (kind, missing)
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        path = op_name.split("/")[:-1]
+        for outer, inner in OLMOE_NESTED.items():
+            for name in inner & set(path):
+                assert outer in path[:path.index(name)], op_name
+    # and the norms are `ln`'s, not the QK-norm's: some op sits directly
+    # under ln inside the layer loop
+    assert any(re.search(r"/layers/.*/ln/[^/]+$", n)
+               for n in re.findall(r'op_name="([^"]*)"', text))
 
 
 def test_gpt_training_forward_carries_the_scopes(tiny_gpt):

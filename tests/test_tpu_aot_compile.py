@@ -255,3 +255,79 @@ def test_gpt2_large_decode_step_at_32_slots_leaves_room_on_v5e(gpt2_large):
     planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                + ma.output_size_in_bytes - ma.alias_size_in_bytes)
     assert planned < 9e9, ma
+
+
+# ---------------------------------------------------------------------------
+# OLMoE-1B-7B at the benchmark's cut (8 layers, 16 slots x 1024 tokens): the
+# expert stacks [8,64,2048,1024] reach the grouped-matmul kernel whole. As
+# the layer scan's xs each layer's slice was a copy for the kernel's sake:
+# 0.8 GB of temporaries, written and read again every layer of every step.
+# The gate of ops/pallas/grouped_matmul.py asks where the program will run;
+# here that is a described chip, so the test answers for it.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def olmoe_8l(v5e):
+    from paddle_tpu.models import olmoe
+    from paddle_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = olmoe.OlmoeConfig(layers=8, max_len=_CONTEXT)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = jax.eval_shape(lambda k: olmoe.init(k, cfg)[0],
+                            jax.random.key(0))
+    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    kv = KVCacheConfig(layers=cfg.layers, kv_heads=cfg.heads,
+                       head_dim=cfg.head_dim, max_len=_CONTEXT,
+                       block_size=_BLOCK,
+                       num_blocks=16 * (_CONTEXT // _BLOCK) + 1)
+    return cfg, params, sds(kv.pool_shape, jnp.dtype(kv.dtype)), sds
+
+
+@pytest.mark.parametrize("program", ["decode@16", "prefill@256"])
+def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
+        olmoe_8l, program, monkeypatch):
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    gm.GATE_COUNTS.clear()
+    cfg, params, pool, sds = olmoe_8l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((16,), np.int32), sds((16,), np.int32), pool, pool,
+            sds((16, _CONTEXT // _BLOCK), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, int(n)), np.int32), sds((), np.int32), pool, pool,
+            sds((_CONTEXT // _BLOCK,), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4)).lower(params, *args).compile()
+    assert pool.shape == (8, 1025, 16, 2048)
+    ma = compiled.memory_analysis()
+    # weights 7.13 GB + pools 1.07 GB resident, the rest temporaries
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert 8.0e9 < planned < 10e9, ma
+    assert ma.temp_size_in_bytes < 0.2e9, ma
+    assert ma.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2, ma
+    text = compiled.as_text()
+    assert not _pool_movers(text, pool.shape)
+    # no op makes a layer's slice of an expert stack: [64,2048,1024] or
+    # [64,1024,2048] appears nowhere as a result
+    slices = re.findall(r"= \(?bf16\[64,(?:2048,1024|1024,2048)\]", text)
+    assert not slices, slices[:3]
+    # the three grouped matmuls are the megablox kernel, and the profile
+    # will find them under mlp/experts
+    assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    assert len(kernels) == 3 and all(
+        "/mlp/experts/" in k for k in kernels), kernels
