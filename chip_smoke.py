@@ -13,7 +13,8 @@ interpreter fallback anywhere below, and no phase's exception is passed
 over. Numbers printed here are information, not benchmark results.
 
     python chip_smoke.py            one chip: device, program, train,
-                                    long_seq, serve
+                                    long_seq, serve, serve_reuse,
+                                    serve_olmoe, paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
                                     one device, then the dp/tp/sp/pp/ep
@@ -327,10 +328,12 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
     import numpy as np
 
     from paddle_tpu.models import gpt
+    from paddle_tpu.ops.pallas import paged_attention
     from paddle_tpu.serving import Server, ServingConfig
     from paddle_tpu.serving.decode import DecodeEngine
 
     params, _ = _init(model or gpt, cfg)
+    paged_attention.GATE_COUNTS.clear()
     engine = DecodeEngine(params, cfg, decode_cfg)
     n_phases = len(engine.decode_slots) + len(engine.prefill_buckets)
     ready, compile_s = _timed(engine.warmup)
@@ -379,6 +382,7 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
                  "max_new_tokens": max_new, "phases_warmed": ready,
                  "compiles_after_warmup": late_compiles,
                  "finished": status["requests"],
+                 "decode_attention": status["decode_attention"],
                  "ref_exact_tokens": f"{exact}/{len(prompts) * max_new}",
                  "ref_max_logit_gap": round(gap, 5),
                  "ref_logit_tol": logit_tol})
@@ -393,9 +397,11 @@ def reuse_phase(info: dict, cfg, decode_cfg, prompt, max_new: int) -> dict:
     import jax
 
     from paddle_tpu.models import gpt
+    from paddle_tpu.ops.pallas import paged_attention
     from paddle_tpu.serving.decode import DecodeEngine
 
     params, _ = _init(gpt, cfg)
+    paged_attention.GATE_COUNTS.clear()
     engine = DecodeEngine(params, cfg, decode_cfg)
     ready, compile_s = _timed(engine.warmup)
     assert ready == 1 + len(engine.decode_slots), ready
@@ -408,7 +414,8 @@ def reuse_phase(info: dict, cfg, decode_cfg, prompt, max_new: int) -> dict:
             timeout_s=600)
         run_s = time.perf_counter() - t0
         late_compiles = _COUNTS["compile_requests"] - after_warmup
-        kv = engine.status()["kv"]
+        status = engine.status()
+        kv = status["kv"]
     finally:
         engine.stop()
     assert len(first) == max_new and first == second, (first, second)
@@ -421,7 +428,78 @@ def reuse_phase(info: dict, cfg, decode_cfg, prompt, max_new: int) -> dict:
                          "prefix_hits": kv["prefix_hits_total"],
                          "blocks_reused": kv["blocks_reused_total"],
                          "reused_equals_recomputed": True,
+                         "decode_attention": status["decode_attention"],
                          "compiles_after_warmup": late_compiles})
+    return info
+
+
+def paged_attention_phase(info: dict, heads: int, head_dim: int,
+                          layers: int, slots: int = 16,
+                          table_blocks: int = 64, tol: float = 0.05,
+                          interpret=False) -> dict:
+    """The paged decode-attention kernel against the gather path
+    (`decoder.gather_attention`) on one device: unit-normal noise in q and
+    in every slot of both pools, scattered tables, lengths from one token
+    to a full table with block and chunk edges and inactive slots among
+    them, every layer of the pool. `tol` bounds the largest absolute
+    difference of a live slot's context: both routes weigh in bf16 and
+    round the result to bf16 (2^-8 of values up to about 4), the gather
+    path also rounds its scores. `interpret` is for the rehearsal off
+    the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    bs = 16
+    full = table_blocks * bs
+    lens = [min(n, full) for n in (0, 1, bs, bs + 1, full, 300, 640, 255,
+                                   256, 257, 0, 5, 900, 511, 512, 513)]
+    lens = lens[:slots]
+    assert len(lens) == slots, f"at most {len(lens)} slots"
+    blocks = 1 + slots * table_blocks
+    keys = jax.random.split(jax.random.key(SEED + 3), 3)
+    shape = (layers, blocks, bs, heads * head_dim)
+    k_pool = jax.random.normal(keys[0], shape, jnp.bfloat16)
+    v_pool = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    q = jax.random.normal(keys[2], (slots, heads * head_dim), jnp.bfloat16)
+    rng = np.random.RandomState(SEED)
+    free = list(rng.permutation(np.arange(1, blocks)))
+    tables = np.zeros((slots, table_blocks), np.int32)
+    for s, n in enumerate(lens):
+        tables[s, :-(-n // bs)] = [free.pop() for _ in range(-(-n // bs))]
+    tables = jnp.asarray(tables)
+    positions = jnp.asarray([max(n - 1, 0) for n in lens], jnp.int32)
+
+    def over_layers(attend):
+        return jax.jit(lambda q, kp, vp: jax.lax.map(
+            lambda l: attend(q, kp, vp, l, tables, positions),
+            jnp.arange(layers, dtype=jnp.int32)))
+
+    paged = over_layers(lambda *a: pa.paged_attention(
+        *a, heads=heads, interpret=interpret))
+    gather = over_layers(lambda *a: decoder.gather_attention(*a, heads))
+    (got, want), compile_s = _timed(
+        lambda: (paged(q, k_pool, v_pool), gather(q, k_pool, v_pool)))
+    _, paged_s = _timed(lambda: paged(q, k_pool, v_pool))
+    _, gather_s = _timed(lambda: gather(q, k_pool, v_pool))
+    live = np.asarray(lens) > 0
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    diff = np.abs(got - want)[:, live]
+    assert np.isfinite(got).all()
+    assert diff.max() <= tol, (
+        f"the kernel's context leaves the gather path's by up to "
+        f"{diff.max():.4f} (tolerance {tol}) at {heads} heads of {head_dim}")
+    info.update(compile_s=round(compile_s, 2),
+                run_s=round(paged_s + gather_s, 4),
+                checked={"heads": heads, "head_dim": head_dim,
+                         "layers": layers, "lens": lens,
+                         "max_abs_diff": float(f"{diff.max():.3g}"),
+                         "tol": tol,
+                         "paged_ms": round(1e3 * paged_s, 3),
+                         "gather_ms": round(1e3 * gather_s, 3)})
     return info
 
 
@@ -522,12 +600,15 @@ def run_one_chip() -> None:
         serve_phase(info, cfg, DecodeConfig(
             block_size=16, num_blocks=16 * 64 + 1, decode_slots=(8, 16)),
             prompts, max_new=24, logit_tol=0.25)
+        # both decode programs read the live blocks through the table
+        assert info["checked"]["decode_attention"] == {"paged": 2}, info
 
     with phase("serve_reuse") as info:
         reuse_phase(info, cfg, DecodeConfig(
             block_size=16, num_blocks=4 * 64 + 1, decode_slots=(4,),
             prefill_chunk=128, prefix_cache=True),
             rng.randint(0, cfg.vocab_size, 300).tolist(), max_new=16)
+        assert info["checked"]["decode_attention"] == {"paged": 1}, info
 
     # OLMoE-1B-7B at its published widths (2048 wide, 16 heads of 128, 64
     # experts of 1024, 8 a token, vocab 50304), 2 of its 16 layers so that
@@ -545,6 +626,13 @@ def run_one_chip() -> None:
             prefill_buckets=(64, 128, 256)), prompts, max_new=24,
             logit_tol=OLMOE_LOGIT_TOL, model=olmoe,
             reference_gaps=_olmoe_reference_gaps)
+        assert info["checked"]["decode_attention"] == {"paged": 1}, info
+
+    # the kernel against the gather path where it runs, at the benchmark's
+    # two widths: GPT-2-large's 20 heads of 64, OLMoE's 16 of 128
+    for heads, head_dim, layers in ((20, 64, 4), (16, 128, 4)):
+        with phase(f"paged_attention_{heads}x{head_dim}") as info:
+            paged_attention_phase(info, heads, head_dim, layers)
 
 
 def run_four_chips() -> None:

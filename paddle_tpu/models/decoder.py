@@ -181,6 +181,34 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     return tok, k_pool, v_pool
 
 
+def gather_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                     layer: jax.Array, block_tables: jax.Array,
+                     positions: jax.Array, heads: int) -> jax.Array:
+    """Decode attention over a gathered copy of every slot's whole table:
+    q `[S, heads*head_dim]` against layer `layer` of the pools through
+    block_tables `[S, MB]`, key positions `<= positions[s]` -> `[S,
+    heads*head_dim]`. The route off the TPU, and what the paged kernel
+    (ops/pallas/paged_attention.py) is compared with on it."""
+    from ..serving import kv_cache as kvc
+
+    S = q.shape[0]
+    hd = q.shape[1] // heads
+    keys = kvc.gather_kv(k_pool, layer, block_tables)   # [S, M, *stored]
+    vals = kvc.gather_kv(v_pool, layer, block_tables)
+    m = keys.shape[1]
+    q = q.reshape(S, heads, hd)
+    keys = keys.reshape(S, m, heads, hd)
+    vals = vals.reshape(S, m, heads, hd)
+    with jax.named_scope("attention"):
+        scores = jnp.einsum("snd,smnd->snm", q, keys) * (1.0 / math.sqrt(hd))
+        mask = jnp.arange(m, dtype=jnp.int32)[None, :] \
+            <= positions[:, None]
+        scores = jnp.where(mask[:, None, :], scores, -1e9)
+        att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        ctx = jnp.einsum("snm,smnd->snd", att.astype(k_pool.dtype), vals)
+    return ctx.reshape(S, heads * hd)
+
+
 def decode_step(model: ServeModel, params: Params, ids: jax.Array,
                 positions: jax.Array, k_pool: jax.Array,
                 v_pool: jax.Array, block_tables: jax.Array, *,
@@ -194,36 +222,33 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     the batch — the property test_decode's admit-mid-decode test pins.
     Returns (next tokens [S], k_pool, v_pool, the layers' stacked
     counters or None: `ServeModel.mlp`)."""
+    from ..ops.pallas import paged_attention as pa
     from ..serving import kv_cache as kvc
 
     S = ids.shape[0]
-    nh, hd = model.heads, model.head_dim
+    nh = model.heads
     adt = k_pool.dtype
     stored = k_pool.shape[3:]     # how the pool stores one token
     with jax.named_scope("embed"):
         x = model.embed(params, ids, positions).astype(adt)
 
-    scale = 1.0 / math.sqrt(hd)
+    # the one gate (ops/pallas/paged_attention.py): on a TPU the kernel
+    # reads the live blocks through the table; elsewhere the gather below
+    paged = pa.use_paged(x, k_pool, nh)
+    pa.GATE_COUNTS["paged" if paged else "gather"] += 1
 
     def attend(l, q, k, v, kp, vp):
         kp = kvc.write_token_kv(kp, l, k.reshape(S, *stored), block_tables,
                                 positions, block_size)
         vp = kvc.write_token_kv(vp, l, v.reshape(S, *stored), block_tables,
                                 positions, block_size)
-        keys = kvc.gather_kv(kp, l, block_tables)       # [S, M, *stored]
-        vals = kvc.gather_kv(vp, l, block_tables)
-        m = keys.shape[1]
-        q = q.reshape(S, nh, hd)
-        keys = keys.reshape(S, m, nh, hd)
-        vals = vals.reshape(S, m, nh, hd)
-        with jax.named_scope("attention"):
-            scores = jnp.einsum("snd,smnd->snm", q, keys) * scale
-            mask = jnp.arange(m, dtype=jnp.int32)[None, :] \
-                <= positions[:, None]
-            scores = jnp.where(mask[:, None, :], scores, -1e9)
-            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("snm,smnd->snd", att.astype(adt), vals)
-        return ctx.reshape(S, nh * hd), kp, vp
+        if paged:
+            with jax.named_scope("attention"):
+                ctx = pa.paged_attention(q, kp, vp, l, block_tables,
+                                         positions, heads=nh)
+        else:
+            ctx = gather_attention(q, kp, vp, l, block_tables, positions, nh)
+        return ctx, kp, vp
 
     x, k_pool, v_pool, stats = serve_layers(model, params, x, positions,
                                             k_pool, v_pool, attend)

@@ -62,6 +62,7 @@ from ..observability import metrics as _m
 from ..observability import perfwatch as _perfwatch
 from ..observability import telemetry as _telemetry
 from ..observability import tracing as _tracing
+from ..ops.pallas import paged_attention as _paged_attention
 from .batcher import QueueFullError, ServerClosed
 from .kv_cache import (BlockAllocator, KVCacheConfig, NoBlocksError,
                        build_block_table, init_pools)
@@ -1145,6 +1146,10 @@ class DecodeEngine:
             "requests": counts,
             "step_ms": self._step_ms(),
             "step_facts": self._step_facts,
+            # which route decode attention took, a count a traced decode
+            # program of this process ("paged": the kernel over the live
+            # blocks; "gather": the padded gather)
+            "decode_attention": dict(_paged_attention.GATE_COUNTS),
         }
         if self._qos is not None:
             out["qos"] = {

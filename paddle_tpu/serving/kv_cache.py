@@ -17,10 +17,16 @@ fixed no matter which sequences are resident.
 Layering: this module owns the host-side `BlockAllocator` (free-list,
 alloc/free, fragmentation accounting) and the pure jnp pool helpers
 (`init_pools`, `write_token_kv`, `write_prefill_kv`, `write_chunk_kv`,
-`write_span_kv`, `gather_kv`) that `models/gpt.py` composes into its
-decode-step attention; they take the whole pool and a layer index, and
-the layer loop carries the pools and addresses them in place. The
-scheduler that decides WHICH sequences own which blocks lives in
+`write_span_kv`, `gather_kv`) that `models/decoder.py` composes into
+the serve programs; they take the whole pool and a layer index, and
+the layer loop carries the pools and addresses them in place. Who still
+composes `gather_kv` (a padded copy of every slot's whole table, a
+layer): `prefill_chunk` and `verify_step` everywhere, and `decode_step`
+off the TPU (`decoder.gather_attention`); on a TPU `decode_step` reads
+the live blocks through the table in a kernel
+(`ops/pallas/paged_attention.py`), which relies on this layout: a block
+is `BS` whole sublane tiles of `heads*head_dim` lanes. The scheduler
+that decides WHICH sequences own which blocks lives in
 `serving/decode.py`.
 
 Block 0 is reserved as the *null block*: padded/inactive decode slots
@@ -173,7 +179,7 @@ class BlockAllocator:
 #
 # Every helper takes the WHOLE pool `[L, NB, BS, *tok]` and a (traced)
 # layer index, and addresses it in place by (layer, block, slot): the
-# layer loop of models/gpt.py holds the pools in its carry, so a write
+# layer loop of models/decoder.py holds the pools in its carry, so a write
 # is one scatter into the donated buffer and no layer's slice is ever
 # taken out or put back. `tok` = `pool.shape[3:]` is how one token is
 # stored: `[kv_heads*head_dim]` in the engine's pools

@@ -69,6 +69,35 @@ def test_serving_step_functions_carry_every_scope(tiny_gpt, kind, extra):
     assert not missing, (kind, missing)
 
 
+def test_the_paged_kernel_sits_under_the_attention_scope(monkeypatch):
+    """On a TPU the decode program's attention is one Mosaic kernel
+    (ops/pallas/paged_attention.py); lowered for that platform, its
+    custom call carries `attention/paged_attention`, so a profile's
+    reduction by scope finds the kernel's time where the gather path's
+    einsums were, and `kv_gather` has nothing left under it."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg = gpt.GPTConfig(vocab_size=97, hidden=128, layers=2, heads=2,
+                        mlp_dim=256, max_len=64, dtype="float32")
+    i32 = jnp.int32
+    # conftest's x64 mode off, as the program runs: under it a kernel's
+    # index arithmetic turns i64, which Mosaic refuses
+    with jax.enable_x64(False):
+        params, _ = gpt.init(jax.random.key(0), cfg)
+        pool = jnp.zeros((cfg.layers, NB, BS, cfg.heads * cfg.head_dim))
+        text = jax.jit(lambda p, *a: decoder.decode_step(
+            cfg.serve_model(), p, *a, block_size=BS, eos_id=1)).trace(
+            params, jnp.zeros((S,), i32), jnp.zeros((S,), i32), pool, pool,
+            jnp.zeros((S, MB), i32)).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    calls = re.findall(r"@tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)
+    assert len(calls) == 1, calls
+    name = re.search(rf'^{calls[0]} = loc\("([^"]*)"', text, re.M).group(1)
+    assert name.startswith("attention/paged_attention/"), name
+    assert "kv_gather" not in text
+
+
 # OLMoE's own parts nest INSIDE the shared names, so that a reduction by
 # the innermost of the harness's fixed scopes lands the expert layer under
 # `mlp` and RoPE / QK-norm under `qkv`

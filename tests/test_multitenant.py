@@ -277,10 +277,14 @@ def test_batcher_queue_full_bronze_arrival_is_its_own_victim():
         assert ei.value.tier == "bronze"
         assert ei.value.tenant == "noisy"
         gate.set()
+        # the submitting threads store their results after the batcher
+        # answers: wait for both (stop() does not join them)
+        _wait_for(lambda: isinstance(results.get(0), dict)
+                  and isinstance(results.get(1), dict),
+                  msg="both vip requests to finish")
     finally:
         gate.set()
         b.stop()
-    assert isinstance(results[0], dict) and isinstance(results[1], dict)
 
 
 def test_batcher_quota_caps_concurrent_footprint():

@@ -1,0 +1,249 @@
+"""The paged decode-attention kernel (ops/pallas/paged_attention.py) against
+the mathematics of the gather path it replaces in `decoder.decode_step`, on
+noise-filled pools, through the Pallas TPU interpreter on the CPU: the
+kernel's constructs (scalar prefetch, DMAs out of an HBM pool into VMEM
+buffers, DMA semaphores, scratch that outlives a grid step) all run there.
+What the interpreter cannot see (tiling, VMEM, what the compiler plans for
+the pools) is `tests/test_tpu_aot_compile.py`'s, and the chip itself is
+`chip_smoke.py`'s `paged_attention` phase.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.models import decoder, gpt
+from paddle_tpu.ops.pallas import attention as A
+from paddle_tpu.ops.pallas import paged_attention as PA
+from paddle_tpu.serving import kv_cache as kvc
+
+L, S, MB, BS = 3, 5, 20, 16          # a full table: 320 tokens, two chunks
+NB = 1 + S * MB
+WIDTHS = [(20, 64), (16, 128)]       # GPT-2-large's heads, OLMoE's
+# tokens each slot attends (0: an inactive slot, its table all null)
+PATTERNS = {
+    "inactive": [0, 0, 0, 0, 0],
+    "one-token": [1, 1, 1, 1, 1],
+    "block-edge": [16, 17, 15, 32, 33],
+    "chunk-edge": [256, 257, 255, 16, 1],
+    "full-table": [MB * BS] * S,
+    "mixed": [0, 1, 300, 17, MB * BS],
+}
+
+
+def gather_math(q, k_pool, v_pool, layer, tables, positions, heads):
+    """decode_step's gather path, in float32 throughout."""
+    s, hd = q.shape
+    d = hd // heads
+    keys = kvc.gather_kv(k_pool, layer, tables).astype(jnp.float32)
+    vals = kvc.gather_kv(v_pool, layer, tables).astype(jnp.float32)
+    m = keys.shape[1]
+    scores = jnp.einsum("snd,smnd->snm",
+                        q.astype(jnp.float32).reshape(s, heads, d),
+                        keys.reshape(s, m, heads, d),
+                        precision="highest") / np.sqrt(d)
+    mask = jnp.arange(m)[None, :] <= positions[:, None]
+    att = jax.nn.softmax(jnp.where(mask[:, None, :], scores, -1e9), axis=-1)
+    return jnp.einsum("snm,smnd->snd", att, vals.reshape(s, m, heads, d),
+                      precision="highest").reshape(s, hd)
+
+
+def tables_for(lens, rng):
+    """Block tables over scattered blocks, and positions, for slots that
+    attend `lens` tokens."""
+    tables = np.zeros((S, MB), np.int32)
+    positions = np.zeros((S,), np.int32)
+    free = list(rng.permutation(np.arange(1, NB)))
+    for s, n in enumerate(lens):
+        for b in range(-(-n // BS)):
+            tables[s, b] = free.pop()
+        positions[s] = max(n - 1, 0)
+    return jnp.asarray(tables), jnp.asarray(positions)
+
+
+@pytest.fixture(scope="module", params=WIDTHS, ids=lambda w: f"{w[0]}x{w[1]}")
+def width(request):
+    """Noise in every slot of every block, the null block included, and
+    the kernel jitted once a width."""
+    heads, d = request.param
+    rng = np.random.default_rng(heads)
+    k_pool, v_pool = (jnp.asarray(
+        rng.standard_normal((L, NB, BS, heads * d)), jnp.bfloat16)
+        for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((S, heads * d)), jnp.bfloat16)
+    run = jax.jit(lambda q, kp, vp, l, t, p: PA.paged_attention(
+        q, kp, vp, l, t, p, heads=heads,
+        interpret=pltpu.InterpretParams()))
+    return heads, q, k_pool, v_pool, run
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("pattern", list(PATTERNS))
+def test_kernel_matches_the_gather_paths_mathematics(width, pattern, layer):
+    heads, q, k_pool, v_pool, run = width
+    lens = PATTERNS[pattern]
+    tables, positions = tables_for(lens, np.random.default_rng(layer))
+    out = np.asarray(run(q, k_pool, v_pool, jnp.int32(layer), tables,
+                         positions).astype(jnp.float32))
+    ref = np.asarray(gather_math(q, k_pool, v_pool, layer, tables,
+                                 positions, heads))
+    live = np.asarray(lens) > 0
+    # bf16 weights and a bf16 result over unit-normal values: 2^-8 of 3.5
+    assert np.abs(out[live] - ref[live]).max(initial=0.0) < 0.03
+    assert not out[~live].any()      # an inactive slot reads nothing
+
+
+def test_garbage_past_the_length_does_not_show(width):
+    """Slots past a sequence's length in its last block, every block it
+    does not own and the null block hold huge values; the result is
+    bit-identical to the one over a pool that holds zeros there."""
+    heads, q, k_pool, v_pool, run = width
+    lens = [17, 1, 250, 33, 0]
+    tables, positions = tables_for(lens, np.random.default_rng(5))
+    own = np.zeros((NB, BS), bool)
+    for s, n in enumerate(lens):
+        for t in range(n):
+            own[int(tables[s, t // BS]), t % BS] = True
+    keep = jnp.asarray(own)[None, :, :, None]
+    outs = []
+    for junk in (0.0, 3e4):
+        kp, vp = (jnp.where(keep, p, jnp.asarray(junk, p.dtype))
+                  for p in (k_pool, v_pool))
+        outs.append(np.asarray(run(q, kp, vp, jnp.int32(1), tables,
+                                   positions).astype(jnp.float32)))
+    assert np.isfinite(outs[1]).all()
+    np.testing.assert_array_equal(outs[0], outs[1])
+
+
+def test_a_slots_result_does_not_depend_on_its_neighbours(width):
+    """Row independence (`ServeModel`'s contract): slot 2 keeps its table
+    and length while every other slot's change, and where it sits among
+    them; its context is bit-identical."""
+    heads, q, k_pool, v_pool, run = width
+    rng = np.random.default_rng(9)
+    base_t, base_p = tables_for([0, 0, 273, 0, 0], rng)
+    row = None
+    for lens, at in (([0, 0, 273, 0, 0], 2), ([320, 1, 273, 17, 256], 2),
+                     ([16, 300, 273, 0, 5], 2), ([100, 0, 0, 31, 273], 4)):
+        others = [n if s != at else 0 for s, n in enumerate(lens)]
+        tables, positions = tables_for(others, np.random.default_rng(at))
+        tables = tables.at[at].set(base_t[2])
+        positions = positions.at[at].set(base_p[2])
+        qs = q.at[at].set(q[2])
+        out = np.asarray(run(qs, k_pool, v_pool, jnp.int32(2), tables,
+                             positions).astype(jnp.float32))[at]
+        if row is None:
+            row = out
+            assert np.abs(row).max() > 0
+        np.testing.assert_array_equal(out, row)
+
+
+# -- the gate -----------------------------------------------------------------
+
+
+def _pool(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("pool,heads,takes", [
+    (_pool((36, 1025, 16, 1280)), 20, True),          # GPT-2-large
+    (_pool((8, 1025, 16, 2048)), 16, True),           # OLMoE
+    (_pool((2, 9, 16, 128), jnp.float32), 2, True),
+    (_pool((2, 9, 16, 2, 64)), 2, False),             # a 5-D pool
+    (_pool((2, 9, 16, 64)), 2, False),                # H*D under a lane tile
+    (_pool((2, 9, 16, 128)), 4, False),               # 32-wide heads
+    (_pool((2, 9, 8, 128)), 2, False),                # half a bf16 tile a block
+], ids=["gpt2-large", "olmoe", "f32", "5d", "narrow", "head32", "bs8"])
+def test_gate_reads_the_route_from_the_platform_and_the_shapes(
+        monkeypatch, pool, heads, takes):
+    q = _pool((4, int(np.prod(pool.shape[3:]))), pool.dtype)
+    assert not PA.use_paged(q, pool, heads)     # here: the CPU
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    assert PA.use_paged(q, pool, heads) == takes
+
+
+def test_gate_stays_shut_under_a_mesh_that_would_partition_the_kernel(
+        monkeypatch):
+    from paddle_tpu.parallel import MeshConfig, make_mesh, mesh_guard
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    pool = _pool((2, 9, 16, 128))
+    with mesh_guard(make_mesh(MeshConfig(dp=-1), devices=jax.devices()[:2])):
+        assert not PA.use_paged(_pool((4, 128)), pool, 2)
+
+
+# -- in the decode program ----------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def small_gpt():
+    """Two heads of 64: the narrowest model the kernel takes."""
+    cfg = gpt.GPTConfig(vocab_size=97, hidden=128, layers=2, heads=2,
+                        mlp_dim=256, max_len=64, dtype="float32")
+    params, _ = gpt.init(jax.random.key(3), cfg)
+    rng = np.random.default_rng(3)
+    pools = tuple(jnp.asarray(rng.standard_normal((2, 17, 16, 128)),
+                              jnp.float32) for _ in range(2))
+    tables = np.zeros((4, 4), np.int32)
+    tables[0, :3], tables[1, :1], tables[3, :2] = (1, 2, 3), (4,), (5, 6)
+    return (cfg, params, jnp.asarray([5, 6, 0, 7], jnp.int32),
+            jnp.asarray([40, 0, 0, 16], jnp.int32), *pools,
+            jnp.asarray(tables))
+
+
+def _decode(small_gpt):
+    cfg, params, *args = small_gpt
+    return jax.jit(lambda p, *a: decoder.decode_step(
+        cfg.serve_model(), p, *a, block_size=16, eos_id=-1))(params, *args)
+
+
+def test_decode_step_takes_the_gather_path_off_the_tpu(small_gpt):
+    PA.GATE_COUNTS.clear()
+    _decode(small_gpt)
+    assert PA.GATE_COUNTS == {"gather": 1}
+
+
+def test_decode_step_through_the_kernel_agrees_with_the_gather_path(
+        small_gpt, monkeypatch):
+    """The whole decode program with the gate answered for (as the AOT
+    compile tests do) and the kernel interpreted: the live slots' tokens
+    and both pools equal the gather path's."""
+    import functools
+
+    toks, kp, vp, _ = _decode(small_gpt)
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    monkeypatch.setattr(PA, "paged_attention", functools.partial(
+        PA.paged_attention, interpret=pltpu.InterpretParams()))
+    PA.GATE_COUNTS.clear()
+    toks2, kp2, vp2, _ = _decode(small_gpt)
+    assert PA.GATE_COUNTS == {"paged": 1}
+    live = [0, 1, 3]
+    np.testing.assert_array_equal(np.asarray(toks)[live],
+                                  np.asarray(toks2)[live])
+    # layer 0's writes are the same; layer 1's see attention's rounding
+    np.testing.assert_array_equal(np.asarray(kp[0]), np.asarray(kp2[0]))
+    own = np.asarray(small_gpt[-1]).ravel()
+    own = own[own > 0]
+    np.testing.assert_allclose(np.asarray(kp)[:, own], np.asarray(kp2)[:, own],
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(vp)[:, own], np.asarray(vp2)[:, own],
+                               atol=1e-4)
+
+
+def test_engine_status_reports_the_route():
+    from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
+
+    cfg = gpt.GPTConfig.tiny()
+    params, _ = gpt.init(jax.random.key(0), cfg)
+    PA.GATE_COUNTS.clear()
+    engine = DecodeEngine(params, cfg, DecodeConfig(
+        block_size=8, num_blocks=17, decode_slots=(2,), prefill_buckets=(8,),
+        max_len=32))
+    try:
+        assert engine.submit([1, 2, 3], max_new_tokens=2).result(
+            timeout_s=120)
+        assert engine.status()["decode_attention"] == {"gather": 1}
+    finally:
+        engine.stop()

@@ -8,7 +8,10 @@ run — `chip_smoke.py` is the chip run.
 
 Kernels (~0.1-2 s each), and the serve programs of GPT-2-large (the
 benchmark's serving width, ~3 s each): what the compiler PLANS for the KV
-pool is the one thing about them a CPU run cannot show.
+pool is the one thing about them a CPU run cannot show. Since PR 28 the
+decode programs are compiled both ways: with the gather path the gate picks
+here, and with the gate answered for the described chip, where decode
+attention is the paged kernel of ops/pallas/paged_attention.py.
 """
 
 import os
@@ -74,7 +77,15 @@ def _fused(kernel):
     return lambda x, s, b, w: fn(x, s, b, w)
 
 
+def _paged(heads):
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    return lambda q, kp, vp, l, t, p: PA.paged_attention(
+        q, kp, vp, l, t, p, heads=heads)
+
+
 _QKV = "qkv"
+_PAGED = "paged"    # shape: (slots, table blocks, layers, heads, head_dim)
 _CASES = [
     # BERT long-seq cell (bs 8, T 4096, 12 heads of 64, bf16), full mask
     pytest.param(_splash(False, False), _QKV, (8, 4096, 12, 64),
@@ -96,6 +107,18 @@ _CASES = [
     # one ring shard's block (T 4096 over sp=2, 12 heads over tp=2)
     pytest.param(lambda q, k, v: A._splash_block_with_lse(q, k, v), _QKV,
                  (8, 2048, 6, 64), id="splash-block-lse-ringshard"),
+    # decode attention through the block table, alone: GPT-2-large's and
+    # OLMoE's widths at the benchmark's 16 slots x 1024 tokens; OLMoE's
+    # published 4096-token context at 32 slots; float32 pools (an engine
+    # with precision "f32"), whose buffers are twice as large
+    pytest.param(_paged(20), _PAGED, (16, 64, 36, 20, 64, jnp.bfloat16),
+                 id="paged-gpt2-large"),
+    pytest.param(_paged(16), _PAGED, (16, 64, 8, 16, 128, jnp.bfloat16),
+                 id="paged-olmoe"),
+    pytest.param(_paged(16), _PAGED, (32, 256, 4, 16, 128, jnp.bfloat16),
+                 id="paged-olmoe-4096x32"),
+    pytest.param(_paged(16), _PAGED, (16, 64, 4, 16, 128, jnp.float32),
+                 id="paged-olmoe-f32"),
 ] + [
     pytest.param(_fused(kernel), "xsbw", shape,
                  id=f"{kernel}-{'x'.join(map(str, shape))}")
@@ -113,6 +136,11 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
 
     if kind == _QKV:
         args = (sds(shape),) * 3
+    elif kind == _PAGED:
+        slots, blocks, layers, heads, d, dt = shape
+        pool = sds((layers, slots * blocks + 1, 16, heads * d), dt)
+        args = (sds((slots, heads * d), dt), pool, pool, sds((), jnp.int32),
+                sds((slots, blocks), jnp.int32), sds((slots,), jnp.int32))
     else:
         M, K, N = shape
         args = (sds((M, K)), sds((K,), jnp.float32),
@@ -229,6 +257,12 @@ def _pool_movers(text, pool_shape):
     return found
 
 
+def _kernels(text):
+    """The `op_name` of every Mosaic kernel in a compiled program."""
+    return re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+
+
 @pytest.mark.parametrize("program", ["decode@16", "prefill@512",
                                      "prefill@1024"])
 def test_gpt2_large_serve_program_addresses_the_pool_in_place(
@@ -246,10 +280,18 @@ def test_gpt2_large_serve_program_addresses_the_pool_in_place(
     assert not movers, movers
 
 
-def test_gpt2_large_decode_step_at_32_slots_leaves_room_on_v5e(gpt2_large):
+@pytest.mark.parametrize("route", ["gather", "paged"])
+def test_gpt2_large_decode_step_at_32_slots_leaves_room_on_v5e(
+        gpt2_large, route, monkeypatch):
     """Weights 1.55 + pools 6.04 + temporaries: 7.8 GB planned, half the
     chip (the parent planned 15.59 GB and left no room for a prefill)."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    if route == "paged":
+        monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    PA.GATE_COUNTS.clear()
     pool, compiled = _compile_decode(gpt2_large, 32)
+    assert PA.GATE_COUNTS == {route: 1}, PA.GATE_COUNTS
     assert pool.shape == (36, 2049, 16, 1280)
     ma = compiled.memory_analysis()
     planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
@@ -327,7 +369,55 @@ def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
     # the three grouped matmuls are the megablox kernel, and the profile
     # will find them under mlp/experts
     assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
-    kernels = re.findall(
-        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
-    assert len(kernels) == 3 and all(
-        "/mlp/experts/" in k for k in kernels), kernels
+    kernels = _kernels(text)
+    # (the decode program's fourth kernel is attention's: below)
+    assert sum("/mlp/experts/" in k for k in kernels) == 3 and all(
+        "/mlp/experts/" in k or "/attention/" in k for k in kernels), kernels
+
+
+# ---------------------------------------------------------------------------
+# Decode attention through the block table (PERF.md section 6, PR 28). With
+# the gate answered for the described chip the decode programs hold the
+# paged kernel where the gather path wrote `[S, 1024, H*D]` for K and for V
+# a layer and viewed it as heads (at 64-wide heads a second, padded copy).
+# The kernel takes the pools whole and by reference: a plan that grew by a
+# pool's size would mean the operand is copied.
+# ---------------------------------------------------------------------------
+
+_GATHERED = ("16,1024,20,64", "1024,16,1280", "16,1024,1280",
+             "1024,16,2048", "16,1024,2048", "16,1024,16,128")
+
+
+@pytest.mark.parametrize("family", ["gpt2_large", "olmoe_8l"])
+def test_decode_program_reads_the_live_blocks_through_the_table(
+        family, request, monkeypatch):
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    PA.GATE_COUNTS.clear()
+    if family == "gpt2_large":
+        pool, compiled = _compile_decode(
+            request.getfixturevalue("gpt2_large"), 16)
+    else:
+        cfg, params, pool, sds = request.getfixturevalue("olmoe_8l")
+        sm = cfg.serve_model()
+        compiled = jax.jit(
+            lambda p, *a: decoder.decode_step(sm, p, *a, block_size=_BLOCK,
+                                              eos_id=-1),
+            donate_argnums=(3, 4)).lower(
+            params, sds((16,), np.int32), sds((16,), np.int32), pool, pool,
+            sds((16, _CONTEXT // _BLOCK), np.int32)).compile()
+    assert PA.GATE_COUNTS == {"paged": 1}, PA.GATE_COUNTS
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 0.5e9, ma
+    assert ma.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2, ma
+    text = compiled.as_text()
+    assert not _pool_movers(text, pool.shape)
+    gathered = [d for d in re.findall(r"= \(?\w+\[([\d,]+)\]", text)
+                if d in _GATHERED]
+    assert not gathered, gathered[:3]
+    # one kernel a layer, and a profile finds it under `attention`
+    paged = [k for k in _kernels(text) if "paged_attention" in k]
+    assert len(paged) == 1 and "/layers/" in paged[0] \
+        and "/attention/" in paged[0], _kernels(text)
