@@ -167,7 +167,7 @@ def program_phase(info: dict, place, steps: int = 40) -> dict:
 
 
 def _bert_driver(cfg, batch_size, seq_len, mesh, deterministic):
-    """(state, step, batch) built exactly as bench.py's BERT cells build
+    """(state, step, batch) built as the benchmark's BERT cells build
     them: make_train_step + ZeRO-1 optimizer state under the mesh."""
     import jax
     import optax

@@ -54,7 +54,7 @@ from typing import Dict, List, Optional, Tuple
 __all__ = [
     "ENV_VAR", "level", "Lock", "RLock", "Condition", "DeadlockError",
     "set_ledger", "ledger_order", "observed_edges",
-    "observed_inversions", "deadlock_count", "note_held", "reset",
+    "observed_inversions", "deadlock_count", "reset",
 ]
 
 ENV_VAR = "PADDLE_TPU_LOCKCHECK"
@@ -134,18 +134,6 @@ def _get_metrics() -> Optional[dict]:
                 "Deadlock cycles detected (and broken) by DeadlockError"),
         }
     return _metrics
-
-
-def note_held(site: str, seconds: float, contended: bool = False):
-    """Record a held-span for a lock NOT built by these factories (the
-    cross-process tpu_lock file lease uses this so the single-flight
-    lock's hold time shows up in the same table)."""
-    m = _get_metrics()
-    if m is None:
-        return
-    m["held"].observe(seconds, site=site)
-    if contended:
-        m["contention"].inc(site=site)
 
 
 # ---------------------------------------------------------------------------

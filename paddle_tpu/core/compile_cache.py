@@ -87,10 +87,9 @@ _DEFAULT_MAX_ENTRIES = 512
 
 def place_jax_cache() -> str:
     """Place JAX's persistent compilation cache for this process; call
-    before the first compile (chip_smoke.py, bench.py's child mode and
-    serving/replica.py do, at the top). Where JAX_COMPILATION_CACHE_DIR
-    is set, JAX's own handling of it is all there is and no other
-    directory is set here. Where it is not, the cache goes to
+    before the first compile (chip_smoke.py and serving/replica.py do,
+    at the top). Where JAX_COMPILATION_CACHE_DIR is set, JAX's own
+    handling of it is all there is and no other directory is set here. Where it is not, the cache goes to
     `<checkout>/.jax_cache` — a fixed path, because the path is part of
     the cache's key and a directory that moves never hits. Programs that
     compile in under a second (most of the decode phase grid) are kept

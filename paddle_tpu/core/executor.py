@@ -765,10 +765,10 @@ class _CompiledStep:
         runs ~2x slower than the same conv inlined), trading compile
         time proportional to n_steps. The streaming driver uses it for
         its small windows. "auto" resolves per backend: unrolled on CPU
-        (up to _UNROLL_WINDOW_MAX — the rolled while-loop is the
-        BENCH_r05 2.6x per-step regression, reproduced by a pure-jax
-        control, so it is opt-in there), rolled elsewhere (one bounded
-        compile, no CPU penalty applies)."""
+        (up to _UNROLL_WINDOW_MAX — the rolled while-loop is slower
+        per step there, reproduced by a pure-jax control, so it is
+        opt-in), rolled elsewhere (one bounded compile, no CPU penalty
+        applies)."""
         if unroll == "auto":
             # resolve against the EXECUTING device's platform when the
             # caller supplies it (run_chained passes the place's) — a
@@ -869,11 +869,10 @@ class _CompiledStep:
     def _run_chained_windowed(self, scope: Scope, feed, rng,
                               n_steps: int, per_step_feeds: bool):
         """CPU fallback for big chained runs: XLA-CPU executes convs
-        inside a rolled while-loop ~2.6x slower than straight-line
-        code (BENCH_r05's scan-chained regression; a pure-jax
-        loop-vs-scan control reproduces it, so it is the backend, not
-        lost donation), so n_steps is split into <=_UNROLL_WINDOW_MAX
-        unrolled windows — identical sequential semantics and rng
+        inside a rolled while-loop slower than straight-line code (a
+        pure-jax loop-vs-scan control reproduces it, so it is the
+        backend, not lost donation), so n_steps is split into
+        <=_UNROLL_WINDOW_MAX unrolled windows — identical sequential semantics and rng
         stream, a handful of dispatches instead of one (dispatch
         overhead on CPU is microseconds)."""
         out_chunks: Optional[List[List[Any]]] = None
@@ -1091,9 +1090,9 @@ class Executor:
 
         `unroll` defaults to "auto": on CPU the scan body is unrolled
         (or, past _UNROLL_WINDOW_MAX steps, windowed into unrolled
-        chunks) because XLA-CPU runs the rolled while-loop ~2.6x slower
-        per step (BENCH_r05); on TPU/GPU it stays a rolled scan — ONE
-        dispatch, bounded compile time. Pass unroll=False explicitly to
+        chunks) because XLA-CPU runs the rolled while-loop slower per
+        step; on TPU/GPU it stays a rolled scan — ONE dispatch, bounded
+        compile time. Pass unroll=False explicitly to
         opt back into the rolled scan everywhere."""
         if int(n_steps) < 1:
             raise ValueError(f"run_chained needs n_steps >= 1, got "

@@ -263,21 +263,6 @@ class ReplicaSupervisor:
         self._set_gauges()
         return slot.endpoint
 
-    def kill_slot(self, slot_id: int) -> Optional[str]:
-        """SIGKILL one replica process (chaos hook for serve_bench
-        --fleet): no drain, no leave — exactly what a hardware loss
-        looks like. The monitor sees rc != 0 and respawns the slot.
-        Returns the killed endpoint."""
-        with self._lock:
-            slot = self._slots.get(slot_id)
-            if slot is None or slot.proc is None:
-                return None
-        try:
-            slot.proc.kill()
-        except OSError:
-            return None
-        return slot.endpoint
-
     # -- introspection -------------------------------------------------
 
     def endpoints(self, live_only: bool = True) -> List[str]:

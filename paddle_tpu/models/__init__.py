@@ -1,9 +1,10 @@
-"""Model zoo (the BASELINE.json config ladder).
+"""Model zoo.
 
 Two API levels:
 - JAX-native functional models (this package): pytree params with logical
   sharding axes (paddle_tpu.parallel.sharding), pure apply fns — the
-  performance path used by bench.py and __graft_entry__.py.
+  performance path used by benchmarks/, chip_smoke.py and
+  __graft_entry__.py.
 - Static-graph builders via paddle_tpu.layers for fluid-API parity live in
   each model file as `build_program_*` where applicable.
 

@@ -1,5 +1,5 @@
-"""LeNet / MNIST — the minimum end-to-end slice (BASELINE.json config 1;
-reference: python/paddle/fluid/tests/book/test_recognize_digits.py).
+"""LeNet / MNIST — the minimum end-to-end slice (reference:
+python/paddle/fluid/tests/book/test_recognize_digits.py).
 
 Provides BOTH API levels: `build_program` constructs the fluid-style static
 graph (exercising the Program IR path end-to-end), and init/apply give the

@@ -33,9 +33,9 @@ class ResNetConfig:
     # Pallas fused matmul+BN for the bottleneck 1x1 convs
     # (ops/pallas/fused_dense_bn.py): conv1/conv3 run as matmuls with BN
     # stats in the epilogue and the preceding BN-apply+relu in conv3's
-    # prologue — the byte-floor attack scoped by tools/rn50_bytes_table.py.
-    # Default OFF (the XLA path is the settled baseline); training-mode,
-    # single-device-or-manual-region only (pallas has no GSPMD rule).
+    # prologue. Default OFF (the XLA path is the settled baseline);
+    # training-mode, single-device-or-manual-region only (pallas has no
+    # GSPMD rule).
     fused_1x1: bool = False
 
     @staticmethod
@@ -48,8 +48,7 @@ class ResNetConfig:
 
     def flops_per_image(self, hw: int = 224) -> float:
         # RN50@224 fwd = 4.089 G multiply-accumulates = 8.18 GFLOPs (the
-        # often-quoted "4.1 GFLOPs" counts MACs; exact conv+head MAC sum
-        # in tools/rn50_roofline.py / PROFILE.md). x3 for training
+        # often-quoted "4.1 GFLOPs" counts MACs). x3 for training
         # (fwd + dgrad + wgrad). Width/resolution scale quadratically.
         base = 8.18e9 * (self.width / 64) ** 2 * (hw / 224) ** 2
         return 3 * base * (1 if self.depth == 50 else self.depth / 50)

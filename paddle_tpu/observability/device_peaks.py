@@ -1,12 +1,5 @@
-"""Per-device-kind peak-throughput table — the ONE MFU denominator.
-
-Before this module, the v5e peak lived hardcoded in three places
-(bench.py PEAK_FLOPS, tools/rn50_bytes_table.py PEAK_TF/PEAK_BW,
-tools/rn50_roofline.py) and a fourth consumer (the live
-`paddle_tpu_mfu` gauge, observability/perfwatch.py) was about to add
-one more. Bench-time MFU and serve-time MFU must divide by the SAME
-number or the acceptance comparison between them is meaningless, so
-the table lives here and everything imports it.
+"""Per-device-kind peak-throughput table: the denominator of the live
+`paddle_tpu_mfu` gauge (observability/perfwatch.py).
 
 Numbers are public per-chip peak dense bf16 matmul throughput, HBM
 bandwidth and capacity. `ici_bytes_per_s` is a one-direction aggregate
@@ -23,8 +16,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
-__all__ = ["DevicePeak", "PEAKS", "DEFAULT_PEAK", "PLATFORM_PEAK_FLOPS",
-           "lookup", "peak_flops", "platform_peak_flops"]
+__all__ = ["DevicePeak", "PEAKS", "DEFAULT_PEAK", "lookup", "peak_flops"]
 
 
 class DevicePeak(NamedTuple):
@@ -55,12 +47,6 @@ PEAKS = (
 # read as such instead of flattering anyone.
 DEFAULT_PEAK = DevicePeak(1e12, 100e9, 8e9, 10e9)
 
-# bench.py's historical platform-level map (it resolves by jax platform
-# string before any device_kind is known). tpu maps to the v5e figure —
-# the chip every BASELINE.json target is quoted for.
-PLATFORM_PEAK_FLOPS = {"tpu": 197e12, "cpu": 1e12, "gpu": 100e12}
-
-
 def lookup(device_kind: Optional[str]) -> DevicePeak:
     """Peak figures for a jax device_kind string (case-insensitive
     substring match); DEFAULT_PEAK when unknown."""
@@ -73,8 +59,3 @@ def lookup(device_kind: Optional[str]) -> DevicePeak:
 
 def peak_flops(device_kind: Optional[str]) -> float:
     return lookup(device_kind).flops
-
-
-def platform_peak_flops(platform: Optional[str]) -> float:
-    """bench.py's denominator: jax platform string -> peak FLOP/s."""
-    return PLATFORM_PEAK_FLOPS.get(platform or "", DEFAULT_PEAK.flops)

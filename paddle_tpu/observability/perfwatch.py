@@ -1,18 +1,15 @@
 """Live utilization attribution: windowed MFU + step-time breakdown.
 
-bench.py computes MFU offline (flops-per-sample x samples/s / peak) and
-prints it once; nothing answered "how fast is the hardware running RIGHT
-NOW" for a live trainer or serving replica. This module is the live
-counterpart: every hot dispatch path (Executor.run, SPMDRunner.run, the
-decode engine's prefill/decode steps) records one `record_step` per
+Answers "how fast is the hardware running RIGHT NOW" for a live trainer
+or serving replica: every hot dispatch path (Executor.run,
+SPMDRunner.run, the decode engine's prefill/decode steps) records one `record_step` per
 step, carrying the FLOPs its executable's cost_analysis() reported at
 compile time (retained per signature by core/executor._JitDispatch).
 A 60-second sliding window turns those into continuous gauges at
 scrape/snapshot time:
 
   paddle_tpu_mfu{kind}            windowed FLOP/s / (n_devices x peak),
-                                  peak from device_peaks.lookup() — the
-                                  SAME denominator bench.py divides by
+                                  peak from device_peaks.lookup()
   paddle_tpu_flops_per_sec{kind}  the numerator, for dashboards that
                                   want absolute throughput
   paddle_tpu_steps_per_sec{kind}  windowed step rate
@@ -59,8 +56,8 @@ MFU = _m.gauge(
     "Windowed model-FLOPs utilization by dispatch kind "
     "(step|chained|spmd|prefill|decode): cost_analysis() FLOPs summed "
     "over the last 60 s divided by elapsed time and the per-device-kind "
-    "peak (observability/device_peaks.py — the same denominator "
-    "bench.py uses). Decays to 0 when steps stop arriving",
+    "peak (observability/device_peaks.py). Decays to 0 when steps "
+    "stop arriving",
     labelnames=("kind",))
 FLOPS_PER_SEC = _m.gauge(
     "paddle_tpu_flops_per_sec",

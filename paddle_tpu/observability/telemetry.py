@@ -417,8 +417,8 @@ def record_chained_eviction():
 
 
 def host_blocked_total() -> float:
-    """Process-wide host-blocked seconds across every site — what
-    bench.py divides by wall time for the host-overlap fraction."""
+    """Process-wide host-blocked seconds across every site; over wall
+    time it is the host-overlap fraction."""
     return HOST_BLOCKED_SECONDS.total()
 
 

@@ -162,7 +162,7 @@ def parse_traceparent(value: Optional[str]) -> Optional[TraceContext]:
 def sample_rate() -> float:
     """Head-sampling probability from PADDLE_TPU_TRACE_SAMPLE (clamped
     to [0, 1]; unset/malformed = 0 = tracing off). Re-read per call so
-    an operator (or the serve_bench overhead A/B) can flip it live."""
+    an operator can flip it live."""
     raw = os.environ.get(TRACE_SAMPLE_ENV)
     if not raw:
         return 0.0

@@ -134,8 +134,7 @@ def _gate_allows(T: int) -> bool:
     # single-chip path is splash_attention (_use_splash, round 4 — tuned
     # blocks beat XLA bf16-scores 2.2x at T=4096), and long-context
     # *scaling* is exact ring attention over the 'sp' mesh axis
-    # (ops/pallas/ring_attention.py). Full tables: PROFILE.md rounds 3-4;
-    # re-measured on-chip each round by bench.py's bert_long config.
+    # (ops/pallas/ring_attention.py).
     del T
     return False
 
@@ -280,7 +279,7 @@ def _merge_causal(mask, T):
 # SplashAttention (the production TPU attention kernel shipped with jax)
 # ---------------------------------------------------------------------------
 
-# Measured on v5e (tools/attn_ab.py, fwd+bwd, bf16, 12 heads, head_dim 64):
+# Measured on v5e before PR 21 (fwd+bwd, bf16, 12 heads, head_dim 64):
 # splash with the block sizes below beats the XLA bf16-scores path for
 # T >= _SPLASH_MIN_T on full (bidirectional) masks and at every causal
 # shape — unlike the legacy flash_attention kernel, which never won.
@@ -318,7 +317,7 @@ def _splash_kernel(Tq: int, Tk: int, n_heads: int, causal: bool,
     # is cheap (lazy Full/Causal masks process block-wise in numpy).
     from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
-    # Block sizes tuned on v5e (tools/attn_ab.py, fwd+bwd, bf16, bs=8):
+    # Block sizes tuned on v5e before PR 21 (fwd+bwd, bf16, bs=8):
     # at T=4096 full-mask this config runs 17.0 ms vs 37.4 ms XLA
     # bf16-scores and 114 ms with the jax default all-128 blocks; at
     # T=8192 it is 56 ms where the XLA path cannot even compile (13 GB

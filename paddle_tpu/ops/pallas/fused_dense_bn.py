@@ -1,10 +1,9 @@
 """Fused matmul+BatchNorm building blocks for 1x1 convolutions.
 
-The ResNet-50 byte-floor analysis (PROFILE.md round 5,
-tools/rn50_bytes_table.py) shows BN passes are 44% of the training
-step's HBM traffic and the ONLY lever that reaches the >=0.40 MFU bar —
-XLA cannot fuse across the BN-stats reduction barrier. These kernels
-implement the forward half of that line-item for the 1x1 convs (2/3 of
+A per-tensor bytes model of the ResNet-50 training step (modelled,
+never measured) puts BN passes at the largest share of its HBM
+traffic, and XLA cannot fuse across the BN-stats reduction barrier.
+These kernels implement the forward half for the 1x1 convs (2/3 of
 ResNet-50's conv units; a 1x1 conv over NHWC is exactly a [B*H*W, Cin]
 @ [Cin, Cout] matmul):
 

@@ -12,7 +12,7 @@ Here the "box" is a host-side LRU over (table, id) -> row:
 
   pull_sparse : cache hits are served locally; misses fan out to the
                 sharded PS (ps/sparse_table.pull_rows) and populate the
-                LRU. Hit/miss counters expose the hit rate (BENCH_CTR).
+                LRU. Hit/miss counters expose the hit rate.
   push_sparse_grad : the SGD update is applied to the cached rows
                 immediately AND enqueued for a background flush thread
                 that batches pushes to the PS — the trainer never blocks
@@ -188,7 +188,7 @@ class BoxSparseCache:
                     key = (name, int(rid))
                     self._fetching[key] = self._fetching.get(key, 0) + 1
             # counters updated under the lock: concurrent trainer
-            # threads must not lose increments (stats drive BENCH_CTR)
+            # threads must not lose increments
             self.misses += len(miss_pos)
             self.hits += int(ids.size - len(miss_pos))
         if miss_pos:

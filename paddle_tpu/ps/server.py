@@ -358,10 +358,9 @@ class ParameterServer:
     def _np_fast_opt(self, od: dict, env: Dict[str, Any]) -> bool:
         """Pure-numpy fast path for the common optimize descs (sgd, adam,
         momentum) — mirrors ops/optimizer_ops.py exactly. The generic
-        per-desc jax-eager path costs ~1.3 ms per push in dispatch
-        overhead alone (tools/ctr_bench.py), which dominates the async
-        server's apply-per-arrival mode; numpy does the same math in the
-        memory-bound ~0.1 ms."""
+        per-desc jax-eager path pays a dispatch per push, which
+        dominates the async server's apply-per-arrival mode; numpy does
+        the same math memory-bound."""
         t = od["type"]
         if t not in ("sgd", "adam", "momentum"):
             return False

@@ -52,7 +52,7 @@ deployment surface in front of it:
 Telemetry flows through the PR 1/2 observability stack: queue depth,
 batch-size/queue-wait/end-to-end histograms, reject/timeout counters,
 per-bucket compile events — all visible on the /metrics endpoint and
-the JSONL event log. `tools/serve_bench.py` load-tests the whole path.
+the JSONL event log.
 """
 
 from .bucketing import BucketPolicy, common_batch  # noqa: F401

@@ -31,8 +31,7 @@ The gap between `high_load` and `low_load` is the hysteresis band: a
 fleet sitting anywhere inside it is left alone. Scale-out lands within
 seconds because replicas boot from the PR 6 warmstart artifact;
 scale-in is graceful because the supervisor SIGTERMs and the replica
-runs leave→drain→stop (zero dropped in-flight requests, tested by
-`serve_bench --fleet`).
+runs leave→drain→stop (zero dropped in-flight requests).
 
 Multi-model fleets (SERVING.md §Multi-tenancy) allocate replica counts
 per model by running one Autoscaler + ReplicaSupervisor pair per model

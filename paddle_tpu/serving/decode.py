@@ -137,9 +137,6 @@ class DecodeConfig:
     prefill_buckets: prompt-length buckets (pow2 from 8 up to max_len
     by default); a prompt pads to the smallest bucket that fits.
     num_blocks/block_size: the KV pool (block 0 is the null block).
-    static_batching=True turns the scheduler into the drain-between-
-    batches baseline (admit only into an EMPTY batch) — the A/B
-    `tools/serve_bench.py --tokens` measures against.
 
     KV-reuse knobs (SERVING.md §KV reuse): prefill_chunk > 0 replaces
     the prefill-bucket grid with ONE fixed-size chunk executable —
@@ -159,7 +156,6 @@ class DecodeConfig:
                  eos_id: Optional[int] = None,
                  max_queue: int = 64,
                  precision: str = "bf16",
-                 static_batching: bool = False,
                  warmstart: Optional[str] = None,
                  prefix_cache: bool = False,
                  prefill_chunk: int = 0,
@@ -176,7 +172,6 @@ class DecodeConfig:
         self.eos_id = eos_id
         self.max_queue = int(max_queue)
         self.precision = str(precision)
-        self.static_batching = bool(static_batching)
         self.warmstart = warmstart
         self.prefix_cache = bool(prefix_cache)
         self.prefill_chunk = int(prefill_chunk)
@@ -1136,7 +1131,6 @@ class DecodeEngine:
             "queue_depth": waiting,
             "active": active,
             "slot_config": self._last_slot_config,
-            "static_batching": self.config.static_batching,
             "precision": self.config.precision,
             "eos_id": self.eos_id,
             "warmed": self.warmed,
@@ -1358,8 +1352,6 @@ class DecodeEngine:
             with self._cv:
                 if not self._waiting or self._closed:
                     break
-                if self.config.static_batching and self._active:
-                    break  # drain-between-batches baseline
                 if len(self._active) >= max_slots:
                     break
                 idx = self._pick_waiting_locked()
@@ -1758,9 +1750,6 @@ class DecodeEngine:
             chunked = False
             with self._cv:
                 if not self._waiting or self._closed:
-                    break
-                if self.config.static_batching and \
-                        (self._active or self._prefilling):
                     break
                 if len(self._active) + len(self._prefilling) \
                         >= max_slots:

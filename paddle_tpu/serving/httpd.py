@@ -66,7 +66,6 @@ numbers round-trip.
 from __future__ import annotations
 
 import json
-import os
 import threading
 import time
 from typing import Dict, Optional
@@ -323,20 +322,6 @@ class _ServingHandler(_base.QuietHandler):
 
     def _do_predict(self, payload):
         try:
-            # chaos hook for latency-SLO testing (serve_bench --fleet
-            # gate 5): when PADDLE_TPU_SLOW_SHIM_FILE names an existing
-            # file, every predict sleeps the float it contains — a slow
-            # replica that can be injected and lifted mid-life by
-            # creating/removing the file, no restart needed
-            shim = os.environ.get("PADDLE_TPU_SLOW_SHIM_FILE")
-            if shim:
-                try:
-                    with open(shim) as f:
-                        delay = float(f.read().strip() or 0.0)
-                except (OSError, ValueError):
-                    delay = 0.0
-                if delay > 0:
-                    time.sleep(delay)
             feeds = payload.get("feeds") if isinstance(payload, dict) \
                 else None
             if not isinstance(feeds, dict) or not feeds:

@@ -18,8 +18,8 @@ Serving membership needs no generations/barrier — replicas never form a
 collective — so this module uses only register/heartbeat/leave from the
 rendezvous protocol; the router reads `live_members()`.
 
-Stdout speaks one JSON "ready" line once serving (the supervisor and
-benches wait on it): {"ready": true, "endpoint": ..., "pid": ...,
+Stdout speaks one JSON "ready" line once serving (the supervisor
+waits on it): {"ready": true, "endpoint": ..., "pid": ...,
 "warmstart_adopted": n, "slot": k}.
 
 Multi-tenant flags (SERVING.md §Multi-tenancy): `--model-id` names the
@@ -49,9 +49,8 @@ def _build_args(argv=None):
     ap.add_argument("--decode-tiny", type=int, default=None,
                     metavar="SEED",
                     help="attach a tiny-GPT continuous-batching decode "
-                    "engine initialized from this seed — the fleet "
-                    "bench / trace-gate shape of a token-serving "
-                    "replica (POST /v1/generate)")
+                    "engine initialized from this seed: a toy "
+                    "token-serving replica (POST /v1/generate)")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0,
                     help="0 binds an ephemeral port (printed in the "

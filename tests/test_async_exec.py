@@ -5,20 +5,15 @@ Ladder: unit (FetchHandle laziness, InFlightWindow bound, Prefetcher
 lifecycle) → executor integration (run_stream vs per-step equivalence,
 in-flight device-buffer cap via live-array accounting) → driver
 integration (streaming train_from_dataset, async train_loop, preemption
-at a step boundary mid-window + CheckpointManager resume) → a
-slow-marked end-to-end smoke of the bench.py pipeline block.
+at a step boundary mid-window + CheckpointManager resume).
 """
 
-import json
-import os
-import sys
 import threading
 import time
 
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 import paddle_tpu as pt  # noqa: E402
 from paddle_tpu.core import async_exec  # noqa: E402
@@ -632,29 +627,3 @@ def test_train_loop_health_check_forces_sync(monkeypatch):
     with pytest.raises(health.NumericsError):
         train_loop(nan_at_2, _S(0), [{} for _ in range(5)],
                    fetch_window=4)
-
-
-# ---------------------------------------------------------------------------
-# CI satellite: streaming driver end-to-end via the bench pipeline block
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_pipeline_smoke():
-    import subprocess
-
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--one",
-         "pipeline"],
-        capture_output=True, text=True, timeout=540,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 PADDLE_TPU_BENCH_FORCE_CPU="1"))
-    lines = [json.loads(l) for l in proc.stdout.splitlines()
-             if l.startswith("{")]
-    metrics = {l["metric"]: l for l in lines}
-    rec = metrics.get("pipeline_stream_samples_per_sec")
-    assert rec, proc.stdout + proc.stderr
-    assert rec["value"] > 0
-    d = rec["detail"]
-    assert d["loss_delta"] <= 1e-6
-    assert d["per_call_samples_per_sec"] > 0

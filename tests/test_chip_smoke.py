@@ -51,28 +51,6 @@ def test_fails_without_a_chip():
     assert phases == ["device"]
 
 
-def test_bench_parent_stays_off_the_backend(tmp_path):
-    """bench.py's parent hands the chip to one child per metric, so it
-    must never initialize a jax backend itself (a parent that has touched
-    JAX holds the chip and its children then fail or hang)."""
-    code = (
-        "import sys; sys.argv = ['bench.py']\n"
-        "import bench\n"
-        "bench._run_bounded = lambda argv, t, env=None: (0, '', '')\n"
-        "bench.main()\n"
-        "from jax._src import xla_bridge\n"
-        "assert not xla_bridge._backends, xla_bridge._backends\n"
-        "print('parent-clean')\n")
-    proc = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=120, cwd=REPO,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 PADDLE_TPU_BENCH_FORCE_CPU="1",
-                 PADDLE_TPU_LOCK_FILE=str(tmp_path / "chip.lock")))
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "parent-clean" in proc.stdout
-
-
 # -- the rehearsal: every phase function, tiny configs, in this process ------
 
 

@@ -1,7 +1,7 @@
 """Continuous-batching decode engine (ISSUE 12, SERVING.md
 §Continuous batching): paged KV block allocator, prefill/decode phase
-split, in-flight batching semantics, streaming HTTP, warmstart grid
-replay, and the serve_bench token-mode smoke.
+split, in-flight batching semantics, streaming HTTP and warmstart grid
+replay.
 
 The load-bearing correctness claims pinned here:
 
@@ -19,7 +19,6 @@ The load-bearing correctness claims pinned here:
 
 import json
 import os
-import subprocess
 import sys
 import time
 import urllib.error
@@ -256,15 +255,14 @@ def _wait_active(eng, timeout=60.0):
 
 
 def test_queue_full_rejects(model):
-    """Reject-not-block admission: with the drain-between-batches
-    scheduler holding one long generation active, the bounded waiting
-    queue fills and the next submit raises QueueFullError."""
-    eng = make_engine(model, static_batching=True, decode_slots=(1,),
-                      max_queue=1, max_len=64)
+    """Reject-not-block admission: with one long generation holding the
+    only slot, the bounded waiting queue fills and the next submit
+    raises QueueFullError."""
+    eng = make_engine(model, decode_slots=(1,), max_queue=1, max_len=64)
     eng.warmup()
     a = eng.submit([1, 2, 3], max_new_tokens=50)     # long generation
     _wait_active(eng)                                # A holds the slot
-    eng.submit([4, 5], max_new_tokens=2)             # waits (static)
+    eng.submit([4, 5], max_new_tokens=2)             # waits (one slot)
     with pytest.raises(QueueFullError):
         eng.submit([6, 7], max_new_tokens=2)
     assert a.result(timeout_s=120)
@@ -480,8 +478,7 @@ def test_streaming_http_e2e(model):
 
 
 def test_http_queue_full_503(model):
-    eng = make_engine(model, static_batching=True, decode_slots=(1,),
-                      max_queue=1, max_len=64)
+    eng = make_engine(model, decode_slots=(1,), max_queue=1, max_len=64)
     eng.warmup()
     srv = Server(ServingConfig(warmup=False), decode=eng)
     port = srv.start(0)
@@ -625,40 +622,3 @@ def test_slot_config_grid_warmed(model):
     assert all(len(h.result(timeout_s=120)) == 3 for h in hs)
     assert eng.status()["slot_config"] in (2, 4)
     eng.stop()
-
-
-# ---------------------------------------------------------------------------
-# serve_bench token mode (slow: subprocess, full A/B + grid replay)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_serve_bench_token_smoke():
-    """The ISSUE 12 acceptance, end to end in a fresh process:
-    continuous batching sustains >=2x tokens/s over the static
-    drain-between-batches baseline at equal-or-better p99, and the
-    warmstart-booted engine replays the phase grid with zero fresh
-    compiles and bit-identical tokens (serve_bench gates all of that
-    in its rc)."""
-    for attempt in (1, 2):
-        proc = subprocess.run(
-            [sys.executable,
-             os.path.join(_REPO, "tools", "serve_bench.py"),
-             "--tokens", "--smoke"],
-            capture_output=True, text=True, timeout=560,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"))
-        if proc.returncode == 0:
-            break
-        # one retry: the speedup gate is a wall-clock measurement and a
-        # noisy-neighbor CI container can steal either phase's timing
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    recs = [json.loads(ln) for ln in proc.stdout.splitlines()
-            if ln.strip().startswith("{")]
-    by_metric = {r["metric"]: r for r in recs}
-    assert by_metric["decode_continuous_speedup"]["value"] >= 2.0
-    assert by_metric["decode_continuous_speedup"]["detail"][
-        "equal_p99_ok"]
-    replay = by_metric["decode_warm_replay_fresh_compiles"]
-    assert replay["value"] == 0
-    assert replay["detail"]["bit_identical"]
-    assert by_metric["decode_tokens_per_sec_continuous"]["value"] > 0

@@ -1,18 +1,16 @@
 """Static repo-hygiene lints in CI — thin wrapper over tools/lint.py.
 
-1. Evidence claims (VERDICT r4 item 9): PARITY.md/PROFILE.md may only
-   cite driver artifacts (BENCH_rNN/MULTICHIP_rNN) whose committed JSON
-   exists and recorded success — a claim against a failed or absent
-   driver file is overclaiming and fails the suite.
-2. Codebase lints: tools/lint.py runs its full pass suite (atomic
+1. Codebase lints: tools/lint.py runs its full pass suite (atomic
    durable-writes — migrated from this file's PR 4 version — plus
    thread-lifetime, swallowed-exception, and lock-held-across-blocking
    passes) over all of paddle_tpu/. Intentional sites carry
    `# lint-exempt:<pass>: <why>` annotations (the atomic pass also
    honors the legacy `# atomic-exempt`).
+2. Lock order: tools/lockgraph.py finds no unexempted cycle.
 3. Cache-writer positive check (ISSUE 6): the persistent compile cache
    and the serving warmstart artifact must publish via
    resilience.atomic.write_bytes.
+4. Metric names PROFILE.md / SERVING.md mention exist in the registry.
 """
 
 import os
@@ -24,42 +22,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 from lint import WRITE_PATTERNS, lint_paths, pass_names  # noqa: E402
-from refresh_evidence import (  # noqa: E402
-    bench_fallback_recorded, lint_evidence_claims,
-)
-
-
-def test_driver_citations_are_valid():
-    errors = lint_evidence_claims()
-    assert not errors, "\n".join(errors)
-
-
-def test_bench_fallback_recorded_distinguishes_crash_from_fallback():
-    """ISSUE 12 satellite (VERDICT weak #7): rc=1 with a structured
-    env block recording the TPU→CPU fallback is citable CPU evidence;
-    rc=1 without it (harness crash, or pre-env bench output) is not."""
-    import json as _json
-
-    fallback_line = _json.dumps({
-        "metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-        "env": {"platform": "cpu", "tpu_reachable": False,
-                "fallback_reason": "TPU backend probe failed/hung"}})
-    ok_line = _json.dumps({
-        "metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-        "env": {"platform": "tpu", "tpu_reachable": True,
-                "fallback_reason": None}})
-    # recorded fallback → citable
-    assert bench_fallback_recorded({"rc": 1, "tail": fallback_line})
-    # same env in the driver's pre-parsed record list
-    assert bench_fallback_recorded(
-        {"rc": 1, "parsed": [_json.loads(fallback_line)]})
-    # healthy-TPU lines under rc=1 = something ELSE crashed, not a
-    # recorded fallback
-    assert not bench_fallback_recorded({"rc": 1, "tail": ok_line})
-    # no env blocks at all (pre-env bench / crash before output)
-    assert not bench_fallback_recorded(
-        {"rc": 1, "tail": '{"metric": "m", "value": 0.0}'})
-    assert not bench_fallback_recorded({"rc": 1, "tail": "Traceback..."})
 
 
 # -- codebase lint passes (tools/lint.py) ------------------------------------

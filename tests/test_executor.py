@@ -227,7 +227,7 @@ def test_run_chained_per_step_feeds_matches_sequential(rng):
 
 def test_run_chained_windowed_matches_sequential(rng):
     """unroll="auto" past _UNROLL_WINDOW_MAX on CPU splits the run into
-    unrolled windows (the BENCH_r05 rolled-scan regression demotion):
+    unrolled windows (the rolled-scan demotion):
     per-step losses, final params, AND the rng stream must match n
     sequential run() calls exactly — windowing is an execution detail,
     not a semantic."""

@@ -23,10 +23,6 @@ The load-bearing correctness claims pinned here:
   kv_pool.
 """
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -42,8 +38,6 @@ from paddle_tpu.serving import DecodeConfig, DecodeEngine
 from paddle_tpu.serving.kv_cache import KVCacheConfig, NoBlocksError
 from paddle_tpu.serving.kv_reuse import (ReuseBlockAllocator,
                                          accept_length, hash_blocks)
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -537,30 +531,3 @@ def test_warmstart_rekeyed_grid_roundtrip(model, tmp_path):
              for k in ("prefill", "decode")}
     assert fresh == {"prefill": 0, "decode": 0}, fresh
     assert warm_toks == cold_toks
-
-
-# ---------------------------------------------------------------------------
-# serve_bench prefix-share workload (slow: subprocess A/B)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_serve_bench_prefix_share_smoke():
-    """The ISSUE 18 acceptance harness end to end in a fresh process:
-    the shared-prefix A/B (plain bucketed vs chunk+prefix+spec) gates
-    bit-identical greedy streams, real cache hits, and the accept
-    rate; the TTFT-speedup gate is hardware-only, so --smoke validates
-    correctness plus the report schema."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "tools", "serve_bench.py"),
-         "--tokens", "--prefix-share", "--smoke"],
-        capture_output=True, text=True, timeout=560,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    recs = [json.loads(ln) for ln in proc.stdout.splitlines()
-            if ln.strip().startswith("{")]
-    by_metric = {r["metric"]: r for r in recs}
-    speedup = by_metric["decode_prefix_share_ttft_speedup"]
-    assert speedup["detail"]["bit_identical"]
-    assert by_metric["decode_prefix_share_hits"]["value"] > 0
-    assert by_metric["decode_spec_accept_rate"]["value"] >= 0.99

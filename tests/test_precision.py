@@ -2,13 +2,9 @@
 feed hot path, policy-keyed executor/compile caches, mixed-precision
 training on both the fluid and jax-native paths, dynamic loss scaling
 (state in TrainState, observability counters/events), checkpoint
-round-trip + cross-precision restore safety, int8 serving, and the
-bench.py precision smoke."""
+round-trip + cross-precision restore safety, and int8 serving."""
 
-import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +17,6 @@ import paddle_tpu as pt
 from paddle_tpu.core import precision
 from paddle_tpu.core.executor import _JitDispatch, _normalize_feed
 from paddle_tpu.observability import events, telemetry
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -673,36 +667,3 @@ def test_serving_config_unknown_precision_fails_fast(tmp_path):
     # fail fast, not silently serve f32 under a mislabeled status
     with pytest.raises(ValueError, match="unknown precision policy"):
         ServingConfig(str(tmp_path), precision="mixed_f16")
-
-
-# ---------------------------------------------------------------------------
-# bench.py precision smoke (CI satellite)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_precision_bench_smoke():
-    """`bench.py --one precision --smoke`: bf16 training parity with
-    zero hot-path upcasts and int8 serving accuracy within the stated
-    bounds, end to end on CPU (rc=0 == both acceptance gates held)."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--one",
-         "precision", "--smoke"],
-        capture_output=True, text=True, timeout=540,
-        env=dict(os.environ, JAX_PLATFORMS="cpu",
-                 PADDLE_TPU_BENCH_FORCE_CPU="1"))
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    lines = [json.loads(ln) for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
-    metrics = {ln["metric"]: ln for ln in lines}
-    train = metrics["precision_bf16_train_samples_per_sec"]
-    assert train["value"] > 0
-    assert train["detail"]["bf16_feeds_upcast_free"] is True
-    assert train["detail"]["loss_rel_delta_max"] \
-        <= train["detail"]["loss_rel_bound"]
-    serve = metrics["precision_int8_serving_p50_ms"]
-    assert serve["value"] > 0
-    assert serve["detail"]["accuracy_delta_max_abs"] \
-        <= serve["detail"]["accuracy_bound"]
-    assert serve["detail"]["engine_accuracy_delta"]["max_abs"] \
-        <= serve["detail"]["accuracy_bound"]

@@ -884,30 +884,3 @@ def test_batcher_inflight_counts_dispatched_requests():
     finally:
         release.set()
         b.stop()
-
-
-# ---------------------------------------------------------------------------
-# Load-generator smoke (CI satellite)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_serve_bench_smoke(tmp_path):
-    import os
-    import subprocess
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "serve_bench.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=540,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    lines = [json.loads(l) for l in proc.stdout.splitlines()
-             if l.startswith("{")]
-    metrics = {l["metric"]: l for l in lines}
-    for name in ("serving_p50_latency_ms", "serving_p99_latency_ms",
-                 "serving_throughput_rps", "serving_reject_rate"):
-        assert name in metrics, proc.stdout
-    assert metrics["serving_throughput_rps"]["value"] > 0
-    assert metrics["serving_p50_latency_ms"]["detail"]["ok"] > 0

@@ -1102,19 +1102,13 @@ def main() -> int:
             # dp-sharded through the scale-in, not silently replicated
             args.batch = args.world * (args.world - 1) \
                 * max(1, args.batch // (args.world * (args.world - 1)))
-        from paddle_tpu.core.tpu_lock import tpu_singleflight
-
-        with tpu_singleflight():
-            return run_elastic_bench(args)
+        return run_elastic_bench(args)
     if args.smoke:
         # tier-1 safety: tiny, CPU-only, a single kill/resume cycle
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         args.steps, args.save_every = 8, 2
         args.kill_steps = "5"
-    from paddle_tpu.core.tpu_lock import tpu_singleflight
-
-    with tpu_singleflight():  # one real chip: serialize vs bench/tools
-        return run_bench(args)
+    return run_bench(args)
 
 
 if __name__ == "__main__":

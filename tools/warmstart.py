@@ -62,8 +62,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def cmd_bake(args) -> int:
-    import contextlib
-
     sys.path.insert(0, _REPO)
     import jax
 
@@ -87,19 +85,11 @@ def cmd_bake(args) -> int:
     cfg = ServingConfig(args.model_dir, buckets=buckets,
                         max_batch=args.max_batch,
                         use_tpu=not args.cpu, aot=True)
-    if args.cpu:
-        guard = contextlib.nullcontext()
-    else:
-        # baking drives the chip: serialize against bench/other tools
-        from paddle_tpu.core.tpu_lock import tpu_singleflight
-
-        guard = tpu_singleflight(timeout=600.0)
-    with guard:
-        t0 = time.perf_counter()
-        engine = Engine(cfg)
-        ready = engine.warmup()
-        warm_s = time.perf_counter() - t0
-        n = engine.export_warmstart(args.out)
+    t0 = time.perf_counter()
+    engine = Engine(cfg)
+    ready = engine.warmup()
+    warm_s = time.perf_counter() - t0
+    n = engine.export_warmstart(args.out)
     print(json.dumps({
         "artifact": args.out,
         "model_dir": args.model_dir,
@@ -113,8 +103,6 @@ def cmd_bake(args) -> int:
 
 
 def cmd_bake_decode(args) -> int:
-    import contextlib
-
     sys.path.insert(0, _REPO)
     import jax
 
@@ -156,18 +144,11 @@ def cmd_bake_decode(args) -> int:
     # self-draft: same params serve as the draft model, so the baked
     # draft/verify phases stay deterministic from --preset/--seed
     draft = (params, cfg) if args.spec_k else None
-    if args.cpu:
-        guard = contextlib.nullcontext()
-    else:
-        from paddle_tpu.core.tpu_lock import tpu_singleflight
-
-        guard = tpu_singleflight(timeout=600.0)
-    with guard:
-        t0 = time.perf_counter()
-        engine = DecodeEngine(params, cfg, dc, draft=draft)
-        ready = engine.warmup()
-        warm_s = time.perf_counter() - t0
-        n = engine.export_warmstart(args.out)
+    t0 = time.perf_counter()
+    engine = DecodeEngine(params, cfg, dc, draft=draft)
+    ready = engine.warmup()
+    warm_s = time.perf_counter() - t0
+    n = engine.export_warmstart(args.out)
     grid_out = {"decode_slots": slots, "spec_k": args.spec_k}
     if args.prefill_chunk:
         grid_out["prefill_chunk"] = args.prefill_chunk
