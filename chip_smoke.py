@@ -329,11 +329,12 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
 
     from paddle_tpu.models import gpt
     from paddle_tpu.ops.pallas import paged_attention
-    from paddle_tpu.serving import Server, ServingConfig
+    from paddle_tpu.serving import Server, ServingConfig, kv_cache
     from paddle_tpu.serving.decode import DecodeEngine
 
     params, _ = _init(model or gpt, cfg)
     paged_attention.GATE_COUNTS.clear()
+    kv_cache.PREFILL_WRITE_UNITS.clear()
     engine = DecodeEngine(params, cfg, decode_cfg)
     n_phases = len(engine.decode_slots) + len(engine.prefill_buckets)
     ready, compile_s = _timed(engine.warmup)
@@ -383,6 +384,7 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
                  "compiles_after_warmup": late_compiles,
                  "finished": status["requests"],
                  "decode_attention": status["decode_attention"],
+                 "prefill_write": status["prefill_write"],
                  "ref_exact_tokens": f"{exact}/{len(prompts) * max_new}",
                  "ref_max_logit_gap": round(gap, 5),
                  "ref_logit_tol": logit_tol})
@@ -602,6 +604,10 @@ def run_one_chip() -> None:
             prompts, max_new=24, logit_tol=0.25)
         # both decode programs read the live blocks through the table
         assert info["checked"]["decode_attention"] == {"paged": 2}, info
+        # K and V of eight prefill programs: the bucket of 8 is under a
+        # block, the seven of 16 to 1024 go in a block at a time
+        assert info["checked"]["prefill_write"] == {"rows": 2,
+                                                    "blocks": 14}, info
 
     with phase("serve_reuse") as info:
         reuse_phase(info, cfg, DecodeConfig(
@@ -627,6 +633,7 @@ def run_one_chip() -> None:
             logit_tol=OLMOE_LOGIT_TOL, model=olmoe,
             reference_gaps=_olmoe_reference_gaps)
         assert info["checked"]["decode_attention"] == {"paged": 1}, info
+        assert info["checked"]["prefill_write"] == {"blocks": 6}, info
 
     # the kernel against the gather path where it runs, at the benchmark's
     # two widths: GPT-2-large's 20 heads of 64, OLMoE's 16 of 128

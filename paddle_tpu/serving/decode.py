@@ -64,8 +64,8 @@ from ..observability import telemetry as _telemetry
 from ..observability import tracing as _tracing
 from ..ops.pallas import paged_attention as _paged_attention
 from .batcher import QueueFullError, ServerClosed
-from .kv_cache import (BlockAllocator, KVCacheConfig, NoBlocksError,
-                       build_block_table, init_pools)
+from .kv_cache import (PREFILL_WRITE_UNITS, BlockAllocator, KVCacheConfig,
+                       NoBlocksError, build_block_table, init_pools)
 from . import kv_reuse as _kvr
 from .kv_reuse import ReuseBlockAllocator
 
@@ -1144,6 +1144,11 @@ class DecodeEngine:
             # program of this process ("paged": the kernel over the live
             # blocks; "gather": the padded gather)
             "decode_attention": dict(_paged_attention.GATE_COUNTS),
+            # which unit whole-prompt writes into the pool took, a count a
+            # traced write, two (K and V) a prefill program ("blocks": a
+            # bucket of whole blocks goes in a block at a time; "rows": a
+            # bucket that is not, a token at a time)
+            "prefill_write": dict(PREFILL_WRITE_UNITS),
         }
         if self._qos is not None:
             out["qos"] = {

@@ -19,7 +19,12 @@ alloc/free, fragmentation accounting) and the pure jnp pool helpers
 (`init_pools`, `write_token_kv`, `write_prefill_kv`, `write_chunk_kv`,
 `write_span_kv`, `gather_kv`) that `models/decoder.py` composes into
 the serve programs; they take the whole pool and a layer index, and
-the layer loop carries the pools and addresses them in place. Who still
+the layer loop carries the pools and addresses them in place. Who writes
+in which unit: a whole prompt goes in a BLOCK at a time wherever its
+bucket is whole blocks (`write_prefill_kv`: on a TPU one DMA a block,
+ops/pallas/kv_block_write.py; `PREFILL_WRITE_UNITS` counts the unit);
+the decode step, a prefill chunk and a verified span, whose positions
+start anywhere, a token at a time. Who still
 composes `gather_kv` (a padded copy of every slot's whole table, a
 layer): `prefill_chunk` and `verify_step` everywhere, and `decode_step`
 off the TPU (`decoder.gather_attention`); on a TPU `decode_step` reads
@@ -37,6 +42,7 @@ already guarantees nothing read from the null block ever contributes.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -48,9 +54,14 @@ import numpy as np
 __all__ = ["KVCacheConfig", "BlockAllocator", "NoBlocksError",
            "init_pools", "write_token_kv", "write_prefill_kv",
            "write_chunk_kv", "write_span_kv", "gather_kv",
-           "NULL_BLOCK"]
+           "NULL_BLOCK", "PREFILL_WRITE_UNITS"]
 
 NULL_BLOCK = 0
+
+# which unit each traced whole-prompt write took ("blocks" | "rows"), one
+# count a call of write_prefill_kv: a prefill program counts two, K and V.
+# DecodeEngine.status() reports them beside the decode attention's route.
+PREFILL_WRITE_UNITS: collections.Counter = collections.Counter()
 
 
 class NoBlocksError(RuntimeError):
@@ -178,13 +189,14 @@ class BlockAllocator:
 # profile's device time reduces by them, PERF.md section 3).
 #
 # Every helper takes the WHOLE pool `[L, NB, BS, *tok]` and a (traced)
-# layer index, and addresses it in place by (layer, block, slot): the
-# layer loop of models/decoder.py holds the pools in its carry, so a write
-# is one scatter into the donated buffer and no layer's slice is ever
-# taken out or put back. `tok` = `pool.shape[3:]` is how one token is
-# stored: `[kv_heads*head_dim]` in the engine's pools
-# (`KVCacheConfig.pool_shape`); the helpers are generic over it and
-# `kv`'s trailing dimensions match it.
+# layer index, and addresses it in place by (layer, block, slot), or by
+# (layer, block) where whole blocks are written: the layer loop of
+# models/decoder.py holds the pools in its carry, so a write goes into
+# the donated buffer (one scatter, or write_prefill_kv's copy a block)
+# and no layer's slice is ever taken out or put back. `tok` =
+# `pool.shape[3:]` is how one token is stored: `[kv_heads*head_dim]` in
+# the engine's pools (`KVCacheConfig.pool_shape`); the helpers are generic
+# over it and `kv`'s trailing dimensions match it.
 # ---------------------------------------------------------------------------
 
 
@@ -211,17 +223,38 @@ def write_token_kv(pool: jax.Array, layer: jax.Array, kv: jax.Array,
 @jax.named_scope("kv_write")
 def write_prefill_kv(pool: jax.Array, layer: jax.Array, kv: jax.Array,
                      block_table: jax.Array, block_size: int) -> jax.Array:
-    """Scatter a whole prompt's K (or V) into layer `layer` of the pool.
+    """Write a whole prompt's K (or V) into layer `layer` of the pool.
     pool `[L, NB, BS, *tok]`, kv `[T, *tok]` (positions 0..T-1),
-    block_table `[MB]`. Positions past the sequence's allocated blocks
-    hit table entries that are still 0 and land in the null block;
+    block_table `[MB]`.
+
+    The unit is chosen by the static shape: a `T` of whole blocks goes in
+    a block at a time, `kv` viewed as `[T/BS, BS, *tok]` to
+    `pool[layer, block_table[:T/BS]]`: `T/BS` updates of one contiguous
+    block each (on a TPU one DMA a block, ops/pallas/kv_block_write.py;
+    elsewhere one scatter with a block a window). A `T` that is not (the
+    engine's smallest default bucket is 8, under a block) keeps the row
+    form, one update a token. `PREFILL_WRITE_UNITS` counts which.
+
+    Either way: table entries past the sequence's allocated blocks are
+    still 0, so those positions land in the null block (several updates
+    may name it; any order is right, nothing reads it unmasked);
     positions inside the last allocated block but past the true length
-    write garbage slots that the decode step overwrites before any
-    mask ever lets them be read."""
-    t = jnp.arange(kv.shape[0], dtype=jnp.int32)
-    blk = block_table[t // block_size]
-    slot = t % block_size
-    return pool.at[layer, blk, slot].set(kv)
+    write garbage slots that the decode step overwrites before any mask
+    ever lets them be read."""
+    from ..ops.pallas import kv_block_write as bw
+
+    T = kv.shape[0]
+    whole = T % block_size == 0
+    PREFILL_WRITE_UNITS["blocks" if whole else "rows"] += 1
+    if whole:
+        nb = T // block_size
+        kv = kv.reshape(nb, block_size, *kv.shape[1:])
+        if bw.use_dma(kv, pool):
+            return bw.write_blocks(pool, layer, kv, block_table[:nb])
+        return pool.at[layer, block_table[:nb]].set(kv)
+    t = jnp.arange(T, dtype=jnp.int32)
+    return pool.at[layer, block_table[t // block_size],
+                   t % block_size].set(kv)
 
 
 @jax.named_scope("kv_write")

@@ -104,6 +104,8 @@ def test_rehearse_serve(smoke):
         logit_tol=0.25)
     assert info["checked"]["finished"]["length"] == 3
     assert info["checked"]["compiles_after_warmup"] == 0
+    # K and V of the two prefill programs, both buckets whole blocks of 8
+    assert info["checked"]["prefill_write"] == {"blocks": 4}
     info = smoke.reuse_phase({}, cfg, DecodeConfig(
         block_size=8, num_blocks=33, decode_slots=(2,), prefill_chunk=8,
         prefix_cache=True, max_len=64),

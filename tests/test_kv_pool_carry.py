@@ -144,3 +144,61 @@ def test_program_equals_plain_loop_over_layers(model, kind):
         untouched = ref == before
         assert 0 < (~untouched).sum() < untouched.size // 4
         np.testing.assert_array_equal(got[untouched], before[untouched])
+
+
+# ---------------------------------------------------------------------------
+# The whole-prompt write alone (PERF.md section 6, PR 30). A bucket that is
+# whole blocks goes into the pool a block at a time, one update a table
+# entry; a bucket under a block keeps the row form. Either way the pool
+# holds what a Python loop over the positions writes: buckets like the
+# engine's 8 / 16 / 64 / 512 / 1024 scaled to a block of 8, tokens like
+# GPT-2-large's 1280 and OLMoE's 2048 lanes scaled down.
+# ---------------------------------------------------------------------------
+
+_W_BS, _W_MB, _W_LAYERS = 8, 16, 3
+
+
+def _allocated(fill, bucket):
+    """How many blocks the sequence owns: enough for the whole bucket, for
+    half of it (the table's tail entries are 0), or one."""
+    whole = -(-bucket // _W_BS)
+    return {"full": whole, "partial": max(1, whole // 2), "one_block": 1}[fill]
+
+
+@pytest.mark.parametrize("width,dtype", [(40, "float32"), (64, "bfloat16")])
+@pytest.mark.parametrize("fill", ["full", "partial", "one_block"])
+@pytest.mark.parametrize("bucket", [4, 8, 32, 64, 128])
+def test_prefill_write_puts_whole_blocks_where_the_rows_went(
+        bucket, fill, width, dtype):
+    from paddle_tpu.serving import kv_cache as kvc
+
+    rng = np.random.default_rng(bucket + width)
+    nb = 2 * _W_MB + 1
+    dt = jnp.dtype(dtype)
+    before = np.asarray(jnp.asarray(rng.standard_normal(
+        (_W_LAYERS, nb, _W_BS, width)), dt))
+    kv = np.asarray(jnp.asarray(rng.standard_normal((bucket, width)), dt))
+    owned = rng.permutation(np.arange(1, nb))[:_allocated(fill, bucket)]
+    table = kvc.build_block_table(owned, _W_MB)
+    layer = 1
+
+    kvc.PREFILL_WRITE_UNITS.clear()
+    got = jax.jit(lambda p, l, x, t: kvc.write_prefill_kv(p, l, x, t, _W_BS))(
+        jnp.asarray(before), jnp.int32(layer), jnp.asarray(kv),
+        jnp.asarray(table))
+    unit = "blocks" if bucket % _W_BS == 0 else "rows"
+    assert kvc.PREFILL_WRITE_UNITS == {unit: 1}, kvc.PREFILL_WRITE_UNITS
+
+    want = before.copy()                    # the row form, a position a time
+    for t in range(bucket):
+        want[layer, table[t // _W_BS], t % _W_BS] = kv[t]
+    got = np.array(got)
+    assert got.shape == before.shape and got.dtype == before.dtype
+    # every allocated block holds what the rows wrote (the last one its
+    # old slots past the bucket's end where the bucket is under a block) ...
+    np.testing.assert_array_equal(got[layer, owned], want[layer, owned])
+    assert (want[layer, owned[0]] != before[layer, owned[0]]).any()
+    # ... and no block the table does not name, of any layer, is touched;
+    # the null block takes the positions past the allocation, in any order
+    got[layer, owned] = before[layer, owned]
+    np.testing.assert_array_equal(got[:, 1:], before[:, 1:])

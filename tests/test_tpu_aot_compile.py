@@ -84,8 +84,15 @@ def _paged(heads):
         q, kp, vp, l, t, p, heads=heads)
 
 
+def _block_write(pool, layer, kv, blocks):
+    from paddle_tpu.ops.pallas import kv_block_write as BW
+
+    return BW.write_blocks(pool, layer, kv, blocks)
+
+
 _QKV = "qkv"
 _PAGED = "paged"    # shape: (slots, table blocks, layers, heads, head_dim)
+_BLOCKS = "blocks"  # shape: (bucket, layers, lanes a token, dtype)
 _CASES = [
     # BERT long-seq cell (bs 8, T 4096, 12 heads of 64, bf16), full mask
     pytest.param(_splash(False, False), _QKV, (8, 4096, 12, 64),
@@ -119,6 +126,17 @@ _CASES = [
                  id="paged-olmoe-4096x32"),
     pytest.param(_paged(16), _PAGED, (16, 64, 4, 16, 128, jnp.float32),
                  id="paged-olmoe-f32"),
+    # a prompt's K or V into the pool, one DMA a block: the benchmark's
+    # longest bucket at GPT-2-large's width, OLMoE's, a 4096-token prompt
+    # (256 copies in flight), and float32 pools, whose tile is 8 rows
+    pytest.param(_block_write, _BLOCKS, (1024, 36, 1280, jnp.bfloat16),
+                 id="block-write-gpt2-large-1024"),
+    pytest.param(_block_write, _BLOCKS, (256, 8, 2048, jnp.bfloat16),
+                 id="block-write-olmoe-256"),
+    pytest.param(_block_write, _BLOCKS, (4096, 4, 2048, jnp.bfloat16),
+                 id="block-write-olmoe-4096"),
+    pytest.param(_block_write, _BLOCKS, (64, 4, 1280, jnp.float32),
+                 id="block-write-f32-64"),
 ] + [
     pytest.param(_fused(kernel), "xsbw", shape,
                  id=f"{kernel}-{'x'.join(map(str, shape))}")
@@ -141,12 +159,23 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
         pool = sds((layers, slots * blocks + 1, 16, heads * d), dt)
         args = (sds((slots, heads * d), dt), pool, pool, sds((), jnp.int32),
                 sds((slots, blocks), jnp.int32), sds((slots,), jnp.int32))
+    elif kind == _BLOCKS:
+        bucket, layers, hd, dt = shape
+        blocks = bucket // 16       # 16 slots of a 1024-token table or more
+        args = (sds((layers, 16 * max(blocks, 64) + 1, 16, hd), dt),
+                sds((), jnp.int32), sds((blocks, 16, hd), dt),
+                sds((blocks,), jnp.int32))
     else:
         M, K, N = shape
         args = (sds((M, K)), sds((K,), jnp.float32),
                 sds((K,), jnp.float32), sds((K, N)))
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jax.jit(fn, donate_argnums=(0,) if kind == _BLOCKS else ()
+                       ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if kind == _BLOCKS:     # the pool is written where it lies
+        ma = compiled.memory_analysis()
+        assert ma.alias_size_in_bytes >= np.prod(args[0].shape) \
+            * jnp.dtype(dt).itemsize and ma.temp_size_in_bytes < 1e6, ma
 
 
 @pytest.mark.parametrize("tp,sp,counter", [
@@ -233,8 +262,11 @@ def _compile_decode(gpt2_large, slots):
 
 
 def _compile_prefill(gpt2_large, bucket):
+    from paddle_tpu.serving import kv_cache as kvc
+
     gpt, cfg, params, sds = gpt2_large
     pool = _pool(cfg, sds, 16)
+    kvc.PREFILL_WRITE_UNITS.clear()
     return pool, jax.jit(
         lambda p, ids, n, kp, vp, bt: gpt.apply_prefill(
             p, cfg, ids, n, kp, vp, bt, block_size=_BLOCK, eos_id=-1),
@@ -263,10 +295,33 @@ def _kernels(text):
         r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
 
 
-@pytest.mark.parametrize("program", ["decode@16", "prefill@512",
-                                     "prefill@1024"])
+def _pool_scatters(text, pool_shape):
+    """Every scatter into a pool (its result has the pool's element count,
+    however XLA has flattened it), as (result dims, update_window_dims).
+    A window of ONE dimension is a token's lanes, so one update a token:
+    until PR 30 every prefill held two a layer, the largest device op of
+    `gpt2_large.doc_closed`: `bf16[590400,1280] scatter(bf16[590400,1280],
+    s32[1024,1], bf16[1024,1280]), update_window_dims={1},
+    inserted_window_dims={0}` (590400 = 36 x 1025 x 16). A block's window
+    is `{1,2}`: `[16,1280]`."""
+    return [(dims, window) for dims, window in re.findall(
+        r"= \w+\[([\d,]+)\]\S* scatter\(.*?update_window_dims=\{([\d,]*)\}",
+        text)
+        if np.prod(list(map(int, dims.split(",")))) == np.prod(pool_shape)]
+
+
+@pytest.mark.parametrize("program", [
+    "decode@16", "prefill@512", "prefill@1024",
+    # with the gate answered for the described chip, as the program runs
+    # there: splash attention and the block-write kernel
+    "prefill@512-on-chip", "prefill@1024-on-chip"])
 def test_gpt2_large_serve_program_addresses_the_pool_in_place(
-        gpt2_large, program):
+        gpt2_large, program, monkeypatch):
+    from paddle_tpu.serving import kv_cache as kvc
+
+    program, _, on_chip = program.partition("-")
+    if on_chip:
+        monkeypatch.setattr(A, "_platform", lambda q: "tpu")
     kind, n = program.split("@")
     pool, compiled = (_compile_decode if kind == "decode"
                       else _compile_prefill)(gpt2_large, int(n))
@@ -276,8 +331,23 @@ def test_gpt2_large_serve_program_addresses_the_pool_in_place(
     assert ma.temp_size_in_bytes < 0.5e9, ma
     # both pools are written where they lie: the outputs alias the inputs
     assert ma.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2, ma
-    movers = _pool_movers(compiled.as_text(), pool.shape)
+    text = compiled.as_text()
+    movers = _pool_movers(text, pool.shape)
     assert not movers, movers
+    if kind == "decode":
+        return
+    # a prompt goes in a block at a time (PERF.md section 6, PR 30): K and V
+    assert kvc.PREFILL_WRITE_UNITS == {"blocks": 2}, kvc.PREFILL_WRITE_UNITS
+    writers = [k for k in _kernels(text) if "kv_block_write" in k]
+    scatters = _pool_scatters(text, pool.shape)
+    if on_chip:     # one DMA a block, and a profile finds it under kv_write
+        assert len(writers) == 2 and all(
+            "/layers/" in k and "/kv_write/" in k for k in writers), writers
+        assert not scatters, scatters
+    else:           # the route off the TPU: one scatter, a block a window
+        #             (the parent's row scatter had the window "1")
+        assert not writers and [w for _, w in scatters] == ["1,2"] * 2, \
+            scatters
 
 
 @pytest.mark.parametrize("route", ["gather", "paged"])
@@ -335,6 +405,7 @@ def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
         olmoe_8l, program, monkeypatch):
     from paddle_tpu.models import decoder
     from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.serving import kv_cache as kvc
 
     monkeypatch.setattr(A, "_platform", lambda q: "tpu")
     gm.GATE_COUNTS.clear()
@@ -342,6 +413,7 @@ def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
     sm = cfg.serve_model()
     kw = dict(block_size=_BLOCK, eos_id=-1)
     kind, n = program.split("@")
+    kvc.PREFILL_WRITE_UNITS.clear()
     if kind == "decode":
         fn, args = decoder.decode_step, (
             sds((16,), np.int32), sds((16,), np.int32), pool, pool,
@@ -370,9 +442,15 @@ def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
     # will find them under mlp/experts
     assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
     kernels = _kernels(text)
-    # (the decode program's fourth kernel is attention's: below)
+    # (the decode program's fourth kernel is attention's: below; the
+    # prefill's other two write the prompt's K and V a block at a time)
     assert sum("/mlp/experts/" in k for k in kernels) == 3 and all(
-        "/mlp/experts/" in k or "/attention/" in k for k in kernels), kernels
+        "/mlp/experts/" in k or "/attention/" in k or "/kv_write/" in k
+        for k in kernels), kernels
+    if kind == "prefill":
+        assert kvc.PREFILL_WRITE_UNITS == {"blocks": 2}
+        assert sum("kv_block_write" in k for k in kernels) == 2, kernels
+        assert not _pool_scatters(text, pool.shape)
 
 
 # ---------------------------------------------------------------------------
