@@ -14,7 +14,8 @@ over. Numbers printed here are information, not benchmark results.
 
     python chip_smoke.py            one chip: device, program, train,
                                     long_seq, serve, serve_reuse,
-                                    serve_olmoe, paged_attention
+                                    serve_olmoe, serve_joyai,
+                                    paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
                                     one device, then the dp/tp/sp/pp/ep
@@ -40,6 +41,7 @@ import time
 
 SEED = 0
 OLMOE_LOGIT_TOL = 0.25   # benchmarks/configs/olmoe_1b_7b.json argues it
+JOYAI_LOGIT_TOL = 0.4    # benchmarks/configs/joyai_llm_flash.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
 # many of those its persistent cache answered (a hit still counts as a
@@ -316,6 +318,23 @@ def _olmoe_reference_gaps(params, cfg, prompts, streams):
         streams, width)
 
 
+def _joyai_reference_gaps(params, cfg, prompts, streams):
+    """As `_olmoe_reference_gaps`, against the benchmark's plain float32
+    latent-attention model in the EXPANDED form (benchmarks/reference/
+    joyai_ref.py; no cache, no code of models/joyai.py)."""
+    from benchmarks.reference import joyai_ref
+
+    ref = {k: getattr(cfg, k) for k in (
+        "layers", "dense_layers", "heads", "kv_rank", "nope_dim", "rope_dim",
+        "v_dim", "top_k", "route_scale", "rope_theta", "rms_eps")}
+    top = {k: v for k, v in params.items()
+           if not k.startswith(("blk.", "dense."))}
+    width = max(len(p) for p in prompts) + len(streams[0])
+    return joyai_ref.stream_gaps(
+        top, lambda i: joyai_ref.layer_of(params, ref, i), ref, prompts,
+        streams, width)
+
+
 def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
                 logit_tol: float, model=None,
                 reference_gaps=_reference_gaps) -> dict:
@@ -440,8 +459,8 @@ def paged_attention_phase(info: dict, heads: int, head_dim: int,
                           table_blocks: int = 64, tol: float = 0.05,
                           interpret=False) -> dict:
     """The paged decode-attention kernel against the gather path
-    (`decoder.gather_attention`) on one device: unit-normal noise in q and
-    in every slot of both pools, scattered tables, lengths from one token
+    (`decoder.cached_attention` of `mha_cached`) on one device: unit-normal
+    noise in q and in every slot of both pools, scattered tables, lengths from one token
     to a full table with block and chunk edges and inactive slots among
     them, every layer of the pool. `tol` bounds the largest absolute
     difference of a live slot's context: both routes weigh in bf16 and
@@ -482,7 +501,12 @@ def paged_attention_phase(info: dict, heads: int, head_dim: int,
 
     paged = over_layers(lambda *a: pa.paged_attention(
         *a, heads=heads, interpret=interpret))
-    gather = over_layers(lambda *a: decoder.gather_attention(*a, heads))
+    def gathered(q, kp, vp, l, bt, pos):    # one query row a slot
+        return decoder.cached_attention(
+            lambda *a: decoder.mha_cached(*a, heads), q[:, None], kp, vp, l,
+            bt, pos[:, None])[:, 0]
+
+    gather = over_layers(gathered)
     (got, want), compile_s = _timed(
         lambda: (paged(q, k_pool, v_pool), gather(q, k_pool, v_pool)))
     _, paged_s = _timed(lambda: paged(q, k_pool, v_pool))
@@ -558,7 +582,7 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import bert, gpt, olmoe
+    from paddle_tpu.models import bert, gpt, joyai, olmoe
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
 
@@ -634,6 +658,30 @@ def run_one_chip() -> None:
             reference_gaps=_olmoe_reference_gaps)
         assert info["checked"]["decode_attention"] == {"paged": 1}, info
         assert info["checked"]["prefill_write"] == {"blocks": 6}, info
+
+    # JoyAI-LLM-Flash at its published widths (latent attention: 32 heads
+    # over a 512 + 64 cache row; top-8 of sigmoid-routed experts of 768 and
+    # a shared one; vocab 129280), the dense layer and ONE expert layer of
+    # 64 of the 256 experts, so that the float32 set for the reference
+    # (3.7 GB) sits beside the served one: the model whose cache layout,
+    # decode attention and leading layer are its own
+    jcfg = joyai.JoyaiConfig(layers=2, n_experts=64, max_len=1024)
+    prompts = [rng.randint(0, jcfg.vocab_size, n).tolist()
+               for n in (12, 200, 5, 64, 40, 250, 129)]
+    with phase("serve_joyai") as info:
+        serve_phase(info, jcfg, DecodeConfig(
+            block_size=16, num_blocks=16 * 64 + 1, decode_slots=(16,),
+            prefill_buckets=(64, 128, 256)), prompts, max_new=24,
+            logit_tol=JOYAI_LOGIT_TOL, model=joyai,
+            reference_gaps=_joyai_reference_gaps)
+        # the latent kernel, not the gathered form: a closed gate would
+        # serve the same tokens slower and nothing else would say so
+        assert info["checked"]["decode_attention"] == {"paged_latent": 1}, \
+            info
+        # the leading dense layer is traced beside the scanned one: two
+        # layer bodies a prefill program, K and V (here `c` and the rotary
+        # key) each, three programs
+        assert info["checked"]["prefill_write"] == {"blocks": 12}, info
 
     # the kernel against the gather path where it runs, at the benchmark's
     # two widths: GPT-2-large's 20 heads of 64, OLMoE's 16 of 128

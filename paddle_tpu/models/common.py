@@ -107,6 +107,19 @@ def layer_norm(params: Params, name: str, x: jax.Array, eps=1e-12) -> jax.Array:
                           eps)
 
 
+def rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last dimension, computed in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+@jax.named_scope("ln")
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """`rms` as a block's norm (the layer scope `ln`)."""
+    return rms(x, scale, eps)
+
+
 def gelu(x):
     return jax.nn.gelu(x, approximate=True)
 

@@ -5,21 +5,29 @@ engine drives (serving/decode.py).
 live; this module holds what is the same for every model: the ONE layer
 loop with the pools in its carry (`serve_layers`) and the four programs
 built on it (`prefill`, `decode_step`, `prefill_chunk`, `verify_step`).
-What differs between GPT-2 (learned positions, LayerNorm, fused QKV with
-bias, GELU MLP) and OLMoE (RoPE, RMSNorm, QK-norm, sparse SwiGLU experts)
-is the block's pieces, which a model hands over as a `ServeModel`. The
-engine asks a model configuration for it (`cfg.serve_model()`) and never
-names a model module.
+What differs between models (learned positions or RoPE, LayerNorm or
+RMSNorm, a dense MLP or sparse experts, leading layers unlike the rest,
+multi-head or latent attention) is the block's pieces, which a model
+hands over as a `ServeModel`. The engine asks a model configuration for
+it (`cfg.serve_model()`) and never names a model module.
 
-Attention here is multi-head over a pool `[L, NB, BS, heads*head_dim]`
-(kv_cache.KVCacheConfig.pool_shape): both models have as many K/V heads as
-query heads.
+The cache belongs to the model too. A token stores two entries in a
+layer, one in each pool (`kv_cache.KVCacheConfig`): `ServeModel.stored`
+says how wide they are, `qkv` produces them, the programs write them at
+(layer, block, slot) and hand them back gathered, or leave them in the
+pools for a kernel, and the model's three attention forms read them:
+`attend_prompt` (a whole prompt from its own projections),
+`attend_cached` (query rows against a gathered context) and
+`attend_paged` (a decode step through the block table, on a TPU). The
+defaults are multi-head attention over K and V of `heads*head_dim`
+lanes, as many K/V heads as query heads.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +38,8 @@ from .common import Params
 class ServeModel:
     """What the engine needs of a model: the cache's shape, the range of
     ids and positions, and the pieces of one block. `lp` is one layer's
-    slice of `layer_params(params)`; activations are `[..., hidden]` with
+    parameters (a slice of `layer_params(params)`, or one of
+    `lead_params(params)`); activations are `[..., hidden]` with
     `positions` shaped like their leading dimensions.
 
     Every piece must be ROW-INDEPENDENT: a row's result may depend on that
@@ -38,7 +47,7 @@ class ServeModel:
     unrelated requests and promises each the tokens it would get alone). A
     model that cannot promise that says why in `refusal`."""
 
-    layers: int
+    layers: int             # all of them, leading ones included
     heads: int
     head_dim: int
     vocab_size: int
@@ -49,8 +58,22 @@ class ServeModel:
     def kv_heads(self) -> int:
         return self.heads
 
+    @property
+    def stored(self) -> Tuple[int, int]:
+        """Lanes of the two entries a token stores in a layer; multi-head
+        attention's are K and V, `kv_heads*head_dim` each."""
+        return (self.kv_heads * self.head_dim,) * 2
+
+    def lead_params(self, params: Params) -> Sequence[Params]:
+        """The parameters of the layers BEFORE the stacked ones, one dict a
+        layer, where the first layers are unlike the rest (a dense MLP
+        before expert layers); the layer loop runs them one by one and
+        then scans the stack."""
+        return ()
+
     def layer_params(self, params: Params) -> Params:
-        """The per-layer parameters, stacked on a leading [L] axis."""
+        """The parameters of the layers that are alike, stacked on a
+        leading axis."""
         raise NotImplementedError
 
     def embed(self, params: Params, ids, positions):
@@ -60,9 +83,47 @@ class ServeModel:
         raise NotImplementedError
 
     def qkv(self, lp, y, positions):
-        """(q, k, v), each `[..., heads*head_dim]`, as the cache stores
-        and attention reads them (a rotary model rotates q and k here)."""
+        """(q, k, v): `k` and `v` `[..., width]` are the two entries the
+        cache stores for each row, as attention reads them back (a rotary
+        model rotates here); `q` is whatever the model's own `attend_*`
+        take, `[..., heads*head_dim]` for the defaults."""
         raise NotImplementedError
+
+    def attend_prompt(self, lp, q, k, v):
+        """Causal self-attention of whole prompts `[B, T, ...]` from the
+        layer's own projections (what `qkv` gave for these rows) ->
+        `[B, T, ctx]`, what `proj` takes."""
+        from ..ops.pallas import attention as pa
+
+        B, T = q.shape[:2]
+        heads = (B, T, self.heads, self.head_dim)
+        ctx = pa.mha(q.reshape(heads), k.reshape(heads), v.reshape(heads),
+                     causal=True, scale=1.0 / math.sqrt(self.head_dim))
+        return ctx.reshape(B, T, -1)
+
+    def attend_cached(self, lp, q, keys, vals, pos):
+        """Query rows against a gathered context: q `[S, W, ...]`, the
+        stored entries `keys` `[S, M, k width]` and `vals` `[S, M, v
+        width]` of positions 0..M-1, `pos` `[S, W]`: row (s, w) sees the
+        positions `<= pos[s, w]` -> `[S, W, ctx]`."""
+        return mha_cached(q, keys, vals, pos, self.heads)
+
+    def paged_route(self, x, k_pool, v_pool) -> Optional[str]:
+        """The name (a key of `paged_attention.GATE_COUNTS`) of the kernel
+        a decode step over these pools takes, or None: the gathered form."""
+        from ..ops.pallas import paged_attention as pa
+
+        return "paged" if pa.use_paged(x, k_pool, self.heads) else None
+
+    def attend_paged(self, lp, q, k_pool, v_pool, layer, block_tables,
+                     positions):
+        """One query row a slot, q `[S, ...]`, against the live blocks of
+        layer `layer` of the pools through the table -> `[S, ctx]`; only
+        where `paged_route` named a kernel."""
+        from ..ops.pallas import paged_attention as pa
+
+        return pa.paged_attention(q, k_pool, v_pool, layer, block_tables,
+                                  positions, heads=self.heads)
 
     def proj(self, lp, ctx, res):
         """The output projection added to the residual stream `res`."""
@@ -73,12 +134,13 @@ class ServeModel:
 
     def mlp(self, lp, y, params: Params, l):
         """(the block's second half for `y`, a small pytree of per-layer
-        counters or None). The counters of a decode step's layers come
-        back stacked from `decode_step` and `step_facts` names them.
+        counters or None). The counters of a decode step's stacked layers
+        come back stacked from `decode_step` and `step_facts` names them.
         `params` are the model's whole parameters and `l` this layer's
-        index, for a piece that must address a stacked tensor in place
-        (a kernel's operand cannot be a slice without being a copy):
-        such a tensor is then left out of `layer_params`."""
+        index among ALL layers, for a piece that must address a stacked
+        tensor in place (a kernel's operand cannot be a slice without
+        being a copy): such a tensor is then left out of `layer_params`.
+        A leading layer's `lp` says by its keys what kind it is."""
         raise NotImplementedError
 
     def head(self, params: Params, x, prev_ids, eos_id: int):
@@ -107,33 +169,53 @@ def beam_top1(prev_ids: jax.Array, logits: jax.Array,
     return out["selected_ids"][:, 0].astype(jnp.int32)
 
 
+@jax.named_scope("head")
+def rms_head(params: Params, x: jax.Array, prev_ids: jax.Array, eos_id: int,
+             eps: float) -> jax.Array:
+    """Final RMSNorm (`ln_f.scale`), an untied output head (`head.w`) and
+    the greedy pick for the rows `x` [N, H]; `prev_ids` [N] are the tokens
+    that led to them."""
+    from .common import rms_norm
+
+    x = rms_norm(x, params["ln_f.scale"], eps)
+    # float32 logits: bf16 ones lie 0.03 apart near the top of a row, and
+    # the greedy pick would be made among ties
+    logits = jnp.dot(x, params["head.w"].astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    return beam_top1(prev_ids.astype(jnp.int32), logits, eos_id)
+
+
 def serve_layers(model: ServeModel, params: Params, x: jax.Array,
                  positions: jax.Array, k_pool: jax.Array,
                  v_pool: jax.Array, attend):
     """The serve programs' layer loop: `x` through every block with the
-    pools in the loop's carry. `attend(l, q, k, v, kp, vp)` is the one
-    part the programs differ in: it gets the layer index, the layer's
-    projections (x's leading shape, `[..., heads*head_dim]` each) and the
-    WHOLE pools, writes k/v at (l, block, slot), and returns
-    `(ctx [..., heads*head_dim], kp, vp)`. Returns (x, k_pool, v_pool,
-    the layers' stacked counters or None)."""
+    pools in the loop's carry: the model's leading layers one by one, then
+    a scan over the stacked ones (a model whose layers are all alike has
+    no leading ones, and the loop is the scan). `attend(l, lp, q, k, v,
+    kp, vp)` is the one part the programs differ in: it gets the layer
+    index, the layer's parameters and projections and the WHOLE pools,
+    writes k/v at (l, block, slot), and returns `(ctx, kp, vp)`. Returns
+    (x, k_pool, v_pool, the stacked layers' counters or None)."""
 
     def layer_body(carry, per_layer):
         h, kp, vp = carry
         lp, l = per_layer
         y = model.norm_attn(lp, h)
         q, k, v = model.qkv(lp, y, positions)
-        ctx, kp, vp = attend(l, q, k, v, kp, vp)
+        ctx, kp, vp = attend(l, lp, q, k, v, kp, vp)
         h = model.proj(lp, ctx, h)
         y = model.norm_mlp(lp, h)
         out, stats = model.mlp(lp, y, params, l)
         return (h + out, kp, vp), stats
 
-    layers = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
+    lead = model.lead_params(params)
+    layers = jnp.arange(len(lead), k_pool.shape[0], dtype=jnp.int32)
     with jax.named_scope("layers"):
+        carry = (x, k_pool, v_pool)
+        for l, lp in enumerate(lead):
+            carry, _ = layer_body(carry, (lp, jnp.int32(l)))
         (x, k_pool, v_pool), stats = jax.lax.scan(
-            layer_body, (x, k_pool, v_pool),
-            (model.layer_params(params), layers))
+            layer_body, carry, (model.layer_params(params), layers))
     return x, k_pool, v_pool, stats
 
 
@@ -150,28 +232,22 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     kv_cache.write_prefill_kv) and, being causally AFTER every real
     position, never contribute to the last real position's logits.
     """
-    from ..ops.pallas import attention as pa
     from ..serving import kv_cache as kvc
 
     B, T = ids.shape
-    nh, hd = model.heads, model.head_dim
     adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
     positions = jnp.arange(T, dtype=jnp.int32)[None]
     with jax.named_scope("embed"):
         x = model.embed(params, ids, positions).astype(adt)
 
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_prefill_kv(kp, l, k[0].reshape(T, *stored),
+    def attend(l, lp, q, k, v, kp, vp):
+        kp = kvc.write_prefill_kv(kp, l, k[0].reshape(T, *kp.shape[3:]),
                                   block_table, block_size)
-        vp = kvc.write_prefill_kv(vp, l, v[0].reshape(T, *stored),
+        vp = kvc.write_prefill_kv(vp, l, v[0].reshape(T, *vp.shape[3:]),
                                   block_table, block_size)
-        q = q.reshape(B, T, nh, hd)
-        k = k.reshape(B, T, nh, hd)
-        v = v.reshape(B, T, nh, hd)
         with jax.named_scope("attention"):
-            ctx = pa.mha(q, k, v, causal=True, scale=1.0 / math.sqrt(hd))
-        return ctx.reshape(B, T, nh * hd), kp, vp
+            ctx = model.attend_prompt(lp, q, k, v)
+        return ctx, kp, vp
 
     x, k_pool, v_pool, _ = serve_layers(model, params, x, positions,
                                         k_pool, v_pool, attend)
@@ -181,32 +257,42 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     return tok, k_pool, v_pool
 
 
-def gather_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                     layer: jax.Array, block_tables: jax.Array,
-                     positions: jax.Array, heads: int) -> jax.Array:
-    """Decode attention over a gathered copy of every slot's whole table:
-    q `[S, heads*head_dim]` against layer `layer` of the pools through
-    block_tables `[S, MB]`, key positions `<= positions[s]` -> `[S,
-    heads*head_dim]`. The route off the TPU, and what the paged kernel
-    (ops/pallas/paged_attention.py) is compared with on it."""
-    from ..serving import kv_cache as kvc
-
-    S = q.shape[0]
-    hd = q.shape[1] // heads
-    keys = kvc.gather_kv(k_pool, layer, block_tables)   # [S, M, *stored]
-    vals = kvc.gather_kv(v_pool, layer, block_tables)
+def mha_cached(q: jax.Array, keys: jax.Array, vals: jax.Array,
+               pos: jax.Array, heads: int) -> jax.Array:
+    """Multi-head attention of query rows over a gathered context (the
+    default `ServeModel.attend_cached`): q `[S, W, heads*head_dim]`, keys
+    and vals `[S, M, heads*head_dim]`, row (s, w) sees key positions `<=
+    pos[s, w]` -> `[S, W, heads*head_dim]`."""
+    S, W, width = q.shape
     m = keys.shape[1]
-    q = q.reshape(S, heads, hd)
+    hd = width // heads
+    q = q.reshape(S, W, heads, hd)
     keys = keys.reshape(S, m, heads, hd)
     vals = vals.reshape(S, m, heads, hd)
+    scores = jnp.einsum("swnd,smnd->swnm", q, keys) * (1.0 / math.sqrt(hd))
+    mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] <= pos[:, :, None]
+    scores = jnp.where(mask[:, :, None, :], scores, -1e9)
+    att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    ctx = jnp.einsum("swnm,smnd->swnd", att.astype(keys.dtype), vals)
+    return ctx.reshape(S, W, width)
+
+
+def cached_attention(attend, q: jax.Array, k_pool: jax.Array,
+                     v_pool: jax.Array, layer: jax.Array,
+                     block_tables: jax.Array, pos: jax.Array) -> jax.Array:
+    """Attention of the query rows q `[S, W, ...]` over a gathered copy of
+    every slot's whole table: layer `layer` of the pools through
+    block_tables `[S, MB]`, key positions `<= pos[s, w]`, by `attend(q,
+    keys, vals, pos)`, a model's `attend_cached` with its layer's
+    parameters bound -> `[S, W, ctx]`. What `prefill_chunk` and
+    `verify_step` run everywhere and `decode_step` off the TPU, and what a
+    paged kernel is compared with on it."""
+    from ..serving import kv_cache as kvc
+
+    keys = kvc.gather_kv(k_pool, layer, block_tables)   # [S, M, width]
+    vals = kvc.gather_kv(v_pool, layer, block_tables)
     with jax.named_scope("attention"):
-        scores = jnp.einsum("snd,smnd->snm", q, keys) * (1.0 / math.sqrt(hd))
-        mask = jnp.arange(m, dtype=jnp.int32)[None, :] \
-            <= positions[:, None]
-        scores = jnp.where(mask[:, None, :], scores, -1e9)
-        att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        ctx = jnp.einsum("snm,smnd->snd", att.astype(k_pool.dtype), vals)
-    return ctx.reshape(S, heads * hd)
+        return attend(q, keys, vals, pos)
 
 
 def decode_step(model: ServeModel, params: Params, ids: jax.Array,
@@ -226,28 +312,29 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     from ..serving import kv_cache as kvc
 
     S = ids.shape[0]
-    nh = model.heads
     adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
     with jax.named_scope("embed"):
         x = model.embed(params, ids, positions).astype(adt)
 
-    # the one gate (ops/pallas/paged_attention.py): on a TPU the kernel
-    # reads the live blocks through the table; elsewhere the gather below
-    paged = pa.use_paged(x, k_pool, nh)
-    pa.GATE_COUNTS["paged" if paged else "gather"] += 1
+    # the one gate (ops/pallas/paged_attention.py, asked through the model,
+    # whose cache it is): on a TPU a kernel reads the live blocks through
+    # the table; elsewhere the gathered form below
+    route = model.paged_route(x, k_pool, v_pool)
+    pa.GATE_COUNTS[route or "gather"] += 1
 
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_token_kv(kp, l, k.reshape(S, *stored), block_tables,
-                                positions, block_size)
-        vp = kvc.write_token_kv(vp, l, v.reshape(S, *stored), block_tables,
-                                positions, block_size)
-        if paged:
+    def attend(l, lp, q, k, v, kp, vp):
+        kp = kvc.write_token_kv(kp, l, k.reshape(S, *kp.shape[3:]),
+                                block_tables, positions, block_size)
+        vp = kvc.write_token_kv(vp, l, v.reshape(S, *vp.shape[3:]),
+                                block_tables, positions, block_size)
+        if route:
             with jax.named_scope("attention"):
-                ctx = pa.paged_attention(q, kp, vp, l, block_tables,
-                                         positions, heads=nh)
+                ctx = model.attend_paged(lp, q, kp, vp, l, block_tables,
+                                         positions)
         else:
-            ctx = gather_attention(q, kp, vp, l, block_tables, positions, nh)
+            ctx = cached_attention(
+                functools.partial(model.attend_cached, lp), q[:, None], kp,
+                vp, l, block_tables, positions[:, None])[:, 0]
         return ctx, kp, vp
 
     x, k_pool, v_pool, stats = serve_layers(model, params, x, positions,
@@ -280,9 +367,7 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
     from ..serving import kv_cache as kvc
 
     _, C = ids.shape
-    nh, hd = model.heads, model.head_dim
     adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
     pos = start + jnp.arange(C, dtype=jnp.int32)
     # the final slice's padded tail can run past the positions the model
     # addresses; clamp (those rows' outputs are never consumed, their KV
@@ -291,26 +376,16 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
         x = model.embed(params, ids[0],
                         jnp.minimum(pos, model.max_len - 1)).astype(adt)
 
-    scale = 1.0 / math.sqrt(hd)
-
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_chunk_kv(kp, l, k.reshape(C, *stored), block_table,
-                                start, block_size)
-        vp = kvc.write_chunk_kv(vp, l, v.reshape(C, *stored), block_table,
-                                start, block_size)
-        keys = kvc.gather_kv(kp, l, block_table[None])[0]   # [M, *stored]
-        vals = kvc.gather_kv(vp, l, block_table[None])[0]
-        m = keys.shape[0]
-        q = q.reshape(C, nh, hd)
-        keys = keys.reshape(m, nh, hd)
-        vals = vals.reshape(m, nh, hd)
-        with jax.named_scope("attention"):
-            scores = jnp.einsum("cnd,mnd->cnm", q, keys) * scale
-            mask = jnp.arange(m, dtype=jnp.int32)[None, :] <= pos[:, None]
-            scores = jnp.where(mask[:, None, :], scores, -1e9)
-            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("cnm,mnd->cnd", att.astype(adt), vals)
-        return ctx.reshape(C, nh * hd), kp, vp
+    def attend(l, lp, q, k, v, kp, vp):
+        kp = kvc.write_chunk_kv(kp, l, k.reshape(C, *kp.shape[3:]),
+                                block_table, start, block_size)
+        vp = kvc.write_chunk_kv(vp, l, v.reshape(C, *vp.shape[3:]),
+                                block_table, start, block_size)
+        # one "slot" of C query rows over the sequence's own table
+        ctx = cached_attention(
+            functools.partial(model.attend_cached, lp), q[None], kp, vp, l,
+            block_table[None], pos[None])[0]
+        return ctx, kp, vp
 
     x, k_pool, v_pool, _ = serve_layers(model, params, x, pos, k_pool,
                                         v_pool, attend)
@@ -343,35 +418,20 @@ def verify_step(model: ServeModel, params: Params, ids: jax.Array,
     from ..serving import kv_cache as kvc
 
     S, W = ids.shape
-    nh, hd = model.heads, model.head_dim
     adt = k_pool.dtype
-    stored = k_pool.shape[3:]     # how the pool stores one token
     pos = positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     with jax.named_scope("embed"):
         x = model.embed(params, ids,
                         jnp.minimum(pos, model.max_len - 1)).astype(adt)
 
-    scale = 1.0 / math.sqrt(hd)
-
-    def attend(l, q, k, v, kp, vp):
-        kp = kvc.write_span_kv(kp, l, k.reshape(S, W, *stored), block_tables,
-                               positions, block_size)
-        vp = kvc.write_span_kv(vp, l, v.reshape(S, W, *stored), block_tables,
-                               positions, block_size)
-        keys = kvc.gather_kv(kp, l, block_tables)       # [S, M, *stored]
-        vals = kvc.gather_kv(vp, l, block_tables)
-        m = keys.shape[1]
-        q = q.reshape(S, W, nh, hd)
-        keys = keys.reshape(S, m, nh, hd)
-        vals = vals.reshape(S, m, nh, hd)
-        with jax.named_scope("attention"):
-            scores = jnp.einsum("swnd,smnd->swnm", q, keys) * scale
-            mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
-                <= pos[:, :, None]
-            scores = jnp.where(mask[:, :, None, :], scores, -1e9)
-            att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-            ctx = jnp.einsum("swnm,smnd->swnd", att.astype(adt), vals)
-        return ctx.reshape(S, W, nh * hd), kp, vp
+    def attend(l, lp, q, k, v, kp, vp):
+        kp = kvc.write_span_kv(kp, l, k.reshape(S, W, *kp.shape[3:]),
+                               block_tables, positions, block_size)
+        vp = kvc.write_span_kv(vp, l, v.reshape(S, W, *vp.shape[3:]),
+                               block_tables, positions, block_size)
+        ctx = cached_attention(functools.partial(model.attend_cached, lp),
+                               q, kp, vp, l, block_tables, pos)
+        return ctx, kp, vp
 
     x, k_pool, v_pool, _ = serve_layers(model, params, x, pos, k_pool,
                                         v_pool, attend)

@@ -17,7 +17,8 @@ then a final RMSNorm and an output head of its own. No token is ever
 dropped, so a row's result depends on that row alone: the decode engine
 serves it (`OlmoeConfig.serve_model()`, models/decoder.py) through the
 same four programs as GPT-2. `apply` is the full forward pass for
-training and scoring; both run ONE expert-layer function, `expert_mlp`.
+training and scoring; both run ONE expert-layer function, `expert_mlp`
+(models/moe.py, shared with the other sparse decoder).
 """
 
 from __future__ import annotations
@@ -29,10 +30,9 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas.grouped_matmul import grouped_matmul
 from ..parallel.sharding import shard
-from . import decoder as _decoder
-from .common import Params
+from . import decoder as _decoder, moe as _moe
+from .common import Params, rms as _rms, rms_norm as _rms_norm
 
 
 @dataclasses.dataclass
@@ -57,6 +57,10 @@ class OlmoeConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden // self.heads
+
+    @property
+    def routing(self) -> _moe.Routing:
+        return _moe.Routing(self.n_experts, self.top_k)
 
     def serve_model(self) -> "OlmoeServe":
         """This configuration behind the interface the decode engine
@@ -140,20 +144,9 @@ def init(rng: jax.Array, cfg: OlmoeConfig, dtype=jnp.float32
 # Layer scopes (`jax.named_scope`: HLO metadata, no op, no run-time cost),
 # named as models/gpt.py names them, with this block's own parts nested
 # INSIDE them so that a reduction by the shared names still adds up: `ln`;
-# `qkv` (holding `qk_norm` and `rope`); `proj`; `mlp` (holding `router`,
-# `moe_route`: sort, gather and weighted combine, and `experts`: the
-# grouped matmuls); `head`. tests/test_layer_scopes.py holds the list.
-
-
-def _rms(x, scale, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-@jax.named_scope("ln")
-def _rms_norm(x, scale, eps):
-    return _rms(x, scale, eps)
+# `qkv` (holding `qk_norm` and `rope`); `proj`; `mlp` (models/moe.py: holding
+# `router`, `moe_route`: sort, gather and weighted combine, and `experts`:
+# the grouped matmuls); `head`. tests/test_layer_scopes.py holds the list.
 
 
 def _rope(x, positions, theta: float):
@@ -188,83 +181,6 @@ def _qkv(lp, y, positions, cfg: OlmoeConfig):
 @jax.named_scope("proj")
 def _proj(lp, ctx, res):
     return res + ctx @ lp["blk.wo"].astype(ctx.dtype)
-
-
-@jax.named_scope("mlp")
-def expert_mlp(lp, y, cfg: OlmoeConfig, layer=None):
-    """The sparse expert layer for the rows `y` [..., hidden]: dropless
-    top_k routing. Every (row, chosen expert) pair is computed: the pairs
-    are sorted by expert, the three projections run as grouped matmuls
-    over the ragged groups (ops/pallas/grouped_matmul.py: the megablox
-    kernel on the chip, `jax.lax.ragged_dot` off it), and each pair's result
-    goes back to its row weighted by the router's probability. A row's
-    result depends on that row alone, to the bit.
-
-    `lp` holds this layer's router and the expert tensors `blk.w_gate`,
-    `blk.w_up`, `blk.w_down`: the layer's own `[E, ...]` (`layer` None:
-    the full forward pass, whose scan slices them), or the stacks of ALL
-    layers `[L, E, ...]` with `layer` this one's index (the serve
-    programs). A stack is addressed in place, as L*E groups of which only
-    this layer's E hold rows: a kernel's operand cannot be a slice without
-    being a copy, and a copy of a layer's experts is 0.8 GB written and
-    read again, as much as a 16-row decode step reads of them at all.
-
-    Returns (out [..., hidden], {"experts_hit": experts with at least one
-    pair, "expert_load_max": most pairs on one expert}), the counters of
-    THIS layer and call."""
-    E, K = cfg.n_experts, cfg.top_k
-    x = y.reshape(-1, y.shape[-1])
-    n = x.shape[0]
-    with jax.named_scope("router"):
-        # float32 out of the matmul, not a rounded bf16 widened again: a
-        # near-tie between the 8th and 9th expert is decided as exactly
-        # as the inputs allow
-        logits = jnp.dot(x, lp["blk.router"].astype(x.dtype),
-                         preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weight, expert = jax.lax.top_k(probs, K)         # [n, K], unnormed
-    with jax.named_scope("moe_route"):
-        expert = expert.reshape(-1).astype(jnp.int32)    # pair (row, k)
-        order = jnp.argsort(expert, stable=True)         # pairs by expert
-        counts = jnp.zeros((E,), jnp.int32).at[expert].add(1)
-        xs = x[order // K]                               # [n*K, hidden]
-        groups = counts
-        if layer is not None:
-            n_layers = lp["blk.w_gate"].shape[0]
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros((n_layers * E,), jnp.int32), counts, (layer * E,))
-    with jax.named_scope("experts"):
-        def experts(name):      # [E or L*E, in, out], in the rows' dtype
-            w = lp[name]
-            return w.reshape((-1,) + w.shape[-2:]).astype(x.dtype)
-
-        gate = grouped_matmul(xs, experts("blk.w_gate"), groups)
-        up = grouped_matmul(xs, experts("blk.w_up"), groups)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, experts("blk.w_down"),
-                            groups)
-    with jax.named_scope("moe_route"):
-        # back to (row, k) order, then each row's K results summed in k's
-        # order: a gather and a fixed-order sum, not a scatter-add, so a
-        # row's bits do not depend on where its pairs were sorted to
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * K, dtype=order.dtype))
-        ys = ys[back].reshape(n, K, -1).astype(jnp.float32)
-        out = jnp.sum(ys * weight[..., None], axis=1)
-    stats = {"experts_hit": jnp.sum(counts > 0).astype(jnp.int32),
-             "expert_load_max": jnp.max(counts)}
-    return out.astype(y.dtype).reshape(y.shape), stats
-
-
-@jax.named_scope("head")
-def _head(params: Params, x, prev_ids, eos_id: int, cfg: OlmoeConfig):
-    """Final RMSNorm, the untied output head and the greedy pick for the
-    rows `x` [N, H]; `prev_ids` [N] are the tokens that led to them."""
-    x = _rms_norm(x, params["ln_f.scale"], cfg.rms_eps)
-    # float32 logits: bf16 ones lie 0.03 apart near the top of a row, and
-    # the greedy pick would be made among ties
-    logits = jnp.dot(x, params["head.w"].astype(x.dtype),
-                     preferred_element_type=jnp.float32)
-    return _decoder.beam_top1(prev_ids.astype(jnp.int32), logits, eos_id)
 
 
 _EXPERTS = ("blk.w_gate", "blk.w_up", "blk.w_down")
@@ -304,20 +220,15 @@ class OlmoeServe(_decoder.ServeModel):
         return _rms_norm(h, lp["blk.ln_post.scale"], self.cfg.rms_eps)
 
     def mlp(self, lp, y, params, l):
-        return expert_mlp(dict(lp, **{k: params[k] for k in _EXPERTS}), y,
-                          self.cfg, layer=l)
+        return _moe.expert_mlp(dict(lp, **{k: params[k] for k in _EXPERTS}),
+                               y, self.cfg.routing, layer=l)
 
     def head(self, params, x, prev_ids, eos_id):
-        return _head(params, x, prev_ids, eos_id, self.cfg)
+        return _decoder.rms_head(params, x, prev_ids, eos_id,
+                                 self.cfg.rms_eps)
 
     def step_facts(self, stats) -> Dict:
-        """`experts_hit`: distinct experts selected, summed over the
-        layers (what a step must read of the expert weights);
-        `expert_load_max`: most pairs on one expert in any layer. Both
-        count every row of the step's batch, idle slots included: the
-        device computes them all."""
-        return {"experts_hit": int(stats["experts_hit"].sum()),
-                "expert_load_max": int(stats["expert_load_max"].max())}
+        return _moe.step_facts(stats)
 
 
 def _block(lp, x, positions, cfg: OlmoeConfig):
@@ -334,7 +245,7 @@ def _block(lp, x, positions, cfg: OlmoeConfig):
     x = shard(_proj(lp, ctx.reshape(B, T, -1), x),
               ("batch", "seq", "embed"))
     y = _rms_norm(x, lp["blk.ln_post.scale"], cfg.rms_eps)
-    out, _ = expert_mlp(lp, y, cfg)
+    out, _ = _moe.expert_mlp(lp, y, cfg.routing)
     return shard(x + out, ("batch", "seq", "embed"))
 
 

@@ -309,6 +309,12 @@ def _use_splash(q, k, mask, causal) -> bool:
     return T >= _SPLASH_MIN_T
 
 
+def _block(cap: int, T: int) -> int:
+    """The largest multiple of 128 that divides `T` and is at most `cap`
+    (`T` itself when it is smaller)."""
+    return max(b for b in range(128, min(cap, T) + 1, 128) if T % b == 0)
+
+
 def _splash_kernel(Tq: int, Tk: int, n_heads: int, causal: bool,
                    interpret: bool = False, save_residuals: bool = False):
     # NOT cached: the kernel pytree holds mask-info arrays; under a vjp
@@ -324,12 +330,16 @@ def _splash_kernel(Tq: int, Tk: int, n_heads: int, causal: bool,
     # of score buffers). Big fwd KV blocks amortize the online-softmax
     # rescale; bwd q-blocks stay at 512 to fit dq/dkv accumulators in
     # VMEM.
-    bq = min(1024, Tq)
-    bkv = min(2048, Tk)
-    bqb = min(512, Tq)
+    # A block has to divide its sequence (the gate lets in every multiple
+    # of 128): the tuned size where it does, as at every power of two, else
+    # the largest multiple of 128 under it that does (T=3072: KV blocks of
+    # 1536).
+    bq = _block(1024, Tq)
+    bkv = _block(2048, Tk)
+    bqb = _block(512, Tq)
     # bwd dkv/dq kv-block: 2048 wins at T>=4096 (17.0 vs 19.0 ms), 1024
     # wins at T<=2048 (6.8 vs 9.2 ms at T=2048)
-    bkvb = min(2048 if Tk >= 4096 else 1024, Tk)
+    bkvb = _block(2048 if Tk >= 4096 else 1024, Tk)
     sizes = sa.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkv,
         block_q_dkv=bqb, block_kv_dkv=bkvb, block_kv_dkv_compute=bkvb,

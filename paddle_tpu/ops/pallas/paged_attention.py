@@ -66,33 +66,64 @@ _CHUNK = 256
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def use_paged(q: jax.Array, pool: jax.Array, heads: int) -> bool:
-    """Whether decode attention takes the kernel: the computation runs on
-    a TPU, outside a mesh that would have to partition a Mosaic kernel,
-    over a pool `[L, NB, BS, H*D]` whose blocks are whole tiles."""
-    if pool.ndim != 4 or q.dtype != pool.dtype \
-            or pool.dtype.itemsize not in (2, 4):
+def _tiles(pool: jax.Array) -> bool:
+    """Whether a pool `[L, NB, BS, width]` is one the kernels can address:
+    a block is whole tiles, fetched `_CHUNK // BS` to a chunk."""
+    if pool.ndim != 4 or pool.dtype.itemsize not in (2, 4):
         return False
-    bs, hd = pool.shape[2:]
-    head_dim = hd // heads
-    return (_attention._platform(q) == "tpu"
-            and _attention._mesh_partitionable(q)
-            and heads * head_dim == hd and hd % 128 == 0
-            and (head_dim == 64 or head_dim % 128 == 0)
-            and bs % (32 // pool.dtype.itemsize) == 0
+    bs, width = pool.shape[2:]
+    return (width % 128 == 0 and bs % (32 // pool.dtype.itemsize) == 0
             and _CHUNK % bs == 0)
 
 
-def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, done_ref, *, heads: int, scale: float):
+def _on_one_tpu(q: jax.Array) -> bool:
+    """The computation runs on a TPU, outside a mesh that would have to
+    partition a Mosaic kernel."""
+    return _attention._platform(q) == "tpu" \
+        and _attention._mesh_partitionable(q)
+
+
+def use_paged(q: jax.Array, pool: jax.Array, heads: int) -> bool:
+    """Whether multi-head decode attention takes the kernel: on one TPU,
+    over pools `[L, NB, BS, H*D]` whose blocks are whole tiles."""
+    if not _tiles(pool) or q.dtype != pool.dtype:
+        return False
+    hd = pool.shape[3]
+    head_dim = hd // heads
+    return (_on_one_tpu(q) and heads * head_dim == hd
+            and (head_dim == 64 or head_dim % 128 == 0))
+
+
+def use_paged_latent(q: jax.Array, c_pool: jax.Array, r_pool: jax.Array,
+                     heads: int) -> bool:
+    """Whether latent decode attention takes the kernel: on one TPU, over
+    two pools of whole tiles that share their blocks, with the heads a
+    whole number of sublane tiles."""
+    return (_tiles(c_pool) and _tiles(r_pool)
+            and q.dtype == c_pool.dtype == r_pool.dtype
+            and c_pool.shape[:3] == r_pool.shape[:3]
+            and heads % (32 // c_pool.dtype.itemsize) == 0
+            and _on_one_tpu(q))
+
+
+def _walk(layer_ref, tables_ref, pos_ref, pools, bufs, sems, done_ref,
+          zeroed, setup, step):
+    """One grid step's walk over slot `program_id(0)`'s live blocks of
+    layer `layer_ref[0]`: chunk by chunk through the double buffers `bufs`
+    (one `[2, _CHUNK, width]` a pool, `sems` `[2, len(pools)]`), one DMA a
+    block and pool, the next chunk (or the next slot's first) in flight
+    while `step(query, c, buf, carry) -> carry` consumes chunk `c` from
+    `bufs[i][buf]`. `setup() -> (query, first carry)` builds the slot's
+    query operands; it runs AFTER the call's first copies are started, so
+    that no DMA waits for it. `zeroed` are the buffers whose stale rows
+    meet exact zero weights and so must be finite from the start. Returns
+    (query, the last carry: the first, for a slot that reads nothing)."""
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
-    _, chunk, hd = kbuf.shape
-    bs = k_hbm.shape[2]
+    chunk = bufs[0].shape[1]
+    bs = pools[0].shape[2]
     per_chunk = chunk // bs
     max_blocks = tables_ref.shape[1]
-    head_dim = hd // heads
-    m_rows = -(-heads // 16) * 16
     layer = layer_ref[0]
 
     def live_blocks(slot):
@@ -108,8 +139,7 @@ def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         def one(j, carry):
             blk = tables_ref[slot, first + j]
             rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
-            for which, (hbm, dst) in enumerate(((k_hbm, kbuf),
-                                                (v_hbm, vbuf))):
+            for which, (hbm, dst) in enumerate(zip(pools, bufs)):
                 getattr(pltpu.make_async_copy(
                     hbm.at[layer, blk], dst.at[buf, rows],
                     sems.at[buf, which]), act)()
@@ -120,7 +150,8 @@ def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     @pl.when(s == 0)
     def _():
         done_ref[0] = 0
-        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        for buf in zeroed:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
         each_copy(0, 0, 0, "start")
 
     def start_next_slot(buf):
@@ -130,20 +161,13 @@ def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     done = done_ref[0]          # chunks consumed so far: the buffers' turn
     chunks = (live_blocks(s) + per_chunk - 1) // per_chunk
-    pos = pos_ref[s]
-
-    row = lax.broadcasted_iota(jnp.int32, (m_rows, hd), 0)
-    col = lax.broadcasted_iota(jnp.int32, (m_rows, hd), 1)
-    own = (col >= row * head_dim) & (col < (row + 1) * head_dim)
-    q = jnp.broadcast_to(q_ref[...].astype(jnp.float32), (m_rows, hd))
-    q = jnp.where(own, q, 0.0).astype(kbuf.dtype)
+    query, carry = setup()
 
     @pl.when(chunks == 0)
     def _():
         start_next_slot(done % 2)
 
     def consume(c, carry):
-        m, l, acc = carry
         buf = (done + c) % 2
 
         @pl.when(c + 1 < chunks)
@@ -155,29 +179,100 @@ def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
             start_next_slot(1 - buf)
 
         each_copy(s, c, buf, "wait")
-        k = kbuf[buf]
-        v = vbuf[buf]
-        sc = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32) * scale
-        tok = c * chunk + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(tok <= pos, sc, _MASKED)
-        m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(sc - m_new)
-        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-        acc = alpha * acc + jnp.dot(p.astype(v.dtype), v,
-                                    preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return step(query, c, buf, carry)
 
-    m, l, acc = lax.fori_loop(
-        0, chunks, consume,
-        (jnp.full((m_rows, 1), _MASKED, jnp.float32),
-         jnp.zeros((m_rows, 1), jnp.float32),
-         jnp.zeros((m_rows, hd), jnp.float32)))
+    carry = lax.fori_loop(0, chunks, consume, carry)
     done_ref[0] = done + chunks
+    return query, carry
+
+
+def _softmax_step(sc, vals, c, pos, carry):
+    """One chunk of the online softmax: scores `sc` [M, chunk] (float32,
+    scaled) of the tokens `c * chunk ...`, of which those `<= pos` count,
+    against `vals` [chunk, width]."""
+    m, l, acc = carry
+    tok = c * sc.shape[1] + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    sc = jnp.where(tok <= pos, sc, _MASKED)
+    m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(sc - m_new)
+    l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+    acc = alpha * acc + jnp.dot(p.astype(vals.dtype), vals,
+                                preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def _softmax_init(rows: int, width: int):
+    return (jnp.full((rows, 1), _MASKED, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, width), jnp.float32))
+
+
+def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sems, done_ref, *, heads: int, scale: float):
+    hd = kbuf.shape[2]
+    head_dim = hd // heads
+    m_rows = -(-heads // 16) * 16
+
+    def setup():
+        pos = pos_ref[pl.program_id(0)]
+        row = lax.broadcasted_iota(jnp.int32, (m_rows, hd), 0)
+        col = lax.broadcasted_iota(jnp.int32, (m_rows, hd), 1)
+        own = (col >= row * head_dim) & (col < (row + 1) * head_dim)
+        q = jnp.broadcast_to(q_ref[...].astype(jnp.float32), (m_rows, hd))
+        q = jnp.where(own, q, 0.0).astype(kbuf.dtype)
+        return (pos, q, own), _softmax_init(m_rows, hd)
+
+    def step(query, c, buf, carry):
+        pos, q, _ = query
+        sc = lax.dot_general(q, kbuf[buf], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32) * scale
+        return _softmax_step(sc, vbuf[buf], c, pos, carry)
+
+    (_, _, own), (m, l, acc) = _walk(
+        layer_ref, tables_ref, pos_ref, (k_hbm, v_hbm), (kbuf, vbuf), sems,
+        done_ref, (vbuf,), setup, step)
     # an inactive slot (l == 0) gives zeros; its row is never read
     ctx = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
     o_ref[...] = jnp.sum(ctx, axis=0, keepdims=True)
+
+
+def _latent_kernel(layer_ref, tables_ref, pos_ref, ql_ref, qr_ref, c_hbm,
+                   r_hbm, o_ref, cbuf, rbuf, sems, done_ref, *,
+                   scale: float):
+    last = (((1,), (1,)), ((), ()))
+
+    def setup():
+        # [heads, latent] with W_UK in it already, [heads, rotary lanes]
+        return (pos_ref[pl.program_id(0)], ql_ref[...], qr_ref[...]), \
+            _softmax_init(*ql_ref.shape)
+
+    def step(query, c, buf, carry):
+        pos, ql, qr = query
+        ctx = cbuf[buf]         # keys AND values: one row a token, all heads
+        sc = (lax.dot_general(ql, ctx, last,
+                              preferred_element_type=jnp.float32)
+              + lax.dot_general(qr, rbuf[buf], last,
+                                preferred_element_type=jnp.float32)) * scale
+        return _softmax_step(sc, ctx, c, pos, carry)
+
+    # rbuf meets the mask alone; cbuf's stale rows meet zero weights
+    _, (m, l, acc) = _walk(layer_ref, tables_ref, pos_ref, (c_hbm, r_hbm),
+                           (cbuf, rbuf), sems, done_ref, (cbuf,), setup,
+                           step)
+    o_ref[...] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def _scalars(layer, block_tables, positions):
+    return (jnp.reshape(layer, (1,)).astype(jnp.int32),
+            block_tables.astype(jnp.int32), positions.astype(jnp.int32))
+
+
+def _scratch(k_pool, v_pool):
+    return [pltpu.VMEM((2, _CHUNK, k_pool.shape[3]), k_pool.dtype),
+            pltpu.VMEM((2, _CHUNK, v_pool.shape[3]), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32)]
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -204,18 +299,51 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((None, 1, hd), lambda s, *_: (s, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, _CHUNK, hd), k_pool.dtype),
-                pltpu.VMEM((2, _CHUNK, hd), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SMEM((1,), jnp.int32),
-            ]),
+            scratch_shapes=_scratch(k_pool, v_pool)),
         out_shape=jax.ShapeDtypeStruct((n_slots, 1, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      block_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      q[:, None, :], k_pool, v_pool)
+    )(*_scalars(layer, block_tables, positions), q[:, None, :], k_pool,
+      v_pool)
     return out[:, 0, :].astype(q.dtype)
+
+
+def paged_latent_attention(q_latent: jax.Array, q_rope: jax.Array,
+                           c_pool: jax.Array, r_pool: jax.Array,
+                           layer: jax.Array, block_tables: jax.Array,
+                           positions: jax.Array, *, scale: float,
+                           interpret: bool = False) -> jax.Array:
+    """Latent (MLA) decode attention in the absorbed form: the queries
+    q_latent `[S, H, C]` (W_UK absorbed) and q_rope `[S, H, R]` against
+    layer `layer` of the latent pool `[L, NB, BS, C]` and the rotary-key
+    pool `[L, NB, BS, R]` through block_tables `[S, MB]`. Slot s attends
+    positions `0..positions[s]` with scores `(q_latent . c + q_rope . r) *
+    scale` (float32, as the softmax) and gets `sum a c` `[H, C]` a head in
+    the queries' dtype, for W_UV to take on; a slot whose table starts
+    with the null block gets zeros. Lanes of `q_rope` past the rotary
+    width meet the pool's zero lanes."""
+    n_slots, heads, latent = q_latent.shape
+    rope = q_rope.shape[2]
+    per_slot = lambda s, *_: (s, 0, 0)      # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_slots,),
+            in_specs=[
+                pl.BlockSpec((None, heads, latent), per_slot),
+                pl.BlockSpec((None, heads, rope), per_slot),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, heads, latent), per_slot),
+            scratch_shapes=_scratch(c_pool, r_pool)),
+        out_shape=jax.ShapeDtypeStruct(q_latent.shape, q_latent.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(*_scalars(layer, block_tables, positions), q_latent, q_rope, c_pool,
+      r_pool)

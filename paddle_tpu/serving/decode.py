@@ -361,9 +361,8 @@ class DecodeEngine:
             for k, v in params.items()}
         max_len = int(self.config.max_len or model.max_len)
         self.kv_cfg = KVCacheConfig(
-            layers=model.layers, kv_heads=model.kv_heads,
-            head_dim=model.head_dim, max_len=max_len,
-            block_size=self.config.block_size,
+            layers=model.layers, widths=model.stored,
+            max_len=max_len, block_size=self.config.block_size,
             num_blocks=self.config.num_blocks,
             dtype=str(np.dtype(self._compute_dtype)))
         # resolved grid lives on the ENGINE, never written back into
@@ -393,9 +392,8 @@ class DecodeEngine:
                 k: _precision.cast_floating(v, self._compute_dtype)
                 for k, v in draft_params.items()}
             self._draft_kv_cfg = KVCacheConfig(
-                layers=dmodel.layers, kv_heads=dmodel.kv_heads,
-                head_dim=dmodel.head_dim, max_len=max_len,
-                block_size=self.config.block_size,
+                layers=dmodel.layers, widths=dmodel.stored,
+                max_len=max_len, block_size=self.config.block_size,
                 num_blocks=self.config.num_blocks,
                 dtype=str(np.dtype(self._compute_dtype)))
 
@@ -762,22 +760,23 @@ class DecodeEngine:
         p_sds = jax.tree_util.tree_map(
             lambda a: sds(a.shape, a.dtype), params)
         kv = self._draft_kv_cfg if draft else self.kv_cfg
-        pool = sds(kv.pool_shape, np.dtype(kv.dtype))
+        kpool, vpool = (sds(shape, np.dtype(kv.dtype))
+                        for shape in kv.pool_shapes)
         mb = kv.max_blocks_per_seq
         base = kind[6:] if draft else kind
         if base == "prefill":
             return (p_sds, sds((1, n), np.int32), sds((), np.int32),
-                    pool, pool, sds((mb,), np.int32))
+                    kpool, vpool, sds((mb,), np.int32))
         if base == "chunk":
             return (p_sds, sds((1, n), np.int32), sds((), np.int32),
-                    sds((), np.int32), pool, pool,
+                    sds((), np.int32), kpool, vpool,
                     sds((mb,), np.int32))
         if base == "verify":
             return (p_sds, sds((n, self.spec_k + 1), np.int32),
-                    sds((n,), np.int32), pool, pool,
+                    sds((n,), np.int32), kpool, vpool,
                     sds((n, mb), np.int32))
         return (p_sds, sds((n,), np.int32), sds((n,), np.int32),
-                pool, pool, sds((n, mb), np.int32))
+                kpool, vpool, sds((n, mb), np.int32))
 
     def warmup(self) -> int:
         """AOT-compile (or adopt from the persistent compile cache /
@@ -1141,8 +1140,8 @@ class DecodeEngine:
             "step_ms": self._step_ms(),
             "step_facts": self._step_facts,
             # which route decode attention took, a count a traced decode
-            # program of this process ("paged": the kernel over the live
-            # blocks; "gather": the padded gather)
+            # program of this process ("paged", "paged_latent": a kernel
+            # over the live blocks; "gather": the padded gather)
             "decode_attention": dict(_paged_attention.GATE_COUNTS),
             # which unit whole-prompt writes into the pool took, a count a
             # traced write, two (K and V) a prefill program ("blocks": a
@@ -1702,11 +1701,10 @@ class DecodeEngine:
 
     def _prefix_block_bytes(self) -> int:
         """Device bytes ONE cached block retains across both models'
-        pools (K and V, all layers) — the unit of the memwatch
+        pools (both entries, all layers) — the unit of the memwatch
         prefix_cache owner row."""
         def per(kv: KVCacheConfig) -> int:
-            return (2 * kv.layers * kv.block_size * kv.kv_heads *
-                    kv.head_dim * np.dtype(kv.dtype).itemsize)
+            return kv.layers * kv.block_size * kv.bytes_per_token()
         n = per(self.kv_cfg)
         if self._draft_kv_cfg is not None:
             n += per(self._draft_kv_cfg)

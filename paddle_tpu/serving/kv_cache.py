@@ -4,11 +4,13 @@ The classic serving memory problem (vLLM SOSP'23): a contiguous
 per-sequence KV buffer must be sized for max_seq_len, so HBM scales
 with max_len × batch even when most sequences are short — and XLA's
 static shapes make "grow the buffer" a recompile. The paged design
-keeps ONE preallocated device pool of fixed-size blocks for all layers
-(`[L, num_blocks, block_size, kv_heads*head_dim]`: a token's heads side
-by side in the lane dimension, so nothing pads and a block is
-contiguous on the TPU) plus a tiny per-sequence *block table* mapping
-logical positions to pool blocks.
+keeps ONE preallocated pair of device pools of fixed-size blocks for all
+layers (`[L, num_blocks, block_size, width]` each: what a token stores in
+a layer lies in the lane dimension, so a block is contiguous on the TPU;
+the MODEL says what the two entries are and how wide, `KVCacheConfig`:
+multi-head K and V with a token's heads side by side, or a latent
+cache's compressed vector and shared rotary key) plus a tiny
+per-sequence *block table* mapping logical positions to pool blocks.
 Memory then scales with LIVE TOKENS (rounded up to the block size),
 sequences grow by appending a block id to their table — a host-side
 int, never a new executable — and the decode executable's shapes stay
@@ -27,10 +29,10 @@ the decode step, a prefill chunk and a verified span, whose positions
 start anywhere, a token at a time. Who still
 composes `gather_kv` (a padded copy of every slot's whole table, a
 layer): `prefill_chunk` and `verify_step` everywhere, and `decode_step`
-off the TPU (`decoder.gather_attention`); on a TPU `decode_step` reads
+off the TPU (`decoder.cached_attention`); on a TPU `decode_step` reads
 the live blocks through the table in a kernel
 (`ops/pallas/paged_attention.py`), which relies on this layout: a block
-is `BS` whole sublane tiles of `heads*head_dim` lanes. The scheduler
+is `BS` whole sublane tiles of the entry's lanes. The scheduler
 that decides WHICH sequences own which blocks lives in
 `serving/decode.py`.
 
@@ -72,17 +74,33 @@ class NoBlocksError(RuntimeError):
 
 @dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
-    """Shape of the device pool. `max_len` bounds any single sequence
+    """Shape of the device pools. `max_len` bounds any single sequence
     (prompt + generated) and fixes the block-table width every decode
-    executable is compiled against."""
+    executable is compiled against.
+
+    A token stores TWO entries in a layer, one in each pool, and how wide
+    each is the model says (`models/decoder.ServeModel.stored`); nothing
+    here or in the engine knows what they hold, and the engine passes
+    `widths` alone. `kv_heads` and `head_dim` are the shorthand of a caller
+    that has no model at hand: multi-head attention's K and V,
+    `kv_heads*head_dim` lanes each. A
+    latent (MLA) cache stores the normalised compressed vector, 512 lanes,
+    and the one rotated key part all heads share, 64 values in a pool of
+    128 lanes: `widths=(512, 128)`, 1280 bytes a token a layer in bf16 for
+    1152 of content. The 64 empty lanes are the price of the layout: the
+    TPU tiles the lane dimension by 128, so a 64-wide pool takes the same
+    HBM and every helper and kernel here (a block = whole tiles, one DMA a
+    block) serves both pools as it is; one padded row of 640 lanes costs
+    the same bytes and would want a single-pool engine beside this one."""
 
     layers: int
-    kv_heads: int
-    head_dim: int
     max_len: int
     block_size: int = 16
     num_blocks: int = 64
     dtype: str = "bfloat16"
+    widths: Optional[Tuple[int, int]] = None
+    kv_heads: int = 0
+    head_dim: int = 0
 
     @property
     def max_blocks_per_seq(self) -> int:
@@ -93,18 +111,38 @@ class KVCacheConfig:
         return int(self.num_blocks) - 1  # block 0 is the null block
 
     @property
+    def entry_widths(self) -> Tuple[int, int]:
+        """Lanes of the two entries a token stores in a layer."""
+        if self.widths is not None:
+            return (int(self.widths[0]), int(self.widths[1]))
+        return (int(self.kv_heads * self.head_dim),) * 2
+
+    @property
+    def pool_shapes(self) -> Tuple[Tuple[int, int, int, int],
+                                   Tuple[int, int, int, int]]:
+        """`[L, NB, BS, width]` of each pool: a token's entry lies in the
+        minor-most (lane) dimension (multi-head: its heads side by side)
+        and the `BS` slots of a block in the sublanes above it, so on the
+        TPU a block is one contiguous, unpadded run of tiles and (layer,
+        block, slot) address it without a re-layout."""
+        return tuple((int(self.layers), int(self.num_blocks),
+                      int(self.block_size), w) for w in self.entry_widths)
+
+    @property
     def pool_shape(self) -> Tuple[int, int, int, int]:
-        """`[L, NB, BS, kv_heads*head_dim]`: a token's heads lie side by
-        side in the minor-most (lane) dimension and the `BS` slots of a
-        block in the sublanes above it, so on the TPU a block is one
-        contiguous, unpadded run of tiles and (layer, block, slot)
-        address it without a re-layout."""
-        return (int(self.layers), int(self.num_blocks),
-                int(self.block_size), int(self.kv_heads * self.head_dim))
+        """The one shape of both pools, where the entries are alike."""
+        a, b = self.pool_shapes
+        if a != b:
+            raise ValueError(f"the pools differ in shape: {a} and {b}")
+        return a
+
+    def bytes_per_token(self) -> int:
+        """Stored bytes of one token in ONE layer, both entries."""
+        return sum(self.entry_widths) * jnp.dtype(self.dtype).itemsize
 
     def pool_bytes(self) -> int:
-        """Device bytes of BOTH pools (K and V)."""
-        return 2 * math.prod(self.pool_shape) * \
+        """Device bytes of BOTH pools."""
+        return sum(math.prod(s) for s in self.pool_shapes) * \
             jnp.dtype(self.dtype).itemsize
 
 
@@ -180,6 +218,10 @@ class BlockAllocator:
             "internal_waste_tokens": waste,
             "waste_fraction": round(waste / cap, 4) if cap else 0.0,
             "pool_bytes": self.cfg.pool_bytes(),
+            # what a token stores in a layer: lanes of the two entries
+            # (the model's say) and their bytes
+            "entry_widths": list(self.cfg.entry_widths),
+            "bytes_per_token_layer": self.cfg.bytes_per_token(),
         }
 
 
@@ -194,16 +236,18 @@ class BlockAllocator:
 # models/decoder.py holds the pools in its carry, so a write goes into
 # the donated buffer (one scatter, or write_prefill_kv's copy a block)
 # and no layer's slice is ever taken out or put back. `tok` =
-# `pool.shape[3:]` is how one token is stored: `[kv_heads*head_dim]` in
-# the engine's pools (`KVCacheConfig.pool_shape`); the helpers are generic
-# over it and `kv`'s trailing dimensions match it.
+# `pool.shape[3:]` is how one token is stored: `[width]` in the engine's
+# pools (`KVCacheConfig.pool_shapes`); the helpers are generic over it and
+# `kv`'s trailing dimensions match it. "K (or V)" below reads as "either
+# entry".
 # ---------------------------------------------------------------------------
 
 
 def init_pools(cfg: KVCacheConfig) -> Tuple[jax.Array, jax.Array]:
-    """Zeroed K and V pools, `cfg.pool_shape` each."""
+    """The two zeroed pools, `cfg.pool_shapes`."""
     dt = jnp.dtype(cfg.dtype)
-    return jnp.zeros(cfg.pool_shape, dt), jnp.zeros(cfg.pool_shape, dt)
+    a, b = cfg.pool_shapes
+    return jnp.zeros(a, dt), jnp.zeros(b, dt)
 
 
 @jax.named_scope("kv_write")

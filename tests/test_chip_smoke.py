@@ -132,6 +132,28 @@ def test_rehearse_serve_olmoe(smoke):
     assert info["checked"]["compiles_after_warmup"] == 0
 
 
+def test_rehearse_serve_joyai(smoke):
+    """The serve_joyai phase at a tiny size: the latent-attention model
+    through the same engine and front, its tokens against the benchmark's
+    plain reference in the expanded form (off the chip the gate takes the
+    gathered form; the chip run asserts the kernel's route)."""
+    from paddle_tpu.models import joyai
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = joyai.JoyaiConfig.tiny()
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 15, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(8, 16), max_len=32), prompts, max_new=4,
+        logit_tol=smoke.JOYAI_LOGIT_TOL, model=joyai,
+        reference_gaps=smoke._joyai_reference_gaps)
+    assert info["checked"]["finished"]["length"] == 3
+    assert info["checked"]["compiles_after_warmup"] == 0
+    assert info["checked"]["decode_attention"] == {"gather": 1}
+
+
 @pytest.mark.parametrize("heads,head_dim", [(20, 64), (16, 128)])
 def test_rehearse_paged_attention(smoke, heads, head_dim):
     """The paged_attention phase at the benchmark's two widths, small

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import optax
 import pytest
 
-from paddle_tpu.models import bert, decoder, gpt, olmoe
+from paddle_tpu.models import bert, decoder, gpt, joyai, olmoe
 
 LAYER = {"ln", "qkv", "attention", "proj", "mlp"}
 SERVE = LAYER | {"embed", "layers", "head", "kv_write"}
@@ -114,12 +114,13 @@ def tiny_olmoe():
     return cfg, params, pool
 
 
-def _lower_olmoe(kind, cfg, params, pool):
+def _lower_olmoe(kind, cfg, params, pool, v_pool=None):
     sm = cfg.serve_model()
     kw = dict(block_size=BS, eos_id=1)
     i32 = jnp.int32
-    slots = (jnp.zeros((S,), i32), pool, pool, jnp.zeros((S, MB), i32))
-    one = (pool, pool, jnp.zeros((MB,), i32))
+    v_pool = pool if v_pool is None else v_pool
+    slots = (jnp.zeros((S,), i32), pool, v_pool, jnp.zeros((S, MB), i32))
+    one = (pool, v_pool, jnp.zeros((MB,), i32))
     fn, args = {
         "decode": (decoder.decode_step, (jnp.zeros((S,), i32),) + slots),
         "verify": (decoder.verify_step, (jnp.zeros((S, 3), i32),) + slots),
@@ -146,6 +147,46 @@ def test_olmoe_serve_programs_carry_every_scope(tiny_olmoe, kind, extra):
     # under ln inside the layer loop
     assert any(re.search(r"/layers/.*/ln/[^/]+$", n)
                for n in re.findall(r'op_name="([^"]*)"', text))
+
+
+# The latent model's own parts nest inside the shared names too: the two
+# low-rank projections and RoPE under `qkv`, the absorb products (in the
+# programs that read the cache) under `attention`, the dense layer's MLP
+# and the expert layer's parts, the shared expert among them, under `mlp`
+JOYAI_NESTED = {"mlp": {"router", "moe_route", "experts", "shared_expert",
+                        "dense_mlp"},
+                "qkv": {"mla_q", "mla_kv", "rope"}}
+
+
+@pytest.fixture(scope="module")
+def tiny_joyai():
+    cfg = joyai.JoyaiConfig.tiny()
+    cfg.dtype = "float32"
+    params, _ = joyai.init(jax.random.key(0), cfg)
+    widths = cfg.serve_model().stored
+    return (cfg, params) + tuple(jnp.zeros((cfg.layers, NB, BS, w))
+                                 for w in widths)
+
+
+@pytest.mark.parametrize("kind,extra", [
+    ("decode", {"kv_gather", "absorb"}), ("verify", {"kv_gather", "absorb"}),
+    ("chunk", {"kv_gather", "absorb"}), ("prefill", set())])
+def test_joyai_serve_programs_carry_every_scope(tiny_joyai, kind, extra):
+    text = _lower_olmoe(kind, *tiny_joyai).compile().as_text()
+    nested = set().union(*JOYAI_NESTED.values())
+    missing = (SERVE | extra | nested) - _scopes(text)
+    assert not missing, (kind, missing)
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for op_name in names:
+        path = op_name.split("/")[:-1]
+        for outer, inner in dict(JOYAI_NESTED, attention={"absorb"}).items():
+            for name in inner & set(path):
+                assert outer in path[:path.index(name)], op_name
+    # the leading dense layer runs before the scan, inside `layers`; the
+    # expert layers in its body
+    assert any(re.search(r"/layers/mlp/dense_mlp/", n) for n in names)
+    assert any(re.search(r"/layers/while/body/.*mlp/experts/", n)
+               for n in names)
 
 
 def test_gpt_training_forward_carries_the_scopes(tiny_gpt):

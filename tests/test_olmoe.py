@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import olmoe_ref
-from paddle_tpu.models import decoder, olmoe
+from paddle_tpu.models import decoder, moe, olmoe
 from paddle_tpu.serving import kv_cache as kvc
 from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
 
@@ -88,7 +88,7 @@ def test_kept_probabilities_are_not_renormalised(model):
             kept[t] += probs[t, e]
     assert kept.max() < 1.0
     with jax.default_matmul_precision("highest"):
-        got, stats = olmoe.expert_mlp(lp, jnp.asarray(y), cfg)
+        got, stats = moe.expert_mlp(lp, jnp.asarray(y), cfg.routing)
     scale = np.abs(want).max()
     assert np.abs(np.asarray(got) - want).max() < 1e-5 * max(1.0, scale)
     assert np.abs(np.asarray(got) - want / kept[:, None]).max() > 0.1 * scale

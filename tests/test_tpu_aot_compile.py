@@ -84,6 +84,13 @@ def _paged(heads):
         q, kp, vp, l, t, p, heads=heads)
 
 
+def _paged_latent(ql, qr, cp, rp, l, t, p):
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    return PA.paged_latent_attention(ql, qr, cp, rp, l, t, p,
+                                     scale=192 ** -0.5)
+
+
 def _block_write(pool, layer, kv, blocks):
     from paddle_tpu.ops.pallas import kv_block_write as BW
 
@@ -92,6 +99,7 @@ def _block_write(pool, layer, kv, blocks):
 
 _QKV = "qkv"
 _PAGED = "paged"    # shape: (slots, table blocks, layers, heads, head_dim)
+_LATENT = "latent"  # shape: (slots, table blocks, layers, heads, dtype)
 _BLOCKS = "blocks"  # shape: (bucket, layers, lanes a token, dtype)
 _CASES = [
     # BERT long-seq cell (bs 8, T 4096, 12 heads of 64, bf16), full mask
@@ -126,6 +134,18 @@ _CASES = [
                  id="paged-olmoe-4096x32"),
     pytest.param(_paged(16), _PAGED, (16, 64, 4, 16, 128, jnp.float32),
                  id="paged-olmoe-f32"),
+    # latent (MLA) decode attention: 32 heads over ONE 512-lane row a token
+    # and the 128-lane pool of the rotary key, at the benchmark's 32 slots
+    # x 4608 tokens, and float32 pools
+    pytest.param(_paged_latent, _LATENT, (32, 288, 5, 32, jnp.bfloat16),
+                 id="paged-latent-joyai-4608x32"),
+    pytest.param(_paged_latent, _LATENT, (16, 64, 3, 32, jnp.float32),
+                 id="paged-latent-f32"),
+    # the latent and the rotary key of a 4096-token prompt into their pools
+    pytest.param(_block_write, _BLOCKS, (4096, 5, 512, jnp.bfloat16),
+                 id="block-write-latent-4096"),
+    pytest.param(_block_write, _BLOCKS, (4096, 5, 128, jnp.bfloat16),
+                 id="block-write-rotary-key-4096"),
     # a prompt's K or V into the pool, one DMA a block: the benchmark's
     # longest bucket at GPT-2-large's width, OLMoE's, a 4096-token prompt
     # (256 copies in flight), and float32 pools, whose tile is 8 rows
@@ -159,6 +179,13 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
         pool = sds((layers, slots * blocks + 1, 16, heads * d), dt)
         args = (sds((slots, heads * d), dt), pool, pool, sds((), jnp.int32),
                 sds((slots, blocks), jnp.int32), sds((slots,), jnp.int32))
+    elif kind == _LATENT:
+        slots, blocks, layers, heads, dt = shape
+        nb = slots * blocks + 1
+        args = (sds((slots, heads, 512), dt), sds((slots, heads, 128), dt),
+                sds((layers, nb, 16, 512), dt), sds((layers, nb, 16, 128), dt),
+                sds((), jnp.int32), sds((slots, blocks), jnp.int32),
+                sds((slots,), jnp.int32))
     elif kind == _BLOCKS:
         bucket, layers, hd, dt = shape
         blocks = bucket // 16       # 16 slots of a 1024-token table or more
@@ -176,6 +203,28 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
         ma = compiled.memory_analysis()
         assert ma.alias_size_in_bytes >= np.prod(args[0].shape) \
             * jnp.dtype(dt).itemsize and ma.temp_size_in_bytes < 1e6, ma
+
+
+@pytest.mark.parametrize("T", [2048, 3072])
+def test_splash_prefill_at_latent_widths_compiles_for_v5e(v5e, T):
+    """A whole prompt of the latent model: scores 192 wide, values 128,
+    32 heads, at a bucket that is a power of two and at 3072, which the
+    tuned 2048-token KV block does not divide (the kernel's blocks are
+    then the largest multiples of 128 that do: `A._block`)."""
+    one = SingleDeviceSharding(v5e[0])
+    qk = jax.ShapeDtypeStruct((1, T, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, T, 32, 128), jnp.bfloat16, sharding=one)
+    compiled = jax.jit(
+        lambda q, k, v: A._splash_mha(q, k, v, 192 ** -0.5, True)
+    ).lower(qk, qk, v).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings is not None
+    assert [A._block(c, T) for c in (1024, 2048, 512)] \
+        == ([1024, 2048, 512] if T == 2048 else [1024, 1536, 512])
+    # the sizes every shape had before are the tuned ones still
+    for t in (128, 512, 1024, 2048, 4096, 8192):
+        assert A._block(1024, t) == min(1024, t)
+        assert A._block(2048, t) == min(2048, t)
 
 
 @pytest.mark.parametrize("tp,sp,counter", [
@@ -499,3 +548,107 @@ def test_decode_program_reads_the_live_blocks_through_the_table(
     paged = [k for k in _kernels(text) if "paged_attention" in k]
     assert len(paged) == 1 and "/layers/" in paged[0] \
         and "/attention/" in paged[0], _kernels(text)
+
+
+# ---------------------------------------------------------------------------
+# The latent model at the benchmark's size (1 dense + 4 expert layers of
+# 256 experts, 32 slots x 4608 tokens): what the compiler plans for the
+# weights, the two pools and the cached context. Per-head keys and values
+# of ONE layer's pool would be 3.0 GB; the decode program's temporaries are
+# MBs, because all heads read the one stored row through the kernel.
+# ---------------------------------------------------------------------------
+
+_JOYAI_SLOTS, _JOYAI_CONTEXT = 32, 4608
+
+
+@pytest.fixture(scope="module")
+def joyai_5l(v5e):
+    from paddle_tpu.models import joyai
+    from paddle_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = joyai.JoyaiConfig(layers=5, max_len=_JOYAI_CONTEXT)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = jax.eval_shape(lambda k: joyai.init(k, cfg)[0],
+                            jax.random.key(0))
+    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    sm = cfg.serve_model()
+    kv = KVCacheConfig(
+        layers=sm.layers, kv_heads=sm.kv_heads, head_dim=sm.head_dim,
+        max_len=_JOYAI_CONTEXT, block_size=_BLOCK, widths=sm.stored,
+        num_blocks=_JOYAI_SLOTS * (_JOYAI_CONTEXT // _BLOCK) + 1)
+    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
+    return cfg, params, pools, kv, sds
+
+
+@pytest.mark.parametrize("program", ["decode@32", "prefill@1024"])
+def test_joyai_serve_program_fits_and_reads_the_latent_cache_in_place(
+        joyai_5l, program, monkeypatch):
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, kv, sds = joyai_5l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _JOYAI_CONTEXT // _BLOCK
+    for counts in (gm.GATE_COUNTS, PA.GATE_COUNTS, A.GATE_COUNTS,
+                   kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4)).lower(params, *args).compile()
+    assert kv.pool_shapes == ((5, 9217, 16, 512), (5, 9217, 16, 128))
+    assert kv.bytes_per_token() == 1280
+    ma = compiled.memory_analysis()
+    # weights 11.12 GB + pools 0.94 GB resident, the rest temporaries
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert 12.0e9 < planned < 12.5e9, ma
+    assert ma.temp_size_in_bytes < (0.02e9 if kind == "decode" else 0.2e9), ma
+    assert ma.alias_size_in_bytes >= kv.pool_bytes(), ma
+    text = compiled.as_text()
+    for pool in pools:
+        assert not _pool_movers(text, pool.shape)
+    # no op makes a layer's slice of an expert stack
+    slices = re.findall(r"= \(?bf16\[256,(?:2048,768|768,2048)\]", text)
+    assert not slices, slices[:3]
+    # the expert layers' grouped matmuls are the megablox kernel (traced
+    # once: the four expert layers are one scan body), under mlp/experts
+    assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
+    kernels = _kernels(text)
+    assert sum("/mlp/experts/" in k for k in kernels) == 3 and all(
+        "/mlp/experts/" in k or "/attention/" in k or "/kv_write/" in k
+        for k in kernels), kernels
+    if kind == "decode":
+        assert PA.GATE_COUNTS == {"paged_latent": 1}, PA.GATE_COUNTS
+        # the leading dense layer's and the scan body's: two kernels
+        latent = [k for k in kernels if "paged_latent_attention" in k]
+        assert len(latent) == 2 and all("/attention/" in k for k in latent)
+        # nothing holds a slot's cached context, gathered or expanded to
+        # heads: no result has a dimension of the context's 4608 tokens or
+        # of a table's 288 blocks x 16 (the tables themselves are [32, 288])
+        held = [d for d in re.findall(r"= \(?\w+\[([\d,]+)\]", text)
+                if {"4608", "288,16"} & {d, *re.findall(r"288,16|4608", d)}]
+        assert not held, held[:5]
+    else:
+        # the leading layer and the scan body: two traces of each
+        assert A.GATE_COUNTS == {"splash": 2}, A.GATE_COUNTS
+        assert kvc.PREFILL_WRITE_UNITS == {"blocks": 4}
+        assert sum("kv_block_write" in k for k in kernels) == 4, kernels
+        for pool in pools:
+            assert not _pool_scatters(text, pool.shape)
