@@ -355,7 +355,10 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
     paged_attention.GATE_COUNTS.clear()
     kv_cache.PREFILL_WRITE_UNITS.clear()
     engine = DecodeEngine(params, cfg, decode_cfg)
-    n_phases = len(engine.decode_slots) + len(engine.prefill_buckets)
+    # prefill buckets, slot configurations, and the id assembly of each
+    # pair of slot configurations
+    n_phases = len(engine.prefill_buckets) + len(engine.decode_slots) \
+        + len(engine.decode_slots) ** 2
     ready, compile_s = _timed(engine.warmup)
     assert ready == n_phases, f"only {ready}/{n_phases} phases compiled"
     server = Server(ServingConfig(), decode=engine)

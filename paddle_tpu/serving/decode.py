@@ -22,8 +22,11 @@ owns —
   bake-decode`), and replayed at boot with zero fresh compiles;
 - **lazy token fetches** (PR 5 FetchHandle): each decode step's
   sampled tokens resolve one step LATE — step N dispatches with step
-  N-1's tokens still device-resident, so the host never blocks the
-  device between steps while the batch composition is stable;
+  N-1's tokens still device-resident, and so does an admission's first
+  token: a prefill is queued behind the step in flight, the next step's
+  ids are put together on the device, and the host never blocks the
+  device between steps, whether the batch composition is stable or an
+  admission or a retirement just changed it;
 - **PR 7 precision policies**: bf16 decode by default (pools + compute
   dtype), f32 opt-in for exactness; the policy is part of every
   executable's signature and persistent-cache fingerprint;
@@ -50,6 +53,7 @@ import weakref
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core import compile_cache as _cc
@@ -298,21 +302,34 @@ class _Request:
 
 
 class _Pending:
-    """One in-flight decode step: the lazy token fetch plus the exact
-    batch composition it was dispatched with."""
+    """One program in flight on the device's queue and the lazy fetch of
+    its tokens: a decode step with the exact batch it was dispatched with
+    (`slots`, and `snapshot` their rids), or an admission's prefill
+    (`slots` the one request, `snapshot` None) whose first token is still
+    on the device. The lazy loop keeps them in `_inflight` in device
+    order and resolves them oldest first."""
 
     __slots__ = ("handle", "tok_dev", "snapshot", "slots", "t_dispatch",
                  "stats")
 
-    def __init__(self, handle, tok_dev, snapshot, slots):
-        self.handle = handle
+    def __init__(self, tok_dev, snapshot, slots, t_dispatch=None):
+        self.handle = FetchHandle([tok_dev], site="decode")
         self.tok_dev = tok_dev
         self.snapshot = snapshot               # tuple of rids (padded -1)
         self.slots = slots                     # list of Optional[_Request]
-        self.t_dispatch = time.perf_counter()
+        # a prefill's clock starts before its call, as it always has
+        self.t_dispatch = time.perf_counter() if t_dispatch is None \
+            else t_dispatch
         # recording on, and the model counts: (the step's record, its
         # counters still on the device)
         self.stats = None
+
+
+def _assemble_ids(prev, first, idx):
+    """ids [C] of the next decode step, on the device: row `idx[i]` of
+    the tokens `prev` [Cp] with one prefill's first token [1] behind
+    them."""
+    return jnp.take(jnp.concatenate([prev, first]), idx, mode="clip")
 
 
 class DecodeEngine:
@@ -439,6 +456,17 @@ class DecodeEngine:
             s: _JitDispatch(jax.jit(_decode_fn, donate_argnums=(3, 4)),
                             "decode", meta={"slots": int(s)}, policy=pol)
             for s in self.decode_slots}
+
+        # the lazy loop's id assembly (`_next_ids`): after an admission
+        # or a retirement the next step's ids are put together on the
+        # device, one program a pair of slot configurations (the step in
+        # flight, the step to come)
+        self._assemble: Dict[Tuple[int, int], _JitDispatch] = {} \
+            if self._sync else {
+                (a, b): _JitDispatch(jax.jit(_assemble_ids), "decode",
+                                     meta={"assemble": [int(a), int(b)]},
+                                     policy=pol)
+                for a in self.decode_slots for b in self.decode_slots}
 
         self._draft_prefill: Dict[int, _JitDispatch] = {}
         self._draft_chunk: Dict[int, _JitDispatch] = {}
@@ -587,6 +615,13 @@ class DecodeEngine:
         # chunk per iteration, interleaved with decode steps
         self._prefilling: "collections.deque[_Request]" = \
             collections.deque()
+        # the lazy loop: what is on the device's queue and not yet on the
+        # host, in device order (decode step, prefill, decode step, ...)
+        self._inflight: "collections.deque[_Pending]" = collections.deque()
+        # how each decode step of the lazy loop got its ids, and the
+        # forced resolves of everything in flight (status()["pipeline"])
+        self._pipeline = {"fed": 0, "assembled": 0, "host": 0, "drains": 0}
+        self._no_first = np.zeros((1,), np.int32)
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._closed = False
@@ -730,6 +765,7 @@ class DecodeEngine:
         else:
             keys.extend(("prefill", t) for t in self.prefill_buckets)
         keys.extend(("decode", s) for s in self.decode_slots)
+        keys.extend(("assemble", pair) for pair in self._assemble)
         if self._draft is not None:
             if self.prefill_chunk:
                 keys.append(("draft_chunk", self.prefill_chunk))
@@ -746,7 +782,7 @@ class DecodeEngine:
         through this one lookup."""
         kind, n = key
         return {"prefill": self._prefill, "chunk": self._chunk,
-                "decode": self._decode,
+                "decode": self._decode, "assemble": self._assemble,
                 "draft_prefill": self._draft_prefill,
                 "draft_chunk": self._draft_chunk,
                 "draft_decode": self._draft_decode,
@@ -755,6 +791,9 @@ class DecodeEngine:
     def _phase_avals(self, key):
         sds = jax.ShapeDtypeStruct
         kind, n = key
+        if kind == "assemble":
+            return (sds((n[0],), np.int32), sds((1,), np.int32),
+                    sds((n[1],), np.int32))
         draft = kind.startswith("draft_")
         params = self._draft_params if draft else self.params
         p_sds = jax.tree_util.tree_map(
@@ -1156,7 +1195,15 @@ class DecodeEngine:
                     t: round(s, 4) for t, s in
                     self._wfq.served_shares().items()},
             }
-        if self._sync:
+        if not self._sync:
+            # the lazy loop's queue on the device: decode steps by how
+            # they got their ids ("fed": the step in flight's tokens as
+            # they are; "assembled": put together on the device after an
+            # admission or a retirement; "host": built from host tokens,
+            # nothing in flight) and the forced resolves of everything in
+            # flight (pool exhaustion, a request's only token, shutdown)
+            out["pipeline"] = dict(self._pipeline)
+        else:
             out["prefilling"] = prefilling
             out["kv_reuse"] = {
                 "prefix_cache": self.config.prefix_cache,
@@ -1311,8 +1358,9 @@ class DecodeEngine:
         """Retire requests whose clients abandoned them (cancel()):
         waiting ones leave the queue, active ones free their slot and
         blocks. Runs at the top of every scheduler iteration; a
-        cancelled request with a token still in flight is skipped by
-        _resolve's not-in-active check."""
+        cancelled request with a token still in flight (a step's, or its
+        own prefill's first) is skipped by the resolve's not-in-active
+        check."""
         with self._cv:
             gone_waiting = [r for r in self._waiting if r.cancelled]
             for r in gone_waiting:
@@ -1343,10 +1391,13 @@ class DecodeEngine:
         return (rank, r.admitted_at)
 
     def _admit(self) -> bool:
-        """Move waiting requests into free slots while blocks last;
-        each admission runs its prefill (the admission boundary is the
-        one place the scheduler syncs with the device). Returns whether
-        the batch composition changed."""
+        """Move waiting requests into free slots while blocks last. Each
+        admission dispatches its prefill and joins `_active` at once; its
+        first token stays on the device, behind whatever is in flight,
+        and is emitted when `_resolve` reaches it. Only a request whose
+        first token is its last (it joins no decode batch) is resolved
+        here, with everything queued before it. Returns whether the batch
+        composition changed."""
         admitted = 0
         max_slots = self.decode_slots[-1]
         waiting = len(self._waiting)
@@ -1365,13 +1416,21 @@ class DecodeEngine:
                     break  # blocks scale with live tokens: defer
                 del self._waiting[idx]
                 QUEUE_DEPTH.set(len(self._waiting))
-            self._prefill_one(req)
+            first = self._prefill_one(req)
             admitted += 1
+            if first is not None:
+                self._inflight.append(first)
+                if len(req.generated) + 1 >= req.max_new:
+                    self._drain()
         if sp is not None:
             sp.close(waiting=waiting, admitted=admitted)
         return bool(admitted)
 
-    def _prefill_one(self, req: _Request):
+    def _prefill_one(self, req: _Request) -> Optional[_Pending]:
+        """Admit `req`: dispatch its whole-prompt prefill and return the
+        fetch of its first token, still in flight (None: the replay
+        outgrew the bucket set and the request ended in error). The lazy
+        loop queues the fetch, the sync loop resolves it at once."""
         # the admission boundary: everything since (re-)enqueue was wait
         req.admitted_at = time.monotonic()
         sp = None
@@ -1383,23 +1442,24 @@ class DecodeEngine:
             sp = _tracing.open_span("decode.prefill", "decode",
                                     parent=req.parent, rid=req.rid,
                                     ctx=req.tctx)
-        bucket = None
+        bucket = self._bucket_for_len(len(req.prompt))
         try:
-            bucket = self._prefill_admitted(req)
+            return self._prefill_admitted(req, bucket)
         finally:
             if sp is not None:
                 sp.close(bucket=bucket, prompt_len=len(req.prompt),
                          queue_wait_s=req.admitted_at - req.enqueued_at)
 
-    def _prefill_admitted(self, req: _Request) -> Optional[int]:
-        """The prompt work of one admitted request; returns its bucket
-        (None: the replay outgrew the bucket set)."""
+    def _prefill_admitted(self, req: _Request, bucket: Optional[int]
+                          ) -> Optional[_Pending]:
+        """The prompt work of one admitted request: the prefill program
+        is dispatched, the request takes its slot, and nothing waits for
+        the device (`decode.prefill.wait` holds the call alone)."""
         if self._wfq is not None:
             # prefill service charge: a long prompt is real work even
             # before its first decode token
             self._wfq.charge(req.tenant, len(req.prompt))
         plen = len(req.prompt)
-        bucket = self._bucket_for_len(plen)
         if bucket is None:  # replay grew past the largest bucket
             req.error = RuntimeError(
                 f"prompt+generated length {plen} exceeds the largest "
@@ -1420,22 +1480,10 @@ class DecodeEngine:
             wait = _tracing.open_span("decode.prefill.wait", "decode")
         tok, kp, vp = self._prefill[bucket](
             self.params, ids, np.int32(plen), kp, vp, bt)
-        t_called = time.perf_counter() if wait is not None else 0.0
         self._pools = (kp, vp)
-        tok0 = int(np.asarray(tok)[0])         # admission-boundary sync
         if wait is not None:
-            wait.close(call_s=t_called - t0)
+            wait.close(call_s=time.perf_counter() - t0)
         STEPS.inc(phase="prefill")
-        _telemetry.record_dispatch_ready(
-            "decode:prefill", time.perf_counter() - t0)
-        # live-MFU sample: the bucket executable's retained
-        # cost_analysis FLOPs over this prefill's wall window (one
-        # token emitted — the TTFT token)
-        _perfwatch.record_step(
-            "prefill", time.perf_counter() - t0,
-            flops=(self._prefill[bucket].current_cost() or {})
-            .get("flops"),
-            tokens=1, device_kind=self._device_kind)
         if self._draft is not None:
             # the draft prefills EVERY sequence (same ids, same block
             # table, its own pools) so speculation can start at the
@@ -1448,19 +1496,16 @@ class DecodeEngine:
             STEPS.inc(phase="draft")
         req.pos = plen
         self._active.append(req)
-        self._emit_token(req, tok0, phase="prefill")
-        reason = self._finished_reason(req)
-        if reason:
-            self._finish(req, reason)
         self._kv_gauges()
-        return bucket
+        return _Pending(tok, None, [req], t0)
 
-    def _grow_blocks(self, pending: Optional[_Pending]
-                     ) -> Optional[_Pending]:
+    def _grow_blocks(self) -> None:
         """Ensure every active slot owns the block its next write
-        lands in. On pool exhaustion: resolve the in-flight step (its
-        finishes may free blocks), retry, then preempt the youngest
-        active sequence until the step fits."""
+        lands in. On pool exhaustion: resolve everything in flight (a
+        forced drain: its finishes may free blocks, and a preempted
+        request's replay prompt needs every token it was given, a first
+        token still on the device included), retry, then preempt the
+        youngest active sequence until the step fits."""
         sp = _tracing.open_span("decode.grow", "decode") \
             if _tracing.recording else None
         taken = preempted = 0
@@ -1479,15 +1524,14 @@ class DecodeEngine:
                     break
             if short is None:
                 break
-            if pending is not None:
-                pending = self._resolve(pending)
+            if self._inflight:
+                self._drain()
                 continue  # finishes may have freed enough
             victim = max(self._active, key=self._victim_key)
             self._preempt(victim)
             preempted += 1
         if sp is not None:
             sp.close(blocks=taken, preempted=preempted)
-        return pending
 
     def _preempt(self, req: _Request):
         """vLLM-style recompute preemption: free the victim's blocks
@@ -1534,19 +1578,83 @@ class DecodeEngine:
             slots.append(None)
         return tuple(r.rid if r else -1 for r in slots), slots
 
-    def _dispatch(self, ids_arg, C: int) -> _Pending:
+    def _next_ids(self, sig, slots):
+        """The ids of the decode step about to be dispatched with the
+        batch `slots`, and how they came about. The host knows where each
+        slot's last token lies without having it: in the row of the
+        decode step in flight that carried the slot, in an admission's
+        prefill still in flight, or (nothing in flight) in
+        `req.last_token`.
+
+        "fed": the batch is the in-flight step's, whose tokens go back
+        in as they are. "assembled": the batch changed (an admission, a
+        retirement): `_assemble` gathers the rows on the device, one
+        call, and one more for each further admission of the turn.
+        "host": nothing is in flight (an empty engine's first step is
+        not: its prefill is), the ids are built here."""
+        prev = next((p for p in reversed(self._inflight)
+                     if p.snapshot is not None), None)
+        if prev is not None and prev.snapshot == sig:
+            return prev.tok_dev, "fed"
+        C = len(slots)
+        if prev is None:
+            # a drain left every resident's token on the host
+            src = np.zeros((C,), np.int32)
+            for i, req in enumerate(slots):
+                if req is not None:
+                    src[i] = req.last_token
+            if not self._inflight:
+                return src, "host"
+            row = {req.rid: i for i, req in enumerate(slots)
+                   if req is not None}
+        else:
+            src = prev.tok_dev
+            row = {req.rid: i for i, req in enumerate(prev.slots)
+                   if req is not None}
+        firsts = {p.slots[0].rid: p for p in self._inflight
+                  if p.snapshot is None}
+        # a resident without a prefill in flight rode the step in flight:
+        # it was admitted before that step, or everything was drained
+        idx = np.zeros((C,), np.int32)
+        late = []       # (slot, the prefill that holds its first token)
+        for i, req in enumerate(slots):
+            if req is None:
+                continue
+            if req.rid in firsts:
+                late.append((i, firsts[req.rid]))
+            else:
+                idx[i] = row[req.rid]
+        n = len(src)
+        first = self._no_first
+        if late:
+            idx[late[0][0]] = n
+            first = late[0][1].tok_dev
+        ids = self._assemble[(n, C)](src, first, idx)
+        for i, p in late[1:]:
+            idx = np.arange(C, dtype=np.int32)
+            idx[i] = C
+            ids = self._assemble[(C, C)](ids, p.tok_dev, idx)
+        return ids, "assembled"
+
+    def _dispatch(self, C: int) -> _Pending:
+        """Dispatch one decode step at `C` slots behind whatever is in
+        flight, and queue its fetch."""
         sp = _tracing.open_span("decode.dispatch", "decode") \
             if _tracing.recording else None
+        sig, slots = self._snapshot(C)
         kp, vp = self._pools
         positions = np.zeros((C,), np.int32)
         bts = np.zeros((C, self.kv_cfg.max_blocks_per_seq), np.int32)
-        sig, slots = self._snapshot(C)
         for i, req in enumerate(slots):
             if req is None:
                 continue
             positions[i] = req.pos
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
+        # last, so that an assembly and its step are dispatched back to
+        # back: the device may be waiting for just these two
+        ids_arg, how = self._next_ids(sig, slots)
+        self._pipeline[how] += 1
         tok, kp, vp, stats = self._decode[C](self.params, ids_arg,
                                              positions, kp, vp, bts)
         self._pools = (kp, vp)
@@ -1556,11 +1664,11 @@ class DecodeEngine:
         STEPS.inc(phase="decode")
         OCCUPANCY.observe(sum(1 for r in slots if r is not None) / C)
         self._last_slot_config = C
-        pending = _Pending(FetchHandle([tok], site="decode"), tok, sig,
-                           slots)
+        pending = _Pending(tok, sig, slots)
+        self._inflight.append(pending)
         self._step_starts.append(pending.t_dispatch)
         if sp is not None:
-            row = self._close_dispatch(sp, "decode", C, slots)
+            row = self._close_dispatch(sp, "decode", C, slots, ids=how)
             if stats is not None:
                 # on their way to the host while the step runs on
                 for a in jax.tree_util.tree_leaves(stats):
@@ -1568,10 +1676,29 @@ class DecodeEngine:
                 pending.stats = (row, stats)
         return pending
 
-    def _resolve(self, pending: _Pending) -> None:
-        """Consume one in-flight step's tokens: stream them, detect
-        finishes, retire (freeing blocks). Tokens for slots that were
-        already retired/preempted after dispatch are discarded."""
+    def _resolve(self, upto: Optional[_Pending] = None) -> None:
+        """Consume what is in flight, oldest first (the device's order),
+        up to the entry `upto` (all of it without one): a resident's
+        token of step N-1 reaches its stream before the host waits for
+        the prefill that was queued behind that step."""
+        while self._inflight and self._inflight[0] is not upto:
+            self._resolve_one(self._inflight.popleft())
+
+    def _drain(self) -> None:
+        """A forced drain: the host waits for everything in flight, so
+        the device's queue runs empty before the next dispatch. Pool
+        exhaustion, a request whose first token is its last, and the
+        loop's end come here; `status()["pipeline"]["drains"]` counts."""
+        if self._inflight:
+            self._pipeline["drains"] += 1
+            self._resolve()
+
+    def _resolve_one(self, pending: _Pending) -> None:
+        """Consume one fetch: stream a decode step's tokens or a
+        prefill's first token, detect finishes, retire (freeing blocks).
+        Tokens for slots that were retired, cancelled or preempted after
+        the dispatch are discarded."""
+        first = pending.snapshot is None
         sp = wait = None
         if _tracing.recording:
             sp = _tracing.open_span("decode.resolve", "decode")
@@ -1584,79 +1711,74 @@ class DecodeEngine:
         if pending.stats is not None:
             self._note_step_stats(*pending.stats)
         wall = now - pending.t_dispatch
-        STEP_SECONDS.observe(wall)
-        # live-MFU sample: the slot-config executable's retained FLOPs
-        # over the dispatch→resolve window; the result() wait is the
-        # host-blocked share, occupied slots are the tokens produced
-        C = len(pending.slots)
-        _perfwatch.record_step(
-            "decode", wall,
-            flops=(self._decode[C].current_cost() or {}).get("flops"),
-            tokens=sum(1 for r in pending.slots if r is not None),
-            host_blocked=min(now - t_wait, wall),
-            device_kind=self._device_kind)
+        if first:
+            _telemetry.record_dispatch_ready("decode:prefill", wall)
+            # live-MFU sample: the bucket executable's retained
+            # cost_analysis FLOPs over the dispatch→resolve window (one
+            # token emitted — the TTFT token)
+            bucket = self._bucket_for_len(len(pending.slots[0].prompt))
+            _perfwatch.record_step(
+                "prefill", wall,
+                flops=(self._prefill[bucket].current_cost() or {})
+                .get("flops"),
+                tokens=1, device_kind=self._device_kind)
+        else:
+            STEP_SECONDS.observe(wall)
+            # live-MFU sample: the slot-config executable's retained
+            # FLOPs over the dispatch→resolve window; the result() wait
+            # is the host-blocked share, occupied slots are the tokens
+            # produced
+            C = len(pending.slots)
+            _perfwatch.record_step(
+                "decode", wall,
+                flops=(self._decode[C].current_cost() or {}).get("flops"),
+                tokens=sum(1 for r in pending.slots if r is not None),
+                host_blocked=min(now - t_wait, wall),
+                device_kind=self._device_kind)
         emitted = finished = 0
         for i, req in enumerate(pending.slots):
             if req is None or req not in self._active:
                 continue
-            self._emit_token(req, int(toks[i]), phase="decode")
+            self._emit_token(req, int(toks[i]),
+                             phase="prefill" if first else "decode")
             emitted += 1
             reason = self._finished_reason(req)
             if reason:
                 self._finish(req, reason)
                 finished += 1
         if sp is not None:
-            sp.close(tokens=emitted, finished=finished)
-        return None
+            # a request's first token is its prefill's, not a step's
+            sp.close(tokens=0 if first else emitted,
+                     first=emitted if first else 0, finished=finished)
 
-    def _turn(self, pending: Optional[_Pending]) -> Optional[_Pending]:
-        """One turn of the lazy loop: admit, grow, dispatch step N,
-        resolve step N-1. Returns the step left in flight."""
+    def _turn(self) -> None:
+        """One turn of the lazy loop: admit (prefills dispatched, their
+        first tokens left on the device), grow, dispatch step N with ids
+        that never came to the host (`_next_ids`), then resolve what is
+        older than step N: step N-1, and the prefills queued behind it.
+        Nothing in a turn waits for the device before the dispatch except
+        a forced drain (`_drain`), so the device's queue stays non-empty
+        across admissions and retirements; a sequence that ends rides one
+        more step, whose token for it is discarded."""
         self._sweep_cancelled()
         self._admit()
         if not self._active:
-            if pending is not None:
-                pending = self._resolve(pending)
-            return pending
-        pending = self._grow_blocks(pending)
-        if not self._active:  # growth preempted everything
-            return pending
-        C = self._slot_config()
-        sig, slots = self._snapshot(C)
-        if pending is not None and pending.snapshot == sig:
-            # steady state: feed the previous step's tokens
-            # back on DEVICE — the host never touched them
-            ids_arg = pending.tok_dev
-        else:
-            if pending is not None:
-                pending = self._resolve(pending)
-                self._admit()  # retirements freed slots
-                # a request admitted HERE whose prompt length
-                # is an exact block multiple needs its next
-                # block before this dispatch, or its first
-                # decode write lands in the null block
-                self._grow_blocks(None)
-                if not self._active:
-                    return pending
-                C = self._slot_config()
-                sig, slots = self._snapshot(C)
-            ids_arg = np.zeros((C,), np.int32)
-            for i, req in enumerate(slots):
-                if req is not None:
-                    ids_arg[i] = req.last_token
-        new_pending = self._dispatch(ids_arg, C)
-        if pending is not None:
-            # overlap: resolve step N-1 while step N runs
-            self._resolve(pending)
-        return new_pending
+            self._resolve()     # nothing to dispatch behind it
+            return
+        self._grow_blocks()
+        if not self._active:    # growth drained, then preempted everything
+            return
+        newest = self._dispatch(self._slot_config())
+        # overlap: resolve step N-1 (and the admissions' first tokens)
+        # while step N runs
+        self._resolve(upto=newest)
 
     def _loop(self):
-        pending: Optional[_Pending] = None
         try:
             while True:
                 with self._cv:
                     while not self._closed and not self._waiting \
-                            and not self._active and pending is None:
+                            and not self._active and not self._inflight:
                         self._cv.wait(timeout=0.5)
                     if self._closed:
                         break
@@ -1664,7 +1786,7 @@ class DecodeEngine:
                 sp = _tracing.open_span("decode.turn", "decode") \
                     if _tracing.recording else None
                 try:
-                    pending = self._turn(pending)
+                    self._turn()
                 finally:
                     if sp is not None:
                         sp.close(loop="lazy")
@@ -1679,11 +1801,10 @@ class DecodeEngine:
                 self._finish(req, "error")
             raise
         finally:
-            if pending is not None:
-                try:
-                    self._resolve(pending)
-                except Exception:  # lint-exempt:swallow: shutdown path; clients are cancelled below
-                    pass
+            try:
+                self._drain()
+            except Exception:  # lint-exempt:swallow: shutdown path; clients are cancelled below
+                pass
             with self._cv:
                 reqs = list(self._active) + list(self._waiting)
                 self._waiting.clear()
@@ -1782,7 +1903,9 @@ class DecodeEngine:
                 self._prefilling.append(req)
                 self._kv_gauges()
             else:
-                self._prefill_one(req)
+                first = self._prefill_one(req)
+                if first is not None:
+                    self._resolve_one(first)    # every round resolves
         if sp is not None:
             sp.close(waiting=waiting, admitted=admitted)
 
@@ -1990,14 +2113,17 @@ class DecodeEngine:
         if res is not None:
             res.close(tokens=emitted, finished=finished)
 
-    def _close_dispatch(self, sp, kind: str, C: int, slots) -> Dict:
+    def _close_dispatch(self, sp, kind: str, C: int, slots,
+                        ids: str = "host") -> Dict:
         """Recording on: the step's record (returned) and the end of its
-        open decode.dispatch span `sp`."""
+        open decode.dispatch span `sp`; `ids` says where the step's ids
+        came from (`_next_ids`; a synchronous round builds them on the
+        host)."""
         live = [r for r in slots if r is not None]
         tokens = sum(r.pos for r in live)
         row = self._step_record(kind, sp.t0, C, len(live), tokens)
         sp.close(slots=C, live=len(live), live_tokens=tokens,
-                 blocks_used=self._alloc.used_blocks())
+                 blocks_used=self._alloc.used_blocks(), ids=ids)
         return row
 
     def _sync_resolve_spans(self, sp, kind: str, C: int, slots):

@@ -132,7 +132,11 @@ def pytest_sessionfinish(session, exitstatus):
     for proc in _live_procs:
         _kill_wait(proc)
     _live_procs.clear()
-    reap_stray_workers()
+    # under xdist only the controller sweeps, after every worker is done:
+    # a worker that finishes early would SIGKILL the worker scripts of
+    # tests still running on the others
+    if not hasattr(session.config, "workerinput"):
+        reap_stray_workers()
     # Concurrency-sanitizer verdict line: when this session ran under
     # PADDLE_TPU_LOCKCHECK, print the deadlock/inversion totals so a
     # wrapper (test_lockcheck's slow family run) can assert on them

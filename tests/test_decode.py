@@ -363,9 +363,10 @@ def test_warmstart_roundtrip_zero_compile(model, tmp_path, monkeypatch):
     kw = dict(decode_slots=(2, 4), prefill_buckets=(8, 16))
     cold = make_engine(model, **kw)
     ready = cold.warmup()
-    assert ready == 4                     # 2 buckets + 2 slot configs
+    # 2 buckets + 2 slot configs + the id assembly of each pair of them
+    assert ready == 8
     art = str(tmp_path / "decode.warmstart")
-    assert cold.export_warmstart(art) == 4
+    assert cold.export_warmstart(art) == 8
     prompt = [3, 1, 4, 1, 5]
     cold_toks = cold.submit(prompt, max_new_tokens=6).result(
         timeout_s=120)
@@ -378,18 +379,18 @@ def test_warmstart_roundtrip_zero_compile(model, tmp_path, monkeypatch):
         lambda kind, seconds, **kw: (compiled.append((kind, kw.get("meta"))),
                                      real(kind, seconds, **kw))[1])
     warm = make_engine(model, warmstart=art, **kw)
-    assert warm.warmstart_adopted == 4
-    assert warm.warmup() == 4
+    assert warm.warmstart_adopted == 8
+    assert warm.warmup() == 8
     warm_toks = warm.submit(prompt, max_new_tokens=6).result(
         timeout_s=120)
     warm.stop()
     grid = [warm._phase_dispatch(key) for key in warm._phase_keys()]
-    assert len(grid) == 4
+    assert len(grid) == 8
     fresh = [kind for kind, meta in compiled
              if any(meta is d._meta for d in grid)]
     assert fresh == [], fresh
     # nor did a phase fall back to the plain jit path, which compiles too
-    assert [d._recorded_jit_compiles for d in grid] == [0] * 4
+    assert [d._recorded_jit_compiles for d in grid] == [0] * 8
     assert warm_toks == cold_toks
 
 
@@ -526,6 +527,229 @@ def test_block_boundary_admit_after_retire(model):
     eng.stop()
 
 
+# ---------------------------------------------------------------------------
+# The lazy loop's queue on the device (ISSUE 32): an admission's first
+# token stays there, a changed batch gets its ids there
+# ---------------------------------------------------------------------------
+
+
+_FORWARD = {}
+
+
+def _reference(model, prompt, max_new, eos_id=None):
+    """Greedy tokens of one sequence alone through the full-context
+    forward pass (no engine, no cache): what every stream must equal."""
+    params, cfg = model
+    if "fn" not in _FORWARD:
+        _FORWARD["fn"] = jax.jit(lambda p, ids: gpt.apply(p, cfg, ids))
+    seq, out = list(prompt), []
+    for _ in range(max_new):
+        ids = np.zeros((1, 64), np.int32)       # causal: the tail is inert
+        ids[0, :len(seq)] = seq
+        logits = np.asarray(_FORWARD["fn"](params, ids))
+        out.append(int(np.argmax(logits[0, len(seq) - 1])))
+        seq.append(out[-1])
+        if out[-1] == eos_id:
+            break
+    return out
+
+
+def _submit_together(eng, *jobs):
+    """Every job waits before the scheduler's first turn sees any: the
+    loop takes `_cv` (re-entrant for its owner) to look at the queue."""
+    with eng._cv:
+        return [eng.submit(p, max_new_tokens=n) for p, n in jobs]
+
+
+def _case_admissions_while_residents_decode(model):
+    eng = make_engine(model, prefill_buckets=(8,))
+    jobs = [([1, 2, 3, 4], 30), ([9, 9], 12), ([5, 6, 7], 9), ([3], 14)]
+    hs = [eng.submit(*jobs[0])]
+    for job in jobs[1:]:
+        _wait_active(eng)
+        time.sleep(0.01)            # the residents are some steps on
+        hs.append(eng.submit(*job))
+    return eng, hs, jobs, {}
+
+
+def _case_retirement_and_admission_in_one_turn(model):
+    # two slots, three requests: C waits for a slot, so the turn that sees
+    # A gone is the turn that admits C, beside B still decoding
+    eng = make_engine(model, decode_slots=(2,))
+    jobs = [([1, 2, 3], 5), ([4, 5, 6, 7], 24), ([8, 9], 10)]
+    return eng, _submit_together(eng, *jobs), jobs, {}
+
+
+def _case_block_multiple_prompt_after_a_retirement(model):
+    eng = make_engine(model, decode_slots=(1,), num_blocks=32)
+    jobs = [([1, 2, 3], 12), ([7, 1, 3, 5, 2, 6, 4, 1], 10)]   # len == BS
+    return eng, _submit_together(eng, *jobs), jobs, {}
+
+
+def _case_preemption_with_a_first_token_in_flight(model):
+    # three blocks of four: A and B are admitted in one turn (a block
+    # each), A's growth takes the third, B's finds none: the loop drains
+    # with both first tokens still on the device, then preempts B, whose
+    # replay prompt must hold the first token it was given
+    eng = make_engine(model, block_size=4, num_blocks=4, decode_slots=(2,),
+                      prefill_buckets=(4, 12), max_len=12)
+    jobs = [([1, 2, 3, 4], 8), ([5, 6, 7, 8], 6)]
+    return eng, _submit_together(eng, *jobs), jobs, \
+        {"preempted": True, "drains": True}
+
+
+def _case_cancel_with_its_prefill_in_flight(model):
+    from paddle_tpu.serving.decode import DecodeHandle
+
+    eng = make_engine(model)
+    jobs = [([1, 2, 3, 4], 20), ([6, 5], 20), ([2, 2, 2], 8)]
+    real = eng._prefill_one
+
+    def prefill_then_cancel(req):
+        first = real(req)
+        if list(req.prompt) == jobs[1][0]:
+            eng.cancel(DecodeHandle(req))    # its first token is in flight
+        return first
+
+    eng._prefill_one = prefill_then_cancel
+    hs = [eng.submit(*jobs[0])]
+    _wait_active(eng)
+    hs += [eng.submit(*job) for job in jobs[1:]]
+    return eng, hs, jobs, {"cancelled": 1}
+
+
+def _case_eos_first_and_one_token_requests(model):
+    eos = _reference(model, [1, 2, 3], 1)[0]
+    eng = make_engine(model, eos_id=eos)
+    jobs = [([4, 5, 6, 7], 20), ([1, 2, 3], 10), ([7, 7], 1), ([2, 8], 1)]
+    hs = [eng.submit(*jobs[0])]
+    _wait_active(eng)
+    hs += [eng.submit(*job) for job in jobs[1:]]
+    return eng, hs, jobs, {"eos_id": eos, "drains": True,
+                           "reasons": {1: "eos", 2: "length", 3: "length"}}
+
+
+@pytest.mark.parametrize("case", [
+    _case_admissions_while_residents_decode,
+    _case_retirement_and_admission_in_one_turn,
+    _case_block_multiple_prompt_after_a_retirement,
+    _case_preemption_with_a_first_token_in_flight,
+    _case_cancel_with_its_prefill_in_flight,
+    _case_eos_first_and_one_token_requests,
+], ids=lambda f: f.__name__[len("_case_"):])
+def test_lazy_loop_streams_equal_the_solo_reference(model, case):
+    """No token comes to the host before the next dispatch, and every
+    stream is still the one its sequence gives alone: none missing, none
+    twice, none another request's."""
+    eng, handles, jobs, expect = case(model)
+    try:
+        streams = [h.result(timeout_s=180) for h in handles]
+        status = eng.status()
+        deadline = time.monotonic() + 30
+        while status["kv"]["blocks_used"] and time.monotonic() < deadline:
+            time.sleep(0.01)
+            status = eng.status()
+    finally:
+        eng.stop()
+    cancelled = expect.get("cancelled")
+    for i, ((prompt, n), got, h) in enumerate(zip(jobs, streams, handles)):
+        want = _reference(model, prompt, n, expect.get("eos_id"))
+        if i == cancelled:
+            assert h.info["finish_reason"] == "cancelled"
+            assert len(got) < n and got == want[:len(got)], (got, want)
+        else:
+            assert got == want, (i, got, want)
+            assert h.info["finish_reason"] == expect.get(
+                "reasons", {}).get(i, h.info["finish_reason"])
+    assert status["kv"]["blocks_used"] == 0 and status["active"] == 0
+    assert bool(status["requests"]["preempted"]) \
+        == bool(expect.get("preempted"))
+    pipe = status["pipeline"]
+    assert bool(pipe["drains"]) == bool(expect.get("drains")), pipe
+    assert pipe["assembled"] >= 1
+
+
+_BACKEND_COMPILES = []
+
+
+def _backend_compiles() -> int:
+    """Programs this process has compiled since the first call (the
+    listener stays registered: jax takes none off)."""
+    if not _BACKEND_COMPILES:
+        _BACKEND_COMPILES.append(0)
+
+        def on_duration(event, secs, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                _BACKEND_COMPILES[0] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return _BACKEND_COMPILES[0]
+
+
+def _wait_admitted(eng, n, timeout=60.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if eng.status()["active"] >= n:
+            return
+        time.sleep(0.001)
+    raise AssertionError(f"engine never held {n} sequences")
+
+
+def test_admissions_are_assembled_on_the_device_and_warmed(model, tmp_path):
+    """The mechanism engages: each of K admissions into a decoding batch
+    is one `decode.dispatch` whose ids were put together on the device,
+    nothing is built from host tokens while something is in flight, the
+    queue is never drained, and the assembly programs are part of the
+    warmed (and warm-started) grid: no program compiles under load."""
+    from paddle_tpu.observability import tracing
+
+    kw = dict(decode_slots=(2, 4), prefill_buckets=(8,))
+    cold = make_engine(model, **kw)
+    n_grid = cold.warmup()
+    art = str(tmp_path / "decode.warmstart")
+    assert cold.export_warmstart(art) == n_grid == 1 + 2 + 4
+    K = 3
+    jobs = [([1, 2, 3, 4], 50), ([9, 9], 50), ([5, 6, 7], 50), ([3], 50)]
+    runs = {}
+    for boot in ("warmup", "warmstart"):
+        eng = cold if boot == "warmup" else make_engine(
+            model, warmstart=art, **kw)
+        try:
+            if boot == "warmstart":
+                assert eng.warmstart_adopted == n_grid
+                assert eng.warmup() == n_grid
+            compiled = _backend_compiles()
+            tracing.clear_spans()
+            with tracing.recorded():
+                hs = []
+                for i, job in enumerate(jobs):
+                    hs.append(eng.submit(job[0], max_new_tokens=job[1]))
+                    _wait_admitted(eng, i + 1)
+                runs[boot] = [h.result(timeout_s=180) for h in hs]
+                pipe = eng.status()["pipeline"]
+            assert _backend_compiles() == compiled
+        finally:
+            eng.stop()
+        grid = [eng._phase_dispatch(key) for key in eng._phase_keys()]
+        assert [d._recorded_jit_compiles for d in grid] == [0] * n_grid
+        disp = sorted((s for s in tracing.get_spans()
+                       if s.name == "decode.dispatch"), key=lambda s: s.ts)
+        tracing.clear_spans()
+        how = [s.args["ids"] for s in disp]
+        joined = [s for prev, s in zip(disp, disp[1:])
+                  if s.args["live"] > prev.args["live"]]
+        assert len(joined) == K
+        assert {s.args["ids"] for s in joined} == {"assembled"}
+        # the first step's ids are its prefill's token, on the device too
+        assert how[0] == "assembled" and "host" not in how
+        assert pipe == {"fed": how.count("fed"),
+                        "assembled": how.count("assembled"),
+                        "host": 0, "drains": 0}
+        assert pipe["fed"] > pipe["assembled"] >= K + 1
+    assert runs["warmup"] == runs["warmstart"] \
+        == [_reference(model, p, n) for p, n in jobs]
+
+
 def test_stop_drains_preenqueued_requests(model):
     """A request enqueued while no scheduler thread exists is drained
     by stop() itself (the _loop finally never runs for a thread never
@@ -619,7 +843,8 @@ def test_decode_metrics_and_obsdump(model, engine, tmp_path, capsys):
 
 def test_slot_config_grid_warmed(model):
     eng = make_engine(model, decode_slots=(2, 4))
-    assert eng.warmup() == 3              # 1 bucket + 2 slot configs
+    # 1 bucket + 2 slot configs + the id assembly of each pair of them
+    assert eng.warmup() == 7
     assert all(d._aot is not None for d in eng._decode.values())
     assert all(d._aot is not None for d in eng._prefill.values())
     hs = [eng.submit([i + 1, i + 2], max_new_tokens=3) for i in range(3)]
