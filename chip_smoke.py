@@ -15,7 +15,7 @@ over. Numbers printed here are information, not benchmark results.
     python chip_smoke.py            one chip: device, program, train,
                                     long_seq, serve, serve_reuse,
                                     serve_olmoe, serve_joyai,
-                                    paged_attention
+                                    serve_nemotron, paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
                                     one device, then the dp/tp/sp/pp/ep
@@ -42,6 +42,7 @@ import time
 SEED = 0
 OLMOE_LOGIT_TOL = 0.25   # benchmarks/configs/olmoe_1b_7b.json argues it
 JOYAI_LOGIT_TOL = 0.4    # benchmarks/configs/joyai_llm_flash.json argues it
+NEMOTRON_LOGIT_TOL = 0.4  # benchmarks/configs/nemotron3_nano.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
 # many of those its persistent cache answered (a hit still counts as a
@@ -335,6 +336,24 @@ def _joyai_reference_gaps(params, cfg, prompts, streams):
         streams, width)
 
 
+def _nemotron_reference_gaps(params, cfg, prompts, streams):
+    """As `_olmoe_reference_gaps`, against the benchmark's plain float32
+    Nemotron-H (benchmarks/reference/nemotron_h_ref.py: the recurrence
+    token by token, every expert for every token, no cache, no state pool,
+    no code of models/nemotron_h.py)."""
+    import dataclasses
+
+    from benchmarks.reference import nemotron_h_ref
+
+    ref = dataclasses.asdict(cfg)
+    top = {k: v for k, v in params.items()
+           if not k.startswith(tuple(nemotron_h_ref.PREFIX.values()))}
+    width = max(len(p) for p in prompts) + len(streams[0])
+    return nemotron_h_ref.stream_gaps(
+        top, lambda i: nemotron_h_ref.layer_of(params, ref, i), ref,
+        prompts, streams, width)
+
+
 def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
                 logit_tol: float, model=None,
                 reference_gaps=_reference_gaps) -> dict:
@@ -347,12 +366,13 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
     import numpy as np
 
     from paddle_tpu.models import gpt
-    from paddle_tpu.ops.pallas import paged_attention
+    from paddle_tpu.ops.pallas import paged_attention, ssm_update
     from paddle_tpu.serving import Server, ServingConfig, kv_cache
     from paddle_tpu.serving.decode import DecodeEngine
 
     params, _ = _init(model or gpt, cfg)
     paged_attention.GATE_COUNTS.clear()
+    ssm_update.GATE_COUNTS.clear()
     kv_cache.PREFILL_WRITE_UNITS.clear()
     engine = DecodeEngine(params, cfg, decode_cfg)
     # prefill buckets, slot configurations, and the id assembly of each
@@ -407,6 +427,8 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
                  "finished": status["requests"],
                  "decode_attention": status["decode_attention"],
                  "prefill_write": status["prefill_write"],
+                 # the state row pool of a model with recurrent layers
+                 "state": status.get("state"),
                  "ref_exact_tokens": f"{exact}/{len(prompts) * max_new}",
                  "ref_max_logit_gap": round(gap, 5),
                  "ref_logit_tol": logit_tol})
@@ -585,7 +607,7 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import bert, gpt, joyai, olmoe
+    from paddle_tpu.models import bert, gpt, joyai, nemotron_h, olmoe
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
 
@@ -685,6 +707,35 @@ def run_one_chip() -> None:
         # layer bodies a prefill program, K and V (here `c` and the rotary
         # key) each, three programs
         assert info["checked"]["prefill_write"] == {"blocks": 12}, info
+
+    # Nemotron-3-Nano at its published widths (Mamba-2: 64 heads of 64, 8
+    # groups, state 128; 32 query heads over 2 K/V heads of 128, no
+    # positions; top-6 of relu^2 experts of 1856 and a shared one of 3712;
+    # vocab 131072), blocks 3 to 7 of its pattern (`MEM*E`: two Mamba-2,
+    # two expert, the attention block) with 32 of the 128 experts, so that
+    # the float32 set for the reference (5.9 GB) sits beside the served
+    # one: the model whose blocks hold one mixer each and whose sequences
+    # keep a state row beside their blocks
+    ncfg = nemotron_h.NemotronHConfig(pattern="MEM*E", n_experts=32,
+                                      max_len=1024)
+    prompts = [rng.randint(0, ncfg.vocab_size, n).tolist()
+               for n in (12, 200, 5, 64, 40, 250, 129)]
+    with phase("serve_nemotron") as info:
+        serve_phase(info, ncfg, DecodeConfig(
+            block_size=16, num_blocks=16 * 64 + 1, decode_slots=(16,),
+            prefill_buckets=(64, 128, 256)), prompts, max_new=24,
+            logit_tol=NEMOTRON_LOGIT_TOL, model=nemotron_h,
+            reference_gaps=_nemotron_reference_gaps)
+        checked = info["checked"]
+        # the 16 query heads of a K/V head through the kernel, and the
+        # state rows advanced where they lie: closed gates would serve the
+        # same tokens slower and nothing else would say so
+        assert checked["decode_attention"] == {"paged_gqa": 1}, info
+        assert checked["state"]["update"] == {"kernel": 2}, info
+        assert checked["state"]["rows"] == 16 \
+            and checked["state"]["used"] == 0, info
+        # one attention block: K and V, three prefill programs
+        assert checked["prefill_write"] == {"blocks": 6}, info
 
     # the kernel against the gather path where it runs, at the benchmark's
     # two widths: GPT-2-large's 20 heads of 64, OLMoE's 16 of 128

@@ -7,7 +7,8 @@ loop with the pools in its carry (`serve_layers`) and the four programs
 built on it (`prefill`, `decode_step`, `prefill_chunk`, `verify_step`).
 What differs between models (learned positions or RoPE, LayerNorm or
 RMSNorm, a dense MLP or sparse experts, leading layers unlike the rest,
-multi-head or latent attention) is the block's pieces, which a model
+multi-head, grouped-query or latent attention; or blocks of ONE mixer each
+in a pattern, some of them recurrent) is the block's pieces, which a model
 hands over as a `ServeModel`. The engine asks a model configuration for
 it (`cfg.serve_model()`) and never names a model module.
 
@@ -19,8 +20,16 @@ pools for a kernel, and the model's three attention forms read them:
 `attend_prompt` (a whole prompt from its own projections),
 `attend_cached` (query rows against a gathered context) and
 `attend_paged` (a decode step through the block table, on a TPU). The
-defaults are multi-head attention over K and V of `heads*head_dim`
-lanes, as many K/V heads as query heads.
+defaults are multi-head attention over K and V of `kv_heads*head_dim`
+lanes; with fewer K/V heads than query heads the `heads / kv_heads` query
+heads of a K/V head read its lanes together (grouped-query attention).
+
+A model may also keep STATE that is not a token's: a recurrent layer's
+fixed-size state a sequence (`ServeModel.state_pools`: row pools `[layers
+of the kind, rows, ...]`, row 0 the null row idle slots read and write).
+The engine owns the pools and hands the programs a row id a sequence, as
+it hands them block tables; the programs carry the pools beside the K/V
+pools and the MODEL reads and writes its rows (`ssm_prompt`, `ssm_token`).
 """
 
 from __future__ import annotations
@@ -53,10 +62,26 @@ class ServeModel:
     vocab_size: int
     max_len: int            # positions the model can address
     refusal: Optional[str] = None
+    # blocks of ONE mixer each: a character a block, `M` a recurrent (state
+    # space) mixer, `E` the `mlp` piece alone, `*` attention alone; None:
+    # every block is attention then `mlp` (`serve_layers` says how each runs)
+    pattern: Optional[str] = None
 
     @property
     def kv_heads(self) -> int:
         return self.heads
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers of the K/V pools: the layers that hold attention."""
+        return self.layers
+
+    def state_pools(self, rows: int, dtype) -> Tuple:
+        """((shape, dtype), ...) of the pools of per-sequence state that is
+        not K/V, `[layers of the kind, rows, ...]` each, for `rows` rows
+        (row 0 the null row) at the served `dtype`; () for a model whose
+        sequences keep nothing but their blocks."""
+        return ()
 
     @property
     def stored(self) -> Tuple[int, int]:
@@ -95,6 +120,8 @@ class ServeModel:
         `[B, T, ctx]`, what `proj` takes."""
         from ..ops.pallas import attention as pa
 
+        if self.kv_heads != self.heads:
+            return gqa_prompt(q, k, v, self.heads, self.kv_heads)
         B, T = q.shape[:2]
         heads = (B, T, self.heads, self.head_dim)
         ctx = pa.mha(q.reshape(heads), k.reshape(heads), v.reshape(heads),
@@ -106,13 +133,16 @@ class ServeModel:
         stored entries `keys` `[S, M, k width]` and `vals` `[S, M, v
         width]` of positions 0..M-1, `pos` `[S, W]`: row (s, w) sees the
         positions `<= pos[s, w]` -> `[S, W, ctx]`."""
-        return mha_cached(q, keys, vals, pos, self.heads)
+        return mha_cached(q, keys, vals, pos, self.heads, self.kv_heads)
 
     def paged_route(self, x, k_pool, v_pool) -> Optional[str]:
         """The name (a key of `paged_attention.GATE_COUNTS`) of the kernel
         a decode step over these pools takes, or None: the gathered form."""
         from ..ops.pallas import paged_attention as pa
 
+        if self.kv_heads != self.heads:
+            return "paged_gqa" if pa.use_paged_gqa(
+                x, k_pool, self.heads, self.kv_heads) else None
         return "paged" if pa.use_paged(x, k_pool, self.heads) else None
 
     def attend_paged(self, lp, q, k_pool, v_pool, layer, block_tables,
@@ -122,6 +152,10 @@ class ServeModel:
         where `paged_route` named a kernel."""
         from ..ops.pallas import paged_attention as pa
 
+        if self.kv_heads != self.heads:
+            return pa.paged_gqa_attention(
+                q, k_pool, v_pool, layer, block_tables, positions,
+                heads=self.heads, kv_heads=self.kv_heads)
         return pa.paged_attention(q, k_pool, v_pool, layer, block_tables,
                                   positions, heads=self.heads)
 
@@ -141,6 +175,33 @@ class ServeModel:
         tensor in place (a kernel's operand cannot be a slice without
         being a copy): such a tensor is then left out of `layer_params`.
         A leading layer's `lp` says by its keys what kind it is."""
+        raise NotImplementedError
+
+    # -- a model of one mixer a block (`pattern`) -------------------------
+
+    def block_params(self, params: Params, kind: str, i: int) -> Params:
+        """The parameters of block `i` AMONG THE BLOCKS OF ITS KIND (a
+        slice of the kind's stack; a stack a kernel addresses in place is
+        left out, as in `layer_params`)."""
+        raise NotImplementedError
+
+    def norm(self, lp, h):
+        """The one norm of a block of a `pattern` model."""
+        raise NotImplementedError
+
+    def ssm_prompt(self, lp, y, length, state, i: int, row):
+        """A recurrent mixer over one whole prompt y `[1, T, hidden]` of
+        true length `length`, from a ZERO state -> (out `[1, T, hidden]`,
+        `state` with row `row` of layer `i` of every pool overwritten by
+        the state after position `length - 1`). Positions at or past
+        `length` (the bucket's padding) must leave the state as it was."""
+        raise NotImplementedError
+
+    def ssm_token(self, lp, y, state, i: int, rows):
+        """One token a slot, y `[S, hidden]`: reads rows `rows` `[S]` of
+        layer `i` of the pools, advances them one token and writes them
+        back in place -> (out `[S, hidden]`, state). Idle slots carry row
+        0; several may, in any order."""
         raise NotImplementedError
 
     def head(self, params: Params, x, prev_ids, eos_id: int):
@@ -185,17 +246,68 @@ def rms_head(params: Params, x: jax.Array, prev_ids: jax.Array, eos_id: int,
     return beam_top1(prev_ids.astype(jnp.int32), logits, eos_id)
 
 
+def pattern_blocks(pattern: str):
+    """(kind, the block's index AMONG THE BLOCKS OF ITS KIND) for every
+    block of a `pattern`, in order: `MEM*` -> M 0, E 0, M 1, * 0."""
+    seen: Dict[str, int] = {}
+    for kind in pattern:
+        i = seen.get(kind, 0)
+        seen[kind] = i + 1
+        yield kind, i
+
+
+def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
+                 positions: jax.Array, k_pool: jax.Array,
+                 v_pool: jax.Array, attend, state, ssm):
+    """`serve_layers` for a model of ONE mixer a block (`model.pattern`):
+    the blocks one by one in the pattern's order, `h + mixer(norm(h))`
+    each, every kind's parameters addressed in its own stack by the
+    block's index AMONG ITS KIND, which is also its layer in the K/V pools
+    (`*`) or the state pools (`M`). `attend` as in `serve_layers`;
+    `ssm(i, lp, y, state) -> (out, state)` is the program's recurrent
+    mixer (a whole prompt, or a token a slot). Returns (x, k_pool, v_pool,
+    the `E` blocks' counters stacked or None, state)."""
+    stats = []
+    with jax.named_scope("layers"):
+        for kind, i in pattern_blocks(model.pattern):
+            lp = model.block_params(params, kind, i)
+            y = model.norm(lp, x)
+            if kind == "M":
+                with jax.named_scope("ssm"):
+                    out, state = ssm(i, lp, y, state)
+                x = x + out
+            elif kind == "E":
+                out, st = model.mlp(lp, y, params, i)
+                x = x + out
+                stats.append(st)
+            elif kind == "*":
+                q, k, v = model.qkv(lp, y, positions)
+                ctx, k_pool, v_pool = attend(jnp.int32(i), lp, q, k, v,
+                                             k_pool, v_pool)
+                x = model.proj(lp, ctx, x)
+            else:
+                raise ValueError(f"unknown block kind {kind!r}")
+    stats = None if not stats or stats[0] is None else \
+        jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
+    return x, k_pool, v_pool, stats, state
+
+
 def serve_layers(model: ServeModel, params: Params, x: jax.Array,
                  positions: jax.Array, k_pool: jax.Array,
-                 v_pool: jax.Array, attend):
+                 v_pool: jax.Array, attend, state=(), ssm=None):
     """The serve programs' layer loop: `x` through every block with the
     pools in the loop's carry: the model's leading layers one by one, then
     a scan over the stacked ones (a model whose layers are all alike has
     no leading ones, and the loop is the scan). `attend(l, lp, q, k, v,
     kp, vp)` is the one part the programs differ in: it gets the layer
     index, the layer's parameters and projections and the WHOLE pools,
-    writes k/v at (l, block, slot), and returns `(ctx, kp, vp)`. Returns
-    (x, k_pool, v_pool, the stacked layers' counters or None)."""
+    writes k/v at (l, block, slot), and returns `(ctx, kp, vp)`. A model
+    of one mixer a block goes through `mixer_layers`, which also carries
+    `state`. Returns (x, k_pool, v_pool, the stacked layers' counters or
+    None, state)."""
+    if model.pattern is not None:
+        return mixer_layers(model, params, x, positions, k_pool, v_pool,
+                            attend, state, ssm)
 
     def layer_body(carry, per_layer):
         h, kp, vp = carry
@@ -216,13 +328,13 @@ def serve_layers(model: ServeModel, params: Params, x: jax.Array,
             carry, _ = layer_body(carry, (lp, jnp.int32(l)))
         (x, k_pool, v_pool), stats = jax.lax.scan(
             layer_body, carry, (model.layer_params(params), layers))
-    return x, k_pool, v_pool, stats
+    return x, k_pool, v_pool, stats, state
 
 
 def prefill(model: ServeModel, params: Params, ids: jax.Array,
             length: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-            block_table: jax.Array, *, block_size: int,
-            eos_id: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+            block_table: jax.Array, state=(), row=None, *, block_size: int,
+            eos_id: int):
     """One prompt through the stack, filling its KV blocks.
 
     ids [1, T] (edge-padded to the prefill bucket T), length = true
@@ -231,6 +343,12 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     write to the null block / soon-overwritten slots (see
     kv_cache.write_prefill_kv) and, being causally AFTER every real
     position, never contribute to the last real position's logits.
+
+    A model with recurrent state also takes its `state` pools and the
+    sequence's `row` in them, which the prompt overwrites from a zero
+    state (`ServeModel.ssm_prompt`: the padded tail leaves the state as
+    position `length - 1` left it), and returns (tok, k_pool, v_pool,
+    state).
     """
     from ..serving import kv_cache as kvc
 
@@ -249,23 +367,62 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
             ctx = model.attend_prompt(lp, q, k, v)
         return ctx, kp, vp
 
-    x, k_pool, v_pool, _ = serve_layers(model, params, x, positions,
-                                        k_pool, v_pool, attend)
+    def ssm(i, lp, y, st):
+        return model.ssm_prompt(lp, y, length, st, i, row)
+
+    x, k_pool, v_pool, _, state = serve_layers(
+        model, params, x, positions, k_pool, v_pool, attend, state, ssm)
     # the final norm is per row: the last real position alone goes through
     last = jnp.maximum(length, 1) - 1
     tok = model.head(params, x[0, last][None], ids[0, last][None], eos_id)
+    if state:
+        return tok, k_pool, v_pool, state
     return tok, k_pool, v_pool
 
 
+def gqa_prompt(q: jax.Array, k: jax.Array, v: jax.Array, heads: int,
+               kv_heads: int) -> jax.Array:
+    """Causal grouped-query attention of whole sequences from their own
+    projections: q `[B, T, heads*D]`, k and v `[B, T, kv_heads*D]`; the
+    `heads / kv_heads` query heads of a K/V head read it together, scores
+    and softmax in float32 at `1/sqrt(D)` -> `[B, T, heads*D]`."""
+    B, T = q.shape[:2]
+    D = q.shape[-1] // heads
+    q = q.reshape(B, T, kv_heads, heads // kv_heads, D)
+    k = k.reshape(B, T, kv_heads, D)
+    v = v.reshape(B, T, kv_heads, D)
+    scores = jnp.einsum("btgrd,bsgd->bgrts", q, k,
+                        preferred_element_type=jnp.float32) \
+        * (1.0 / math.sqrt(D))
+    seen = jnp.tril(jnp.ones((T, T), bool))
+    att = jax.nn.softmax(jnp.where(seen, scores, -1e9), axis=-1)
+    ctx = jnp.einsum("bgrts,bsgd->btgrd", att.astype(v.dtype), v)
+    return ctx.reshape(B, T, heads * D)
+
+
 def mha_cached(q: jax.Array, keys: jax.Array, vals: jax.Array,
-               pos: jax.Array, heads: int) -> jax.Array:
+               pos: jax.Array, heads: int, kv_heads: Optional[int] = None
+               ) -> jax.Array:
     """Multi-head attention of query rows over a gathered context (the
     default `ServeModel.attend_cached`): q `[S, W, heads*head_dim]`, keys
-    and vals `[S, M, heads*head_dim]`, row (s, w) sees key positions `<=
-    pos[s, w]` -> `[S, W, heads*head_dim]`."""
+    and vals `[S, M, kv_heads*head_dim]`, row (s, w) sees key positions
+    `<= pos[s, w]` -> `[S, W, heads*head_dim]`. With fewer K/V heads than
+    query heads, a K/V head's query heads read it together."""
     S, W, width = q.shape
     m = keys.shape[1]
     hd = width // heads
+    if kv_heads is not None and kv_heads != heads:
+        q = q.reshape(S, W, kv_heads, heads // kv_heads, hd)
+        keys = keys.reshape(S, m, kv_heads, hd)
+        vals = vals.reshape(S, m, kv_heads, hd)
+        scores = jnp.einsum("swgrd,smgd->swgrm", q, keys) \
+            * (1.0 / math.sqrt(hd))
+        mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
+            <= pos[:, :, None]
+        scores = jnp.where(mask[:, :, None, None, :], scores, -1e9)
+        att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        ctx = jnp.einsum("swgrm,smgd->swgrd", att.astype(keys.dtype), vals)
+        return ctx.reshape(S, W, width)
     q = q.reshape(S, W, heads, hd)
     keys = keys.reshape(S, m, heads, hd)
     vals = vals.reshape(S, m, heads, hd)
@@ -297,8 +454,8 @@ def cached_attention(attend, q: jax.Array, k_pool: jax.Array,
 
 def decode_step(model: ServeModel, params: Params, ids: jax.Array,
                 positions: jax.Array, k_pool: jax.Array,
-                v_pool: jax.Array, block_tables: jax.Array, *,
-                block_size: int, eos_id: int):
+                v_pool: jax.Array, block_tables: jax.Array, state=(),
+                rows=None, *, block_size: int, eos_id: int):
     """One decode step for S resident slots.
 
     ids [S] (each slot's previous token), positions [S] (where this
@@ -307,7 +464,10 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     blocks, so a slot's tokens are bit-identical whatever else shares
     the batch — the property test_decode's admit-mid-decode test pins.
     Returns (next tokens [S], k_pool, v_pool, the layers' stacked
-    counters or None: `ServeModel.mlp`)."""
+    counters or None: `ServeModel.mlp`). A model with recurrent state also
+    takes its `state` pools and each slot's row `rows` [S] (0, the null
+    row, for an idle slot), advances the rows in place
+    (`ServeModel.ssm_token`) and returns the pools as a fifth result."""
     from ..ops.pallas import paged_attention as pa
     from ..serving import kv_cache as kvc
 
@@ -337,9 +497,15 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
                 vp, l, block_tables, positions[:, None])[:, 0]
         return ctx, kp, vp
 
-    x, k_pool, v_pool, stats = serve_layers(model, params, x, positions,
-                                            k_pool, v_pool, attend)
-    return model.head(params, x, ids, eos_id), k_pool, v_pool, stats
+    def ssm(i, lp, y, st):
+        return model.ssm_token(lp, y, st, i, rows)
+
+    x, k_pool, v_pool, stats, state = serve_layers(
+        model, params, x, positions, k_pool, v_pool, attend, state, ssm)
+    tok = model.head(params, x, ids, eos_id)
+    if state:
+        return tok, k_pool, v_pool, stats, state
+    return tok, k_pool, v_pool, stats
 
 
 def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
@@ -387,8 +553,8 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
             block_table[None], pos[None])[0]
         return ctx, kp, vp
 
-    x, k_pool, v_pool, _ = serve_layers(model, params, x, pos, k_pool,
-                                        v_pool, attend)
+    x, k_pool, v_pool, _, _ = serve_layers(model, params, x, pos, k_pool,
+                                           v_pool, attend)
     last = jnp.clip(length - 1 - start, 0, C - 1)
     tok = model.head(params, x[last][None], ids[0, last][None], eos_id)
     return tok, k_pool, v_pool
@@ -433,8 +599,8 @@ def verify_step(model: ServeModel, params: Params, ids: jax.Array,
                                q, kp, vp, l, block_tables, pos)
         return ctx, kp, vp
 
-    x, k_pool, v_pool, _ = serve_layers(model, params, x, pos, k_pool,
-                                        v_pool, attend)
+    x, k_pool, v_pool, _, _ = serve_layers(model, params, x, pos, k_pool,
+                                           v_pool, attend)
     tokens = model.head(params, x.reshape(S * W, -1), ids.reshape(S * W),
                         eos_id).reshape(S, W)
     return tokens, k_pool, v_pool

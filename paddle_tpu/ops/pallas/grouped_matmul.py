@@ -26,21 +26,29 @@ import jax
 from . import attention as _attention
 
 # tiles (rows, contraction, columns) of the megablox kernel: rows are
-# (token, expert) pairs, so 16 decode slots x 8 experts fill one tile
+# (token, expert) pairs, so 16 decode slots x 8 experts fill one tile. The
+# contraction and column tiles are the most that `_tile` finds under these
 TILES = (128, 1024, 1024)
 
 # which route each trace took ("megablox" | "xla"), as attention's counts
 GATE_COUNTS: collections.Counter = collections.Counter()
 
 
+def _tile(size: int, cap: int) -> int:
+    """The tile of a dimension of `size` whole lane tiles: `size` itself
+    under the cap, else the largest multiple of 128 up to `cap` that
+    divides it (2048 -> 1024; 2688 = 21 x 128 -> 896; 1920 -> 640)."""
+    if size <= cap:
+        return size
+    return max(t for t in range(128, cap + 1, 128) if size % t == 0)
+
+
 def _use_megablox(x, w) -> bool:
     m, k = x.shape
     n = w.shape[-1]
-    tm, tk, tn = TILES
     return (_attention._platform(x) == "tpu"
             and _attention._mesh_partitionable(x)
-            and m % tm == 0 and k % min(k, tk) == 0 and n % min(n, tn) == 0
-            and k % 128 == 0 and n % 128 == 0)
+            and m % TILES[0] == 0 and k % 128 == 0 and n % 128 == 0)
 
 
 def grouped_matmul(x: jax.Array, w: jax.Array,
@@ -54,6 +62,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array,
         tm, tk, tn = TILES
         GATE_COUNTS["megablox"] += 1
         return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
-                   tiling=(tm, min(tk, x.shape[1]), min(tn, w.shape[-1])))
+                   tiling=(tm, _tile(x.shape[1], tk),
+                           _tile(w.shape[-1], tn)))
     GATE_COUNTS["xla"] += 1
     return jax.lax.ragged_dot(x, w, group_sizes)

@@ -26,6 +26,13 @@ head h's context in its own D lanes and other heads' in the rest, which the
 block-diagonal mask drops once a slot, when the rows are summed to
 `[1, H*D]`. 64-wide and 128-wide heads differ in D alone.
 
+Grouped-query attention (fewer K/V heads than query heads: `_gqa_kernel`)
+is the same walk with the `heads / kv_heads` query heads of a K/V head as
+ONE query block: query head h sits in row h, in the lanes of ITS K/V head
+(`Q[h, g*D:(g+1)*D] = q[h]`, g = h // group), so one `Q . K^T` scores all
+query heads against the K/V rows as they lie, and row h's context is its
+K/V head's D lanes of `acc`. A token is read once for all of its group.
+
 How it reads only what is live. The grid walks the slots in order (one
 TensorCore; the steps depend on each other through the buffers). A slot
 with position p owns `p // BS + 1` blocks; a slot whose table starts with
@@ -55,8 +62,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import attention as _attention
 
-# which route each decode program's trace took ("paged" | "gather"), as
-# attention's counts; DecodeEngine.status() reports them
+# which route each decode program's trace took ("paged" | "paged_gqa" |
+# "paged_latent" | "gather"), as attention's counts; DecodeEngine.status()
+# reports them
 GATE_COUNTS: collections.Counter = collections.Counter()
 
 # tokens a compute step: a multiple of 128, so scores [M, _CHUNK] fill
@@ -92,6 +100,19 @@ def use_paged(q: jax.Array, pool: jax.Array, heads: int) -> bool:
     head_dim = hd // heads
     return (_on_one_tpu(q) and heads * head_dim == hd
             and (head_dim == 64 or head_dim % 128 == 0))
+
+
+def use_paged_gqa(q: jax.Array, pool: jax.Array, heads: int,
+                  kv_heads: int) -> bool:
+    """Whether grouped-query decode attention takes the kernel: on one
+    TPU, over pools `[L, NB, BS, kv_heads*D]` whose blocks are whole tiles,
+    D whole lane tiles and the query heads whole sublane tiles."""
+    if not _tiles(pool) or q.dtype != pool.dtype:
+        return False
+    head_dim = pool.shape[3] // kv_heads
+    return (_on_one_tpu(q) and kv_heads * head_dim == pool.shape[3]
+            and heads % kv_heads == 0 and head_dim % 128 == 0
+            and heads % (32 // pool.dtype.itemsize) == 0)
 
 
 def use_paged_latent(q: jax.Array, c_pool: jax.Array, r_pool: jax.Array,
@@ -237,6 +258,38 @@ def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[...] = jnp.sum(ctx, axis=0, keepdims=True)
 
 
+def _gqa_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                kbuf, vbuf, sems, done_ref, *, kv_heads: int, scale: float):
+    heads, head_dim = q_ref.shape
+    width = kbuf.shape[2]               # kv_heads * head_dim
+    group = heads // kv_heads
+
+    def setup():
+        pos = pos_ref[pl.program_id(0)]
+        row = lax.broadcasted_iota(jnp.int32, (heads, width), 0)
+        col = lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+        own = col // head_dim == row // group
+        q = jnp.concatenate([q_ref[...]] * kv_heads, axis=1)
+        q = jnp.where(own, q, jnp.zeros_like(q))
+        return (pos, q, own), _softmax_init(heads, width)
+
+    def step(query, c, buf, carry):
+        pos, q, _ = query
+        sc = lax.dot_general(q, kbuf[buf], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32) * scale
+        return _softmax_step(sc, vbuf[buf], c, pos, carry)
+
+    (_, _, own), (m, l, acc) = _walk(
+        layer_ref, tables_ref, pos_ref, (k_hbm, v_hbm), (kbuf, vbuf), sems,
+        done_ref, (vbuf,), setup, step)
+    # row h keeps its own K/V head's lanes; an inactive slot gives zeros
+    ctx = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
+    out = ctx[:, :head_dim]
+    for g in range(1, kv_heads):
+        out = out + ctx[:, g * head_dim:(g + 1) * head_dim]
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
 def _latent_kernel(layer_ref, tables_ref, pos_ref, ql_ref, qr_ref, c_hbm,
                    r_hbm, o_ref, cbuf, rbuf, sems, done_ref, *,
                    scale: float):
@@ -308,6 +361,42 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     )(*_scalars(layer, block_tables, positions), q[:, None, :], k_pool,
       v_pool)
     return out[:, 0, :].astype(q.dtype)
+
+
+def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                        layer: jax.Array, block_tables: jax.Array,
+                        positions: jax.Array, *, heads: int, kv_heads: int,
+                        interpret: bool = False) -> jax.Array:
+    """Grouped-query decode attention: q `[S, heads*D]` against layer
+    `layer` of the pools `[L, NB, BS, kv_heads*D]` through block_tables
+    `[S, MB]`; query head h reads K/V head `h // (heads / kv_heads)`. Slot
+    s attends key positions `0..positions[s]` at `1/sqrt(D)`, scores and
+    softmax in float32, and gets `[heads*D]` in q's dtype; a slot whose
+    table starts with the null block gets zeros."""
+    n_slots = q.shape[0]
+    head_dim = k_pool.shape[3] // kv_heads
+    per_slot = lambda s, *_: (s, 0, 0)      # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_gqa_kernel, kv_heads=kv_heads,
+                          scale=1.0 / math.sqrt(head_dim)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_slots,),
+            in_specs=[
+                pl.BlockSpec((None, heads, head_dim), per_slot),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, heads, head_dim), per_slot),
+            scratch_shapes=_scratch(k_pool, v_pool)),
+        out_shape=jax.ShapeDtypeStruct((n_slots, heads, head_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_gqa_attention",
+    )(*_scalars(layer, block_tables, positions),
+      q.reshape(n_slots, heads, head_dim), k_pool, v_pool)
+    return out.reshape(n_slots, heads * head_dim)
 
 
 def paged_latent_attention(q_latent: jax.Array, q_rope: jax.Array,

@@ -1,0 +1,166 @@
+"""The state-space (Mamba-2, "SSD") recurrence and the causal depthwise
+convolution before it, in the two forms a served model needs.
+
+A head h keeps a state `S` [P, N] (P the head's width, N the state size).
+With `a_t = dt_t * A_h` (A negative, dt positive) a token does
+
+    S_t = exp(a_t) S_{t-1} + dt_t x_t B_t^T          x_t [P], B_t [N]
+    y_t = S_t C_t + D_h x_t                          C_t [N]
+
+B and C belong to a GROUP of heads (`G` groups, H/G heads each).
+
+- `ssd_chunked`: a whole prompt. The sequence is cut into chunks of
+  `chunk` tokens; inside a chunk the recurrence is the quadratic form
+  `y_i = sum_{j<=i} (C_i . B_j) exp(cs_i - cs_j) dt_j x_j` (cs the running
+  sum of `a` in the chunk: two matmuls and a mask), between chunks only
+  the state moves: `S_c+1 = exp(cs_last) S_c + sum_j exp(cs_last - cs_j)
+  dt_j x_j B_j^T`, and a token reads the state its chunk began with,
+  `exp(cs_i) C_i . S_c`. Decay sums and their exponentials are float32;
+  the products run in the inputs' dtype and accumulate in float32. A
+  position whose `dt` is 0 (`dt_t = 0`: decay 1, no input) leaves the state
+  as it was: how a prompt padded to its bucket keeps the padding out.
+- `ssd_step`: one token a row, the recurrence as written.
+- `ssd_recurrent`: the recurrence token by token over a sequence (a
+  `lax.scan` of `ssd_step`): what the chunked form is tested against.
+
+`causal_conv` / `conv_step` are the width-K depthwise convolution over the
+sequence: `out_t = b + sum_k w[k] x_{t-(K-1)+k}`, and its one-token form
+over a tail of the last K-1 inputs.
+
+Every function is row-independent: a sequence's results depend on that
+sequence alone. All ops carry the layer scope `ssm` of their caller.
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """x [B, T, C], w [K, C], b [C] -> [B, T, C]: position t sees inputs
+    t-K+1..t (zeros before the sequence)."""
+    K = w.shape[0]
+    T = x.shape[1]
+    xp = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
+    out = b.astype(jnp.float32)
+    for k in range(K):
+        out = out + xp[:, k:k + T].astype(jnp.float32) \
+            * w[k].astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+def conv_tail(x: jax.Array, length: jax.Array, K: int) -> jax.Array:
+    """The last K-1 inputs before position `length` of x [B, T, C] (zeros
+    before the sequence) -> [B, K-1, C]: what `conv_step` continues from."""
+    xp = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
+    return jax.lax.dynamic_slice_in_dim(xp, length, K - 1, axis=1)
+
+
+def conv_step(tail: jax.Array, x: jax.Array, w: jax.Array, b: jax.Array
+              ) -> Tuple[jax.Array, jax.Array]:
+    """One token: tail [S, K-1, C] (the inputs before it), x [S, C] ->
+    (out [S, C], the new tail)."""
+    window = jnp.concatenate([tail, x[:, None].astype(tail.dtype)], axis=1)
+    out = b.astype(jnp.float32) + jnp.sum(
+        window.astype(jnp.float32) * w.astype(jnp.float32)[None], axis=1)
+    return out.astype(x.dtype), window[:, 1:]
+
+
+def ssd_step(state: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
+             Bm: jax.Array, Cm: jax.Array, D: jax.Array
+             ) -> Tuple[jax.Array, jax.Array]:
+    """One token a row. state [S, H, P, N] float32, x [S, H, P], dt [S, H]
+    float32, A and D [H], Bm and Cm [S, G, N] -> (y [S, H, P] float32, the
+    new state)."""
+    S, H, P, N = state.shape
+    G = Bm.shape[1]
+    f32 = jnp.float32
+    xf = x.astype(f32)
+    Bh = jnp.repeat(Bm.astype(f32), H // G, axis=1)         # [S, H, N]
+    Ch = jnp.repeat(Cm.astype(f32), H // G, axis=1)
+    decay = jnp.exp(dt * A.astype(f32))                     # [S, H]
+    state = decay[..., None, None] * state \
+        + (dt[..., None] * xf)[..., None] * Bh[:, :, None, :]
+    y = jnp.sum(state * Ch[:, :, None, :], axis=-1) \
+        + D.astype(f32)[None, :, None] * xf
+    return y, state
+
+
+def ssd_recurrent(x, dt, A, Bm, Cm, D, init: Optional[jax.Array] = None):
+    """The recurrence token by token: x [B, T, H, P], dt [B, T, H], Bm and
+    Cm [B, T, G, N] -> (y [B, T, H, P] float32, the last state [B, H, P, N]
+    float32)."""
+    B, T, H, P = x.shape
+    N = Bm.shape[-1]
+    state = jnp.zeros((B, H, P, N), jnp.float32) if init is None else init
+
+    def one(state, t):
+        y, state = ssd_step(state, t[0], t[1], A, t[2], t[3], D)
+        return state, y
+
+    move = lambda a: jnp.moveaxis(a, 1, 0)      # noqa: E731
+    state, ys = jax.lax.scan(one, state,
+                             (move(x), move(dt), move(Bm), move(Cm)))
+    return jnp.moveaxis(ys, 0, 1), state
+
+
+def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+                Cm: jax.Array, D: jax.Array, chunk: int,
+                init: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """A whole sequence in chunks of `chunk` tokens: x [B, T, H, P], dt
+    [B, T, H] float32 (0 where a position must not count), A and D [H], Bm
+    and Cm [B, T, G, N] -> (y [B, T, H, P] float32, the state after the
+    last position [B, H, P, N] float32). `T` is padded to whole chunks with
+    positions that do not count."""
+    B, T, H, P = x.shape
+    G, N = Bm.shape[2:]
+    R = H // G
+    f32 = jnp.float32
+    pad = -T % chunk
+    if pad:
+        x, dt, Bm, Cm = (jnp.pad(a, [(0, 0), (0, pad)]
+                                 + [(0, 0)] * (a.ndim - 2))
+                         for a in (x, dt, Bm, Cm))
+    nc, L = (T + pad) // chunk, chunk
+    xc = x.reshape(B, nc, L, G, R, P)
+    Bc = Bm.reshape(B, nc, L, G, N)
+    Cc = Cm.reshape(B, nc, L, G, N)
+    # per-head scalars with the chunk's positions minor-most: [B,nc,G,R,L]
+    dtc = dt.astype(f32).reshape(B, nc, L, G, R).transpose(0, 1, 3, 4, 2)
+    cs = jnp.cumsum(dtc * A.astype(f32).reshape(G, R, 1), axis=-1)
+    by_pos = lambda a: a.transpose(0, 1, 4, 2, 3)   # noqa: E731 [B,nc,L,G,R]
+    # inside a chunk: (C_i . B_j) exp(cs_i - cs_j) dt_j for j <= i
+    cb = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc,
+                    preferred_element_type=f32)
+    seen = jnp.tril(jnp.ones((L, L), bool))
+    # the exponent is masked BEFORE the exponential: cs_i - cs_j > 0 above
+    # the diagonal, and its exponential may overflow
+    gap = jnp.where(seen, cs[..., :, None] - cs[..., None, :], -jnp.inf)
+    m = cb[:, :, :, None] * jnp.exp(gap) * dtc[..., None, :]  # [B,nc,G,R,i,j]
+    y = jnp.einsum("bcgrij,bcjgrp->bcigrp", m.astype(x.dtype), xc,
+                   preferred_element_type=f32)
+    # what a chunk adds to the state, and how far the state it met decays
+    last = cs[..., -1]                                      # [B, nc, G, R]
+    to_end = by_pos(jnp.exp(last[..., None] - cs) * dtc)    # [B,nc,L,G,R]
+    added = jnp.einsum("bcjgrp,bcjgn->bcgrpn",
+                       (xc.astype(f32) * to_end[..., None]).astype(x.dtype),
+                       Bc, preferred_element_type=f32)
+    state = jnp.zeros((B, G, R, P, N), f32) if init is None \
+        else init.reshape(B, G, R, P, N)
+
+    def carry(state, c):
+        return jnp.exp(c[0])[..., None, None] * state + c[1], state
+
+    state, met = jax.lax.scan(
+        carry, state, (jnp.moveaxis(last, 1, 0), jnp.moveaxis(added, 1, 0)))
+    met = jnp.moveaxis(met, 0, 1)                           # [B,nc,G,R,P,N]
+    y = y + jnp.einsum("bcign,bcgrpn->bcigrp", Cc, met.astype(x.dtype),
+                       preferred_element_type=f32) \
+        * by_pos(jnp.exp(cs))[..., None]
+    y = y + D.astype(f32).reshape(G, R)[..., None] * xc.astype(f32)
+    return (y.reshape(B, nc * L, H, P)[:, :T],
+            state.reshape(B, H, P, N))

@@ -9,7 +9,10 @@ owns —
 - **paged KV cache** (kv_cache.py): fixed-size blocks in ONE
   preallocated device pool, per-sequence block tables, blocks
   allocated on admit / freed on finish, so HBM scales with live
-  tokens, not max_seq_len × batch;
+  tokens, not max_seq_len × batch; and, for a model with recurrent
+  layers, STATE ROW pools beside it: a fixed-size row a sequence that
+  its prefill overwrites and every decode step advances in place,
+  addressed by a row id handed to the step as block tables are;
 - **continuous (in-flight) batching** (ORCA OSDI'22): the scheduler
   admits new requests into the RUNNING decode batch every step and
   retires finished ones without draining it;
@@ -67,9 +70,11 @@ from ..observability import perfwatch as _perfwatch
 from ..observability import telemetry as _telemetry
 from ..observability import tracing as _tracing
 from ..ops.pallas import paged_attention as _paged_attention
+from ..ops.pallas import ssm_update as _ssm_update
 from .batcher import QueueFullError, ServerClosed
-from .kv_cache import (PREFILL_WRITE_UNITS, BlockAllocator, KVCacheConfig,
-                       NoBlocksError, build_block_table, init_pools)
+from .kv_cache import (NULL_ROW, PREFILL_WRITE_UNITS, BlockAllocator,
+                       KVCacheConfig, NoBlocksError, StateRowAllocator,
+                       build_block_table, init_pools)
 from . import kv_reuse as _kvr
 from .kv_reuse import ReuseBlockAllocator
 
@@ -257,7 +262,8 @@ class _Request:
                  "error", "cancelled", "last_token", "pos", "blocks",
                  "admitted_at", "tctx", "enqueued_at",
                  "prefill_pos", "draft_pos", "n_reused", "hashes",
-                 "tenant", "traced", "parent", "arrival", "preempted")
+                 "tenant", "traced", "parent", "arrival", "preempted",
+                 "state_row")
 
     def __init__(self, rid: int, prompt: np.ndarray, max_new: int,
                  tenant: str = "default"):
@@ -282,7 +288,12 @@ class _Request:
         self.prompt_len0 = len(prompt)         # original, for reporting
         self.max_new = int(max_new)
         self.generated: List[int] = []
-        self.events: "queue.Queue" = queue.Queue()
+        # the token stream to the request's reader. A SimpleQueue: `put`
+        # and a blocked `get` are single C calls, where `queue.Queue` takes
+        # a Python-level mutex and condition on either side, a handful of
+        # interpreter-lock handoffs a token a stream that the decode loop
+        # pays for once 64 streams are read at once (PR 34)
+        self.events: "queue.SimpleQueue" = queue.SimpleQueue()
         self.t_submit = time.monotonic()
         self.enqueued_at = self.t_submit   # re-stamped on preempt requeue
         self.t_first: Optional[float] = None
@@ -293,6 +304,7 @@ class _Request:
         self.last_token = 0
         self.pos = 0                           # next KV write position
         self.blocks: List[int] = []
+        self.state_row = NULL_ROW              # its row of the state pools
         self.admitted_at = 0.0
         # KV-reuse state (chunked prefill / prefix cache / speculation)
         self.prefill_pos = 0     # next prompt position to chunk-prefill
@@ -365,6 +377,30 @@ class DecodeEngine:
                 "DecodeConfig(spec_k=k) to enable speculation")
         # any reuse feature runs the synchronous scheduler (_loop_sync)
         self._sync = bool(self.prefill_chunk or self.spec_k)
+        rows = max(self.config.decode_slots) + 1    # + the null row
+        if model.state_pools(rows, np.dtype("float32")):
+            # a sequence's recurrent state is ONE row, the state after its
+            # last token: nothing in it can be shared, resumed or unwound
+            refused = [why for on, why in (
+                (self.config.prefix_cache,
+                 "prefix_cache: a shared prefix's blocks hold its K/V, but "
+                 "the recurrent state after the prefix is not kept (it "
+                 "needs a snapshot a cached prefix), so a reused prefix "
+                 "would start from no state"),
+                (self.prefill_chunk,
+                 "prefill_chunk: a prompt's slices would have to carry the "
+                 "recurrent state from one to the next (the prefill "
+                 "program starts from a zero state and writes the row "
+                 "once)"),
+                (self.spec_k,
+                 "spec_k: a rejected draft token has already advanced the "
+                 "recurrent state, and there is no snapshot to step back "
+                 "to"),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    "a model with recurrent state cannot be served with "
+                    + "; ".join(refused))
         if self.config.precision not in ("f32", "bf16"):
             _precision.get_policy(self.config.precision)  # typo => full msg
             raise ValueError(
@@ -378,10 +414,16 @@ class DecodeEngine:
             for k, v in params.items()}
         max_len = int(self.config.max_len or model.max_len)
         self.kv_cfg = KVCacheConfig(
-            layers=model.layers, widths=model.stored,
+            layers=model.kv_layers, widths=model.stored,
             max_len=max_len, block_size=self.config.block_size,
             num_blocks=self.config.num_blocks,
             dtype=str(np.dtype(self._compute_dtype)))
+        # the model's state pools ((shape, dtype), ...): () for a model
+        # whose sequences keep nothing but their blocks, and then no
+        # program takes or returns anything more than it did
+        self._state_specs = tuple(
+            (tuple(shape), np.dtype(dt)) for shape, dt in
+            model.state_pools(rows, np.dtype(self._compute_dtype)))
         # resolved grid lives on the ENGINE, never written back into
         # the caller's config (a DecodeConfig reused across engines
         # must not carry the first engine's derived bucket set)
@@ -409,7 +451,7 @@ class DecodeEngine:
                 k: _precision.cast_floating(v, self._compute_dtype)
                 for k, v in draft_params.items()}
             self._draft_kv_cfg = KVCacheConfig(
-                layers=dmodel.layers, widths=dmodel.stored,
+                layers=dmodel.kv_layers, widths=dmodel.stored,
                 max_len=max_len, block_size=self.config.block_size,
                 num_blocks=self.config.num_blocks,
                 dtype=str(np.dtype(self._compute_dtype)))
@@ -419,15 +461,21 @@ class DecodeEngine:
         pol = None if self.config.precision == "f32" \
             else self.config.precision
 
-        def _prefill_fn(p, ids, length, kp, vp, bt):
+        # `state`: the state pools and the row id(s), for a model that has
+        # them: (tok, k_pool, v_pool[, counters], state) come back
+        def _prefill_fn(p, ids, length, kp, vp, bt, *state):
             return _decoder.prefill(model, p, ids, length, kp, vp, bt,
-                                    block_size=bs, eos_id=self.eos_id)
+                                    *state, block_size=bs,
+                                    eos_id=self.eos_id)
 
         # (tokens, k_pool, v_pool, the model's per-layer counters or None)
-        def _decode_fn(p, ids, positions, kp, vp, bts):
+        def _decode_fn(p, ids, positions, kp, vp, bts, *state):
             return _decoder.decode_step(model, p, ids, positions, kp, vp,
-                                        bts, block_size=bs,
+                                        bts, *state, block_size=bs,
                                         eos_id=self.eos_id)
+
+        # the pools are donated and updated in place, the state pools too
+        donate = (3, 4, 6) if self._state_specs else (3, 4)
 
         def _chunk_fn(p, ids, start, length, kp, vp, bt):
             return _decoder.prefill_chunk(
@@ -448,12 +496,12 @@ class DecodeEngine:
         else:
             self._prefill = {
                 t: _JitDispatch(jax.jit(_prefill_fn,
-                                        donate_argnums=(3, 4)),
+                                        donate_argnums=donate),
                                 "prefill", meta={"bucket": int(t)},
                                 policy=pol)
                 for t in self.prefill_buckets}
         self._decode: Dict[int, _JitDispatch] = {
-            s: _JitDispatch(jax.jit(_decode_fn, donate_argnums=(3, 4)),
+            s: _JitDispatch(jax.jit(_decode_fn, donate_argnums=donate),
                             "decode", meta={"slots": int(s)}, policy=pol)
             for s in self.decode_slots}
 
@@ -523,6 +571,10 @@ class DecodeEngine:
         self.analysis = self._validate_boot()
 
         self._pools = init_pools(self.kv_cfg)
+        self._state = tuple(jnp.zeros(shape, dt)
+                            for shape, dt in self._state_specs)
+        self._state_alloc = StateRowAllocator(rows, self._state_specs) \
+            if self._state_specs else None
         self._draft_pools = init_pools(self._draft_kv_cfg) \
             if draft is not None else None
         # annotated with the reuse subtype so the lock-order analyzer
@@ -573,6 +625,13 @@ class DecodeEngine:
         self._mem_handles = [
             _memwatch.register_provider(own("kv_pool"), _kv_arrays),
             _memwatch.register_provider(own("params"), _param_arrays)]
+        if self._state_specs:
+            def _state_arrays():
+                eng = ref()
+                return () if eng is None else list(eng._state)
+
+            self._mem_handles.append(_memwatch.register_provider(
+                own("state_pool"), _state_arrays))
         if self.config.prefix_cache:
             # retained-prefix accounting: bytes of cached (unreferenced
             # but evictable) blocks across BOTH models' pools. These
@@ -803,9 +862,11 @@ class DecodeEngine:
                         for shape in kv.pool_shapes)
         mb = kv.max_blocks_per_seq
         base = kind[6:] if draft else kind
+        state = tuple(sds(shape, dt) for shape, dt in self._state_specs)
         if base == "prefill":
             return (p_sds, sds((1, n), np.int32), sds((), np.int32),
-                    kpool, vpool, sds((mb,), np.int32))
+                    kpool, vpool, sds((mb,), np.int32)) \
+                + ((state, sds((), np.int32)) if state else ())
         if base == "chunk":
             return (p_sds, sds((1, n), np.int32), sds((), np.int32),
                     sds((), np.int32), kpool, vpool,
@@ -815,7 +876,8 @@ class DecodeEngine:
                     sds((n,), np.int32), kpool, vpool,
                     sds((n, mb), np.int32))
         return (p_sds, sds((n,), np.int32), sds((n,), np.int32),
-                kpool, vpool, sds((n, mb), np.int32))
+                kpool, vpool, sds((n, mb), np.int32)) \
+            + ((state, sds((n,), np.int32)) if state else ())
 
     def warmup(self) -> int:
         """AOT-compile (or adopt from the persistent compile cache /
@@ -1188,6 +1250,15 @@ class DecodeEngine:
             # bucket that is not, a token at a time)
             "prefill_write": dict(PREFILL_WRITE_UNITS),
         }
+        if self._state_alloc is not None:
+            # the state row pools beside the K/V pools: rows a sequence can
+            # hold, rows held, device bytes
+            out["state"] = self._state_alloc.stats()
+            # which route the decode programs' state updates took, a count
+            # a recurrent layer traced ("kernel": the rows advanced in
+            # place, ops/pallas/ssm_update.py; "xla": gathered, advanced
+            # and scattered back)
+            out["state"]["update"] = dict(_ssm_update.GATE_COUNTS)
         if self._qos is not None:
             out["qos"] = {
                 "policy": self._qos.spec_dict(),
@@ -1279,6 +1350,7 @@ class DecodeEngine:
         if req.blocks:
             self._alloc.free(req.blocks)   # reuse allocator: decref;
             req.blocks = []                # cached blocks go to LRU
+        self._free_state_row(req)
         if req in self._active:
             self._active.remove(req)
         if req in self._prefilling:
@@ -1286,6 +1358,15 @@ class DecodeEngine:
         self._count(reason, req.tenant)
         req.events.put(None)
         self._kv_gauges()
+
+    def _free_state_row(self, req: _Request) -> None:
+        """A sequence that leaves (finish, cancel, preemption) gives its
+        state row back; what the row holds is overwritten by the prefill
+        of whoever takes it next, which the device runs after every step
+        already dispatched with this sequence in it."""
+        if req.state_row != NULL_ROW:
+            self._state_alloc.free(req.state_row)
+            req.state_row = NULL_ROW
 
     def _record_finish(self, req: _Request, reason: str, now: float):
         if req.t_first is not None and len(req.generated) > 1:
@@ -1323,6 +1404,9 @@ class DecodeEngine:
                "live_tokens": live_tokens,
                "blocks_used": self._alloc.used_blocks(),
                "blocks_usable": self.kv_cfg.usable_blocks}
+        if self._state_alloc is not None:
+            row["state_rows_used"] = self._state_alloc.used_rows()
+            row["state_rows"] = self._state_alloc.rows - 1
         _tracing.add_record("decode.steps", row)
         return row
 
@@ -1473,14 +1557,22 @@ class DecodeEngine:
         ids[0, :plen] = req.prompt
         ids[0, plen:] = req.prompt[-1]         # edge-pad (in-distribution)
         kp, vp = self._pools
+        state = ()
+        if self._state_alloc is not None:
+            # a row of its own, which this prefill overwrites from a zero
+            # state: nothing of the row's last holder is read
+            req.state_row = self._state_alloc.alloc()
+            state = (self._state, np.int32(req.state_row))
         t0 = time.perf_counter()
         wait = None
         if _tracing.recording:
             self._step_record("prefill", t0, 1, 1, plen)
             wait = _tracing.open_span("decode.prefill.wait", "decode")
-        tok, kp, vp = self._prefill[bucket](
-            self.params, ids, np.int32(plen), kp, vp, bt)
+        tok, kp, vp, *out = self._prefill[bucket](
+            self.params, ids, np.int32(plen), kp, vp, bt, *state)
         self._pools = (kp, vp)
+        if out:
+            self._state = out[0]
         if wait is not None:
             wait.close(call_s=time.perf_counter() - t0)
         STEPS.inc(phase="prefill")
@@ -1545,6 +1637,7 @@ class DecodeEngine:
         self._alloc.free(req.blocks)   # reuse allocator: decref — a
         req.blocks = []                # shared prefix survives for the
         req.prefill_pos = 0            # replay to hit again
+        self._free_state_row(req)      # the replay's prefill rebuilds it
         req.draft_pos = 0
         req.n_reused = 0
         req.hashes = None
@@ -1645,19 +1738,24 @@ class DecodeEngine:
         kp, vp = self._pools
         positions = np.zeros((C,), np.int32)
         bts = np.zeros((C, self.kv_cfg.max_blocks_per_seq), np.int32)
+        rows = np.full((C,), NULL_ROW, np.int32)    # idle slots: the null row
         for i, req in enumerate(slots):
             if req is None:
                 continue
             positions[i] = req.pos
+            rows[i] = req.state_row
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
+        state = (self._state, rows) if self._state_specs else ()
         # last, so that an assembly and its step are dispatched back to
         # back: the device may be waiting for just these two
         ids_arg, how = self._next_ids(sig, slots)
         self._pipeline[how] += 1
-        tok, kp, vp, stats = self._decode[C](self.params, ids_arg,
-                                             positions, kp, vp, bts)
+        tok, kp, vp, stats, *out = self._decode[C](
+            self.params, ids_arg, positions, kp, vp, bts, *state)
         self._pools = (kp, vp)
+        if out:
+            self._state = out[0]
         for req in slots:
             if req is not None:
                 req.pos += 1

@@ -146,10 +146,13 @@ class _ServingHandler(_base.QuietHandler):
     # -- token streaming (/v1/generate) --------------------------------
 
     def _chunk(self, line: str):
+        # ONE write a chunk: `wfile` is unbuffered, so the size line, the
+        # data and the CRLF as three writes were three `sendall` calls a
+        # token a stream, each giving up the interpreter lock and asking
+        # for it back; with 64 streams that contention was most of the
+        # decode loop's turn (PERF.md section 6, PR 34)
         data = line.encode("utf-8")
-        self.wfile.write(f"{len(data):x}\r\n".encode())
-        self.wfile.write(data)
-        self.wfile.write(b"\r\n")
+        self.wfile.write(b"%x\r\n%s\r\n" % (len(data), data))
         self.wfile.flush()
 
     def _shed_reply(self, e: ShedError):

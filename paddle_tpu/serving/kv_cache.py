@@ -40,6 +40,12 @@ Block 0 is reserved as the *null block*: padded/inactive decode slots
 and out-of-range table entries all read and write it, so a fixed-shape
 executable needs no validity branches — the attention length mask
 already guarantees nothing read from the null block ever contributes.
+
+Beside the block pools a model with recurrent layers keeps STATE ROW
+pools (`StateRowAllocator`): a fixed-size row a sequence, allocated at
+admission, overwritten by its prefill, advanced in place by every decode
+step, freed at finish, cancel and preemption (a replay's prefill rebuilds
+it). Row 0 is the null row, as block 0 is the null block.
 """
 
 from __future__ import annotations
@@ -54,11 +60,13 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["KVCacheConfig", "BlockAllocator", "NoBlocksError",
+           "StateRowAllocator", "NULL_ROW",
            "init_pools", "write_token_kv", "write_prefill_kv",
            "write_chunk_kv", "write_span_kv", "gather_kv",
            "NULL_BLOCK", "PREFILL_WRITE_UNITS"]
 
 NULL_BLOCK = 0
+NULL_ROW = 0
 
 # which unit each traced whole-prompt write took ("blocks" | "rows"), one
 # count a call of write_prefill_kv: a prefill program counts two, K and V.
@@ -223,6 +231,58 @@ class BlockAllocator:
             "entry_widths": list(self.cfg.entry_widths),
             "bytes_per_token_layer": self.cfg.bytes_per_token(),
         }
+
+
+class StateRowAllocator:
+    """Host-side free-list over the rows of a model's STATE pools: what a
+    sequence keeps that is not a token's (a recurrent layer's state, a
+    fixed size a sequence whatever its length; `models/decoder.ServeModel
+    .state_pools` gives the pools' shapes, `[layers of the kind, rows,
+    ...]`). A sequence holds ONE row id, the same in every pool and layer,
+    from admission to finish; the programs get the ids as they get block
+    tables. Row 0 is the null row: idle decode slots read and write it,
+    and it is never handed out. One row a slot of the largest decode
+    configuration plus the null row, so an admission that found a slot
+    finds a row. Single-owner like `BlockAllocator`: no locking."""
+
+    def __init__(self, rows: int, pools=()):
+        if rows < 2:
+            raise ValueError(f"a state pool needs the null row and one "
+                             f"more, got {rows} rows")
+        self.rows = int(rows)
+        self._free: List[int] = list(range(self.rows - 1, 0, -1))
+        self._owned: Dict[int, bool] = {}
+        self._bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                          for shape, dt in pools)
+
+    def used_rows(self) -> int:
+        return len(self._owned)
+
+    def alloc(self) -> int:
+        if not self._free:
+            raise NoBlocksError(
+                f"no state row free of {self.rows - 1}")
+        row = self._free.pop()
+        self._owned[row] = True
+        return row
+
+    def free(self, row: int):
+        """Double-free and foreign ids raise, as a block's do: a row listed
+        twice would hold two sequences' state."""
+        if row == NULL_ROW:
+            raise ValueError("row 0 (the null row) is never allocated "
+                             "and cannot be freed")
+        if row not in self._owned:
+            raise ValueError(f"state row {row} is not allocated "
+                             "(double free?)")
+        del self._owned[row]
+        self._free.append(int(row))
+
+    def stats(self) -> Dict[str, int]:
+        """`DecodeEngine.status()["state"]`: rows a sequence can hold (the
+        null row left out), rows held, and the pools' device bytes."""
+        return {"rows": self.rows - 1, "used": self.used_rows(),
+                "bytes": int(self._bytes)}
 
 
 # ---------------------------------------------------------------------------

@@ -154,6 +154,33 @@ def test_rehearse_serve_joyai(smoke):
     assert info["checked"]["decode_attention"] == {"gather": 1}
 
 
+def test_rehearse_serve_nemotron(smoke):
+    """The serve_nemotron phase at a tiny size: the model of one mixer a
+    block through the same engine and front, its state rows handed out and
+    given back, its tokens against the benchmark's plain reference (the
+    recurrence token by token; off the chip both gates take the gathered
+    forms, the chip run asserts the kernels' routes)."""
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = nemotron_h.NemotronHConfig.tiny()
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 15, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(8, 16), max_len=32), prompts, max_new=4,
+        logit_tol=smoke.NEMOTRON_LOGIT_TOL, model=nemotron_h,
+        reference_gaps=smoke._nemotron_reference_gaps)
+    checked = info["checked"]
+    assert checked["finished"]["length"] == 3
+    assert checked["compiles_after_warmup"] == 0
+    assert checked["decode_attention"] == {"gather": 1}
+    assert checked["state"]["update"] == {"xla": 2}
+    assert checked["state"]["rows"] == 4 and checked["state"]["used"] == 0
+    assert checked["state"]["bytes"] > 0
+
+
 @pytest.mark.parametrize("heads,head_dim", [(20, 64), (16, 128)])
 def test_rehearse_paged_attention(smoke, heads, head_dim):
     """The paged_attention phase at the benchmark's two widths, small

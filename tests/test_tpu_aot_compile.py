@@ -14,6 +14,7 @@ here, and with the gate answered for the described chip, where decode
 attention is the paged kernel of ops/pallas/paged_attention.py.
 """
 
+import collections
 import os
 import re
 
@@ -91,6 +92,19 @@ def _paged_latent(ql, qr, cp, rp, l, t, p):
                                      scale=192 ** -0.5)
 
 
+def _paged_gqa(heads, kv_heads):
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    return lambda q, kp, vp, l, t, p: PA.paged_gqa_attention(
+        q, kp, vp, l, t, p, heads=heads, kv_heads=kv_heads)
+
+
+def _state_update(pool, layer, rows, decay, dtx, b, c):
+    from paddle_tpu.ops.pallas import ssm_update as SU
+
+    return SU.state_update(pool, layer, rows, decay, dtx, b, c)
+
+
 def _block_write(pool, layer, kv, blocks):
     from paddle_tpu.ops.pallas import kv_block_write as BW
 
@@ -101,6 +115,8 @@ _QKV = "qkv"
 _PAGED = "paged"    # shape: (slots, table blocks, layers, heads, head_dim)
 _LATENT = "latent"  # shape: (slots, table blocks, layers, heads, dtype)
 _BLOCKS = "blocks"  # shape: (bucket, layers, lanes a token, dtype)
+_GQA = "gqa"        # shape: (slots, table blocks, layers, heads, kv, d, dtype)
+_STATE = "state"    # shape: (slots, layers, heads, P, N, groups)
 _CASES = [
     # BERT long-seq cell (bs 8, T 4096, 12 heads of 64, bf16), full mask
     pytest.param(_splash(False, False), _QKV, (8, 4096, 12, 64),
@@ -141,6 +157,18 @@ _CASES = [
                  id="paged-latent-joyai-4608x32"),
     pytest.param(_paged_latent, _LATENT, (16, 64, 3, 32, jnp.float32),
                  id="paged-latent-f32"),
+    # grouped-query decode attention: Nemotron-3-Nano's 32 query heads over
+    # 2 K/V heads of 128 at the benchmark's 64 slots x 2560 tokens, and
+    # float32 pools
+    pytest.param(_paged_gqa(32, 2), _GQA,
+                 (64, 160, 1, 32, 2, 128, jnp.bfloat16),
+                 id="paged-gqa-nemotron-2560x64"),
+    pytest.param(_paged_gqa(32, 2), _GQA,
+                 (16, 64, 2, 32, 2, 128, jnp.float32), id="paged-gqa-f32"),
+    # the decode step's state update where the rows lie: 64 slots of the
+    # 65-row pool of 4 Mamba-2 layers, 64 heads of [64, 128] float32
+    pytest.param(_state_update, _STATE, (64, 4, 64, 64, 128, 8),
+                 id="ssm-state-update-nemotron-64"),
     # the latent and the rotary key of a 4096-token prompt into their pools
     pytest.param(_block_write, _BLOCKS, (4096, 5, 512, jnp.bfloat16),
                  id="block-write-latent-4096"),
@@ -186,6 +214,19 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
                 sds((layers, nb, 16, 512), dt), sds((layers, nb, 16, 128), dt),
                 sds((), jnp.int32), sds((slots, blocks), jnp.int32),
                 sds((slots,), jnp.int32))
+    elif kind == _GQA:
+        slots, blocks, layers, heads, kv, d, dt = shape
+        pool = sds((layers, slots * blocks + 1, 16, kv * d), dt)
+        args = (sds((slots, heads * d), dt), pool, pool, sds((), jnp.int32),
+                sds((slots, blocks), jnp.int32), sds((slots,), jnp.int32))
+    elif kind == _STATE:
+        slots, layers, H, P, N, G = shape
+        f32 = jnp.float32
+        args = (sds((layers, slots + 1, H, P, N), f32), sds((), jnp.int32),
+                sds((slots,), jnp.int32), sds((slots, H), f32),
+                sds((slots, H, P), f32), sds((slots, G, N), f32),
+                sds((slots, G, N), f32))
+        dt = f32
     elif kind == _BLOCKS:
         bucket, layers, hd, dt = shape
         blocks = bucket // 16       # 16 slots of a 1024-token table or more
@@ -196,10 +237,11 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
         M, K, N = shape
         args = (sds((M, K)), sds((K,), jnp.float32),
                 sds((K,), jnp.float32), sds((K, N)))
-    compiled = jax.jit(fn, donate_argnums=(0,) if kind == _BLOCKS else ()
+    in_place = kind in (_BLOCKS, _STATE)
+    compiled = jax.jit(fn, donate_argnums=(0,) if in_place else ()
                        ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    if kind == _BLOCKS:     # the pool is written where it lies
+    if in_place:            # the pool is written where it lies
         ma = compiled.memory_analysis()
         assert ma.alias_size_in_bytes >= np.prod(args[0].shape) \
             * jnp.dtype(dt).itemsize and ma.temp_size_in_bytes < 1e6, ma
@@ -652,3 +694,124 @@ def test_joyai_serve_program_fits_and_reads_the_latent_cache_in_place(
         assert sum("kv_block_write" in k for k in kernels) == 4, kernels
         for pool in pools:
             assert not _pool_scatters(text, pool.shape)
+
+
+_NEMOTRON_SLOTS, _NEMOTRON_CONTEXT = 64, 2560
+
+
+@pytest.fixture(scope="module")
+def nemotron_9l(v5e):
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = nemotron_h.NemotronHConfig(pattern="MEMEM*EME",
+                                     max_len=_NEMOTRON_CONTEXT)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = jax.eval_shape(lambda k: nemotron_h.init(k, cfg)[0],
+                            jax.random.key(0))
+    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    sm = cfg.serve_model()
+    kv = KVCacheConfig(
+        layers=sm.kv_layers, widths=sm.stored, max_len=_NEMOTRON_CONTEXT,
+        block_size=_BLOCK,
+        num_blocks=_NEMOTRON_SLOTS * (_NEMOTRON_CONTEXT // _BLOCK) + 1)
+    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
+    state = tuple(sds(shape, dt) for shape, dt in
+                  sm.state_pools(_NEMOTRON_SLOTS + 1, jnp.bfloat16))
+    return cfg, params, pools, state, kv, sds
+
+
+@pytest.mark.parametrize("program", ["decode@64", "prefill@1024"])
+def test_nemotron_serve_program_fits_and_updates_the_state_in_place(
+        nemotron_9l, program, monkeypatch):
+    """One period of Nemotron-3-Nano (4 Mamba-2, 4 expert, 1 attention
+    block at the published widths, all 128 experts, the whole vocabulary)
+    as the cell serves it: 12.5 GB of weights as laid out, a K/V pool of
+    ONE layer and the state row pools, all three donated and written where
+    they lie. No op of the decode program holds the slots' states outside
+    the pool (the gathered form would: `f32[64,64,64,128]`, 134 MB a
+    layer, three times)."""
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.ops.pallas import ssm_update as SU
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, state, kv, sds = nemotron_9l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _NEMOTRON_CONTEXT // _BLOCK
+    for counts in (gm.GATE_COUNTS, PA.GATE_COUNTS, SU.GATE_COUNTS,
+                   kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32), state, sds((n,), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32), state, sds((), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4, 6)).lower(params,
+                                                       *args).compile()
+    assert kv.pool_shapes == ((1, 10241, 16, 256),) * 2
+    assert kv.bytes_per_token() == 1024
+    assert [s.shape for s in state] == [(4, 65, 144, 128),
+                                        (4, 65, 64, 64, 128)]
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    # weights 12.50 GB + K/V 0.17 GB + state 0.55 GB resident, the rest
+    # temporaries
+    assert 13.2e9 < planned < 13.6e9, ma
+    assert ma.temp_size_in_bytes < (0.05e9 if kind == "decode" else 0.25e9), ma
+    state_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in state)
+    assert ma.alias_size_in_bytes >= kv.pool_bytes() + state_bytes, ma
+    text = compiled.as_text()
+    for pool in pools:
+        assert not _pool_movers(text, pool.shape)
+    tails, ssm = state
+    # the SSM states, 0.55 GB: the decode program's kernel updates the rows
+    # where they lie and nothing else touches the pool; a prefill writes its
+    # one row a Mamba block into the donated pool
+    moved = collections.Counter(op for op, _ in _pool_movers(text, ssm.shape))
+    assert moved == ({} if kind == "decode"
+                     else {"dynamic-update-slice": 4}), moved
+    # the convolution's tails, 9.6 MB in all (a row's 3 x 6144 values as
+    # 144 whole lane tiles, one contiguous block): the prefill writes its
+    # row a block into the donated pool, the decode program scatters the
+    # slots' rows into it; the pool is small enough that XLA may prefetch
+    # it into VMEM (an asynchronous copy), but nothing relays it out and no
+    # program slices it by row
+    moved = collections.Counter(op for op, _ in
+                                _pool_movers(text, tails.shape))
+    if kind == "decode":
+        assert set(moved) <= {"copy-start", "copy-done"}, moved
+    else:
+        assert moved == {"dynamic-update-slice": 4}, moved
+    # no op makes a layer's slice of an expert stack
+    slices = re.findall(r"= \(?bf16\[128,(?:2688,1920|1920,2688)\]", text)
+    assert not slices, slices[:3]
+    # two grouped matmuls an expert block, the megablox kernel (2688 and
+    # 1920 are not multiples of the 1024 tile: the tile adapts)
+    assert gm.GATE_COUNTS == {"megablox": 8}, gm.GATE_COUNTS
+    kernels = _kernels(text)
+    assert sum("/mlp/experts/" in k for k in kernels) == 8, kernels
+    if kind == "decode":
+        assert PA.GATE_COUNTS == {"paged_gqa": 1}, PA.GATE_COUNTS
+        assert SU.GATE_COUNTS == {"kernel": 4}, SU.GATE_COUNTS
+        assert sum("/attention/" in k for k in kernels) == 1
+        assert sum("/ssm/scan/" in k for k in kernels) == 4, kernels
+        held = re.findall(r"= \(?f32\[64,64,64,128\]", text)
+        assert not held, held[:3]
+    else:
+        assert kvc.PREFILL_WRITE_UNITS == {"blocks": 2}
+        assert sum("kv_block_write" in k for k in kernels) == 2, kernels
