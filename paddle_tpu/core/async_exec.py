@@ -120,7 +120,7 @@ class FetchHandle:
     pad-slice postprocessing)."""
 
     __slots__ = ("_values", "_result", "_resolved", "_site", "_transform",
-                 "_dispatch_t", "_lock", "n_steps", "start_step")
+                 "_lock", "n_steps", "start_step")
 
     def __init__(self, values: Iterable[Any], site: str = "executor",
                  transform: Optional[Callable[[List[np.ndarray]], Any]]
@@ -130,7 +130,6 @@ class FetchHandle:
         self._resolved = False
         self._site = site
         self._transform = transform
-        self._dispatch_t = time.perf_counter()
         from ..analysis import lockcheck as _lockcheck  # deferred
 
         self._lock = _lockcheck.Lock("core.async_exec.FetchHandle._lock")
@@ -175,8 +174,6 @@ class FetchHandle:
                 pass  # non-jax values (numpy, scalars) need no wait
             out = [np.asarray(v) for v in values]
             now = time.perf_counter()
-            _telemetry.record_dispatch_ready(
-                "fetch:" + self._site, now - self._dispatch_t)
             if not was_ready:
                 _telemetry.record_host_blocked(
                     "fetch:" + self._site, now - t0, stall=stall)

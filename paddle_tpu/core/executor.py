@@ -160,6 +160,11 @@ class _JitDispatch:
         self._meta = meta
         self._aot = None
         self.aot_error: Optional[BaseException] = None  # last warm() failure
+        # how the executable in place got there: "compiled" (XLA),
+        # "jax_cache" (JAX's persistent cache returned it), "paddle_cache"
+        # (compile_cache.load), "warmstart" (adopt), "remembered" (a
+        # signature this wrapper had compiled before)
+        self.installed: Optional[str] = None
         self._tried = False
         self._tried_sig = None
         self._aot_by_sig: "OrderedDict[Tuple, Any]" = OrderedDict()
@@ -239,8 +244,9 @@ class _JitDispatch:
                 self._aot = remembered
                 self._cost_current = self._cost_by_sig.get(sig)
                 self._tried, self._tried_sig = True, sig
+                self.installed = "remembered"
                 return True
-            t0 = time.perf_counter()
+            t0 = _tracing.clock()
             aot = None
             try:
                 lowered = self._jit.lower(*args)
@@ -248,14 +254,22 @@ class _JitDispatch:
                        if compile_cache.enabled() else None)
                 if key:
                     aot = compile_cache.load(key, self._kind)
-                if aot is None:
+                if aot is not None:
+                    self.installed = "paddle_cache"
+                else:
                     aot = lowered.compile()
-                    seconds = time.perf_counter() - t0
+                    seconds = _tracing.clock() - t0
+                    # the request's own row (`compile.requests`): tracing,
+                    # lowering, JAX's cache's answer, the backend's time
+                    request = _tracing.last_compile_request(t0)
+                    self.installed = "jax_cache" if request is not None \
+                        and request["cache"] == "hit" else "compiled"
                     flops, out_bytes = _compile_cost(aot)
                     _telemetry.record_compile(self._kind, seconds,
                                               flops=flops,
                                               out_bytes=out_bytes,
-                                              meta=self._meta)
+                                              meta=self._meta,
+                                              request=request)
                     if key:
                         compile_cache.store(key, aot, self._kind)
             except Exception as e:
@@ -265,6 +279,7 @@ class _JitDispatch:
                 # raise it now from aot_error
                 aot = None
                 self.aot_error = e
+                self.installed = None
             self._aot = aot
             if aot is not None:
                 self._remember_locked(sig, aot)
@@ -301,6 +316,7 @@ class _JitDispatch:
         signature as covered."""
         with self._compile_lock:
             self._aot = executable
+            self.installed = "warmstart"
             self._tried = True
             self._tried_sig = self._aval_sig(args) if args else None
             if self._tried_sig is not None:
@@ -386,7 +402,7 @@ class _JitDispatch:
         # (the recompile-storm signal must not go dark). The high-water
         # mark makes concurrent dispatchers that blocked on the SAME
         # compile record it once, not once per waiting thread.
-        t0 = time.perf_counter()
+        t0 = _tracing.clock()
         out = self._jit(*args)
         after = self._jit._cache_size()
         if after > self._recorded_jit_compiles:
@@ -394,8 +410,9 @@ class _JitDispatch:
                 if after > self._recorded_jit_compiles:
                     self._recorded_jit_compiles = after
                     _telemetry.record_compile(
-                        self._kind, time.perf_counter() - t0,
-                        meta=dict(self._meta or {}, jit_fallback=True))
+                        self._kind, _tracing.clock() - t0,
+                        meta=dict(self._meta or {}, jit_fallback=True),
+                        request=_tracing.last_compile_request(t0))
         return out
 
 

@@ -87,6 +87,19 @@ def _trace_annotation():
 # a recorded span is a TraceAnnotation while it is open (injected the
 # same way: tools/obsdump.py loads tracing.py by path, without JAX)
 tracing.set_annotation_provider(_trace_annotation)
+
+
+def _listen_to_compiles():
+    import jax
+
+    # one row a compile request (`compile.requests`), joined from what JAX
+    # reports of it; the listeners run only when JAX compiles
+    jax.monitoring.register_event_listener(tracing.compile_event)
+    jax.monitoring.register_event_duration_secs_listener(
+        tracing.compile_duration)
+
+
+_listen_to_compiles()
 from .httpd import (  # noqa: F401
     maybe_start_http_server, start_http_server, stop_http_server,
 )

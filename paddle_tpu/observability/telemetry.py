@@ -32,7 +32,7 @@ __all__ = [
     "record_trainer_run", "record_spmd_step", "record_pipeline_trace",
     "record_compile", "record_compile_cache", "record_device_memory",
     "record_amp", "record_analysis",
-    "record_host_blocked", "record_dispatch_ready",
+    "record_host_blocked",
     "record_prefetch_depth", "record_prefetch_item",
     "record_async_inflight", "record_chained_eviction",
     "host_blocked_total",
@@ -99,18 +99,23 @@ PIPELINE_BUBBLE_FRACTION = _m.gauge(
 COMPILES = _m.counter(
     "paddle_tpu_compiles_total",
     "XLA compiles by program kind (step|chained|sharded|spmd); a rising "
-    "rate at steady state is a recompile storm", labelnames=("kind",))
+    "rate at steady state is a recompile storm. A program that JAX's "
+    "persistent cache returned is not one: it counts in "
+    "paddle_tpu_compile_cache_total{event=\"hit\"}", labelnames=("kind",))
 COMPILE_SECONDS = _m.histogram(
     "paddle_tpu_compile_seconds",
-    "Wall seconds per XLA trace+compile", labelnames=("kind",))
+    "Seconds XLA's backend spent per compile (the compile request's "
+    "backend_s; tracing and lowering are on the compile event)",
+    labelnames=("kind",))
 COMPILE_FLOPS = _m.gauge(
     "paddle_tpu_compile_flops",
     "cost_analysis() FLOPs estimate of the most recent compile",
     labelnames=("kind",))
 COMPILE_CACHE = _m.counter(
     "paddle_tpu_compile_cache_total",
-    "Persistent compile-cache (PADDLE_TPU_COMPILE_CACHE) outcomes by "
-    "program kind: hit (deserialized, compile skipped), miss, store, "
+    "Persistent compile-cache outcomes by program kind "
+    "(PADDLE_TPU_COMPILE_CACHE's, and the hits of JAX's own persistent "
+    "cache): hit (deserialized, compile skipped), miss, store, "
     "corrupt (bad/mismatched entry dropped), store_error, evict",
     labelnames=("kind", "event"))
 COMPILE_CACHE_BYTES = _m.counter(
@@ -149,20 +154,15 @@ DEVICE_LIVE_BUFFERS = _m.gauge(
     "Count of live device arrays")
 
 # -- host-overlap pipeline (core/async_exec.py) -----------------------------
-# The host-overlap story in three numbers: how long the host sat blocked
-# on the device (should be ~0 when the pipeline hides transfers), how
-# long a dispatched fetch took to become ready (device-side latency the
-# host never has to see), and how full the prefetch buffer ran (0 depth
-# at steady state = the consumer is input-bound).
+# The host-overlap story in two numbers: how long the host sat blocked
+# on the device (should be ~0 when the pipeline hides transfers) and how
+# full the prefetch buffer ran (0 depth at steady state = the consumer
+# is input-bound).
 HOST_BLOCKED_SECONDS = _m.counter(
     "paddle_tpu_host_blocked_seconds_total",
     "Wall seconds the host spent blocked waiting on device results or "
     "an empty prefetch queue, by site (executor_sync|fetch:*|"
     "prefetch:*)", labelnames=("site",))
-DISPATCH_READY_SECONDS = _m.histogram(
-    "paddle_tpu_dispatch_ready_seconds",
-    "Latency from dispatch to the fetched values being ready on host",
-    labelnames=("site",))
 PREFETCH_DEPTH = _m.gauge(
     "paddle_tpu_prefetch_queue_depth",
     "Items buffered in a prefetch stage right after the last put/get",
@@ -280,12 +280,36 @@ def record_spmd_step(axis: str, seconds: float,
 def record_compile(kind: str, seconds: float,
                    flops: Optional[float] = None,
                    out_bytes: Optional[int] = None,
-                   meta: Optional[Dict] = None):
-    """One XLA trace+compile: metrics + a `compile` event so a recompile
-    storm is visible both as a rate and as a timeline."""
-    COMPILES.inc(kind=kind)
-    COMPILE_SECONDS.observe(seconds, kind=kind)
+                   meta: Optional[Dict] = None,
+                   request: Optional[Dict] = None):
+    """One program made ready in `seconds` of wall time: metrics + a
+    `compile` event so a recompile storm is visible both as a rate and as
+    a timeline. `request` is the compile request's own row of
+    `compile.requests` (`tracing.last_compile_request`), and the split is
+    taken from it: what XLA compiled counts in `paddle_tpu_compiles_total`
+    and, with the backend's seconds, in `paddle_tpu_compile_seconds`; a
+    program JAX's persistent cache returned is a compile that did NOT
+    happen and goes to `record_compile_cache(kind, "hit")` with the
+    retrieval's seconds. Tracing and lowering, which no cache saves, are
+    on the event either way (`lower_s`, `backend_s`, `cache`, and `trace_s`
+    where the request's own trace was found).
+    Without a row (no listener saw the request) the whole of `seconds`
+    counts as a compile, as it always did."""
     fields: Dict = {"compile_kind": kind, "seconds": round(seconds, 6)}
+    hit = request is not None and request["cache"] == "hit"
+    if request is not None:
+        fields.update(lower_s=round(request["lower_s"], 6),
+                      backend_s=round(request["backend_s"], 6),
+                      cache=request["cache"])
+        if request["trace_s"] is not None:
+            fields["trace_s"] = round(request["trace_s"], 6)
+    if hit:
+        record_compile_cache(kind, "hit", seconds=request["retrieval_s"])
+    else:
+        COMPILES.inc(kind=kind)
+        COMPILE_SECONDS.observe(
+            seconds if request is None else request["backend_s"],
+            kind=kind)
     if flops is not None:
         COMPILE_FLOPS.set(flops, kind=kind)
         fields["flops"] = flops
@@ -394,10 +418,6 @@ def record_host_blocked(site: str, seconds: float, stall: bool = True):
         PIPELINE_STALLS.inc(site=site)
         _events.emit("pipeline_stall", site=site,
                      seconds=round(seconds, 6))
-
-
-def record_dispatch_ready(site: str, seconds: float):
-    DISPATCH_READY_SECONDS.observe(seconds, site=site)
 
 
 def record_prefetch_depth(stage: str, depth: int):

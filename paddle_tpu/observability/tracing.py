@@ -52,7 +52,10 @@ __all__ = ["Span", "span", "record_span", "get_spans", "clear_spans",
            "dropped_spans", "save_spans", "export_trace",
            "OpenSpan", "open_span", "record", "current_span",
            "start_recording", "stop_recording", "recorded",
-           "add_record", "get_records", "set_annotation_provider",
+           "add_record", "get_records", "kept_rows",
+           "set_annotation_provider",
+           "process_start", "boot_span", "compile_event",
+           "compile_duration", "last_compile_request", "boot_summary",
            "merge_chrome_traces",
            "TraceContext", "parse_traceparent", "sample_rate",
            "start_trace", "current_trace", "current_trace_id",
@@ -490,7 +493,12 @@ def step_span(name: str, cat: str = "step", **args):
 
 recording = False
 clock = time.monotonic
+_IMPORT_STAMP = clock()
 MAX_RECORDS = 200_000
+# record lists that are the PROCESS's, not a recording's: always on, short
+# (a boot makes tens of rows), and left alone by `clear_spans()`
+KEPT_RECORDS = {"compile.requests": 4096, "boot.spans": 256}
+_kept_rows = 0
 
 _annotation_provider = None     # () -> jax.profiler.TraceAnnotation
 _annotation = None              # resolved by start_recording()
@@ -543,7 +551,7 @@ class OpenSpan:
     __slots__ = ("name", "cat", "sid", "parent", "rid", "t0", "ctx",
                  "_token", "_ann", "_keep")
 
-    def close(self, **facts):
+    def close(self, **facts) -> float:
         t1 = clock()
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
@@ -563,6 +571,7 @@ class OpenSpan:
                             cat=self.cat, t0_perf=self.t0, **facts)
         elif self._keep:
             record_span(self.name, self.t0, t1 - self.t0, self.cat, facts)
+        return t1
 
 
 def _parent_sid(parent) -> Optional[int]:
@@ -628,11 +637,22 @@ def add_record(kind: str, row: Dict[str, Any]):
     """Append `row` to the record list `kind` (the decode engine's
     `decode.steps` and `decode.requests`), kept with the spans, bounded
     like them, read with `get_records` when a run ends."""
+    global _kept_rows
     with _lock:
         rows = _records.get(kind)
         if rows is None:
-            rows = _records[kind] = collections.deque(maxlen=MAX_RECORDS)
+            rows = _records[kind] = collections.deque(
+                maxlen=KEPT_RECORDS.get(kind, MAX_RECORDS))
         rows.append(row)
+        if kind in KEPT_RECORDS:
+            _kept_rows += 1
+
+
+def kept_rows() -> int:
+    """How many rows the kept lists have taken so far: a view computed
+    from them (a polled `status()["boot"]`) is made anew only when this
+    has risen."""
+    return _kept_rows
 
 
 def get_records(kind: str) -> List[Dict[str, Any]]:
@@ -657,8 +677,228 @@ def clear_spans():
     global _dropped
     with _lock:
         _spans.clear()
-        _records.clear()
+        for kind in [k for k in _records if k not in KEPT_RECORDS]:
+            del _records[kind]
         _dropped = 0
+
+
+# ---------------------------------------------------------------------------
+# A boot, from the inside: the process's start, one row a compile request
+# ---------------------------------------------------------------------------
+#
+# JAX reports every compile request through `jax.monitoring`, on the thread
+# that makes it: `jaxpr_trace_duration` once for every nested jitted
+# function and last for the outermost, `jaxpr_to_mlir_module_duration`
+# (inside which inner functions are traced and reported again), then the
+# persistent cache's `compile_requests_use_cache`, `cache_hits` (+
+# `compile_time_saved_sec`, `cache_retrieval_time_sec`) or `cache_misses`,
+# and `backend_compile_duration`, which closes the request, on a hit too.
+# `observability/__init__` registers `compile_event` / `compile_duration`
+# as the listeners (this file imports no JAX); they join the events of one
+# thread into one row of `compile.requests` when the last one arrives. The
+# rows are always on: a process makes tens of them, and one inside a served
+# window is a fault that wants a name.
+
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_BACKEND = "/jax/core/compile/backend_compile_duration"
+_JAX_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+# `cache_misses` comes with the entry a miss writes, so that the next
+# process hits; a request that the cache was not asked about, or asked
+# with no directory to look in, or whose program it does not keep (under
+# JAX's thresholds of size and compile time) is "off": compiled every boot
+_JAX_CACHE = {"/jax/compilation_cache/cache_misses": "miss",
+              "/jax/compilation_cache/cache_hits": "hit"}
+
+# the most a request's own trace may end before its lowering begins: what
+# JAX does between the two grows with the program (the largest read on the
+# chip: 0.05 s, a training step traced for 1.7 s; PERF.md section 6, PR 37)
+TRACE_JOIN_GAP_S = 1.0
+
+_process_start: Optional[float] = None
+
+
+def process_start() -> float:
+    """This process's start on `clock`: the anchor a boot's parts are
+    measured from. The kernel's own stamp (`/proc/self/stat` field 22, on
+    CLOCK_BOOTTIME, brought over to CLOCK_MONOTONIC by the two clocks'
+    difference now), else this module's import stamp."""
+    global _process_start
+    if _process_start is None:
+        start = _IMPORT_STAMP
+        try:
+            with open("/proc/self/stat", "rb") as f:
+                stat = f.read()
+            # field 2, the command, may hold spaces: count from its end
+            ticks = int(stat[stat.rindex(b")") + 2:].split()[19])
+            kernel = ticks / os.sysconf("SC_CLK_TCK") - (
+                time.clock_gettime(time.CLOCK_BOOTTIME) - clock())
+            if kernel <= _IMPORT_STAMP:
+                start = kernel
+        except (OSError, ValueError, IndexError, AttributeError):
+            pass
+        _process_start = start
+    return _process_start
+
+
+def _compile_state() -> Dict[str, Any]:
+    state = getattr(_stack, "compile", None)
+    if state is None:
+        state = _stack.compile = {}
+    return state
+
+
+@contextlib.contextmanager
+def boot_span(name: str):
+    """`with tracing.boot_span("boot.engine_build") as facts:` is one row
+    of the kept list `boot.spans` (`name`, `t0`, `t1` and whatever the body
+    puts into `facts`), whether or not a recording is on: a boot outlives
+    any recording, and `boot_summary()` and the benchmark's `setup_*`
+    metrics read it long after. While it is open it is the thread's
+    innermost span, so a compile request inside it says so (`span`), and in
+    a recording it is a span of the ring like any other."""
+    facts: Dict[str, Any] = {}
+    sp = open_span(name, "boot")
+    try:
+        yield facts
+    finally:
+        t1 = sp.close(**facts)
+        add_record("boot.spans", dict(facts, name=name, t0=sp.t0, t1=t1))
+
+
+def compile_event(event: str, **_kw):
+    """`jax.monitoring` event listener: the persistent cache's answer to
+    the request this thread is making."""
+    answer = _JAX_CACHE.get(event)
+    if answer is not None:
+        _compile_state()["cache"] = answer
+
+
+def compile_duration(event: str, secs: float, fun_name: str = "", **_kw):
+    """`jax.monitoring` duration listener. The clock is stamped when an
+    event arrives and its duration subtracted, so a row's `t0` / `t1` lie on
+    `clock` beside the spans."""
+    if event == _JAX_TRACE:
+        # by name: the inner functions' traces lie inside the outermost
+        # one's, and a lowering traces inner functions again before it
+        # reports, so "the last trace before the lowering" is not it
+        traced = getattr(_stack, "traced", None)
+        if traced is None or len(traced) >= 256:
+            traced = _stack.traced = {}
+        traced[fun_name] = (secs, clock())
+    elif event == _JAX_LOWER:
+        state = _compile_state()
+        state.clear()               # a request begins with its lowering
+        now = clock()
+        state["lower"] = (fun_name, secs, now)
+        # the request's own trace: `f` of `jit(f)`, ended before the
+        # lowering began and not long before (`TRACE_JOIN_GAP_S`). A jaxpr
+        # traced earlier (the engine's `eval_shape` at boot validation) is
+        # found again and reported as a trace of no length; where no trace
+        # of the name is that near, the row's `trace_s` is None
+        trace = getattr(_stack, "traced", {}).pop(fun_name[4:-1], None) \
+            if fun_name.startswith("jit(") else None
+        if trace is not None \
+                and -1e-3 <= now - secs - trace[1] <= TRACE_JOIN_GAP_S:
+            state["trace"] = trace
+    elif event == _JAX_RETRIEVAL:
+        _compile_state()["retrieval_s"] = secs
+    elif event == _JAX_SAVED:
+        _compile_state()["saved_s"] = secs
+    elif event == _JAX_BACKEND:
+        _close_compile_request(fun_name, secs)
+
+
+def _close_compile_request(fun_name: str, backend_s: float):
+    t1 = clock()
+    state = _compile_state()
+    t0 = t1 - backend_s
+    # None: no trace of this name ended just before the lowering (the
+    # join missed it): unknown, not 0
+    trace_s, lower_s = None, 0.0
+    lower = state.get("lower")
+    if lower is not None and lower[0] == fun_name:
+        lower_s = lower[1]
+        t0 = min(t0, lower[2] - lower_s)
+        trace = state.get("trace")
+        if trace is not None:
+            trace_s = trace[0]
+            t0 = min(t0, trace[1] - trace_s)
+    top = current_span()
+    row = {"t0": t0, "t1": t1, "fun_name": fun_name, "trace_s": trace_s,
+           "lower_s": lower_s, "backend_s": backend_s,
+           "cache": state.get("cache", "off"),
+           "retrieval_s": state.get("retrieval_s"),
+           "saved_s": state.get("saved_s"),
+           "tid": threading.get_ident(),
+           "span": top.name if top is not None else None}
+    state.clear()
+    _stack.last_compile = row
+    add_record("compile.requests", row)
+    if recording:
+        # beside the `decode.*` span it interrupted, in the same ring, and
+        # caused by it as any span it had opened would be
+        facts = {k: v for k, v in row.items()
+                 if k not in ("t0", "t1", "tid") and v is not None}
+        facts["sid"] = next(_next_sid)
+        if top is not None:
+            facts["parent"] = top.sid
+        record_span("xla.compile", t0, t1 - t0, "compile", facts)
+
+
+def last_compile_request(since: float = 0.0) -> Optional[Dict[str, Any]]:
+    """The row of the compile request this thread closed last, if it
+    closed at or after `since` (a caller that timed a `compile()` asks
+    for that compile's own row)."""
+    row = getattr(_stack, "last_compile", None)
+    return row if row is not None and row["t1"] >= since else None
+
+
+def boot_summary() -> Dict[str, Any]:
+    """What this process spent before it served, computed when asked from
+    the kept rows (`DecodeEngine.status()["boot"]`): seconds from the
+    process's start to this module's import and to the first compile
+    request, seconds by boot span name, and of the compile requests their
+    count, the cache's answers, the sums of tracing, lowering and the
+    backend's time, how many have no trace of their own (`untraced`: the
+    join missed it, so `trace_s` is a lower bound by that many programs),
+    the seconds of the clock they cover
+    together, and the five slowest."""
+    start = process_start()
+    rows = get_records("compile.requests")
+    by_span: Dict[str, float] = {}
+    for sp in get_records("boot.spans"):
+        by_span[sp["name"]] = by_span.get(sp["name"], 0.0) \
+            + sp["t1"] - sp["t0"]
+    covered, end = 0.0, float("-inf")
+    for r in sorted(rows, key=lambda r: r["t0"]):
+        covered += max(0.0, r["t1"] - max(r["t0"], end))
+        end = max(end, r["t1"])
+    slowest = sorted(rows, key=lambda r: r["t0"] - r["t1"])[:5]
+    return {
+        "process_start": start,
+        # the interpreter and the imports up to this module (JAX's among
+        # them); what `first_program_s` holds beyond it is the backend's
+        # start-up and the caller's own work before its first program
+        "imported_s": _IMPORT_STAMP - start,
+        "first_program_s": min(r["t0"] for r in rows) - start
+        if rows else None,
+        "spans_s": by_span,
+        "compile": {
+            "requests": len(rows),
+            "hits": sum(r["cache"] == "hit" for r in rows),
+            "misses": sum(r["cache"] == "miss" for r in rows),
+            "uncached": sum(r["cache"] == "off" for r in rows),
+            "untraced": sum(r["trace_s"] is None for r in rows),
+            "trace_s": sum(r["trace_s"] or 0.0 for r in rows),
+            "lower_s": sum(r["lower_s"] for r in rows),
+            "backend_s": sum(r["backend_s"] for r in rows),
+            "covered_s": covered,
+            "slowest": [{"fun_name": r["fun_name"],
+                         "seconds": r["t1"] - r["t0"],
+                         "cache": r["cache"], "span": r["span"]}
+                        for r in slowest]}}
 
 
 # ---------------------------------------------------------------------------

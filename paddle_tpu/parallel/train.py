@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core import precision as _precision
 from ..models.common import Params, ParamAxes, is_trainable
 from ..observability import memwatch as _memwatch
+from ..observability import tracing as _tracing
 from .sharding import LogicalRules, current_rules, named_sharding_tree
 
 
@@ -215,6 +216,14 @@ def make_train_step(
         """Takes ownership of `params`: buffers may be aliased into the
         donated TrainState (the reference's overwrite-in-scope semantics,
         scope.h). Re-init or copy if the caller needs them afterwards."""
+        # a boot span, kept whether or not a recording is on (the
+        # benchmark's `setup_train_build_s` reads it); the step itself
+        # gets no site: its first call's compile is a row of
+        # `compile.requests` under its own name
+        with _tracing.boot_span("boot.train_build"):
+            return _init_state(params)
+
+    def _init_state(params: Params) -> TrainState:
         if policy.cast_state:
             # pure low-precision: master weights themselves live at the
             # compute width (mixed policies keep f32 masters instead)

@@ -344,6 +344,44 @@ def _assemble_ids(prev, first, idx):
     return jnp.take(jnp.concatenate([prev, first]), idx, mode="clip")
 
 
+def _nbytes(arrays) -> int:
+    return sum(int(a.nbytes) for a in arrays)
+
+
+def _phase_name(key) -> str:
+    """`("decode", 16)` -> `"decode@16"`, `("assemble", (16, 32))` ->
+    `"assemble@16x32"`: a phase key as boot spans and messages name it."""
+    kind, n = key
+    return f"{kind}@" + ("x".join(map(str, n)) if isinstance(n, tuple)
+                         else str(n))
+
+
+def _boot_status() -> Dict:
+    """`status()["boot"]`: the tracing's summary of the process's boot,
+    and of the boot spans this file owns `ready_s` (the process's start to
+    the end of the last warm-up: what a replica's autoscaler waits for)
+    and `phases` (every warm-up phase, oldest first: its seconds and how
+    its executable got there). A `status()` may be polled: the view is
+    made anew only when a kept row has been added."""
+    global _boot_view
+    version = _tracing.kept_rows()
+    if _boot_view is None or _boot_view[0] != version:
+        boot = _tracing.boot_summary()
+        spans = _tracing.get_records("boot.spans")
+        warm = [s["t1"] for s in spans if s["name"] == "boot.engine_warmup"]
+        boot["ready_s"] = max(warm) - boot["process_start"] if warm \
+            else None
+        boot["phases"] = [
+            {"phase": s.get("phase"), "installed": s.get("installed"),
+             "seconds": s["t1"] - s["t0"]}
+            for s in spans if s["name"] == "boot.warm_phase"]
+        _boot_view = (version, boot)
+    return dict(_boot_view[1])
+
+
+_boot_view: Optional[Tuple[int, Dict]] = None
+
+
 class DecodeEngine:
     """Continuous-batching token generation over a paged KV cache.
 
@@ -356,6 +394,15 @@ class DecodeEngine:
 
     def __init__(self, params, model_cfg, config: Optional[DecodeConfig]
                  = None, draft=None):
+        # kept whether or not a recording is on: `status()["boot"]` and a
+        # cold start's account read it long after
+        with _tracing.boot_span("boot.engine_build") as facts:
+            self._build(params, model_cfg, config, draft)
+            facts.update(weight_bytes=_nbytes(self.params.values()),
+                         pool_bytes=_nbytes(self._pools),
+                         state_bytes=_nbytes(self._state))
+
+    def _build(self, params, model_cfg, config, draft):
         from ..models import decoder as _decoder
 
         self.config = config or DecodeConfig()
@@ -887,12 +934,22 @@ class DecodeEngine:
         raises here, at boot, with the compiler's error, instead of
         failing its first request. Idempotent."""
         keys = self._phase_keys()
-        for key in keys:
-            disp = self._phase_dispatch(key)
-            if not disp.warm(*self._phase_avals(key)):
-                raise RuntimeError(
-                    f"decode phase {key[0]}@{key[1]} failed to "
-                    f"compile") from disp.aot_error
+        with _tracing.boot_span("boot.engine_warmup") as boot:
+            boot["phases"] = len(keys)
+            for key in keys:
+                disp = self._phase_dispatch(key)
+                with _tracing.boot_span("boot.warm_phase") as phase:
+                    phase["phase"] = _phase_name(key)
+                    ok = disp.warm(*self._phase_avals(key))
+                    # how the executable got there ("compiled",
+                    # "jax_cache", "paddle_cache", "warmstart",
+                    # "remembered"), None where none did: read by
+                    # `status()["boot"]["phases"]`
+                    phase["installed"] = disp.installed
+                if not ok:
+                    raise RuntimeError(
+                        f"decode phase {key[0]}@{key[1]} failed to "
+                        f"compile") from disp.aot_error
         self.warmed = True
         return len(keys)
 
@@ -1249,6 +1306,13 @@ class DecodeEngine:
             # bucket of whole blocks goes in a block at a time; "rows": a
             # bucket that is not, a token at a time)
             "prefill_write": dict(PREFILL_WRITE_UNITS),
+            # what this PROCESS spent before it served, computed now from
+            # the kept boot spans and compile-request rows: seconds from
+            # the process's start to the last warm-up's end, by boot span
+            # and by warm-up phase (with where its executable came from),
+            # and the compile requests' count, cache answers, tracing /
+            # lowering / backend sums and slowest programs
+            "boot": _boot_status(),
         }
         if self._state_alloc is not None:
             # the state row pools beside the K/V pools: rows a sequence can
@@ -1810,7 +1874,6 @@ class DecodeEngine:
             self._note_step_stats(*pending.stats)
         wall = now - pending.t_dispatch
         if first:
-            _telemetry.record_dispatch_ready("decode:prefill", wall)
             # live-MFU sample: the bucket executable's retained
             # cost_analysis FLOPs over the dispatch→resolve window (one
             # token emitted — the TTFT token)

@@ -463,66 +463,70 @@ class Server:
         with self._lock:
             if self._started_t is not None:
                 return self._http.port()
-            self._draining = False
-            # thread-spawn ordering is the leak discipline: everything
-            # that can FAIL (warmups, the bind) happens before anything
-            # that starts a thread, except the batcher — whose
-            # constructor spawns — which is therefore created last
-            # before the bind and stopped if the bind raises. The
-            # decode scheduler starts only after the bind succeeds, so
-            # a failed start never leaves it running (and never kills
-            # the caller's engine, whose stop() is terminal).
-            if self.config.warmup:
-                for dec in self._decodes.values():
-                    if not dec.warmed:
-                        dec.warmup()
-            batcher = None
-            if self._engine is not None:
-                if self.config.warmup:
-                    self._engine.warmup()
-                batcher = self._make_batcher(self._engine, self.config)
-            extra_batchers = []
-            try:
-                for mid, slot in self._extra.items():
-                    if slot["config"].warmup:
-                        slot["engine"].warmup()
-                    extra_batchers.append(
-                        (mid, self._make_batcher(slot["engine"],
-                                                 slot["config"])))
-                bound = self._http.start(
-                    self.config.port if port is None else port,
-                    host=self.config.host)
-            except BaseException:
-                if batcher is not None:
-                    batcher.stop()  # failed bind must not leak the thread
-                for _, b in extra_batchers:
-                    b.stop()
-                raise
-            for dec in self._decodes.values():
-                dec.start()
-            self._batcher = batcher
-            for mid, b in extra_batchers:
-                self._extra[mid]["batcher"] = b
-            self._started_t = time.monotonic()
-            import atexit
+            with _tracing.boot_span("boot.server_start"):
+                return self._start_locked(port)
 
-            atexit.register(self.stop)
-            # telemetry pipeline: the env-gated TS recorder plus the
-            # SLO evaluator when the config declares objectives (both
-            # no-ops without PADDLE_TPU_TS_DIR)
-            _timeseries.maybe_start_recorder()
-            _slo.maybe_start_evaluator(
-                spec_path=getattr(self.config, "slo_spec", None))
-            _events.emit("serve_start", port=bound,
-                         buckets=list(self._engine.policy.buckets)
-                         if self._engine is not None else [],
-                         decode=bool(self._decodes),
-                         models=self._model_ids(),
-                         qos=self._qos is not None,
-                         max_queue=self.config.max_queue,
-                         max_wait_ms=self.config.max_wait_ms)
-            self._maybe_start_watcher()
-            return bound
+    def _start_locked(self, port: Optional[int]) -> int:
+        self._draining = False
+        # thread-spawn ordering is the leak discipline: everything
+        # that can FAIL (warmups, the bind) happens before anything
+        # that starts a thread, except the batcher — whose
+        # constructor spawns — which is therefore created last
+        # before the bind and stopped if the bind raises. The
+        # decode scheduler starts only after the bind succeeds, so
+        # a failed start never leaves it running (and never kills
+        # the caller's engine, whose stop() is terminal).
+        if self.config.warmup:
+            for dec in self._decodes.values():
+                if not dec.warmed:
+                    dec.warmup()
+        batcher = None
+        if self._engine is not None:
+            if self.config.warmup:
+                self._engine.warmup()
+            batcher = self._make_batcher(self._engine, self.config)
+        extra_batchers = []
+        try:
+            for mid, slot in self._extra.items():
+                if slot["config"].warmup:
+                    slot["engine"].warmup()
+                extra_batchers.append(
+                    (mid, self._make_batcher(slot["engine"],
+                                             slot["config"])))
+            bound = self._http.start(
+                self.config.port if port is None else port,
+                host=self.config.host)
+        except BaseException:
+            if batcher is not None:
+                batcher.stop()  # failed bind must not leak the thread
+            for _, b in extra_batchers:
+                b.stop()
+            raise
+        for dec in self._decodes.values():
+            dec.start()
+        self._batcher = batcher
+        for mid, b in extra_batchers:
+            self._extra[mid]["batcher"] = b
+        self._started_t = time.monotonic()
+        import atexit
+
+        atexit.register(self.stop)
+        # telemetry pipeline: the env-gated TS recorder plus the
+        # SLO evaluator when the config declares objectives (both
+        # no-ops without PADDLE_TPU_TS_DIR)
+        _timeseries.maybe_start_recorder()
+        _slo.maybe_start_evaluator(
+            spec_path=getattr(self.config, "slo_spec", None))
+        _events.emit("serve_start", port=bound,
+                     buckets=list(self._engine.policy.buckets)
+                     if self._engine is not None else [],
+                     decode=bool(self._decodes),
+                     models=self._model_ids(),
+                     qos=self._qos is not None,
+                     max_queue=self.config.max_queue,
+                     max_wait_ms=self.config.max_wait_ms)
+        self._maybe_start_watcher()
+        return bound
 
     def _make_batcher(self, engine: Engine, cfg: ServingConfig) -> Batcher:
         return Batcher(

@@ -111,17 +111,41 @@ def test_recording_off_keeps_nothing_and_calls_nothing(gpt_model,
                                                        monkeypatch):
     calls = []
     for name in ("open_span", "record", "add_record", "record_span"):
-        monkeypatch.setattr(
-            t, name, lambda *a, _n=name, **k: calls.append(_n))
+        def site(*a, _n=name, _real=getattr(t, name), **k):
+            calls.append((_n,) + tuple(x for x in a[:2]
+                                       if isinstance(x, str)))
+            return _real(*a, **k)
+        monkeypatch.setattr(t, name, site)
+
+    def boot_only():
+        # a boot is not the hot path: its spans and the compile requests'
+        # rows are kept with recording off (tests/test_boot_records.py)
+        rest = [c for c in calls
+                if not (c[0] == "open_span" and c[2] == "boot")
+                and c[:2] not in (("add_record", "boot.spans"),
+                                  ("add_record", "compile.requests"))]
+        seen, calls[:] = list(calls), []
+        assert rest == [], rest
+        return seen
+
     eng = _engine(gpt_model, "lazy")
     try:
+        assert ("open_span", "boot.engine_build", "boot") in boot_only()
+        # the first request, unwarmed: its programs' compile requests are
+        # rows, and no site of the loop calls into the store
         toks = eng.submit([1, 2, 3], max_new_tokens=NEW).result(
+            timeout_s=120)
+        assert ("add_record", "compile.requests") in boot_only()
+        eng.warmup()
+        assert ("open_span", "boot.warm_phase", "boot") in boot_only()
+        # served warm: a span site with recording off is
+        # `if tracing.recording`: no call into the store, so no clock
+        # read, no allocation, no lock
+        toks2 = eng.submit([4, 5, 6, 7], max_new_tokens=NEW).result(
             timeout_s=120)
     finally:
         eng.stop()
-    assert len(toks) == NEW
-    # a span site with recording off is `if tracing.recording`: no call
-    # into the store, so no clock read, no allocation, no lock
+    assert len(toks) == NEW and len(toks2) == NEW
     assert calls == []
     assert t.get_spans() == [] and t.get_records("decode.steps") == [] \
         and t.get_records("decode.requests") == []
