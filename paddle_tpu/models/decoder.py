@@ -481,6 +481,9 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     # the table; elsewhere the gathered form below
     route = model.paged_route(x, k_pool, v_pool)
     pa.GATE_COUNTS[route or "gather"] += 1
+    # what the kernel's walk may fetch in one copy, counted once a step
+    # and not once a layer
+    tables = pa.with_runs(block_tables, k_pool, v_pool) if route else None
 
     def attend(l, lp, q, k, v, kp, vp):
         kp = kvc.write_token_kv(kp, l, k.reshape(S, *kp.shape[3:]),
@@ -489,7 +492,7 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
                                 block_tables, positions, block_size)
         if route:
             with jax.named_scope("attention"):
-                ctx = model.attend_paged(lp, q, kp, vp, l, block_tables,
+                ctx = model.attend_paged(lp, q, kp, vp, l, tables,
                                          positions)
         else:
             ctx = cached_attention(

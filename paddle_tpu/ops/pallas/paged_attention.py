@@ -36,16 +36,39 @@ K/V head's D lanes of `acc`. A token is read once for all of its group.
 How it reads only what is live. The grid walks the slots in order (one
 TensorCore; the steps depend on each other through the buffers). A slot
 with position p owns `p // BS + 1` blocks; a slot whose table starts with
-the null block is inactive and reads nothing. Blocks are fetched `_CHUNK //
-BS` at a time into one of two VMEM buffers, one DMA a block for K and one
-for V; while a chunk is consumed the next is in flight, and the last chunk
-of a slot overlaps the first of the next slot. How a slot's context is
-chunked depends on its own length alone, so a row's result does not depend
-on what shares the batch (`ServeModel`'s contract). Rows of a buffer past
-the live blocks hold what an earlier chunk left there: their scores are
-masked, and their weights are exactly zero against V rows that are finite
-(the buffers start zeroed; the pool's garbage is finite by the same
-contract the gather path relies on).
+the null block is inactive and reads nothing. Blocks are fetched a chunk
+(`chunk_tokens // BS`) at a time into one of two VMEM buffers; while a
+chunk is consumed the next is in flight, and the last chunk of a slot
+overlaps the first of the next slot. How a slot's context is chunked
+depends on its own length alone, so a row's result does not depend on what
+shares the batch (`ServeModel`'s contract). Rows of a buffer past the live
+blocks hold what an earlier chunk left there: their scores are masked, and
+their weights are exactly zero against V rows that are finite (the buffers
+start zeroed; the pool's garbage is finite by the same contract the gather
+path relies on).
+
+How many copies that takes. Where a token stores much (multi-head K and V
+of 1280 or 2048 lanes: 40 and 64 KB a block), one DMA a block and pool, as
+ever: such a walk is at its bytes. A NARROW cache's block is small (16 KB
+and 4 KB for a latent cache, `narrow`): block by block its walk is bound by
+the count of copies, not by bytes, and it takes a table's RUNS whole.
+Consecutive block ids are one contiguous span of a layer of the pool (the
+kernels take it as its rows, `[L, NB*BS, width]`: the same bytes), so what
+a table names IN A ROW goes in one copy a pool. Of each chunk of the table
+the wrapper counts, beside the kernel, how many entries from its first on
+are consecutive ids (`Tables.runs`, a fourth prefetched scalar array: no
+search in the kernel's scalar loop, whose iterations cost as much as the
+copies they would save); the walk fetches that many of the chunk's live
+blocks in the binary pieces of their count, the largest first (DMA shapes
+are static), and the rest a block at a time. A full chunk of one run is ONE
+copy a pool where it was 16; a sequence's last, shorter chunk at most five;
+a chunk whose ids do not follow each other a block at a time, as every
+chunk was before the allocator handed out runs (serving/kv_cache.py
+`BlockAllocator`; `run_chunks` is the host's count of the chunks the walk
+takes whole). Only blocks the slot owns are ever fetched, the pieces put
+the same bytes in the same buffer rows as the single blocks would, and
+start and wait of a chunk read the same numbers: the result does not depend
+on where a sequence's blocks lie.
 """
 
 from __future__ import annotations
@@ -53,6 +76,7 @@ from __future__ import annotations
 import collections
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,13 +94,58 @@ GATE_COUNTS: collections.Counter = collections.Counter()
 # tokens a compute step: a multiple of 128, so scores [M, _CHUNK] fill
 # their lanes; K and V double-buffered are 4 * _CHUNK * H*D elements of VMEM
 _CHUNK = 256
+# a NARROW cache: `_CHUNK` tokens of both pools are under this many bytes
+_NARROW_CHUNK_BYTES = 512 * 1024
 
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def narrow(token_bytes: int) -> bool:
+    """Whether a cache whose token stores `token_bytes` in a layer, in BOTH
+    pools, is one whose walk is bound by what a chunk costs beside its
+    bytes, and not by the bytes: the latent cache (512 + 128 lanes, a 16 KB
+    and a 4 KB block) and the grouped-query one (2 x 256 lanes). What this
+    decides, from shapes alone (measured on a v5e, PERF.md section 6,
+    PR 40):
+
+    - such a walk takes a table's RUNS of consecutive blocks in one copy a
+      pool (`_walk`): block by block it reached 27% and 25% of the HBM
+      peak, over runs at 256 tokens a chunk 1.86 and 1.92 times that.
+      Multi-head K and V of 1280 or 2048 lanes (40 and 64 KB a block) are
+      at their bytes block by block, 0.79 and 0.82 of the peak, and gain
+      nothing from runs; the run branch's scalar work cost GPT-2's kernel
+      2 us of 92 over scattered tables and, over the mostly idle slots of
+      an open loop, 0.6 us of 12.9 a call, 36 calls a step: its call is
+      the one it always was (`_call_form`), and `chat_open`'s decode
+      program takes the device time it took, to four digits;
+    - and it takes 512 tokens a compute step for `_CHUNK`'s 256
+      (`chunk_tokens`): a chunk's fixed cost, some 0.5 us of the scalar
+      core's turn and the pipeline's fill, is as long as 256 such tokens
+      take to arrive (320 KB, 256 KB); at 512 the two walks over runs were
+      another 1.33 and 1.36 times faster, 2.5 and 2.6 times the
+      block-by-block walk (67% and 64% of the peak)."""
+    return _CHUNK * token_bytes < _NARROW_CHUNK_BYTES
+
+
+def chunk_tokens(token_bytes: int) -> int:
+    """Tokens a compute step: `_CHUNK`, twice that for a `narrow` cache."""
+    return 2 * _CHUNK if narrow(token_bytes) else _CHUNK
+
+
+def blocks_per_chunk(block_size: int, token_bytes: int) -> int:
+    """Blocks of `block_size` tokens the walk fetches a chunk: what one
+    copy of a narrow cache's walk can take at most
+    (`serving/kv_cache.run_chunks` counts by it)."""
+    return max(1, chunk_tokens(token_bytes) // block_size)
+
+
+def _token_bytes(*pools) -> int:
+    return sum(p.shape[3] * p.dtype.itemsize for p in pools)
+
+
 def _tiles(pool: jax.Array) -> bool:
     """Whether a pool `[L, NB, BS, width]` is one the kernels can address:
-    a block is whole tiles, fetched `_CHUNK // BS` to a chunk."""
+    a block is whole tiles, fetched `chunk_tokens // BS` to a chunk."""
     if pool.ndim != 4 or pool.dtype.itemsize not in (2, 4):
         return False
     bs, width = pool.shape[2:]
@@ -127,12 +196,14 @@ def use_paged_latent(q: jax.Array, c_pool: jax.Array, r_pool: jax.Array,
             and _on_one_tpu(q))
 
 
-def _walk(layer_ref, tables_ref, pos_ref, pools, bufs, sems, done_ref,
-          zeroed, setup, step):
+def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
+          done_ref, zeroed, setup, step, bs):
     """One grid step's walk over slot `program_id(0)`'s live blocks of
-    layer `layer_ref[0]`: chunk by chunk through the double buffers `bufs`
-    (one `[2, _CHUNK, width]` a pool, `sems` `[2, len(pools)]`), one DMA a
-    block and pool, the next chunk (or the next slot's first) in flight
+    layer `layer_ref[0]` of `pools` (`_call_form`; `bs` tokens a block):
+    chunk by chunk through the double buffers `bufs` (one `[2, chunk,
+    width]` a pool, `sems` `[2, len(pools)]`), one DMA a pool for every
+    piece of consecutive blocks (`each_copy`; `lead_ref` `[S, chunks]`:
+    `Tables.runs`), the next chunk (or the next slot's first) in flight
     while `step(query, c, buf, carry) -> carry` consumes chunk `c` from
     `bufs[i][buf]`. `setup() -> (query, first carry)` builds the slot's
     query operands; it runs AFTER the call's first copies are started, so
@@ -142,31 +213,68 @@ def _walk(layer_ref, tables_ref, pos_ref, pools, bufs, sems, done_ref,
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
     chunk = bufs[0].shape[1]
-    bs = pools[0].shape[2]
     per_chunk = chunk // bs
     max_blocks = tables_ref.shape[1]
     layer = layer_ref[0]
+    sizes = [1 << i for i in reversed(range(per_chunk.bit_length()))]
+    take_runs = lead_ref is not None    # a narrow cache's: `_call_form`
 
     def live_blocks(slot):
         live = jnp.minimum(pos_ref[slot] // bs + 1, max_blocks)
         return jnp.where(tables_ref[slot, 0] == 0, 0, live)
 
     def each_copy(slot, c, buf, act):
-        """`act` ("start" | "wait") on the copy of every live block of
-        chunk `c` of `slot` into buffer `buf`."""
+        """`act` ("start" | "wait") on the copies of the live blocks of
+        chunk `c` of `slot` into buffer `buf`: the blocks the table names
+        in a row from the chunk's first (`lead_ref`: `Tables.runs`) in
+        the binary pieces of their count, the largest first (a full chunk
+        of one run: ONE copy a pool), the rest a block at a time. Start
+        and wait read the same numbers, so they make the same copies."""
         first = c * per_chunk
         n = jnp.clip(live_blocks(slot) - first, 0, per_chunk)
 
-        def one(j, carry):
+        def copy(j, k):
             blk = tables_ref[slot, first + j]
-            rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            # a narrow cache's pools come as rows (`_call_form`): k blocks are
+            # one span; a wide cache's as they lie, a block a copy
+            src = pl.ds(pl.multiple_of(blk * bs, bs), k * bs) \
+                if take_runs else blk
+            rows = pl.ds(pl.multiple_of(j * bs, bs), k * bs)
             for which, (hbm, dst) in enumerate(zip(pools, bufs)):
                 getattr(pltpu.make_async_copy(
-                    hbm.at[layer, blk], dst.at[buf, rows],
+                    hbm.at[layer, src], dst.at[buf, rows],
                     sems.at[buf, which]), act)()
+
+        def one(j, carry):
+            copy(j, 1)
             return carry
 
-        lax.fori_loop(0, n, one, 0)
+        if not take_runs:       # a wide cache: a block is a large copy
+            lax.fori_loop(0, n, one, 0)
+            return
+
+        # a scalar branch costs some 25 cycles and a narrow chunk's bytes
+        # 400: the two common cases, a full chunk of one run and a chunk of
+        # scattered blocks, take few
+        run = jnp.minimum(lead_ref[slot, c], n)
+        whole = run == per_chunk
+
+        @pl.when(whole)
+        def _():
+            copy(0, per_chunk)
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            pieces = run > 1        # a lone block goes with the rest
+
+            @pl.when(pieces)
+            def _():
+                for k in sizes[1:]:
+                    @pl.when(run & k != 0)
+                    def _(k=k):
+                        copy(run & -(2 * k), k)     # larger ones lie before
+
+            lax.fori_loop(jnp.where(pieces, run, 0), n, one, 0)
 
     @pl.when(s == 0)
     def _():
@@ -229,8 +337,9 @@ def _softmax_init(rows: int, width: int):
             jnp.zeros((rows, width), jnp.float32))
 
 
-def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, done_ref, *, heads: int, scale: float):
+def _kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm, v_hbm,
+            o_ref, kbuf, vbuf, sems, done_ref, *, heads: int, scale: float,
+            block_size: int):
     hd = kbuf.shape[2]
     head_dim = hd // heads
     m_rows = -(-heads // 16) * 16
@@ -251,15 +360,16 @@ def _kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         return _softmax_step(sc, vbuf[buf], c, pos, carry)
 
     (_, _, own), (m, l, acc) = _walk(
-        layer_ref, tables_ref, pos_ref, (k_hbm, v_hbm), (kbuf, vbuf), sems,
-        done_ref, (vbuf,), setup, step)
+        layer_ref, tables_ref, pos_ref, lead_ref, (k_hbm, v_hbm),
+        (kbuf, vbuf), sems, done_ref, (vbuf,), setup, step, block_size)
     # an inactive slot (l == 0) gives zeros; its row is never read
     ctx = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
     o_ref[...] = jnp.sum(ctx, axis=0, keepdims=True)
 
 
-def _gqa_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                kbuf, vbuf, sems, done_ref, *, kv_heads: int, scale: float):
+def _gqa_kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm,
+                v_hbm, o_ref, kbuf, vbuf, sems, done_ref, *, kv_heads: int,
+                scale: float, block_size: int):
     heads, head_dim = q_ref.shape
     width = kbuf.shape[2]               # kv_heads * head_dim
     group = heads // kv_heads
@@ -280,8 +390,8 @@ def _gqa_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         return _softmax_step(sc, vbuf[buf], c, pos, carry)
 
     (_, _, own), (m, l, acc) = _walk(
-        layer_ref, tables_ref, pos_ref, (k_hbm, v_hbm), (kbuf, vbuf), sems,
-        done_ref, (vbuf,), setup, step)
+        layer_ref, tables_ref, pos_ref, lead_ref, (k_hbm, v_hbm),
+        (kbuf, vbuf), sems, done_ref, (vbuf,), setup, step, block_size)
     # row h keeps its own K/V head's lanes; an inactive slot gives zeros
     ctx = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
     out = ctx[:, :head_dim]
@@ -290,9 +400,9 @@ def _gqa_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _latent_kernel(layer_ref, tables_ref, pos_ref, ql_ref, qr_ref, c_hbm,
-                   r_hbm, o_ref, cbuf, rbuf, sems, done_ref, *,
-                   scale: float):
+def _latent_kernel(layer_ref, tables_ref, pos_ref, lead_ref, ql_ref, qr_ref,
+                   c_hbm, r_hbm, o_ref, cbuf, rbuf, sems, done_ref, *,
+                   scale: float, block_size: int):
     last = (((1,), (1,)), ((), ()))
 
     def setup():
@@ -310,20 +420,74 @@ def _latent_kernel(layer_ref, tables_ref, pos_ref, ql_ref, qr_ref, c_hbm,
         return _softmax_step(sc, ctx, c, pos, carry)
 
     # rbuf meets the mask alone; cbuf's stale rows meet zero weights
-    _, (m, l, acc) = _walk(layer_ref, tables_ref, pos_ref, (c_hbm, r_hbm),
-                           (cbuf, rbuf), sems, done_ref, (cbuf,), setup,
-                           step)
+    _, (m, l, acc) = _walk(layer_ref, tables_ref, pos_ref, lead_ref,
+                           (c_hbm, r_hbm), (cbuf, rbuf), sems, done_ref,
+                           (cbuf,), setup, step, block_size)
     o_ref[...] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-def _scalars(layer, block_tables, positions):
-    return (jnp.reshape(layer, (1,)).astype(jnp.int32),
-            block_tables.astype(jnp.int32), positions.astype(jnp.int32))
+class Tables(NamedTuple):
+    """Block tables `[S, MB]` with what the walk reads beside them: `runs`
+    `[S, chunks]`, of each chunk of `blocks_per_chunk` entries how many from
+    its first on are consecutive block ids (1 at least). The walk fetches
+    that many of the chunk's live blocks in the binary pieces of their
+    count, one copy where the whole chunk is a run, and never searches the
+    table itself. `decoder.decode_step` makes one ONCE a step, outside its
+    layer loop (inside, XLA counts again every layer: three fusions and a
+    reduce-window, 2 to 11 us at the cells' tables); the kernels take one
+    wherever they take block tables, and count for a bare array in the
+    call."""
+
+    ids: jax.Array
+    runs: Optional[jax.Array]     # None: a wide cache's walk reads none
+
+
+def with_runs(block_tables, k_pool, v_pool) -> Tables:
+    """`block_tables` as `Tables` for a walk over these two pools (as they
+    are, if they are). Entries past a sequence's blocks are the null block
+    and end a run; the walk stops at the live blocks anyway.
+    `serving/kv_cache.run_chunks` is the host's count of the chunks this
+    finds whole."""
+    if isinstance(block_tables, Tables):
+        return block_tables
+    ids = block_tables.astype(jnp.int32)
+    slots, mb = ids.shape
+    token_bytes = _token_bytes(k_pool, v_pool)
+    if not narrow(token_bytes):     # its walk takes a block a copy
+        return Tables(ids, None)
+    per_chunk = blocks_per_chunk(k_pool.shape[2], token_bytes)
+    chunks = -(-mb // per_chunk)
+    t = jnp.pad(ids, ((0, 0), (0, chunks * per_chunk - mb)))
+    t = t.reshape(slots, chunks, per_chunk)
+    follows = (t[..., 1:] == t[..., :-1] + 1).astype(jnp.int32)
+    return Tables(ids, 1 + jnp.sum(
+        jnp.cumprod(follows, axis=-1, dtype=jnp.int32), axis=-1,
+        dtype=jnp.int32))
+
+
+def _call_form(kernel, layer, block_tables, positions, *pools):
+    """How a walk's `pallas_call` is made: (the body, the prefetched scalar
+    arrays, the pools as the body takes them). THE place where the cache's
+    shape decides. A wide cache's call is what it always was: layer, tables
+    and positions, pools `[L, NB, BS, width]`, a body that has no
+    `lead_ref`. A narrow cache's prefetches the tables' runs as a fourth
+    array and takes the pools as their rows `[L, NB*BS, width]`, the same
+    bytes (a block is whole tiles): consecutive blocks are one contiguous
+    span of rows, which one copy takes."""
+    tables = with_runs(block_tables, *pools)
+    scalars = (jnp.reshape(layer, (1,)).astype(jnp.int32), tables.ids,
+               positions.astype(jnp.int32))
+    if tables.runs is None:
+        return (lambda layer_ref, tables_ref, pos_ref, *refs: kernel(
+            layer_ref, tables_ref, pos_ref, None, *refs)), scalars, pools
+    return kernel, (*scalars, tables.runs), tuple(
+        p.reshape(p.shape[0], -1, p.shape[3]) for p in pools)
 
 
 def _scratch(k_pool, v_pool):
-    return [pltpu.VMEM((2, _CHUNK, k_pool.shape[3]), k_pool.dtype),
-            pltpu.VMEM((2, _CHUNK, v_pool.shape[3]), v_pool.dtype),
+    chunk = chunk_tokens(_token_bytes(k_pool, v_pool))
+    return [pltpu.VMEM((2, chunk, k_pool.shape[3]), k_pool.dtype),
+            pltpu.VMEM((2, chunk, v_pool.shape[3]), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32)]
 
@@ -333,18 +497,22 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     positions: jax.Array, *, heads: int,
                     interpret: bool = False) -> jax.Array:
     """q `[S, H*D]` against layer `layer` of the pools `[L, NB, BS, H*D]`
-    through block_tables `[S, MB]`: slot s attends key positions
-    `0..positions[s]`, scaled by `1/sqrt(D)`, scores and softmax in
-    float32, and gets its context `[H*D]` in q's dtype; a slot whose
-    table starts with the null block gets zeros. `interpret` runs the
-    kernel in the Pallas TPU interpreter (tests, off the chip)."""
+    through block_tables `[S, MB]` (or `Tables`: the same with its runs
+    counted): slot s attends key positions `0..positions[s]`, scaled by
+    `1/sqrt(D)`, scores and softmax in float32, and gets its context
+    `[H*D]` in q's dtype; a slot whose table starts with the null block
+    gets zeros. `interpret` runs the kernel in the Pallas TPU interpreter
+    (tests, off the chip)."""
     n_slots, hd = q.shape
     kernel = functools.partial(_kernel, heads=heads,
-                               scale=1.0 / math.sqrt(hd // heads))
+                               scale=1.0 / math.sqrt(hd // heads),
+                               block_size=k_pool.shape[2])
+    kernel, scalars, pools = _call_form(kernel, layer, block_tables,
+                                        positions, k_pool, v_pool)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(n_slots,),
             in_specs=[
                 pl.BlockSpec((None, 1, hd), lambda s, *_: (s, 0, 0)),
@@ -358,8 +526,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(*_scalars(layer, block_tables, positions), q[:, None, :], k_pool,
-      v_pool)
+    )(*scalars, q[:, None, :], *pools)
     return out[:, 0, :].astype(q.dtype)
 
 
@@ -376,11 +543,15 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     n_slots = q.shape[0]
     head_dim = k_pool.shape[3] // kv_heads
     per_slot = lambda s, *_: (s, 0, 0)      # noqa: E731
-    out = pl.pallas_call(
+    kernel, scalars, pools = _call_form(
         functools.partial(_gqa_kernel, kv_heads=kv_heads,
-                          scale=1.0 / math.sqrt(head_dim)),
+                          scale=1.0 / math.sqrt(head_dim),
+                          block_size=k_pool.shape[2]),
+        layer, block_tables, positions, k_pool, v_pool)
+    out = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(n_slots,),
             in_specs=[
                 pl.BlockSpec((None, heads, head_dim), per_slot),
@@ -394,8 +565,7 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_gqa_attention",
-    )(*_scalars(layer, block_tables, positions),
-      q.reshape(n_slots, heads, head_dim), k_pool, v_pool)
+    )(*scalars, q.reshape(n_slots, heads, head_dim), *pools)
     return out.reshape(n_slots, heads * head_dim)
 
 
@@ -416,10 +586,14 @@ def paged_latent_attention(q_latent: jax.Array, q_rope: jax.Array,
     n_slots, heads, latent = q_latent.shape
     rope = q_rope.shape[2]
     per_slot = lambda s, *_: (s, 0, 0)      # noqa: E731
+    kernel, scalars, pools = _call_form(
+        functools.partial(_latent_kernel, scale=scale,
+                          block_size=c_pool.shape[2]),
+        layer, block_tables, positions, c_pool, r_pool)
     return pl.pallas_call(
-        functools.partial(_latent_kernel, scale=scale),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(n_slots,),
             in_specs=[
                 pl.BlockSpec((None, heads, latent), per_slot),
@@ -434,5 +608,4 @@ def paged_latent_attention(q_latent: jax.Array, q_rope: jax.Array,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_latent_attention",
-    )(*_scalars(layer, block_tables, positions), q_latent, q_rope, c_pool,
-      r_pool)
+    )(*scalars, q_latent, q_rope, *pools)

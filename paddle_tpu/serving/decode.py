@@ -74,7 +74,7 @@ from ..ops.pallas import ssm_update as _ssm_update
 from .batcher import QueueFullError, ServerClosed
 from .kv_cache import (NULL_ROW, PREFILL_WRITE_UNITS, BlockAllocator,
                        KVCacheConfig, NoBlocksError, StateRowAllocator,
-                       build_block_table, init_pools)
+                       build_block_table, init_pools, run_chunks)
 from . import kv_reuse as _kvr
 from .kv_reuse import ReuseBlockAllocator
 
@@ -1595,8 +1595,12 @@ class DecodeEngine:
             return self._prefill_admitted(req, bucket)
         finally:
             if sp is not None:
+                # the admitted table's chunks, and how many of them the
+                # decode kernels will read with one copy a pool
+                runs, chunks = run_chunks(req.blocks, self._alloc.per_chunk)
                 sp.close(bucket=bucket, prompt_len=len(req.prompt),
-                         queue_wait_s=req.admitted_at - req.enqueued_at)
+                         queue_wait_s=req.admitted_at - req.enqueued_at,
+                         runs=runs, chunks=chunks)
 
     def _prefill_admitted(self, req: _Request, bucket: Optional[int]
                           ) -> Optional[_Pending]:
@@ -1671,7 +1675,7 @@ class DecodeEngine:
                 bi = req.pos // self.kv_cfg.block_size
                 while bi >= len(req.blocks):
                     try:
-                        req.blocks.extend(self._alloc.alloc(1))
+                        self._alloc.grow(req.blocks)
                         taken += 1
                     except NoBlocksError:
                         short = req
@@ -2191,7 +2195,7 @@ class DecodeEngine:
                     lo = req.pos // bs
                     hi = (req.pos + span - 1) // bs
                     while hi >= len(req.blocks):
-                        req.blocks.extend(self._alloc.alloc(1))
+                        self._alloc.grow(req.blocks)
                         taken += 1
                     self._cow_guard(req, lo, hi)
             except NoBlocksError:

@@ -16,8 +16,10 @@ sequences grow by appending a block id to their table — a host-side
 int, never a new executable — and the decode executable's shapes stay
 fixed no matter which sequences are resident.
 
-Layering: this module owns the host-side `BlockAllocator` (free-list,
-alloc/free, fragmentation accounting) and the pure jnp pool helpers
+Layering: this module owns the host-side `BlockAllocator` (free extents,
+tables handed out as runs of consecutive ids, the count of the chunks the
+decode kernels read with one copy: `run_chunks`) and the pure jnp pool
+helpers
 (`init_pools`, `write_token_kv`, `write_prefill_kv`, `write_chunk_kv`,
 `write_span_kv`, `gather_kv`) that `models/decoder.py` composes into
 the serve programs; they take the whole pool and a layer index, and
@@ -50,6 +52,7 @@ it). Row 0 is the null row, as block 0 is the null block.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import math
@@ -59,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["KVCacheConfig", "BlockAllocator", "NoBlocksError",
+__all__ = ["KVCacheConfig", "BlockAllocator", "NoBlocksError", "run_chunks",
            "StateRowAllocator", "NULL_ROW",
            "init_pools", "write_token_kv", "write_prefill_kv",
            "write_chunk_kv", "write_span_kv", "gather_kv",
@@ -97,9 +100,10 @@ class KVCacheConfig:
     128 lanes: `widths=(512, 128)`, 1280 bytes a token a layer in bf16 for
     1152 of content. The 64 empty lanes are the price of the layout: the
     TPU tiles the lane dimension by 128, so a 64-wide pool takes the same
-    HBM and every helper and kernel here (a block = whole tiles, one DMA a
-    block) serves both pools as it is; one padded row of 640 lanes costs
-    the same bytes and would want a single-pool engine beside this one."""
+    HBM and every helper and kernel here (a block = whole tiles, a run of
+    blocks one DMA) serves both pools as it is; one padded row of 640 lanes
+    costs the same bytes and would want a single-pool engine beside this
+    one."""
 
     layers: int
     max_len: int
@@ -154,25 +158,152 @@ class KVCacheConfig:
             jnp.dtype(self.dtype).itemsize
 
 
+def run_chunks(blocks: Sequence[int], per_chunk: int) -> Tuple[int, int]:
+    """(runs, chunks) of one sequence's table: its chunks of `per_chunk`
+    entries (the last may be shorter), and how many of them hold
+    consecutive block ids and nothing else. THE definition of "the paged
+    decode kernels read this chunk with one copy a pool"
+    (ops/pallas/paged_attention.py `_walk`: a full chunk of one run is one
+    copy; the sequence's last, shorter chunk the binary pieces of its
+    length, at most four of them at 16 blocks a chunk, five at 32)."""
+    ids = np.asarray(blocks, np.int64)
+    if not ids.size:
+        return 0, 0
+    chunks = -(-ids.size // per_chunk)
+    breaks = np.flatnonzero(np.diff(ids) != 1) + 1
+    # a break AT a chunk's first entry parts two chunks and splits neither
+    split = np.unique(breaks[breaks % per_chunk != 0] // per_chunk)
+    return chunks - split.size, chunks
+
+
+class _FreeExtents:
+    """The free block ids as extents `[start, end)`, no two of them
+    adjacent: what lets `BlockAllocator` hand out RUNS. `_starts` is kept
+    sorted; a take or a give touches the extents, not the ids (some tens
+    under churn, against 9 216 blocks)."""
+
+    def __init__(self, lo: int, hi: int):
+        self.count = 0
+        self._starts: List[int] = []
+        self._end: Dict[int, int] = {}          # start -> end
+        self._start_of_end: Dict[int, int] = {}
+        self._add(lo, hi)
+
+    def __len__(self) -> int:
+        return self.count
+
+    def _add(self, start: int, end: int) -> None:
+        if start < end:
+            bisect.insort(self._starts, start)
+            self._end[start] = end
+            self._start_of_end[end] = start
+            self.count += end - start
+
+    def _drop(self, start: int) -> int:
+        """Forget the extent at `start`; its end."""
+        end = self._end.pop(start)
+        del self._start_of_end[end]
+        del self._starts[bisect.bisect_left(self._starts, start)]
+        self.count -= end - start
+        return end
+
+    def _carve(self, start: int, first: int, n: int) -> List[int]:
+        """The ids `first .. first + n` out of the extent at `start`."""
+        end = self._drop(start)
+        self._add(start, first)
+        self._add(first + n, end)
+        return list(range(first, first + n))
+
+    def _longest_first(self, start: int) -> Tuple[int, int]:
+        """Sort key of an extent: the longest first (ties: the lowest)."""
+        return start - self._end[start], start
+
+    def take(self, n: int) -> List[int]:
+        """n ids (the caller has checked that n are free): the first n of
+        the LOWEST extent that holds n (address-ordered first fit); where
+        none does, the largest extents whole, then the first ids of the
+        next largest: the fewest pieces, each ascending, in address
+        order."""
+        if not n:
+            return []
+        for start in self._starts:
+            if self._end[start] - start >= n:
+                return self._carve(start, start, n)
+        pieces = []
+        for start in sorted(self._starts, key=self._longest_first):
+            pieces.append((start, min(self._end[start] - start, n)))
+            n -= pieces[-1][1]
+            if not n:
+                break
+        return [b for start, k in sorted(pieces)
+                for b in self._carve(start, start, k)]
+
+    def take_after(self, last: int) -> int:
+        """One id (the caller has checked that one is free) for the table
+        that ends with `last`: `last + 1` if it is free (which for an
+        owned `last` means it starts an extent), so that the run goes on;
+        else the MIDDLE of the largest extent, where a new run has the
+        most room before it meets another sequence's (the start of an
+        extent is where the sequence before it grows, and where the next
+        admission lands: against the lowest free block, `take(1)`, the
+        chip read `run_chunk_share` 0.905 for 0.858 where tables are
+        mostly prompt and 0.71 for 0.355 where they are mostly growth,
+        and there the grouped-query walk at 53% of its roofline for 41%;
+        PERF.md section 6, PR 40; tests/test_block_runs.py runs the same
+        closed loops over the allocator alone and counts the same)."""
+        if last + 1 in self._end:
+            return self._carve(last + 1, last + 1, 1)[0]
+        start = min(self._starts, key=self._longest_first)
+        return self._carve(start, (start + self._end[start]) // 2, 1)[0]
+
+    def give(self, block: int) -> None:
+        start, end = block, block + 1
+        if block in self._start_of_end:         # an extent ends here
+            start = self._start_of_end[block]
+            self._drop(start)
+        if end in self._end:                    # and one starts after it
+            end = self._drop(end)
+        self._add(start, end)
+
+
 class BlockAllocator:
-    """Host-side free-list over the pool's block ids (1..num_blocks-1;
+    """Host-side allocator over the pool's block ids (1..num_blocks-1;
     block 0 is never handed out). Single-owner by design — the decode
     scheduler thread is the only caller — so no locking here.
 
-    Fragmentation accounting: paged allocation has no *external*
-    fragmentation (any free block serves any sequence), so the number
-    reported is *internal* waste — slots allocated but not (yet)
-    holding a live token — which `waste_fraction` reports against the
-    allocated capacity."""
+    It hands out RUNS: `alloc(n)` returns n consecutive ascending ids
+    wherever a free extent holds n (the lowest such extent; a fresh pool
+    gives 1, 2, 3, ...), else the fewest ascending pieces, and `grow`
+    extends a table by the block after its last one when that is free.
+    Nothing is reserved ahead of need: a block is free or owned, so
+    `can_alloc`, admission and preemption see the capacity they always
+    saw. Any free block still serves any sequence; a run is what the paged
+    decode kernels fetch with ONE copy a pool and chunk where a token stores
+    little (ops/pallas/paged_attention.py `narrow`; a wide cache's walk
+    stays a block a copy and the count then describes its tables alone),
+    and `run_chunks` counts it.
+
+    Accounting: `waste_fraction` is *internal* waste — slots allocated
+    but not (yet) holding a live token — against the allocated capacity.
+    `run_chunk_share` is the *external* side: of the chunks of the live
+    tables, the share the kernels read with one copy a pool, kept as
+    tables are built (`alloc`, `grow`) and given back (`free`: a table
+    whole, as they built it)."""
 
     def __init__(self, cfg: KVCacheConfig):
+        from ..ops.pallas.paged_attention import blocks_per_chunk
+
         self.cfg = cfg
         if cfg.num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is reserved), got "
                 f"{cfg.num_blocks}")
-        self._free: List[int] = list(range(cfg.num_blocks - 1, 0, -1))
+        self._free = _FreeExtents(1, cfg.num_blocks)
         self._owned: Dict[int, bool] = {}
+        # blocks the kernels' walk takes a chunk: `run_chunks`' unit
+        self.per_chunk = blocks_per_chunk(cfg.block_size,
+                                          cfg.bytes_per_token())
+        self._runs = self._chunks = 0       # of the live tables
 
     def free_blocks(self) -> int:
         return len(self._free)
@@ -184,19 +315,43 @@ class BlockAllocator:
         return n <= len(self._free)
 
     def alloc(self, n: int) -> List[int]:
-        """Take n blocks off the free list; raises NoBlocksError
-        without allocating anything when fewer than n are free (a
-        partial grant would leak on the caller's error path)."""
+        """A new table of n blocks, one ascending run where a free extent
+        holds n; raises NoBlocksError without allocating anything when
+        fewer than n are free (a partial grant would leak on the caller's
+        error path)."""
         if n < 0:
             raise ValueError(f"cannot allocate {n} blocks")
         if n > len(self._free):
             raise NoBlocksError(
                 f"need {n} blocks, only {len(self._free)} of "
                 f"{self.cfg.usable_blocks} free")
-        out = [self._free.pop() for _ in range(n)]
+        out = self._free.take(n)
         for b in out:
             self._owned[b] = True
+        runs, chunks = run_chunks(out, self.per_chunk)
+        self._runs += runs
+        self._chunks += chunks
         return out
+
+    def grow(self, table: List[int]) -> None:
+        """Append one block to a sequence's (non-empty) table: the block
+        after its last one if that is free, so that the run goes on, else
+        the middle of the largest free extent (`_FreeExtents.take_after`).
+        Raises NoBlocksError, the table as it was, when none is free."""
+        if not len(self._free):
+            raise NoBlocksError(
+                f"need 1 block, none of {self.cfg.usable_blocks} free")
+        last = table[-1]
+        block = self._free.take_after(last)
+        self._owned[block] = True
+        into = len(table) % self.per_chunk     # entries of its chunk so far
+        if not into:
+            self._runs += 1
+            self._chunks += 1
+        elif block != last + 1 and run_chunks(
+                table[-into:], self.per_chunk)[0]:
+            self._runs -= 1
+        table.append(block)
 
     def free(self, blocks: Sequence[int]):
         """Return blocks to the pool. Double-free and foreign ids are
@@ -210,7 +365,10 @@ class BlockAllocator:
                 raise ValueError(f"block {b} is not allocated "
                                  "(double free?)")
             del self._owned[b]
-            self._free.append(int(b))
+            self._free.give(int(b))
+        runs, chunks = run_chunks(blocks, self.per_chunk)
+        self._runs -= runs
+        self._chunks -= chunks
 
     def stats(self, live_tokens: int = 0) -> Dict[str, float]:
         used = self.used_blocks()
@@ -225,6 +383,10 @@ class BlockAllocator:
             "allocated_token_capacity": cap,
             "internal_waste_tokens": waste,
             "waste_fraction": round(waste / cap, 4) if cap else 0.0,
+            # of the live tables' chunks, the share the paged decode
+            # kernels read with one copy a pool (None: no live table)
+            "run_chunk_share": round(self._runs / self._chunks, 4)
+            if self._chunks > 0 else None,
             "pool_bytes": self.cfg.pool_bytes(),
             # what a token stores in a layer: lanes of the two entries
             # (the model's say) and their bytes

@@ -125,7 +125,11 @@ class ReuseBlockAllocator(BlockAllocator):
     indexed, evictable), an unregistered one returns to the free list.
     `can_alloc`/`alloc` treat LRU blocks as allocatable: eviction
     (oldest first) is folded into allocation, so callers — admission,
-    mid-decode growth, preemption retries — need no new code paths."""
+    mid-decode growth, preemption retries — need no new code paths.
+
+    Free blocks are the base class's extents, so fresh blocks come as
+    runs here too; `run_chunk_share` stays None: these tables splice
+    shared blocks in front of what `alloc` gave, outside its count."""
 
     def __init__(self, cfg: KVCacheConfig):
         super().__init__(cfg)
@@ -159,7 +163,7 @@ class ReuseBlockAllocator(BlockAllocator):
         while len(self._free) < n:
             blk, _ = self._lru.popitem(last=False)       # oldest first
             del self._index[self._hash_of.pop(blk)]
-            self._free.append(int(blk))
+            self._free.give(int(blk))
             evicted += 1
         if evicted:
             self.evicted_total += evicted
@@ -175,11 +179,23 @@ class ReuseBlockAllocator(BlockAllocator):
                     f"{len(self._lru)} evictable of "
                     f"{self.cfg.usable_blocks}")
             self._evict_for_locked(n)
-            out = [self._free.pop() for _ in range(n)]
+            out = self._free.take(n)
             for b in out:
                 self._owned[b] = True
                 self._refs[b] = 1
         return out
+
+    def grow(self, table: List[int]) -> None:
+        with self._lock:
+            if not len(self._free) + len(self._lru):
+                raise NoBlocksError(
+                    f"need 1 block, 0 free or evictable of "
+                    f"{self.cfg.usable_blocks}")
+            self._evict_for_locked(1)
+            block = self._free.take_after(table[-1])
+            self._owned[block] = True
+            self._refs[block] = 1
+        table.append(block)
 
     def free(self, blocks: Sequence[int]):
         """Decref. The last reference parks a registered block on the
@@ -202,7 +218,7 @@ class ReuseBlockAllocator(BlockAllocator):
                 if b in self._hash_of:
                     self._lru[b] = None
                 else:
-                    self._free.append(int(b))
+                    self._free.give(int(b))
 
     # -- prefix index --------------------------------------------------
 
@@ -285,7 +301,7 @@ class ReuseBlockAllocator(BlockAllocator):
                     f"copy-on-write needs 1 block, 0 free of "
                     f"{self.cfg.usable_blocks}")
             self._evict_for_locked(1)
-            new = self._free.pop()
+            new = self._free.take(1)[0]
             self._owned[new] = True
             self._refs[new] = 1
             self._refs[block] -= 1

@@ -8,6 +8,8 @@ the pools) is `tests/test_tpu_aot_compile.py`'s, and the chip itself is
 `chip_smoke.py`'s `paged_attention` phase.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,6 +140,214 @@ def test_a_slots_result_does_not_depend_on_its_neighbours(width):
             row = out
             assert np.abs(row).max() > 0
         np.testing.assert_array_equal(out, row)
+
+
+# -- runs of consecutive blocks: one copy a pool -----------------------------
+#
+# A narrow cache's walk fetches what the table names in a row from a
+# chunk's first entry with one copy a pool (the binary pieces of the
+# count), block by block only where the ids do not follow each other; a
+# wide cache's stays a block a copy. What a slot's blocks HOLD decides its
+# result, never where they lie: the same
+# contents under a table of runs and under a descending table (no two ids
+# in a row: the per-block walk) give the same bits.
+
+R_S, R_MB, R_L = 4, 36, 2       # 36 blocks: 2 1/4 chunks of 256 tokens, or
+R_NB = 1 + R_S * R_MB           # 1 1/8 of 512 (a narrow cache's:
+#                                 `chunk_tokens`); slot s's region starts at
+#                                 block 1 + s*MB
+
+
+def _asc(n):
+    return list(range(n))
+
+
+def _desc(n):
+    return list(range(R_MB - 1, R_MB - 1 - n, -1))
+
+
+def _pairs(n):
+    """1, 0, 3, 2, ...: scattered, no id followed by the next one."""
+    return [j ^ 1 if j ^ 1 < n else j for j in range(n)]
+
+
+def _tail(n):
+    """A run that ends with the region's last block."""
+    return list(range(R_MB - n, R_MB))
+
+
+# a slot: (tokens it attends, where in its region block j lies); and what
+# `kv_cache.run_chunks` counts over the pattern's tables: every chunk a run
+# ("all"), none ("none"), or some
+RUN_PATTERNS = {
+    "all-runs": ([(570, _asc), (256, _asc), (100, _asc), (17, _asc)],
+                 "all"),
+    "descending": ([(570, _desc), (256, _desc), (100, _desc), (17, _desc)],
+                   "none"),
+    # chunk 0 a run; chunk 1 eight scattered blocks, then a run of eight;
+    # chunk 2 a run of four
+    "mixed": ([(576, lambda n: _asc(16) + [16 + j for j in _pairs(8)]
+                + list(range(24, 36))),
+               (300, _pairs), (40, _asc), (0, _asc)], "some"),
+    # a run of 21 blocks ends inside chunk 1, the rest descends
+    "run-ends-mid-chunk": ([(480, lambda n: _asc(21)
+                             + list(range(35, 26, -1))),
+                            (200, lambda n: _asc(5) + _desc(n - 5)),
+                            (0, _asc), (16, _asc)], "some"),
+    "under-a-chunk": ([(5, _asc), (40, _asc), (100, _asc), (250, _asc)],
+                      "all"),
+    # slot 3's run ends with block NB - 1, inside its second chunk
+    "last-block": ([(256, _asc), (0, _asc), (33, _tail), (317, _tail)],
+                   "all"),
+    "inactive-between": ([(300, _asc), (0, _asc), (290, _asc), (0, _asc)],
+                         "all"),
+}
+
+
+def _tables(pattern, per_block):
+    """(tables, positions) of a pattern; `per_block`: the same slots and
+    lengths with every table descending, which the walk takes a block at
+    a time."""
+    tables = np.zeros((R_S, R_MB), np.int32)
+    positions = np.zeros((R_S,), np.int32)
+    for s, (n, where) in enumerate(RUN_PATTERNS[pattern][0]):
+        blocks = -(-n // BS)
+        offs = _desc(blocks) if per_block else where(blocks)
+        assert len(offs) == blocks and len(set(offs)) == blocks
+        tables[s, :blocks] = 1 + s * R_MB + np.asarray(offs, np.int32)
+        positions[s] = max(n - 1, 0)
+    return tables, positions
+
+
+def _laid_out(contents, tables, seed):
+    """The pools `[L, NB, BS, width]` that hold slot s's block j
+    (`contents[i][:, s, j]`) where `tables` says, noise everywhere else."""
+    rng = np.random.default_rng(seed)
+    pools = []
+    for c in contents:
+        pool = rng.standard_normal(
+            (R_L, R_NB, BS, c.shape[-1])).astype(np.float32)
+        for s in range(R_S):
+            for j in range(R_MB):
+                if tables[s, j]:
+                    pool[:, tables[s, j]] = c[:, s, j]
+        pools.append(jnp.asarray(pool, jnp.bfloat16))
+    return pools
+
+
+def _paged_case(rng, heads=20, d=64):
+    """5 KB a token: a wide cache, a block a copy, chunks of 256 tokens;
+    2 heads of 64: a narrow one, runs whole, chunks of 512."""
+    q = jnp.asarray(rng.standard_normal((R_S, heads * d)), jnp.bfloat16)
+    run = jax.jit(lambda kp, vp, t, p: PA.paged_attention(
+        q, kp, vp, jnp.int32(1), t, p, heads=heads,
+        interpret=pltpu.InterpretParams()))
+    ref = lambda kp, vp, t, p: gather_math(     # noqa: E731
+        q, kp, vp, 1, t, p, heads)
+    return (heads * d,) * 2, run, ref, 0.03
+
+
+def _gqa_case(rng):
+    heads, kv_heads, d = 16, 2, 128         # 1 KB a token: chunks of 512
+    q = jnp.asarray(rng.standard_normal((R_S, heads * d)), jnp.bfloat16)
+    run = jax.jit(lambda kp, vp, t, p: PA.paged_gqa_attention(
+        q, kp, vp, jnp.int32(1), t, p, heads=heads, kv_heads=kv_heads,
+        interpret=pltpu.InterpretParams()))
+    f32 = jnp.float32
+    ref = lambda kp, vp, t, p: decoder.mha_cached(      # noqa: E731
+        q.astype(f32)[:, None], kvc.gather_kv(kp, 1, t).astype(f32),
+        kvc.gather_kv(vp, 1, t).astype(f32), p[:, None], heads,
+        kv_heads)[:, 0]
+    return (kv_heads * d,) * 2, run, ref, 0.02
+
+
+def _latent_case(rng):
+    heads, latent, rope, scale = 16, 128, 128, 0.1  # 512 B: chunks of 512
+    ql = jnp.asarray(rng.standard_normal((R_S, heads, latent)), jnp.bfloat16)
+    qr = jnp.asarray(rng.standard_normal((R_S, heads, rope)), jnp.bfloat16)
+    run = jax.jit(lambda cp, rp, t, p: PA.paged_latent_attention(
+        ql, qr, cp, rp, jnp.int32(1), t, p, scale=scale,
+        interpret=pltpu.InterpretParams()).reshape(R_S, heads * latent))
+
+    def ref(cp, rp, t, p):
+        keys = kvc.gather_kv(cp, 1, t).astype(jnp.float32)
+        rot = kvc.gather_kv(rp, 1, t).astype(jnp.float32)
+        sc = (jnp.einsum("snc,smc->snm", ql.astype(jnp.float32), keys)
+              + jnp.einsum("snr,smr->snm", qr.astype(jnp.float32), rot))
+        seen = jnp.arange(keys.shape[1])[None, :] <= p[:, None]
+        sc = jnp.where(seen[:, None, :], sc * scale, -jnp.inf)
+        return jnp.einsum("snm,smc->snc", jax.nn.softmax(sc, -1),
+                          keys).reshape(R_S, heads * latent)
+
+    return (latent, rope), run, ref, 0.02
+
+
+_RUN_KERNELS = {"paged": _paged_case,
+                "paged-narrow": functools.partial(_paged_case, heads=2),
+                "gqa": _gqa_case, "latent": _latent_case}
+
+
+@pytest.fixture(scope="module", params=list(_RUN_KERNELS))
+def run_kernel(request):
+    """A kernel jitted once, its reference, and what every slot's blocks
+    hold (both pools), wherever a table puts them."""
+    rng = np.random.default_rng(11)
+    widths, run, ref, tol = _RUN_KERNELS[request.param](rng)
+    contents = [rng.standard_normal((R_L, R_S, R_MB, BS, w)).astype(
+        np.float32) for w in widths]
+    per_chunk = PA.blocks_per_chunk(BS, 2 * sum(widths))
+    assert per_chunk == (16 if request.param == "paged" else 32)
+    return contents, run, ref, tol, per_chunk
+
+
+@pytest.mark.parametrize("pattern", list(RUN_PATTERNS))
+def test_a_run_of_blocks_in_one_copy_gives_the_per_block_walks_bits(
+        run_kernel, pattern):
+    contents, run, ref, tol, per_chunk = run_kernel
+    outs = []
+    for per_block in (False, True):
+        tables, positions = _tables(pattern, per_block)
+        live = [t[t > 0] for t in tables]
+        runs, chunks = np.sum([kvc.run_chunks(t, per_chunk) for t in live],
+                              axis=0)
+        if per_block:       # no two ids in a row: nothing to take together
+            assert not any((np.diff(t) == 1).any() for t in live)
+        else:
+            assert {"all": runs == chunks, "none": runs == 0,
+                    "some": 0 < runs < chunks}[RUN_PATTERNS[pattern][1]]
+            assert tables.max() <= R_NB - 1
+        pools = _laid_out(contents, tables, seed=int(per_block))
+        t, p = jnp.asarray(tables), jnp.asarray(positions)
+        outs.append(np.asarray(run(*pools, t, p), np.float32))
+    np.testing.assert_array_equal(outs[0], outs[1])
+    want = np.asarray(ref(*pools, t, p))
+    active = np.asarray([n > 0 for n, _ in RUN_PATTERNS[pattern][0]])
+    assert np.abs(outs[0] - want)[active].max() < tol
+    assert not outs[0][~active].any()       # an inactive slot reads nothing
+
+
+@pytest.mark.parametrize("heads,scalars,pool_rank", [(20, 3, 4), (2, 4, 3)],
+                         ids=["wide", "narrow"])
+def test_a_wide_caches_call_is_the_one_it_always_was(heads, scalars,
+                                                     pool_rank):
+    """The cache's shape decides in ONE place (`_call_form`): a wide
+    cache's kernel prefetches layer, tables and positions, takes the pools
+    as they lie, and nothing counts runs for it (on the chip its decode
+    program then takes the device time it took before runs: PERF.md
+    section 6, PR 40); a narrow cache's prefetches the tables' runs as a
+    fourth array and takes the pools as their rows."""
+    pool = jax.ShapeDtypeStruct((R_L, R_NB, BS, heads * 64), jnp.bfloat16)
+    assert PA.narrow(PA._token_bytes(pool, pool)) == (scalars == 4)
+    jaxpr = jax.make_jaxpr(lambda q, kp, vp, t, p: PA.paged_attention(
+        q, kp, vp, jnp.int32(1), t, p, heads=heads))(
+            jax.ShapeDtypeStruct((R_S, heads * 64), jnp.bfloat16), pool, pool,
+            jax.ShapeDtypeStruct((R_S, R_MB), jnp.int32),
+            jax.ShapeDtypeStruct((R_S,), jnp.int32)).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].num_index_operands == scalars
+    assert [v.aval.ndim for v in call.invars[-2:]] == [pool_rank] * 2
+    counting = {"eq", "reduce_sum"} & {e.primitive.name for e in jaxpr.eqns}
+    assert len(counting) == (2 if scalars == 4 else 0)
 
 
 # -- the gate -----------------------------------------------------------------

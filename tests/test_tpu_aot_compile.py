@@ -241,6 +241,14 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
     compiled = jax.jit(fn, donate_argnums=(0,) if in_place else ()
                        ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if kind in (_PAGED, _LATENT, _GQA):
+        # a narrow cache's walk takes the pools as their rows `[L, NB*BS,
+        # width]` (a run of blocks is one span of rows, one copy): the same
+        # bytes, read where they lie, never a copy of a pool or of a layer
+        ma = compiled.memory_analysis()
+        pool = args[2]
+        layer_bytes = np.prod(pool.shape[1:]) * pool.dtype.itemsize
+        assert ma.temp_size_in_bytes < layer_bytes / 8, (ma, layer_bytes)
     if in_place:            # the pool is written where it lies
         ma = compiled.memory_analysis()
         assert ma.alias_size_in_bytes >= np.prod(args[0].shape) \
@@ -590,6 +598,9 @@ def test_decode_program_reads_the_live_blocks_through_the_table(
     paged = [k for k in _kernels(text) if "paged_attention" in k]
     assert len(paged) == 1 and "/layers/" in paged[0] \
         and "/attention/" in paged[0], _kernels(text)
+    # a wide cache's walk takes a block a copy: no run is counted
+    assert not re.findall(r"= s32\[[\d,]+\]\S* reduce-window\(.*cumprod",
+                          text)
 
 
 # ---------------------------------------------------------------------------
@@ -678,6 +689,13 @@ def test_joyai_serve_program_fits_and_reads_the_latent_cache_in_place(
         for k in kernels), kernels
     if kind == "decode":
         assert PA.GATE_COUNTS == {"paged_latent": 1}, PA.GATE_COUNTS
+        # the tables' runs (`Tables.runs`: a cumulative product over the
+        # 31 pairs of neighbours of each of the 9 chunks of 32 slots'
+        # tables) are counted ONCE a step, in the entry computation, for
+        # both kernels below and every layer of the scan
+        entry = text[text.index("\nENTRY "):]
+        windows = re.findall(r"= s32\[32,9,31\]\S* reduce-window\(", text)
+        assert len(windows) == 1 and windows[0] in entry, windows
         # the leading dense layer's and the scan body's: two kernels
         latent = [k for k in kernels if "paged_latent_attention" in k]
         assert len(latent) == 2 and all("/attention/" in k for k in latent)
