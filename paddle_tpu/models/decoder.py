@@ -19,7 +19,11 @@ says how wide they are, `qkv` produces them, the programs write them at
 pools for a kernel, and the model's three attention forms read them:
 `attend_prompt` (a whole prompt from its own projections),
 `attend_cached` (query rows against a gathered context) and
-`attend_paged` (a decode step through the block table, on a TPU). The
+`attend_paged` (a decode step through the block table, on a TPU). A model
+may store a THIRD entry at a rate, one every so many tokens
+(`ServeModel.rated`: compressed keys a block-sparse attention scores
+before it reads any K/V); its pools ride beside the state pools in the
+programs' `state` and the programs hand them to the attention pieces. The
 defaults are multi-head attention over K and V of `kv_heads*head_dim`
 lanes; with fewer K/V heads than query heads the `heads / kv_heads` query
 heads of a K/V head read its lanes together (grouped-query attention).
@@ -30,6 +34,13 @@ of the kind, rows, ...]`, row 0 the null row idle slots read and write).
 The engine owns the pools and hands the programs a row id a sequence, as
 it hands them block tables; the programs carry the pools beside the K/V
 pools and the MODEL reads and writes its rows (`ssm_prompt`, `ssm_token`).
+
+A model whose prompts are too long for one pass says so with
+`prompt_slice`: its prefill program walks the prompt in slices of that
+many tokens (`prefill_sliced`), each slice through every block, its K/V
+written before its queries read the cache so far, the recurrent state
+carried from slice to slice in its row: no temporary grows with the
+prompt.
 """
 
 from __future__ import annotations
@@ -66,6 +77,18 @@ class ServeModel:
     # space) mixer, `E` the `mlp` piece alone, `*` attention alone; None:
     # every block is attention then `mlp` (`serve_layers` says how each runs)
     pattern: Optional[str] = None
+    # tokens a slice of the prefill program's walk over a prompt
+    # (`prefill_sliced`); None: a prompt goes through in one pass
+    prompt_slice: Optional[int] = None
+
+    @property
+    def rated(self) -> Tuple[Tuple[int, int], ...]:
+        """Entries stored at a RATE beside the two a token: (lanes, stride
+        in tokens) each, one entry every `stride` tokens a layer of the
+        K/V pools, in pools of their own that follow the block table
+        (`kv_cache.KVCacheConfig.rated`); the programs carry them after the
+        state pools in `state`. () for a model that stores none."""
+        return ()
 
     @property
     def kv_heads(self) -> int:
@@ -128,11 +151,13 @@ class ServeModel:
                      causal=True, scale=1.0 / math.sqrt(self.head_dim))
         return ctx.reshape(B, T, -1)
 
-    def attend_cached(self, lp, q, keys, vals, pos):
+    def attend_cached(self, lp, q, keys, vals, pos, extra=()):
         """Query rows against a gathered context: q `[S, W, ...]`, the
         stored entries `keys` `[S, M, k width]` and `vals` `[S, M, v
         width]` of positions 0..M-1, `pos` `[S, W]`: row (s, w) sees the
-        positions `<= pos[s, w]` -> `[S, W, ctx]`."""
+        positions `<= pos[s, w]` -> `[S, W, ctx]`. `extra`: the rated
+        entries gathered through the same tables, `[S, MB, E * width]` each
+        (`kv_cache.gather_rated`), for a model that stores them."""
         return mha_cached(q, keys, vals, pos, self.heads, self.kv_heads)
 
     def paged_route(self, x, k_pool, v_pool) -> Optional[str]:
@@ -146,10 +171,11 @@ class ServeModel:
         return "paged" if pa.use_paged(x, k_pool, self.heads) else None
 
     def attend_paged(self, lp, q, k_pool, v_pool, layer, block_tables,
-                     positions):
+                     positions, rated=()):
         """One query row a slot, q `[S, ...]`, against the live blocks of
         layer `layer` of the pools through the table -> `[S, ctx]`; only
-        where `paged_route` named a kernel."""
+        where `paged_route` named a kernel. `rated`: the pools of the
+        entries stored at a rate, for a model that stores them."""
         from ..ops.pallas import paged_attention as pa
 
         if self.kv_heads != self.heads:
@@ -190,19 +216,55 @@ class ServeModel:
         raise NotImplementedError
 
     def ssm_prompt(self, lp, y, length, state, i: int, row):
-        """A recurrent mixer over one whole prompt y `[1, T, hidden]` of
-        true length `length`, from a ZERO state -> (out `[1, T, hidden]`,
-        `state` with row `row` of layer `i` of every pool overwritten by
-        the state after position `length - 1`). Positions at or past
-        `length` (the bucket's padding) must leave the state as it was."""
+        """A recurrent mixer (a state-space layer whose decay its input
+        sets, linear attention whose decay is fixed a head: `ops/ssm.py`)
+        over one whole prompt y `[1, T, hidden]` of true length `length`,
+        from a ZERO state -> (out `[1, T, hidden]`, `state` with row `row`
+        of layer `i` of every pool overwritten by the state after position
+        `length - 1`). Positions at or past `length` (the bucket's
+        padding) must leave the state as it was."""
         raise NotImplementedError
 
-    def ssm_token(self, lp, y, state, i: int, rows):
-        """One token a slot, y `[S, hidden]`: reads rows `rows` `[S]` of
-        layer `i` of the pools, advances them one token and writes them
-        back in place -> (out `[S, hidden]`, state). Idle slots carry row
-        0; several may, in any order."""
+    def ssm_slice(self, lp, y, start, length, state, i: int, row):
+        """`ssm_prompt` for ONE SLICE of a prompt (`prompt_slice`): y `[1,
+        C, hidden]` holds positions `start .. start + C - 1`; the mixer
+        starts from a zero state where `start` is 0 and from row `row` as
+        the slice before left it otherwise, and writes the row back."""
         raise NotImplementedError
+
+    def ssm_token(self, lp, y, state, i: int, rows, positions=None):
+        """One token a slot of a recurrent mixer, y `[S, hidden]`: reads
+        rows `rows` `[S]` of layer `i` of the pools, advances them one
+        token and writes them back in place -> (out `[S, hidden]`, state).
+        `positions` `[S]` are the tokens' positions, for a mixer that
+        encodes them (rotary linear attention). Idle slots carry row 0;
+        several may, in any order."""
+        raise NotImplementedError
+
+    # -- a model that stores entries at a rate (`rated`) -------------------
+
+    def store_token(self, lp, k_pool, rated, layer, block_tables, positions,
+                    block_size: int):
+        """A decode step's part of the rated entries: after the step's K
+        and V are in the pools, write what this token completes (an entry
+        every `stride` tokens) -> `rated`."""
+        raise NotImplementedError
+
+    def attend_slice(self, lp, q, k_pool, v_pool, rated, layer, block_table,
+                     start, block_size: int):
+        """One slice of a prompt (`prompt_slice`): the queries q `[1, C,
+        ...]` of positions `start .. start + C - 1` against the CACHE SO
+        FAR, layer `layer` of the pools through the sequence's table, this
+        slice's K and V already in it -> (ctx `[1, C, ctx]`, `rated` with
+        the entries this slice completes written)."""
+        raise NotImplementedError
+
+    def step_counters(self, positions, block_tables):
+        """A small pytree of counters of ONE decode step that follow from
+        the slots' positions (rows that took a sparse read, ...), or None;
+        `decode_step` returns it beside the layers' counters, as `(layers'
+        counters, this)`, and `step_facts` names both."""
+        return None
 
     def head(self, params: Params, x, prev_ids, eos_id: int):
         """Greedy next tokens [N] for the rows `x` [N, hidden]."""
@@ -258,7 +320,7 @@ def pattern_blocks(pattern: str):
 
 def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
                  positions: jax.Array, k_pool: jax.Array,
-                 v_pool: jax.Array, attend, state, ssm):
+                 v_pool: jax.Array, attend, state, ssm, rated=()):
     """`serve_layers` for a model of ONE mixer a block (`model.pattern`):
     the blocks one by one in the pattern's order, `h + mixer(norm(h))`
     each, every kind's parameters addressed in its own stack by the
@@ -266,7 +328,7 @@ def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
     (`*`) or the state pools (`M`). `attend` as in `serve_layers`;
     `ssm(i, lp, y, state) -> (out, state)` is the program's recurrent
     mixer (a whole prompt, or a token a slot). Returns (x, k_pool, v_pool,
-    the `E` blocks' counters stacked or None, state)."""
+    the `E` blocks' counters stacked or None, state, rated)."""
     stats = []
     with jax.named_scope("layers"):
         for kind, i in pattern_blocks(model.pattern):
@@ -282,39 +344,40 @@ def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
                 stats.append(st)
             elif kind == "*":
                 q, k, v = model.qkv(lp, y, positions)
-                ctx, k_pool, v_pool = attend(jnp.int32(i), lp, q, k, v,
-                                             k_pool, v_pool)
+                ctx, k_pool, v_pool, rated = attend(
+                    jnp.int32(i), lp, q, k, v, k_pool, v_pool, rated)
                 x = model.proj(lp, ctx, x)
             else:
                 raise ValueError(f"unknown block kind {kind!r}")
     stats = None if not stats or stats[0] is None else \
         jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
-    return x, k_pool, v_pool, stats, state
+    return x, k_pool, v_pool, stats, state, rated
 
 
 def serve_layers(model: ServeModel, params: Params, x: jax.Array,
                  positions: jax.Array, k_pool: jax.Array,
-                 v_pool: jax.Array, attend, state=(), ssm=None):
+                 v_pool: jax.Array, attend, state=(), ssm=None, rated=()):
     """The serve programs' layer loop: `x` through every block with the
     pools in the loop's carry: the model's leading layers one by one, then
     a scan over the stacked ones (a model whose layers are all alike has
     no leading ones, and the loop is the scan). `attend(l, lp, q, k, v,
-    kp, vp)` is the one part the programs differ in: it gets the layer
-    index, the layer's parameters and projections and the WHOLE pools,
-    writes k/v at (l, block, slot), and returns `(ctx, kp, vp)`. A model
-    of one mixer a block goes through `mixer_layers`, which also carries
-    `state`. Returns (x, k_pool, v_pool, the stacked layers' counters or
-    None, state)."""
+    kp, vp, rated)` is the one part the programs differ in: it gets the
+    layer index, the layer's parameters and projections, the WHOLE pools
+    and the pools of the entries stored at a rate (() for most models),
+    writes k/v at (l, block, slot), and returns `(ctx, kp, vp, rated)`. A
+    model of one mixer a block goes through `mixer_layers`, which also
+    carries `state`. Returns (x, k_pool, v_pool, the stacked layers'
+    counters or None, state, rated)."""
     if model.pattern is not None:
         return mixer_layers(model, params, x, positions, k_pool, v_pool,
-                            attend, state, ssm)
+                            attend, state, ssm, rated)
 
     def layer_body(carry, per_layer):
         h, kp, vp = carry
         lp, l = per_layer
         y = model.norm_attn(lp, h)
         q, k, v = model.qkv(lp, y, positions)
-        ctx, kp, vp = attend(l, lp, q, k, v, kp, vp)
+        ctx, kp, vp, _ = attend(l, lp, q, k, v, kp, vp, ())
         h = model.proj(lp, ctx, h)
         y = model.norm_mlp(lp, h)
         out, stats = model.mlp(lp, y, params, l)
@@ -328,7 +391,16 @@ def serve_layers(model: ServeModel, params: Params, x: jax.Array,
             carry, _ = layer_body(carry, (lp, jnp.int32(l)))
         (x, k_pool, v_pool), stats = jax.lax.scan(
             layer_body, carry, (model.layer_params(params), layers))
-    return x, k_pool, v_pool, stats, state
+    return x, k_pool, v_pool, stats, state, rated
+
+
+def _split_state(model: ServeModel, state):
+    """The programs' `state` as (the state row pools, the pools of the
+    entries stored at a rate): the engine carries them as one tuple, the
+    rated ones last."""
+    n = len(model.rated)
+    return (tuple(state[:len(state) - n]), tuple(state[len(state) - n:])) \
+        if n else (tuple(state), ())
 
 
 def prefill(model: ServeModel, params: Params, ids: jax.Array,
@@ -348,33 +420,112 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     sequence's `row` in them, which the prompt overwrites from a zero
     state (`ServeModel.ssm_prompt`: the padded tail leaves the state as
     position `length - 1` left it), and returns (tok, k_pool, v_pool,
-    state).
+    state). A model that walks its prompts in slices (`prompt_slice`) goes
+    through `prefill_sliced`.
     """
     from ..serving import kv_cache as kvc
 
+    if model.prompt_slice:
+        return prefill_sliced(model, params, ids, length, k_pool, v_pool,
+                              block_table, state, row,
+                              block_size=block_size, eos_id=eos_id)
     B, T = ids.shape
     adt = k_pool.dtype
     positions = jnp.arange(T, dtype=jnp.int32)[None]
     with jax.named_scope("embed"):
         x = model.embed(params, ids, positions).astype(adt)
 
-    def attend(l, lp, q, k, v, kp, vp):
+    def attend(l, lp, q, k, v, kp, vp, rated):
         kp = kvc.write_prefill_kv(kp, l, k[0].reshape(T, *kp.shape[3:]),
                                   block_table, block_size)
         vp = kvc.write_prefill_kv(vp, l, v[0].reshape(T, *vp.shape[3:]),
                                   block_table, block_size)
         with jax.named_scope("attention"):
             ctx = model.attend_prompt(lp, q, k, v)
-        return ctx, kp, vp
+        return ctx, kp, vp, rated
 
     def ssm(i, lp, y, st):
         return model.ssm_prompt(lp, y, length, st, i, row)
 
-    x, k_pool, v_pool, _, state = serve_layers(
+    x, k_pool, v_pool, _, state, _ = serve_layers(
         model, params, x, positions, k_pool, v_pool, attend, state, ssm)
     # the final norm is per row: the last real position alone goes through
     last = jnp.maximum(length, 1) - 1
     tok = model.head(params, x[0, last][None], ids[0, last][None], eos_id)
+    if state:
+        return tok, k_pool, v_pool, state
+    return tok, k_pool, v_pool
+
+
+def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
+                   length: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                   block_table: jax.Array, state=(), row=None, *,
+                   block_size: int, eos_id: int):
+    """`prefill` for a model that walks a prompt in SLICES
+    (`ServeModel.prompt_slice`): ids [1, T] go through the whole stack C =
+    min(T, prompt_slice) tokens at a time, in ONE program. A slice's K and
+    V are written (whole blocks: C is whole blocks) before its queries
+    attend to the cache so far through the sequence's table
+    (`attend_slice`, which also writes the rated entries the slice
+    completes), and a recurrent mixer starts each slice from the row the
+    slice before left (`ssm_slice`), so no temporary grows with T: a
+    32k-token prompt costs the memory of a 2k one. Only the slices that
+    hold real tokens run; the slice of position `length - 1` gives the
+    first token. Returns what `prefill` returns."""
+    from ..serving import kv_cache as kvc
+
+    _, T = ids.shape
+    C = min(T, int(model.prompt_slice))
+    if T % C or C % block_size:
+        raise ValueError(
+            f"a prompt walked in slices needs a bucket of whole slices of "
+            f"whole blocks: bucket {T}, slice {C}, block {block_size}")
+    adt = k_pool.dtype
+    rows_state, rated = _split_state(model, state)
+    per_slice = C // block_size
+    # the blocks a prompt of this bucket can own: what a slice's queries
+    # gather and score is bounded by the bucket, not by the table's width
+    block_table = block_table[:T // block_size]
+
+    def one(s, carry):
+        kp, vp, st, rt, last_x = carry
+        start = s * C
+        slice_ids = jax.lax.dynamic_slice_in_dim(ids, start, C, axis=1)
+        positions = (start + jnp.arange(C, dtype=jnp.int32))[None]
+        blocks = jax.lax.dynamic_slice_in_dim(
+            block_table, s * per_slice, per_slice)
+        with jax.named_scope("embed"):
+            x = model.embed(params, slice_ids, positions).astype(adt)
+
+        def attend(l, lp, q, k, v, kp, vp, rt):
+            kp = kvc.write_prefill_kv(kp, l, k[0].reshape(C, *kp.shape[3:]),
+                                      blocks, block_size)
+            vp = kvc.write_prefill_kv(vp, l, v[0].reshape(C, *vp.shape[3:]),
+                                      blocks, block_size)
+            with jax.named_scope("attention"):
+                ctx, rt = model.attend_slice(lp, q, kp, vp, rt, l,
+                                             block_table, start, block_size)
+            return ctx, kp, vp, rt
+
+        def ssm(i, lp, y, st):
+            return model.ssm_slice(lp, y, start, length, st, i, row)
+
+        x, kp, vp, _, st, rt = serve_layers(
+            model, params, x, positions, kp, vp, attend, st, ssm, rt)
+        # the last real position lies in the last slice that runs
+        at = jnp.clip(jnp.maximum(length, 1) - 1 - start, 0, C - 1)
+        return kp, vp, st, rt, x[0, at]
+
+    hidden = jax.eval_shape(
+        lambda p: model.embed(p, ids[:, :1], jnp.zeros((1, 1), jnp.int32)),
+        params).shape[-1]
+    live = jnp.clip(-(-length // C), 1, T // C)
+    k_pool, v_pool, rows_state, rated, last_x = jax.lax.fori_loop(
+        0, live, one,
+        (k_pool, v_pool, rows_state, rated, jnp.zeros((hidden,), adt)))
+    last = jnp.maximum(length, 1) - 1
+    tok = model.head(params, last_x[None], ids[0, last][None], eos_id)
+    state = rows_state + rated
     if state:
         return tok, k_pool, v_pool, state
     return tok, k_pool, v_pool
@@ -436,19 +587,27 @@ def mha_cached(q: jax.Array, keys: jax.Array, vals: jax.Array,
 
 def cached_attention(attend, q: jax.Array, k_pool: jax.Array,
                      v_pool: jax.Array, layer: jax.Array,
-                     block_tables: jax.Array, pos: jax.Array) -> jax.Array:
+                     block_tables: jax.Array, pos: jax.Array,
+                     rated=()) -> jax.Array:
     """Attention of the query rows q `[S, W, ...]` over a gathered copy of
     every slot's whole table: layer `layer` of the pools through
     block_tables `[S, MB]`, key positions `<= pos[s, w]`, by `attend(q,
     keys, vals, pos)`, a model's `attend_cached` with its layer's
     parameters bound -> `[S, W, ctx]`. What `prefill_chunk` and
     `verify_step` run everywhere and `decode_step` off the TPU, and what a
-    paged kernel is compared with on it."""
+    paged kernel is compared with on it. The pools of entries stored at a
+    rate (`rated`) are gathered through the same tables and handed over as
+    `extra`."""
     from ..serving import kv_cache as kvc
 
     keys = kvc.gather_kv(k_pool, layer, block_tables)   # [S, M, width]
     vals = kvc.gather_kv(v_pool, layer, block_tables)
+    with jax.named_scope("kv_gather"):
+        extra = tuple(kvc.gather_rated(p, layer, block_tables)
+                      for p in rated)
     with jax.named_scope("attention"):
+        if extra:
+            return attend(q, keys, vals, pos, extra)
         return attend(q, keys, vals, pos)
 
 
@@ -467,7 +626,9 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     counters or None: `ServeModel.mlp`). A model with recurrent state also
     takes its `state` pools and each slot's row `rows` [S] (0, the null
     row, for an idle slot), advances the rows in place
-    (`ServeModel.ssm_token`) and returns the pools as a fifth result."""
+    (`ServeModel.ssm_token`) and returns the pools as a fifth result; the
+    pools of entries stored at a rate (`ServeModel.rated`) are the last of
+    `state`, written by `store_token` and read by the attention pieces."""
     from ..ops.pallas import paged_attention as pa
     from ..serving import kv_cache as kvc
 
@@ -484,27 +645,37 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     # what the kernel's walk may fetch in one copy, counted once a step
     # and not once a layer
     tables = pa.with_runs(block_tables, k_pool, v_pool) if route else None
+    rows_state, rated = _split_state(model, state)
 
-    def attend(l, lp, q, k, v, kp, vp):
+    def attend(l, lp, q, k, v, kp, vp, rt):
         kp = kvc.write_token_kv(kp, l, k.reshape(S, *kp.shape[3:]),
                                 block_tables, positions, block_size)
         vp = kvc.write_token_kv(vp, l, v.reshape(S, *vp.shape[3:]),
                                 block_tables, positions, block_size)
+        if rt:
+            with jax.named_scope("attention"):
+                rt = model.store_token(lp, kp, rt, l, block_tables,
+                                       positions, block_size)
         if route:
             with jax.named_scope("attention"):
                 ctx = model.attend_paged(lp, q, kp, vp, l, tables,
-                                         positions)
+                                         positions, *((rt,) if rt else ()))
         else:
             ctx = cached_attention(
                 functools.partial(model.attend_cached, lp), q[:, None], kp,
-                vp, l, block_tables, positions[:, None])[:, 0]
-        return ctx, kp, vp
+                vp, l, block_tables, positions[:, None], rt)[:, 0]
+        return ctx, kp, vp, rt
 
     def ssm(i, lp, y, st):
-        return model.ssm_token(lp, y, st, i, rows)
+        return model.ssm_token(lp, y, st, i, rows, positions)
 
-    x, k_pool, v_pool, stats, state = serve_layers(
-        model, params, x, positions, k_pool, v_pool, attend, state, ssm)
+    x, k_pool, v_pool, stats, rows_state, rated = serve_layers(
+        model, params, x, positions, k_pool, v_pool, attend, rows_state,
+        ssm, rated)
+    counters = model.step_counters(positions, block_tables)
+    if counters is not None:
+        stats = (stats, counters)
+    state = rows_state + rated
     tok = model.head(params, x, ids, eos_id)
     if state:
         return tok, k_pool, v_pool, stats, state
@@ -545,7 +716,7 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
         x = model.embed(params, ids[0],
                         jnp.minimum(pos, model.max_len - 1)).astype(adt)
 
-    def attend(l, lp, q, k, v, kp, vp):
+    def attend(l, lp, q, k, v, kp, vp, rated):
         kp = kvc.write_chunk_kv(kp, l, k.reshape(C, *kp.shape[3:]),
                                 block_table, start, block_size)
         vp = kvc.write_chunk_kv(vp, l, v.reshape(C, *vp.shape[3:]),
@@ -554,10 +725,10 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
         ctx = cached_attention(
             functools.partial(model.attend_cached, lp), q[None], kp, vp, l,
             block_table[None], pos[None])[0]
-        return ctx, kp, vp
+        return ctx, kp, vp, rated
 
-    x, k_pool, v_pool, _, _ = serve_layers(model, params, x, pos, k_pool,
-                                           v_pool, attend)
+    x, k_pool, v_pool, *_ = serve_layers(model, params, x, pos, k_pool,
+                                         v_pool, attend)
     last = jnp.clip(length - 1 - start, 0, C - 1)
     tok = model.head(params, x[last][None], ids[0, last][None], eos_id)
     return tok, k_pool, v_pool
@@ -593,17 +764,17 @@ def verify_step(model: ServeModel, params: Params, ids: jax.Array,
         x = model.embed(params, ids,
                         jnp.minimum(pos, model.max_len - 1)).astype(adt)
 
-    def attend(l, lp, q, k, v, kp, vp):
+    def attend(l, lp, q, k, v, kp, vp, rated):
         kp = kvc.write_span_kv(kp, l, k.reshape(S, W, *kp.shape[3:]),
                                block_tables, positions, block_size)
         vp = kvc.write_span_kv(vp, l, v.reshape(S, W, *vp.shape[3:]),
                                block_tables, positions, block_size)
         ctx = cached_attention(functools.partial(model.attend_cached, lp),
                                q, kp, vp, l, block_tables, pos)
-        return ctx, kp, vp
+        return ctx, kp, vp, rated
 
-    x, k_pool, v_pool, _, _ = serve_layers(model, params, x, pos, k_pool,
-                                           v_pool, attend)
+    x, k_pool, v_pool, *_ = serve_layers(model, params, x, pos, k_pool,
+                                         v_pool, attend)
     tokens = model.head(params, x.reshape(S * W, -1), ids.reshape(S * W),
                         eos_id).reshape(S, W)
     return tokens, k_pool, v_pool

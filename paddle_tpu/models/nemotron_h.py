@@ -466,7 +466,7 @@ class NemotronHServe(_decoder.ServeModel):
             pool = pool.at[i, row].set(s[0])
         return out, (conv, pool)
 
-    def ssm_token(self, lp, y, state, i, rows):
+    def ssm_token(self, lp, y, state, i, rows, positions=None):
         """The convolution's tails are gathered and scattered (37 KB a
         row); the SSM states, 2 MB a row, are advanced where they lie by
         the kernel of ops/pallas/ssm_update.py on a TPU, and gathered,

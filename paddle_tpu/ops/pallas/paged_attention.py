@@ -87,8 +87,8 @@ from jax.experimental.pallas import tpu as pltpu
 from . import attention as _attention
 
 # which route each decode program's trace took ("paged" | "paged_gqa" |
-# "paged_latent" | "gather"), as attention's counts; DecodeEngine.status()
-# reports them
+# "paged_latent" | "paged_sparse" | "gather"), as attention's counts;
+# DecodeEngine.status() reports them
 GATE_COUNTS: collections.Counter = collections.Counter()
 
 # tokens a compute step: a multiple of 128, so scores [M, _CHUNK] fill
@@ -184,6 +184,18 @@ def use_paged_gqa(q: jax.Array, pool: jax.Array, heads: int,
             and heads % (32 // pool.dtype.itemsize) == 0)
 
 
+def use_paged_sparse(q: jax.Array, pool: jax.Array, heads: int,
+                     kv_heads: int) -> bool:
+    """Whether block-sparse grouped-query decode attention takes the
+    kernel: where `paged_gqa_attention` would, with a K/V head's query
+    heads whole sublane tiles (its walk is a K/V head's own) and a cache
+    narrow enough that its pools are taken as rows (`narrow`)."""
+    group = heads // max(kv_heads, 1)
+    return (use_paged_gqa(q, pool, heads, kv_heads)
+            and group % (32 // pool.dtype.itemsize) == 0
+            and narrow(_token_bytes(pool, pool)))
+
+
 def use_paged_latent(q: jax.Array, c_pool: jax.Array, r_pool: jax.Array,
                      heads: int) -> bool:
     """Whether latent decode attention takes the kernel: on one TPU, over
@@ -197,7 +209,7 @@ def use_paged_latent(q: jax.Array, c_pool: jax.Array, r_pool: jax.Array,
 
 
 def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
-          done_ref, zeroed, setup, step, bs):
+          done_ref, zeroed, setup, step, bs, lane_groups: int = 1):
     """One grid step's walk over slot `program_id(0)`'s live blocks of
     layer `layer_ref[0]` of `pools` (`_call_form`; `bs` tokens a block):
     chunk by chunk through the double buffers `bufs` (one `[2, chunk,
@@ -209,7 +221,11 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
     query operands; it runs AFTER the call's first copies are started, so
     that no DMA waits for it. `zeroed` are the buffers whose stale rows
     meet exact zero weights and so must be finite from the start. Returns
-    (query, the last carry: the first, for a slot that reads nothing)."""
+    (query, the last carry: the first, for a slot that reads nothing).
+    With `lane_groups` > 1 the grid's slots are (slot, lane group) pairs,
+    the group minor-most, each with a table of its own, and a copy takes
+    its group's share of a pool's lanes alone (a buffer is that wide): a
+    K/V head's walk over the blocks IT selected (`paged_sparse_attention`)."""
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
     chunk = bufs[0].shape[1]
@@ -241,9 +257,20 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
                 if take_runs else blk
             rows = pl.ds(pl.multiple_of(j * bs, bs), k * bs)
             for which, (hbm, dst) in enumerate(zip(pools, bufs)):
-                getattr(pltpu.make_async_copy(
-                    hbm.at[layer, src], dst.at[buf, rows],
-                    sems.at[buf, which]), act)()
+                def go(lanes=None, hbm=hbm, dst=dst, which=which):
+                    from_ = hbm.at[layer, src] if lanes is None \
+                        else hbm.at[layer, src, lanes]
+                    getattr(pltpu.make_async_copy(
+                        from_, dst.at[buf, rows], sems.at[buf, which]),
+                        act)()
+
+                if lane_groups == 1:
+                    go()
+                    continue
+                width = dst.shape[2]
+                for g in range(lane_groups):    # static lanes a branch
+                    pl.when(slot % lane_groups == g)(functools.partial(
+                        go, pl.ds(g * width, width)))
 
         def one(j, carry):
             copy(j, 1)
@@ -369,7 +396,7 @@ def _kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm, v_hbm,
 
 def _gqa_kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm,
                 v_hbm, o_ref, kbuf, vbuf, sems, done_ref, *, kv_heads: int,
-                scale: float, block_size: int):
+                scale: float, block_size: int, lane_groups: int = 1):
     heads, head_dim = q_ref.shape
     width = kbuf.shape[2]               # kv_heads * head_dim
     group = heads // kv_heads
@@ -391,7 +418,8 @@ def _gqa_kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm,
 
     (_, _, own), (m, l, acc) = _walk(
         layer_ref, tables_ref, pos_ref, lead_ref, (k_hbm, v_hbm),
-        (kbuf, vbuf), sems, done_ref, (vbuf,), setup, step, block_size)
+        (kbuf, vbuf), sems, done_ref, (vbuf,), setup, step, block_size,
+        lane_groups)
     # row h keeps its own K/V head's lanes; an inactive slot gives zeros
     ctx = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
     out = ctx[:, :head_dim]
@@ -484,10 +512,14 @@ def _call_form(kernel, layer, block_tables, positions, *pools):
         p.reshape(p.shape[0], -1, p.shape[3]) for p in pools)
 
 
-def _scratch(k_pool, v_pool):
+def _scratch(k_pool, v_pool, lane_groups: int = 1):
+    """The walk's double buffers, a chunk of a pool's lanes each (of ONE
+    lane group's share of them, where a copy takes no more)."""
     chunk = chunk_tokens(_token_bytes(k_pool, v_pool))
-    return [pltpu.VMEM((2, chunk, k_pool.shape[3]), k_pool.dtype),
-            pltpu.VMEM((2, chunk, v_pool.shape[3]), v_pool.dtype),
+    return [pltpu.VMEM((2, chunk, k_pool.shape[3] // lane_groups),
+                       k_pool.dtype),
+            pltpu.VMEM((2, chunk, v_pool.shape[3] // lane_groups),
+                       v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32)]
 
@@ -566,6 +598,55 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         interpret=interpret,
         name="paged_gqa_attention",
     )(*scalars, q.reshape(n_slots, heads, head_dim), *pools)
+    return out.reshape(n_slots, heads * head_dim)
+
+
+def paged_sparse_attention(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, layer: jax.Array,
+                           tables: jax.Array, positions: jax.Array, *,
+                           heads: int, kv_heads: int,
+                           interpret: bool = False) -> jax.Array:
+    """Grouped-query decode attention in which every (slot, K/V head) reads
+    a list of blocks OF ITS OWN: q `[S, heads*D]` against layer `layer` of
+    the pools `[L, NB, BS, kv_heads*D]`; `tables` `[S*kv_heads, W]` holds,
+    for slot s and K/V head g in row `s*kv_heads + g`, the block ids that
+    pair reads, in the order its tokens count, and `positions`
+    `[S*kv_heads]` the index IN THAT LIST of the pair's newest token (the
+    tokens `0..positions` of the list are attended; the blocks before the
+    last are whole). A block-sparse layer hands over the blocks each K/V
+    head selected; a row that reads everything its own table for every
+    head. The walk is `paged_gqa_attention`'s (`_walk`: runs of
+    consecutive ids in one copy) over ONE K/V head's lanes of the pools,
+    which is all a copy takes; a pair whose list starts with the null
+    block gets zeros. -> `[S, heads*D]` in q's dtype."""
+    n_slots = q.shape[0]
+    head_dim = k_pool.shape[3] // kv_heads
+    group = heads // kv_heads
+    per_pair = lambda s, *_: (s, 0, 0)      # noqa: E731
+    kernel, scalars, pools = _call_form(
+        functools.partial(_gqa_kernel, kv_heads=1,
+                          scale=1.0 / math.sqrt(head_dim),
+                          block_size=k_pool.shape[2], lane_groups=kv_heads),
+        layer, tables, positions, k_pool, v_pool)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(n_slots * kv_heads,),
+            in_specs=[
+                pl.BlockSpec((None, group, head_dim), per_pair),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, group, head_dim), per_pair),
+            scratch_shapes=_scratch(k_pool, v_pool, kv_heads)),
+        out_shape=jax.ShapeDtypeStruct((n_slots * kv_heads, group, head_dim),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_sparse_attention",
+    )(*scalars, q.reshape(n_slots * kv_heads, group, head_dim), *pools)
     return out.reshape(n_slots, heads * head_dim)
 
 

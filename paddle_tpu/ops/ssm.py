@@ -1,5 +1,6 @@
-"""The state-space (Mamba-2, "SSD") recurrence and the causal depthwise
-convolution before it, in the two forms a served model needs.
+"""The recurrence of a recurrent mixer (a state-space layer, Mamba-2's
+"SSD"; or linear attention) and the causal depthwise convolution before a
+state-space layer, in the two forms a served model needs.
 
 A head h keeps a state `S` [P, N] (P the head's width, N the state size).
 With `a_t = dt_t * A_h` (A negative, dt positive) a token does
@@ -8,6 +9,16 @@ With `a_t = dt_t * A_h` (A negative, dt positive) a token does
     y_t = S_t C_t + D_h x_t                          C_t [N]
 
 B and C belong to a GROUP of heads (`G` groups, H/G heads each).
+
+The DECAY `exp(a_t)` has two forms, and the functions here are told it
+through `dt` and `A` alone:
+
+- input-dependent (Mamba-2): `dt_t` is a softplus of the token's own
+  projection, `A_h` a learned scalar a head;
+- fixed a head (linear attention with decay, "lightning attention"): `S_t
+  = exp(-s_h) S_{t-1} + v_t k_t^T`, `o_t = S_t q_t`, which is the recurrence
+  above with x = v, B = k, C = q, one group a head (G = H), `A_h = -s_h`,
+  `D = 0` and `dt_t = 1` for a token that counts (`linear_attention_args`).
 
 - `ssd_chunked`: a whole prompt. The sequence is cut into chunks of
   `chunk` tokens; inside a chunk the recurrence is the quadratic form
@@ -37,6 +48,18 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+
+def linear_attention_args(slopes: jax.Array, live: jax.Array):
+    """(dt, A, D) that make `ssd_chunked` / `ssd_step` the linear-attention
+    recurrence with a fixed decay a head: slopes `[H]` (a head forgets by
+    `exp(-slopes[h])` a token), `live` `[...]` bool: which positions count (padding leaves the state as it was: dt 0). Call with
+    x = v, Bm = k, Cm = q (scaled), one group a head."""
+    f32 = jnp.float32
+    slopes = slopes.astype(f32)
+    dt = jnp.broadcast_to(live.astype(f32)[..., None],
+                          live.shape + slopes.shape)
+    return dt, -slopes, jnp.zeros_like(slopes)
 
 
 def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:

@@ -398,9 +398,11 @@ class DecodeEngine:
         # cold start's account read it long after
         with _tracing.boot_span("boot.engine_build") as facts:
             self._build(params, model_cfg, config, draft)
+            n_rows = len(self._row_specs)
             facts.update(weight_bytes=_nbytes(self.params.values()),
-                         pool_bytes=_nbytes(self._pools),
-                         state_bytes=_nbytes(self._state))
+                         pool_bytes=_nbytes(self._pools)
+                         + _nbytes(self._state[n_rows:]),
+                         state_bytes=_nbytes(self._state[:n_rows]))
 
     def _build(self, params, model_cfg, config, draft):
         from ..models import decoder as _decoder
@@ -425,7 +427,7 @@ class DecodeEngine:
         # any reuse feature runs the synchronous scheduler (_loop_sync)
         self._sync = bool(self.prefill_chunk or self.spec_k)
         rows = max(self.config.decode_slots) + 1    # + the null row
-        if model.state_pools(rows, np.dtype("float32")):
+        if model.state_pools(rows, np.dtype("float32")) or model.rated:
             # a sequence's recurrent state is ONE row, the state after its
             # last token: nothing in it can be shared, resumed or unwound
             refused = [why for on, why in (
@@ -446,7 +448,8 @@ class DecodeEngine:
             ) if on]
             if refused:
                 raise ValueError(
-                    "a model with recurrent state cannot be served with "
+                    "a model with recurrent state, or with entries stored "
+                    "at a rate beside a token's two, cannot be served with "
                     + "; ".join(refused))
         if self.config.precision not in ("f32", "bf16"):
             _precision.get_policy(self.config.precision)  # typo => full msg
@@ -464,13 +467,20 @@ class DecodeEngine:
             layers=model.kv_layers, widths=model.stored,
             max_len=max_len, block_size=self.config.block_size,
             num_blocks=self.config.num_blocks,
-            dtype=str(np.dtype(self._compute_dtype)))
+            dtype=str(np.dtype(self._compute_dtype)),
+            rated=tuple(model.rated))
         # the model's state pools ((shape, dtype), ...): () for a model
         # whose sequences keep nothing but their blocks, and then no
         # program takes or returns anything more than it did
-        self._state_specs = tuple(
+        self._row_specs = tuple(
             (tuple(shape), np.dtype(dt)) for shape, dt in
             model.state_pools(rows, np.dtype(self._compute_dtype)))
+        # the programs carry the pools of the entries stored at a rate
+        # (`ServeModel.rated`: they follow the block tables, not the rows)
+        # behind the row pools, in the one donated `state`
+        self._state_specs = self._row_specs + tuple(
+            (shape, np.dtype(self._compute_dtype))
+            for shape in self.kv_cfg.rated_pool_shapes)
         # resolved grid lives on the ENGINE, never written back into
         # the caller's config (a DecodeConfig reused across engines
         # must not carry the first engine's derived bucket set)
@@ -620,8 +630,8 @@ class DecodeEngine:
         self._pools = init_pools(self.kv_cfg)
         self._state = tuple(jnp.zeros(shape, dt)
                             for shape, dt in self._state_specs)
-        self._state_alloc = StateRowAllocator(rows, self._state_specs) \
-            if self._state_specs else None
+        self._state_alloc = StateRowAllocator(rows, self._row_specs) \
+            if self._row_specs else None
         self._draft_pools = init_pools(self._draft_kv_cfg) \
             if draft is not None else None
         # annotated with the reuse subtype so the lock-order analyzer
@@ -673,6 +683,8 @@ class DecodeEngine:
             _memwatch.register_provider(own("kv_pool"), _kv_arrays),
             _memwatch.register_provider(own("params"), _param_arrays)]
         if self._state_specs:
+            # the row pools and, behind them, the pools of the entries
+            # stored at a rate
             def _state_arrays():
                 eng = ref()
                 return () if eng is None else list(eng._state)
@@ -1630,6 +1642,7 @@ class DecodeEngine:
             # a row of its own, which this prefill overwrites from a zero
             # state: nothing of the row's last holder is read
             req.state_row = self._state_alloc.alloc()
+        if self._state_specs:
             state = (self._state, np.int32(req.state_row))
         t0 = time.perf_counter()
         wait = None

@@ -38,6 +38,16 @@ is `BS` whole sublane tiles of the entry's lanes. The scheduler
 that decides WHICH sequences own which blocks lives in
 `serving/decode.py`.
 
+A model may store MORE than the two entries a token: an entry at a RATE,
+one every `stride` tokens (`KVCacheConfig.rated`: compressed keys that a
+block-sparse attention scores before it reads any K/V). Such an entry has
+a pool of its own, `[L, NB, BS/stride * width]`: the `BS/stride` entries of
+a block side by side in the lanes of ONE row, so that a block of the
+sequence's one table names them as it names its K and V (they are freed
+with it), a block's entries are one contiguous span and the pool is whole
+tiles whatever `BS/stride` is (`write_token_rated`, `write_blocks_rated`,
+`gather_rated`).
+
 Block 0 is reserved as the *null block*: padded/inactive decode slots
 and out-of-range table entries all read and write it, so a fixed-shape
 executable needs no validity branches — the attention length mask
@@ -55,6 +65,7 @@ from __future__ import annotations
 import bisect
 import collections
 import dataclasses
+import fractions
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -64,8 +75,10 @@ import numpy as np
 
 __all__ = ["KVCacheConfig", "BlockAllocator", "NoBlocksError", "run_chunks",
            "StateRowAllocator", "NULL_ROW",
-           "init_pools", "write_token_kv", "write_prefill_kv",
-           "write_chunk_kv", "write_span_kv", "gather_kv",
+           "init_pools", "init_rated_pools", "write_token_kv",
+           "write_prefill_kv", "write_chunk_kv", "write_span_kv",
+           "write_token_rated", "write_blocks_rated", "gather_kv",
+           "gather_rated",
            "NULL_BLOCK", "PREFILL_WRITE_UNITS"]
 
 NULL_BLOCK = 0
@@ -113,6 +126,16 @@ class KVCacheConfig:
     widths: Optional[Tuple[int, int]] = None
     kv_heads: int = 0
     head_dim: int = 0
+    # entries stored at a rate beside the two: (lanes, stride in tokens)
+    # each, one entry every `stride` tokens (`ServeModel.rated`)
+    rated: Tuple[Tuple[int, int], ...] = ()
+
+    def __post_init__(self):
+        for width, stride in self.rated:
+            if stride < 1 or self.block_size % stride:
+                raise ValueError(
+                    f"an entry every {stride} tokens needs a block size "
+                    f"of whole strides, got {self.block_size}")
 
     @property
     def max_blocks_per_seq(self) -> int:
@@ -148,13 +171,32 @@ class KVCacheConfig:
             raise ValueError(f"the pools differ in shape: {a} and {b}")
         return a
 
-    def bytes_per_token(self) -> int:
-        """Stored bytes of one token in ONE layer, both entries."""
+    @property
+    def rated_pool_shapes(self) -> Tuple[Tuple[int, int, int], ...]:
+        """`[L, NB, BS/stride * width]` of each rated entry's pool: a
+        block's entries side by side in the lanes of one row."""
+        return tuple((int(self.layers), int(self.num_blocks),
+                      int(self.block_size) // int(stride) * int(width))
+                     for width, stride in self.rated)
+
+    def walk_bytes_per_token(self) -> int:
+        """Stored bytes of one token in ONE layer in the TWO pools the
+        decode kernels walk (what decides their chunk: `narrow`)."""
         return sum(self.entry_widths) * jnp.dtype(self.dtype).itemsize
 
+    def bytes_per_token(self):
+        """Stored bytes of one token in ONE layer, EVERY entry: the two a
+        token, and a rated entry's `width / stride` (an int where that is
+        whole)."""
+        lanes = sum(self.entry_widths) + sum(
+            fractions.Fraction(int(w), int(s)) for w, s in self.rated)
+        n = lanes * jnp.dtype(self.dtype).itemsize
+        return int(n) if n == int(n) else float(n)
+
     def pool_bytes(self) -> int:
-        """Device bytes of BOTH pools."""
-        return sum(math.prod(s) for s in self.pool_shapes) * \
+        """Device bytes of ALL the block pools, the rated entries' too."""
+        return sum(math.prod(s) for s in
+                   self.pool_shapes + self.rated_pool_shapes) * \
             jnp.dtype(self.dtype).itemsize
 
 
@@ -302,7 +344,7 @@ class BlockAllocator:
         self._owned: Dict[int, bool] = {}
         # blocks the kernels' walk takes a chunk: `run_chunks`' unit
         self.per_chunk = blocks_per_chunk(cfg.block_size,
-                                          cfg.bytes_per_token())
+                                          cfg.walk_bytes_per_token())
         self._runs = self._chunks = 0       # of the live tables
 
     def free_blocks(self) -> int:
@@ -391,6 +433,13 @@ class BlockAllocator:
             # what a token stores in a layer: lanes of the two entries
             # (the model's say) and their bytes
             "entry_widths": list(self.cfg.entry_widths),
+            # entries stored at a rate beside them: lanes, stride in tokens
+            # and bytes a token a layer of each
+            "rated_entries": [
+                {"width": int(w), "stride": int(s),
+                 "bytes_per_token_layer":
+                     w * jnp.dtype(self.cfg.dtype).itemsize / s}
+                for w, s in self.cfg.rated],
             "bytes_per_token_layer": self.cfg.bytes_per_token(),
         }
 
@@ -470,6 +519,13 @@ def init_pools(cfg: KVCacheConfig) -> Tuple[jax.Array, jax.Array]:
     dt = jnp.dtype(cfg.dtype)
     a, b = cfg.pool_shapes
     return jnp.zeros(a, dt), jnp.zeros(b, dt)
+
+
+def init_rated_pools(cfg: KVCacheConfig) -> Tuple[jax.Array, ...]:
+    """The zeroed pools of the entries stored at a rate,
+    `cfg.rated_pool_shapes`; () where the model stores none."""
+    dt = jnp.dtype(cfg.dtype)
+    return tuple(jnp.zeros(shape, dt) for shape in cfg.rated_pool_shapes)
 
 
 @jax.named_scope("kv_write")
@@ -573,6 +629,48 @@ def gather_kv(pool: jax.Array, layer: jax.Array,
     s, mb = block_tables.shape
     ctx = pool[layer, block_tables]                  # [S, MB, BS, *tok]
     return ctx.reshape(s, mb * pool.shape[2], *pool.shape[3:])
+
+
+def write_token_rated(pool: jax.Array, layer: jax.Array, entry: jax.Array,
+                      block_tables: jax.Array, index: jax.Array,
+                      due: jax.Array, per_block: int) -> jax.Array:
+    """Put one rated entry a slot into layer `layer` of its pool `[L, NB,
+    per_block * width]`: entry `[S, width]` becomes the sequence's entry
+    number `index` `[S]` (block `index // per_block` of its table, lanes
+    `index % per_block` of that block's row) where `due` `[S]` is true; a
+    slot that is not due writes the null block. The block's row is read,
+    its `width` lanes replaced and the row written back: the pool keeps
+    its shape, whole tiles whatever `per_block` is."""
+    width = entry.shape[-1]
+    blk = jnp.take_along_axis(
+        block_tables, (index // per_block)[:, None], axis=1)[:, 0]
+    blk = jnp.where(due, blk, NULL_BLOCK)
+    row = pool[layer, blk]                                  # [S, E * width]
+    at = jnp.arange(per_block * width, dtype=jnp.int32) // width
+    row = jnp.where(at[None, :] == (index % per_block)[:, None],
+                    jnp.tile(entry.astype(pool.dtype), (1, per_block)), row)
+    return pool.at[layer, blk].set(row)
+
+
+def write_blocks_rated(pool: jax.Array, layer: jax.Array,
+                       entries: jax.Array, blocks: jax.Array) -> jax.Array:
+    """Whole blocks of rated entries into layer `layer` of their pool:
+    entries `[n * per_block, width]`, the entries of `n` consecutive blocks
+    of a sequence, to the pool rows `blocks` `[n]` (block ids)."""
+    n = blocks.shape[0]
+    return pool.at[layer, blocks].set(
+        entries.reshape(n, -1).astype(pool.dtype))
+
+
+def gather_rated(pool: jax.Array, layer: jax.Array,
+                 block_tables: jax.Array) -> jax.Array:
+    """Every slot's rated entries through its table: `[L, NB, E * width]` x
+    `[S, MB]` -> `[S, MB, E * width]`, a block's `E` entries side by side
+    in the lanes as they lie (a reader slices the lanes; no re-layout).
+    The caller's scope names it: `kv_gather` where a program gathers a
+    whole context, the model's own (`select`) where it reads them to pick
+    blocks."""
+    return pool[layer, block_tables]
 
 
 def build_block_table(blocks: Sequence[int], max_blocks: int) -> np.ndarray:

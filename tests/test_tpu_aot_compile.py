@@ -833,3 +833,132 @@ def test_nemotron_serve_program_fits_and_updates_the_state_in_place(
     else:
         assert kvc.PREFILL_WRITE_UNITS == {"blocks": 2}
         assert sum("kv_block_write" in k for k in kernels) == 2, kernels
+
+
+_SALA_SLOTS, _SALA_CONTEXT, _SALA_BLOCK = 32, 49152, 64
+
+
+@pytest.fixture(scope="module")
+def minicpm_8l(v5e):
+    from paddle_tpu.models import minicpm_sala
+    from paddle_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = minicpm_sala.MiniCPMSALAConfig(mixers="SLLLLLLS",
+                                         max_len=_SALA_CONTEXT)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = jax.eval_shape(lambda k: minicpm_sala.init(k, cfg)[0],
+                            jax.random.key(0))
+    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    sm = cfg.serve_model()
+    kv = KVCacheConfig(
+        layers=sm.kv_layers, widths=sm.stored, max_len=_SALA_CONTEXT,
+        block_size=_SALA_BLOCK, rated=sm.rated,
+        num_blocks=_SALA_SLOTS * (_SALA_CONTEXT // _SALA_BLOCK) + 1)
+    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
+    # the programs' `state`: the row pools, then the rated entries' pools
+    state = tuple(sds(shape, dt) for shape, dt in
+                  sm.state_pools(_SALA_SLOTS + 1, jnp.bfloat16)) \
+        + tuple(sds(shape, jnp.dtype(kv.dtype))
+                for shape in kv.rated_pool_shapes)
+    return cfg, params, pools, state, kv, sds
+
+
+@pytest.mark.parametrize("program", ["decode@32", "prefill@32768",
+                                     "prefill@16384"])
+def test_minicpm_sala_serve_program_fits_whatever_the_prompts_length(
+        minicpm_8l, program, monkeypatch):
+    """MiniCPM-SALA's stage of 8 layers (2 block-sparse, 6 lightning, the
+    published widths and the whole vocabulary) as the cell serves it: 5.64
+    GB of weights, K/V and compressed-key pools of 32 x 49152 tokens (3.32
+    GB) and 33 state rows (0.42 GB), all donated and written where they
+    lie. The decode step takes the block-sparse walk's kernel in both
+    sparse layers and the row update's in all six lightning layers; the
+    prefill program walks its prompt in slices of 2048 tokens, so its
+    temporaries are the same at 16k and at 32k tokens (one pass over 32k
+    tokens would hold float32 scores of 32 x T x T: 137 GB)."""
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.ops.pallas import ssm_update as SU
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, state, kv, sds = minicpm_8l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_SALA_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _SALA_CONTEXT // _SALA_BLOCK
+    for counts in (PA.GATE_COUNTS, SU.GATE_COUNTS, kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32), state, sds((n,), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32), state, sds((), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4, 6)).lower(params,
+                                                       *args).compile()
+    assert kv.pool_shapes == ((2, 24577, 64, 256),) * 2
+    assert kv.rated_pool_shapes == ((2, 24577, 1024),)
+    assert kv.bytes_per_token() == 1056 and kv.walk_bytes_per_token() == 1024
+    assert [s.shape for s in state] == [(6, 33, 32, 128, 128),
+                                        (2, 24577, 1024)]
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    # weights 5.64 GB + block pools 3.32 GB + state 0.42 GB resident
+    assert 9.3e9 < ma.argument_size_in_bytes < 9.5e9, ma
+    # the stated budget of the temporaries: a decode step a quarter of a
+    # GB, a prompt 2 GB WHATEVER its length
+    budget = 0.25e9 if kind == "decode" else 2.0e9
+    assert ma.temp_size_in_bytes < budget, ma
+    assert planned < 11.5e9, ma
+    state_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in state)
+    assert kv.pool_bytes() + state_bytes - 2 * 24577 * 1024 * 2 \
+        <= ma.alias_size_in_bytes, ma
+    text = compiled.as_text()
+    for pool in pools:
+        assert not _pool_movers(text, pool.shape), pool.shape
+    # the lightning states, 0.42 GB: the decode program's kernel updates
+    # the rows where they lie; a prompt's slice writes its one row a layer
+    # into the donated pool
+    moved = collections.Counter(
+        op for op, _ in _pool_movers(text, state[0].shape))
+    assert moved == ({} if kind == "decode"
+                     else {"dynamic-update-slice": 6}), moved
+    kernels = _kernels(text)
+    if kind == "decode":
+        assert PA.GATE_COUNTS == {"paged_sparse": 1}, PA.GATE_COUNTS
+        assert SU.GATE_COUNTS == {"kernel": 6}, SU.GATE_COUNTS
+        assert sum("/attention/" in k for k in kernels) == 2, kernels
+        assert sum("/ssm/scan/" in k for k in kernels) == 6, kernels
+    else:
+        # a slice's K and V go in a block at a time: two sparse layers
+        assert kvc.PREFILL_WRITE_UNITS == {"blocks": 4}
+        assert not PA.GATE_COUNTS and not SU.GATE_COUNTS
+
+
+def test_the_sparse_walk_compiles_for_v5e(v5e, monkeypatch):
+    """The block-sparse walk alone: a copy takes ONE K/V head's 128 lanes
+    of a block's 256, by a static lane slice a branch, which the
+    interpreter cannot refuse and Mosaic can."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    for bs in (16, 64):
+        fn = jax.jit(lambda q, k, v, l, t, p: PA.paged_sparse_attention(
+            q, k, v, l, t, p, heads=32, kv_heads=2))
+        fn.lower(sds((32, 4096)), sds((2, 513, bs, 256)),
+                 sds((2, 513, bs, 256)), sds((), np.int32),
+                 sds((64, 128), np.int32), sds((64,), np.int32)).compile()
