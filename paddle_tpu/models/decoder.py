@@ -243,6 +243,15 @@ class ServeModel:
 
     # -- a model that stores entries at a rate (`rated`) -------------------
 
+    def rated_tables(self, x, tables, rated, positions, block_size: int):
+        """`tables` (`paged_attention.Tables`, or None off the kernels'
+        route) as `attend_paged` gets them in a step that reads rated
+        entries: a model whose kernel walks THEIR pool through the table
+        adds what that walk reads beside it, once a step and not once a
+        layer, and counts which way it reads them
+        (`paged_attention.GATE_COUNTS`)."""
+        return tables
+
     def store_token(self, lp, k_pool, rated, layer, block_tables, positions,
                     block_size: int):
         """A decode step's part of the rated entries: after the step's K
@@ -646,6 +655,9 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     # and not once a layer
     tables = pa.with_runs(block_tables, k_pool, v_pool) if route else None
     rows_state, rated = _split_state(model, state)
+    if rated:
+        tables = model.rated_tables(x, tables, rated, positions,
+                                    block_size)
 
     def attend(l, lp, q, k, v, kp, vp, rt):
         kp = kvc.write_token_kv(kp, l, k.reshape(S, *kp.shape[3:]),

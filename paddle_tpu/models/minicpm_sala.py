@@ -276,9 +276,11 @@ def block_params(params: Params, kind: str, i: int) -> Params:
 
 # Layer scopes: `ln`; a sparse layer's `qkv`, `kv_write`, `attention`
 # (holding `kc_write`: the compressed key a token or a slice completes, and
-# `select`: the compressed keys' scores and the top-k), `proj`; a lightning
-# layer's `ssm` (holding `ssm_in`, `scan`, `state_read` / `state_write`,
-# `ssm_out`); `mlp`; `head`. tests/test_minicpm_sala.py holds the list.
+# `select`: the compressed keys' scores, in a decode step on a TPU by the
+# kernel that reads them where they lie, the top-k and the pairs' tables),
+# `proj`; a lightning layer's `ssm` (holding `ssm_in`, `scan`, `state_read`
+# / `state_write`, `ssm_out`); `mlp`; `head`. tests/test_minicpm_sala.py
+# holds the list.
 
 
 def _head_norm(x, gain, heads: int, eps: float):
@@ -328,15 +330,17 @@ def top_mask(score, exists, k: int):
                             <= left))
 
 
-def select_blocks(cfg: MiniCPMSALAConfig, q, kc, n, block_size: int):
-    """Which selection blocks each query reads. q `[S, kv_heads, group, D]`
+def block_scores(cfg: MiniCPMSALAConfig, q, kc, n, block_size: int):
+    """What the selection ranks the blocks by. q `[S, kv_heads, group, D]`
     (normalised, unscaled), `kc` the sequences' compressed keys as they lie
     in their pool's rows through the table, `[S, MB, E * W]` (or `[MB, E *
     W]`: one sequence all the queries share), E = block_size / stride
     entries a cache block of W = kv_heads * D lanes; `n` `[S]` the tokens
-    each query sees (its position + 1). Returns the taken blocks as a mask
-    `[S, kv_heads, NSB]`, min(topk, the blocks that exist) of them a row. A
-    K/V head's query heads select together; ties go to the lower index."""
+    each query sees (its position + 1). A K/V head's query heads each take
+    a softmax over the windows the query sees and sum it; a selection
+    block scores the largest sum of the windows that overlap it ->
+    `[S, kv_heads, NSB]` float32 (on a TPU a decode step reads the same
+    from the pool where it lies: `paged_attention.paged_select_scores`)."""
     f32 = jnp.float32
     S, G, R, D = q.shape
     stride, sb = cfg.kernel_stride, cfg.sel_block
@@ -378,7 +382,17 @@ def select_blocks(cfg: MiniCPMSALAConfig, q, kc, n, block_size: int):
     # a block alone, the block before
     first_of_next = jnp.pad(P[0][..., 1:], [(0, 0), (0, 0), (0, 1)])
     score = jnp.maximum(jnp.max(P, axis=0), first_of_next)  # [S, G, MB]
-    score = jnp.max(score.reshape(S, G, NSB, per_sel), axis=-1)
+    return jnp.max(score.reshape(S, G, NSB, per_sel), axis=-1)
+
+
+def take_blocks(cfg: MiniCPMSALAConfig, score, n):
+    """The taken blocks of `score` `[S, kv_heads, NSB]` (`block_scores`)
+    for queries that see `n` `[S]` tokens, as a mask: the first
+    `init_blocks` and those that hold the newest `window` tokens always,
+    the rest of min(topk, the blocks that exist) by score; ties go to the
+    lower index."""
+    sb = cfg.sel_block
+    NSB = score.shape[-1]
     b = jnp.arange(NSB, dtype=jnp.int32)[None, :]
     exists = b <= ((n - 1) // sb)[:, None]
     forced = (b < cfg.init_blocks) \
@@ -389,13 +403,29 @@ def select_blocks(cfg: MiniCPMSALAConfig, q, kc, n, block_size: int):
                     min(cfg.topk, NSB))
 
 
+def select_blocks(cfg: MiniCPMSALAConfig, q, kc, n, block_size: int):
+    """Which selection blocks each query reads: `take_blocks` of
+    `block_scores` (their arguments) -> a mask `[S, kv_heads, NSB]`,
+    min(topk, the blocks that exist) of them a row. A K/V head's query
+    heads select together."""
+    return take_blocks(cfg, block_scores(cfg, q, kc, n, block_size), n)
+
+
 def taken_indices(mask, k: int):
     """A mask `[..., N]` of at most `k` taken blocks as their indices in
     ascending order `[..., k]`, `N` (past the last) where fewer are
-    taken."""
+    taken. No sort and no gather: the i-th taken block is as many blocks
+    in as there are blocks with at most i taken up to and with them, and
+    that count is a product with a triangle (0 and 1 in bf16, summed in
+    float32: exact)."""
     N = mask.shape[-1]
-    at = jnp.where(mask, jnp.arange(N, dtype=jnp.int32), N)
-    return jnp.sort(at, axis=-1)[..., :k]
+    at = jnp.arange(N, dtype=jnp.int32)
+    upto = jnp.dot(mask.astype(jnp.bfloat16),
+                   (at[:, None] <= at[None, :]).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    return jnp.sum(upto[..., None, :]
+                   <= jnp.arange(k, dtype=jnp.int32)[:, None],
+                   axis=-1, dtype=jnp.int32)
 
 
 class MiniCPMSALAServe(_decoder.ServeModel):
@@ -492,6 +522,27 @@ class MiniCPMSALAServe(_decoder.ServeModel):
         return "paged_sparse" if pa.use_paged_sparse(
             x, k_pool, self.heads, self.kv_heads) else None
 
+    def rated_tables(self, x, tables, rated, positions, block_size):
+        """How a decode step reads the compressed keys, said and counted
+        once a step: where the kernel takes them
+        (`paged_attention.use_paged_select`) the tables with what its
+        walk goes by counted (`paged_attention.with_rows`, under the scope
+        `select` as all of the selection is), which is what `attend_paged`
+        goes by; else the tables as they are, and the gather of every
+        slot's whole table."""
+        from ..ops.pallas import paged_attention as pa
+
+        cfg = self.cfg
+        paged = tables is not None and pa.use_paged_select(
+            x, rated[0], cfg.heads, cfg.kv_heads,
+            block_size // cfg.kernel_stride, cfg.sel_block // block_size,
+            tables.ids.shape[1])
+        pa.GATE_COUNTS["select_paged" if paged else "select_gather"] += 1
+        if not paged:
+            return tables
+        with jax.named_scope("select"):
+            return pa.with_rows(tables, positions, block_size)
+
     def store_token(self, lp, k_pool, rated, layer, block_tables, positions,
                     block_size):
         """The compressed key a decode step completes: where position p
@@ -523,26 +574,65 @@ class MiniCPMSALAServe(_decoder.ServeModel):
                            cfg.heads // cfg.kv_heads, cfg.head_dim)
             return select_blocks(cfg, qh, kc, n, block_size)
 
+    def scores_where_they_lie(self, q, pool, layer, tables, positions,
+                              block_size, interpret=False):
+        """`block_scores` of the query rows q `[S, heads * D]` from the
+        pool of compressed keys through `tables`
+        (`paged_attention.with_rows`): by the kernel that walks the live
+        pieces (`paged_select_scores`), or, in a step whose tables are
+        too many pieces for that to pay (`Tables.few`: a pool that has
+        fragmented), by the gather of every slot's whole table; the same
+        scores either way, chosen in the program by what the tables
+        say."""
+        from ..ops.pallas import paged_attention as pa
+        from ..serving import kv_cache as kvc
+
+        cfg = self.cfg
+        S = q.shape[0]
+
+        def gathered():
+            return block_scores(
+                cfg, q.reshape(S, cfg.kv_heads, cfg.heads // cfg.kv_heads,
+                               cfg.head_dim),
+                kvc.gather_rated(pool, layer, tables.ids), positions + 1,
+                block_size)
+
+        return jax.lax.cond(tables.few, lambda: pa.paged_select_scores(
+            q, pool, layer, tables, positions, kv_heads=cfg.kv_heads,
+            stride=cfg.kernel_stride, block_size=block_size,
+            interpret=interpret), gathered)
+
     def attend_paged(self, lp, q, k_pool, v_pool, layer, block_tables,
                      positions, rated=()):
         """Selection, then the walk over the taken blocks: every (slot,
         K/V head) gets a table of its own, the blocks it reads in
         ascending order (a row at or under `dense_len`: its own table),
-        and the position of its newest token in that list."""
+        and the position of its newest token in that list. The blocks'
+        scores come from the compressed keys where they lie
+        (`scores_where_they_lie`) where the step's tables carry the
+        pieces of that walk (`Tables.rows`: `rated_tables` found its gate
+        open), else from a gather of every slot's whole table of them;
+        one `take_blocks` either way."""
         from ..ops.pallas import paged_attention as pa
         from ..serving import kv_cache as kvc
 
         cfg = self.cfg
         q, gate = self._ungate(q)
-        ids = pa.with_runs(block_tables, k_pool, v_pool).ids
+        block_tables = pa.with_runs(block_tables, k_pool, v_pool)
+        ids = block_tables.ids
         S, MB = ids.shape
         bs = k_pool.shape[2]
         G, sb = cfg.kv_heads, cfg.sel_block
         per_sel = sb // bs
         n = positions + 1
-        with jax.named_scope("select"):
-            kc = kvc.gather_rated(rated[0], layer, ids)
-        mask = self._selection(q, kc, n, bs)
+        if block_tables.rows is not None:
+            with jax.named_scope("select"):
+                mask = take_blocks(cfg, self.scores_where_they_lie(
+                    q, rated[0], layer, block_tables, positions, bs), n)
+        else:
+            with jax.named_scope("select"):
+                kc = kvc.gather_rated(rated[0], layer, ids)
+            mask = self._selection(q, kc, n, bs)
         with jax.named_scope("select"):
             K = min(cfg.topk, mask.shape[-1])
             idx = taken_indices(mask, K)
@@ -551,10 +641,11 @@ class MiniCPMSALAServe(_decoder.ServeModel):
             width = max(K * per_sel, dense_blocks)
             at = (idx[..., None] * per_sel
                   + jnp.arange(per_sel, dtype=jnp.int32)).reshape(S, G, -1)
-            taken = jnp.where(
-                at < MB, jnp.take_along_axis(
-                    jnp.broadcast_to(ids[:, None, :], (S, G, MB)),
-                    jnp.minimum(at, MB - 1), axis=2), 0)
+            # the table's entry `at`, the null block past its end: one
+            # compare a (taken, table) pair, cheaper than a gather of ids
+            taken = jnp.sum(jnp.where(
+                at[..., None] == jnp.arange(MB, dtype=jnp.int32),
+                ids[:, None, None, :], 0), axis=-1, dtype=jnp.int32)
             taken = jnp.pad(taken, [(0, 0), (0, 0),
                                     (0, width - K * per_sel)])
             own = jnp.pad(ids[:, :dense_blocks],

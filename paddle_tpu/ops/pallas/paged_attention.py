@@ -69,6 +69,17 @@ takes whole). Only blocks the slot owns are ever fetched, the pieces put
 the same bytes in the same buffer rows as the single blocks would, and
 start and wait of a chunk read the same numbers: the result does not depend
 on where a sequence's blocks lie.
+
+An entry stored at a RATE (serving/kv_cache.py `KVCacheConfig.rated`: the
+compressed keys a block-sparse attention scores before it reads any K or V)
+lies a block a ROW, `[L, NB, E * W]`, NB whole tiles of 8 rows, and a row
+is under a tile, which is the least a copy can address.
+`paged_select_scores` walks such a pool by the same discipline with units
+of its own (`Tables.rows`, `_select_kernel`): a PIECE is up to
+`_PIECE` consecutive block ids, fetched in one copy from the tile of its
+first block on, `_ROWS` rows of the layer or `_SHORT_ROWS` where the piece
+is short, and a piece's scores are turned to their place in the table in
+VMEM. Only the block scores leave the chip's VMEM.
 """
 
 from __future__ import annotations
@@ -87,8 +98,10 @@ from jax.experimental.pallas import tpu as pltpu
 from . import attention as _attention
 
 # which route each decode program's trace took ("paged" | "paged_gqa" |
-# "paged_latent" | "paged_sparse" | "gather"), as attention's counts;
-# DecodeEngine.status() reports them
+# "paged_latent" | "paged_sparse" | "gather"), as attention's counts, and,
+# of a program whose model scores a rated entry before it reads K and V, how
+# it reads that entry ("select_paged": `paged_select_scores` | "select_gather":
+# every slot's whole table gathered); DecodeEngine.status() reports them
 GATE_COUNTS: collections.Counter = collections.Counter()
 
 # tokens a compute step: a multiple of 128, so scores [M, _CHUNK] fill
@@ -96,6 +109,26 @@ GATE_COUNTS: collections.Counter = collections.Counter()
 _CHUNK = 256
 # a NARROW cache: `_CHUNK` tokens of both pools are under this many bytes
 _NARROW_CHUNK_BYTES = 512 * 1024
+
+# a pool of rated entries `[L, NB, lanes]` holds a block a row, and a copy
+# addresses whole tiles: `_ROW_TILE` rows of 2- and of 4-byte lanes alike
+# (`kv_cache.RATED_ROW_TILE` makes NB whole tiles). A copy of its walk takes
+# `_ROWS` blocks from the tile of a piece's first block on, so a piece is
+# `_PIECE` blocks at most; one of `_SHORT_PIECE` at most takes `_SHORT_ROWS`
+_ROW_TILE = 8
+_ROWS = 128
+_PIECE = _ROWS - _ROW_TILE
+_SHORT_ROWS = 32
+_SHORT_PIECE = _SHORT_ROWS - _ROW_TILE
+# what that walk may hold of a core's memories (`use_paged_select`): the
+# tables and the pieces' lengths in the scalar memory (half of a v5e's
+# 1 MiB), two copies and a slot's scores in the vector memory (half of
+# what a kernel is given unasked)
+# a piece costs that walk what this many entries of a gathered table cost
+# the gather it replaces (a v5e: 0.45 us a short piece, 0.024 us an entry)
+_PIECE_ENTRIES = 20
+_SELECT_SMEM_BYTES = 512 * 1024
+_SELECT_VMEM_BYTES = 8 * 1024 * 1024
 
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -194,6 +227,49 @@ def use_paged_sparse(q: jax.Array, pool: jax.Array, heads: int,
     return (use_paged_gqa(q, pool, heads, kv_heads)
             and group % (32 // pool.dtype.itemsize) == 0
             and narrow(_token_bytes(pool, pool)))
+
+
+def _select_scratch(pool, max_blocks: int, heads: int, kv_heads: int,
+                    per_block: int):
+    """`paged_select_scores`' scratch: two copies of `_ROWS` rows, a
+    slot's scores a (tile of the table, K/V head, entry) and one tile more
+    (a piece's last lanes may lie in the tile after its first), the
+    copies' semaphores, the count of pieces consumed."""
+    tiles = -(-max_blocks // _ROWS)
+    return [pltpu.VMEM((2, _ROWS, pool.shape[2]), pool.dtype),
+            pltpu.VMEM((tiles + 1, kv_heads, per_block, heads // kv_heads,
+                        _ROWS), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32)]
+
+
+def use_paged_select(q: jax.Array, pool: jax.Array, heads: int,
+                     kv_heads: int, per_block: int, per_sel: int,
+                     max_blocks: int) -> bool:
+    """Whether a block-sparse attention's scores of its compressed keys
+    take the kernel (`paged_select_scores`): on one TPU, over a pool of
+    rated entries `[L, NB, per_block * kv_heads * D]` whose row is a
+    block's `per_block` entries of every K/V head's D lanes, D whole lane
+    tiles, NB whole tiles of rows and a copy's rows at least, a K/V
+    head's query heads whole sublane tiles, a selection block ONE cache
+    block (`per_sel` 1: the kernel scores a row a block), and tables
+    `[slots of q, max_blocks]` that the scalar memory holds twice over
+    beside scores that the vector memory holds."""
+    if pool.ndim != 3 or pool.dtype.itemsize not in (2, 4) \
+            or q.dtype != pool.dtype or per_sel != 1:
+        return False
+    width = per_block * kv_heads * 128
+    group = heads // max(kv_heads, 1)
+    if not (_on_one_tpu(q) and pool.shape[2] % width == 0
+            and group * kv_heads == heads and pool.shape[1] >= _ROWS
+            and pool.shape[1] % _ROW_TILE == 0
+            and group % (32 // pool.dtype.itemsize) == 0):
+        return False
+    vmem = sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+               for x in _select_scratch(pool, max_blocks, heads, kv_heads,
+                                        per_block)[:2])
+    return (2 * q.shape[0] * max_blocks * 4 <= _SELECT_SMEM_BYTES
+            and vmem <= _SELECT_VMEM_BYTES)
 
 
 def use_paged_latent(q: jax.Array, c_pool: jax.Array, r_pool: jax.Array,
@@ -464,10 +540,19 @@ class Tables(NamedTuple):
     layer loop (inside, XLA counts again every layer: three fusions and a
     reduce-window, 2 to 11 us at the cells' tables); the kernels take one
     wherever they take block tables, and count for a bare array in the
-    call."""
+    call. `rows` `[S, MB]` is the same for the walk over a pool of rated
+    entries (`with_rows`, `paged_select_scores`), whose pieces are cut at
+    the runs' ends and not at a chunk's: of every entry, how many ids from
+    it on are consecutive, `_PIECE` at most, and `few` (a bool) whether
+    the live pieces are few enough for that walk to beat a gather of the
+    whole tables. Both are there exactly where a step's model found that
+    walk's gate open (`ServeModel.rated_tables`), and are how the layers'
+    `attend_paged` know."""
 
     ids: jax.Array
     runs: Optional[jax.Array]     # None: a wide cache's walk reads none
+    rows: Optional[jax.Array] = None    # None: no rated entry is walked
+    few: Optional[jax.Array] = None
 
 
 def with_runs(block_tables, k_pool, v_pool) -> Tables:
@@ -491,6 +576,49 @@ def with_runs(block_tables, k_pool, v_pool) -> Tables:
     return Tables(ids, 1 + jnp.sum(
         jnp.cumprod(follows, axis=-1, dtype=jnp.int32), axis=-1,
         dtype=jnp.int32))
+
+
+def _next_after(marks: jax.Array, none: int) -> jax.Array:
+    """Of every entry j of `marks` `[S, N]` (bool), the index of the first
+    mark AFTER j, `none` where there is none. A cumulative minimum over a
+    whole row is a window as wide as the row on a TPU (N * N compares), so
+    it is taken in two levels, of 32 and of `N / 32`."""
+    span = 32
+    slots, n = marks.shape
+    pad = -n % span
+    at = jnp.where(marks, jnp.arange(n, dtype=jnp.int32)[None, :], none)
+    at = jnp.pad(at, ((0, 0), (0, pad)), constant_values=none)
+    at = at.reshape(slots, -1, span)
+    inside = lax.cummin(at, axis=2, reverse=True)       # from j on, its span
+    later = lax.cummin(inside[:, :, 0], axis=1, reverse=True)
+    later = jnp.pad(later[:, 1:], ((0, 0), (0, 1)), constant_values=none)
+    from_j = jnp.minimum(inside, later[:, :, None]).reshape(slots, -1)
+    return jnp.pad(from_j[:, 1:n], ((0, 0), (0, 1)), constant_values=none)
+
+
+def with_rows(tables: Tables, positions: jax.Array,
+              block_size: int) -> Tables:
+    """`tables` with what the walk over a pool of rated entries goes by,
+    counted once a step and not once a layer as `runs` is. `rows[s, j]`:
+    how many of the ids `j, j + 1, ...` of slot s's table follow each
+    other, `_PIECE` at most, which is the piece that walk fetches in one
+    copy when it stands at `j` (it then stands at `j + rows[s, j]`: no
+    search in the kernel). `few`: whether the live blocks (slot s at
+    `positions[s]`) are so few pieces that the walk costs less than a
+    gather of every slot's whole table, a piece `_PIECE_ENTRIES` entries
+    of it."""
+    ids = tables.ids
+    slots, mb = ids.shape
+    starts = jnp.pad(ids[:, 1:] != ids[:, :-1] + 1, ((0, 0), (1, 0)),
+                     constant_values=True)
+    at = jnp.arange(mb, dtype=jnp.int32)[None, :]
+    live = jnp.where(ids[:, 0] == 0, 0,
+                     jnp.minimum(positions // block_size + 1, mb))
+    pieces = jnp.sum(starts & (at < live[:, None]), dtype=jnp.int32) \
+        + jnp.sum(live // _PIECE, dtype=jnp.int32)
+    return tables._replace(
+        rows=jnp.clip(_next_after(starts, mb) - at, 1, _PIECE),
+        few=pieces * _PIECE_ENTRIES <= slots * mb)
 
 
 def _call_form(kernel, layer, block_tables, positions, *pools):
@@ -690,3 +818,236 @@ def paged_latent_attention(q_latent: jax.Array, q_rope: jax.Array,
         interpret=interpret,
         name="paged_latent_attention",
     )(*scalars, q_latent, q_rope, *pools)
+
+
+def _select_kernel(layer_ref, tables_ref, pos_ref, rows_ref, q_ref, pool_hbm,
+                   o_ref, buf, sc, sems, done_ref, *, kv_heads: int,
+                   per_block: int, stride: int, block_size: int,
+                   scale: float):
+    """One grid step: slot `program_id(0)`'s block scores. The slot's live
+    blocks are walked in PIECES of consecutive ids (`rows_ref`: `Tables
+    .rows`), a piece one copy of layer `layer_ref[0]`'s rows from the tile
+    of its first block on (the pool's last rows at the latest): `_ROWS` of
+    them, or `_SHORT_ROWS` where the piece is at most `_SHORT_PIECE`
+    blocks. Double-buffered as `_walk`'s chunks are: the next piece, or
+    the next slot's first, is in flight while this one is scored. A
+    product is a K/V head's query heads against 128 keys: one entry's of a
+    long copy's rows, or every entry's of a short copy's, side by side.
+    Its columns are turned to their place in the TABLE (a lane a block;
+    `sc` `[tiles, kv_heads, per_block, group, 128]`), unseen entries
+    masked, so that the softmax and the blocks' scores read whole tiles
+    whatever the pieces were."""
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    n_blocks, lanes = pool_hbm.shape[1:]
+    head_dim = lanes // (per_block * kv_heads)
+    group = q_ref.shape[0] // kv_heads
+    max_blocks = tables_ref.shape[1]
+    layer = layer_ref[0]
+    f32 = jnp.float32
+
+    def live_blocks(slot):
+        live = jnp.minimum(pos_ref[slot] // block_size + 1, max_blocks)
+        return jnp.where(tables_ref[slot, 0] == 0, 0, live)
+
+    def piece_blocks(slot, j):
+        return jnp.minimum(rows_ref[slot, j], live_blocks(slot) - j)
+
+    def first_row(slot, j, rows):
+        return jnp.minimum(tables_ref[slot, j] // _ROW_TILE * _ROW_TILE,
+                           n_blocks - rows)
+
+    def each_size(slot, j, do):
+        """`do(rows)` for the copy that holds the piece at `j`: start,
+        wait and the products agree on it."""
+        short = piece_blocks(slot, j) <= _SHORT_PIECE
+        pl.when(short)(functools.partial(do, _SHORT_ROWS))
+        pl.when(jnp.logical_not(short))(functools.partial(do, _ROWS))
+
+    def copy(slot, j, b, act):
+        def do(rows):
+            at = pl.multiple_of(first_row(slot, j, rows), _ROW_TILE)
+            getattr(pltpu.make_async_copy(
+                pool_hbm.at[layer, pl.ds(at, rows)],
+                buf.at[b, pl.ds(0, rows)], sems.at[b]), act)()
+
+        each_size(slot, j, do)
+
+    def start_next_slot(b):
+        @pl.when(s + 1 < n_slots)
+        def _():
+            @pl.when(live_blocks(s + 1) > 0)
+            def _():
+                copy(s + 1, 0, b, "start")
+
+    @pl.when(s == 0)
+    def _():
+        done_ref[0] = 0
+        # rows past a piece are scored and masked: finite from the start
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+        @pl.when(live_blocks(0) > 0)
+        def _():
+            copy(0, 0, 0, "start")
+
+    done = done_ref[0]          # pieces consumed so far: the buffers' turn
+    n = live_blocks(s)
+    seen_entries = (pos_ref[s] + 1) // stride
+    lane = lax.broadcasted_iota(jnp.int32, (1, _ROWS), 1)
+
+    @pl.when(n == 0)
+    def _():
+        start_next_slot(done % 2)
+
+    def score(b, j, k, rows):
+        """The piece of `k` blocks at table entry `j`, in the first `rows`
+        rows of buffer `b`: buffer row `lead + i` holds entry `j + i`,
+        which belongs in lane `at + i` of tile `tile` of the scores, or in
+        the tile after."""
+        lead = tables_ref[s, j] - first_row(s, j, rows)
+        tile, at = j // _ROWS, j % _ROWS
+        side = _ROWS // rows        # entries side by side in a product
+        # of the two tiles' lanes, those this piece fills and the query
+        # sees: entry 0 is no window, and a query sees the windows it
+        # sees the last token of
+        seen = []
+        for e in range(per_block):
+            seen.append([])
+            for half in range(2):
+                here = lane + half * _ROWS
+                ent = (tile * _ROWS + here) * per_block + e
+                seen[e].append((here >= at) & (here < at + k) & (ent >= 1)
+                               & (ent < seen_entries))
+        for g in range(kv_heads):
+            for e0 in range(0, per_block, side):
+                first = [(min(e0 + i, per_block - 1) * kv_heads + g)
+                         * head_dim for i in range(side)]
+                keys = [buf[b, :rows, c:c + head_dim] for c in first]
+                product = lax.dot_general(
+                    q_ref[g * group:(g + 1) * group, :],
+                    keys[0] if side == 1 else jnp.concatenate(keys, axis=0),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=f32) * scale
+                for e in range(e0, min(e0 + side, per_block)):
+                    part = pltpu.roll(
+                        product, (at - lead - (e - e0) * rows) % _ROWS, 1)
+                    # lanes before the piece keep what earlier pieces put
+                    # there; lanes after it are masked until a later
+                    # piece fills them
+                    sc[tile, g, e] = jnp.where(
+                        lane < at, sc[tile, g, e],
+                        jnp.where(seen[e][0], part, _MASKED))
+                    sc[tile + 1, g, e] = jnp.where(seen[e][1], part,
+                                                   _MASKED)
+
+    def piece(carry):
+        j, i = carry
+        b = (done + i) % 2
+        k = piece_blocks(s, j)
+
+        @pl.when(j + k < n)
+        def _():
+            copy(s, j + k, 1 - b, "start")
+
+        @pl.when(j + k >= n)
+        def _():
+            start_next_slot(1 - b)
+
+        copy(s, j, b, "wait")
+        each_size(s, j, functools.partial(score, b, j, k))
+        return j + k, i + 1
+
+    _, pieces = lax.while_loop(lambda c: c[0] < n, piece,
+                               (jnp.int32(0), jnp.int32(0)))
+    done_ref[0] = done + pieces
+
+    # the group's softmax over all it sees, summed over its heads; a block
+    # scores the largest of its own entries and the first of the next block
+    tiles = (n + _ROWS - 1) // _ROWS
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    for g in range(kv_heads):
+        def highest(t, m, g=g):
+            for e in range(per_block):
+                m = jnp.maximum(m, sc[t, g, e])
+            return m
+
+        top = jnp.max(lax.fori_loop(
+            0, tiles, highest, jnp.full((group, _ROWS), _MASKED, f32)),
+            axis=1, keepdims=True)
+
+        def weigh(t, total, g=g, top=top):
+            for e in range(per_block):
+                v = sc[t, g, e]
+                p = jnp.where(v > 0.5 * _MASKED, jnp.exp(v - top), 0.0)
+                sc[t, g, e] = p
+                total = total + p
+            return total
+
+        total = jnp.maximum(jnp.sum(lax.fori_loop(
+            0, tiles, weigh, jnp.zeros((group, _ROWS), f32)),
+            axis=1, keepdims=True), 1e-30)
+
+        def blocks(i, nxt, g=g, total=total):
+            t = tiles - 1 - i       # from the last: a block needs the next
+            share = [jnp.sum(sc[t, g, e] / total, axis=0, keepdims=True)
+                     for e in range(per_block)]
+            own = functools.reduce(jnp.maximum, share)
+            after = jnp.where(lane == _ROWS - 1, nxt,
+                              pltpu.roll(share[0], _ROWS - 1, 1))
+            o_ref[t, g:g + 1, :] = jnp.maximum(own, after)
+            return jnp.sum(jnp.where(lane == 0, share[0], 0.0), axis=1,
+                           keepdims=True)
+
+        lax.fori_loop(0, tiles, blocks, jnp.zeros((1, 1), f32))
+
+
+def paged_select_scores(q: jax.Array, pool: jax.Array, layer: jax.Array,
+                        tables: Tables, positions: jax.Array, *,
+                        kv_heads: int, stride: int, block_size: int,
+                        interpret: bool = False) -> jax.Array:
+    """What a block-sparse attention's selection scores its blocks by,
+    read where the compressed keys lie: q `[S, heads * D]` (normalised,
+    unscaled) against layer `layer` of the pool of rated entries `[L, NB,
+    E * kv_heads * D]` (E = `block_size / stride` entries a block, entry e
+    the window that completes in the e-th group of `stride` tokens: none
+    in the first) through `tables` (`with_rows`: ids `[S, MB]` and the
+    pieces' lengths). Slot s at position `positions[s]` sees the entries
+    `1 .. (positions[s] + 1) // stride - 1`; a K/V head's query heads each
+    take a softmax over them (`1/sqrt(D)`, float32) and sum it, and a
+    block scores the largest sum of its own entries and the FIRST entry of
+    the next block -> `[S, kv_heads, MB]` float32, 0 for a block the slot
+    sees no entry of and for a slot whose table starts with the null block
+    (`models/minicpm_sala.block_scores` is the same on gathered keys)."""
+    n_slots, mb = tables.ids.shape
+    lanes = pool.shape[2]
+    per_block = block_size // stride
+    head_dim = lanes // (per_block * kv_heads)
+    heads = q.shape[1] // head_dim
+    tiles = -(-mb // _ROWS)
+    out = pl.pallas_call(
+        functools.partial(_select_kernel, kv_heads=kv_heads,
+                          per_block=per_block, stride=stride,
+                          block_size=block_size,
+                          scale=1.0 / math.sqrt(head_dim)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_slots,),
+            in_specs=[
+                pl.BlockSpec((None, heads, head_dim),
+                             lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, tiles, kv_heads, _ROWS),
+                                   lambda s, *_: (s, 0, 0, 0)),
+            scratch_shapes=_select_scratch(pool, mb, heads, kv_heads,
+                                           per_block)),
+        out_shape=jax.ShapeDtypeStruct((n_slots, tiles, kv_heads, _ROWS),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_select_scores",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tables.ids,
+      positions.astype(jnp.int32), tables.rows,
+      q.reshape(n_slots, heads, head_dim), pool)
+    return jnp.swapaxes(out, 1, 2).reshape(n_slots, kv_heads, -1)[..., :mb]

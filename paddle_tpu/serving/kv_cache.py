@@ -83,6 +83,9 @@ __all__ = ["KVCacheConfig", "BlockAllocator", "NoBlocksError", "run_chunks",
 
 NULL_BLOCK = 0
 NULL_ROW = 0
+# rows of a TPU tile, of 2- and of 4-byte lanes alike: a pool that holds a
+# block a row has whole tiles of them (`KVCacheConfig.rated_pool_shapes`)
+RATED_ROW_TILE = 8
 
 # which unit each traced whole-prompt write took ("blocks" | "rows"), one
 # count a call of write_prefill_kv: a prefill program counts two, K and V.
@@ -173,9 +176,16 @@ class KVCacheConfig:
 
     @property
     def rated_pool_shapes(self) -> Tuple[Tuple[int, int, int], ...]:
-        """`[L, NB, BS/stride * width]` of each rated entry's pool: a
-        block's entries side by side in the lanes of one row."""
-        return tuple((int(self.layers), int(self.num_blocks),
+        """`[L, NB8, BS/stride * width]` of each rated entry's pool: a
+        block's entries side by side in the lanes of one row, a block a
+        row, and the rows made up to whole tiles of `RATED_ROW_TILE` (NB8:
+        `num_blocks` rounded up; no table names the rows past `num_blocks`
+        and `pool_bytes` does not count them). A pool of whole tiles lies
+        on a TPU as its shape says, so a kernel that walks a layer's rows
+        (`ops/pallas/paged_attention.py paged_select_scores`) takes it
+        where it lies; `[2, 24577, 1024]` lay with its LAYERS minor."""
+        rows = -(-int(self.num_blocks) // RATED_ROW_TILE) * RATED_ROW_TILE
+        return tuple((int(self.layers), rows,
                       int(self.block_size) // int(stride) * int(width))
                      for width, stride in self.rated)
 
@@ -194,9 +204,11 @@ class KVCacheConfig:
         return int(n) if n == int(n) else float(n)
 
     def pool_bytes(self) -> int:
-        """Device bytes of ALL the block pools, the rated entries' too."""
-        return sum(math.prod(s) for s in
-                   self.pool_shapes + self.rated_pool_shapes) * \
+        """Device bytes of ALL the block pools' `num_blocks` blocks, the
+        rated entries' too."""
+        rated = sum(l * int(self.num_blocks) * lanes
+                    for l, _, lanes in self.rated_pool_shapes)
+        return (sum(math.prod(s) for s in self.pool_shapes) + rated) * \
             jnp.dtype(self.dtype).itemsize
 
 

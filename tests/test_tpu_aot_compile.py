@@ -906,10 +906,10 @@ def test_minicpm_sala_serve_program_fits_whatever_the_prompts_length(
                        donate_argnums=(3, 4, 6)).lower(params,
                                                        *args).compile()
     assert kv.pool_shapes == ((2, 24577, 64, 256),) * 2
-    assert kv.rated_pool_shapes == ((2, 24577, 1024),)
+    assert kv.rated_pool_shapes == ((2, 24584, 1024),)     # whole tiles
     assert kv.bytes_per_token() == 1056 and kv.walk_bytes_per_token() == 1024
     assert [s.shape for s in state] == [(6, 33, 32, 128, 128),
-                                        (2, 24577, 1024)]
+                                        (2, 24584, 1024)]
     ma = compiled.memory_analysis()
     planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                + ma.output_size_in_bytes - ma.alias_size_in_bytes)
@@ -935,9 +935,26 @@ def test_minicpm_sala_serve_program_fits_whatever_the_prompts_length(
                      else {"dynamic-update-slice": 6}), moved
     kernels = _kernels(text)
     if kind == "decode":
-        assert PA.GATE_COUNTS == {"paged_sparse": 1}, PA.GATE_COUNTS
+        assert PA.GATE_COUNTS == {"paged_sparse": 1, "select_paged": 1}, \
+            PA.GATE_COUNTS
         assert SU.GATE_COUNTS == {"kernel": 6}, SU.GATE_COUNTS
-        assert sum("/attention/" in k for k in kernels) == 2, kernels
+        # a sparse layer: the compressed keys' scores read where they lie,
+        # under `select` and no other scope, then the taken blocks' walk;
+        # the gather of every slot's whole table is the other branch of
+        # ONE conditional a sparse layer (`Tables.few` chooses in the
+        # program: a pool that has fragmented)
+        assert sum("/attention/" in k for k in kernels) == 4, kernels
+        select = [k for k in kernels if "/paged_select_scores/" in k]
+        assert len(select) == 2 and all(
+            "/attention/select/" in k for k in select), kernels
+        assert len(re.findall(r" conditional\(", text)) == 2
+        # nor is the pool turned around for the kernel: its blocks are
+        # whole tiles of rows (`kv_cache.RATED_ROW_TILE`), so it lies as
+        # its shape says and the kernel takes a layer's rows where they
+        # lie (`[2, 24577, 1024]` lay with its LAYERS minor)
+        assert "bf16[2,24584,1024]{2,1,0:T(8,128)(2,1)}" in text
+        assert "copy" not in [
+            op for op, _ in _pool_movers(text, state[1].shape)]
         assert sum("/ssm/scan/" in k for k in kernels) == 6, kernels
     else:
         # a slice's K and V go in a block at a time: two sparse layers
@@ -962,3 +979,42 @@ def test_the_sparse_walk_compiles_for_v5e(v5e, monkeypatch):
         fn.lower(sds((32, 4096)), sds((2, 513, bs, 256)),
                  sds((2, 513, bs, 256)), sds((), np.int32),
                  sds((64, 128), np.int32), sds((64,), np.int32)).compile()
+
+
+@pytest.mark.parametrize("dtype,layers,nb,slots,mb", [
+    (jnp.bfloat16, 2, 24584, 32, 768), (jnp.bfloat16, 8, 4104, 32, 768),
+    (jnp.bfloat16, 3, 4104, 8, 100), (jnp.float32, 2, 1032, 32, 768),
+    (jnp.bfloat16, 1, 24584, 64, 1024), (jnp.bfloat16, 2, 24584, 4, 15000)])
+def test_the_selections_walk_compiles_for_v5e(v5e, monkeypatch, dtype,
+                                              layers, nb, slots, mb):
+    """The compressed keys' scores alone, at the cell's 32 slots over a
+    table of 768 blocks and at the largest tables and scores the gate
+    lets through (`use_paged_select`: the tables and the pieces' counts
+    are the scalar memory's, a slot's scores the vector memory's): a
+    block of the third pool is ONE row of 1024 lanes, under a tile, and a
+    copy addresses whole tiles (a row copy is what Mosaic refuses and the
+    interpreter cannot). The pool's blocks are whole tiles
+    (`kv_cache.RATED_ROW_TILE`), so the compiler lays it out as its shape
+    says and nothing of the pool's size is made beside it."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    pool = sds((layers, nb, 1024))
+    monkeypatch.setattr(PA, "_on_one_tpu", lambda x: True)
+    assert PA.use_paged_select(sds((slots, 4096)), pool, 32, 2, 4, 1, mb)
+    fn = jax.jit(lambda q, p, l, t, pos: PA.paged_select_scores(
+        q, p, l, PA.with_rows(PA.Tables(t, None), pos, 64), pos, kv_heads=2,
+        stride=16, block_size=64))
+    compiled = fn.lower(sds((slots, 4096)), pool, sds((), np.int32),
+                        sds((slots, mb), np.int32),
+                        sds((slots,), np.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    laid = re.search(r"entry_computation_layout=\{.*?\w+\[%d,%d,1024\]"
+                     r"\{([\d,]+)" % (layers, nb), text).group(1)
+    assert laid == "2,1,0", laid
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
