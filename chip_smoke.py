@@ -14,7 +14,7 @@ over. Numbers printed here are information, not benchmark results.
 
     python chip_smoke.py            one chip: device, program, train,
                                     long_seq, serve, serve_reuse,
-                                    serve_olmoe, serve_joyai,
+                                    serve_olmoe, serve_joyai, serve_xing4,
                                     serve_nemotron, paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
@@ -42,6 +42,7 @@ import time
 SEED = 0
 OLMOE_LOGIT_TOL = 0.25   # benchmarks/configs/olmoe_1b_7b.json argues it
 JOYAI_LOGIT_TOL = 0.4    # benchmarks/configs/joyai_llm_flash.json argues it
+XING4_LOGIT_TOL = 0.55   # benchmarks/configs/xing4_29b_a4b.json argues it
 NEMOTRON_LOGIT_TOL = 0.4  # benchmarks/configs/nemotron3_nano.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
@@ -336,6 +337,23 @@ def _joyai_reference_gaps(params, cfg, prompts, streams):
         streams, width)
 
 
+def _xing4_reference_gaps(params, cfg, prompts, streams):
+    """As `_joyai_reference_gaps`, against the benchmark's plain float32
+    model of four residual streams (benchmarks/reference/xing4_ref.py: the
+    maps and all 20 Sinkhorn rounds token by token, YaRN's frequencies, the
+    expanded attention; no cache, no code of models/xing4.py)."""
+    from benchmarks.reference import xing4_ref
+
+    import dataclasses
+
+    ref = dataclasses.asdict(cfg)       # the reference reads them by name
+    top = {k: v for k, v in params.items()
+           if not k.startswith(("blk.", "dense."))}
+    return xing4_ref.stream_gaps(
+        top, lambda i: xing4_ref.layer_of(params, ref, i), ref, prompts,
+        streams, 0)
+
+
 def _nemotron_reference_gaps(params, cfg, prompts, streams):
     """As `_olmoe_reference_gaps`, against the benchmark's plain float32
     Nemotron-H (benchmarks/reference/nemotron_h_ref.py: the recurrence
@@ -607,7 +625,7 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import bert, gpt, joyai, nemotron_h, olmoe
+    from paddle_tpu.models import bert, gpt, joyai, nemotron_h, olmoe, xing4
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
 
@@ -707,6 +725,30 @@ def run_one_chip() -> None:
         # layer bodies a prefill program, K and V (here `c` and the rotary
         # key) each, three programs
         assert info["checked"]["prefill_write"] == {"blocks": 12}, info
+
+    # Xing4.0-29B-A4B at its published widths (four residual streams of
+    # 3584 mixed by Sinkhorn-projected maps around JoyAI's block: latent
+    # attention with YaRN, top-4 of sigmoid-routed experts of 1024 and a
+    # shared one; vocab 131072), ONE dense and ONE expert layer of 16 of the
+    # 64 experts, so that the float32 set for the reference (5.2 GB) sits
+    # beside the served one: a prompt in one slice of 2048 (the 2048
+    # bucket), prompts walked in two slices over the latent cache (the 4096
+    # bucket), and 16 decode steps each through the latent kernel
+    xcfg = xing4.Xing4Config(layers=2, dense_layers=1, n_experts=16,
+                             max_len=4352)
+    prompts = [rng.randint(0, xcfg.vocab_size, n).tolist()
+               for n in (12, 200, 2100, 3000, 40, 2048)]
+    with phase("serve_xing4") as info:
+        serve_phase(info, xcfg, DecodeConfig(
+            block_size=16, num_blocks=8 * 272 + 1, decode_slots=(8,),
+            prefill_buckets=(2048, 4096)), prompts, max_new=16,
+            logit_tol=XING4_LOGIT_TOL, model=xing4,
+            reference_gaps=_xing4_reference_gaps)
+        assert info["checked"]["decode_attention"] == {"paged_latent": 1}, \
+            info
+        # two layer bodies a prefill program, `c` and the rotary key each,
+        # two programs
+        assert info["checked"]["prefill_write"] == {"blocks": 8}, info
 
     # Nemotron-3-Nano at its published widths (Mamba-2: 64 heads of 64, 8
     # groups, state 128; 32 query heads over 2 K/V heads of 128, no

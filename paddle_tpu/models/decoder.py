@@ -275,6 +275,47 @@ class ServeModel:
         counters, this)`, and `step_facts` names both."""
         return None
 
+    # -- the residual path ------------------------------------------------
+
+    def widen(self, params: Params, x):
+        """What a row CARRIES from block to block, from its embedding `x`
+        `[..., hidden]` (already in the served dtype): the embedding itself
+        unless the model carries more (several residual streams)."""
+        return x
+
+    def narrow(self, params: Params, x):
+        """The carried state -> the `[..., hidden]` that `head` reads."""
+        return x
+
+    def res_in(self, lp, h, which: str):
+        """Into a sub-layer (`which`: "attn", "mlp", or "ssm" in a
+        `pattern` model): the carried state `h` -> (the sub-layer's input
+        `[..., hidden]`, which its norm takes; what `res_out` is handed
+        back). Per row, as every piece."""
+        return h, h
+
+    def res_out(self, lp, kept, out, which: str):
+        """Out of a sub-layer: what `res_in` kept and the sub-layer's
+        output -> the carried state. `out` of "attn" is the attention's
+        context BEFORE its output projection: the default adds as it
+        projects (`proj`)."""
+        if which == "attn":
+            return self.proj(lp, out, kept)
+        return kept + out
+
+    def res_counters(self, stats, *kept):
+        """A block's counters: `mlp`'s `stats` with whatever the residual
+        maps of the block's sub-layers count (`kept`: what each `res_in`
+        of the block kept, in order), for a model whose maps count
+        something; `stats` as they are otherwise."""
+        return stats
+
+    def describe(self) -> Dict:
+        """What `DecodeEngine.status()["model"]` says of the model beyond
+        the cache's shape (residual streams, iterations of a projection);
+        {} for a model with nothing to add."""
+        return {}
+
     def head(self, params: Params, x, prev_ids, eos_id: int):
         """Greedy next tokens [N] for the rows `x` [N, hidden]."""
         raise NotImplementedError
@@ -327,6 +368,10 @@ def pattern_blocks(pattern: str):
         yield kind, i
 
 
+# a `pattern` block's kind -> what `res_in` / `res_out` call its sub-layer
+_SUB_LAYER = {"M": "ssm", "E": "mlp", "*": "attn"}
+
+
 def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
                  positions: jax.Array, k_pool: jax.Array,
                  v_pool: jax.Array, attend, state, ssm, rated=()):
@@ -342,22 +387,21 @@ def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
     with jax.named_scope("layers"):
         for kind, i in pattern_blocks(model.pattern):
             lp = model.block_params(params, kind, i)
-            y = model.norm(lp, x)
+            if kind not in _SUB_LAYER:
+                raise ValueError(f"unknown block kind {kind!r}")
+            u, kept = model.res_in(lp, x, _SUB_LAYER[kind])
+            y = model.norm(lp, u)
             if kind == "M":
                 with jax.named_scope("ssm"):
                     out, state = ssm(i, lp, y, state)
-                x = x + out
             elif kind == "E":
                 out, st = model.mlp(lp, y, params, i)
-                x = x + out
-                stats.append(st)
-            elif kind == "*":
-                q, k, v = model.qkv(lp, y, positions)
-                ctx, k_pool, v_pool, rated = attend(
-                    jnp.int32(i), lp, q, k, v, k_pool, v_pool, rated)
-                x = model.proj(lp, ctx, x)
+                stats.append(model.res_counters(st, kept))
             else:
-                raise ValueError(f"unknown block kind {kind!r}")
+                q, k, v = model.qkv(lp, y, positions)
+                out, k_pool, v_pool, rated = attend(
+                    jnp.int32(i), lp, q, k, v, k_pool, v_pool, rated)
+            x = model.res_out(lp, kept, out, _SUB_LAYER[kind])
     stats = None if not stats or stats[0] is None else \
         jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
     return x, k_pool, v_pool, stats, state, rated
@@ -375,8 +419,11 @@ def serve_layers(model: ServeModel, params: Params, x: jax.Array,
     and the pools of the entries stored at a rate (() for most models),
     writes k/v at (l, block, slot), and returns `(ctx, kp, vp, rated)`. A
     model of one mixer a block goes through `mixer_layers`, which also
-    carries `state`. Returns (x, k_pool, v_pool, the stacked layers'
-    counters or None, state, rated)."""
+    carries `state`. `x` is the CARRIED state (`ServeModel.widen`), which
+    every sub-layer reads and writes through the model's `res_in` /
+    `res_out`. Returns (x, k_pool, v_pool, the stacked layers' counters or
+    None, state, rated); where the leading layers count too,
+    `{"lead": [theirs], "stack": the stack's}`."""
     if model.pattern is not None:
         return mixer_layers(model, params, x, positions, k_pool, v_pool,
                             attend, state, ssm, rated)
@@ -384,22 +431,31 @@ def serve_layers(model: ServeModel, params: Params, x: jax.Array,
     def layer_body(carry, per_layer):
         h, kp, vp = carry
         lp, l = per_layer
-        y = model.norm_attn(lp, h)
+        u, kept_a = model.res_in(lp, h, "attn")
+        y = model.norm_attn(lp, u)
         q, k, v = model.qkv(lp, y, positions)
         ctx, kp, vp, _ = attend(l, lp, q, k, v, kp, vp, ())
-        h = model.proj(lp, ctx, h)
-        y = model.norm_mlp(lp, h)
+        h = model.res_out(lp, kept_a, ctx, "attn")
+        u, kept_m = model.res_in(lp, h, "mlp")
+        y = model.norm_mlp(lp, u)
         out, stats = model.mlp(lp, y, params, l)
-        return (h + out, kp, vp), stats
+        h = model.res_out(lp, kept_m, out, "mlp")
+        return (h, kp, vp), model.res_counters(stats, kept_a, kept_m)
 
     lead = model.lead_params(params)
     layers = jnp.arange(len(lead), k_pool.shape[0], dtype=jnp.int32)
     with jax.named_scope("layers"):
         carry = (x, k_pool, v_pool)
+        lead_stats = []
         for l, lp in enumerate(lead):
-            carry, _ = layer_body(carry, (lp, jnp.int32(l)))
+            carry, st = layer_body(carry, (lp, jnp.int32(l)))
+            lead_stats.append(st)
         (x, k_pool, v_pool), stats = jax.lax.scan(
             layer_body, carry, (model.layer_params(params), layers))
+    if any(st is not None for st in lead_stats):
+        # leading layers that count something (none did before a model's
+        # residual maps counted): theirs beside the stack's
+        stats = {"lead": lead_stats, "stack": stats}
     return x, k_pool, v_pool, stats, state, rated
 
 
@@ -442,7 +498,8 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     adt = k_pool.dtype
     positions = jnp.arange(T, dtype=jnp.int32)[None]
     with jax.named_scope("embed"):
-        x = model.embed(params, ids, positions).astype(adt)
+        x = model.widen(params, model.embed(params, ids, positions)
+                        .astype(adt))
 
     def attend(l, lp, q, k, v, kp, vp, rated):
         kp = kvc.write_prefill_kv(kp, l, k[0].reshape(T, *kp.shape[3:]),
@@ -460,7 +517,8 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
         model, params, x, positions, k_pool, v_pool, attend, state, ssm)
     # the final norm is per row: the last real position alone goes through
     last = jnp.maximum(length, 1) - 1
-    tok = model.head(params, x[0, last][None], ids[0, last][None], eos_id)
+    tok = model.head(params, model.narrow(params, x[0, last][None]),
+                     ids[0, last][None], eos_id)
     if state:
         return tok, k_pool, v_pool, state
     return tok, k_pool, v_pool
@@ -504,7 +562,8 @@ def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
         blocks = jax.lax.dynamic_slice_in_dim(
             block_table, s * per_slice, per_slice)
         with jax.named_scope("embed"):
-            x = model.embed(params, slice_ids, positions).astype(adt)
+            x = model.widen(params, model.embed(params, slice_ids, positions)
+                            .astype(adt))
 
         def attend(l, lp, q, k, v, kp, vp, rt):
             kp = kvc.write_prefill_kv(kp, l, k[0].reshape(C, *kp.shape[3:]),
@@ -525,15 +584,18 @@ def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
         at = jnp.clip(jnp.maximum(length, 1) - 1 - start, 0, C - 1)
         return kp, vp, st, rt, x[0, at]
 
-    hidden = jax.eval_shape(
-        lambda p: model.embed(p, ids[:, :1], jnp.zeros((1, 1), jnp.int32)),
-        params).shape[-1]
+    # what ONE row carries, by the model (`widen`): `[hidden]` by default
+    carried = jax.eval_shape(
+        lambda p: model.widen(p, model.embed(
+            p, ids[:, :1], jnp.zeros((1, 1), jnp.int32)).astype(adt)),
+        params).shape[2:]
     live = jnp.clip(-(-length // C), 1, T // C)
     k_pool, v_pool, rows_state, rated, last_x = jax.lax.fori_loop(
         0, live, one,
-        (k_pool, v_pool, rows_state, rated, jnp.zeros((hidden,), adt)))
+        (k_pool, v_pool, rows_state, rated, jnp.zeros(carried, adt)))
     last = jnp.maximum(length, 1) - 1
-    tok = model.head(params, last_x[None], ids[0, last][None], eos_id)
+    tok = model.head(params, model.narrow(params, last_x[None]),
+                     ids[0, last][None], eos_id)
     state = rows_state + rated
     if state:
         return tok, k_pool, v_pool, state
@@ -644,7 +706,8 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     S = ids.shape[0]
     adt = k_pool.dtype
     with jax.named_scope("embed"):
-        x = model.embed(params, ids, positions).astype(adt)
+        x = model.widen(params, model.embed(params, ids, positions)
+                        .astype(adt))
 
     # the one gate (ops/pallas/paged_attention.py, asked through the model,
     # whose cache it is): on a TPU a kernel reads the live blocks through
@@ -688,7 +751,7 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     if counters is not None:
         stats = (stats, counters)
     state = rows_state + rated
-    tok = model.head(params, x, ids, eos_id)
+    tok = model.head(params, model.narrow(params, x), ids, eos_id)
     if state:
         return tok, k_pool, v_pool, stats, state
     return tok, k_pool, v_pool, stats
@@ -725,8 +788,8 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
     # addresses; clamp (those rows' outputs are never consumed, their KV
     # lands in the null block / overwritten slots)
     with jax.named_scope("embed"):
-        x = model.embed(params, ids[0],
-                        jnp.minimum(pos, model.max_len - 1)).astype(adt)
+        x = model.widen(params, model.embed(
+            params, ids[0], jnp.minimum(pos, model.max_len - 1)).astype(adt))
 
     def attend(l, lp, q, k, v, kp, vp, rated):
         kp = kvc.write_chunk_kv(kp, l, k.reshape(C, *kp.shape[3:]),
@@ -742,7 +805,8 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
     x, k_pool, v_pool, *_ = serve_layers(model, params, x, pos, k_pool,
                                          v_pool, attend)
     last = jnp.clip(length - 1 - start, 0, C - 1)
-    tok = model.head(params, x[last][None], ids[0, last][None], eos_id)
+    tok = model.head(params, model.narrow(params, x[last][None]),
+                     ids[0, last][None], eos_id)
     return tok, k_pool, v_pool
 
 
@@ -773,8 +837,8 @@ def verify_step(model: ServeModel, params: Params, ids: jax.Array,
     adt = k_pool.dtype
     pos = positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     with jax.named_scope("embed"):
-        x = model.embed(params, ids,
-                        jnp.minimum(pos, model.max_len - 1)).astype(adt)
+        x = model.widen(params, model.embed(
+            params, ids, jnp.minimum(pos, model.max_len - 1)).astype(adt))
 
     def attend(l, lp, q, k, v, kp, vp, rated):
         kp = kvc.write_span_kv(kp, l, k.reshape(S, W, *kp.shape[3:]),
@@ -787,6 +851,6 @@ def verify_step(model: ServeModel, params: Params, ids: jax.Array,
 
     x, k_pool, v_pool, *_ = serve_layers(model, params, x, pos, k_pool,
                                          v_pool, attend)
-    tokens = model.head(params, x.reshape(S * W, -1), ids.reshape(S * W),
-                        eos_id).reshape(S, W)
+    tokens = model.head(params, model.narrow(params, x).reshape(S * W, -1),
+                        ids.reshape(S * W), eos_id).reshape(S, W)
     return tokens, k_pool, v_pool

@@ -58,6 +58,8 @@ from . import decoder as _decoder, moe as _moe
 from .common import Params, rms as _rms, rms_norm as _rms_norm
 
 ROPE_LANES = 128    # the pool that holds the 64-wide rotary key: one lane tile
+SLICE_KEYS = 1024   # keys a chunk of a prompt slice's walk (`attend_slice`)
+_MASKED = -1e30     # a score no softmax sees
 
 
 @dataclasses.dataclass
@@ -96,6 +98,19 @@ class JoyaiConfig:
     @property
     def expert_layers(self) -> int:
         return self.layers - self.dense_layers
+
+    @property
+    def rope_inv_freq(self):
+        """The rotary pairs' inverse frequencies where they are not the
+        plain `theta^(-2i/d)` (a scaled RoPE: `models/xing4.py`'s YaRN);
+        None here: `rope_scaling` is null."""
+        return None
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the attention scores are multiplied by: `1/sqrt(192)`, and
+        no mscale (no rope scaling)."""
+        return 1.0 / math.sqrt(self.qk_dim)
 
     @property
     def routing(self) -> _moe.Routing:
@@ -231,12 +246,14 @@ def init_top(rng: jax.Array, cfg: JoyaiConfig) -> Params:
     }
 
 
-def init(rng: jax.Array, cfg: JoyaiConfig, dtype=jnp.float32
-         ) -> Tuple[Params, Dict]:
+def init(rng: jax.Array, cfg: JoyaiConfig, dtype=jnp.float32,
+         init_layer=init_layer, layer_axes=None) -> Tuple[Params, Dict]:
     """The dense layers are stacked under `dense.` and the expert layers
     under `blk.`, each on a leading axis, made one layer at a time and cast
     to `dtype` as each is made: the float32 set of a model too large for
-    the device is never whole on it."""
+    the device is never whole on it. A model that shares this block and
+    adds parameters to a layer (`models/xing4.py`) hands over its own
+    `init_layer` and the axes of what it adds (`layer_axes`)."""
     def cast(lp):
         return {k: v.astype(dtype) for k, v in lp.items()}
 
@@ -250,7 +267,8 @@ def init(rng: jax.Array, cfg: JoyaiConfig, dtype=jnp.float32
     axes = dict(_TOP_AXES)
     for prefix, kind in (("dense.", _DENSE_AXES), ("blk.", _EXPERT_AXES)):
         axes.update({prefix + k: ("layer",) + a
-                     for k, a in {**_ATTN_AXES, **kind}.items()})
+                     for k, a in {**_ATTN_AXES, **kind,
+                         **(layer_axes or {})}.items()})
     return params, axes
 
 
@@ -264,13 +282,16 @@ def init(rng: jax.Array, cfg: JoyaiConfig, dtype=jnp.float32
 # `shared_expert`); `head`. tests/test_layer_scopes.py holds the list.
 
 
-def _rope(x, positions, theta: float):
+def _rope(x, positions, theta: float, inv=None):
     """Rotary embedding of the trailing dimension of `x` [..., d] at
     `positions` (shaped like x's leading dimensions, or broadcastable to
     them): the INTERLEAVED convention, pair i is lanes (2i, 2i+1) and
-    turns by position * theta^(-2i/d); angles and rotation in float32."""
+    turns by position * theta^(-2i/d), or by position * `inv[i]` where the
+    model scales its frequencies (`cfg.rope_inv_freq`, [d/2] float32);
+    angles and rotation in float32."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[..., None] * inv      # [..., d/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
@@ -294,11 +315,12 @@ def _qkv(lp, y, positions, cfg: JoyaiConfig):
         c = _rms(ckr[..., :cfg.kv_rank], lp["blk.kv_norm.scale"],
                  cfg.rms_eps)
     with jax.named_scope("rope"):
-        kr = _rope(ckr[..., cfg.kv_rank:], positions, cfg.rope_theta)
+        inv = cfg.rope_inv_freq
+        kr = _rope(ckr[..., cfg.kv_rank:], positions, cfg.rope_theta, inv)
         q = jnp.concatenate(
             [q[..., :cfg.nope_dim],
              _rope(q[..., cfg.nope_dim:], positions[..., None],
-                   cfg.rope_theta)], axis=-1)
+                   cfg.rope_theta, inv)], axis=-1)
     return q.reshape(lead + (-1,)), c, kr
 
 
@@ -316,7 +338,7 @@ def _expanded_attention(lp, q, c, kr, cfg: JoyaiConfig):
          jnp.broadcast_to(kr[:, :, None, :cfg.rope_dim],
                           (B, T, nh, cfg.rope_dim))], axis=-1)
     ctx = pa.mha(q.reshape(B, T, nh, cfg.qk_dim), k, kv[..., dn:],
-                 causal=True, scale=1.0 / math.sqrt(cfg.qk_dim))
+                 causal=True, scale=cfg.softmax_scale)
     return ctx.reshape(B, T, nh * dv)
 
 
@@ -347,8 +369,11 @@ def _absorb_context(lp, ctx, cfg: JoyaiConfig):
 
 
 @jax.named_scope("proj")
-def _proj(lp, ctx, res):
-    return res + ctx @ lp["blk.wo"].astype(ctx.dtype)
+def _proj(lp, ctx, res=None):
+    """The output projection, added to the residual stream `res` where one
+    is given (a model whose residual path is its own adds it itself)."""
+    out = ctx @ lp["blk.wo"].astype(ctx.dtype)
+    return out if res is None else res + out
 
 
 def _mlp(lp, y, cfg: JoyaiConfig, layer=None):
@@ -420,7 +445,7 @@ class JoyaiServe(_decoder.ServeModel):
         scores = (jnp.einsum("swnc,smc->swnm", ql, keys)
                   + jnp.einsum("swnr,smr->swnm", qr,
                                vals[..., :cfg.rope_dim])) \
-            * (1.0 / math.sqrt(cfg.qk_dim))
+            * cfg.softmax_scale
         mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
             <= pos[:, :, None]
         scores = jnp.where(mask[:, :, None, :], scores, -1e9)
@@ -444,8 +469,68 @@ class JoyaiServe(_decoder.ServeModel):
                           (0, self.rope_lanes - cfg.rope_dim)])
         ctx = pa.paged_latent_attention(
             ql, qr, k_pool, v_pool, layer, block_tables, positions,
-            scale=1.0 / math.sqrt(cfg.qk_dim))
+            scale=cfg.softmax_scale)
         return _absorb_context(lp, ctx, cfg)
+
+    def attend_slice(self, lp, q, k_pool, v_pool, rated, layer, block_table,
+                     start, block_size):
+        """One slice of a prompt against the latent cache so far, in the
+        EXPANDED form: the keys are walked in chunks of `SLICE_KEYS` tokens
+        up to the slice's own, each chunk's `c` and rotary key gathered
+        through the table, taken through `W_kvb` to per-head keys and
+        values (never stored) and met by all the slice's queries under an
+        online softmax: float32 scores of `heads x C x SLICE_KEYS` whatever
+        the prompt's length. Expanded because a prompt's attention is
+        compute-bound: a (query, key) pair of a head costs 192 + 128
+        multiply-adds here against 576 + 512 absorbed, and expanding a
+        chunk again for every later slice adds a fifth of that at 12k
+        tokens (PERF.md section 6, PR 45)."""
+        from ..serving import kv_cache as kvc
+
+        cfg = self.cfg
+        f32 = jnp.float32
+        nh, dn, dv, dr = cfg.heads, cfg.nope_dim, cfg.v_dim, cfg.rope_dim
+        C = q.shape[1]
+        chunk = min(SLICE_KEYS, C)
+        if C % chunk or chunk % block_size:
+            raise ValueError(
+                f"a slice of {C} queries walks its keys in whole chunks of "
+                f"{chunk} tokens of whole blocks of {block_size}")
+        per_chunk = chunk // block_size
+        qh = q[0].reshape(C, nh, cfg.qk_dim)
+        t = start + jnp.arange(C, dtype=jnp.int32)
+        w_kvb = lp["blk.wkv_b"].astype(q.dtype)
+
+        def one(i, carry):
+            m, l, acc = carry
+            blocks = jax.lax.dynamic_slice_in_dim(
+                block_table, i * per_chunk, per_chunk)[None]
+            c = kvc.gather_kv(k_pool, layer, blocks)[0]     # [chunk, 512]
+            kr = kvc.gather_kv(v_pool, layer, blocks)[0][:, :dr]
+            kv = (c @ w_kvb).reshape(chunk, nh, dn + dv)
+            sc = (jnp.einsum("qnd,knd->nqk", qh[..., :dn], kv[..., :dn],
+                             preferred_element_type=f32)
+                  + jnp.einsum("qnr,kr->nqk", qh[..., dn:], kr,
+                               preferred_element_type=f32)) \
+                * cfg.softmax_scale
+            tok = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
+            ok = (tok[None, :] <= t[:, None])[None]         # [1, C, chunk]
+            sc = jnp.where(ok, sc, _MASKED)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc = alpha * acc + jnp.einsum(
+                "nqk,knd->nqd", p.astype(kv.dtype), kv[..., dn:],
+                preferred_element_type=f32)
+            return m_new, l, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, (start + C) // chunk, one,
+            (jnp.full((nh, C, 1), _MASKED, f32), jnp.zeros((nh, C, 1), f32),
+             jnp.zeros((nh, C, dv), f32)))
+        ctx = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+        return ctx.transpose(1, 0, 2).reshape(1, C, nh * dv), rated
 
     def proj(self, lp, ctx, res):
         return _proj(lp, ctx, res)

@@ -1309,6 +1309,10 @@ class DecodeEngine:
             "requests": counts,
             "step_ms": self._step_ms(),
             "step_facts": self._step_facts,
+            # what the model says of itself beyond the cache's shape
+            # (`ServeModel.describe`: residual streams, the rounds of a
+            # projection its maps go through); {} for most
+            "model": self._model.describe(),
             # which route decode attention took, a count a traced decode
             # program of this process ("paged", "paged_latent": a kernel
             # over the live blocks; "gather": the padded gather)

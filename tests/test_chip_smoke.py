@@ -154,6 +154,29 @@ def test_rehearse_serve_joyai(smoke):
     assert info["checked"]["decode_attention"] == {"gather": 1}
 
 
+def test_rehearse_serve_xing4(smoke):
+    """The serve_xing4 phase at a tiny size: the model of four residual
+    streams through the same engine and front, a prompt in one slice and
+    one walked in two, its tokens against the benchmark's plain reference
+    (off the chip the gate takes the gathered form; the chip run asserts
+    the kernel's route)."""
+    from paddle_tpu.models import xing4
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = xing4.Xing4Config.tiny()
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 27, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(16, 32), max_len=48), prompts, max_new=4,
+        logit_tol=smoke.XING4_LOGIT_TOL, model=xing4,
+        reference_gaps=smoke._xing4_reference_gaps)
+    assert info["checked"]["finished"]["length"] == 3
+    assert info["checked"]["compiles_after_warmup"] == 0
+    assert info["checked"]["decode_attention"] == {"gather": 1}
+
+
 def test_rehearse_serve_nemotron(smoke):
     """The serve_nemotron phase at a tiny size: the model of one mixer a
     block through the same engine and front, its state rows handed out and

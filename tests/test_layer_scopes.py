@@ -189,6 +189,48 @@ def test_joyai_serve_programs_carry_every_scope(tiny_joyai, kind, extra):
                for n in names)
 
 
+# Xing4's residual path is a layer scope of its own: `mhc`, a SIBLING of
+# `ln` / `qkv` / `attention` / `proj` / `mlp` (whose seconds existing
+# readers divide by), holding `mhc_map`, `mhc_pre` and `mhc_post`; the
+# block inside it is the latent model's, scope for scope
+XING4_NESTED = dict(JOYAI_NESTED, mhc={"mhc_map", "mhc_pre", "mhc_post"})
+
+
+@pytest.fixture(scope="module")
+def tiny_xing4():
+    from paddle_tpu.models import xing4
+
+    cfg = xing4.Xing4Config.tiny()
+    cfg.dtype = "float32"
+    params, _ = xing4.init(jax.random.key(0), cfg)
+    widths = cfg.serve_model().stored
+    return (cfg, params) + tuple(jnp.zeros((cfg.layers, NB, BS, w))
+                                 for w in widths)
+
+
+@pytest.mark.parametrize("kind,extra", [
+    ("decode", {"kv_gather", "absorb"}), ("verify", {"kv_gather", "absorb"}),
+    ("chunk", {"kv_gather", "absorb"}), ("prefill", set())])
+def test_xing4_serve_programs_carry_every_scope(tiny_xing4, kind, extra):
+    text = _lower_olmoe(kind, *tiny_xing4).compile().as_text()
+    nested = set().union(*XING4_NESTED.values())
+    missing = (SERVE | extra | nested | {"mhc"}) - _scopes(text)
+    assert not missing, (kind, missing)
+    names = re.findall(r'op_name="([^"]*)"', text)
+    siblings = {"ln", "qkv", "attention", "proj", "mlp"}
+    for op_name in names:
+        path = op_name.split("/")[:-1]
+        for outer, inner in dict(XING4_NESTED, attention={"absorb"}).items():
+            for name in inner & set(path):
+                assert outer in path[:path.index(name)], op_name
+        # never inside another layer scope, nor another inside it
+        if "mhc" in path:
+            assert not siblings & set(path), op_name
+    assert any(re.search(r"/layers/mhc/mhc_map/", n) for n in names)
+    assert any(re.search(r"/layers/while/body/.*mhc/mhc_post/", n)
+               for n in names)
+
+
 def test_gpt_training_forward_carries_the_scopes(tiny_gpt):
     cfg, params, _ = tiny_gpt
     text = jax.jit(lambda p, i: gpt.apply(p, cfg, i)).lower(

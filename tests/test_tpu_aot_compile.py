@@ -1018,3 +1018,116 @@ def test_the_selections_walk_compiles_for_v5e(v5e, monkeypatch, dtype,
                      r"\{([\d,]+)" % (layers, nb), text).group(1)
     assert laid == "2,1,0", laid
     assert compiled.memory_analysis().temp_size_in_bytes < 4e6
+
+
+# ---------------------------------------------------------------------------
+# Xing4.0-29B-A4B's serve programs at the shapes of its cell
+# (xing4_29b_a4b.ctx12k_sessions): 2 dense + 4 expert layers, 32 slots of
+# 20480 tokens. Resident: 8.35 GB of bf16 weights + 5.03 GB of latent cache.
+# The 12288-token prefill walks the prompt in slices of 2048: its
+# temporaries (a slice's four streams, the expanded keys and values of one
+# chunk of 1024 keys, float32 scores of 32 heads x 2048 x 1024, the dense
+# MLP's 2048 x 9216 twice) are those of ONE slice whatever the bucket; the
+# decode step's are a few rows of 14336 lanes.
+# ---------------------------------------------------------------------------
+
+_XING4_SLOTS, _XING4_CONTEXT = 32, 20480
+
+
+@pytest.fixture(scope="module")
+def xing4_6l(v5e):
+    from paddle_tpu.models import xing4
+    from paddle_tpu.serving.kv_cache import KVCacheConfig
+
+    cfg = xing4.Xing4Config(layers=6, max_len=_XING4_CONTEXT)
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = jax.eval_shape(lambda k: xing4.init(k, cfg)[0],
+                            jax.random.key(0))
+    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    sm = cfg.serve_model()
+    kv = KVCacheConfig(
+        layers=sm.layers, kv_heads=sm.kv_heads, head_dim=sm.head_dim,
+        max_len=_XING4_CONTEXT, block_size=_BLOCK, widths=sm.stored,
+        num_blocks=_XING4_SLOTS * (_XING4_CONTEXT // _BLOCK) + 1)
+    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
+    return cfg, params, pools, kv, sds
+
+
+@pytest.mark.parametrize("program", ["decode@32", "prefill@12288",
+                                     "prefill@8192"])
+def test_xing4_serve_program_fits_whatever_the_prompts_length(
+        xing4_6l, program, monkeypatch):
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, kv, sds = xing4_6l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _XING4_CONTEXT // _BLOCK
+    for counts in (gm.GATE_COUNTS, PA.GATE_COUNTS, A.GATE_COUNTS,
+                   kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4)).lower(params, *args).compile()
+    assert kv.pool_shapes == ((6, 40961, 16, 512), (6, 40961, 16, 128))
+    assert kv.pool_bytes() == pytest.approx(5.03e9, rel=1e-2)
+    ma = compiled.memory_analysis()
+    resident = ma.argument_size_in_bytes
+    assert 13.3e9 < resident < 13.5e9, ma
+    # the temporaries the issue allows: 0.1 GB a decode step, 1.2 GB a
+    # prefill, and the same for the 8192 bucket as for the 12288 one
+    assert ma.temp_size_in_bytes < (0.1e9 if kind == "decode" else 1.2e9), ma
+    assert ma.alias_size_in_bytes >= kv.pool_bytes(), ma
+    text = compiled.as_text()
+    for pool in pools:
+        assert not _pool_movers(text, pool.shape)
+    # no op makes a layer's slice of an expert stack
+    slices = re.findall(r"= \(?bf16\[64,(?:3584,1024|1024,3584)\]", text)
+    assert not slices, slices[:3]
+    assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
+    kernels = _kernels(text)
+    # nothing of the residual path is a kernel, and nothing of it lies
+    # under `attention` or `mlp`
+    assert not [k for k in kernels if "/mhc" in k], kernels
+    assert "/mhc/mhc_map/" in text and "/mhc/mhc_post/" in text
+    assert not re.findall(r"/(?:attention|mlp)/[^\"]*mhc", text)
+    if kind == "decode":
+        assert PA.GATE_COUNTS == {"paged_latent": 1}, PA.GATE_COUNTS
+        # the two leading dense layers' and the scan body's
+        latent = [k for k in kernels if "paged_latent_attention" in k]
+        assert len(latent) == 3 and all("/attention/" in k for k in latent)
+        # nothing holds a slot's cached context: no result has a dimension
+        # of the context's 20480 tokens or of a table's 1280 blocks x 16
+        held = [d for d in re.findall(r"= \(?\w+\[([\d,]+)\]", text)
+                if re.search(r"(?:^|,)(?:20480|1280,16)(?:,|$)", d)]
+        assert not held, held[:5]
+    else:
+        # a slice's latent and rotary key go in a block at a time (two
+        # leading layers and the scan body, two pools)
+        assert kvc.PREFILL_WRITE_UNITS == {"blocks": 6}
+        assert sum("kv_block_write" in k for k in kernels) == 6, kernels
+        for pool in pools:
+            assert not _pool_scatters(text, pool.shape)
+        # no temporary is as long as the bucket: the widest thing the
+        # prompt's length reaches is its ids
+        # (8192 is also W_kvb's width, 32 heads x 256: the 12288 bucket says)
+        long = [d for d in re.findall(r"= \(?(?:bf16|f32)\[([\d,]+)\]", text)
+                if re.search(r"(?:^|,)12288(?:,|$)", d)]
+        assert not long, long[:5]
