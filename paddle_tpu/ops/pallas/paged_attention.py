@@ -40,12 +40,13 @@ the null block is inactive and reads nothing. Blocks are fetched a chunk
 (`chunk_tokens // BS`) at a time into one of two VMEM buffers; while a
 chunk is consumed the next is in flight, and the last chunk of a slot
 overlaps the first of the next slot. How a slot's context is chunked
-depends on its own length alone, so a row's result does not depend on what
-shares the batch (`ServeModel`'s contract). Rows of a buffer past the live
-blocks hold what an earlier chunk left there: their scores are masked, and
-their weights are exactly zero against V rows that are finite (the buffers
-start zeroed; the pool's garbage is finite by the same contract the gather
-path relies on).
+depends on its own length and on static shapes alone (the cache's token
+bytes, the table's width: `chunk_tokens`), so a row's result does not
+depend on what shares the batch (`ServeModel`'s contract). Rows of a buffer
+past the live blocks hold what an earlier chunk left there: their scores
+are masked, and their weights are exactly zero against V rows that are
+finite (the buffers start zeroed; the pool's garbage is finite by the same
+contract the gather path relies on).
 
 How many copies that takes. Where a token stores much (multi-head K and V
 of 1280 or 2048 lanes: 40 and 64 KB a block), one DMA a block and pool, as
@@ -69,6 +70,24 @@ takes whole). Only blocks the slot owns are ever fetched, the pieces put
 the same bytes in the same buffer rows as the single blocks would, and
 start and wait of a chunk read the same numbers: the result does not depend
 on where a sequence's blocks lie.
+
+How long a chunk is. A wide cache's is `_CHUNK` tokens, a narrow one's
+`_SUB` = 512: what a chunk costs beside its bytes (the wait for its copies,
+the MXU's fill and drain around two small products, the carry from the
+chunk before) is paid once a chunk whatever it holds. Under a LONG table
+(`_LONG_TABLE` tokens or more: rows of ten thousand tokens are twenty such
+chunks a layer) a narrow cache's chunk is `_LONG_CHUNK` tokens: the copies
+take it whole as ever (one copy a pool where it is one run; the ladder of
+pieces has one more size), and the STEP goes through it in sub-tiles of
+`_SUB` tokens in one straight line, scores `[M, _SUB]` as ever, the next
+sub-tile's scores formed before this one's carry is consumed, and in a
+row's last chunk up to the sub-tile that holds its newest token and no
+further (`_softmax_chunk`). A long chunk that holds a break would go a
+block a copy from the break on, up to 63 blocks; a prompt's blocks and its
+growth's are TWO runs, so of a long chunk the tables count the run after
+the first break as well (`Tables.runs`' second half) and it goes in pieces
+too. `chunk_tokens` is the one number; the wrapper's count, the scratch and
+the allocator's `run_chunk_share` go by it.
 
 An entry stored at a RATE (serving/kv_cache.py `KVCacheConfig.rated`: the
 compressed keys a block-sparse attention scores before it reads any K or V)
@@ -109,6 +128,12 @@ GATE_COUNTS: collections.Counter = collections.Counter()
 _CHUNK = 256
 # a NARROW cache: `_CHUNK` tokens of both pools are under this many bytes
 _NARROW_CHUNK_BYTES = 512 * 1024
+# a narrow cache's walk takes `_SUB` tokens a compute step; over a table of
+# `_LONG_TABLE` tokens or more it fetches `_LONG_CHUNK` a chunk, and the
+# step goes through the chunk in sub-tiles of `_SUB` (`narrow`)
+_SUB = 2 * _CHUNK
+_LONG_CHUNK = 1024
+_LONG_TABLE = 16384
 
 # a pool of rated entries `[L, NB, lanes]` holds a block a row, and a copy
 # addresses whole tiles: `_ROW_TILE` rows of 2- and of 4-byte lanes alike
@@ -156,20 +181,43 @@ def narrow(token_bytes: int) -> bool:
       core's turn and the pipeline's fill, is as long as 256 such tokens
       take to arrive (320 KB, 256 KB); at 512 the two walks over runs were
       another 1.33 and 1.36 times faster, 2.5 and 2.6 times the
-      block-by-block walk (67% and 64% of the peak)."""
+      block-by-block walk (67% and 64% of the peak);
+    - and under a table of `_LONG_TABLE` tokens or more, `_LONG_CHUNK`
+      tokens a chunk with the step in sub-tiles of `_SUB` (`chunk_tokens`;
+      PERF.md section 6, PR 46, the latent walk alone at 32 slots x
+      8k-15k tokens, tables 20480 wide, us a call): 743 at 512 tokens a
+      chunk, of which the copies alone take 624 (722 GB/s: the floor) and
+      the step alone 434; 1024 tokens a chunk with nothing else changed
+      672; with the step in sub-tiles 669, and 643 once the run after a
+      chunk's first break goes in pieces too (a table there is a prompt's
+      run and its growth's); 2048 and 4096 tokens a chunk 653 and 671
+      (more blocks behind a break, more dead tokens fetched in a row's
+      last chunk). Tables of ONE run each: 721 -> 644 / 642 / 643 at 1024
+      / 2048 / 4096. The same walk at 32 x 1.2k-4.1k tokens under tables
+      of 4608 read 192 -> 169; it keeps 512 all the same: a threshold
+      that low would move the grouped-query walk of a 9216-token table
+      with it, which this PR did not measure."""
     return _CHUNK * token_bytes < _NARROW_CHUNK_BYTES
 
 
-def chunk_tokens(token_bytes: int) -> int:
-    """Tokens a compute step: `_CHUNK`, twice that for a `narrow` cache."""
-    return 2 * _CHUNK if narrow(token_bytes) else _CHUNK
+def chunk_tokens(token_bytes: int, table_tokens: int) -> int:
+    """Tokens the walk fetches a chunk, from the cache's token bytes and the
+    table's width in tokens (`max_blocks * block_size`, a static shape)
+    alone: `_CHUNK`; `_SUB` for a `narrow` cache; `_LONG_CHUNK` for a narrow
+    cache under a table of `_LONG_TABLE` tokens or more. THE one number
+    that `blocks_per_chunk`, `with_runs`, `_scratch` and the allocator's
+    count (`serving/kv_cache.BlockAllocator.per_chunk`) go by."""
+    if not narrow(token_bytes):
+        return _CHUNK
+    return _LONG_CHUNK if table_tokens >= _LONG_TABLE else _SUB
 
 
-def blocks_per_chunk(block_size: int, token_bytes: int) -> int:
+def blocks_per_chunk(block_size: int, token_bytes: int,
+                     table_tokens: int) -> int:
     """Blocks of `block_size` tokens the walk fetches a chunk: what one
     copy of a narrow cache's walk can take at most
     (`serving/kv_cache.run_chunks` counts by it)."""
-    return max(1, chunk_tokens(token_bytes) // block_size)
+    return max(1, chunk_tokens(token_bytes, table_tokens) // block_size)
 
 
 def _token_bytes(*pools) -> int:
@@ -310,6 +358,10 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
     layer = layer_ref[0]
     sizes = [1 << i for i in reversed(range(per_chunk.bit_length()))]
     take_runs = lead_ref is not None    # a narrow cache's: `_call_form`
+    # a long chunk's tables count the run after the first break too
+    # (`with_runs`: the second half of `Tables.runs`)
+    n_chunks = -(-max_blocks // per_chunk)
+    two_runs = take_runs and lead_ref.shape[1] == 2 * n_chunks
 
     def live_blocks(slot):
         live = jnp.minimum(pos_ref[slot] // bs + 1, max_blocks)
@@ -320,8 +372,11 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
         chunk `c` of `slot` into buffer `buf`: the blocks the table names
         in a row from the chunk's first (`lead_ref`: `Tables.runs`) in
         the binary pieces of their count, the largest first (a full chunk
-        of one run: ONE copy a pool), the rest a block at a time. Start
-        and wait read the same numbers, so they make the same copies."""
+        of one run: ONE copy a pool), the rest a block at a time; in a
+        long chunk (`_SUB` < chunk) the run that follows the first break
+        in pieces as well, so that a prompt's blocks and its growth's are
+        a few copies where they meet. Start and wait read the same
+        numbers, so they make the same copies."""
         first = c * per_chunk
         n = jnp.clip(live_blocks(slot) - first, 0, per_chunk)
 
@@ -366,18 +421,28 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
         def _():
             copy(0, per_chunk)
 
+        def in_pieces(run, at=None):
+            """The `run` blocks from the chunk's entry `at` on (None: its
+            first, and the trace a short chunk always had), in the binary
+            pieces of their count."""
+            for k in sizes[1:]:
+                @pl.when(run & k != 0)
+                def _(k=k):
+                    j = run & -(2 * k)              # larger ones lie before
+                    copy(j if at is None else at + j, k)
+
         @pl.when(jnp.logical_not(whole))
         def _():
             pieces = run > 1        # a lone block goes with the rest
-
-            @pl.when(pieces)
-            def _():
-                for k in sizes[1:]:
-                    @pl.when(run & k != 0)
-                    def _(k=k):
-                        copy(run & -(2 * k), k)     # larger ones lie before
-
-            lax.fori_loop(jnp.where(pieces, run, 0), n, one, 0)
+            pl.when(pieces)(functools.partial(in_pieces, run))
+            done = jnp.where(pieces, run, 0)
+            if two_runs:
+                after = jnp.where(pieces, jnp.minimum(
+                    lead_ref[slot, n_chunks + c], n - run), 0)
+                after = jnp.where(after > 1, after, 0)
+                pl.when(after > 0)(functools.partial(in_pieces, after, run))
+                done = done + after
+            lax.fori_loop(done, n, one, 0)
 
     @pl.when(s == 0)
     def _():
@@ -440,6 +505,42 @@ def _softmax_init(rows: int, width: int):
             jnp.zeros((rows, width), jnp.float32))
 
 
+def _softmax_chunk(tile, chunk: int, c, pos, carry):
+    """Chunk `c` of the online softmax. `tile(rows) -> (scores [M, rows]
+    float32 and scaled, values [rows, width])` of those rows of the chunk's
+    buffers. A chunk of `_SUB` tokens at most is one tile, as ever. A
+    longer one (`chunk_tokens`: a narrow cache under a long table) is
+    walked in sub-tiles of `_SUB`, so that scores stay `[M, _SUB]`: where
+    every sub-tile holds a live token, in one straight line, the scores of
+    sub-tile j + 1 formed before the carry of j is consumed (both operands
+    lie in VMEM and nothing waits between them, so the compiler may put the
+    one's `q . k^T` beside the other's `p . v`); in a row's LAST chunk, up
+    to the sub-tile that holds `pos` and no further, so that no arithmetic
+    is done on dead tokens whatever the chunk's length."""
+    if chunk <= _SUB:
+        return _softmax_step(*tile(slice(None)), c, pos, carry)
+    n_sub = chunk // _SUB
+    last = (pos - c * chunk) // _SUB        # the sub-tile that holds `pos`
+
+    def whole(carry):
+        nxt = tile(pl.ds(0, _SUB))
+        for j in range(n_sub):
+            sc, vals = nxt
+            if j + 1 < n_sub:
+                nxt = tile(pl.ds((j + 1) * _SUB, _SUB))
+            carry = _softmax_step(sc, vals, c * n_sub + j, pos, carry)
+        return carry
+
+    def part(carry):
+        def one(j, carry):
+            sc, vals = tile(pl.ds(pl.multiple_of(j * _SUB, _SUB), _SUB))
+            return _softmax_step(sc, vals, c * n_sub + j, pos, carry)
+
+        return lax.fori_loop(0, last + 1, one, carry)
+
+    return lax.cond(last >= n_sub - 1, whole, part, carry)
+
+
 def _kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm, v_hbm,
             o_ref, kbuf, vbuf, sems, done_ref, *, heads: int, scale: float,
             block_size: int):
@@ -458,9 +559,13 @@ def _kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm, v_hbm,
 
     def step(query, c, buf, carry):
         pos, q, _ = query
-        sc = lax.dot_general(q, kbuf[buf], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32) * scale
-        return _softmax_step(sc, vbuf[buf], c, pos, carry)
+
+        def tile(rows):
+            sc = lax.dot_general(q, kbuf[buf, rows], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+            return sc, vbuf[buf, rows]
+
+        return _softmax_chunk(tile, kbuf.shape[1], c, pos, carry)
 
     (_, _, own), (m, l, acc) = _walk(
         layer_ref, tables_ref, pos_ref, lead_ref, (k_hbm, v_hbm),
@@ -488,9 +593,13 @@ def _gqa_kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm,
 
     def step(query, c, buf, carry):
         pos, q, _ = query
-        sc = lax.dot_general(q, kbuf[buf], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32) * scale
-        return _softmax_step(sc, vbuf[buf], c, pos, carry)
+
+        def tile(rows):
+            sc = lax.dot_general(q, kbuf[buf, rows], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+            return sc, vbuf[buf, rows]
+
+        return _softmax_chunk(tile, kbuf.shape[1], c, pos, carry)
 
     (_, _, own), (m, l, acc) = _walk(
         layer_ref, tables_ref, pos_ref, lead_ref, (k_hbm, v_hbm),
@@ -516,12 +625,17 @@ def _latent_kernel(layer_ref, tables_ref, pos_ref, lead_ref, ql_ref, qr_ref,
 
     def step(query, c, buf, carry):
         pos, ql, qr = query
-        ctx = cbuf[buf]         # keys AND values: one row a token, all heads
-        sc = (lax.dot_general(ql, ctx, last,
-                              preferred_element_type=jnp.float32)
-              + lax.dot_general(qr, rbuf[buf], last,
-                                preferred_element_type=jnp.float32)) * scale
-        return _softmax_step(sc, ctx, c, pos, carry)
+
+        def tile(rows):
+            ctx = cbuf[buf, rows]   # keys AND values: a row a token, all heads
+            sc = (lax.dot_general(ql, ctx, last,
+                                  preferred_element_type=jnp.float32)
+                  + lax.dot_general(qr, rbuf[buf, rows], last,
+                                    preferred_element_type=jnp.float32)) \
+                * scale
+            return sc, ctx
+
+        return _softmax_chunk(tile, cbuf.shape[1], c, pos, carry)
 
     # rbuf meets the mask alone; cbuf's stale rows meet zero weights
     _, (m, l, acc) = _walk(layer_ref, tables_ref, pos_ref, lead_ref,
@@ -536,8 +650,12 @@ class Tables(NamedTuple):
     its first on are consecutive block ids (1 at least). The walk fetches
     that many of the chunk's live blocks in the binary pieces of their
     count, one copy where the whole chunk is a run, and never searches the
-    table itself. `decoder.decode_step` makes one ONCE a step, outside its
-    layer loop (inside, XLA counts again every layer: three fusions and a
+    table itself. Where a chunk is long (`chunk_tokens` over `_SUB`: a
+    break would cost it up to 63 copies of a block a pool) `runs` is `[S,
+    2 * chunks]`: in its second half, how many ids FOLLOW each other from
+    the first break on (0: no break), which go in pieces as well.
+    `decoder.decode_step` makes one ONCE a step, outside its layer loop
+    (inside, XLA counts again every layer: three fusions and a
     reduce-window, 2 to 11 us at the cells' tables); the kernels take one
     wherever they take block tables, and count for a bare array in the
     call. `rows` `[S, MB]` is the same for the walk over a pool of rated
@@ -568,14 +686,20 @@ def with_runs(block_tables, k_pool, v_pool) -> Tables:
     token_bytes = _token_bytes(k_pool, v_pool)
     if not narrow(token_bytes):     # its walk takes a block a copy
         return Tables(ids, None)
-    per_chunk = blocks_per_chunk(k_pool.shape[2], token_bytes)
+    bs = k_pool.shape[2]
+    per_chunk = blocks_per_chunk(bs, token_bytes, mb * bs)
     chunks = -(-mb // per_chunk)
     t = jnp.pad(ids, ((0, 0), (0, chunks * per_chunk - mb)))
     t = t.reshape(slots, chunks, per_chunk)
     follows = (t[..., 1:] == t[..., :-1] + 1).astype(jnp.int32)
-    return Tables(ids, 1 + jnp.sum(
-        jnp.cumprod(follows, axis=-1, dtype=jnp.int32), axis=-1,
-        dtype=jnp.int32))
+    runs = 1 + jnp.sum(jnp.cumprod(follows, axis=-1, dtype=jnp.int32),
+                       axis=-1, dtype=jnp.int32)
+    if per_chunk * bs > _SUB:
+        # a long chunk: the entries after ONE break, the next run's length
+        after = jnp.sum(jnp.cumsum(1 - follows, axis=-1, dtype=jnp.int32)
+                        == 1, axis=-1, dtype=jnp.int32)
+        runs = jnp.concatenate([runs, after], axis=1)
+    return Tables(ids, runs)
 
 
 def _next_after(marks: jax.Array, none: int) -> jax.Array:
@@ -640,10 +764,12 @@ def _call_form(kernel, layer, block_tables, positions, *pools):
         p.reshape(p.shape[0], -1, p.shape[3]) for p in pools)
 
 
-def _scratch(k_pool, v_pool, lane_groups: int = 1):
-    """The walk's double buffers, a chunk of a pool's lanes each (of ONE
-    lane group's share of them, where a copy takes no more)."""
-    chunk = chunk_tokens(_token_bytes(k_pool, v_pool))
+def _scratch(k_pool, v_pool, max_blocks: int, lane_groups: int = 1):
+    """The walk's double buffers under tables `max_blocks` wide, a chunk of
+    a pool's lanes each (of ONE lane group's share of them, where a copy
+    takes no more)."""
+    chunk = chunk_tokens(_token_bytes(k_pool, v_pool),
+                         max_blocks * k_pool.shape[2])
     return [pltpu.VMEM((2, chunk, k_pool.shape[3] // lane_groups),
                        k_pool.dtype),
             pltpu.VMEM((2, chunk, v_pool.shape[3] // lane_groups),
@@ -680,7 +806,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((None, 1, hd), lambda s, *_: (s, 0, 0)),
-            scratch_shapes=_scratch(k_pool, v_pool)),
+            scratch_shapes=_scratch(k_pool, v_pool, scalars[1].shape[1])),
         out_shape=jax.ShapeDtypeStruct((n_slots, 1, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -719,7 +845,7 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((None, heads, head_dim), per_slot),
-            scratch_shapes=_scratch(k_pool, v_pool)),
+            scratch_shapes=_scratch(k_pool, v_pool, scalars[1].shape[1])),
         out_shape=jax.ShapeDtypeStruct((n_slots, heads, head_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -767,7 +893,8 @@ def paged_sparse_attention(q: jax.Array, k_pool: jax.Array,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((None, group, head_dim), per_pair),
-            scratch_shapes=_scratch(k_pool, v_pool, kv_heads)),
+            scratch_shapes=_scratch(k_pool, v_pool, scalars[1].shape[1],
+                                    kv_heads)),
         out_shape=jax.ShapeDtypeStruct((n_slots * kv_heads, group, head_dim),
                                        q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -811,7 +938,7 @@ def paged_latent_attention(q_latent: jax.Array, q_rope: jax.Array,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((None, heads, latent), per_slot),
-            scratch_shapes=_scratch(c_pool, r_pool)),
+            scratch_shapes=_scratch(c_pool, r_pool, scalars[1].shape[1])),
         out_shape=jax.ShapeDtypeStruct(q_latent.shape, q_latent.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),

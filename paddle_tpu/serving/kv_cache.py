@@ -354,9 +354,11 @@ class BlockAllocator:
                 f"{cfg.num_blocks}")
         self._free = _FreeExtents(1, cfg.num_blocks)
         self._owned: Dict[int, bool] = {}
-        # blocks the kernels' walk takes a chunk: `run_chunks`' unit
-        self.per_chunk = blocks_per_chunk(cfg.block_size,
-                                          cfg.walk_bytes_per_token())
+        # blocks the kernels' walk takes a chunk of a table as wide as this
+        # cache's: `run_chunks`' unit
+        self.per_chunk = blocks_per_chunk(
+            cfg.block_size, cfg.walk_bytes_per_token(),
+            cfg.max_blocks_per_seq * cfg.block_size)
         self._runs = self._chunks = 0       # of the live tables
 
     def free_blocks(self) -> int:
@@ -441,6 +443,9 @@ class BlockAllocator:
             # kernels read with one copy a pool (None: no live table)
             "run_chunk_share": round(self._runs / self._chunks, 4)
             if self._chunks > 0 else None,
+            # tokens such a chunk holds: what the walk over this cache's
+            # tables fetches a chunk (`paged_attention.chunk_tokens`)
+            "walk_chunk_tokens": self.per_chunk * self.cfg.block_size,
             "pool_bytes": self.cfg.pool_bytes(),
             # what a token stores in a layer: lanes of the two entries
             # (the model's say) and their bytes

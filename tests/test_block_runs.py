@@ -15,14 +15,15 @@ import numpy as np
 import pytest
 
 from paddle_tpu.models import gpt
+from paddle_tpu.ops.pallas import paged_attention as PA
 from paddle_tpu.serving import DecodeConfig, DecodeEngine, kv_cache
 from paddle_tpu.serving.kv_cache import (BlockAllocator, KVCacheConfig,
                                          NoBlocksError, run_chunks)
 
 
-def _allocator(num_blocks=65, widths=(1280, 1280)):
+def _allocator(num_blocks=65, widths=(1280, 1280), max_len=256):
     return BlockAllocator(KVCacheConfig(
-        layers=1, widths=widths, max_len=256, block_size=16,
+        layers=1, widths=widths, max_len=max_len, block_size=16,
         num_blocks=num_blocks))
 
 
@@ -239,17 +240,21 @@ def test_run_chunks_agrees_with_a_brute_force_count(seed, per_chunk):
             blocks
 
 
-@pytest.mark.parametrize("widths,per_chunk", [((1280, 1280), 16),
-                                              ((512, 128), 32)])
-def test_the_allocators_count_follows_its_tables(widths, per_chunk):
+@pytest.mark.parametrize("widths,max_len,per_chunk", [
+    ((1280, 1280), 256, 16), ((512, 128), 256, 32),
+    ((512, 128), 20480, PA._LONG_CHUNK // 16),
+    ((1280, 1280), 20480, 16)])
+def test_the_allocators_count_follows_its_tables(widths, max_len, per_chunk):
     """`run_chunk_share` against a recount of the live tables, through
     allocations, growth (adjacent and not) and retirements; the chunk is
-    the kernels' for the cache's width (multi-head K and V of 1280 lanes:
-    256 tokens; a latent cache: 512)."""
+    the kernels' for the cache's width and the table's (multi-head K and
+    V of 1280 lanes: 256 tokens under any table; a latent cache: 512, and
+    `_LONG_CHUNK` under a table of 20480 tokens)."""
     rng = np.random.default_rng(5)
-    al = _allocator(num_blocks=513, widths=widths)
+    al = _allocator(num_blocks=513, widths=widths, max_len=max_len)
     assert al.stats()["run_chunk_share"] is None
     assert al.per_chunk == per_chunk
+    assert al.stats()["walk_chunk_tokens"] == 16 * per_chunk
     held = []
     for step in range(600):
         roll = rng.random()
@@ -265,6 +270,72 @@ def test_the_allocators_count_follows_its_tables(widths, per_chunk):
         share = al.stats()["run_chunk_share"]
         assert share == (round(counted[0] / counted[1], 4) if held else None)
     assert 0 < share <= 1
+
+
+def _runs_from(chunk, at):
+    """How many ids of `chunk` follow each other from entry `at` on."""
+    n = at < len(chunk)
+    while at + n < len(chunk) and chunk[at + n] == chunk[at + n - 1] + 1:
+        n += 1
+    return int(n)
+
+
+@pytest.mark.parametrize("table_blocks", [288, 576, 1280],
+                         ids=["rag_closed", "doc_sessions",
+                              "ctx12k_sessions"])
+def test_the_kernels_count_of_runs_is_the_hosts(table_blocks):
+    """ONE definition of a chunk: what `with_runs` counts for the walk
+    (`Tables.runs`), what `run_chunks` counts on the host and what the
+    allocator keeps (`per_chunk`, `run_chunk_share`) agree chunk for
+    chunk over tables as the allocator builds them, at 32 blocks a chunk
+    under a table of 4608 or 9216 tokens and at `_LONG_CHUNK` tokens
+    under one of 20480; a long chunk's second count is the run after its
+    first break."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(table_blocks)
+    slots = 6
+    al = BlockAllocator(KVCacheConfig(
+        layers=1, widths=(512, 128), max_len=16 * table_blocks,
+        block_size=16, num_blocks=slots * table_blocks + 1))
+    long = 16 * table_blocks >= PA._LONG_TABLE
+    per_chunk = al.per_chunk
+    assert per_chunk == (PA._LONG_CHUNK // 16 if long else 32)
+    held = [al.alloc(int(rng.integers(1, table_blocks // 2)))
+            for _ in range(slots)]
+    for _ in range(40 * slots):     # growth, and a retirement now and then
+        i = int(rng.integers(slots))
+        if rng.random() < 0.05:
+            al.free(held[i])
+            held[i] = al.alloc(int(rng.integers(1, table_blocks // 2)))
+        for _ in range(int(rng.integers(1, 8))):
+            if len(held[i]) < table_blocks:
+                al.grow(held[i])
+    ids = np.zeros((slots, table_blocks), np.int32)
+    for s, t in enumerate(held):
+        ids[s, :len(t)] = t
+    pools = [jnp.zeros((1, 2, 16, w), jnp.bfloat16) for w in (512, 128)]
+    runs = np.asarray(PA.with_runs(jnp.asarray(ids), *pools).runs)
+    chunks = -(-table_blocks // per_chunk)
+    assert runs.shape == (slots, 2 * chunks if long else chunks)
+    whole = total = 0
+    for s, t in enumerate(held):
+        for c in range(-(-len(t) // per_chunk)):
+            chunk = t[c * per_chunk:(c + 1) * per_chunk]
+            lead = min(int(runs[s, c]), len(chunk))
+            assert lead == _runs_from(chunk, 0)
+            assert (lead == len(chunk)) == (run_chunks(chunk, per_chunk)
+                                            == (1, 1))
+            if long and lead < len(chunk):
+                assert min(int(runs[s, chunks + c]), len(chunk) - lead) \
+                    == _runs_from(chunk, lead)
+            whole += lead == len(chunk)
+            total += 1
+        assert run_chunks(t, per_chunk)[1] == -(-len(t) // per_chunk)
+    assert 0 < whole < total        # the churn left breaks, and runs
+    assert (whole, total) == tuple(np.sum(
+        [run_chunks(t, per_chunk) for t in held], axis=0))
+    assert al.stats()["run_chunk_share"] == round(whole / total, 4)
 
 
 # -- in the engine -----------------------------------------------------------

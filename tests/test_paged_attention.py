@@ -9,6 +9,7 @@ the pools) is `tests/test_tpu_aot_compile.py`'s, and the chip itself is
 """
 
 import functools
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,20 @@ PATTERNS = {
     "full-table": [MB * BS] * S,
     "mixed": [0, 1, 300, 17, MB * BS],
 }
+# the latent walk under a table of `_LONG_TABLE` tokens: a chunk is
+# `_LONG_CHUNK` = 1024 tokens, its step two sub-tiles of 512. The same
+# pattern names; rows that end in a chunk's first sub-tile, on a
+# sub-tile's edge, in the last sub-tile, on the chunk's edge
+LONG_MB = PA._LONG_TABLE // BS
+LONG_NB = 1 + LONG_MB + 64
+LONG_PATTERNS = {
+    "inactive": [0, 0, 0, 0, 0],
+    "one-token": [1, 1, 1, 1, 1],
+    "block-edge": [16, 17, 511, 512, 513],
+    "chunk-edge": [1024, 1025, 1023, 2048, 2049],
+    "full-table": [LONG_MB * BS, 0, 0, 0, 0],
+    "mixed": [0, 1537, 1024 + 300, 4096 + 1024, 3 * 512],
+}
 
 
 def gather_math(q, k_pool, v_pool, layer, tables, positions, heads):
@@ -52,48 +67,121 @@ def gather_math(q, k_pool, v_pool, layer, tables, positions, heads):
                       precision="highest").reshape(s, hd)
 
 
-def tables_for(lens, rng):
-    """Block tables over scattered blocks, and positions, for slots that
-    attend `lens` tokens."""
-    tables = np.zeros((S, MB), np.int32)
-    positions = np.zeros((S,), np.int32)
-    free = list(rng.permutation(np.arange(1, NB)))
-    for s, n in enumerate(lens):
-        for b in range(-(-n // BS)):
-            tables[s, b] = free.pop()
-        positions[s] = max(n - 1, 0)
-    return jnp.asarray(tables), jnp.asarray(positions)
+def latent_math(ql, qr, c_pool, r_pool, layer, tables, positions, scale):
+    """The absorbed latent attention over gathered rows, in float32."""
+    f32 = jnp.float32
+    live = int(np.asarray(positions).max()) // BS + 1   # the rest is masked
+    keys = kvc.gather_kv(c_pool, layer, tables[:, :live]).astype(f32)
+    rot = kvc.gather_kv(r_pool, layer, tables[:, :live]).astype(f32)
+    sc = (jnp.einsum("snc,smc->snm", ql.astype(f32), keys)
+          + jnp.einsum("snr,smr->snm", qr.astype(f32), rot))
+    seen = jnp.arange(keys.shape[1])[None, :] <= positions[:, None]
+    sc = jnp.where(seen[:, None, :], sc * scale, -jnp.inf)
+    return jnp.einsum("snm,smc->snc", jax.nn.softmax(sc, -1),
+                      keys).reshape(ql.shape[0], -1)
 
 
-@pytest.fixture(scope="module", params=WIDTHS, ids=lambda w: f"{w[0]}x{w[1]}")
+class Walk(NamedTuple):
+    """A kernel over noise-filled pools: `run` / `ref` `(queries, k_pool,
+    v_pool, layer, tables, positions) -> [S, lanes]`, its table's width
+    and pool's blocks, and the lengths its cases walk."""
+
+    queries: tuple              # one array or two, a row a slot
+    k_pool: jax.Array
+    v_pool: jax.Array
+    run: Callable
+    ref: Callable
+    blocks: int                 # a table's width
+    pool_blocks: int
+    patterns: dict
+    garbage: list               # `test_garbage_past_the_length_does_not_show`
+    # `test_a_slots_result_does_not_depend_on_its_neighbours`: the tokens
+    # of the row that keeps its table and length; (every slot's length,
+    # where the row sits among them) a call
+    row: int
+    neighbours: list
+    tol: float
+
+    def args(self, layer, tables, positions):
+        return (self.queries, self.k_pool, self.v_pool, jnp.int32(layer),
+                tables, positions)
+
+    def tables_for(self, lens, rng):
+        """Block tables over scattered blocks, and positions, for slots
+        that attend `lens` tokens."""
+        tables = np.zeros((S, self.blocks), np.int32)
+        positions = np.zeros((S,), np.int32)
+        free = list(rng.permutation(np.arange(1, self.pool_blocks)))
+        for s, n in enumerate(lens):
+            for b in range(-(-n // BS)):
+                tables[s, b] = free.pop()
+            positions[s] = max(n - 1, 0)
+        return jnp.asarray(tables), jnp.asarray(positions)
+
+
+def _noise(rng, *shape):
+    return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+
+def _multi_head(heads, d):
+    rng = np.random.default_rng(heads)
+    k_pool, v_pool = (_noise(rng, L, NB, BS, heads * d) for _ in range(2))
+    q = _noise(rng, S, heads * d)
+    run = jax.jit(lambda q, kp, vp, l, t, p: PA.paged_attention(
+        q[0], kp, vp, l, t, p, heads=heads,
+        interpret=pltpu.InterpretParams()).astype(jnp.float32))
+    ref = lambda q, kp, vp, l, t, p: gather_math(       # noqa: E731
+        q[0], kp, vp, l, t, p, heads)
+    # bf16 weights and a bf16 result over unit-normal values: 2^-8 of 3.5
+    return Walk((q,), k_pool, v_pool, run, ref, MB, NB, PATTERNS,
+                [17, 1, 250, 33, 0], 273,
+                [([0, 0, 273, 0, 0], 2), ([320, 1, 273, 17, 256], 2),
+                 ([16, 300, 273, 0, 5], 2), ([100, 0, 0, 31, 273], 4)], 0.03)
+
+
+def _latent_long():
+    heads, latent, rope, scale = 16, 128, 128, 0.1
+    rng = np.random.default_rng(46)
+    c_pool = _noise(rng, L, LONG_NB, BS, latent)
+    r_pool = _noise(rng, L, LONG_NB, BS, rope)
+    ql, qr = _noise(rng, S, heads, latent), _noise(rng, S, heads, rope)
+    assert PA.chunk_tokens(2 * (latent + rope), LONG_MB * BS) \
+        == PA._LONG_CHUNK == 2 * PA._SUB
+    run = jax.jit(lambda q, cp, rp, l, t, p: PA.paged_latent_attention(
+        *q, cp, rp, l, t, p, scale=scale,
+        interpret=pltpu.InterpretParams()).astype(jnp.float32).reshape(
+            S, heads * latent))
+    ref = lambda q, cp, rp, l, t, p: latent_math(       # noqa: E731
+        *q, cp, rp, l, t, p, scale)
+    row = 2048 + 513        # ends in its third chunk's second sub-tile
+    return Walk((ql, qr), c_pool, r_pool, run, ref, LONG_MB, LONG_NB,
+                LONG_PATTERNS, [17, 513, 2050, 1537, 0], row,
+                [([0, 0, row, 0, 0], 2), ([4096, 1, row, 17, 2048], 2),
+                 ([16, 3000, row, 0, 511], 2), ([1025, 0, 0, 31, row], 4)],
+                0.02)
+
+
+@pytest.fixture(scope="module", params=WIDTHS + ["latent-long"],
+                ids=lambda w: w if isinstance(w, str) else f"{w[0]}x{w[1]}")
 def width(request):
     """Noise in every slot of every block, the null block included, and
     the kernel jitted once a width."""
-    heads, d = request.param
-    rng = np.random.default_rng(heads)
-    k_pool, v_pool = (jnp.asarray(
-        rng.standard_normal((L, NB, BS, heads * d)), jnp.bfloat16)
-        for _ in range(2))
-    q = jnp.asarray(rng.standard_normal((S, heads * d)), jnp.bfloat16)
-    run = jax.jit(lambda q, kp, vp, l, t, p: PA.paged_attention(
-        q, kp, vp, l, t, p, heads=heads,
-        interpret=pltpu.InterpretParams()))
-    return heads, q, k_pool, v_pool, run
+    if request.param == "latent-long":
+        return _latent_long()
+    return _multi_head(*request.param)
 
 
 @pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("pattern", list(PATTERNS))
 def test_kernel_matches_the_gather_paths_mathematics(width, pattern, layer):
-    heads, q, k_pool, v_pool, run = width
-    lens = PATTERNS[pattern]
-    tables, positions = tables_for(lens, np.random.default_rng(layer))
-    out = np.asarray(run(q, k_pool, v_pool, jnp.int32(layer), tables,
-                         positions).astype(jnp.float32))
-    ref = np.asarray(gather_math(q, k_pool, v_pool, layer, tables,
-                                 positions, heads))
+    lens = width.patterns[pattern]
+    tables, positions = width.tables_for(lens, np.random.default_rng(layer))
+    args = width.args(layer, tables, positions)
+    out = np.asarray(width.run(*args))
     live = np.asarray(lens) > 0
-    # bf16 weights and a bf16 result over unit-normal values: 2^-8 of 3.5
-    assert np.abs(out[live] - ref[live]).max(initial=0.0) < 0.03
+    if live.any():
+        ref = np.asarray(width.ref(*args))
+        assert np.abs(out[live] - ref[live]).max() < width.tol
     assert not out[~live].any()      # an inactive slot reads nothing
 
 
@@ -101,10 +189,9 @@ def test_garbage_past_the_length_does_not_show(width):
     """Slots past a sequence's length in its last block, every block it
     does not own and the null block hold huge values; the result is
     bit-identical to the one over a pool that holds zeros there."""
-    heads, q, k_pool, v_pool, run = width
-    lens = [17, 1, 250, 33, 0]
-    tables, positions = tables_for(lens, np.random.default_rng(5))
-    own = np.zeros((NB, BS), bool)
+    lens = width.garbage
+    tables, positions = width.tables_for(lens, np.random.default_rng(5))
+    own = np.zeros((width.pool_blocks, BS), bool)
     for s, n in enumerate(lens):
         for t in range(n):
             own[int(tables[s, t // BS]), t % BS] = True
@@ -112,9 +199,9 @@ def test_garbage_past_the_length_does_not_show(width):
     outs = []
     for junk in (0.0, 3e4):
         kp, vp = (jnp.where(keep, p, jnp.asarray(junk, p.dtype))
-                  for p in (k_pool, v_pool))
-        outs.append(np.asarray(run(q, kp, vp, jnp.int32(1), tables,
-                                   positions).astype(jnp.float32)))
+                  for p in (width.k_pool, width.v_pool))
+        outs.append(np.asarray(width.run(width.queries, kp, vp, jnp.int32(1),
+                                         tables, positions)))
     assert np.isfinite(outs[1]).all()
     np.testing.assert_array_equal(outs[0], outs[1])
 
@@ -123,19 +210,18 @@ def test_a_slots_result_does_not_depend_on_its_neighbours(width):
     """Row independence (`ServeModel`'s contract): slot 2 keeps its table
     and length while every other slot's change, and where it sits among
     them; its context is bit-identical."""
-    heads, q, k_pool, v_pool, run = width
     rng = np.random.default_rng(9)
-    base_t, base_p = tables_for([0, 0, 273, 0, 0], rng)
+    base_t, base_p = width.tables_for(width.neighbours[0][0], rng)
     row = None
-    for lens, at in (([0, 0, 273, 0, 0], 2), ([320, 1, 273, 17, 256], 2),
-                     ([16, 300, 273, 0, 5], 2), ([100, 0, 0, 31, 273], 4)):
+    for lens, at in width.neighbours:
         others = [n if s != at else 0 for s, n in enumerate(lens)]
-        tables, positions = tables_for(others, np.random.default_rng(at))
+        tables, positions = width.tables_for(others,
+                                             np.random.default_rng(at))
         tables = tables.at[at].set(base_t[2])
         positions = positions.at[at].set(base_p[2])
-        qs = q.at[at].set(q[2])
-        out = np.asarray(run(qs, k_pool, v_pool, jnp.int32(2), tables,
-                             positions).astype(jnp.float32))[at]
+        queries = tuple(q.at[at].set(q[2]) for q in width.queries)
+        out = np.asarray(width.run(queries, width.k_pool, width.v_pool,
+                                   jnp.int32(2), tables, positions))[at]
         if row is None:
             row = out
             assert np.abs(row).max() > 0
@@ -204,33 +290,78 @@ RUN_PATTERNS = {
 }
 
 
-def _tables(pattern, per_block):
+# The latent walk under a table of `_LONG_TABLE` tokens (`LONG_MB` entries;
+# a chunk is `_LONG_CHUNK` tokens, 64 blocks, its step two sub-tiles of
+# 512): a slot's region is `LONG_R_MB` = 300 blocks, 4 2/3 chunks
+LONG_R_MB = 300
+
+
+def _long_desc(n):
+    return list(range(LONG_R_MB - 1, LONG_R_MB - 1 - n, -1))
+
+
+LONG_RUN_PATTERNS = {
+    # rows that end in their last chunk's first sub-tile, on a sub-tile's
+    # edge, in the last sub-tile and on the chunk's edge
+    "long-all-runs": ([(4096 + 300, _asc), (2048 + 1024, _asc),
+                       (2047, _asc), (1536, _asc)], "all"),
+    "long-descending": ([(4096 + 300, _long_desc), (2048 + 1024, _long_desc),
+                         (2047, _long_desc), (1536, _long_desc)], "none"),
+    # a prompt's run and its growth's: the break lies inside chunk 2 (at
+    # block 150; the growth 20 blocks from block 200 on), the second row's
+    # inside chunk 0, on no sub-tile's edge; the third's first two chunks
+    # are runs and its third scattered
+    "long-run-breaks-mid-chunk": (
+        [(170 * 16 - 5, lambda n: _asc(150) + list(range(200, 220))),
+         (1900, lambda n: _asc(37) + list(range(100, 100 + n - 37))),
+         (2048 + 200, lambda n: _asc(128) + [128 + j for j in _pairs(13)]),
+         (0, _asc)], "some"),
+    "long-under-a-sub-tile": ([(5, _asc), (511, _asc), (512, _asc),
+                               (513, _asc)], "all"),
+}
+
+
+class _Geometry(NamedTuple):
+    region: int         # blocks of the pool a slot's table draws from
+    blocks: int         # a table's width
+    patterns: dict
+    desc: Callable
+
+    @property
+    def pool_blocks(self):
+        return 1 + R_S * self.region
+
+
+_SHORT = _Geometry(R_MB, R_MB, RUN_PATTERNS, _desc)
+_LONG = _Geometry(LONG_R_MB, LONG_MB, LONG_RUN_PATTERNS, _long_desc)
+
+
+def _tables(geo, pattern, per_block):
     """(tables, positions) of a pattern; `per_block`: the same slots and
     lengths with every table descending, which the walk takes a block at
     a time."""
-    tables = np.zeros((R_S, R_MB), np.int32)
+    tables = np.zeros((R_S, geo.blocks), np.int32)
     positions = np.zeros((R_S,), np.int32)
-    for s, (n, where) in enumerate(RUN_PATTERNS[pattern][0]):
+    for s, (n, where) in enumerate(geo.patterns[pattern][0]):
         blocks = -(-n // BS)
-        offs = _desc(blocks) if per_block else where(blocks)
+        offs = geo.desc(blocks) if per_block else where(blocks)
         assert len(offs) == blocks and len(set(offs)) == blocks
-        tables[s, :blocks] = 1 + s * R_MB + np.asarray(offs, np.int32)
+        tables[s, :blocks] = 1 + s * geo.region + np.asarray(offs, np.int32)
         positions[s] = max(n - 1, 0)
     return tables, positions
 
 
-def _laid_out(contents, tables, seed):
+def _laid_out(geo, contents, tables, seed):
     """The pools `[L, NB, BS, width]` that hold slot s's block j
     (`contents[i][:, s, j]`) where `tables` says, noise everywhere else."""
     rng = np.random.default_rng(seed)
     pools = []
     for c in contents:
         pool = rng.standard_normal(
-            (R_L, R_NB, BS, c.shape[-1])).astype(np.float32)
+            (R_L, geo.pool_blocks, BS, c.shape[-1])).astype(np.float32)
         for s in range(R_S):
-            for j in range(R_MB):
-                if tables[s, j]:
-                    pool[:, tables[s, j]] = c[:, s, j]
+            live = np.flatnonzero(tables[s])
+            pool[:, tables[s, live]] = c[:, s, live]
         pools.append(jnp.asarray(pool, jnp.bfloat16))
     return pools
 
@@ -269,44 +400,44 @@ def _latent_case(rng):
         ql, qr, cp, rp, jnp.int32(1), t, p, scale=scale,
         interpret=pltpu.InterpretParams()).reshape(R_S, heads * latent))
 
-    def ref(cp, rp, t, p):
-        keys = kvc.gather_kv(cp, 1, t).astype(jnp.float32)
-        rot = kvc.gather_kv(rp, 1, t).astype(jnp.float32)
-        sc = (jnp.einsum("snc,smc->snm", ql.astype(jnp.float32), keys)
-              + jnp.einsum("snr,smr->snm", qr.astype(jnp.float32), rot))
-        seen = jnp.arange(keys.shape[1])[None, :] <= p[:, None]
-        sc = jnp.where(seen[:, None, :], sc * scale, -jnp.inf)
-        return jnp.einsum("snm,smc->snc", jax.nn.softmax(sc, -1),
-                          keys).reshape(R_S, heads * latent)
-
+    ref = lambda cp, rp, t, p: latent_math(     # noqa: E731
+        ql, qr, cp, rp, 1, t, p, scale)
     return (latent, rope), run, ref, 0.02
 
 
 _RUN_KERNELS = {"paged": _paged_case,
                 "paged-narrow": functools.partial(_paged_case, heads=2),
-                "gqa": _gqa_case, "latent": _latent_case}
+                "gqa": _gqa_case, "latent": _latent_case,
+                "latent-long": _latent_case}
+_RUN_CASES = [(k, p) for k in _RUN_KERNELS
+              for p in (LONG_RUN_PATTERNS if k == "latent-long"
+                        else RUN_PATTERNS)]
 
 
-@pytest.fixture(scope="module", params=list(_RUN_KERNELS))
+@pytest.fixture(scope="module")
 def run_kernel(request):
     """A kernel jitted once, its reference, and what every slot's blocks
     hold (both pools), wherever a table puts them."""
     rng = np.random.default_rng(11)
+    geo = _LONG if request.param == "latent-long" else _SHORT
     widths, run, ref, tol = _RUN_KERNELS[request.param](rng)
-    contents = [rng.standard_normal((R_L, R_S, R_MB, BS, w)).astype(
+    contents = [rng.standard_normal((R_L, R_S, geo.blocks, BS, w)).astype(
         np.float32) for w in widths]
-    per_chunk = PA.blocks_per_chunk(BS, 2 * sum(widths))
-    assert per_chunk == (16 if request.param == "paged" else 32)
-    return contents, run, ref, tol, per_chunk
+    per_chunk = PA.blocks_per_chunk(BS, 2 * sum(widths), geo.blocks * BS)
+    assert per_chunk == {"paged": 16, "latent-long": 64}.get(
+        request.param, 32)
+    return geo, contents, run, ref, tol, per_chunk
 
 
-@pytest.mark.parametrize("pattern", list(RUN_PATTERNS))
+@pytest.mark.parametrize("run_kernel,pattern", _RUN_CASES,
+                         indirect=["run_kernel"],
+                         ids=[f"{k}-{p}" for k, p in _RUN_CASES])
 def test_a_run_of_blocks_in_one_copy_gives_the_per_block_walks_bits(
         run_kernel, pattern):
-    contents, run, ref, tol, per_chunk = run_kernel
+    geo, contents, run, ref, tol, per_chunk = run_kernel
     outs = []
     for per_block in (False, True):
-        tables, positions = _tables(pattern, per_block)
+        tables, positions = _tables(geo, pattern, per_block)
         live = [t[t > 0] for t in tables]
         runs, chunks = np.sum([kvc.run_chunks(t, per_chunk) for t in live],
                               axis=0)
@@ -314,14 +445,14 @@ def test_a_run_of_blocks_in_one_copy_gives_the_per_block_walks_bits(
             assert not any((np.diff(t) == 1).any() for t in live)
         else:
             assert {"all": runs == chunks, "none": runs == 0,
-                    "some": 0 < runs < chunks}[RUN_PATTERNS[pattern][1]]
-            assert tables.max() <= R_NB - 1
-        pools = _laid_out(contents, tables, seed=int(per_block))
+                    "some": 0 < runs < chunks}[geo.patterns[pattern][1]]
+            assert tables.max() <= geo.pool_blocks - 1
+        pools = _laid_out(geo, contents, tables, seed=int(per_block))
         t, p = jnp.asarray(tables), jnp.asarray(positions)
         outs.append(np.asarray(run(*pools, t, p), np.float32))
     np.testing.assert_array_equal(outs[0], outs[1])
     want = np.asarray(ref(*pools, t, p))
-    active = np.asarray([n > 0 for n, _ in RUN_PATTERNS[pattern][0]])
+    active = np.asarray([n > 0 for n, _ in geo.patterns[pattern][0]])
     assert np.abs(outs[0] - want)[active].max() < tol
     assert not outs[0][~active].any()       # an inactive slot reads nothing
 
@@ -348,6 +479,53 @@ def test_a_wide_caches_call_is_the_one_it_always_was(heads, scalars,
     assert [v.aval.ndim for v in call.invars[-2:]] == [pool_rank] * 2
     counting = {"eq", "reduce_sum"} & {e.primitive.name for e in jaxpr.eqns}
     assert len(counting) == (2 if scalars == 4 else 0)
+
+
+def _latent_call(blocks):
+    """The traced `pallas_call` of the latent walk (the latent cache's 512
+    + 128 lanes) under tables `blocks` wide."""
+    sds = jax.ShapeDtypeStruct
+    bf16 = jnp.bfloat16
+    jaxpr = jax.make_jaxpr(
+        lambda ql, qr, cp, rp, t, p: PA.paged_latent_attention(
+            ql, qr, cp, rp, jnp.int32(1), t, p, scale=0.1))(
+                sds((R_S, 32, 512), bf16), sds((R_S, 32, 128), bf16),
+                sds((R_L, 65, BS, 512), bf16), sds((R_L, 65, BS, 128), bf16),
+                sds((R_S, blocks), jnp.int32), sds((R_S,), jnp.int32)).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return call
+
+
+# a table's width in blocks of 16 tokens: `rag_closed`'s 4608 tokens,
+# `doc_sessions`' 9216, one block under `_LONG_TABLE`, `_LONG_TABLE`, and
+# `ctx12k_sessions`' 20480
+@pytest.mark.parametrize("blocks,long", [
+    (288, False), (576, False), (LONG_MB - 1, False), (LONG_MB, True),
+    (1280, True)])
+def test_the_narrow_walks_chunk_follows_the_tables_width(blocks, long):
+    """The chunk is ONE number (`chunk_tokens`), from the cache's token
+    bytes and the table's width alone: under `_LONG_TABLE` tokens a narrow
+    cache's walk traces to the scratch it always traced to, `[2, 512,
+    lanes]` a pool and a run count a chunk of 32 blocks; from there on to
+    `_LONG_CHUNK` tokens a buffer, and its table counts two runs a chunk
+    (`Tables.runs`). The allocator's count goes by the same number."""
+    chunk = PA._LONG_CHUNK if long else 512
+    assert PA.chunk_tokens(1280, blocks * BS) == chunk
+    assert PA.chunk_tokens(2 * 2 * 1280, blocks * BS) == 256    # a wide one
+    call = _latent_call(blocks)
+    grid = call.params["grid_mapping"]
+    scratch = call.params["jaxpr"].invars[-grid.num_scratch_operands:]
+    assert [v.aval.shape for v in scratch[:2]] == [(2, chunk, 512),
+                                                   (2, chunk, 128)]
+    runs = call.invars[grid.num_index_operands - 1].aval.shape
+    chunks = -(-blocks * BS // chunk)
+    assert runs == (R_S, 2 * chunks if long else chunks)
+    al = kvc.BlockAllocator(kvc.KVCacheConfig(
+        layers=1, widths=(512, 128), max_len=blocks * BS, block_size=BS,
+        num_blocks=9))
+    assert al.per_chunk == chunk // BS == PA.blocks_per_chunk(
+        BS, 1280, blocks * BS)
+    assert al.stats()["walk_chunk_tokens"] == chunk
 
 
 # -- the gate -----------------------------------------------------------------
@@ -440,6 +618,27 @@ def test_decode_step_through_the_kernel_agrees_with_the_gather_path(
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(vp)[:, own], np.asarray(vp2)[:, own],
                                atol=1e-4)
+
+
+def test_engine_status_reports_the_walks_chunk():
+    """`status()["kv"]["walk_chunk_tokens"]`: what the walk over this
+    engine's cache takes a chunk, the unit `run_chunk_share` counts by
+    (two heads of 8: a narrow cache, under a table of 32 tokens)."""
+    from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
+
+    cfg = gpt.GPTConfig.tiny()
+    params, _ = gpt.init(jax.random.key(0), cfg)
+    engine = DecodeEngine(params, cfg, DecodeConfig(
+        block_size=8, num_blocks=17, decode_slots=(2,), prefill_buckets=(8,),
+        max_len=32))
+    try:
+        kv = engine.status()["kv"]
+        assert kv["walk_chunk_tokens"] == PA.chunk_tokens(
+            engine.kv_cfg.walk_bytes_per_token(), 32) == 512
+        assert kv["walk_chunk_tokens"] == 8 * engine._alloc.per_chunk
+        assert kv["run_chunk_share"] is None
+    finally:
+        engine.stop()
 
 
 def test_engine_status_reports_the_route():

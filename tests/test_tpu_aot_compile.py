@@ -157,6 +157,10 @@ _CASES = [
                  id="paged-latent-joyai-4608x32"),
     pytest.param(_paged_latent, _LATENT, (16, 64, 3, 32, jnp.float32),
                  id="paged-latent-f32"),
+    # the same walk under xing4's table of 20480 tokens: a chunk of
+    # `_LONG_CHUNK` tokens a copy, the step in sub-tiles (`chunk_tokens`)
+    pytest.param(_paged_latent, _LATENT, (32, 1280, 6, 32, jnp.bfloat16),
+                 id="paged-latent-xing4-20480x32"),
     # grouped-query decode attention: Nemotron-3-Nano's 32 query heads over
     # 2 K/V heads of 128 at the benchmark's 64 slots x 2560 tokens, and
     # float32 pools
@@ -1113,6 +1117,18 @@ def test_xing4_serve_program_fits_whatever_the_prompts_length(
         # the two leading dense layers' and the scan body's
         latent = [k for k in kernels if "paged_latent_attention" in k]
         assert len(latent) == 3 and all("/attention/" in k for k in latent)
+        # under a table of 20480 tokens the walk's chunk is `_LONG_CHUNK`
+        # tokens (`chunk_tokens`): 20 chunks of 64 blocks a slot, and of
+        # each the tables count TWO runs (`Tables.runs`: the lead, a
+        # cumulative product over its 63 pairs of neighbours, and the run
+        # after the first break, a cumulative sum), ONCE a step, in the
+        # entry computation, for the three kernels and every layer
+        assert PA.chunk_tokens(kv.walk_bytes_per_token(),
+                               _XING4_CONTEXT) == 1024
+        entry = text[text.index("\nENTRY "):]
+        windows = re.findall(r"= s32\[32,20,63\]\S* reduce-window\(", text)
+        assert len(windows) == 2 and all(w in entry for w in windows), \
+            windows
         # nothing holds a slot's cached context: no result has a dimension
         # of the context's 20480 tokens or of a table's 1280 blocks x 16
         held = [d for d in re.findall(r"= \(?\w+\[([\d,]+)\]", text)
