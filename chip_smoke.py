@@ -384,11 +384,14 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
     import numpy as np
 
     from paddle_tpu.models import gpt
-    from paddle_tpu.ops.pallas import paged_attention, ssm_update
+    from paddle_tpu.ops.pallas import (grouped_matmul, paged_attention,
+                                       ssm_update)
     from paddle_tpu.serving import Server, ServingConfig, kv_cache
     from paddle_tpu.serving.decode import DecodeEngine
 
     params, _ = _init(model or gpt, cfg)
+    grouped_matmul.GATE_COUNTS.clear()
+    grouped_matmul.TILES.clear()
     paged_attention.GATE_COUNTS.clear()
     ssm_update.GATE_COUNTS.clear()
     kv_cache.PREFILL_WRITE_UNITS.clear()
@@ -444,6 +447,7 @@ def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
                  "compiles_after_warmup": late_compiles,
                  "finished": status["requests"],
                  "decode_attention": status["decode_attention"],
+                 "expert_matmul": status["expert_matmul"],
                  "prefill_write": status["prefill_write"],
                  # the state row pool of a model with recurrent layers
                  "state": status.get("state"),
@@ -774,6 +778,14 @@ def run_one_chip() -> None:
         # same tokens slower and nothing else would say so
         assert checked["decode_attention"] == {"paged_gqa": 1}, info
         assert checked["state"]["update"] == {"kernel": 2}, info
+        # the experts of the three prefill programs through the megablox
+        # kernel, their columns whole (the decode program's 16 slots x 6
+        # experts are 96 rows, under a row tile: `ragged_dot`)
+        assert checked["expert_matmul"]["routes"] == {
+            "megablox": 12, "xla": 4}, info
+        assert checked["expert_matmul"]["tiles"] == {
+            "1920x2688": [128, 640, 2688],
+            "2688x1920": [128, 896, 1920]}, info
         assert checked["state"]["rows"] == 16 \
             and checked["state"]["used"] == 0, info
         # one attention block: K and V, three prefill programs

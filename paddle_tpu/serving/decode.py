@@ -69,6 +69,7 @@ from ..observability import metrics as _m
 from ..observability import perfwatch as _perfwatch
 from ..observability import telemetry as _telemetry
 from ..observability import tracing as _tracing
+from ..ops.pallas import grouped_matmul as _grouped_matmul
 from ..ops.pallas import paged_attention as _paged_attention
 from ..ops.pallas import ssm_update as _ssm_update
 from .batcher import QueueFullError, ServerClosed
@@ -1317,6 +1318,15 @@ class DecodeEngine:
             # program of this process ("paged", "paged_latent": a kernel
             # over the live blocks; "gather": the padded gather)
             "decode_attention": dict(_paged_attention.GATE_COUNTS),
+            # which route the expert layers' grouped matmuls took, a count
+            # a traced call ("megablox": the kernel; "xla": `ragged_dot`),
+            # and the kernel's tiles by the matrix it read, which
+            # `grouped_matmul.tiles` chose from the shapes:
+            # {"2688x1920": [128, 896, 1920]}; both {} for a dense model
+            "expert_matmul": {
+                "routes": dict(_grouped_matmul.GATE_COUNTS),
+                "tiles": {f"{k}x{n}": list(t) for (k, n), t in
+                          sorted(_grouped_matmul.TILES.items())}},
             # which unit whole-prompt writes into the pool took, a count a
             # traced write, two (K and V) a prefill program ("blocks": a
             # bucket of whole blocks goes in a block at a time; "rows": a

@@ -199,6 +199,9 @@ def test_rehearse_serve_nemotron(smoke):
     assert checked["finished"]["length"] == 3
     assert checked["compiles_after_warmup"] == 0
     assert checked["decode_attention"] == {"gather": 1}
+    # two expert blocks, two matmuls each, in three programs; no kernel
+    # off the chip, so no tiles
+    assert checked["expert_matmul"] == {"routes": {"xla": 12}, "tiles": {}}
     assert checked["state"]["update"] == {"xla": 2}
     assert checked["state"]["rows"] == 4 and checked["state"]["used"] == 0
     assert checked["state"]["bytes"] > 0

@@ -132,11 +132,50 @@ def test_relu2_experts_through_the_shared_expert_layer(model):
         moe.expert_mlp(lp, y, dataclasses.replace(cfg.routing, form="glu"))
 
 
-@pytest.mark.parametrize("size, cap, tile", [
-    (2048, 1024, 1024), (768, 1024, 768), (1024, 1024, 1024),
-    (2688, 1024, 896), (1920, 1024, 640), (128, 1024, 128)])
-def test_the_grouped_matmuls_tile_divides_the_width(size, cap, tile):
-    assert gm._tile(size, cap) == tile and size % tile == 0
+# every (K, N) the four sparse configurations hand the grouped matmul:
+# OLMoE's, JoyAI's and Xing4's gate/up then down, and Nemotron's up and down
+_OTHERS = [(2048, 1024), (1024, 2048), (2048, 768), (768, 2048),
+           (3584, 1024), (1024, 3584)]
+_NEMOTRON = [(2688, 1920), (1920, 2688)]
+
+
+# those, then a K and an N too long for a whole dimension, and one lane tile
+@pytest.mark.parametrize("k, n, tile", [
+    (2048, 1024, (1024, 1024)), (1024, 2048, (512, 2048)),
+    (2048, 768, (2048, 768)), (768, 2048, (768, 2048)),
+    (3584, 1024, (1792, 1024)), (1024, 3584, (512, 3584)),
+    (2688, 1920, (896, 1920)), (1920, 2688, (640, 2688)),
+    (65536, 1024, (1024, 1024)), (1024, 65536, (128, 8192)),
+    (128, 128, (128, 128))])
+def test_the_grouped_matmuls_tiles_follow_the_shapes(k, n, tile):
+    """`grouped_matmul.tiles` reads (K, N, itemsize) alone: the tiles divide
+    the operands, a weight tile is within the budget, what the kernel
+    holds fits its VMEM, and the columns are whole wherever a tile of
+    whole columns fits."""
+    tm, tk, tn = gm.tiles(k, n, 2)
+    assert (tk, tn) == tile and tm == gm.ROW_TILE
+    assert k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+    assert tk * tn * 2 <= gm.TILE_BYTES < 4 * 2 ** 20
+    assert gm.planned_vmem((tm, tk, tn), 2) <= gm.VMEM_BYTES == 16 * 2 ** 20
+    if 128 * n * 2 <= gm.TILE_BYTES:
+        assert tn == n
+    # float32 operands: half the elements, the same bytes
+    fm, fk, fn = gm.tiles(k, n, 4)
+    assert k % fk == 0 and n % fn == 0 and fk * fn * 4 <= gm.TILE_BYTES
+    assert gm.planned_vmem((fm, fk, fn), 4) <= gm.VMEM_BYTES
+
+
+def test_nemotrons_tiles_are_no_smaller_than_the_other_models():
+    """The widths that are no power of two (2688 = 21 x 128, 1920 = 15 x
+    128) once fell to the smallest tiles of the four, 1.15 MB in pieces a
+    third of a row long; the rule gives them whole rows and tiles as large
+    as anyone's."""
+    def tile_bytes(kn):
+        _, tk, tn = gm.tiles(*kn, 2)
+        return tk * tn * 2
+    ours = [tile_bytes(kn) for kn in _NEMOTRON]
+    theirs = [tile_bytes(kn) for kn in _OTHERS]
+    assert min(ours) >= min(theirs) and min(ours) > 3e6
 
 
 # -- the recurrence ----------------------------------------------------------

@@ -285,6 +285,28 @@ def test_step_records_count_the_experts_only_while_recording(model, engine):
                                                   "expert_load_max"}
 
 
+def test_status_names_the_grouped_matmuls_route_and_tiles(engine,
+                                                          monkeypatch):
+    """`status()["expert_matmul"]`: the route the expert layers' traces
+    took (off the chip `ragged_dot`, which has no tiles) and, where the
+    kernel ran, its tiles by matrix, in a form JSON carries."""
+    import json
+
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    gm.grouped_matmul(jnp.ones((4, 16)), jnp.ones((2, 16, 8)),
+                      jnp.asarray([1, 3], jnp.int32))
+    got = engine.status()["expert_matmul"]
+    assert got["routes"] == dict(gm.GATE_COUNTS) and got["routes"]["xla"] >= 1
+    assert "megablox" not in got["routes"] and got["tiles"] == {}
+    # what a traced kernel call leaves behind, as `grouped_matmul` does
+    monkeypatch.setattr(gm, "TILES", {
+        (k, n): gm.tiles(k, n, 2) for k, n in [(2688, 1920), (1920, 2688)]})
+    got = json.loads(json.dumps(engine.status()))["expert_matmul"]
+    assert got["tiles"] == {"1920x2688": [128, 640, 2688],
+                            "2688x1920": [128, 896, 1920]}
+
+
 def test_lm_loss_falls_through_the_train_driver(model):
     import optax
 

@@ -512,6 +512,7 @@ def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
 
     monkeypatch.setattr(A, "_platform", lambda q: "tpu")
     gm.GATE_COUNTS.clear()
+    gm.TILES.clear()
     cfg, params, pool, sds = olmoe_8l
     sm = cfg.serve_model()
     kw = dict(block_size=_BLOCK, eos_id=-1)
@@ -544,6 +545,8 @@ def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
     # the three grouped matmuls are the megablox kernel, and the profile
     # will find them under mlp/experts
     assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
+    assert gm.TILES == {(2048, 1024): (128, 1024, 1024),
+                        (1024, 2048): (128, 512, 2048)}, gm.TILES
     kernels = _kernels(text)
     # (the decode program's fourth kernel is attention's: below; the
     # prefill's other two write the prompt's K and V a block at a time)
@@ -554,6 +557,39 @@ def test_olmoe_serve_program_fits_and_leaves_the_experts_in_place(
         assert kvc.PREFILL_WRITE_UNITS == {"blocks": 2}
         assert sum("kv_block_write" in k for k in kernels) == 2, kernels
         assert not _pool_scatters(text, pool.shape)
+
+
+@pytest.mark.parametrize("k, n", [(2688, 1920), (1920, 2688), (1920, 1024)])
+def test_grouped_matmuls_gradient_fits_at_the_rules_tiles(v5e, k, n,
+                                                          monkeypatch):
+    """The backward pass of `grouped_matmul` takes the forward's tuple
+    (megablox's `custom_vjp`): a transposed `gmm` for the rows and `tgmm`
+    for the matrices, whose float32 accumulator and two output tiles have
+    the WEIGHT tile's shape. At Nemotron's widths (tiles of 3.4 MB) and at
+    a matrix that is one tile of exactly `TILE_BYTES` it fits the kernel's
+    16 MiB; at 4 MiB `tgmm` asked for 16.38 (PERF.md section 6, PR 47)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    gm.GATE_COUNTS.clear()
+    gm.TILES.clear()
+    one = SingleDeviceSharding(v5e[0])
+
+    def loss(x, w, sizes):
+        return gm.grouped_matmul(x, w, sizes).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.ShapeDtypeStruct((384, k), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((128, k, n), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one)).compile()
+    assert gm.GATE_COUNTS == {"megablox": 1}, gm.GATE_COUNTS
+    tm, tk, tn = gm.TILES[k, n]
+    assert tn == n and 3.4e6 < tk * tn * 2 <= gm.TILE_BYTES
+    # the rows' gradient and the matrices' (the sum's gradient needs no
+    # forward value, so the forward kernel is not in the program)
+    kernels = _kernels(compiled.as_text())
+    assert sorted(k.split("/")[-2] for k in kernels) == [
+        "transpose(jvp(jit(gmm)))", "transpose(jvp(jit(tgmm)))"], kernels
 
 
 # ---------------------------------------------------------------------------
@@ -656,7 +692,7 @@ def test_joyai_serve_program_fits_and_reads_the_latent_cache_in_place(
     kind, n = program.split("@")
     n = int(n)
     mb = _JOYAI_CONTEXT // _BLOCK
-    for counts in (gm.GATE_COUNTS, PA.GATE_COUNTS, A.GATE_COUNTS,
+    for counts in (gm.GATE_COUNTS, gm.TILES, PA.GATE_COUNTS, A.GATE_COUNTS,
                    kvc.PREFILL_WRITE_UNITS):
         counts.clear()
     if kind == "decode":
@@ -687,6 +723,9 @@ def test_joyai_serve_program_fits_and_reads_the_latent_cache_in_place(
     # the expert layers' grouped matmuls are the megablox kernel (traced
     # once: the four expert layers are one scan body), under mlp/experts
     assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
+    # each matrix whole in one tile of 3.1 MB
+    assert gm.TILES == {(2048, 768): (128, 2048, 768),
+                        (768, 2048): (128, 768, 2048)}, gm.TILES
     kernels = _kernels(text)
     assert sum("/mlp/experts/" in k for k in kernels) == 3 and all(
         "/mlp/experts/" in k or "/attention/" in k or "/kv_write/" in k
@@ -770,7 +809,7 @@ def test_nemotron_serve_program_fits_and_updates_the_state_in_place(
     kind, n = program.split("@")
     n = int(n)
     mb = _NEMOTRON_CONTEXT // _BLOCK
-    for counts in (gm.GATE_COUNTS, PA.GATE_COUNTS, SU.GATE_COUNTS,
+    for counts in (gm.GATE_COUNTS, gm.TILES, PA.GATE_COUNTS, SU.GATE_COUNTS,
                    kvc.PREFILL_WRITE_UNITS):
         counts.clear()
     if kind == "decode":
@@ -822,9 +861,12 @@ def test_nemotron_serve_program_fits_and_updates_the_state_in_place(
     # no op makes a layer's slice of an expert stack
     slices = re.findall(r"= \(?bf16\[128,(?:2688,1920|1920,2688)\]", text)
     assert not slices, slices[:3]
-    # two grouped matmuls an expert block, the megablox kernel (2688 and
-    # 1920 are not multiples of the 1024 tile: the tile adapts)
+    # two grouped matmuls an expert block, the megablox kernel, its
+    # columns whole in tiles of 3.4 MB (2688 = 21 x 128 and 1920 = 15 x
+    # 128: `grouped_matmul.tiles` goes by divisors)
     assert gm.GATE_COUNTS == {"megablox": 8}, gm.GATE_COUNTS
+    assert gm.TILES == {(2688, 1920): (128, 896, 1920),
+                        (1920, 2688): (128, 640, 2688)}, gm.TILES
     kernels = _kernels(text)
     assert sum("/mlp/experts/" in k for k in kernels) == 8, kernels
     if kind == "decode":
@@ -1077,7 +1119,7 @@ def test_xing4_serve_program_fits_whatever_the_prompts_length(
     kind, n = program.split("@")
     n = int(n)
     mb = _XING4_CONTEXT // _BLOCK
-    for counts in (gm.GATE_COUNTS, PA.GATE_COUNTS, A.GATE_COUNTS,
+    for counts in (gm.GATE_COUNTS, gm.TILES, PA.GATE_COUNTS, A.GATE_COUNTS,
                    kvc.PREFILL_WRITE_UNITS):
         counts.clear()
     if kind == "decode":
@@ -1106,6 +1148,8 @@ def test_xing4_serve_program_fits_whatever_the_prompts_length(
     slices = re.findall(r"= \(?bf16\[64,(?:3584,1024|1024,3584)\]", text)
     assert not slices, slices[:3]
     assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
+    assert gm.TILES == {(3584, 1024): (128, 1792, 1024),
+                        (1024, 3584): (128, 512, 3584)}, gm.TILES
     kernels = _kernels(text)
     # nothing of the residual path is a kernel, and nothing of it lies
     # under `attention` or `mlp`
