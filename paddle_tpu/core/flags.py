@@ -22,10 +22,8 @@ _DEFAULTS: Dict[str, Any] = {
     "FLAGS_profile_dir": "/tmp/paddle_tpu_profile",
     # attention kernel selection: "auto" (splash_attention for mask-free/
     # causal T>=1024 on TPU — tuned blocks beat XLA bf16-scores 2.2x at
-    # T=4096, PROFILE.md round 4; XLA path otherwise — the legacy flash
-    # kernel is never auto-selected, PROFILE.md round 3), "splash" (force
-    # splash on any eligible shape), "on" (force the legacy Pallas flash
-    # kernel on TPU), "off" (always the XLA path)
+    # T=4096, PROFILE.md round 4; XLA path otherwise), "splash" (force
+    # splash on any eligible shape), "off" (always the XLA path)
     "FLAGS_flash_attention": "auto",
     # memory knobs recorded for parity (XLA owns allocation)
     "FLAGS_fraction_of_gpu_memory_to_use": 0.92,

@@ -3,7 +3,7 @@
 Reference: no TPU counterpart — the reference computes attention from
 unfused matmul/softmax ops (e.g. the BERT graph in
 inference/tests/api/analyzer_bert_tester.cc). TPU-native: the gate
-(_use_splash / _multichip_splash_route / _use_pallas) picks a Pallas
+(_use_splash / _multichip_splash_route) picks a Pallas
 kernel route or the XLA einsum+softmax path from the shape, the mesh,
 the platform and FLAGS_flash_attention — and the pick is final: a
 selected kernel that fails to trace or compile raises, it is never
@@ -27,7 +27,7 @@ import jax.numpy as jnp
 # Trace-time gate observability: which attention path was selected.
 # Keys: "splash" (single-device / manual region), "splash_shardmap"
 # (dp/tp shard_map wrapper), "ring_splash" (sp ring with splash blocks),
-# "ring_xla" (sp ring, XLA blocks), "pallas_flash" (legacy kernel),
+# "ring_xla" (sp ring, XLA blocks),
 # "xla". Incremented once per mha() trace; reset with GATE_COUNTS.clear()
 # in tests/dryruns to assert a path actually engaged (VERDICT r5 item 4).
 GATE_COUNTS: collections.Counter = collections.Counter()
@@ -107,36 +107,6 @@ def _mesh_partitionable(q) -> bool:
     abstract = jax.sharding.get_abstract_mesh()
     return (not abstract.empty
             and set(abstract.manual_axes) == set(abstract.axis_names))
-
-
-def _use_pallas(q) -> bool:
-    if _platform(q) != "tpu" or q.ndim != 4 or not _mesh_partitionable(q):
-        return False
-    return _gate_allows(q.shape[1])
-
-
-def _gate_allows(T: int) -> bool:
-    """Mode dispatch of the flash gate, separated from the platform check
-    so the decision logic is unit-testable off-TPU."""
-    mode = _flag_mode()
-    if mode in ("on", "1", "true"):
-        return True
-    if mode in ("off", "0", "false"):
-        return False
-    # Measured on v5e (BERT-base training steps, bf16-scores XLA path as
-    # the baseline): flash is 2.5x slower at T=128, 2.1x at 512, 2.3x at
-    # 1024, 2.7x at 2048, 2.8x at 4096 (bs=2), 2.7x at 8192 (bs=1), 2.8x
-    # at 16384 (bs=1) — and XLA + rematerialization FITS at every one of
-    # those shapes, so the round-2 hypothesis that score buffers crowd
-    # HBM at T>=4096 is refuted on this chip/kernel version. Auto
-    # therefore never selects the jax-shipped LEGACY flash kernel; it
-    # remains an explicit opt-in (FLAGS_flash_attention=on). The long-T
-    # single-chip path is splash_attention (_use_splash, round 4 — tuned
-    # blocks beat XLA bf16-scores 2.2x at T=4096), and long-context
-    # *scaling* is exact ring attention over the 'sp' mesh axis
-    # (ops/pallas/ring_attention.py).
-    del T
-    return False
 
 
 def _multichip_splash_route(q, k, mask, causal):
@@ -261,10 +231,6 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array,
                 causal=causal, scale=scale)
             GATE_COUNTS["ring_xla"] += 1
         return out
-    if _use_pallas(q):
-        out = _pallas_mha(q, k, v, mask, scale, causal)
-        GATE_COUNTS["pallas_flash"] += 1
-        return out
     out = _xla_mha(q, k, v, mask if not causal else _merge_causal(mask, q.shape[1]), scale)
     GATE_COUNTS["xla"] += 1
     return out.astype(q.dtype)
@@ -282,13 +248,13 @@ def _merge_causal(mask, T):
 # Measured on v5e before PR 21 (fwd+bwd, bf16, 12 heads, head_dim 64):
 # splash with the block sizes below beats the XLA bf16-scores path for
 # T >= _SPLASH_MIN_T on full (bidirectional) masks and at every causal
-# shape — unlike the legacy flash_attention kernel, which never won.
+# shape.
 _SPLASH_MIN_T = 1024
 
 
 def _use_splash(q, k, mask, causal) -> bool:
     """Splash handles the padding-free (mask=None) and causal cases; an
-    arbitrary additive mask takes the XLA/legacy paths."""
+    arbitrary additive mask takes the XLA path."""
     if q.ndim != 4 or mask is not None:
         return False  # additive masks (padding) take the XLA path
     T, Tk, hd = q.shape[1], k.shape[1], q.shape[-1]
@@ -305,7 +271,7 @@ def _use_splash(q, k, mask, causal) -> bool:
     if _platform(q) != "tpu":
         return False
     if mode not in ("auto",):
-        return False  # explicit on(legacy flash)/off respected
+        return False  # explicit off respected
     return T >= _SPLASH_MIN_T
 
 
@@ -380,26 +346,3 @@ def _splash_block_with_lse(q, k, v, interpret=False):
     vt = v.transpose(0, 2, 1, 3)
     out, (lse,) = jax.vmap(kernel)(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
-
-
-# ---------------------------------------------------------------------------
-# Pallas flash-attention kernel (TPU)
-# ---------------------------------------------------------------------------
-
-
-def _pallas_mha(q, k, v, mask, scale, causal):
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention)
-
-    # pallas kernel wants [B, N, T, H]
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    ab = None
-    if mask is not None:
-        ab = jnp.broadcast_to(
-            mask.astype(jnp.float32),
-            (q.shape[0], q.shape[2], q.shape[1], k.shape[1]))
-    out = flash_attention(qt, kt, vt, ab=ab, causal=causal,
-                          sm_scale=float(scale))
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)

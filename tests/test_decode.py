@@ -17,6 +17,7 @@ The load-bearing correctness claims pinned here:
   fresh compile events and bit-identical first tokens vs a cold boot.
 """
 
+import functools
 import json
 import os
 import sys
@@ -30,6 +31,7 @@ import pytest
 import jax
 
 import paddle_tpu  # noqa: F401 — package init registers telemetry
+from benchmarks.reference import gpt_ref
 from paddle_tpu import observability
 from paddle_tpu.models import gpt
 from paddle_tpu.observability import events
@@ -40,14 +42,21 @@ from paddle_tpu.serving.kv_cache import (BlockAllocator, KVCacheConfig,
                                          gather_kv, init_pools,
                                          write_prefill_kv, write_token_kv)
 
+from serve_contract import Family, ServeContract, seeded
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@functools.cache
+def _tiny():
+    cfg = gpt.GPTConfig.tiny()
+    cfg.dtype = "float32"  # exactness vs the full-forward reference
+    return cfg, seeded(gpt, cfg)
 
 
 @pytest.fixture(scope="module")
 def model():
-    cfg = gpt.GPTConfig.tiny()
-    cfg.dtype = "float32"  # exactness vs the full-forward reference
-    params, _ = gpt.init(jax.random.key(0), cfg)
+    cfg, params = _tiny()
     return params, cfg
 
 
@@ -65,6 +74,27 @@ def engine(model):
     eng.warmup()
     yield eng
     eng.stop()
+
+
+# ---------------------------------------------------------------------------
+# What every served family must do (tests/serve_contract.py), for GPT-2: the
+# family whose engine tests are this file's. Its programs have no scope and
+# no counter beside the shared ones.
+# ---------------------------------------------------------------------------
+
+FAMILY = Family(
+    module=gpt, tiny=_tiny, ref=gpt_ref,
+    ref_model=lambda cfg: {"heads": cfg.heads, "layers": cfg.layers},
+    gaps=gpt_ref.stream_gaps,
+    tol=5e-5, tol_why="float32 on both sides: rounding on logits whose "
+                      "deviation is 0.16 (the tied embedding at the init's "
+                      "0.02)",
+    faults=(("one_layer_fewer", {"layers": 3}),
+            ("two_heads_for_four", {"heads": 2})))
+
+
+class TestContract(ServeContract):
+    family = FAMILY
 
 
 # ---------------------------------------------------------------------------

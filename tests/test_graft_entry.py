@@ -6,8 +6,6 @@ breaking)."""
 import sys
 
 import jax
-import jax.numpy as jnp
-import pytest
 
 
 def _entry_module():
@@ -33,7 +31,6 @@ def test_entry_traces_and_infers():
     assert out.shape[1] == 128
 
 
-@pytest.mark.slow
 def test_dryrun_multichip_in_process():
     """On the conftest-forced 8-device CPU platform the dryrun runs
     in-process, covering dp/tp/sp and pp/dp/ep/sp end to end."""

@@ -247,12 +247,14 @@ def test_dygraph_lr_scheduler_steps_once_per_minimize(rng):
                                     / g[g != 0]))
             seen.append(round(applied, 6))
         # one schedule step per minimize: steps 0,1 -> 0.1; 2,3 -> 0.01;
-        # 4 -> 0.001. rtol 1e-3: `applied` is RECOVERED from f32 update
-        # deltas (w_before-w_after)/g, whose rounding noise measured right
-        # AT the old 1e-4 bound; schedule values differ by 10x, so 1e-3
-        # still pins the schedule unambiguously.
+        # 4 -> 0.001. rtol 1e-2: `applied` is RECOVERED from f32 update
+        # deltas (w_before-w_after)/g: at the last step the delta is a
+        # thousandth of a gradient beside a weight of order 1, so float32's
+        # rounding of the weight is 0.2% of it about one run in six (the
+        # layer's init is not seeded; PR 40's tier-1 failed on it at 1e-3).
+        # Schedule values differ by 10x, so 1e-2 still pins the schedule.
         np.testing.assert_allclose(seen, [0.1, 0.1, 0.01, 0.01, 0.001],
-                                   rtol=1e-3)
+                                   rtol=1e-2)
         assert sched.step_num == 5
 
 

@@ -1,12 +1,12 @@
 """models/joyai.py against the plain reference (benchmarks/reference/
 joyai_ref.py) at a tiny size on the CPU, seeded random weights, float32:
-the full forward pass (expanded attention), the serve programs through the
-latent paged cache (absorbed attention), the engine end to end, the
-router's properties one by one, and what the comparison tells apart.
-Tolerances: float32 on both sides, so 2e-4 on logits of unit scale is
-rounding; every fault below moves a logit by 100 times that or more."""
+what every served family must do is `tests/serve_contract.py`'s, bound here
+(the full forward pass in the expanded form, the serve programs through the
+latent paged cache in the absorbed one); what is JoyAI's own follows it: the
+router's properties one by one, the latent pool's layout, the two forms of
+attention, and the latent kernel through the interpreter."""
 
-import time
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,83 +16,110 @@ import pytest
 from benchmarks.reference import joyai_ref
 from paddle_tpu.models import decoder, joyai, moe
 from paddle_tpu.serving import kv_cache as kvc
-from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
-
-TOL = 2e-4
-BS = 8      # block size
+from serve_contract import (BS, SLOTS, Family, ServeContract, pools,
+                            program, seeded, table)
 
 
-def _ref_model(cfg):
-    return {"layers": cfg.layers, "dense_layers": cfg.dense_layers,
-            "heads": cfg.heads, "top_k": cfg.top_k,
-            "nope_dim": cfg.nope_dim, "rope_dim": cfg.rope_dim,
-            "v_dim": cfg.v_dim, "kv_rank": cfg.kv_rank,
-            "rope_theta": cfg.rope_theta, "route_scale": cfg.route_scale,
-            "rms_eps": cfg.rms_eps}
-
-
-@pytest.fixture(scope="module")
-def model():
+@functools.cache
+def _tiny():
     cfg = joyai.JoyaiConfig.tiny()      # hidden 64, 4 heads of 16+8 / 16,
     cfg.dtype = "float32"               # latent 32, 1 dense + 2 expert layers
-    params, _ = joyai.init(jax.random.key(0), cfg)   # of 8 experts, top-2
+    params = seeded(joyai, cfg)         # of 8 experts, top-2
     # a correction bias that matters at 8 experts (the configuration's own
     # spread is sized for 256): it changes which experts are chosen
     params["blk.router_bias"] = 0.3 * jax.random.normal(
         jax.random.key(9), params["blk.router_bias"].shape, jnp.float32)
-    return params, cfg, _ref_model(cfg)
+    return cfg, params
 
 
-def _ref_logits(params, ref, ids):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(joyai_ref.logits_rows(
-            params, ref, jnp.asarray(ids), 0, len(ids)))
+# The latent model's own parts nest inside the shared names: the two
+# low-rank projections and RoPE under `qkv`, the absorb products (in the
+# programs that read the cache) under `attention`, the dense layer's MLP
+# and the expert layer's parts, the shared expert among them, under `mlp`
+NESTED = {"mlp": frozenset({"router", "moe_route", "experts",
+                            "shared_expert", "dense_mlp"}),
+          "qkv": frozenset({"mla_q", "mla_kv", "rope"}),
+          "attention": frozenset({"absorb"})}
+
+FAMILY = Family(
+    module=joyai, tiny=_tiny, ref=joyai_ref,    # which reads them by name
+    tol=2e-4, tol_why="float32 on both sides: rounding on logits of unit "
+                      "scale; each of the faults the bf16 tolerance of the "
+                      "benchmark's cell may or may not tell apart moves a "
+                      "logit by 100 times that or more",
+    faults=(("shared_expert_dropped", {"shared_expert": False}),
+            ("scale_left_out", {"route_scale": 1.0}),
+            ("softmax_for_sigmoid", {"score": "softmax"}),
+            ("bias_left_out_of_the_selection", {"bias_selects": False}),
+            ("bias_let_into_the_weights", {"bias_weighs": True}),
+            ("unnormalised_weights", {"norm_topk_prob": False}),
+            ("rotate_half_for_interleaved", {"rope": "half"}),
+            ("rope_over_the_wrong_64", {"rope_on": "nope"}),
+            ("latent_used_before_its_norm", {"kv_norm": False}),
+            ("one_expert_fewer", {"top_k": 1})),
+    # 4 slots x top-2 pairs a layer, 2 expert layers
+    counters={"experts_hit": (2, 16), "expert_load_max": (1, SLOTS)},
+    nested=NESTED, reading=frozenset({"kv_gather", "absorb"}),
+    # the leading dense layer runs before the scan, inside `layers`; the
+    # expert layers in its body
+    paths=(r"/layers/mlp/dense_mlp/", r"/layers/while/body/.*mlp/experts/"))
 
 
-def test_full_forward_matches_the_reference(model):
-    params, cfg, ref = model
-    ids = np.asarray(jax.random.randint(jax.random.key(1), (2, 40), 0,
-                                        cfg.vocab_size))
-    with jax.default_matmul_precision("highest"):
-        got = np.asarray(joyai.apply(params, cfg, jnp.asarray(ids)))
-    for b in range(2):
-        want = _ref_logits(params, ref, ids[b])
-        assert want.std() > 0.5                 # logits of unit scale
-        assert np.abs(got[b] - want).max() < TOL
+class TestContract(ServeContract):
+    family = FAMILY
 
+    def test_the_pools_hold_the_rotary_key_in_its_first_lanes(self,
+                                                              programs):
+        cfg, sm = programs.cfg, programs.sm
+        n = FAMILY.prompts[0]
+        served = programs.served("whole", n)
+        # the counters are the expert layers': the leading dense layer has
+        # none
+        assert served.stats["experts_hit"].shape == (cfg.expert_layers,)
+        facts = sm.step_facts(jax.device_get(served.stats))
+        assert 2 <= facts["experts_hit"] \
+            <= cfg.expert_layers * cfg.top_k * SLOTS
+        assert 1 <= facts["expert_load_max"] <= SLOTS
+        # what lies in the pools: the rotary key in its first lanes, zeros
+        # after
+        used = np.asarray(served.cache.v)[:, programs.blocks[:3]]
+        assert np.abs(used[..., :cfg.rope_dim]).max() > 0.1
+        assert not used[..., cfg.rope_dim:].any()
 
-@pytest.mark.parametrize("fault, switch", [
-    ("shared_expert_dropped", {"shared_expert": False}),
-    ("scale_left_out", {"route_scale": 1.0}),
-    ("softmax_for_sigmoid", {"score": "softmax"}),
-    ("bias_left_out_of_the_selection", {"bias_selects": False}),
-    ("bias_let_into_the_weights", {"bias_weighs": True}),
-    ("unnormalised_weights", {"norm_topk_prob": False}),
-    ("rotate_half_for_interleaved", {"rope": "half"}),
-    ("rope_over_the_wrong_64", {"rope_on": "nope"}),
-    ("latent_used_before_its_norm", {"kv_norm": False}),
-    ("one_expert_fewer", {"top_k": 1})])
-def test_the_comparison_fails_a_wrong_reference(model, fault, switch):
-    """The layer's semantics are pinned in float32: each of the faults the
-    bf16 tolerance of the benchmark's cell may or may not tell apart is
-    100 tolerances away here."""
-    params, cfg, ref = model
-    ids = np.asarray(jax.random.randint(jax.random.key(1), (40,), 0,
-                                        cfg.vocab_size))
-    with jax.default_matmul_precision("highest"):
-        got = np.asarray(joyai.apply(params, cfg, jnp.asarray(ids)[None]))[0]
-    wrong = _ref_logits(params, dict(ref, **switch), ids)
-    assert np.abs(got - wrong).max() > 100 * TOL, fault
+    def test_the_engine_reports_the_stored_layout(self, programs, engine):
+        cfg = programs.cfg
+        engine.submit([5, 6, 7], max_new_tokens=3).result(timeout_s=120)
+        assert engine.kv_cfg.pool_shapes == (
+            (cfg.layers, 64, BS, cfg.kv_rank), (cfg.layers, 64, BS, 128))
+        status = engine.status()
+        assert status["kv"]["bytes_per_token_layer"] \
+            == (cfg.kv_rank + 128) * 4
+        assert status["kv"]["pool_bytes"] == engine.kv_cfg.pool_bytes() \
+            == cfg.layers * 64 * BS * (cfg.kv_rank + 128) * 4
+        assert status["decode_attention"].get("gather", 0) >= 1
+
+    def test_the_warm_start_digest_covers_the_cache_layout(self, engine,
+                                                           monkeypatch):
+        """The digest hashes the pool geometry's repr, which names the
+        stored layout: two geometries that differ in the entries' widths
+        alone do not share warm-start artifacts."""
+        import dataclasses
+
+        assert "widths=(32, 128)" in repr(engine.kv_cfg)
+        digest = engine._model_digest()
+        monkeypatch.setattr(engine, "kv_cfg", dataclasses.replace(
+            engine.kv_cfg, widths=(32, 256)))
+        assert engine._model_digest() != digest
 
 
 # -- the router's properties, one by one -------------------------------------
 
 
 @pytest.fixture(scope="module")
-def routed(model):
+def routed():
     """16 rows through the routing rule alone, with what it should say
     written out in numpy."""
-    _, cfg, _ = model
+    cfg, _ = _tiny()
     logits = np.asarray(jax.random.normal(jax.random.key(5),
                                           (16, cfg.n_experts))) * 1.5
     bias = np.asarray(jax.random.normal(jax.random.key(6),
@@ -138,10 +165,10 @@ def test_router_weights_are_normalised_then_scaled(routed):
     assert cfg.route_scale == 2.5
 
 
-def test_the_shared_expert_is_always_on(model):
+def test_the_shared_expert_is_always_on():
     """The expert layer alone against the formula written out: the routed
     sum plus one more SwiGLU that every row gets unweighted."""
-    params, cfg, _ = model
+    cfg, params = _tiny()
     lp = {k: np.asarray(v[0]) for k, v in params.items()
           if k.startswith("blk.")}
     y = np.asarray(jax.random.normal(jax.random.key(2), (6, cfg.hidden)))
@@ -214,31 +241,7 @@ def test_a_norms_gains_are_not_all_one():
         assert 0.5 * joyai.NORM_STD < g.std() < 1.5 * joyai.NORM_STD
 
 
-# -- the serve programs through the latent paged cache, on logits -----------
-
-
-@pytest.fixture()
-def logits_head(monkeypatch):
-    """The programs return the head's float32 logits in place of the
-    greedy pick."""
-    monkeypatch.setattr(decoder, "beam_top1",
-                        lambda prev, logits, eos: logits.astype(jnp.float32))
-
-
-def _kv(cfg, num_blocks=24, dtype="float32"):
-    sm = cfg.serve_model()
-    return kvc.KVCacheConfig(layers=sm.layers, kv_heads=sm.kv_heads,
-                             head_dim=sm.head_dim, max_len=64,
-                             block_size=BS, num_blocks=num_blocks,
-                             dtype=dtype, widths=sm.stored)
-
-
-def _pools(cfg, num_blocks=24):
-    return kvc.init_pools(_kv(cfg, num_blocks))
-
-
-def _table(blocks, width=8):
-    return np.asarray(list(blocks) + [0] * (width - len(blocks)), np.int32)
+# -- the latent pool and the two forms of attention -------------------------
 
 
 def test_the_pool_stores_the_latent_and_the_rotary_key_and_nothing_else():
@@ -266,118 +269,16 @@ def test_the_pool_stores_the_latent_and_the_rotary_key_and_nothing_else():
     assert mha.pool_shape == (36, 1025, 16, 1280)
     assert mha.pool_bytes() == 2 * 36 * 1025 * 16 * 1280 * 2
     assert mha.bytes_per_token() == 2 * 1280 * 2
-    kp, vp = kvc.init_pools(_kv(joyai.JoyaiConfig.tiny()))
+    _, (kp, vp), _ = pools(joyai.JoyaiConfig.tiny().serve_model(), 24, 64)
     assert kp.shape == (3, 24, BS, 32) and vp.shape == (3, 24, BS, 128)
 
 
-@pytest.mark.parametrize("chunked", [False, True])
-def test_prefill_then_decode_matches_the_reference(model, logits_head,
-                                                   chunked):
-    """Prefill (expanded attention, or chunks in the absorbed form) then
-    decode steps (absorbed, through the latent pool) against the
-    reference's full forward pass in the expanded form."""
-    params, cfg, ref = model
-    sm = cfg.serve_model()
-    kw = dict(block_size=BS, eos_id=-1)
-    seq = np.asarray(jax.random.randint(jax.random.key(3), (30,), 0,
-                                        cfg.vocab_size), np.int32)
-    want = _ref_logits(params, ref, seq)
-    n = 13                                   # prompt length
-    kp, vp = _pools(cfg)
-    bt = _table([3, 5, 7, 9])
-    prefill, prefill_chunk, decode_step = (
-        jax.jit(lambda *a, f=f: f(sm, *a, **kw)) for f in (
-            decoder.prefill, decoder.prefill_chunk, decoder.decode_step))
-    with jax.default_matmul_precision("highest"):
-        if chunked:
-            for start in (0, 8):
-                ids = np.full((1, 8), seq[n - 1], np.int32)
-                seg = seq[start:min(start + 8, n)]
-                ids[0, :len(seg)] = seg
-                row, kp, vp = prefill_chunk(
-                    params, ids, np.int32(start), np.int32(n), kp, vp, bt)
-        else:
-            ids = np.full((1, 16), seq[n - 1], np.int32)
-            ids[0, :n] = seq[:n]
-            row, kp, vp = prefill(params, ids, np.int32(n), kp, vp, bt)
-        assert np.abs(np.asarray(row)[0] - want[n - 1]).max() < TOL
-        # teacher-forced decode steps, the sequence in slot 1 of 3
-        for t in range(n, len(seq)):
-            ids = np.asarray([0, seq[t], 0], np.int32)
-            pos = np.asarray([0, t, 0], np.int32)
-            bts = np.stack([_table([]), bt, _table([])])
-            rows, kp, vp, stats = decode_step(params, ids, pos, kp, vp,
-                                              bts)
-            assert np.abs(np.asarray(rows)[1] - want[t]).max() < TOL, t
-    # the counters are the expert layers': the leading dense layer has none
-    assert stats["experts_hit"].shape == (cfg.expert_layers,)
-    facts = sm.step_facts(jax.device_get(stats))
-    assert 2 <= facts["experts_hit"] <= cfg.expert_layers * cfg.top_k * 3
-    assert 1 <= facts["expert_load_max"] <= 3
-    # what lies in the pools: the rotary key in its first lanes, zeros after
-    used = np.asarray(vp)[:, [3, 5, 7]]
-    assert np.abs(used[..., :cfg.rope_dim]).max() > 0.1
-    assert not used[..., cfg.rope_dim:].any()
-
-
-def test_chunked_prefill_equals_whole_prefill_in_the_pools(model,
-                                                           logits_head):
-    params, cfg, _ = model
-    sm = cfg.serve_model()
-    kw = dict(block_size=BS, eos_id=-1)
-    seq = jax.random.randint(jax.random.key(8), (16,), 0, cfg.vocab_size,
-                             jnp.int32)
-    bt = jnp.asarray(_table([2, 4]))
-    with jax.default_matmul_precision("highest"):
-        whole, kp, vp = decoder.prefill(sm, params, seq[None],
-                                        np.int32(16), *_pools(cfg), bt, **kw)
-        ckp, cvp = _pools(cfg)
-        for start in (0, 8):
-            part, ckp, cvp = decoder.prefill_chunk(
-                sm, params, seq[None, start:start + 8], np.int32(start),
-                np.int32(16), ckp, cvp, bt, **kw)
-    assert np.abs(np.asarray(whole) - np.asarray(part)).max() < TOL
-    for a, b in ((kp, ckp), (vp, cvp)):
-        assert np.abs(np.asarray(a)[:, [2, 4]]
-                      - np.asarray(b)[:, [2, 4]]).max() < 1e-5
-
-
-def test_verify_step_equals_stepwise_decode(model, monkeypatch):
-    """W tokens a slot in one step give the rows that W decode steps give
-    one after another, for the latent cache as for K and V (compared on
-    each row's largest logit, which the head hands back for its pick)."""
-    monkeypatch.setattr(
-        decoder, "beam_top1",
-        lambda prev, logits, eos: logits.astype(jnp.float32).max(-1))
-    params, cfg, _ = model
-    sm = cfg.serve_model()
-    kw = dict(block_size=BS, eos_id=-1)
-    seq = np.asarray(jax.random.randint(jax.random.key(11), (14,), 0,
-                                        cfg.vocab_size), np.int32)
-    bt = _table([3, 6])
-    ids = np.full((1, 16), seq[9], np.int32)
-    ids[0, :10] = seq[:10]
-    with jax.default_matmul_precision("highest"):
-        _, kp, vp = decoder.prefill(sm, params, ids, np.int32(10),
-                                    *_pools(cfg), bt, **kw)
-        bts = np.stack([bt, _table([])])
-        span, _, _ = decoder.verify_step(
-            sm, params, np.stack([seq[10:14], np.zeros(4, np.int32)]),
-            np.asarray([10, 0], np.int32), kp, vp, bts, **kw)
-        for j in range(4):
-            row, kp, vp, _ = decoder.decode_step(
-                sm, params, np.asarray([seq[10 + j], 0], np.int32),
-                np.asarray([10 + j, 0], np.int32), kp, vp, bts, **kw)
-            assert abs(float(span[0, j]) - float(row[0])) < TOL, j
-            assert abs(float(row[0])) > 0.5
-
-
-def test_absorbed_attention_equals_expanded_attention(model):
+def test_absorbed_attention_equals_expanded_attention():
     """The two forms of one layer's attention on the same rows: the
     prompt's expanded form (per-head keys and values through W_kvb, scores
     192 wide) and the cache's absorbed form (W_UK in the query, the
     context through W_UV), row by causal row."""
-    params, cfg, _ = model
+    cfg, params = _tiny()
     sm = cfg.serve_model()
     lp = sm.lead_params(params)[0]
     T = 12
@@ -391,29 +292,6 @@ def test_absorbed_attention_equals_expanded_attention(model):
     assert expanded.shape == (1, T, cfg.heads * cfg.v_dim)
     assert np.abs(np.asarray(expanded)).max() > 0.1
     assert np.abs(np.asarray(expanded) - np.asarray(absorbed)).max() < 1e-5
-
-
-def test_a_rows_logits_do_not_depend_on_its_batch(model, logits_head):
-    """Dropless routing and a slot's own blocks: the same row beside
-    different neighbours (other tokens, other experts hit, idle slots)
-    gives the same bits, in float32 and in bfloat16."""
-    params, cfg, _ = model
-    kw = dict(block_size=BS, eos_id=-1)
-    sm = cfg.serve_model()
-    step = jax.jit(lambda p, i, po, k, v, b: decoder.decode_step(
-        sm, p, i, po, k, v, b, **kw)[0])
-    for dt in ("float32", "bfloat16"):
-        p = {k: v.astype(dt) for k, v in params.items()}
-        rows = []
-        for others in ([0, 0, 0], [17, 400, 3], [255, 1, 99]):
-            kp, vp = (a.astype(dt) for a in _pools(cfg))
-            ids = np.asarray([others[0], 42, others[1], others[2]], np.int32)
-            pos = np.asarray([2, 5, 0, 9], np.int32)
-            bts = np.stack([_table([2, 4]), _table([1]), _table([]),
-                            _table([6, 8])])
-            rows.append(np.asarray(step(p, ids, pos, kp, vp, bts))[1])
-        assert np.array_equal(rows[0], rows[1]), dt
-        assert np.array_equal(rows[0], rows[2]), dt
 
 
 # -- the latent kernel against the gathered form -----------------------------
@@ -499,31 +377,29 @@ def test_a_slots_latent_result_does_not_depend_on_its_neighbours():
 
 
 def test_decode_step_through_the_latent_kernel_agrees_with_the_gather(
-        model, logits_head, monkeypatch):
+        monkeypatch):
     """`decode_step` with the gate answered yes and the kernel in the
     interpreter against the route the gate picks here."""
     from jax.experimental.pallas import tpu as pltpu
 
     from paddle_tpu.ops.pallas import paged_attention as PA
 
-    params, _, _ = model
     # a latent width the kernel's lanes can hold; the rest stays tiny
     cfg = joyai.JoyaiConfig.tiny()
     cfg.dtype, cfg.kv_rank, cfg.heads = "float32", 128, 8
     params, _ = joyai.init(jax.random.key(0), cfg)
     sm = cfg.serve_model()
-    kw = dict(block_size=BS, eos_id=-1)
-    kp, vp = _pools(cfg)
+    _, (kp, vp), _ = pools(sm, 24, 64)
     ids = np.full((1, 16), 7, np.int32)
     ids[0, :11] = np.arange(20, 31)
-    bt = _table([3, 5])
-    _, kp, vp = decoder.prefill(sm, params, ids, np.int32(11), kp, vp, bt,
-                                **kw)
-    args = (np.asarray([0, 44, 0], np.int32), np.asarray([0, 11, 0],
-                                                         np.int32),
-            kp, vp, np.stack([_table([]), bt, _table([])]))
+    bt = table([3, 5], 8)
+    fill = (params, jnp.asarray(ids), jnp.int32(11), kp, vp, jnp.asarray(bt))
+    _, kp, vp = program(sm, decoder.prefill, *fill)(*fill)
+    args = (params, jnp.asarray([0, 44, 0], jnp.int32),
+            jnp.asarray([0, 11, 0], jnp.int32), kp, vp,
+            jnp.asarray(np.stack([table([], 8), bt, table([], 8)])))
     PA.GATE_COUNTS.clear()
-    want = decoder.decode_step(sm, params, *args, **kw)[0]
+    want = program(sm, decoder.decode_step, *args)(*args)[0]
     assert PA.GATE_COUNTS == {"gather": 1}
     monkeypatch.setattr(PA, "use_paged_latent", lambda *a: True)
     real = PA.paged_latent_attention
@@ -531,109 +407,6 @@ def test_decode_step_through_the_latent_kernel_agrees_with_the_gather(
         PA, "paged_latent_attention",
         lambda *a, **k: real(*a, interpret=pltpu.InterpretParams(), **k))
     PA.GATE_COUNTS.clear()
-    got = decoder.decode_step(sm, params, *args, **kw)[0]
+    got = program(sm, decoder.decode_step, *args)(*args)[0]
     assert PA.GATE_COUNTS == {"paged_latent": 1}
     assert np.abs(np.asarray(got)[1] - np.asarray(want)[1]).max() < 1e-4
-
-
-# -- the engine end to end --------------------------------------------------
-
-
-def _engine(model, **kw):
-    params, cfg, _ = model
-    base = dict(block_size=BS, num_blocks=64, decode_slots=(4,),
-                prefill_buckets=(8, 16), precision="f32", max_len=64)
-    base.update(kw)
-    return DecodeEngine(params, cfg, DecodeConfig(**base))
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    eng = _engine(model)
-    eng.warmup()
-    yield eng
-    eng.stop()
-
-
-def test_the_engine_serves_the_latent_model_within_the_reference(model,
-                                                                 engine):
-    """Prefill then decode through the engine's loop, allocator and latent
-    pools: every generated token is the reference's argmax at its position,
-    or within rounding of it; the engine reports the stored layout."""
-    params, cfg, ref = model
-    prompts = [[5, 6, 7, 8, 9], list(range(100, 113)), [400, 3]]
-    handles = [engine.submit(p, max_new_tokens=12) for p in prompts]
-    streams = [h.result(timeout_s=120) for h in handles]
-    assert all(len(s) == 12 for s in streams)
-    top = {k: v for k, v in params.items()
-           if not k.startswith(("blk.", "dense."))}
-    gap, exact = joyai_ref.stream_gaps(
-        top, lambda i: joyai_ref.layer_of(params, ref, i), ref, prompts,
-        streams, 32)
-    assert gap < TOL and exact >= 35
-    assert engine.kv_cfg.pool_shapes == ((cfg.layers, 64, BS, cfg.kv_rank),
-                                         (cfg.layers, 64, BS, 128))
-    status = engine.status()
-    assert status["kv"]["entry_widths"] == [cfg.kv_rank, 128]
-    assert status["kv"]["bytes_per_token_layer"] == (cfg.kv_rank + 128) * 4
-    assert status["kv"]["pool_bytes"] == engine.kv_cfg.pool_bytes() \
-        == cfg.layers * 64 * BS * (cfg.kv_rank + 128) * 4
-    assert status["decode_attention"].get("gather", 0) >= 1
-
-
-def test_chunked_prefill_serves_the_same_tokens(model, engine):
-    prompts = [list(range(100, 113)), [5, 6, 7, 8, 9, 10, 11, 12, 13]]
-    want = [engine.submit(p, max_new_tokens=10).result(timeout_s=120)
-            for p in prompts]
-    chunked = _engine(model, prefill_chunk=8)
-    try:
-        got = [chunked.submit(p, max_new_tokens=10).result(timeout_s=120)
-               for p in prompts]
-    finally:
-        chunked.stop()
-    assert got == want
-
-
-def test_admit_mid_decode_bit_identical(engine):
-    """A slot's tokens are the same whether it decodes alone or another
-    request joins the running batch."""
-    solo = engine.submit([1, 2, 3, 4],
-                         max_new_tokens=14).result(timeout_s=120)
-    a = engine.submit([1, 2, 3, 4], max_new_tokens=14)
-    time.sleep(0.02)
-    b = engine.submit([9, 9, 200], max_new_tokens=6)
-    assert a.result(timeout_s=120) == solo
-    assert len(b.result(timeout_s=120)) == 6
-
-
-def test_step_records_count_the_experts_while_recording(model, engine):
-    from paddle_tpu.observability import tracing
-
-    cfg = model[1]
-    with tracing.recorded():
-        engine.submit([1, 2, 3], max_new_tokens=6).result(timeout_s=120)
-        steps = [s for s in tracing.get_records("decode.steps")
-                 if s["kind"] == "decode" and "experts_hit" in s]
-    assert len(steps) >= 3
-    for s in steps:
-        # 4 slots x top-2 pairs a layer, 2 expert layers
-        assert 2 <= s["experts_hit"] <= cfg.expert_layers * 4 * cfg.top_k
-        assert 1 <= s["expert_load_max"] <= 4
-    assert set(engine.status()["step_facts"]) == {"experts_hit",
-                                                  "expert_load_max"}
-
-
-def test_the_warm_start_digest_covers_the_cache_layout(model):
-    """The digest hashes the pool geometry's repr, which names the stored
-    layout: two geometries that differ in the entries' widths alone do not
-    share warm-start artifacts."""
-    import dataclasses
-
-    eng = _engine(model)
-    try:
-        assert "widths=(32, 128)" in repr(eng.kv_cfg)
-        digest = eng._model_digest()
-        eng.kv_cfg = dataclasses.replace(eng.kv_cfg, widths=(32, 256))
-        assert eng._model_digest() != digest
-    finally:
-        eng.stop()

@@ -96,8 +96,6 @@ def test_prefill_through_the_kernel_agrees_with_the_scatter(monkeypatch):
     tok, kp, vp = prefill()
     assert kvc.PREFILL_WRITE_UNITS == {"blocks": 2}
     monkeypatch.setattr(A, "_platform", lambda q: "tpu")
-    # attention stays on the XLA path: this test is about the write
-    monkeypatch.setattr(A, "_use_pallas", lambda q: False)
     calls = []
 
     def interpreted(*a, **kw):

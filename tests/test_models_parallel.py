@@ -207,16 +207,6 @@ def test_bert_attention_mask_respected():
                                atol=2e-2)
 
 
-def test_graft_entry_and_dryrun():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "__graft_entry__", "/root/repo/__graft_entry__.py")
-    m = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(m)
-    m.dryrun_multichip(8)
-
-
 def test_vgg16_forward_shapes_and_grad():
     from paddle_tpu.models import vgg
 

@@ -8,8 +8,7 @@ import optax
 import pytest
 
 from paddle_tpu.models import gpt
-from paddle_tpu.ops.pallas.attention import (_merge_causal, _use_pallas,
-                                             _xla_mha, mha)
+from paddle_tpu.ops.pallas.attention import _merge_causal, _xla_mha, mha
 from paddle_tpu.ops.pallas.ring_attention import ring_attention
 from paddle_tpu.parallel import MeshConfig, make_mesh, mesh_guard
 from paddle_tpu.parallel.pipeline import pipeline_apply
@@ -123,25 +122,8 @@ def test_flash_attention_gate_and_numpy_reference():
     """The pallas gate: CPU always uses the XLA path; mha matches an
     independent numpy softmax-attention (TPU-chip pallas-vs-XLA agreement at
     T=1024 verified on hardware, bf16 max err 0.016)."""
-    assert not _use_pallas(jnp.zeros((2, 1024, 8, 64)))  # cpu backend
-    # mode-dispatch logic (platform-independent, _gate_allows): the auto
-    # gate never selects the LEGACY flash kernel at ANY T (PROFILE.md
-    # round 3: XLA bf16-scores measured 2.7-2.8x faster at T=4096..16384
-    # on-chip); "on"/"off" override. The production long-T path is
-    # splash_attention (round 4), gated separately below.
     from paddle_tpu.core.flags import set_flags
-    from paddle_tpu.ops.pallas.attention import (
-        _SPLASH_MIN_T, _gate_allows, _use_splash)
-    for T in (128, 4096, 16384):
-        assert not _gate_allows(T)
-    try:
-        set_flags({"FLAGS_flash_attention": "on"})
-        assert _gate_allows(128)
-        assert not _use_pallas(jnp.zeros((2, 128, 8, 64)))  # still cpu
-        set_flags({"FLAGS_flash_attention": "off"})
-        assert not _gate_allows(16384)
-    finally:
-        set_flags({"FLAGS_flash_attention": "auto"})
+    from paddle_tpu.ops.pallas.attention import _SPLASH_MIN_T, _use_splash
     # splash gate: never on CPU; never with an additive mask; TPU-only
     # shape/threshold logic (T >= _SPLASH_MIN_T, T % 128 == 0, hd % 64
     # == 0) — on-chip parity vs the XLA path measured at T=1024 bf16:

@@ -118,8 +118,10 @@ class _FakeEngine:
         self.calls = []
         self.gate = gate
         self.fail = fail
+        self.entered = threading.Event()    # a batch has reached the engine
 
     def run_batch(self, feeds):
+        self.entered.set()
         if self.gate is not None:
             assert self.gate.wait(20), "test gate never opened"
         if self.fail:
@@ -228,8 +230,14 @@ def test_batcher_backpressure_rejects_when_full():
                 max_queue=2, max_wait_ms=1, timeout_s=20)
     try:
         results = {}
-        threads = [_submit_async(b, {"x": np.zeros((1, 2), "float32")},
-                                 results, i) for i in range(3)]
+        feeds = {"x": np.zeros((1, 2), "float32")}
+        # the first is IN FLIGHT before the next two arrive: three at once
+        # find a queue of two full whenever the worker has not yet taken
+        # the first, and one of them is the request refused (a loaded
+        # tier-1 run failed so)
+        threads = [_submit_async(b, feeds, results, 0)]
+        assert eng.entered.wait(10)
+        threads += [_submit_async(b, feeds, results, i) for i in (1, 2)]
         deadline = time.monotonic() + 10
         while b.depth() < 2 and time.monotonic() < deadline:
             time.sleep(0.01)  # 1 in flight + 2 queued

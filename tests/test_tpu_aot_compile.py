@@ -326,19 +326,41 @@ _MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
           "dynamic-update-slice")
 
 
+def _described(v5e, module, cfg, slots, context, block=_BLOCK):
+    """A family at the cell's widths as shapes on the described chip, the
+    way `DecodeEngine.__init__` builds it: (cfg, params, (k_pool, v_pool),
+    the programs' `state` (the row pools, then the rated entries' pools),
+    the pools' geometry, sds)."""
+    from paddle_tpu.serving.kv_cache import KVCacheConfig
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = jax.eval_shape(lambda k: module.init(k, cfg)[0],
+                            jax.random.key(0))
+    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    sm = cfg.serve_model()
+    kv = KVCacheConfig(
+        layers=sm.kv_layers, widths=sm.stored, max_len=context,
+        block_size=block, rated=tuple(sm.rated),
+        num_blocks=slots * (context // block) + 1)
+    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
+    state = tuple(sds(shape, dt) for shape, dt in
+                  sm.state_pools(slots + 1, jnp.bfloat16)) \
+        + tuple(sds(shape, jnp.dtype(kv.dtype))
+                for shape in kv.rated_pool_shapes)
+    return cfg, params, pools, state, kv, sds
+
+
 @pytest.fixture(scope="module")
 def gpt2_large(v5e):
     from paddle_tpu.models import gpt
 
     cfg = gpt.GPTConfig(vocab_size=50257, hidden=1280, layers=36, heads=20,
                         mlp_dim=5120, max_len=_CONTEXT, dtype="bfloat16")
-    one = SingleDeviceSharding(v5e[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    shapes = jax.eval_shape(lambda k: gpt.init(k, cfg)[0], jax.random.key(0))
-    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    _, params, _, _, _, sds = _described(v5e, gpt, cfg, 16, _CONTEXT)
     return gpt, cfg, params, sds
 
 
@@ -485,22 +507,11 @@ def test_gpt2_large_decode_step_at_32_slots_leaves_room_on_v5e(
 @pytest.fixture(scope="module")
 def olmoe_8l(v5e):
     from paddle_tpu.models import olmoe
-    from paddle_tpu.serving.kv_cache import KVCacheConfig
 
-    cfg = olmoe.OlmoeConfig(layers=8, max_len=_CONTEXT)
-    one = SingleDeviceSharding(v5e[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    shapes = jax.eval_shape(lambda k: olmoe.init(k, cfg)[0],
-                            jax.random.key(0))
-    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
-    kv = KVCacheConfig(layers=cfg.layers, kv_heads=cfg.heads,
-                       head_dim=cfg.head_dim, max_len=_CONTEXT,
-                       block_size=_BLOCK,
-                       num_blocks=16 * (_CONTEXT // _BLOCK) + 1)
-    return cfg, params, sds(kv.pool_shape, jnp.dtype(kv.dtype)), sds
+    cfg, params, pools, _, _, sds = _described(
+        v5e, olmoe, olmoe.OlmoeConfig(layers=8, max_len=_CONTEXT), 16,
+        _CONTEXT)
+    return cfg, params, pools[0], sds
 
 
 @pytest.mark.parametrize("program", ["decode@16", "prefill@256"])
@@ -657,23 +668,10 @@ _JOYAI_SLOTS, _JOYAI_CONTEXT = 32, 4608
 @pytest.fixture(scope="module")
 def joyai_5l(v5e):
     from paddle_tpu.models import joyai
-    from paddle_tpu.serving.kv_cache import KVCacheConfig
 
-    cfg = joyai.JoyaiConfig(layers=5, max_len=_JOYAI_CONTEXT)
-    one = SingleDeviceSharding(v5e[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    shapes = jax.eval_shape(lambda k: joyai.init(k, cfg)[0],
-                            jax.random.key(0))
-    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
-    sm = cfg.serve_model()
-    kv = KVCacheConfig(
-        layers=sm.layers, kv_heads=sm.kv_heads, head_dim=sm.head_dim,
-        max_len=_JOYAI_CONTEXT, block_size=_BLOCK, widths=sm.stored,
-        num_blocks=_JOYAI_SLOTS * (_JOYAI_CONTEXT // _BLOCK) + 1)
-    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
+    cfg, params, pools, _, kv, sds = _described(
+        v5e, joyai, joyai.JoyaiConfig(layers=5, max_len=_JOYAI_CONTEXT),
+        _JOYAI_SLOTS, _JOYAI_CONTEXT)
     return cfg, params, pools, kv, sds
 
 
@@ -763,27 +761,11 @@ _NEMOTRON_SLOTS, _NEMOTRON_CONTEXT = 64, 2560
 @pytest.fixture(scope="module")
 def nemotron_9l(v5e):
     from paddle_tpu.models import nemotron_h
-    from paddle_tpu.serving.kv_cache import KVCacheConfig
 
-    cfg = nemotron_h.NemotronHConfig(pattern="MEMEM*EME",
-                                     max_len=_NEMOTRON_CONTEXT)
-    one = SingleDeviceSharding(v5e[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    shapes = jax.eval_shape(lambda k: nemotron_h.init(k, cfg)[0],
-                            jax.random.key(0))
-    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
-    sm = cfg.serve_model()
-    kv = KVCacheConfig(
-        layers=sm.kv_layers, widths=sm.stored, max_len=_NEMOTRON_CONTEXT,
-        block_size=_BLOCK,
-        num_blocks=_NEMOTRON_SLOTS * (_NEMOTRON_CONTEXT // _BLOCK) + 1)
-    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
-    state = tuple(sds(shape, dt) for shape, dt in
-                  sm.state_pools(_NEMOTRON_SLOTS + 1, jnp.bfloat16))
-    return cfg, params, pools, state, kv, sds
+    return _described(
+        v5e, nemotron_h, nemotron_h.NemotronHConfig(
+            pattern="MEMEM*EME", max_len=_NEMOTRON_CONTEXT),
+        _NEMOTRON_SLOTS, _NEMOTRON_CONTEXT)
 
 
 @pytest.mark.parametrize("program", ["decode@64", "prefill@1024"])
@@ -887,30 +869,11 @@ _SALA_SLOTS, _SALA_CONTEXT, _SALA_BLOCK = 32, 49152, 64
 @pytest.fixture(scope="module")
 def minicpm_8l(v5e):
     from paddle_tpu.models import minicpm_sala
-    from paddle_tpu.serving.kv_cache import KVCacheConfig
 
-    cfg = minicpm_sala.MiniCPMSALAConfig(mixers="SLLLLLLS",
-                                         max_len=_SALA_CONTEXT)
-    one = SingleDeviceSharding(v5e[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    shapes = jax.eval_shape(lambda k: minicpm_sala.init(k, cfg)[0],
-                            jax.random.key(0))
-    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
-    sm = cfg.serve_model()
-    kv = KVCacheConfig(
-        layers=sm.kv_layers, widths=sm.stored, max_len=_SALA_CONTEXT,
-        block_size=_SALA_BLOCK, rated=sm.rated,
-        num_blocks=_SALA_SLOTS * (_SALA_CONTEXT // _SALA_BLOCK) + 1)
-    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
-    # the programs' `state`: the row pools, then the rated entries' pools
-    state = tuple(sds(shape, dt) for shape, dt in
-                  sm.state_pools(_SALA_SLOTS + 1, jnp.bfloat16)) \
-        + tuple(sds(shape, jnp.dtype(kv.dtype))
-                for shape in kv.rated_pool_shapes)
-    return cfg, params, pools, state, kv, sds
+    return _described(
+        v5e, minicpm_sala, minicpm_sala.MiniCPMSALAConfig(
+            mixers="SLLLLLLS", max_len=_SALA_CONTEXT),
+        _SALA_SLOTS, _SALA_CONTEXT, _SALA_BLOCK)
 
 
 @pytest.mark.parametrize("program", ["decode@32", "prefill@32768",
@@ -1083,23 +1046,10 @@ _XING4_SLOTS, _XING4_CONTEXT = 32, 20480
 @pytest.fixture(scope="module")
 def xing4_6l(v5e):
     from paddle_tpu.models import xing4
-    from paddle_tpu.serving.kv_cache import KVCacheConfig
 
-    cfg = xing4.Xing4Config(layers=6, max_len=_XING4_CONTEXT)
-    one = SingleDeviceSharding(v5e[0])
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    shapes = jax.eval_shape(lambda k: xing4.init(k, cfg)[0],
-                            jax.random.key(0))
-    params = {k: sds(v.shape, jnp.bfloat16) for k, v in shapes.items()}
-    sm = cfg.serve_model()
-    kv = KVCacheConfig(
-        layers=sm.layers, kv_heads=sm.kv_heads, head_dim=sm.head_dim,
-        max_len=_XING4_CONTEXT, block_size=_BLOCK, widths=sm.stored,
-        num_blocks=_XING4_SLOTS * (_XING4_CONTEXT // _BLOCK) + 1)
-    pools = tuple(sds(shape, jnp.dtype(kv.dtype)) for shape in kv.pool_shapes)
+    cfg, params, pools, _, kv, sds = _described(
+        v5e, xing4, xing4.Xing4Config(layers=6, max_len=_XING4_CONTEXT),
+        _XING4_SLOTS, _XING4_CONTEXT)
     return cfg, params, pools, kv, sds
 
 
