@@ -15,7 +15,8 @@ over. Numbers printed here are information, not benchmark results.
     python chip_smoke.py            one chip: device, program, train,
                                     long_seq, serve, serve_reuse,
                                     serve_olmoe, serve_joyai, serve_xing4,
-                                    serve_nemotron, paged_attention
+                                    serve_nemotron, serve_jamba,
+                                    paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
                                     one device, then the dp/tp/sp/pp/ep
@@ -44,6 +45,7 @@ OLMOE_LOGIT_TOL = 0.25   # benchmarks/configs/olmoe_1b_7b.json argues it
 JOYAI_LOGIT_TOL = 0.4    # benchmarks/configs/joyai_llm_flash.json argues it
 XING4_LOGIT_TOL = 0.55   # benchmarks/configs/xing4_29b_a4b.json argues it
 NEMOTRON_LOGIT_TOL = 0.4  # benchmarks/configs/nemotron3_nano.json argues it
+JAMBA_LOGIT_TOL = 2.5    # benchmarks/configs/jamba2_3b.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
 # many of those its persistent cache answered (a hit still counts as a
@@ -354,22 +356,39 @@ def _xing4_reference_gaps(params, cfg, prompts, streams):
         streams, 0)
 
 
+def _pattern_reference_gaps(ref_mod, params, cfg, prompts, streams):
+    """A model of one mixer a block against its plain float32 reference
+    `ref_mod` (the blocks of a kind stacked under the prefixes `ref_mod
+    .PREFIX` names, handed over one block at a time)."""
+    import dataclasses
+
+    ref = dataclasses.asdict(cfg)
+    top = {k: v for k, v in params.items()
+           if not k.startswith(tuple(ref_mod.PREFIX.values()))}
+    width = max(len(p) for p in prompts) + len(streams[0])
+    return ref_mod.stream_gaps(
+        top, lambda i: ref_mod.layer_of(params, ref, i), ref, prompts,
+        streams, width)
+
+
 def _nemotron_reference_gaps(params, cfg, prompts, streams):
     """As `_olmoe_reference_gaps`, against the benchmark's plain float32
     Nemotron-H (benchmarks/reference/nemotron_h_ref.py: the recurrence
     token by token, every expert for every token, no cache, no state pool,
     no code of models/nemotron_h.py)."""
-    import dataclasses
-
     from benchmarks.reference import nemotron_h_ref
 
-    ref = dataclasses.asdict(cfg)
-    top = {k: v for k, v in params.items()
-           if not k.startswith(tuple(nemotron_h_ref.PREFIX.values()))}
-    width = max(len(p) for p in prompts) + len(streams[0])
-    return nemotron_h_ref.stream_gaps(
-        top, lambda i: nemotron_h_ref.layer_of(params, ref, i), ref,
-        prompts, streams, width)
+    return _pattern_reference_gaps(nemotron_h_ref, params, cfg, prompts,
+                                   streams)
+
+
+def _jamba_reference_gaps(params, cfg, prompts, streams):
+    """As `_nemotron_reference_gaps`, against the benchmark's plain float32
+    Jamba (benchmarks/reference/jamba_ref.py: the selective recurrence
+    token by token, no cache, no state pool, no code of models/jamba.py)."""
+    from benchmarks.reference import jamba_ref
+
+    return _pattern_reference_gaps(jamba_ref, params, cfg, prompts, streams)
 
 
 def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
@@ -629,7 +648,8 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import bert, gpt, joyai, nemotron_h, olmoe, xing4
+    from paddle_tpu.models import (bert, gpt, jamba, joyai, nemotron_h, olmoe,
+                                   xing4)
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
 
@@ -789,6 +809,38 @@ def run_one_chip() -> None:
         assert checked["state"]["rows"] == 16 \
             and checked["state"]["used"] == 0, info
         # one attention block: K and V, three prefill programs
+        assert checked["prefill_write"] == {"blocks": 6}, info
+
+    # AI21-Jamba2-3B at its published widths (Mamba-1: 5120 channels of 16
+    # state lanes, dt of rank 160, norms inside the mixer; 20 query heads
+    # over ONE K/V head of 128, no positions; dense SwiGLU of 8192; the
+    # embedding of 65536 rows read as the head), four layers with the
+    # attention layer second (`ME*EMEME`), so that the float32 set for the
+    # reference (2.1 GB) sits beside the served one: the selective
+    # recurrence's rows advanced where they lie, and multi-query attention
+    # through the table
+    jcfg = jamba.JambaConfig(n_layers=4, attn_period=4, attn_offset=1,
+                             max_len=1024)
+    prompts = [rng.randint(0, jcfg.vocab_size, n).tolist()
+               for n in (12, 200, 5, 64, 40, 250, 129)]
+    with phase("serve_jamba") as info:
+        serve_phase(info, jcfg, DecodeConfig(
+            block_size=16, num_blocks=16 * 64 + 1, decode_slots=(16,),
+            prefill_buckets=(64, 128, 256)), prompts, max_new=24,
+            logit_tol=JAMBA_LOGIT_TOL, model=jamba,
+            reference_gaps=_jamba_reference_gaps)
+        checked = info["checked"]
+        # the 20 query heads of the one K/V head through the kernel (its
+        # query block filled up to 32 rows), and the three Mamba layers'
+        # rows advanced where they lie
+        assert checked["decode_attention"] == {"paged_gqa": 1}, info
+        # (and a prompt's scan with its state in VMEM: three Mamba layers
+        # in each of three prefill programs)
+        assert checked["state"]["update"] == {"kernel": 3,
+                                              "scan_kernel": 9}, info
+        assert checked["state"]["rows"] == 16 \
+            and checked["state"]["used"] == 0, info
+        # one attention layer: K and V, three prefill programs
         assert checked["prefill_write"] == {"blocks": 6}, info
 
     # the kernel against the gather path where it runs, at the benchmark's

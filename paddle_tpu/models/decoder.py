@@ -217,7 +217,8 @@ class ServeModel:
 
     def ssm_prompt(self, lp, y, length, state, i: int, row):
         """A recurrent mixer (a state-space layer whose decay its input
-        sets, linear attention whose decay is fixed a head: `ops/ssm.py`)
+        sets, a scalar a head or a value a channel and state lane; linear
+        attention whose decay is fixed a head: `ops/ssm.py`)
         over one whole prompt y `[1, T, hidden]` of true length `length`,
         from a ZERO state -> (out `[1, T, hidden]`, `state` with row `row`
         of layer `i` of every pool overwritten by the state after position
@@ -344,17 +345,23 @@ def beam_top1(prev_ids: jax.Array, logits: jax.Array,
 
 @jax.named_scope("head")
 def rms_head(params: Params, x: jax.Array, prev_ids: jax.Array, eos_id: int,
-             eps: float) -> jax.Array:
-    """Final RMSNorm (`ln_f.scale`), an untied output head (`head.w`) and
-    the greedy pick for the rows `x` [N, H]; `prev_ids` [N] are the tokens
-    that led to them."""
+             eps: float, tied: bool = False) -> jax.Array:
+    """Final RMSNorm (`ln_f.scale`), an untied output head (`head.w`), or
+    with `tied` the embedding `wte.w` `[vocab, hidden]` read as the head,
+    and the greedy pick for the rows `x` [N, H]; `prev_ids` [N] are the
+    tokens that led to them."""
     from .common import rms_norm
 
     x = rms_norm(x, params["ln_f.scale"], eps)
     # float32 logits: bf16 ones lie 0.03 apart near the top of a row, and
     # the greedy pick would be made among ties
-    logits = jnp.dot(x, params["head.w"].astype(x.dtype),
-                     preferred_element_type=jnp.float32)
+    if tied:    # contracted over the embedding's lanes: no transposed copy
+        logits = jax.lax.dot_general(
+            x, params["wte.w"].astype(x.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.dot(x, params["head.w"].astype(x.dtype),
+                         preferred_element_type=jnp.float32)
     return beam_top1(prev_ids.astype(jnp.int32), logits, eos_id)
 
 

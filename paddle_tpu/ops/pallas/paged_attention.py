@@ -256,13 +256,20 @@ def use_paged_gqa(q: jax.Array, pool: jax.Array, heads: int,
                   kv_heads: int) -> bool:
     """Whether grouped-query decode attention takes the kernel: on one
     TPU, over pools `[L, NB, BS, kv_heads*D]` whose blocks are whole tiles,
-    D whole lane tiles and the query heads whole sublane tiles."""
+    D whole lane tiles and the query heads whole sublane tiles; under ONE
+    K/V head (multi-query attention) any count of query heads, which
+    `paged_gqa_attention` fills up to whole tiles with rows of zeros."""
     if not _tiles(pool) or q.dtype != pool.dtype:
         return False
     head_dim = pool.shape[3] // kv_heads
     return (_on_one_tpu(q) and kv_heads * head_dim == pool.shape[3]
             and heads % kv_heads == 0 and head_dim % 128 == 0
-            and heads % (32 // pool.dtype.itemsize) == 0)
+            and (heads % _query_tile(pool) == 0 or kv_heads == 1))
+
+
+def _query_tile(pool) -> int:
+    """Rows of a sublane tile of the query block, in the pools' dtype."""
+    return 32 // pool.dtype.itemsize
 
 
 def use_paged_sparse(q: jax.Array, pool: jax.Array, heads: int,
@@ -825,9 +832,24 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     `[S, MB]`; query head h reads K/V head `h // (heads / kv_heads)`. Slot
     s attends key positions `0..positions[s]` at `1/sqrt(D)`, scores and
     softmax in float32, and gets `[heads*D]` in q's dtype; a slot whose
-    table starts with the null block gets zeros."""
+    table starts with the null block gets zeros. Query heads that are not
+    whole sublane tiles (one K/V head alone: every query head is of its
+    group wherever it sits) are filled up with rows of zeros, whose
+    context, a plain mean of V, is dropped."""
     n_slots = q.shape[0]
     head_dim = k_pool.shape[3] // kv_heads
+    spare = -heads % _query_tile(k_pool)
+    if spare:
+        if kv_heads != 1:
+            raise ValueError(
+                f"{heads} query heads over {kv_heads} K/V heads are not "
+                "whole sublane tiles, and rows of zeros would move the "
+                "groups")
+        out = paged_gqa_attention(
+            jnp.pad(q, [(0, 0), (0, spare * head_dim)]), k_pool, v_pool,
+            layer, block_tables, positions, heads=heads + spare,
+            kv_heads=1, interpret=interpret)
+        return out[:, :heads * head_dim]
     per_slot = lambda s, *_: (s, 0, 0)      # noqa: E731
     kernel, scalars, pools = _call_form(
         functools.partial(_gqa_kernel, kv_heads=kv_heads,

@@ -1,4 +1,10 @@
-"""The decode step's state-space update, in place in the state row pool.
+"""The decode step's state-space update, in place in the state row pool,
+for the two recurrences of `ops/ssm.py`: `state_update` / `use_kernel` the
+one whose decay is ONE SCALAR A HEAD (Mamba-2, lightning attention:
+`ssd_step`), `selective_update` / `use_selective_kernel` the one whose
+decay differs a channel AND a state lane (Mamba-1: `selective_step`; its
+section is at the end of this docstring). Both count their route in
+`GATE_COUNTS`.
 
 A decode step advances one token a slot: every slot's SSM state `[H, P,
 N]` float32 (2 MB at 64 x 64 x 128) is read, decayed, added to and read
@@ -35,6 +41,23 @@ the head's 64 KB move in 0.08 (chip run of PR 34: 28% of the roofline).
 Idle slots carry row 0, several of them: their blocks are read and written
 in the grid's order, stale or not, and nothing reads row 0 for a live
 sequence.
+
+The SELECTIVE recurrence's rows (`selective_update`). A row is `[N,
+channels]` float32 with the channels in the lanes (16 x 5120: 2 x 40 whole
+tiles, one contiguous 320 KB); `S' = exp(dt A) S + (dt x) B` is elementwise
+in it, with `dt` and `dt x` rows `[1, channels]` broadcast down the
+sublanes and B and C columns `[N, 1]` broadcast along the lanes, and `y =
+sum_n S' C` a sum over the 16 sublanes. The decay is a value a state VALUE,
+so it is computed IN the kernel from `dt` and `A` `[N, channels]` (a
+block of its own that never changes and is fetched once a call): handing
+`exp(dt A)` in would double the bytes. The grid walks the slots, a whole
+row a step. The rows `dt`, `dt x` and `y` are `[slots, channels]` arrays
+taken eight slots a block (one sublane tile; a slot alone would be padded
+to a tile in HBM and cost half a state row) and a slot's row picked by its
+index in the tile; B and C arrive as `[slots, N, 1]`, whose padding to a
+lane tile is 8 KB a slot, a fortieth of the row. The body goes through the
+row in pieces of `_LANES` channels so that a piece's values stay in
+registers.
 """
 
 from __future__ import annotations
@@ -155,3 +178,77 @@ def state_update(pool: jax.Array, layer, rows: jax.Array, decay: jax.Array,
       Bm.astype(f32), Cm.astype(f32), pool)
     y = jnp.swapaxes(y, 1, 2)
     return y, pool
+
+
+# channels a piece of the selective body: [16, 512] float32 is 8 registers
+# an operand
+_LANES = 512
+_SLOT_TILE = 8      # slots a block of the row operands: a sublane tile
+
+
+def use_selective_kernel(x: jax.Array, pool: jax.Array) -> bool:
+    """Whether the selective recurrence's state update takes the kernel:
+    on one TPU, over a float32 pool `[L, R, N, C]` whose rows are whole
+    tiles (N whole sublane tiles, C whole pieces of `_LANES` channels: a
+    narrower piece's row pick out of a tile of slots is a dynamic load
+    Mosaic refuses, tests/test_tpu_aot_compile.py)."""
+    if pool.ndim != 4 or pool.dtype != jnp.float32:
+        return False
+    N, C = pool.shape[2:]
+    return _pa._on_one_tpu(x) and N % 8 == 0 and C % _LANES == 0
+
+
+def _selective_kernel(layer_ref, rows_ref, dt_ref, dtx_ref, a_ref, b_ref,
+                      c_ref, pool_ref, y_ref, out_ref):
+    at = pl.ds(pl.program_id(0) % _SLOT_TILE, 1)
+    C = pool_ref.shape[1]
+    b, c = b_ref[...], c_ref[...]                               # [N, 1]
+    step = min(_LANES, C)
+    for first in range(0, C, step):
+        lanes = pl.ds(first, step)
+        new = jnp.exp(dt_ref[at, lanes] * a_ref[:, lanes]) \
+            * pool_ref[:, lanes] + dtx_ref[at, lanes] * b        # [N, step]
+        out_ref[:, lanes] = new
+        y_ref[at, lanes] = jnp.sum(new * c, axis=0, keepdims=True)
+
+
+def selective_update(pool: jax.Array, layer, rows: jax.Array, dt: jax.Array,
+                     dtx: jax.Array, A: jax.Array, Bm: jax.Array,
+                     Cm: jax.Array, *, interpret: bool = False):
+    """One token a slot of the selective recurrence, in place: pool `[L, R,
+    N, C]` float32 (donated by the caller's program: it is aliased to the
+    result), `layer` its layer, rows `[S]` the slots' rows, dt `[S, C]`,
+    dtx `[S, C]` = dt x, A `[N, C]` (negative), Bm and Cm `[S, N]`, all
+    float32 -> (y `[S, C]` float32 = sum_n S' C, without the `D x` term,
+    and the pool with `pool[layer, rows[s]] = exp(dt A) S + dtx B` for
+    every slot)."""
+    S, C = dt.shape
+    N = A.shape[0]
+    f32 = jnp.float32
+    pad = -S % _SLOT_TILE
+    tiled = lambda a: jnp.pad(a.astype(f32), [(0, pad), (0, 0)])  # noqa: E731
+    by_tile = pl.BlockSpec((_SLOT_TILE, C),
+                           lambda s, *_: (s // _SLOT_TILE, 0))
+    column = pl.BlockSpec((None, N, 1), lambda s, *_: (s, 0, 0))
+    row_spec = pl.BlockSpec(
+        (None, None, N, C), lambda s, layer, rows: (layer[0], rows[s], 0, 0))
+    y, pool = pl.pallas_call(
+        _selective_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[by_tile, by_tile,
+                      pl.BlockSpec((N, C), lambda s, *_: (0, 0)),
+                      column, column, row_spec],
+            out_specs=[by_tile, row_spec]),
+        out_shape=[jax.ShapeDtypeStruct((S + pad, C), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="ssm_selective_update",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
+      tiled(dt), tiled(dtx), A.astype(f32), Bm.astype(f32)[..., None],
+      Cm.astype(f32)[..., None], pool)
+    return y[:S], pool

@@ -1,16 +1,19 @@
-"""The recurrence of a recurrent mixer (a state-space layer, Mamba-2's
-"SSD"; or linear attention) and the causal depthwise convolution before a
-state-space layer, in the two forms a served model needs.
+"""The recurrences of the recurrent mixers (state-space layers: Mamba-2's
+"SSD" and Mamba-1's selective scan; linear attention) and the causal
+depthwise convolution before a state-space layer, in the forms a served
+model needs. TWO recurrences live here, told apart by what a decay is:
 
-A head h keeps a state `S` [P, N] (P the head's width, N the state size).
-With `a_t = dt_t * A_h` (A negative, dt positive) a token does
+ONE SCALAR A HEAD (`ssd_step`, `ssd_recurrent`, `ssd_chunked`,
+`linear_attention_args`: Mamba-2, lightning attention). A head h keeps a
+state `S` [P, N] (P the head's width, N the state size). With `a_t = dt_t *
+A_h` (A negative, dt positive) a token does
 
     S_t = exp(a_t) S_{t-1} + dt_t x_t B_t^T          x_t [P], B_t [N]
     y_t = S_t C_t + D_h x_t                          C_t [N]
 
 B and C belong to a GROUP of heads (`G` groups, H/G heads each).
 
-The DECAY `exp(a_t)` has two forms, and the functions here are told it
+The DECAY `exp(a_t)` has two forms, and the `ssd_*` functions are told it
 through `dt` and `A` alone:
 
 - input-dependent (Mamba-2): `dt_t` is a softplus of the token's own
@@ -34,9 +37,34 @@ through `dt` and `A` alone:
 - `ssd_recurrent`: the recurrence token by token over a sequence (a
   `lax.scan` of `ssd_step`): what the chunked form is tested against.
 
+A DECAY A CHANNEL AND A STATE LANE (`selective_step`,
+`selective_recurrent`: Mamba-1's selective scan). No
+heads: every one of the `C` channels keeps `N` state lanes of its own, `A`
+is `[N, C]` (the published `A_log` is `[C, N]`; kept here with the channels
+in the lanes, which is how a state row `[N, C]` is whole tiles at N = 16),
+`dt_t` a value a channel, B_t and C_t `[N]` shared by all channels:
+
+    S_t[n, c] = exp(dt_t[c] A[n, c]) S_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+    y_t[c]    = sum_n S_t[n, c] C_t[n] + D[c] x_t[c]
+
+The decay differs for each of a channel's lanes, so a chunk's decays do NOT
+factor into one `[T, T]` mask a head and `ssd_chunked`'s quadratic form does
+not exist here; the other factoring, `S_t = P_t (S_0 + sum_j b_j / P_j)`
+with `P_t = exp(A cumsum(dt)_t)`, overflows float32 as soon as `dt |A|`
+summed over a chunk passes 88, which 6 tokens at `dt` 1 and `A` -16 do.
+A prompt therefore runs the recurrence AS WRITTEN: `selective_recurrent`, a
+`lax.scan` of `selective_step` that carries `[B, N, C]` and holds no
+temporary that grows with the prompt beyond the `[B, T, C]` output,
+`SELECTIVE_UNROLL` tokens unrolled in its body so that the compiler sees
+one straight line to fuse. `dt_t = 0` keeps a position out, as above.
+On a TPU a prompt's scan keeps its state in VMEM
+(`ops/pallas/ssm_scan.selective_scan`) and a decode step's rows are
+advanced where they lie (`ops/pallas/ssm_update.selective_update`); these
+functions are what both are tested against and what runs everywhere else.
+
 `causal_conv` / `conv_step` are the width-K depthwise convolution over the
 sequence: `out_t = b + sum_k w[k] x_{t-(K-1)+k}`, and its one-token form
-over a tail of the last K-1 inputs.
+over a tail of the last K-1 inputs; both recurrences' layers use them.
 
 Every function is row-independent: a sequence's results depend on that
 sequence alone. All ops carry the layer scope `ssm` of their caller.
@@ -126,7 +154,8 @@ def ssd_recurrent(x, dt, A, Bm, Cm, D, init: Optional[jax.Array] = None):
 
     move = lambda a: jnp.moveaxis(a, 1, 0)      # noqa: E731
     state, ys = jax.lax.scan(one, state,
-                             (move(x), move(dt), move(Bm), move(Cm)))
+                             (move(x), move(dt), move(Bm), move(Cm)),
+                             unroll=SELECTIVE_UNROLL)
     return jnp.moveaxis(ys, 0, 1), state
 
 
@@ -187,3 +216,43 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     y = y + D.astype(f32).reshape(G, R)[..., None] * xc.astype(f32)
     return (y.reshape(B, nc * L, H, P)[:, :T],
             state.reshape(B, H, P, N))
+
+
+SELECTIVE_UNROLL = 8    # tokens a body of `selective_recurrent`'s scan
+
+
+def selective_step(state: jax.Array, x: jax.Array, dt: jax.Array,
+                   A: jax.Array, Bm: jax.Array, Cm: jax.Array, D: jax.Array
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """One token a row of the selective recurrence. state [S, N, C]
+    float32, x [S, C], dt [S, C] float32, A [N, C] (negative), Bm and Cm
+    [S, N], D [C] -> (y [S, C] float32, the new state)."""
+    f32 = jnp.float32
+    xf = x.astype(f32)
+    decay = jnp.exp(dt[:, None, :] * A.astype(f32)[None])       # [S, N, C]
+    state = decay * state \
+        + (dt * xf)[:, None, :] * Bm.astype(f32)[:, :, None]
+    y = jnp.sum(state * Cm.astype(f32)[:, :, None], axis=1) \
+        + D.astype(f32)[None] * xf
+    return y, state
+
+
+def selective_recurrent(x, dt, A, Bm, Cm, D,
+                        init: Optional[jax.Array] = None):
+    """The selective recurrence token by token: x and dt [B, T, C], A [N,
+    C], Bm and Cm [B, T, N], D [C] -> (y [B, T, C] float32, the last state
+    [B, N, C] float32). A prompt's form off a TPU, and what the kernels are
+    tested against."""
+    B, T, C = x.shape
+    N = A.shape[0]
+    state = jnp.zeros((B, N, C), jnp.float32) if init is None else init
+
+    def one(state, t):
+        y, state = selective_step(state, t[0], t[1], A, t[2], t[3], D)
+        return state, y
+
+    move = lambda a: jnp.moveaxis(a, 1, 0)      # noqa: E731
+    state, ys = jax.lax.scan(one, state,
+                             (move(x), move(dt), move(Bm), move(Cm)),
+                             unroll=SELECTIVE_UNROLL)
+    return jnp.moveaxis(ys, 0, 1), state

@@ -746,6 +746,9 @@ class DecodeEngine:
         self._closed = False
         self._draining = False
         self._thread: Optional[threading.Thread] = None
+        # what the lazy loop's turn resolved and has not handed to the
+        # readers yet (`_to_reader`); None outside its thread
+        self._outbox: Optional[List] = None
         self._rid = 0
         self._last_slot_config: Optional[int] = None
         # start times of the last decode dispatches: status() reads the
@@ -1401,6 +1404,49 @@ class DecodeEngine:
                 outcome=outcome)
         self._counts[outcome] = self._counts.get(outcome, 0) + 1
 
+    def _to_reader(self, req: _Request, item) -> None:
+        """A token (or the stream's end) on its way to the request's
+        reader. The lazy loop keeps what a turn resolves in `_outbox` and
+        hands it over AFTER the next dispatch (`_flush_outbox`): a `put`
+        wakes the reader's thread, and 128 readers awake while the loop
+        prepares the next step take the interpreter lock from it every
+        time a call into JAX or numpy lets go of it, 9 of a turn's 22 ms at
+        128 slots, with the device idle a tenth of the window (chip runs of
+        PR 49). Handed over just before the loop waits for the step in
+        flight, the readers write while it waits. Outside the lazy loop's
+        thread (a synchronous loop, `stop()` before a start) there is no
+        outbox and the item goes at once."""
+        if self._outbox is None:
+            self._hand_over(req, item)
+        else:
+            self._outbox.append((req, item))
+
+    def _flush_outbox(self) -> None:
+        box, self._outbox = self._outbox, []
+        for req, item in box:
+            self._hand_over(req, item)
+
+    def _hand_over(self, req: _Request, item) -> None:
+        if item is not None and req.t_first is None:
+            self._first_token_out(req)
+        req.events.put(item)
+
+    def _first_token_out(self, req: _Request) -> None:
+        """The request's first token leaves for its reader NOW: the time
+        to the first token ends here, the outbox's hold inside it, and not
+        where the token was resolved."""
+        req.t_first = time.monotonic()
+        TTFT_SECONDS.observe(req.t_first - req.t_submit)
+        if self._qos is not None:
+            self._qosm.TENANT_TTFT_SECONDS.observe(
+                req.t_first - req.t_submit, tenant=req.tenant)
+        if _tracing.recording or req.traced:
+            # per-request TTFT span: submit -> first token handed over
+            _tracing.record(
+                "decode.ttft", req.t_submit, req.t_first, "decode",
+                parent=req.parent, rid=req.rid, ctx=req.tctx,
+                prompt_len=req.prompt_len0, tenant=req.tenant)
+
     def _emit_token(self, req: _Request, tok: int, phase: str):
         req.last_token = int(tok)
         req.generated.append(int(tok))
@@ -1412,19 +1458,7 @@ class DecodeEngine:
             # same-tier tenants
             self._wfq.charge(req.tenant, 1)
             self._qosm.TENANT_TOKENS.inc(tenant=req.tenant)
-        if req.t_first is None:
-            req.t_first = time.monotonic()
-            TTFT_SECONDS.observe(req.t_first - req.t_submit)
-            if self._qos is not None:
-                self._qosm.TENANT_TTFT_SECONDS.observe(
-                    req.t_first - req.t_submit, tenant=req.tenant)
-            if _tracing.recording or req.traced:
-                # per-request TTFT span: submit -> first sampled token
-                _tracing.record(
-                    "decode.ttft", req.t_submit, req.t_first, "decode",
-                    parent=req.parent, rid=req.rid, ctx=req.tctx,
-                    prompt_len=req.prompt_len0, tenant=req.tenant)
-        req.events.put(int(tok))
+        self._to_reader(req, int(tok))
 
     def _finished_reason(self, req: _Request) -> Optional[str]:
         if req.generated and req.generated[-1] == self.eos_id:
@@ -1435,6 +1469,10 @@ class DecodeEngine:
 
     def _finish(self, req: _Request, reason: str):
         req.finish_reason = reason
+        if req.t_first is None and req.generated:
+            # its first token still waits in the outbox and leaves with
+            # the stream's end
+            self._first_token_out(req)
         if _tracing.recording or req.traced:
             self._record_finish(req, reason, time.monotonic())
         if req.blocks:
@@ -1446,7 +1484,7 @@ class DecodeEngine:
         if req in self._prefilling:
             self._prefilling.remove(req)
         self._count(reason, req.tenant)
-        req.events.put(None)
+        self._to_reader(req, None)
         self._kv_gauges()
 
     def _free_state_row(self, req: _Request) -> None:
@@ -1956,16 +1994,22 @@ class DecodeEngine:
         self._admit()
         if not self._active:
             self._resolve()     # nothing to dispatch behind it
+            self._flush_outbox()
             return
         self._grow_blocks()
         if not self._active:    # growth drained, then preempted everything
+            self._flush_outbox()
             return
         newest = self._dispatch(self._slot_config())
+        # the last turn's tokens to their readers, who write them while
+        # the loop waits below (`_to_reader`)
+        self._flush_outbox()
         # overlap: resolve step N-1 (and the admissions' first tokens)
         # while step N runs
         self._resolve(upto=newest)
 
     def _loop(self):
+        self._outbox = []
         try:
             while True:
                 with self._cv:
@@ -2003,6 +2047,9 @@ class DecodeEngine:
                 QUEUE_DEPTH.set(0)
             for req in reqs:
                 self._finish(req, "cancelled")
+            box, self._outbox = self._outbox, None
+            for req, item in box or ():
+                self._hand_over(req, item)
 
     # -- KV-reuse scheduler (chunked prefill / prefix cache / spec) ----
     #

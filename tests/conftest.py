@@ -36,6 +36,42 @@ def pytest_configure(config):
 
 
 # ---------------------------------------------------------------------------
+# A pin that a later cell cannot keep. tests/benchmarks/test_xing4_cell.py
+# (PR 45) asserts that its cell is the LAST entry of BENCHMARK.json's
+# `workloads` ("appended, nothing moved"); every cell appended after it makes
+# that one line false, and a PR that adds a cell may not edit a file the
+# benchmark already has. While a later cell exists that test MUST fail
+# (strict: on an assertion, and it may not pass), and everything else it
+# asserts runs in tests/benchmarks/test_xing4_cell_as_left.py against the
+# list as PR 45 left it. The next `benchmark` PR should compare the entry
+# with its own place in the list and take this hook and that file out. (It
+# lives here and not in a conftest.py of tests/benchmarks: neither directory
+# is a package, and a second module named `conftest` takes this one's place
+# for the tests that import from it.)
+# ---------------------------------------------------------------------------
+
+_PINNED_LAST = ("xing4_29b_a4b.ctx12k_sessions",
+                "tests/benchmarks/test_xing4_cell.py::test_the_cell_is_"
+                "found_with_its_readers_and_the_issues_traffic")
+
+
+def pytest_collection_modifyitems(config, items):
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           os.pardir, "BENCHMARK.json")) as f:
+        last = json.load(f)["workloads"][-1]["name"]
+    if last == _PINNED_LAST[0]:
+        return
+    for item in items:
+        if item.nodeid.endswith(_PINNED_LAST[1]):
+            item.add_marker(pytest.mark.xfail(
+                reason="pins BENCHMARK.json's last cell to PR 45's; "
+                       f"{last} was appended after it",
+                raises=AssertionError, strict=True))
+
+
+# ---------------------------------------------------------------------------
 # Subprocess hygiene (round-4 post-mortem: six ps_worker.py orphans leaked by
 # an assertion path wedged the single TPU chip for every later job). Every
 # Popen created anywhere during a test — test code, paddle_tpu launchers,

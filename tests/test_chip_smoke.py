@@ -207,6 +207,34 @@ def test_rehearse_serve_nemotron(smoke):
     assert checked["state"]["bytes"] > 0
 
 
+def test_rehearse_serve_jamba(smoke):
+    """The serve_jamba phase at a tiny size: Mamba-1 layers and multi-query
+    attention layers through the same engine and front, the state rows
+    handed out and given back, the tokens against the benchmark's plain
+    reference (off the chip both gates take the gathered forms, the chip
+    run asserts the kernels' routes)."""
+    from paddle_tpu.models import jamba
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = jamba.JambaConfig.tiny()
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 15, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(8, 16), max_len=32), prompts, max_new=4,
+        logit_tol=smoke.JAMBA_LOGIT_TOL, model=jamba,
+        reference_gaps=smoke._jamba_reference_gaps)
+    checked = info["checked"]
+    assert checked["finished"]["length"] == 3
+    assert checked["compiles_after_warmup"] == 0
+    assert checked["decode_attention"] == {"gather": 1}
+    # two Mamba layers: the decode program's, and two prefill programs'
+    assert checked["state"]["update"] == {"xla": 2, "scan_xla": 4}
+    assert checked["state"]["rows"] == 4 and checked["state"]["used"] == 0
+    assert checked["state"]["bytes"] > 0
+
+
 @pytest.mark.parametrize("heads,head_dim", [(20, 64), (16, 128)])
 def test_rehearse_paged_attention(smoke, heads, head_dim):
     """The paged_attention phase at the benchmark's two widths, small

@@ -797,6 +797,64 @@ def test_stop_drains_preenqueued_requests(model):
     assert req.finish_reason == "cancelled"
 
 
+def test_the_first_token_is_timed_where_it_leaves_the_outbox(model):
+    """The time to the first token ends where the token is handed to its
+    reader, the outbox's hold inside it: `t_first` (and with it
+    `decode.ttft` and `http.first_write`'s start) is stamped by the flush
+    that delivers the token, not where the turn resolved it; a request
+    whose only token leaves with its end has one too."""
+    eng = make_engine(model)
+    flushes = []
+    flush = eng._flush_outbox
+
+    def timed():
+        flushes.append((time.monotonic(), [
+            req for req, item in eng._outbox
+            if item is not None and req.t_first is None]))
+        flush()
+
+    eng._flush_outbox = timed
+    try:
+        handles = [eng.submit([1, 2, 3 + i], max_new_tokens=n)
+                   for i, n in enumerate((1, 4, 2))]
+        assert [len(h.result(timeout_s=120)) for h in handles] == [1, 4, 2]
+    finally:
+        eng.stop()
+    firsts = [(at, req) for at, reqs in flushes for req in reqs]
+    assert {req.rid for _, req in firsts} >= {
+        h._req.rid for h in handles[1:]}
+    for at, req in firsts:
+        assert req.t_first >= at > req.t_submit
+    assert all(h.t_first is not None for h in handles)
+
+
+def test_tokens_reach_their_readers_after_the_next_dispatch(model):
+    """What a turn resolves waits in the loop's outbox until the next
+    step is dispatched (or nothing is left to dispatch), in order, the
+    stream's end after its last token; outside the loop's thread there is
+    no outbox."""
+    eng = make_engine(model)
+    assert eng._outbox is None
+    try:
+        handles = [eng.submit([1, 2, 3 + i], max_new_tokens=n)
+                   for i, n in enumerate((1, 5, 2))]
+        streams = [h.result(timeout_s=120) for h in handles]
+        assert [len(s) for s in streams] == [1, 5, 2]
+        assert eng._outbox == []            # all of it handed over
+        solo = eng.submit([1, 2, 4], max_new_tokens=5).result(timeout_s=120)
+        assert solo == streams[1]
+    finally:
+        eng.stop()
+    assert eng._outbox is None
+    # a request finished by `stop()` before any loop ran ends at once
+    from paddle_tpu.serving.decode import DecodeHandle, _Request
+    late = make_engine(model)
+    req = _Request(1, np.array([1, 2], np.int32), 4)
+    late._finish(req, "cancelled")
+    assert DecodeHandle(req).result(timeout_s=5) == []
+    late.stop()
+
+
 def test_client_disconnect_cancels_generation(model):
     """A streaming client that hangs up mid-generation must not keep
     its slot/KV blocks for the full max_new_tokens: the frontend

@@ -105,6 +105,18 @@ def _state_update(pool, layer, rows, decay, dtx, b, c):
     return SU.state_update(pool, layer, rows, decay, dtx, b, c)
 
 
+def _selective_update(pool, layer, rows, dt, dtx, a, b, c):
+    from paddle_tpu.ops.pallas import ssm_update as SU
+
+    return SU.selective_update(pool, layer, rows, dt, dtx, a, b, c)
+
+
+def _selective_scan(x, dt, a, b, c):
+    from paddle_tpu.ops.pallas import ssm_scan as SS
+
+    return SS.selective_scan(x, dt, a, b, c)
+
+
 def _block_write(pool, layer, kv, blocks):
     from paddle_tpu.ops.pallas import kv_block_write as BW
 
@@ -117,6 +129,8 @@ _LATENT = "latent"  # shape: (slots, table blocks, layers, heads, dtype)
 _BLOCKS = "blocks"  # shape: (bucket, layers, lanes a token, dtype)
 _GQA = "gqa"        # shape: (slots, table blocks, layers, heads, kv, d, dtype)
 _STATE = "state"    # shape: (slots, layers, heads, P, N, groups)
+_SELECTIVE = "selective"    # shape: (slots, layers, state lanes, channels)
+_SCAN = "scan"      # shape: (sequences, tokens, state lanes, channels)
 _CASES = [
     # BERT long-seq cell (bs 8, T 4096, 12 heads of 64, bf16), full mask
     pytest.param(_splash(False, False), _QKV, (8, 4096, 12, 64),
@@ -173,6 +187,26 @@ _CASES = [
     # 65-row pool of 4 Mamba-2 layers, 64 heads of [64, 128] float32
     pytest.param(_state_update, _STATE, (64, 4, 64, 64, 128, 8),
                  id="ssm-state-update-nemotron-64"),
+    # the selective recurrence's rows where they lie: 128 slots of the
+    # 129-row pool of Jamba2-3B's 26 Mamba-1 layers, [16, 5120] float32 a row;
+    # and a slot count that is not whole tiles of eight
+    pytest.param(_selective_update, _SELECTIVE, (128, 26, 16, 5120),
+                 id="ssm-selective-update-jamba-128"),
+    pytest.param(_selective_update, _SELECTIVE, (12, 2, 16, 1024),
+                 id="ssm-selective-update-12-slots"),
+    # a prompt's selective scan with its state in VMEM: Jamba2-3B's 5120
+    # channels of 16 lanes over the cell's longest bucket, and two
+    # sequences of 24 tokens (a block of eight tokens)
+    pytest.param(_selective_scan, _SCAN, (1, 512, 16, 5120),
+                 id="ssm-selective-scan-jamba-512"),
+    pytest.param(_selective_scan, _SCAN, (2, 24, 16, 1024),
+                 id="ssm-selective-scan-24-tokens"),
+    # multi-query decode attention: Jamba2-3B's 20 query heads over ONE
+    # K/V head of 128 (the query block filled up to 32 rows) at the
+    # benchmark's 128 slots x 1024 tokens
+    pytest.param(_paged_gqa(20, 1), _GQA,
+                 (128, 64, 2, 20, 1, 128, jnp.bfloat16),
+                 id="paged-mqa-jamba-1024x128"),
     # the latent and the rotary key of a 4096-token prompt into their pools
     pytest.param(_block_write, _BLOCKS, (4096, 5, 512, jnp.bfloat16),
                  id="block-write-latent-4096"),
@@ -231,6 +265,19 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
                 sds((slots, H, P), f32), sds((slots, G, N), f32),
                 sds((slots, G, N), f32))
         dt = f32
+    elif kind == _SELECTIVE:
+        slots, layers, N, C = shape
+        f32 = jnp.float32
+        args = (sds((layers, slots + 1, N, C), f32), sds((), jnp.int32),
+                sds((slots,), jnp.int32), sds((slots, C), f32),
+                sds((slots, C), f32), sds((N, C), f32), sds((slots, N), f32),
+                sds((slots, N), f32))
+        dt = f32
+    elif kind == _SCAN:
+        B, T, N, C = shape
+        f32 = jnp.float32
+        args = (sds((B, T, C)), sds((B, T, C), f32), sds((N, C), f32),
+                sds((B, T, N), f32), sds((B, T, N), f32))
     elif kind == _BLOCKS:
         bucket, layers, hd, dt = shape
         blocks = bucket // 16       # 16 slots of a 1024-token table or more
@@ -241,7 +288,7 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
         M, K, N = shape
         args = (sds((M, K)), sds((K,), jnp.float32),
                 sds((K,), jnp.float32), sds((K, N)))
-    in_place = kind in (_BLOCKS, _STATE)
+    in_place = kind in (_BLOCKS, _STATE, _SELECTIVE)
     compiled = jax.jit(fn, donate_argnums=(0,) if in_place else ()
                        ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -1141,3 +1188,113 @@ def test_xing4_serve_program_fits_whatever_the_prompts_length(
         long = [d for d in re.findall(r"= \(?(?:bf16|f32)\[([\d,]+)\]", text)
                 if re.search(r"(?:^|,)12288(?:,|$)", d)]
         assert not long, long[:5]
+
+
+_JAMBA_SLOTS, _JAMBA_CONTEXT = 128, 1024
+
+
+@pytest.fixture(scope="module")
+def jamba_28l(v5e):
+    from paddle_tpu.models import jamba
+
+    return _described(v5e, jamba, jamba.JambaConfig(max_len=_JAMBA_CONTEXT),
+                      _JAMBA_SLOTS, _JAMBA_CONTEXT)
+
+
+# the smallest of the cell's four buckets: a prefill program holds 26 scan
+# kernels and takes from half a minute (64 tokens) to a minute and a half
+# (512) to compile here; the larger ones compile on the chip at every boot
+@pytest.mark.parametrize("program", ["decode@128", "prefill@64"])
+def test_jamba_serve_program_fits_and_updates_the_state_in_place(
+        jamba_28l, program, monkeypatch):
+    """AI21-Jamba2-3B whole (26 Mamba-1 and 2 attention layers at the
+    published widths, the whole vocabulary) as `jamba2_3b.chat_closed`
+    serves it: 6.06 GB of weights, a K/V pool of TWO layers and the state
+    row pools, all three donated and written where they lie. The decode
+    program moves the slots' states across HBM twice (the kernel's read
+    and write of each row) and not five times: no op holds them outside
+    the pool (the gathered form would: `f32[128,16,5120]`, 42 MB a layer,
+    three times)."""
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.ops.pallas import ssm_update as SU
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, state, kv, sds = jamba_28l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _JAMBA_CONTEXT // _BLOCK
+    for counts in (PA.GATE_COUNTS, SU.GATE_COUNTS, kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32), state, sds((n,), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32), state, sds((), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4, 6)).lower(params,
+                                                       *args).compile()
+    assert kv.pool_shapes == ((2, 8193, 16, 128),) * 2
+    assert kv.bytes_per_token() == 512      # a layer; two layers hold K/V
+    assert [s.shape for s in state] == [(26, 129, 120, 128),
+                                        (26, 129, 16, 5120)]
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    # weights 6.06 GB + K/V 0.13 GB + state 1.20 GB resident, the rest
+    # temporaries: a prompt's are bounded by its bucket's rows of
+    # activations, not by a state a token
+    assert 7.35e9 < planned < 7.75e9, ma
+    assert ma.temp_size_in_bytes < (0.08e9 if kind == "decode" else 0.3e9), ma
+    state_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in state)
+    assert state_bytes == pytest.approx(1.202e9, rel=1e-3)
+    assert ma.alias_size_in_bytes >= kv.pool_bytes() + state_bytes, ma
+    text = compiled.as_text()
+    # the K/V pools, 33.5 MB each (two layers of ONE K/V head): nothing
+    # slices or relays them; they are small enough that XLA may prefetch
+    # one into VMEM around a prefill's block writes (an asynchronous copy
+    # in and out, 0.1 ms of a prefill's several)
+    for pool in pools:
+        moved = {op for op, _ in _pool_movers(text, pool.shape)}
+        assert moved <= ({"copy-start", "copy-done"} if kind == "prefill"
+                         else set()), moved
+    tails, ssm = state
+    # the states, 1.10 GB: the decode program's kernel updates the rows
+    # where they lie and nothing else touches the pool; a prefill writes its
+    # one row a Mamba layer into the donated pool
+    moved = collections.Counter(op for op, _ in _pool_movers(text, ssm.shape))
+    assert moved == ({} if kind == "decode"
+                     else {"dynamic-update-slice": 26}), moved
+    moved = collections.Counter(op for op, _ in
+                                _pool_movers(text, tails.shape))
+    if kind == "decode":
+        assert set(moved) <= {"copy-start", "copy-done"}, moved
+    else:
+        assert moved == {"dynamic-update-slice": 26}, moved
+    kernels = _kernels(text)
+    if kind == "decode":
+        assert PA.GATE_COUNTS == {"paged_gqa": 1}, PA.GATE_COUNTS
+        assert SU.GATE_COUNTS == {"kernel": 26}, SU.GATE_COUNTS
+        assert sum("/attention/" in k for k in kernels) == 2, kernels
+        updates = [k for k in kernels if "/ssm/scan/" in k]
+        assert len(updates) == 26 and all(
+            "ssm_selective_update" in k for k in updates), kernels
+        held = re.findall(r"= \(?f32\[128,16,5120\]", text)
+        assert not held, held[:3]
+    else:
+        assert kvc.PREFILL_WRITE_UNITS == {"blocks": 4}
+        assert sum("kv_block_write" in k for k in kernels) == 4, kernels
+        # a prompt's scan keeps its state in VMEM, a kernel a Mamba layer,
+        # and holds never a state a token of the bucket
+        assert SU.GATE_COUNTS == {"scan_kernel": 26}, SU.GATE_COUNTS
+        scans = [k for k in kernels if "/ssm/scan/" in k]
+        assert len(scans) == 26 and all(
+            "ssm_selective_scan" in k for k in scans), kernels
+        held = re.findall(r"= \(?f32\[(?:1,)?%d,16,5120\]" % n, text)
+        assert not held, held[:3]
