@@ -128,7 +128,12 @@ def dropout(rng: Optional[jax.Array], x: jax.Array, rate: float,
             deterministic: bool) -> jax.Array:
     if deterministic or rate == 0.0 or rng is None:
         return x
-    keep = jax.random.bernoulli(rng, 1.0 - rate, x.shape)
+    with jax.named_scope("dropout"):
+        # the barrier keeps the generator out of its readers' fusions: XLA
+        # takes threefry for a cheap elementwise producer and would run it
+        # again inside every fusion that reads the mask, forward and backward
+        keep = jax.lax.optimization_barrier(
+            jax.random.bernoulli(rng, 1.0 - rate, x.shape))
     return jnp.where(keep, x / (1.0 - rate), 0).astype(x.dtype)
 
 

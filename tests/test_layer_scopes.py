@@ -10,6 +10,7 @@ import re
 import jax
 import jax.numpy as jnp
 import optax
+import pytest
 
 from paddle_tpu.models import bert, decoder, gpt
 from serve_contract import scopes_of as _scopes
@@ -57,7 +58,10 @@ def test_gpt_training_forward_carries_the_scopes():
     assert not missing, missing
 
 
-def test_bert_train_step_carries_the_scopes_forward_and_backward():
+@pytest.fixture(scope="module")
+def bert_step_op_names():
+    """Every `op_name` of the tiny BERT's compiled train step (dropout 0.1,
+    the configuration's default)."""
     from paddle_tpu.parallel import MeshConfig, make_mesh, mesh_guard
     from paddle_tpu.parallel.train import TrainStrategy, make_train_step
 
@@ -74,13 +78,45 @@ def test_bert_train_step_carries_the_scopes_forward_and_backward():
         batch = bert.make_batch(jax.random.key(1), cfg, 4, 16)
         text = step.lower(state, batch, jax.random.key(2)).compile(
             ).as_text()
-    names = re.findall(r'op_name="([^"]*)"', text)
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+def _scopes_of_names(names):
+    return _scopes("\n".join(f'op_name="{n}"' for n in names))
+
+
+def test_bert_train_step_carries_the_scopes_forward_and_backward(
+        bert_step_op_names):
+    names = bert_step_op_names
     missing = (LAYER | {"embed", "layers", "mlm_head", "nsp_head", "loss",
-                        "clip", "optimizer"}) - _scopes(text)
+                        "clip", "optimizer"}) - _scopes_of_names(names)
     assert not missing, missing
     # the backward pass keeps the scopes; autodiff wraps the outermost:
     # transpose(jvp(layers))/attention/..., transpose(jvp(mlm_head))/...
-    backward = _scopes("\n".join(f'op_name="{n}"' for n in names
-                                 if "transpose(jvp(" in n))
+    backward = _scopes_of_names(n for n in names if "transpose(jvp(" in n)
     missing = (LAYER | {"layers", "mlm_head"}) - backward
     assert not missing, missing
+
+
+def test_a_dropout_masks_ops_sit_under_dropout_inside_proj_and_mlp(
+        bert_step_op_names):
+    """The generator of a mask carries `dropout` in its `op_name`, inside
+    the scope of the layer that drops (`proj`, `mlp`), and only there; the
+    benchmark's reduction still books it to that layer, since `dropout` is
+    not among its `SCOPES` (a `dropout_share` is a benchmark PR's to add)."""
+    from benchmarks.harness import program_trace
+
+    names = bert_step_op_names
+    mask = [n for n in names if n.endswith(("/xor", "/shift_left"))
+            and "_bernoulli" in n]
+    assert mask
+    for n in mask:
+        path = n.split("/")
+        at = path.index("dropout")
+        assert path[at - 1] in ("proj", "mlp"), n
+        assert path[at + 1] == "jit(_bernoulli)", n
+    assert {program_trace.scope_of(n) for n in mask} == {"proj", "mlp"}
+    assert "dropout" not in program_trace.SCOPES
+    # nothing else of the step moved under the new scope: the select and
+    # its transpose stay the layer's own ops
+    assert all("_bernoulli" in n for n in names if "/dropout/" in n)

@@ -1298,3 +1298,103 @@ def test_jamba_serve_program_fits_and_updates_the_state_in_place(
             "ssm_selective_scan" in k for k in scans), kernels
         held = re.findall(r"= \(?f32\[(?:1,)?%d,16,5120\]" % n, text)
         assert not held, held[:3]
+
+
+# -- the BERT-base train step (bert_base.pretrain128's shapes) ---------------
+
+_BERT_BATCH, _BERT_SEQ, _BERT_MASKED = 256, 128, 20
+
+
+def _described_train_step(v5e):
+    """The step of `bert_base.pretrain128` as `benchmarks/kinds/train.py`
+    builds it (the family's loss, adamw, optimizer states sharded over a
+    `dp` of one), lowered for shapes on the described chip."""
+    import optax
+
+    from paddle_tpu.models import bert
+    from paddle_tpu.parallel import MeshConfig, make_mesh, mesh_guard
+    from paddle_tpu.parallel import train as T
+
+    cfg = bert.BertConfig(vocab_size=30522, hidden=768, layers=12, heads=12,
+                          mlp_dim=3072, max_len=512, dropout=0.1,
+                          dtype="bfloat16")
+    mesh = make_mesh(MeshConfig(dp=1), devices=list(v5e[:1]))
+    repl = NamedSharding(mesh, P())
+
+    def sds(x, sharding=repl):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    with mesh_guard(mesh):
+        axes = {}   # the logical axes are plain Python: kept on the way
+
+        def init(key):
+            params, named = bert.init(key, cfg)
+            axes.update(named)
+            return params
+
+        shapes = jax.eval_shape(init, jax.random.key(0))
+        tx = optax.adamw(1e-4)
+        _, step = T.make_train_step(
+            lambda p, b, r: bert.pretrain_loss(p, cfg, b, rng=r,
+                                               deterministic=False),
+            tx, mesh, axes,
+            strategy=T.TrainStrategy(shard_optimizer_states=True))
+        sharded = T.param_shardings(mesh, axes, T.current_rules())
+        params = {k: sds(v, sharded[k]) for k, v in shapes.items()}
+        opt = jax.tree.map(sds, jax.eval_shape(      # every one trainable
+            optax.masked(tx, lambda p: dict.fromkeys(p, True)).init, params))
+        state = T.TrainState(params, opt, sds(jnp.zeros((), jnp.int32)))
+        rows = NamedSharding(mesh, P("dp"))
+        batch = {k: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rows)
+                 for k, s in {
+                     "input_ids": (_BERT_BATCH, _BERT_SEQ),
+                     "token_type_ids": (_BERT_BATCH, _BERT_SEQ),
+                     "masked_positions": (_BERT_BATCH, _BERT_MASKED),
+                     "masked_labels": (_BERT_BATCH, _BERT_MASKED),
+                     "nsp_labels": (_BERT_BATCH,)}.items()}
+        return cfg, step.lower(state, batch,
+                               sds(jax.eval_shape(jax.random.key, 0)))
+
+
+def _threefry_evaluations(text, shape):
+    """Evaluations of threefry2x32 over `shape` in an optimized HLO text,
+    through nested fusions: its `xor`s over `u32[shape]` in every
+    computation, 21 an evaluation (20 rounds and the fold of the two
+    halves); and the number of computations that hold any."""
+    xor = re.compile(r"= u32\[%s\][^ ]* xor\(" % ",".join(map(str, shape)))
+    held = collections.Counter()
+    name = None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", line)
+            name = m.group(1) if m else None
+        elif name and xor.search(line):
+            held[name] += 1
+    total = sum(held.values())
+    assert total % 21 == 0, held
+    return total // 21, len(held)
+
+
+def test_bert_base_train_step_evaluates_each_dropout_mask_once(v5e):
+    """24 masks a step (two a layer, `bool[256,128,768]`), each drawn ONCE:
+    `common.dropout` pins its mask, so that XLA cannot run the generator
+    again inside every fusion that reads it. Without the pin this program
+    reads 96 evaluations in 80 computations (the forward's matmul, the
+    backward's input and weight gradients, and the bias gradients' eight
+    reductions with three masks each), 63 ms more of a 221 ms step on the
+    chip (PERF.md, PR 50). And the 24 stored masks (0.6 GB) leave the step
+    under the chip's memory."""
+    cfg, lowered = _described_train_step(v5e)
+    compiled = lowered.compile()
+    masks = 2 * cfg.layers
+    evaluations, computations = _threefry_evaluations(
+        compiled.as_text(), (_BERT_BATCH, _BERT_SEQ, cfg.hidden))
+    assert evaluations == masks, (evaluations, computations)
+    assert computations <= masks
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes
+               + ma.generated_code_size_in_bytes)
+    # parameters and both moments in float32 1.32 GB; temporaries 8.8 GB,
+    # of them 0.6 GB the masks (8.24 GB without the pin); the chip has 16
+    assert 9.5e9 < planned < 11.0e9, ma
