@@ -832,12 +832,12 @@ def run_one_chip() -> None:
         checked = info["checked"]
         # the 20 query heads of the one K/V head through the kernel (its
         # query block filled up to 32 rows), and the three Mamba layers'
-        # rows advanced where they lie
+        # rows, states and tails, advanced where they lie
         assert checked["decode_attention"] == {"paged_gqa": 1}, info
         # (and a prompt's scan with its state in VMEM: three Mamba layers
         # in each of three prefill programs)
-        assert checked["state"]["update"] == {"kernel": 3,
-                                              "scan_kernel": 9}, info
+        assert checked["state"]["update"] == {
+            "kernel": 3, "tail_kernel": 3, "scan_kernel": 9}, info
         assert checked["state"]["rows"] == 16 \
             and checked["state"]["used"] == 0, info
         # one attention layer: K and V, three prefill programs

@@ -316,21 +316,23 @@ def mamba_prompt(lp, y, length, cfg: JambaConfig):
     return _ssm_out(lp, out, z, y.dtype), tail, state
 
 
-def _token_inputs(lp, y, tail, cfg: JambaConfig):
+def _token_inputs(lp, y, tail, cfg: JambaConfig, advance=_ssm.conv_step):
     """One token a row through the input projection, the convolution and
-    the selection: (z, x [S, inner], dt, B, C, the new tail)."""
+    the selection: (z, x [S, inner], dt, B, C, the new tail).
+    `advance(tail, x, w, b)` is the convolution's step: `ops/ssm.conv_step`
+    over gathered tails, or the tails' pool moved on where it lies."""
     x, z = _split_in(lp, y, cfg)
     with jax.named_scope("conv"):
-        x, tail = _ssm.conv_step(tail, x, lp["blk.conv_w"],
-                                 lp["blk.conv_b"])
+        x, tail = advance(tail, x, lp["blk.conv_w"], lp["blk.conv_b"])
         x = jax.nn.silu(x)
     return (z, x) + _selection(lp, x, cfg) + (tail,)
 
 
-def mamba_token(lp, y, tail, state, cfg: JambaConfig):
+def mamba_token(lp, y, tail, state, cfg: JambaConfig,
+                advance=_ssm.conv_step):
     """One token a row: y [S, hidden], tail [S, K-1, inner], state [S, N,
     inner] float32 -> (out [S, hidden], tail, state)."""
-    z, x, dt, Bm, Cm, tail = _token_inputs(lp, y, tail, cfg)
+    z, x, dt, Bm, Cm, tail = _token_inputs(lp, y, tail, cfg, advance)
     with jax.named_scope("scan"):
         out, state = _ssm.selective_step(
             state, x, dt, _decay_rates(lp), Bm, Cm, lp["blk.D"])
@@ -422,25 +424,36 @@ class JambaServe(_decoder.ServeModel):
         return out, (conv, pool)
 
     def ssm_token(self, lp, y, state, i, rows, positions=None):
-        """The convolution's tails are gathered and scattered (30 KB a
-        row); the states, 320 KB a row, are advanced where they lie by
-        `ops/pallas/ssm_update.selective_update` on a TPU, and gathered,
-        advanced and scattered back elsewhere."""
+        """On a TPU both pools are advanced where they lie, a kernel each
+        and several rows a grid step (`ops/pallas/ssm_update.py`: the
+        convolution's tails, 30 KB a row, by `advance_tails`, the states,
+        320 KB a row, by `selective_update`); elsewhere a pool's rows are
+        gathered, advanced and scattered back. Each pool has its own gate."""
         cfg = self.cfg
         conv, pool = state
         kernel = _update.use_selective_kernel(y, pool)
+        in_place = _update.use_tail_kernel(y, conv, cfg.conv_kernel)
         _update.GATE_COUNTS["kernel" if kernel else "xla"] += 1
-        with jax.named_scope("state_read"):
-            tail = conv[i, rows].reshape(
-                rows.shape[0], cfg.conv_kernel - 1, cfg.inner)
+        _update.GATE_COUNTS["tail_kernel" if in_place else "tail_xla"] += 1
+        if in_place:
+            tail = conv
+
+            def advance(conv, x, w, b):
+                return _update.advance_tails(conv, jnp.int32(i), rows, x, w,
+                                             b)
+        else:
+            advance = _ssm.conv_step
+            with jax.named_scope("state_read"):
+                tail = conv[i, rows].reshape(
+                    rows.shape[0], cfg.conv_kernel - 1, cfg.inner)
         if not kernel:
             with jax.named_scope("state_read"):
                 s = pool[i, rows]
-            out, tail, s = mamba_token(lp, y, tail, s, cfg)
+            out, tail, s = mamba_token(lp, y, tail, s, cfg, advance)
             with jax.named_scope("state_write"):
                 pool = pool.at[i, rows].set(s)
         else:
-            z, x, dt, Bm, Cm, tail = _token_inputs(lp, y, tail, cfg)
+            z, x, dt, Bm, Cm, tail = _token_inputs(lp, y, tail, cfg, advance)
             with jax.named_scope("scan"):
                 xf = x.astype(jnp.float32)
                 out, pool = _update.selective_update(
@@ -448,6 +461,8 @@ class JambaServe(_decoder.ServeModel):
                     Bm, Cm)
                 out = out + lp["blk.D"].astype(jnp.float32)[None] * xf
             out = _ssm_out(lp, out, z, y.dtype)
+        if in_place:
+            return out, (tail, pool)
         with jax.named_scope("state_write"):
             conv = conv.at[i, rows].set(
                 tail.reshape(rows.shape[:1] + conv.shape[2:])

@@ -64,7 +64,9 @@ functions are what both are tested against and what runs everywhere else.
 
 `causal_conv` / `conv_step` are the width-K depthwise convolution over the
 sequence: `out_t = b + sum_k w[k] x_{t-(K-1)+k}`, and its one-token form
-over a tail of the last K-1 inputs; both recurrences' layers use them.
+over a tail of the last K-1 inputs; both recurrences' layers use them (on
+a TPU a Mamba-1 layer's tails are moved on where they lie in their pool,
+`ops/pallas/ssm_update.advance_tails`, which is tested against `conv_step`).
 
 Every function is row-independent: a sequence's results depend on that
 sequence alone. All ops carry the layer scope `ssm` of their caller.

@@ -230,7 +230,8 @@ def test_rehearse_serve_jamba(smoke):
     assert checked["compiles_after_warmup"] == 0
     assert checked["decode_attention"] == {"gather": 1}
     # two Mamba layers: the decode program's, and two prefill programs'
-    assert checked["state"]["update"] == {"xla": 2, "scan_xla": 4}
+    assert checked["state"]["update"] == {"xla": 2, "tail_xla": 2,
+                                          "scan_xla": 4}
     assert checked["state"]["rows"] == 4 and checked["state"]["used"] == 0
     assert checked["state"]["bytes"] > 0
 

@@ -306,13 +306,53 @@ def test_the_pools_are_the_models():
 # -- the two kernels, through the interpreter --------------------------------
 
 
-@pytest.mark.parametrize("C", [256, 1024])
-def test_the_selective_update_kernel_is_the_recurrences_step(C):
-    L, R, N, S = 2, 7, 16, 11
+# (S slots' rows, rows a grid step or None for the rule's, channels): what
+# the row walk must get right whatever the kernel's body
+_IDLE = [3, 0, 5, 0, 1, 6, 2, 0, 4, 0, 0]
+_WALKS = [
+    pytest.param(_IDLE, None, 256, id="fewer-slots-than-a-step-256"),
+    pytest.param(_IDLE, None, 1024, id="fewer-slots-than-a-step-1024"),
+    pytest.param(_IDLE, 4, 512, id="a-last-step-filled-up"),
+    pytest.param([6, 2, 7, 1, 5, 3, 9, 4], 4, 512,
+                 id="whole-steps-rows-out-of-order"),
+    pytest.param([0, 0, 5, 0, 1, 0, 0, 2, 0, 8, 0, 0], 4, 512,
+                 id="idle-slots-beside-live-rows"),
+    pytest.param([9, 4, 1, 12, 7, 2, 11, 5, 3, 8, 6, 10, 13], 2, 512,
+                 id="seven-steps-through-two-slots"),
+    pytest.param([5], None, 512, id="one-slot"),
+]
+_POOL_ROWS = 16
+
+
+def _walks_rows(monkeypatch, rows, per_step, row_bytes):
+    """`rows` as an array, with the rule steered to `per_step` rows a grid
+    step for a row of `row_bytes` (the rule itself has a test of its own)."""
+    if per_step is not None:
+        monkeypatch.setattr(SU, "_STEP_BYTES", per_step * row_bytes)
+    assert SU.rows_per_step(row_bytes, len(rows)) == (
+        per_step or 1 << (len(rows) - 1).bit_length())
+    return jnp.asarray(rows, jnp.int32)
+
+
+def _left_alone(new, old, layer, rows):
+    """The other layer, and this layer's rows that `rows` does not name, bit
+    for bit as they were: nothing else of a pool moves (but the null row,
+    which a last step is filled up with)."""
+    np.testing.assert_array_equal(new[1 - layer], old[1 - layer])
+    rest = sorted(set(range(1, old.shape[1]))
+                  - set(np.asarray(rows).tolist()))
+    assert rest
+    np.testing.assert_array_equal(new[layer, rest], old[layer, rest])
+
+
+@pytest.mark.parametrize("rows, per_step, C", _WALKS)
+def test_the_selective_update_kernel_is_the_recurrences_step(
+        monkeypatch, rows, per_step, C):
+    L, N, S = 2, 16, len(rows)
+    rows = _walks_rows(monkeypatch, rows, per_step, N * C * 4)
     rng = np.random.default_rng(0)
     f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731,E501
-    pool = f32(L, R, N, C)
-    rows = jnp.asarray([3, 0, 5, 0, 1, 6, 2, 0, 4, 0, 0], jnp.int32)
+    pool = f32(L, _POOL_ROWS, N, C)
     x, Bm, Cm = f32(S, C), f32(S, N), f32(S, N)
     dt = jnp.asarray(rng.uniform(0.01, 0.5, (S, C)), jnp.float32)
     A = -jnp.asarray(rng.uniform(1, 16, (N, C)), jnp.float32)
@@ -325,8 +365,86 @@ def test_the_selective_update_kernel_is_the_recurrences_step(C):
     live = np.asarray(rows) > 0
     np.testing.assert_allclose(y[live], want_y[live], atol=2e-5)
     np.testing.assert_allclose(new[1, rows][live], want_s[live], atol=2e-6)
-    # the other layer is as it was: nothing else of the pool moves
-    np.testing.assert_array_equal(new[0], pool[0])
+    _left_alone(new, pool, 1, rows)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("rows, per_step, C", _WALKS[1:])
+def test_the_tails_kernel_is_the_convolutions_step(monkeypatch, rows,
+                                                   per_step, C, dtype):
+    C = max(C, 1024)        # a position is whole float32 tiles: the gate
+    L, K, S = 2, 4, len(rows)
+    rows = _walks_rows(monkeypatch, rows, per_step,
+                       (K - 1) * C * jnp.dtype(dtype).itemsize)
+    rng = np.random.default_rng(1)
+    draw = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)  # noqa: E731
+    pool = draw(L, _POOL_ROWS, (K - 1) * C // 128, 128)
+    x, w, b = draw(S, C), draw(K, C), draw(C)
+    want, want_tail = ssm.conv_step(
+        pool[1, rows].reshape(S, K - 1, C), x, w, b)
+    out, new = jax.jit(lambda *a: SU.advance_tails(
+        *a, interpret=pltpu.InterpretParams()))(
+        pool, jnp.int32(1), rows, x, w, b)
+    assert out.shape == (S, C) and out.dtype == dtype
+    live = np.asarray(rows) > 0
+    as_f32 = lambda a: np.asarray(a.astype(jnp.float32))    # noqa: E731
+    # float32 sums in another order, then (bf16) one rounding to 8 bits
+    np.testing.assert_allclose(
+        as_f32(out)[live], as_f32(want)[live], atol=1e-5,
+        rtol=2 ** -7 if dtype == jnp.bfloat16 else 1e-5)
+    # the stored tail bit for bit: the same inputs, moved on by one
+    np.testing.assert_array_equal(
+        as_f32(new[1, rows]).reshape(S, K - 1, C)[live],
+        as_f32(want_tail)[live])
+    _left_alone(as_f32(new), as_f32(pool), 1, rows)
+
+
+def test_a_prompts_tail_is_advanced_token_by_token_where_it_lies():
+    """`ssm_prompt` writes a prompt's tail into the pool; `advance_tails`
+    then moves it on a token at a time, and row and convolution agree with
+    `mamba_prompt` over the prompt grown by those tokens."""
+    cfg = jamba.JambaConfig(vocab_size=64, hidden=512, n_layers=1,
+                            attn_period=2, attn_offset=1, mlp_dim=64,
+                            dt_rank=8, heads=4, head_dim=16, max_len=32,
+                            dtype="float32")
+    sm, T0, T, row = cfg.serve_model(), 5, 9, 2
+    lp = jamba.init_layer(jax.random.PRNGKey(0), cfg, 0, "M")
+    y = _normal(jax.random.PRNGKey(1), (1, T, cfg.hidden))
+    state = tuple(jnp.ones(s, d) for s, d in sm.state_pools(4, jnp.float32))
+    assert state[0].shape == (1, 4, 24, 128)
+    bucket = jnp.pad(y[:, :T0], [(0, 0), (0, 8 - T0), (0, 0)])
+    _, (conv, _) = jax.jit(lambda b, s: sm.ssm_prompt(
+        lp, b, jnp.int32(T0), s, 0, row))(bucket, state)
+    grown = jax.jit(lambda n: jamba.mamba_prompt(lp, y, n, cfg)[1])
+    xs = jamba._split_in(lp, y, cfg)[0]
+    convolved = ssm.causal_conv(xs, lp["blk.conv_w"], lp["blk.conv_b"])
+    step = jax.jit(lambda c, x: SU.advance_tails(
+        c, jnp.int32(0), jnp.asarray([row], jnp.int32), x, lp["blk.conv_w"],
+        lp["blk.conv_b"], interpret=pltpu.InterpretParams()))
+    # (to float32 rounding: the projection of 8, of 9 and of one token are
+    # three matmuls; what the kernel itself stores is exact, above)
+    close = functools.partial(np.testing.assert_allclose, atol=1e-5,
+                              rtol=1e-5)
+    close(conv[0, row].reshape(3, cfg.inner), grown(T0)[0])
+    for t in range(T0, T):
+        out, conv = step(conv, xs[:, t])
+        close(out, convolved[:, t])
+        close(conv[0, row].reshape(3, cfg.inner), grown(t + 1)[0])
+    np.testing.assert_array_equal(conv[0, [0, 1, 3]], state[0][0, [0, 1, 3]])
+
+
+def test_the_walks_rows_a_step_come_from_the_shapes():
+    # a state row of Jamba2-3B (16 x 5120 float32, 320 KB) and a tail (3 x
+    # 5120 bf16, 30 KB) at 128 slots: the powers of two nearest 2 MB a step
+    assert SU.rows_per_step(16 * 5120 * 4, 128) == 8
+    assert SU.rows_per_step(3 * 5120 * 2, 128) == 64
+    assert SU.rows_per_step(16 * 8192 * 4, 128) == 4
+    assert SU.rows_per_step(8 << 20, 128) == 1
+    # and never more than cover the slots
+    assert [SU.rows_per_step(3 * 5120 * 2, s) for s in (1, 2, 3, 16, 17,
+                                                        100)] \
+        == [1, 2, 4, 16, 32, 64]
 
 
 def test_the_selective_updates_gate(monkeypatch):
@@ -343,6 +461,24 @@ def test_the_selective_updates_gate(monkeypatch):
         x, jax.ShapeDtypeStruct((26, 129, 16, 1280), jnp.float32))
     # and Mamba-2's gate stays shut for this state, as ever
     assert not SU.use_kernel(x, pool, 1)
+
+
+def test_the_tails_gate(monkeypatch):
+    from paddle_tpu.ops.pallas import attention as A
+
+    sds = jax.ShapeDtypeStruct
+    pool = sds((26, 129, 120, 128), jnp.bfloat16)
+    x = jnp.zeros((128, 2560), jnp.bfloat16)
+    assert not SU.use_tail_kernel(x, pool, 4)           # off the TPU
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    assert SU.use_tail_kernel(x, pool, 4)
+    assert SU.use_tail_kernel(x, sds(pool.shape, jnp.float32), 4)
+    # a position must be whole float32 tiles of 8 x 128: 5120 channels are
+    # 40 rows of lanes, the tiny model's 128 one
+    assert not SU.use_tail_kernel(x, sds((2, 5, 3, 128), jnp.bfloat16), 4)
+    assert not SU.use_tail_kernel(x, pool, 5)
+    assert not SU.use_tail_kernel(x, sds((26, 129, 384), jnp.bfloat16), 4)
+    assert not SU.use_tail_kernel(x, sds(pool.shape, jnp.float16), 4)
 
 
 @pytest.mark.parametrize("T, C, n", [(24, 512, 19), (256, 1024, 256),

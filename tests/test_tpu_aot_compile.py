@@ -111,6 +111,12 @@ def _selective_update(pool, layer, rows, dt, dtx, a, b, c):
     return SU.selective_update(pool, layer, rows, dt, dtx, a, b, c)
 
 
+def _advance_tails(pool, layer, rows, x, w, b):
+    from paddle_tpu.ops.pallas import ssm_update as SU
+
+    return SU.advance_tails(pool, layer, rows, x, w, b)
+
+
 def _selective_scan(x, dt, a, b, c):
     from paddle_tpu.ops.pallas import ssm_scan as SS
 
@@ -130,6 +136,7 @@ _BLOCKS = "blocks"  # shape: (bucket, layers, lanes a token, dtype)
 _GQA = "gqa"        # shape: (slots, table blocks, layers, heads, kv, d, dtype)
 _STATE = "state"    # shape: (slots, layers, heads, P, N, groups)
 _SELECTIVE = "selective"    # shape: (slots, layers, state lanes, channels)
+_TAILS = "tails"    # shape: (slots, layers, taps, channels, dtype)
 _SCAN = "scan"      # shape: (sequences, tokens, state lanes, channels)
 _CASES = [
     # BERT long-seq cell (bs 8, T 4096, 12 heads of 64, bf16), full mask
@@ -194,6 +201,19 @@ _CASES = [
                  id="ssm-selective-update-jamba-128"),
     pytest.param(_selective_update, _SELECTIVE, (12, 2, 16, 1024),
                  id="ssm-selective-update-12-slots"),
+    # (the walk takes 8 such rows a grid step, 16 of the narrow ones: 12
+    # and 100 slots leave a last step that is filled up with the null row)
+    pytest.param(_selective_update, _SELECTIVE, (100, 2, 16, 5120),
+                 id="ssm-selective-update-100-slots"),
+    # the convolution's tails where they lie: a row the last 3 inputs of
+    # 5120 bf16 channels end to end, [120, 128]: 7.5 bf16 tiles, widened in
+    # VMEM to 15 float32 ones; 64 rows a grid step
+    pytest.param(_advance_tails, _TAILS, (128, 26, 4, 5120, jnp.bfloat16),
+                 id="ssm-advance-tails-jamba-128"),
+    pytest.param(_advance_tails, _TAILS, (100, 2, 4, 5120, jnp.bfloat16),
+                 id="ssm-advance-tails-100-slots"),
+    pytest.param(_advance_tails, _TAILS, (12, 2, 4, 1024, jnp.float32),
+                 id="ssm-advance-tails-f32-12-slots"),
     # a prompt's selective scan with its state in VMEM: Jamba2-3B's 5120
     # channels of 16 lanes over the cell's longest bucket, and two
     # sequences of 24 tokens (a block of eight tokens)
@@ -273,6 +293,11 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
                 sds((slots, C), f32), sds((N, C), f32), sds((slots, N), f32),
                 sds((slots, N), f32))
         dt = f32
+    elif kind == _TAILS:
+        slots, layers, K, C, dt = shape
+        args = (sds((layers, slots + 1, (K - 1) * C // 128, 128), dt),
+                sds((), jnp.int32), sds((slots,), jnp.int32),
+                sds((slots, C), dt), sds((K, C), dt), sds((C,), dt))
     elif kind == _SCAN:
         B, T, N, C = shape
         f32 = jnp.float32
@@ -288,7 +313,7 @@ def test_kernel_compiles_for_v5e(v5e, fn, kind, shape):
         M, K, N = shape
         args = (sds((M, K)), sds((K,), jnp.float32),
                 sds((K,), jnp.float32), sds((K, N)))
-    in_place = kind in (_BLOCKS, _STATE, _SELECTIVE)
+    in_place = kind in (_BLOCKS, _STATE, _SELECTIVE, _TAILS)
     compiled = jax.jit(fn, donate_argnums=(0,) if in_place else ()
                        ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -1271,21 +1296,27 @@ def test_jamba_serve_program_fits_and_updates_the_state_in_place(
     moved = collections.Counter(op for op, _ in _pool_movers(text, ssm.shape))
     assert moved == ({} if kind == "decode"
                      else {"dynamic-update-slice": 26}), moved
+    # and the tails, 0.10 GB, the same: the decode program's kernel moves
+    # each row on where it lies (no gather, scatter or slice of the pool,
+    # no copy of the slots' tails outside it)
     moved = collections.Counter(op for op, _ in
                                 _pool_movers(text, tails.shape))
-    if kind == "decode":
-        assert set(moved) <= {"copy-start", "copy-done"}, moved
-    else:
-        assert moved == {"dynamic-update-slice": 26}, moved
+    assert moved == ({} if kind == "decode"
+                     else {"dynamic-update-slice": 26}), moved
     kernels = _kernels(text)
     if kind == "decode":
         assert PA.GATE_COUNTS == {"paged_gqa": 1}, PA.GATE_COUNTS
-        assert SU.GATE_COUNTS == {"kernel": 26}, SU.GATE_COUNTS
+        assert SU.GATE_COUNTS == {"kernel": 26, "tail_kernel": 26}, \
+            SU.GATE_COUNTS
         assert sum("/attention/" in k for k in kernels) == 2, kernels
         updates = [k for k in kernels if "/ssm/scan/" in k]
         assert len(updates) == 26 and all(
             "ssm_selective_update" in k for k in updates), kernels
-        held = re.findall(r"= \(?f32\[128,16,5120\]", text)
+        advances = [k for k in kernels if "/ssm/conv/" in k]
+        assert len(advances) == 26 and all(
+            "ssm_advance_tails" in k for k in advances), kernels
+        held = re.findall(r"= \(?(?:f32\[128,16,5120|bf16\[128,"
+                          r"(?:3,5120|120,128|15360))\]", text)
         assert not held, held[:3]
     else:
         assert kvc.PREFILL_WRITE_UNITS == {"blocks": 4}
