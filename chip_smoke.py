@@ -16,6 +16,7 @@ over. Numbers printed here are information, not benchmark results.
                                     long_seq, serve, serve_reuse,
                                     serve_olmoe, serve_joyai, serve_xing4,
                                     serve_nemotron, serve_jamba,
+                                    serve_longcat, longcat_experts,
                                     paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
@@ -46,6 +47,7 @@ JOYAI_LOGIT_TOL = 0.4    # benchmarks/configs/joyai_llm_flash.json argues it
 XING4_LOGIT_TOL = 0.55   # benchmarks/configs/xing4_29b_a4b.json argues it
 NEMOTRON_LOGIT_TOL = 0.4  # benchmarks/configs/nemotron3_nano.json argues it
 JAMBA_LOGIT_TOL = 2.5    # benchmarks/configs/jamba2_3b.json argues it
+LONGCAT_LOGIT_TOL = 0.3  # benchmarks/configs/longcat_flash_chat.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
 # many of those its persistent cache answered (a hit still counts as a
@@ -391,6 +393,23 @@ def _jamba_reference_gaps(params, cfg, prompts, streams):
     return _pattern_reference_gaps(jamba_ref, params, cfg, prompts, streams)
 
 
+def _longcat_reference_gaps(params, cfg, prompts, streams):
+    """As `_joyai_reference_gaps`, against the benchmark's plain float32
+    LongCat (benchmarks/reference/longcat_ref.py: both sub-blocks and the
+    shortcut a layer, every held expert for every token, the expanded
+    attention; no cache, no code of models/longcat.py)."""
+    from benchmarks.reference import longcat_ref
+
+    import dataclasses
+
+    ref = dataclasses.asdict(cfg)       # the reference reads them by name
+    top = {k: v for k, v in params.items() if not k.startswith("blk.")}
+    width = max(len(p) for p in prompts) + len(streams[0])
+    return longcat_ref.stream_gaps(
+        top, lambda i: longcat_ref.layer_of(params, ref, i), ref, prompts,
+        streams, width)
+
+
 def serve_phase(info: dict, cfg, decode_cfg, prompts, max_new: int,
                 logit_tol: float, model=None,
                 reference_gaps=_reference_gaps) -> dict:
@@ -595,6 +614,90 @@ def paged_attention_phase(info: dict, heads: int, head_dim: int,
     return info
 
 
+def longcat_experts_phase(info: dict, cfg, rows=(128, 512), layer: int = 1,
+                          tol: float = 0.03) -> dict:
+    """LongCat's expert path ALONE against the plain reference's, at the
+    widths of `cfg`: `moe.expert_mlp` as the serve programs call it (bf16,
+    the held experts' stacks of `layer + 1` layers addressed in place at
+    `layer`, under the scope `shortcut_experts`) for `rows` unit-normal
+    rows (a decode step's 128, the largest prefill bucket's 512) against
+    `longcat_ref.experts` in float32 on the SAME bf16-rounded weights and
+    rows, so that both routers see the same logits. What is compared is
+    the HELD experts' part by itself: the program's `m` less the
+    reference's `m` with the held term dropped, against the reference's
+    held term, row by row where a row has a pair on a held expert (a
+    relative L2 distance of `tol` at most: the matmuls round to bf16
+    twice), and the rows with none against the reference outright. The
+    cell's `correct` sees this term through four layers and a head; here
+    nothing stands between the grouped matmuls' tiles and the verdict."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.reference import longcat_ref
+    from paddle_tpu.models import longcat, moe
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+    names = ("blk.router", "blk.router_bias") + tuple(longcat._EXPERTS)
+    key = jax.random.key(SEED + 5)
+    make = jax.jit(lambda l: {
+        k: v.astype(jnp.bfloat16) if v.ndim >= 2 else v
+        for k, v in longcat.init_layer(key, cfg, l).items() if k in names})
+    made = [make(np.int32(l)) for l in range(layer + 1)]
+    served = dict(made[layer], **{
+        k: jnp.stack([lp[k] for lp in made]) for k in longcat._EXPERTS})
+    exact = {k: v.astype(jnp.float32) for k, v in made[layer].items()}
+    del made
+    model = {"n_experts": cfg.n_experts, "top_k": cfg.top_k,
+             "route_scale": cfg.route_scale,
+             "held": list(cfg.routing.held_range)}
+    first, past = model["held"]
+    run = jax.jit(lambda lp, y: moe.expert_mlp(
+        lp, y, cfg.routing, np.int32(layer), scope=longcat.SCOPE))
+    gmm.GATE_COUNTS.clear()
+    gmm.TILES.clear()
+    checked = {"rows": {}}
+    for n in rows:
+        y = jax.random.normal(jax.random.fold_in(key, n), (n, cfg.hidden),
+                              jnp.bfloat16)
+        got, stats = run(served, y)
+        with jax.default_matmul_precision("highest"):
+            y32 = y.astype(jnp.float32)
+            w = np.asarray(longcat_ref.route(exact, y32, model))
+            whole = np.asarray(longcat_ref.experts(exact, y32, model))
+            bare = np.asarray(longcat_ref.experts(
+                exact, y32, dict(model, held_term=False)))
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all()
+        picked = w > 0
+        assert int(stats["held_pairs"]) == picked[:, first:past].sum()
+        assert int(stats["zero_pairs"]) == picked[:, cfg.n_experts:].sum()
+        held = picked[:, first:past].any(-1)
+        assert held.sum() >= n // 8, "too few rows reach a held expert"
+        term = (whole - bare)[held]
+        norm = np.linalg.norm(term, axis=-1)
+        away = np.linalg.norm((got - bare)[held] - term, axis=-1) / norm
+        # a row with no held pair: the zero experts' scale alone, rounded
+        rest = np.abs(got - whole)[~held].max() if (~held).any() else 0.0
+        assert away.max() <= tol, (
+            f"the held experts' term leaves the reference's by "
+            f"{away.max():.4f} of its norm at {n} rows (tolerance {tol})")
+        assert rest <= tol * np.abs(whole).max(), rest
+        checked["rows"][str(n)] = {
+            "held_rows": int(held.sum()),
+            "held_pairs": int(stats["held_pairs"]),
+            "experts_hit": int(stats["experts_hit"]),
+            "held_term_rms": float(f"{np.sqrt((term ** 2).mean()):.3g}"),
+            "m_rms": float(f"{np.sqrt((whole ** 2).mean()):.3g}"),
+            "max_rel_l2": float(f"{away.max():.3g}"),
+            "other_rows_max_abs": float(f"{rest:.3g}")}
+    checked.update(
+        tol=tol, routes=dict(gmm.GATE_COUNTS),
+        tiles={f"{k}x{n}": list(t) for (k, n), t in sorted(gmm.TILES.items())})
+    info.update(checked=checked)
+    return info
+
+
 def sharded_phase(info: dict, cfg, batch_size: int, seq_len: int,
                   devices, steps: int = 5, rel_tol: float = 1e-2) -> dict:
     """BERT under make_mesh(dp=-1, tp=2) over `devices` against the same
@@ -648,8 +751,8 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import (bert, gpt, jamba, joyai, nemotron_h, olmoe,
-                                   xing4)
+    from paddle_tpu.models import (bert, gpt, jamba, joyai, longcat,
+                                   nemotron_h, olmoe, xing4)
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
 
@@ -842,6 +945,50 @@ def run_one_chip() -> None:
             and checked["state"]["used"] == 0, info
         # one attention layer: K and V, three prefill programs
         assert checked["prefill_write"] == {"blocks": 6}, info
+
+    # LongCat-Flash-Chat at its published widths (a layer of TWO latent
+    # attention sub-layers of 64 heads and two dense SwiGLUs of 12288, the
+    # expert path a shortcut across the second; a router over 768 outputs,
+    # 256 of them zero-compute, top-12), two layers = four cache layers
+    # with 2 of the 512 routed experts held and an eighth of the
+    # vocabulary, so that the float32 set for the reference (5.7 GB) sits
+    # beside the served one: the latent kernel at 64 heads, the grouped
+    # matmuls at 6144 x 2048 over a share of the experts
+    lcfg = longcat.LongcatConfig(layers=2, vocab_size=16384, held=(0, 2),
+                                 max_len=1024)
+    prompts = [rng.randint(0, lcfg.vocab_size, n).tolist()
+               for n in (12, 200, 5, 64, 40, 250, 129)]
+    with phase("serve_longcat") as info:
+        serve_phase(info, lcfg, DecodeConfig(
+            block_size=16, num_blocks=16 * 64 + 1, decode_slots=(16,),
+            prefill_buckets=(64, 128, 256)), prompts, max_new=24,
+            logit_tol=LONGCAT_LOGIT_TOL, model=longcat,
+            reference_gaps=_longcat_reference_gaps)
+        checked = info["checked"]
+        assert checked["decode_attention"] == {"paged_latent": 1}, info
+        # the held experts of the three prefill programs through the
+        # megablox kernel, their columns whole (the decode program's 16
+        # slots x 12 picks are 192 rows, not whole row tiles: `ragged_dot`)
+        assert checked["expert_matmul"]["tiles"] == {
+            "6144x2048": [128, 768, 2048],
+            "2048x6144": [128, 256, 6144]}, info
+        # two sub-blocks a layer body, `c` and the rotary key each, three
+        # prefill programs
+        assert checked["prefill_write"] == {"blocks": 12}, info
+
+    # the expert path alone at the cell's widths and share (16 of 512
+    # held, 768 router outputs, top-12) against the reference's, the held
+    # experts' term by itself: what the cell's `correct` sees only through
+    # four layers and a head
+    with phase("longcat_experts") as info:
+        longcat_experts_phase(info, longcat.LongcatConfig(
+            layers=2, vocab_size=16384, held=(0, 16), max_len=1024))
+        checked = info["checked"]
+        # three matmuls a call, both row counts through the kernel
+        assert checked["routes"] == {"megablox": 6}, info
+        assert checked["tiles"] == {
+            "6144x2048": [128, 768, 2048],
+            "2048x6144": [128, 256, 6144]}, info
 
     # the kernel against the gather path where it runs, at the benchmark's
     # two widths: GPT-2-large's 20 heads of 64, OLMoE's 16 of 128

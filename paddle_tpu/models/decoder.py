@@ -80,6 +80,15 @@ class ServeModel:
     # tokens a slice of the prefill program's walk over a prompt
     # (`prefill_sliced`); None: a prompt goes through in one pass
     prompt_slice: Optional[int] = None
+    # attention-then-`mlp` SUB-BLOCKS a layer, each with a cache layer of
+    # its own (`serve_layers`, `sub_params`): a layer of two attention
+    # sub-layers stores `2 x layers` layers of K/V
+    sub_blocks: int = 1
+    # the whole-prompt prefill program returns the layers' counters too
+    # (`prefill`: beside the pools, as `decode_step` returns them), for a
+    # model whose prompts' routing a step record should show; False: it
+    # returns what it always has
+    prefill_counters: bool = False
 
     @property
     def rated(self) -> Tuple[Tuple[int, int], ...]:
@@ -96,8 +105,8 @@ class ServeModel:
 
     @property
     def kv_layers(self) -> int:
-        """Layers of the K/V pools: the layers that hold attention."""
-        return self.layers
+        """Layers of the K/V pools: one for every attention sub-layer."""
+        return self.layers * self.sub_blocks
 
     def state_pools(self, rows: int, dtype) -> Tuple:
         """((shape, dtype), ...) of the pools of per-sequence state that is
@@ -123,6 +132,12 @@ class ServeModel:
         """The parameters of the layers that are alike, stacked on a
         leading axis."""
         raise NotImplementedError
+
+    def sub_params(self, lp: Params, j: int) -> Params:
+        """Sub-block `j`'s parameters out of one layer's `lp`, where a
+        layer holds several (`sub_blocks`); what every piece of that
+        sub-block is handed as its `lp`."""
+        return lp
 
     def embed(self, params: Params, ids, positions):
         raise NotImplementedError
@@ -322,8 +337,9 @@ class ServeModel:
         raise NotImplementedError
 
     def step_facts(self, stats) -> Dict:
-        """Fields for a decode step's record (`decode.steps`) from the
-        step's stacked counters, fetched to the host."""
+        """Fields for a decode step's record (`decode.steps`), or a
+        prompt's where `prefill_counters`, from the step's stacked
+        counters, fetched to the host."""
         return {}
 
 
@@ -428,29 +444,45 @@ def serve_layers(model: ServeModel, params: Params, x: jax.Array,
     model of one mixer a block goes through `mixer_layers`, which also
     carries `state`. `x` is the CARRIED state (`ServeModel.widen`), which
     every sub-layer reads and writes through the model's `res_in` /
-    `res_out`. Returns (x, k_pool, v_pool, the stacked layers' counters or
-    None, state, rated); where the leading layers count too,
-    `{"lead": [theirs], "stack": the stack's}`."""
+    `res_out`. A layer of several sub-blocks (`ServeModel.sub_blocks`) runs
+    them one after another inside the one body, sub-block `j` of layer `l`
+    on cache layer `l * sub_blocks + j`; what a row carries BETWEEN a
+    layer's sub-blocks is whatever that model's `res_out` hands its next
+    `res_in` (an expert path's result that waits for a later sub-block's
+    end: models/longcat.py), and only the last `res_out` of a layer must
+    give the carried state back as it came. Returns (x, k_pool, v_pool, the
+    stacked layers' counters or None, state, rated); where the leading
+    layers count too, `{"lead": [theirs], "stack": the stack's}`."""
     if model.pattern is not None:
         return mixer_layers(model, params, x, positions, k_pool, v_pool,
                             attend, state, ssm, rated)
 
+    subs = model.sub_blocks
+
     def layer_body(carry, per_layer):
         h, kp, vp = carry
-        lp, l = per_layer
-        u, kept_a = model.res_in(lp, h, "attn")
-        y = model.norm_attn(lp, u)
-        q, k, v = model.qkv(lp, y, positions)
-        ctx, kp, vp, _ = attend(l, lp, q, k, v, kp, vp, ())
-        h = model.res_out(lp, kept_a, ctx, "attn")
-        u, kept_m = model.res_in(lp, h, "mlp")
-        y = model.norm_mlp(lp, u)
-        out, stats = model.mlp(lp, y, params, l)
-        h = model.res_out(lp, kept_m, out, "mlp")
-        return (h, kp, vp), model.res_counters(stats, kept_a, kept_m)
+        layer, l = per_layer
+        stats, kept = None, []
+        for j in range(subs):
+            lp, at = (layer, l) if subs == 1 else \
+                (model.sub_params(layer, j), l * subs + j)
+            u, kept_a = model.res_in(lp, h, "attn")
+            y = model.norm_attn(lp, u)
+            q, k, v = model.qkv(lp, y, positions)
+            ctx, kp, vp, _ = attend(at, lp, q, k, v, kp, vp, ())
+            h = model.res_out(lp, kept_a, ctx, "attn")
+            u, kept_m = model.res_in(lp, h, "mlp")
+            y = model.norm_mlp(lp, u)
+            out, counted = model.mlp(lp, y, params, l)
+            h = model.res_out(lp, kept_m, out, "mlp")
+            if counted is not None:
+                assert stats is None, "two sub-blocks of a layer count"
+                stats = counted
+            kept += [kept_a, kept_m]
+        return (h, kp, vp), model.res_counters(stats, *kept)
 
     lead = model.lead_params(params)
-    layers = jnp.arange(len(lead), k_pool.shape[0], dtype=jnp.int32)
+    layers = jnp.arange(len(lead), k_pool.shape[0] // subs, dtype=jnp.int32)
     with jax.named_scope("layers"):
         carry = (x, k_pool, v_pool)
         lead_stats = []
@@ -483,7 +515,9 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
 
     ids [1, T] (edge-padded to the prefill bucket T), length = true
     prompt length, block_table [MB] (the sequence's row). Returns
-    (first sampled token [1], k_pool, v_pool). Padded tail positions
+    (first sampled token [1], k_pool, v_pool), and after the pools the
+    layers' stacked counters over ALL T rows of the bucket where the model
+    asks for them (`ServeModel.prefill_counters`). Padded tail positions
     write to the null block / soon-overwritten slots (see
     kv_cache.write_prefill_kv) and, being causally AFTER every real
     position, never contribute to the last real position's logits.
@@ -498,6 +532,7 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     from ..serving import kv_cache as kvc
 
     if model.prompt_slice:
+        assert not model.prefill_counters, "a sliced walk returns no counters"
         return prefill_sliced(model, params, ids, length, k_pool, v_pool,
                               block_table, state, row,
                               block_size=block_size, eos_id=eos_id)
@@ -520,15 +555,16 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     def ssm(i, lp, y, st):
         return model.ssm_prompt(lp, y, length, st, i, row)
 
-    x, k_pool, v_pool, _, state, _ = serve_layers(
+    x, k_pool, v_pool, stats, state, _ = serve_layers(
         model, params, x, positions, k_pool, v_pool, attend, state, ssm)
     # the final norm is per row: the last real position alone goes through
     last = jnp.maximum(length, 1) - 1
     tok = model.head(params, model.narrow(params, x[0, last][None]),
                      ids[0, last][None], eos_id)
+    counters = (stats,) if model.prefill_counters else ()
     if state:
-        return tok, k_pool, v_pool, state
-    return tok, k_pool, v_pool
+        return (tok, k_pool, v_pool) + counters + (state,)
+    return (tok, k_pool, v_pool) + counters
 
 
 def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,

@@ -113,6 +113,14 @@ class JoyaiConfig:
         return 1.0 / math.sqrt(self.qk_dim)
 
     @property
+    def lora_scales(self) -> Tuple[float, float]:
+        """What the compressed query and the compressed kv are multiplied
+        by AFTER their norms (a model that scales them by `sqrt(hidden /
+        rank)`: `models/longcat.py`'s `mla_scale_q_lora` /
+        `mla_scale_kv_lora`); 1 and 1 here: the norms' gains alone."""
+        return 1.0, 1.0
+
+    @property
     def routing(self) -> _moe.Routing:
         return _moe.Routing(self.n_experts, self.top_k, score="sigmoid",
                             bias=True, normalise=True,
@@ -305,15 +313,21 @@ def _qkv(lp, y, positions, cfg: JoyaiConfig):
     """(q `[..., heads*192]`, rotated; c `[..., 512]`, normalised; k_rope
     `[..., 64]`, rotated): the query and the two things a token stores."""
     lead = y.shape[:-1]
+    q_scale, kv_scale = cfg.lora_scales
+
+    def gains(name, by):    # a scale after a norm is a scale of its gains
+        g = lp[name]
+        return g if by == 1.0 else g.astype(jnp.float32) * by
+
     with jax.named_scope("mla_q"):
         cq = _rms(y @ lp["blk.wq_a"].astype(y.dtype),
-                  lp["blk.q_norm.scale"], cfg.rms_eps)
+                  gains("blk.q_norm.scale", q_scale), cfg.rms_eps)
         q = (cq @ lp["blk.wq_b"].astype(y.dtype)).reshape(
             lead + (cfg.heads, cfg.qk_dim))
     with jax.named_scope("mla_kv"):
         ckr = y @ lp["blk.wkv_a"].astype(y.dtype)
-        c = _rms(ckr[..., :cfg.kv_rank], lp["blk.kv_norm.scale"],
-                 cfg.rms_eps)
+        c = _rms(ckr[..., :cfg.kv_rank],
+                 gains("blk.kv_norm.scale", kv_scale), cfg.rms_eps)
     with jax.named_scope("rope"):
         inv = cfg.rope_inv_freq
         kr = _rope(ckr[..., cfg.kv_rank:], positions, cfg.rope_theta, inv)

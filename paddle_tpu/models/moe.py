@@ -1,7 +1,8 @@
 """The sparse expert layer of the mixture-of-experts decoders
-(models/olmoe.py, models/joyai.py, models/nemotron_h.py): dropless top-k
-routing over experts of the model's form, with the routing rule and an
-optional always-on expert given by the model.
+(models/olmoe.py, models/joyai.py, models/nemotron_h.py,
+models/longcat.py): dropless top-k routing over experts of the model's
+form, with the routing rule and an optional always-on expert given by the
+model.
 
 What the models share is everything after the router has spoken: the
 (row, expert) pairs sorted by expert, the projections as grouped matmuls
@@ -12,12 +13,28 @@ the top-k is taken of (the scores | the scores plus a per-expert
 correction bias, which selects and never weighs), whether the kept scores
 are normalised to sum to 1 and scaled, whether one more expert sees every
 row, and what an expert IS (`form`: three matrices, `down(silu(gate x) *
-up x)`, or two, `down(relu(up x)^2)`).
+up x)`, or two, `down(relu(up x)^2)`), and two things a router's output may
+be that is no matrix of this layer:
+
+- a ZERO-COMPUTE expert (`zero_experts`: the router's last outputs, after
+  the `n_experts` routed ones) returns its input: a pair on one adds
+  `weight * y` and reaches no matmul, so all of a row's zero experts
+  together cost one scale of `y`;
+- an expert NOT HELD here (`held`: the range of the routed ids whose
+  matrices this layer holds, a chip's share of a layer that an
+  expert-parallel deployment spreads over several): the router still scores
+  every output and picks among all of them, this layer computes its own
+  experts' part for the pairs routed to them and leaves out what the absent
+  experts would add. Nothing here stands in for the other shares or for the
+  exchange of rows between them (an all-to-all over an `ep` mesh axis:
+  ROADMAP M6); the shares' partial results, summed, are the whole layer
+  (tests/test_longcat.py).
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +49,13 @@ class Routing:
     layer holds one always-on expert (`blk.shared_gate`, `blk.shared_up`,
     `blk.shared_down`) whose result is added unweighted. `form`: an
     expert's matrices, routed or shared: "swiglu" (`w_gate`, `w_up`,
-    `w_down`) or "relu2" (`w_up`, `w_down`; no `*_gate` parameter)."""
+    `w_down`) or "relu2" (`w_up`, `w_down`; no `*_gate` parameter).
+    `zero_experts`: the router has that many outputs AFTER the `n_experts`
+    routed ones (`blk.router` and `blk.router_bias` are `n_experts +
+    zero_experts` wide) and a pair on one of them is the identity: it adds
+    `weight * y`. `held`: `(first, past the last)` of the routed ids whose
+    matrices the layer holds, `blk.w_*` then `[past - first, ...]`; a pair
+    on a routed expert outside it contributes nothing HERE. None: all."""
 
     n_experts: int
     top_k: int
@@ -42,6 +65,18 @@ class Routing:
     scale: float = 1.0
     shared: bool = False
     form: str = "swiglu"        # "swiglu" | "relu2"
+    zero_experts: int = 0
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
+
+    @property
+    def partial(self) -> bool:
+        """Some of the router's outputs are no matrix of this layer."""
+        first, past = self.held_range
+        return bool(self.zero_experts) or past - first != self.n_experts
 
 
 def route(logits: jax.Array, routing: Routing, bias=None):
@@ -80,8 +115,7 @@ def relu2_mlp(x, up, down):
     return relu2(x @ up.astype(x.dtype)) @ down.astype(x.dtype)
 
 
-@jax.named_scope("mlp")
-def expert_mlp(lp, y, routing: Routing, layer=None):
+def expert_mlp(lp, y, routing: Routing, layer=None, scope: str = "mlp"):
     """The sparse expert layer for the rows `y` [..., hidden]: dropless
     top_k routing. Every (row, chosen expert) pair is computed: the pairs
     are sorted by expert, the projections (three of a SwiGLU expert, two
@@ -102,10 +136,32 @@ def expert_mlp(lp, y, routing: Routing, layer=None):
     (OLMoE) to 2.4 GB (256 experts of 768) written and read again, more
     than a decode step reads of them at all.
 
+    Where some of the router's outputs are no matrix of this layer
+    (`routing.partial`: zero-compute experts, or a share `held` of the
+    routed ones, E then the number HELD), the pairs that reach no matrix
+    sort behind the last group: the grouped matmuls visit no tile for them
+    (their rows of the static `[n * top_k, ...]` shape come back unwritten
+    and are selected away, not multiplied by zero), a zero-compute pair
+    adds `weight * y` (`zero_experts`: one scale a row), and a pair on an
+    absent expert adds nothing.
+
+    `scope` is the layer scope all of this runs under: `mlp`, or a name of
+    its own for a model whose expert path runs BESIDE its dense MLPs
+    (models/longcat.py).
+
     Returns (out [..., hidden], {"experts_hit": experts with at least one
-    pair, "expert_load_max": most pairs on one expert}), the counters of
-    THIS layer and call."""
-    E, K = routing.n_experts, routing.top_k
+    pair, "expert_load_max": most pairs on one expert; where
+    `routing.partial`, both over the held experts, and `held_pairs`: pairs
+    on an expert held here, `zero_pairs`: pairs on a zero-compute expert,
+    `pairs`: all n * top_k}), the counters of THIS layer and call."""
+    with jax.named_scope(scope):
+        return _expert_mlp(lp, y, routing, layer)
+
+
+def _expert_mlp(lp, y, routing: Routing, layer):
+    K = routing.top_k
+    first, past = routing.held_range
+    E = past - first                    # the experts whose matrices are here
     if routing.form not in ("swiglu", "relu2"):
         raise ValueError(f"unknown expert form {routing.form!r}")
     gated = routing.form == "swiglu"
@@ -119,9 +175,17 @@ def expert_mlp(lp, y, routing: Routing, layer=None):
                          preferred_element_type=jnp.float32)
         weight, expert = route(logits, routing, lp.get("blk.router_bias"))
     with jax.named_scope("moe_route"):
+        picked = expert
         expert = expert.reshape(-1)                      # pair (row, k)
+        if routing.partial:
+            # a pair that reaches no matrix here goes to group E, behind
+            # the last expert's: sorted away, and dropped from the counts
+            here = (expert >= first) & (expert < past)
+            zero = picked >= routing.n_experts     # on a zero-compute expert
+            expert = jnp.where(here, expert - first, E)
         order = jnp.argsort(expert, stable=True)         # pairs by expert
-        counts = jnp.zeros((E,), jnp.int32).at[expert].add(1)
+        counts = jnp.zeros((E,), jnp.int32).at[expert].add(
+            1, mode="drop" if routing.partial else None)
         xs = x[order // K]                               # [n*K, hidden]
         groups = counts
         if layer is not None:
@@ -147,7 +211,13 @@ def expert_mlp(lp, y, routing: Routing, layer=None):
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(n * K, dtype=order.dtype))
         ys = ys[back].reshape(n, K, -1).astype(jnp.float32)
+        if routing.partial:
+            ys = jnp.where(here.reshape(n, K, 1), ys, 0.0)
         out = jnp.sum(ys * weight[..., None], axis=1)
+    if routing.zero_experts:
+        with jax.named_scope("zero_experts"):
+            identity = jnp.sum(jnp.where(zero, weight, 0.0), axis=-1)
+            out = out + identity[:, None] * x.astype(jnp.float32)
     if routing.shared:
         with jax.named_scope("shared_expert"):
             shared = swiglu(x, lp["blk.shared_gate"], lp["blk.shared_up"],
@@ -157,6 +227,11 @@ def expert_mlp(lp, y, routing: Routing, layer=None):
             out = out + shared.astype(jnp.float32)
     stats = {"experts_hit": jnp.sum(counts > 0).astype(jnp.int32),
              "expert_load_max": jnp.max(counts)}
+    if routing.partial:
+        stats.update(
+            held_pairs=jnp.sum(counts),
+            zero_pairs=jnp.sum(zero).astype(jnp.int32),
+            pairs=jnp.int32(n * K))
     return out.astype(y.dtype).reshape(y.shape), stats
 
 
@@ -165,6 +240,15 @@ def step_facts(stats) -> dict:
     `experts_hit`: distinct experts selected, summed over the layers (what
     a step must read of the expert weights); `expert_load_max`: most pairs
     on one expert in any layer. Both count every row of the step's batch,
-    idle slots included: the device computes them all."""
-    return {"experts_hit": int(stats["experts_hit"].sum()),
-            "expert_load_max": int(stats["expert_load_max"].max())}
+    idle slots included (and of a prompt's bucket, its padded rows
+    included): the device computes them all. Where the layers
+    are `Routing.partial`, both count the HELD experts, and beside them
+    `held_pairs` (pairs routed to an expert held here), `zero_pairs` (pairs
+    routed to a zero-compute expert) and `pairs` (the step's rows x top_k),
+    each summed over the layers."""
+    facts = {"experts_hit": int(stats["experts_hit"].sum()),
+             "expert_load_max": int(stats["expert_load_max"].max())}
+    for name in ("held_pairs", "zero_pairs", "pairs"):
+        if name in stats:
+            facts[name] = int(stats[name].sum())
+    return facts

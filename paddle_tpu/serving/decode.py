@@ -1539,12 +1539,14 @@ class DecodeEngine:
         return row
 
     def _note_step_stats(self, row: Dict, stats) -> None:
-        """Recording on: what the model counted in the decode step that
-        `row` records (an expert layer's `experts_hit`,
-        `expert_load_max`) joins the row, fetched once the step's
-        tokens are on the host."""
-        self._step_facts = self._model.step_facts(jax.device_get(stats))
-        row.update(self._step_facts)
+        """Recording on: what the model counted in the step that `row`
+        records (an expert layer's `experts_hit`, `expert_load_max`)
+        joins the row, fetched once the step's tokens are on the host;
+        `status()["step_facts"]` stays the newest DECODE step's."""
+        facts = self._model.step_facts(jax.device_get(stats))
+        if row["kind"] == "decode":
+            self._step_facts = facts
+        row.update(facts)
 
     def _kv_gauges(self):
         KV_BLOCKS.set(self._alloc.used_blocks(), state="used")
@@ -1697,13 +1699,16 @@ class DecodeEngine:
         if self._state_specs:
             state = (self._state, np.int32(req.state_row))
         t0 = time.perf_counter()
-        wait = None
+        wait = row = None
         if _tracing.recording:
-            self._step_record("prefill", t0, 1, 1, plen)
+            row = self._step_record("prefill", t0, 1, 1, plen)
             wait = _tracing.open_span("decode.prefill.wait", "decode")
         tok, kp, vp, *out = self._prefill[bucket](
             self.params, ids, np.int32(plen), kp, vp, bt, *state)
         self._pools = (kp, vp)
+        # what the model counted over the bucket's rows, where it counts
+        # its prompts (`ServeModel.prefill_counters`)
+        stats = out.pop(0) if self._model.prefill_counters else None
         if out:
             self._state = out[0]
         if wait is not None:
@@ -1722,7 +1727,12 @@ class DecodeEngine:
         req.pos = plen
         self._active.append(req)
         self._kv_gauges()
-        return _Pending(tok, None, [req], t0)
+        pending = _Pending(tok, None, [req], t0)
+        if row is not None and stats is not None:
+            for a in jax.tree_util.tree_leaves(stats):
+                a.copy_to_host_async()
+            pending.stats = (row, stats)
+        return pending
 
     def _grow_blocks(self) -> None:
         """Ensure every active slot owns the block its next write

@@ -287,8 +287,10 @@ class Programs:
         bt = jnp.asarray(self.table if blocks is None
                          else table(blocks, self.width))
         extra = (cache.state, jnp.int32(row)) if cache.state else ()
-        out = run(params, self._padded(ids, self.family.bucket),
-                  jnp.int32(len(ids)), cache.k, cache.v, bt, *extra)
+        out = list(run(params, self._padded(ids, self.family.bucket),
+                       jnp.int32(len(ids)), cache.k, cache.v, bt, *extra))
+        if self.sm.prefill_counters:    # the prompt's, after the pools
+            del out[3]
         return np.asarray(out[0])[0], Cache(*out[1:])
 
     def chunks(self, ids, cache: Cache):

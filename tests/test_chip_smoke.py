@@ -236,6 +236,49 @@ def test_rehearse_serve_jamba(smoke):
     assert checked["state"]["bytes"] > 0
 
 
+def test_rehearse_serve_longcat(smoke):
+    """The serve_longcat phase at a tiny size: two attention sub-layers a
+    layer over four cache layers, the expert path a shortcut with
+    zero-compute experts and a share of the routed ones, through the same
+    engine and front, the tokens against the benchmark's plain reference
+    (off the chip the gates take the gathered form and `ragged_dot`)."""
+    from paddle_tpu.models import longcat
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = longcat.LongcatConfig.tiny()
+    cfg.dtype = "float32"
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 15, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(8, 16), max_len=32), prompts, max_new=4,
+        logit_tol=smoke.LONGCAT_LOGIT_TOL, model=longcat,
+        reference_gaps=smoke._longcat_reference_gaps)
+    checked = info["checked"]
+    assert checked["finished"]["length"] == 3
+    assert checked["compiles_after_warmup"] == 0
+    assert checked["decode_attention"] == {"gather": 1}
+    # one expert path a layer body, three matmuls, in three programs
+    assert checked["expert_matmul"] == {"routes": {"xla": 9}, "tiles": {}}
+    assert checked["state"] is None
+
+
+def test_rehearse_longcat_experts(smoke):
+    """The longcat_experts phase at a tiny size: the held experts' term of
+    `moe.expert_mlp` (stacks of two layers addressed at the second) against
+    the plain reference's, by itself (off the chip `ragged_dot`)."""
+    from paddle_tpu.models import longcat
+
+    cfg = longcat.LongcatConfig.tiny()
+    info = smoke.longcat_experts_phase({}, cfg, rows=(24, 64))
+    checked = info["checked"]
+    assert checked["routes"] == {"xla": 6} and checked["tiles"] == {}
+    for n in ("24", "64"):
+        assert checked["rows"][n]["held_rows"] >= int(n) // 8
+        assert checked["rows"][n]["max_rel_l2"] <= checked["tol"]
+
+
 @pytest.mark.parametrize("heads,head_dim", [(20, 64), (16, 128)])
 def test_rehearse_paged_attention(smoke, heads, head_dim):
     """The paged_attention phase at the benchmark's two widths, small

@@ -1331,6 +1331,102 @@ def test_jamba_serve_program_fits_and_updates_the_state_in_place(
         assert not held, held[:3]
 
 
+_LONGCAT_SLOTS, _LONGCAT_CONTEXT = 128, 1024
+
+
+@pytest.fixture(scope="module")
+def longcat_4l(v5e):
+    from paddle_tpu.models import longcat
+
+    cfg, params, pools, _, kv, sds = _described(
+        v5e, longcat, longcat.LongcatConfig(
+            layers=4, vocab_size=16384, held=(0, 16),
+            max_len=_LONGCAT_CONTEXT), _LONGCAT_SLOTS, _LONGCAT_CONTEXT)
+    return cfg, params, pools, kv, sds
+
+
+@pytest.mark.parametrize("program", ["decode@128", "prefill@512"])
+def test_longcat_serve_program_fits_its_share_and_both_paths(
+        longcat_4l, program, monkeypatch):
+    """LongCat-Flash-Chat as `longcat_flash_chat.chat_closed` serves it: 4
+    layers of two latent-attention sub-layers (8 cache layers), both dense
+    MLPs of 12288, the router over 768 outputs and the 16 experts this
+    chip holds of 512, an eighth of the vocabulary: 10.35 GB of weights
+    and 1.34 GB of latent cache, donated and written where they lie. The
+    latent kernel walks 64 heads a slot (twice the widest before), the
+    grouped matmuls run at 6144 x 2048 and 2048 x 6144 over the held
+    experts' stacks in place."""
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, kv, sds = longcat_4l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _LONGCAT_CONTEXT // _BLOCK
+    for counts in (gm.GATE_COUNTS, gm.TILES, PA.GATE_COUNTS, A.GATE_COUNTS,
+                   kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4)).lower(params, *args).compile()
+    assert kv.pool_shapes == ((8, 8193, 16, 512), (8, 8193, 16, 128))
+    assert kv.bytes_per_token() == 1280     # a cache layer; 8 of them
+    weights = sum(int(np.prod(v.shape)) * 2 for v in params.values())
+    assert weights == pytest.approx(10.345e9, rel=1e-3)
+    assert params["blk.w_up"].shape == (4, 16, 6144, 2048)
+    assert params["blk.router"].shape == (4, 6144, 768)
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    # weights 10.35 GB + pools 1.34 GB resident, the rest temporaries: 14
+    # MB of a step's, 0.31 GB of a 512-token prompt's (6144 pairs a layer)
+    assert 11.65e9 < planned < 12.05e9, ma
+    assert ma.temp_size_in_bytes < (0.03e9 if kind == "decode" else 0.35e9), ma
+    assert ma.alias_size_in_bytes >= kv.pool_bytes(), ma
+    text = compiled.as_text()
+    for pool in pools:
+        assert not _pool_movers(text, pool.shape)
+    # no op makes a layer's slice of an expert stack (and a step's 14 MB of
+    # temporaries hold no copy of a dense matrix, 151 MB, either: a layer's
+    # slice of each is read inside the fusion that multiplies by it)
+    slices = re.findall(r"= \(?bf16\[16,(?:6144,2048|2048,6144)\]", text)
+    assert not slices, slices[:3]
+    # the held experts' grouped matmuls are the megablox kernel, under the
+    # expert path's own scope and not under `mlp`; whole columns, a tile
+    # of 3.1 MB (`grouped_matmul.tiles`)
+    assert gm.GATE_COUNTS == {"megablox": 3}, gm.GATE_COUNTS
+    assert gm.TILES == {(6144, 2048): (128, 768, 2048),
+                        (2048, 6144): (128, 256, 6144)}, gm.TILES
+    kernels = _kernels(text)
+    assert sum("/shortcut_experts/experts/" in k for k in kernels) == 3 \
+        and not any("/mlp/" in k for k in kernels), kernels
+    if kind == "decode":
+        # the gate admits 64 heads (4 sublane tiles of bf16) and Mosaic
+        # takes the kernel's VMEM at them: one kernel a sub-block
+        assert PA.GATE_COUNTS == {"paged_latent": 1}, PA.GATE_COUNTS
+        latent = [k for k in kernels if "paged_latent_attention" in k]
+        assert len(latent) == 2 and all("/attention/" in k for k in latent)
+        # (a slot's context gathered, 134 MB a cache layer, would not fit
+        # the 14 MB of temporaries above)
+    else:
+        assert kvc.PREFILL_WRITE_UNITS == {"blocks": 4}
+        assert sum("kv_block_write" in k for k in kernels) == 4, kernels
+        for pool in pools:
+            assert not _pool_scatters(text, pool.shape)
+
+
 # -- the BERT-base train step (bert_base.pretrain128's shapes) ---------------
 
 _BERT_BATCH, _BERT_SEQ, _BERT_MASKED = 256, 128, 20
