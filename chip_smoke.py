@@ -614,6 +614,59 @@ def paged_attention_phase(info: dict, heads: int, head_dim: int,
     return info
 
 
+def short_attention_phase(info: dict, batch: int = 256, seq: int = 128,
+                          heads: int = 12, head_dim: int = 64,
+                          tol: float = 0.01, interpret=False) -> dict:
+    """The fused short-sequence attention kernel (`attention._short_mha`,
+    BERT's route since PR 54) against `_xla_mha` in FLOAT32 on one device,
+    at the training cells' shape: unit-normal bf16 q, k, v and cotangent;
+    the context and all three gradients by relative L2 norm. `tol` 0.01:
+    the kernel rounds q, k, v, the probabilities and dS to bf16 once each
+    (2^-9 relative a rounding; on the chip the context reads 0.17% and the
+    gradients 0.06-0.07%, PERF.md section 6, PR 54) and the XLA route in
+    bf16, which also rounds its scores, stands at 0.45% from the same
+    reference; a lost head, a wrong scale or a missing term of dS reads
+    10% and more. `interpret` is for the rehearsal off the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import attention
+
+    shape = (batch, seq, heads, head_dim)
+    q, k, v, ct = (jax.random.normal(key, shape, jnp.bfloat16)
+                   for key in jax.random.split(jax.random.key(SEED + 5), 4))
+    scale = head_dim ** -0.5
+
+    def both(attend, dtype):
+        def loss(q, k, v):
+            out = attend(q.astype(dtype), k.astype(dtype), v.astype(dtype))
+            return (out * ct).astype(jnp.float32).sum(), out
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+
+    short = both(lambda q, k, v: attention._short_mha(
+        q, k, v, scale, interpret=interpret), jnp.bfloat16)
+    ((_, got), got_g), compile_s = _timed(lambda: short(q, k, v))
+    _, run_s = _timed(lambda: short(q, k, v))
+    (_, want), want_g = both(lambda q, k, v: attention._xla_mha(
+        q, k, v, None, scale), jnp.float32)(q, k, v)
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    diffs = {name: rel(a, b) for name, a, b in zip(
+        ("context", "dq", "dk", "dv"), (got, *got_g), (want, *want_g))}
+    assert all(d <= tol for d in diffs.values()), (
+        f"the short kernel leaves float32 attention by {diffs} "
+        f"(tolerance {tol}) at {shape}")
+    info.update(compile_s=round(compile_s, 2), run_s=round(run_s, 4),
+                checked={"shape": list(shape), "tol": tol,
+                         "rel_l2": {k: float(f"{d:.3g}")
+                                    for k, d in diffs.items()},
+                         "fwd_bwd_ms": round(1e3 * run_s, 3)})
+    return info
+
+
 def longcat_experts_phase(info: dict, cfg, rows=(128, 512), layer: int = 1,
                           tol: float = 0.03) -> dict:
     """LongCat's expert path ALONE against the plain reference's, at the
@@ -989,6 +1042,11 @@ def run_one_chip() -> None:
         assert checked["tiles"] == {
             "6144x2048": [128, 768, 2048],
             "2048x6144": [128, 256, 6144]}, info
+
+    # Mosaic is not run by the CPU tests: the short kernel alone at the
+    # training cells' shape against float32 attention
+    with phase("short_attention") as info:
+        short_attention_phase(info)
 
     # the kernel against the gather path where it runs, at the benchmark's
     # two widths: GPT-2-large's 20 heads of 64, OLMoE's 16 of 128

@@ -5,8 +5,12 @@ op zoo (matmul/softmax/layer_norm + Adam; inference/tests/api/
 analyzer_bert_tester.cc exercises the graph). Rebuilt TPU-first:
 
 - bf16 activations, fp32 params/LN stats → MXU-friendly
-- attention as one fused einsum chain; Pallas flash-attention kernel is used
-  when available (ops/pallas), falling back to the XLA softmax path
+- attention through ops/pallas/attention.mha, whose gate picks the route
+  from the shape: on a TPU an unmasked sequence of 128, 256 or 512 tokens
+  (the pretraining cells' T = 128) goes through the fused short-sequence
+  kernel, forward and one-pass backward, with no [B, heads, T, T] buffer in
+  HBM; T >= 1024 through splash; a padding mask, any other length and
+  every run off the chip through the XLA einsum + softmax path
 - logical sharding axes: batch→dp, seq→sp, heads/mlp/vocab→tp — megatron TP
   + sequence parallelism come from the rule table, no model change
   (parallel/sharding.py)

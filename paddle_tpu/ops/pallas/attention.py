@@ -3,15 +3,26 @@
 Reference: no TPU counterpart — the reference computes attention from
 unfused matmul/softmax ops (e.g. the BERT graph in
 inference/tests/api/analyzer_bert_tester.cc). TPU-native: the gate
-(_use_splash / _multichip_splash_route) picks a Pallas
+(_use_short / _use_splash / _multichip_splash_route) picks a Pallas
 kernel route or the XLA einsum+softmax path from the shape, the mesh,
 the platform and FLAGS_flash_attention — and the pick is final: a
 selected kernel that fails to trace or compile raises, it is never
-replaced by another path. The f32 XLA path is semantically identical to
-the kernels, so tests run on CPU; for bf16 inputs it stores the T x T
-logits in bf16 (f32-accumulated, f32 softmax — halves score-buffer HBM
-traffic; see PROFILE.md), which rounds logits to bf16 precision relative
-to the kernel's f32 score pipeline.
+replaced by another path. Three routes:
+
+- _short_mha (since PR 54; BERT's route: T = 128, no mask, not causal):
+  short bidirectional sequences, T in _SHORT_T. One kernel forward and one
+  backward over [B, T, heads*head_dim] as it lies; a grid step holds whole
+  sequences with all their heads, the [T, T] scores live in VMEM in f32,
+  and the backward is one pass (no dq / dkv split). Nothing of shape
+  [., T, T] or [B, N, T, H] reaches HBM.
+- _splash_mha: long (T >= _SPLASH_MIN_T) or causal sequences, the splash
+  kernel shipped with jax, blocked over T, heads-major operands.
+- _xla_mha: everything else (additive masks, odd shapes, off the chip,
+  FLAGS_flash_attention=off). The f32 XLA path is semantically identical to
+  the kernels, so tests run on CPU; for bf16 inputs it stores the T x T
+  logits in bf16 (f32-accumulated, f32 softmax — halves score-buffer HBM
+  traffic; see PROFILE.md), which rounds logits to bf16 precision relative
+  to the kernels' f32 score pipeline.
 """
 
 from __future__ import annotations
@@ -23,9 +34,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Trace-time gate observability: which attention path was selected.
-# Keys: "splash" (single-device / manual region), "splash_shardmap"
+# Keys: "short" (the short kernel, single-device / manual region),
+# "short_shardmap" (the same under the dp/tp shard_map wrapper), "splash"
+# (single-device / manual region), "splash_shardmap"
 # (dp/tp shard_map wrapper), "ring_splash" (sp ring with splash blocks),
 # "ring_xla" (sp ring, XLA blocks),
 # "xla". Incremented once per mha() trace; reset with GATE_COUNTS.clear()
@@ -123,7 +138,11 @@ def _multichip_splash_route(q, k, mask, causal):
       static per trace and cannot track the rotating KV block's
       diagonal.
 
-    Returns None (no reroute), "shardmap", "ring", or "ring_xla".
+    A _short_shape takes the first composition with _short_mha as its
+    kernel ("shardmap_short"), at any length the single-device gate admits.
+
+    Returns None (no reroute), "shardmap", "shardmap_short", "ring", or
+    "ring_xla".
     """
     from paddle_tpu.parallel.mesh import current_mesh
     from paddle_tpu.parallel.sharding import current_rules, in_manual_region
@@ -137,7 +156,9 @@ def _multichip_splash_route(q, k, mask, causal):
     force = mode == "splash"
     if _platform(q) != "tpu" and not force:
         return None  # interpret-mode execution is explicit opt-in
-    if not (force or (mode == "auto" and q.shape[1] >= _SPLASH_MIN_T)):
+    splash = force or (mode == "auto" and q.shape[1] >= _SPLASH_MIN_T)
+    short = mode in ("auto", "splash") and _short_shape(q, k, mask, causal)
+    if not (splash or short):
         return None
     rules = current_rules()
 
@@ -150,7 +171,7 @@ def _multichip_splash_route(q, k, mask, causal):
     Tk = k.shape[1]
     sp = _size(s_ax)
     if sp > 1:
-        if T % sp or Tk != T:
+        if T % sp or Tk != T or not splash:
             return None
         if causal or (T // sp) % 128 or H % 64 or B % _size(b_ax) \
                 or N % _size(h_ax):
@@ -160,15 +181,19 @@ def _multichip_splash_route(q, k, mask, causal):
         return None  # replicated: the plain paths handle it
     if B % _size(b_ax) or N % _size(h_ax) or T % 128 or Tk % 128 or H % 64:
         return None
-    return "shardmap"
+    if short and (N // _size(h_ax) * H) % 128 == 0:
+        return "shardmap_short"  # a device's heads still fill whole tiles
+    return "shardmap" if splash else None
 
 
-def _shardmap_splash_mha(q, k, v, scale, causal, interpret):
-    """Splash composed with dp/tp: attention is independent across batch
+def _shardmap_splash_mha(q, k, v, scale, causal, interpret, kernel=None):
+    """A kernel route (`kernel`: _splash_mha, or _short_mha) composed with
+    dp/tp: attention is independent across batch
     and heads, so splitting those axes feeds the tuned kernel per-device
     local blocks with NO collectives. The region is manual over every
     mesh axis (_mesh_partitionable); q/k/v are replicated over the axes
     the spec does not name."""
+    kernel = kernel or _splash_mha
     from paddle_tpu.parallel.mesh import current_mesh
     from paddle_tpu.parallel.sharding import current_rules
 
@@ -187,7 +212,7 @@ def _shardmap_splash_mha(q, k, v, scale, causal, interpret):
                        out_specs=spec, axis_names=set(m.axis_names),
                        check_vma=False)
     def run(ql, kl, vl):
-        return _splash_mha(ql, kl, vl, scale, causal, interpret=interpret)
+        return kernel(ql, kl, vl, scale, causal, interpret=interpret)
 
     return run(q, k, v)
 
@@ -202,11 +227,21 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     interpret = _interpret_requested(q)
-    if _use_splash(q, k, mask, causal):
+    short = _short_shape(q, k, mask, causal)
+    if short and _use_short(q):
+        out = _short_mha(q, k, v, scale, interpret=interpret)
+        GATE_COUNTS["short"] += 1
+        return out
+    if not short and _use_splash(q, k, mask, causal):
         out = _splash_mha(q, k, v, scale, causal, interpret=interpret)
         GATE_COUNTS["splash"] += 1
         return out
     route = _multichip_splash_route(q, k, mask, causal)
+    if route == "shardmap_short":
+        out = _shardmap_splash_mha(q, k, v, scale, causal, interpret,
+                                   kernel=_short_mha)
+        GATE_COUNTS["short_shardmap"] += 1
+        return out
     if route == "shardmap":
         out = _shardmap_splash_mha(q, k, v, scale, causal, interpret)
         GATE_COUNTS["splash_shardmap"] += 1
@@ -346,3 +381,223 @@ def _splash_block_with_lse(q, k, v, interpret=False):
     vt = v.transpose(0, 2, 1, 3)
     out, (lse,) = jax.vmap(kernel)(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
+
+
+# ---------------------------------------------------------------------------
+# Short bidirectional attention: scores stay in VMEM, heads stay in the lanes
+# ---------------------------------------------------------------------------
+
+# Sequence lengths the short kernel takes: those timed on a v5e against
+# _xla_mha alone (fwd+bwd, bf16, 32768 tokens of 12 heads of 64, 12 calls
+# in one jit: 16.7 / 20.7 / 30.9 ms where XLA's take 43 / 58 / 93; PERF.md
+# section 6, PR 54).
+_SHORT_T = (128, 256, 512)
+# One grid step's block of an operand: one 128-lane tile of whole sequences,
+# up to this many bytes (8 sequences of 128 tokens in bf16).
+_SHORT_TILE_BYTES = 1 << 18
+_SHORT_VMEM_LIMIT = 64 << 20
+# Sequences a loop iteration of the kernel (_each_sequence): 1 / 2 / 4 / 8
+# read 24.5 / 21.3 / 16.7 / 14.8 ms for the 12 calls, and cost a boot 0.6 /
+# 0.9 / 2.0 / 3.7 s of lowering (the kernel's text grows with them).
+_SHORT_SEQS = 4
+
+_NT = (((1,), (1,)), ((), ()))  # a . b^T
+_TN = (((0,), (0,)), ((), ()))  # a^T . b
+
+
+def _short_shape(q, k, mask, causal) -> bool:
+    """What the short kernel can take, from shapes alone."""
+    if q.ndim != 4 or mask is not None or causal or q.shape != k.shape:
+        return False
+    _, T, N, H = q.shape
+    return T in _SHORT_T and H in (64, 128) and (N * H) % 128 == 0
+
+
+def _use_short(q) -> bool:
+    """A _short_shape goes to _short_mha on a TPU (or where
+    FLAGS_flash_attention=splash asks for the interpreter); `off` keeps the
+    XLA route."""
+    mode = _flag_mode()
+    if mode != "splash" and (mode != "auto" or _platform(q) != "tpu"):
+        return False
+    return _mesh_partitionable(q)
+
+
+def _short_tile(B: int, T: int, D: int, itemsize: int) -> int:
+    """Sequences a grid step: what _SHORT_TILE_BYTES hold of [T, D] rows,
+    a whole number of _each_sequence's loop iterations."""
+    Bt = max(1, min(B, _SHORT_TILE_BYTES // (T * D * itemsize)))
+    return Bt - Bt % min(Bt, _SHORT_SEQS)
+
+
+def _own_lanes(T: int, head_dim: int):
+    """For each head of a 128-lane tile, the lanes that hold it ([None]:
+    the tile is one head)."""
+    if head_dim == 128:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (T, 128), 1)
+    return [lane // head_dim == j for j in range(128 // head_dim)]
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _each_sequence(Bt: int, one):
+    """`one(b)` for every sequence of a block, _SHORT_SEQS of them a loop
+    iteration: independent chains in one basic block, which the scheduler
+    interleaves (the kernel is bound by a head's dependent chain, not by
+    its bytes: PERF.md section 6, PR 54)."""
+    seqs = min(Bt, _SHORT_SEQS)
+    assert Bt % seqs == 0, (Bt, seqs)
+
+    def body(i, carry):
+        for u in range(seqs):
+            one(i * seqs + u)
+        return carry
+
+    jax.lax.fori_loop(0, Bt // seqs, body, None)
+
+
+def _only(own, x):
+    return x if own is None else jnp.where(own, x, jnp.zeros_like(x))
+
+
+def _short_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+                      head_dim):
+    """One 128-lane tile (two 64-wide heads, or one of 128) of a block of
+    whole sequences. Scores are held TRANSPOSED, [Tk, Tq]: the softmax's
+    reductions run down the sublanes and the row statistics come out as
+    rows [1, Tq], which is how `lse` is stored. A head is taken out of its
+    tile by zeroing the other head's lanes of K and V (the MXU pass is
+    half filled either way), so the context is written a whole tile at a
+    time."""
+    Bt, T, _ = q_ref.shape
+    owns = _own_lanes(T, head_dim)
+
+    def one(b):
+        q, k, v = q_ref[b], k_ref[b], v_ref[b]
+        ctx = None
+        for j, own in enumerate(owns):
+            st = _dot(_only(own, k), q, _NT) * scale            # [Tk, Tq]
+            m = jnp.max(st, axis=0, keepdims=True)
+            e = jnp.exp(st - m)
+            l = jnp.sum(e, axis=0, keepdims=True)
+            pt = (e * (1.0 / l)).astype(v.dtype)
+            c = _dot(pt, _only(own, v), _TN)                    # [Tq, 128]
+            ctx = c if ctx is None else ctx + c
+            lse_ref[b, 0, pl.ds(j, 1), :] = m + jnp.log(l)
+        o_ref[b] = ctx.astype(o_ref.dtype)
+
+    _each_sequence(Bt, one)
+
+
+def _short_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, *, scale, head_dim):
+    """The whole backward of a tile's heads in one grid step: T fits, so
+    there is no dq / dkv split and nothing accumulates across steps.
+    Transposed scores as in the forward; delta = rowsum(P * dP), which
+    equals rowsum(dO * O) and wants no read of the context."""
+    Bt, T, _ = q_ref.shape
+    owns = _own_lanes(T, head_dim)
+
+    def one(b):
+        q, k, v, do = q_ref[b], k_ref[b], v_ref[b], do_ref[b]
+        dq = dk = dv = None
+        for j, own in enumerate(owns):
+            qm, km, dom = _only(own, q), _only(own, k), _only(own, do)
+            lse = lse_ref[b, 0, pl.ds(j, 1), :]                 # [1, Tq]
+            pt = jnp.exp(_dot(km, q, _NT) * scale - lse)        # [Tk, Tq]
+            dpt = _dot(v, dom, _NT)                             # [Tk, Tq]
+            delta = jnp.sum(pt * dpt, axis=0, keepdims=True)
+            dst = (pt * (dpt - delta) * scale).astype(q.dtype)
+            dv_j = _dot(pt.astype(do.dtype), dom)               # [Tk, 128]
+            dk_j = _dot(dst, qm)                                # [Tk, 128]
+            dq_j = _dot(dst, km, _TN)                           # [Tq, 128]
+            dq = dq_j if dq is None else dq + dq_j
+            dk = dk_j if dk is None else dk + dk_j
+            dv = dv_j if dv is None else dv + dv_j
+        dq_ref[b] = dq.astype(dq_ref.dtype)
+        dk_ref[b] = dk.astype(dk_ref.dtype)
+        dv_ref[b] = dv.astype(dv_ref.dtype)
+
+    _each_sequence(Bt, one)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _short_call(operands, lse, heads, scale, interpret):
+    """One pallas_call over (blocks of whole sequences) x (128-lane tiles
+    of their heads). Forward (`lse` None): `operands` q, k, v -> the
+    context and `lse` [B, tiles, heads a tile, T]; backward: q, k, v, dO
+    and `lse` -> dq, dk, dv; every other array [B, T, heads*head_dim].
+    The tile is the grid's, not the kernel's, so the kernel's text is one
+    tile's; and the call is jitted, so that a model's layers, which make
+    the same call, share ONE trace of the kernel and one lowering (12
+    layers x 2 kernels, each with all six tiles in its text, were 28 s of
+    a boot's lowering)."""
+    forward = lse is None
+    kernel, name, n_out, passes = (
+        (_short_fwd_kernel, "short_mha_fwd", 1, 2) if forward
+        else (_short_bwd_kernel, "short_mha_bwd", 3, 5))
+    B, T, D = operands[0].shape
+    dtype = operands[0].dtype
+    tiles, per = D // 128, heads * 128 // D
+    Bt = _short_tile(B, T, 128, dtype.itemsize)
+    rows = pl.BlockSpec((Bt, T, 128), lambda i, t: (i, 0, t))
+    stats = pl.BlockSpec((Bt, 1, per, T), lambda i, t: (i, t, 0, 0))
+    out_shape = [jax.ShapeDtypeStruct((B, T, D), dtype)] * n_out
+    out_specs = [rows] * n_out
+    in_specs = [rows] * len(operands)
+    if forward:
+        out_shape.append(
+            jax.ShapeDtypeStruct((B, tiles, per, T), jnp.float32))
+        out_specs.append(stats)
+    else:
+        operands = (*operands, lse)
+        in_specs.append(stats)
+    moved = (len(in_specs) + len(out_specs)) * B * T * D * dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale, head_dim=D // heads),
+        grid=(pl.cdiv(B, Bt), tiles), in_specs=in_specs,
+        out_specs=out_specs, out_shape=out_shape, name=name,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_SHORT_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=passes * 2 * B * heads * T * T * (D // heads),
+            transcendentals=B * heads * T * T, bytes_accessed=moved),
+    )(*operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _short_attention(q, k, v, heads, scale, interpret):
+    return _short_attention_fwd(q, k, v, heads, scale, interpret)[0]
+
+
+def _short_attention_fwd(q, k, v, heads, scale, interpret):
+    out, lse = _short_call((q, k, v), None, heads, scale, interpret)
+    return out, (q, k, v, lse)
+
+
+def _short_attention_bwd(heads, scale, interpret, res, dout):
+    q, k, v, lse = res
+    return tuple(_short_call((q, k, v, dout.astype(q.dtype)), lse, heads,
+                             scale, interpret))
+
+
+_short_attention.defvjp(_short_attention_fwd, _short_attention_bwd)
+
+
+def _short_mha(q, k, v, scale, causal=False, interpret=False):
+    """[B,T,N,H] short bidirectional attention through the fused kernel:
+    operands go in as [B, T, N*H], as they lie (the reshape is free), and
+    the context and all three gradients come back the same way; nothing of
+    shape [., T, T] or [B, N, T, H] reaches HBM, forward or backward."""
+    assert not causal
+    B, T, N, H = q.shape
+    flat = (B, T, N * H)
+    out = _short_attention(q.reshape(flat), k.reshape(flat), v.reshape(flat),
+                           N, float(scale), bool(interpret))
+    return out.reshape(q.shape)

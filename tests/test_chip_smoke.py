@@ -279,6 +279,18 @@ def test_rehearse_longcat_experts(smoke):
         assert checked["rows"][n]["max_rel_l2"] <= checked["tol"]
 
 
+@pytest.mark.parametrize("heads,head_dim", [(12, 64), (4, 128)])
+def test_rehearse_short_attention(smoke, heads, head_dim):
+    """The short_attention phase at a small batch, the kernel under the
+    Pallas interpreter: the same comparison and the same limit."""
+    info = smoke.short_attention_phase({}, batch=3, heads=heads,
+                                       head_dim=head_dim, interpret=True)
+    checked = info["checked"]
+    assert checked["shape"] == [3, 128, heads, head_dim]
+    assert set(checked["rel_l2"]) == {"context", "dq", "dk", "dv"}
+    assert all(0 < d <= checked["tol"] for d in checked["rel_l2"].values())
+
+
 @pytest.mark.parametrize("heads,head_dim", [(20, 64), (16, 128)])
 def test_rehearse_paged_attention(smoke, heads, head_dim):
     """The paged_attention phase at the benchmark's two widths, small
@@ -341,9 +353,12 @@ def test_cache_placement(monkeypatch, jax_cache_config, outside):
 # -- a selected kernel route that fails, raises ------------------------------
 
 
-def test_selected_kernel_failure_propagates(monkeypatch):
-    """mha() with the splash route selected and the kernel raising: the
-    error reaches the caller; _xla_mha is never tried in its place."""
+@pytest.mark.parametrize("kernel,causal", [("_splash_mha", True),
+                                           ("_short_mha", False)])
+def test_selected_kernel_failure_propagates(monkeypatch, kernel, causal):
+    """mha() with a kernel route selected (splash for a causal call, the
+    short kernel for BERT's) and the kernel raising: the error reaches the
+    caller; _xla_mha is never tried in its place."""
     from paddle_tpu.core.flags import set_flags
     from paddle_tpu.ops.pallas import attention as A
 
@@ -353,13 +368,13 @@ def test_selected_kernel_failure_propagates(monkeypatch):
     def never(*a, **k):
         raise AssertionError("fell through to the XLA path")
 
-    monkeypatch.setattr(A, "_splash_mha", boom)
+    monkeypatch.setattr(A, kernel, boom)
     monkeypatch.setattr(A, "_xla_mha", never)
     q = jax.numpy.ones((1, 128, 2, 64), jax.numpy.float32)
     set_flags({"FLAGS_flash_attention": "splash"})
     try:
         with pytest.raises(RuntimeError, match="kernel refused"):
-            A.mha(q, q, q)
+            A.mha(q, q, q, causal=causal)
     finally:
         set_flags({"FLAGS_flash_attention": "auto"})
 

@@ -48,6 +48,45 @@ def test_the_paged_kernel_sits_under_the_attention_scope(monkeypatch):
     assert "kv_gather" not in text
 
 
+def test_the_short_kernel_sits_under_the_attention_scope(monkeypatch):
+    """BERT's attention on a TPU is two Mosaic kernels a layer since PR 54
+    (`attention._short_mha`: forward, and the one-pass backward of its
+    custom vjp), each lowered ONCE as a function of its own
+    (`jit(_short_call)`) that every layer calls. Lowered for that platform
+    every call site carries `attention` (the backward as
+    `transpose(jvp(...))`), so a compiled kernel's `op_name`, call site
+    then kernel, falls under the scope that the benchmark's
+    `attention_share` reads (the compiled step's own names:
+    `tests/test_tpu_aot_compile.py`)."""
+    from benchmarks.harness import program_trace
+    from paddle_tpu.ops.pallas import attention as A
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg = bert.BertConfig(vocab_size=128, hidden=128, layers=2, heads=2,
+                          mlp_dim=128, max_len=128, dropout=0.0,
+                          dtype="bfloat16")
+    A.GATE_COUNTS.clear()
+    with jax.enable_x64(False):
+        params, _ = bert.init(jax.random.key(0), cfg)
+        ids = jnp.zeros((2, 128), jnp.int32)
+        text = jax.jit(jax.grad(lambda p: bert.encode(p, cfg, ids).astype(
+            jnp.float32).sum())).trace(params).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert dict(A.GATE_COUNTS) == {"short": cfg.layers}
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    kernels = [locs[c] for c in re.findall(
+        r"@tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
+    assert sorted(kernels) == ["short_mha_bwd/pallas_call",
+                               "short_mha_fwd/pallas_call"], kernels
+    sites = [locs[c] for c in re.findall(
+        r"call @_short_call.*loc\((#loc\d+)\)$", text, re.M)]
+    assert len(sites) == 2 * cfg.layers, sites
+    backward = [n for n in sites if "transpose(jvp(" in n]
+    assert len(backward) == cfg.layers, sites
+    assert {program_trace.scope_of(f"{site}/{kernel}")
+            for site in sites for kernel in kernels} == {"attention"}, sites
+
+
 def test_gpt_training_forward_carries_the_scopes():
     cfg = gpt.GPTConfig.tiny()
     cfg.dtype = "float32"

@@ -10,6 +10,8 @@ Routes under test (ops/pallas/attention.py _multichip_splash_route):
 - "ring_xla":  seq sharded, causal -> exact XLA-block ring (static splash
                masks cannot track the rotating block's diagonal)
 - single-device "splash" path must be unaffected (no regression).
+- since PR 54 the "shardmap" wrapper also carries the short kernel
+  (_short_mha) for short unmasked bidirectional shapes: "short_shardmap".
 """
 
 import math
@@ -51,15 +53,23 @@ def _ref(q, k, v, causal=False):
     return A._xla_mha(q, k, v, mask, scale)
 
 
-def test_shardmap_splash_dp_tp(rng):
-    """seq unsharded: splash under shard_map(batch, heads) — fwd+bwd
-    parity vs the XLA path and the gate counter proves the route ran."""
-    mesh = make_mesh(MeshConfig(dp=2, tp=2), devices=jax.devices()[:4])
-    q, k, v = _qkv(rng, 4, 256, 4, 64)
+@pytest.mark.parametrize("T,tp,counter", [
+    (384, 2, "splash_shardmap"),  # a length the short kernel does not take
+    (256, 2, "short_shardmap"),   # the short kernel, two heads a device
+    (128, 1, "short_shardmap"),   # dp alone, as bert_base.dp4 shards it
+])
+def test_shardmap_splash_dp_tp(rng, T, tp, counter):
+    """seq unsharded: the kernel under shard_map(batch, heads) — fwd+bwd
+    parity vs the XLA path and the gate counter proves the route ran. ONE
+    wrapper (_shardmap_splash_mha) carries splash and, since PR 54, the
+    short kernel for the lengths it takes."""
+    mesh = make_mesh(MeshConfig(dp=4 // tp, tp=tp), devices=jax.devices()[:4])
+    q, k, v = _qkv(rng, 4, T, 4, 64)
     with mesh_guard(mesh):
         out = jax.jit(lambda a, b, c: A.mha(a, b, c))(q, k, v)
         out.block_until_ready()
-    assert A.GATE_COUNTS["splash_shardmap"] >= 1, dict(A.GATE_COUNTS)
+    assert A.GATE_COUNTS[counter] >= 1, dict(A.GATE_COUNTS)
+    assert set(A.GATE_COUNTS) == {counter}, dict(A.GATE_COUNTS)
     np.testing.assert_allclose(np.asarray(out), np.asarray(_ref(q, k, v)),
                                atol=2e-5, rtol=2e-5)
     # backward composes too (splash ships a custom vjp)
@@ -138,15 +148,15 @@ def test_ring_splash_parity_T1024(rng):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_single_device_splash_unchanged(rng):
+@pytest.mark.parametrize("T,counter", [(384, "splash"), (256, "short")])
+def test_single_device_splash_unchanged(rng, T, counter):
     """No single-chip regression: a 1-device mesh still takes the plain
-    splash path (here via the interpreter), not a sharded wrapper."""
+    kernel path (here via the interpreter), not a sharded wrapper."""
     mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
-    q, k, v = _qkv(rng, 2, 256, 2, 64)
+    q, k, v = _qkv(rng, 2, T, 2, 64)
     with mesh_guard(mesh):
         out = jax.jit(lambda a, b, c: A.mha(a, b, c))(q, k, v)
         out.block_until_ready()
-    assert A.GATE_COUNTS["splash"] >= 1, dict(A.GATE_COUNTS)
-    assert A.GATE_COUNTS["splash_shardmap"] == 0
+    assert dict(A.GATE_COUNTS) == {counter: 1}
     np.testing.assert_allclose(np.asarray(out), np.asarray(_ref(q, k, v)),
                                atol=2e-5, rtol=2e-5)

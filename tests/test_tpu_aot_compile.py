@@ -31,6 +31,23 @@ from paddle_tpu.ops.pallas import attention as A
 from paddle_tpu.ops.pallas import fused_dense_bn as F
 
 
+@pytest.fixture(autouse=True)
+def _leave_no_gate_counts():
+    """The gates' counters are the PROCESS's: what a compile here counted
+    (routes answered for the described chip: `megablox`, `paged`, `short`)
+    is cleared when its test ends, so a file that runs after this one in
+    the same xdist worker reads its own traces alone
+    (`tests/test_olmoe.py` asserts that no `megablox` route was taken)."""
+    yield
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.ops.pallas import ssm_update as SU
+
+    for counts in (A.GATE_COUNTS, gm.GATE_COUNTS, gm.TILES, PA.GATE_COUNTS,
+                   SU.GATE_COUNTS):
+        counts.clear()
+
+
 @pytest.fixture(scope="module")
 def v5e():
     """The described 2x2 v5e; module-scoped because describing it loads
@@ -382,6 +399,51 @@ def test_multichip_route_compiles_for_v5e_2x2(v5e, tp, sp, counter):
     assert "tpu_custom_call" in text
     if sp > 1:
         assert "collective-permute" in text  # the ring's ppermute
+
+
+@pytest.mark.parametrize("B,T,N,H", [
+    (256, 128, 12, 64),   # bert_base.pretrain128's call
+    (128, 256, 12, 64), (64, 512, 12, 64),   # the other admitted lengths
+    (37, 128, 4, 128),    # one head a tile, a batch no tile divides
+])
+def test_short_attention_compiles_for_v5e(v5e, B, T, N, H):
+    """The short kernel, forward and its one-pass backward, at every length
+    the gate admits: Mosaic takes the transposed-operand products, the
+    masked head pairs and the single-row `lse` stores, inside the VMEM
+    limit the call sets."""
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((B, T, N, H), jnp.bfloat16, sharding=one)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda q, k, v: A._short_mha(q, k, v, H ** -0.5).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))).lower(x, x, x).compile()
+    text = compiled.as_text()
+    assert text.count("short_mha_fwd") >= 1 and "short_mha_bwd" in text
+    assert "[%d,%d,%d,%d]" % (B, N, T, T) not in text
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_short_attention_under_a_mesh_compiles_for_v5e_2x2(v5e, tp):
+    """`bert_base.dp4`'s call (dp = 4, 256 sequences a chip), and the same
+    under dp x tp: the gate wraps the short kernel in the shard_map region
+    splash already had, with no collective in forward or backward."""
+    from paddle_tpu.parallel import MeshConfig, make_mesh, mesh_guard
+
+    mesh = make_mesh(MeshConfig(dp=-1, tp=tp), devices=v5e)
+    qkv = jax.ShapeDtypeStruct(
+        (1024, 128, 12, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "tp", None)))
+    A.GATE_COUNTS.clear()
+    with mesh_guard(mesh):
+        compiled = jax.jit(jax.value_and_grad(
+            lambda q, k, v: A.mha(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))).lower(qkv, qkv, qkv).compile()
+    assert dict(A.GATE_COUNTS) == {"short_shardmap": 1}
+    text = compiled.as_text()
+    assert "short_mha_fwd" in text and "short_mha_bwd" in text
+    assert not re.search(r"all-gather|collective-permute|all-to-all", text)
+    # the one all-reduce is this test's own: the scalar it differentiates
+    assert all(re.search(r"= f32\[\][^ ]* all-reduce\(", ln) for ln in
+               text.splitlines() if " all-reduce(" in ln)
 
 
 # ---------------------------------------------------------------------------
@@ -1502,7 +1564,17 @@ def _threefry_evaluations(text, shape):
     return total // 21, len(held)
 
 
-def test_bert_base_train_step_evaluates_each_dropout_mask_once(v5e):
+@pytest.fixture(scope="module")
+def bert_step(v5e):
+    """`bert_base.pretrain128`'s step compiled ONCE for the described chip
+    (~70 s), with what the attention gate counted while it was traced."""
+    A.GATE_COUNTS.clear()
+    cfg, lowered = _described_train_step(v5e)
+    gate = dict(A.GATE_COUNTS)
+    return cfg, lowered.compile(), gate
+
+
+def test_bert_base_train_step_evaluates_each_dropout_mask_once(bert_step):
     """24 masks a step (two a layer, `bool[256,128,768]`), each drawn ONCE:
     `common.dropout` pins its mask, so that XLA cannot run the generator
     again inside every fusion that reads it. Without the pin this program
@@ -1511,8 +1583,7 @@ def test_bert_base_train_step_evaluates_each_dropout_mask_once(v5e):
     reductions with three masks each), 63 ms more of a 221 ms step on the
     chip (PERF.md, PR 50). And the 24 stored masks (0.6 GB) leave the step
     under the chip's memory."""
-    cfg, lowered = _described_train_step(v5e)
-    compiled = lowered.compile()
+    cfg, compiled, _ = bert_step
     masks = 2 * cfg.layers
     evaluations, computations = _threefry_evaluations(
         compiled.as_text(), (_BERT_BATCH, _BERT_SEQ, cfg.hidden))
@@ -1522,6 +1593,37 @@ def test_bert_base_train_step_evaluates_each_dropout_mask_once(v5e):
     planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                + ma.output_size_in_bytes - ma.alias_size_in_bytes
                + ma.generated_code_size_in_bytes)
-    # parameters and both moments in float32 1.32 GB; temporaries 8.8 GB,
-    # of them 0.6 GB the masks (8.24 GB without the pin); the chip has 16
-    assert 9.5e9 < planned < 11.0e9, ma
+    # parameters and both moments in float32 1.32 GB; temporaries 7.56 GB,
+    # of them 0.6 GB the masks. Until PR 54 they were 8.8 GB (planned 9.5 to
+    # 11.0): the XLA route kept a bf16 [256,12,128,128] of probabilities a
+    # layer for the backward, 12 x 100.7 MB = 1.21 GB; the short kernel
+    # keeps lse [256,12,128] float32, 12 x 1.6 MB. 1.32 + 7.56 + the
+    # step's outputs less what they alias = 9.07 GB; the chip has 16
+    assert 8.6e9 < planned < 9.6e9, ma
+
+
+def test_bert_base_train_step_attends_through_the_short_kernel(bert_step):
+    """Attention at T = 128 is one fused kernel forward and one backward a
+    layer (`attention._short_mha`, PERF.md section 6, PR 54): 12 + 12
+    custom calls, chosen by the gate from the shape alone, and nothing of
+    the XLA route's left in the step: no `[256,12,128,128]` score buffer
+    and no heads-major `[256,12,128,64]` copy of q, k, v, the context or a
+    gradient, of any dtype."""
+    cfg, compiled, gate = bert_step
+    assert gate == {"short": cfg.layers}, gate
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert sum("short_mha_fwd" in ln for ln in calls) == cfg.layers
+    assert sum("short_mha_bwd" in ln for ln in calls) == cfg.layers
+    assert len(calls) == 2 * cfg.layers, len(calls)
+    # ... and the benchmark's scope reduction books every one of them to
+    # `attention`, the backward's through `transpose(jvp(layers))`
+    from benchmarks.harness import program_trace
+    names = [re.search(r'op_name="([^"]*)"', ln).group(1) for ln in calls]
+    assert {program_trace.scope_of(n) for n in names} == {"attention"}, names
+    assert sum("transpose(jvp(" in n for n in names) == cfg.layers, names
+    heads, hd = cfg.heads, cfg.head_dim
+    for shape in ((_BERT_BATCH, heads, _BERT_SEQ, _BERT_SEQ),
+                  (_BERT_BATCH, heads, _BERT_SEQ, hd)):
+        assert "[%s]" % ",".join(map(str, shape)) not in text, shape
