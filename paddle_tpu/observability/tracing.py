@@ -53,6 +53,7 @@ __all__ = ["Span", "span", "record_span", "get_spans", "clear_spans",
            "OpenSpan", "open_span", "record", "current_span",
            "start_recording", "stop_recording", "recorded",
            "add_record", "get_records", "kept_rows",
+           "HICCUP_TICK_S", "HICCUP_LATE_S",
            "set_annotation_provider",
            "process_start", "boot_span", "compile_event",
            "compile_duration", "last_compile_request", "boot_summary",
@@ -500,8 +501,14 @@ MAX_RECORDS = 200_000
 KEPT_RECORDS = {"compile.requests": 4096, "boot.spans": 256}
 _kept_rows = 0
 
+# the recording's hiccup thread wakes every HICCUP_TICK_S and writes a row
+# of `host.hiccups` when it wakes more than HICCUP_LATE_S after it meant to
+HICCUP_TICK_S = 0.02
+HICCUP_LATE_S = 0.05
+
 _annotation_provider = None     # () -> jax.profiler.TraceAnnotation
 _annotation = None              # resolved by start_recording()
+_hiccups = None                 # (thread, its stop event) of the recording
 _stack = threading.local()
 _next_sid = itertools.count(1)
 _records: Dict[str, "collections.deque"] = {}
@@ -517,18 +524,52 @@ def set_annotation_provider(fn):
 def start_recording(clear: bool = True):
     """Turn the program's span sites and record lists on (a `--trace 1`
     benchmark run, a `POST /v1/profile` capture); `clear` empties the
-    ring and the record lists first."""
-    global recording, _annotation
+    ring and the record lists first. The recording owns ONE hiccup thread
+    (`_watch_hiccups`), however often it is started."""
+    global recording, _annotation, _hiccups
     if clear:
         clear_spans()
     if _annotation is None and _annotation_provider is not None:
         _annotation = _annotation_provider()
+    with _lock:
+        if _hiccups is None:
+            stop = threading.Event()
+            _hiccups = (threading.Thread(
+                target=_watch_hiccups, args=(stop,),
+                name="paddle-tpu-hiccups", daemon=True), stop)
+            _hiccups[0].start()
     recording = True
 
 
 def stop_recording():
-    global recording
+    global recording, _hiccups
     recording = False
+    with _lock:
+        watch, _hiccups = _hiccups, None
+    if watch is not None:
+        watch[1].set()
+        watch[0].join(timeout=1.0)
+
+
+def _watch_hiccups(stop: threading.Event, sleep=None, now=None):
+    """The body of the recording's hiccup thread: sleep HICCUP_TICK_S at a
+    time on `clock` and, on a wake more than HICCUP_LATE_S after the one
+    meant, append `{"t": the wake meant, "late_s": how late}` to the
+    record list `host.hiccups`. It does nothing else and holds no lock
+    while it sleeps, so a late wake means that NO Python thread of this
+    process ran for that long (the host, the hypervisor, a collector, a C
+    call that kept the interpreter lock): a long `decode.resolve.wait`
+    with no row beside it was the device's or its runtime's. `sleep` and
+    `now` are the tests' (a made-up late wake)."""
+    sleep = stop.wait if sleep is None else sleep
+    now = clock if now is None else now
+    due = now() + HICCUP_TICK_S
+    while not stop.is_set():
+        sleep(max(0.0, due - now()))
+        woke = now()
+        if woke - due > HICCUP_LATE_S and not stop.is_set():
+            add_record("host.hiccups", {"t": due, "late_s": woke - due})
+        due = woke + HICCUP_TICK_S
 
 
 @contextlib.contextmanager

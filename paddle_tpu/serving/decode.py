@@ -108,7 +108,9 @@ TTFT_SECONDS = _m.histogram(
 STEP_SECONDS = _m.histogram(
     "paddle_tpu_decode_step_seconds",
     "Wall seconds from a decode step's dispatch to its tokens on the "
-    "host (under the lazy loop's overlap: about two device steps)",
+    "host: dispatch to resolve, about two device steps under the lazy "
+    "loop's overlap, not a step's time (status()['step_ms'] is start to "
+    "start)",
     buckets=_LATENCY_BUCKETS)
 TOKENS = _m.counter(
     "paddle_tpu_decode_tokens_total",
@@ -738,8 +740,11 @@ class DecodeEngine:
         # host, in device order (decode step, prefill, decode step, ...)
         self._inflight: "collections.deque[_Pending]" = collections.deque()
         # how each decode step of the lazy loop got its ids, and the
-        # forced resolves of everything in flight (status()["pipeline"])
-        self._pipeline = {"fed": 0, "assembled": 0, "host": 0, "drains": 0}
+        # forced resolves of everything in flight (status()["pipeline"]);
+        # `starved`: dispatches of a recording that saw the device's queue
+        # empty (`_queue_empty`)
+        self._pipeline = {"fed": 0, "assembled": 0, "host": 0, "drains": 0,
+                          "starved": 0}
         self._no_first = np.zeros((1,), np.int32)
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -1364,8 +1369,10 @@ class DecodeEngine:
             # they got their ids ("fed": the step in flight's tokens as
             # they are; "assembled": put together on the device after an
             # admission or a retirement; "host": built from host tokens,
-            # nothing in flight) and the forced resolves of everything in
+            # nothing in flight), the forced resolves of everything in
             # flight (pool exhaustion, a request's only token, shutdown)
+            # and, counted while a recording is on, the dispatches before
+            # which the host saw the device's queue empty ("starved")
             out["pipeline"] = dict(self._pipeline)
         else:
             out["prefilling"] = prefilling
@@ -1423,8 +1430,12 @@ class DecodeEngine:
 
     def _flush_outbox(self) -> None:
         box, self._outbox = self._outbox, []
+        sp = _tracing.open_span("decode.flush", "decode") \
+            if _tracing.recording and box else None
         for req, item in box:
             self._hand_over(req, item)
+        if sp is not None:
+            sp.close(items=len(box))
 
     def _hand_over(self, req: _Request, item) -> None:
         if item is not None and req.t_first is None:
@@ -1568,6 +1579,34 @@ class DecodeEngine:
                 return s
         return self.decode_slots[-1]
 
+    def _queue_empty(self) -> Optional[float]:
+        """Recording on, before a span's first enqueuing call (at the
+        opening of `decode.dispatch` and again where its build ends, at
+        the opening of an admission's `decode.prefill`): the time on
+        `tracing.clock` if the host sees the device's queue EMPTY (the
+        newest entry in flight has its tokens ready; nothing in flight
+        counts as ready), else None. From here to that call's return the
+        device has nothing to run: the span's `starved_s`, a lower bound
+        on the device's idle time that needs no profiler."""
+        if self._inflight and not self._inflight[-1].tok_dev.is_ready():
+            return None
+        self._pipeline["starved"] += 1
+        return _tracing.clock()
+
+    def _same_bucket_waiting(self, req: _Request) -> int:
+        """Recording on, as `req` is admitted: how many requests still
+        waiting fall into its prefill bucket (within 256 tokens of its
+        length where no bucket holds it): the company a batched prefill
+        would have found."""
+        n = len(req.prompt)
+        bucket = self._bucket_for_len(n)
+        with self._cv:
+            if bucket is None:
+                return sum(abs(len(r.prompt) - n) <= 256
+                           for r in self._waiting)
+            return sum(self._bucket_for_len(len(r.prompt)) == bucket
+                       for r in self._waiting)
+
     def _sweep_cancelled(self):
         """Retire requests whose clients abandoned them (cancel()):
         waiting ones leave the queue, active ones free their slot and
@@ -1647,7 +1686,7 @@ class DecodeEngine:
         loop queues the fetch, the sync loop resolves it at once."""
         # the admission boundary: everything since (re-)enqueue was wait
         req.admitted_at = time.monotonic()
-        sp = None
+        sp = facts = probe = called = None
         if _tracing.recording or req.traced:
             _tracing.record(
                 "decode.queue_wait", req.enqueued_at, req.admitted_at,
@@ -1656,23 +1695,33 @@ class DecodeEngine:
             sp = _tracing.open_span("decode.prefill", "decode",
                                     parent=req.parent, rid=req.rid,
                                     ctx=req.tctx)
+            if _tracing.recording:
+                probe = self._queue_empty()
+                facts = {"same_bucket_waiting":
+                         self._same_bucket_waiting(req),
+                         "queue_empty": False}
         bucket = self._bucket_for_len(len(req.prompt))
         try:
-            return self._prefill_admitted(req, bucket)
+            first, called = self._prefill_admitted(req, bucket)
+            return first
         finally:
             if sp is not None:
+                if probe is not None and called is not None:
+                    facts.update(queue_empty=True, starved_s=called - probe)
                 # the admitted table's chunks, and how many of them the
                 # decode kernels will read with one copy a pool
                 runs, chunks = run_chunks(req.blocks, self._alloc.per_chunk)
                 sp.close(bucket=bucket, prompt_len=len(req.prompt),
                          queue_wait_s=req.admitted_at - req.enqueued_at,
-                         runs=runs, chunks=chunks)
+                         runs=runs, chunks=chunks, **(facts or {}))
 
     def _prefill_admitted(self, req: _Request, bucket: Optional[int]
-                          ) -> Optional[_Pending]:
+                          ) -> Tuple[Optional[_Pending], Optional[float]]:
         """The prompt work of one admitted request: the prefill program
         is dispatched, the request takes its slot, and nothing waits for
-        the device (`decode.prefill.wait` holds the call alone)."""
+        the device (`decode.prefill.wait` holds the call alone). Returns
+        the fetch and, recording on, when the call returned on
+        `tracing.clock` (the end of the admission's `starved_s`)."""
         if self._wfq is not None:
             # prefill service charge: a long prompt is real work even
             # before its first decode token
@@ -1683,7 +1732,7 @@ class DecodeEngine:
                 f"prompt+generated length {plen} exceeds the largest "
                 f"prefill bucket {self.prefill_buckets[-1]}")
             self._finish(req, "error")
-            return None
+            return None, None
         need = -(-plen // self.kv_cfg.block_size)
         req.blocks = self._alloc.alloc(need)
         bt = build_block_table(req.blocks, self.kv_cfg.max_blocks_per_seq)
@@ -1699,7 +1748,7 @@ class DecodeEngine:
         if self._state_specs:
             state = (self._state, np.int32(req.state_row))
         t0 = time.perf_counter()
-        wait = row = None
+        wait = row = called = None
         if _tracing.recording:
             row = self._step_record("prefill", t0, 1, 1, plen)
             wait = _tracing.open_span("decode.prefill.wait", "decode")
@@ -1712,7 +1761,7 @@ class DecodeEngine:
         if out:
             self._state = out[0]
         if wait is not None:
-            wait.close(call_s=time.perf_counter() - t0)
+            called = wait.close(call_s=time.perf_counter() - t0)
         STEPS.inc(phase="prefill")
         if self._draft is not None:
             # the draft prefills EVERY sequence (same ids, same block
@@ -1732,7 +1781,7 @@ class DecodeEngine:
             for a in jax.tree_util.tree_leaves(stats):
                 a.copy_to_host_async()
             pending.stats = (row, stats)
-        return pending
+        return pending, called
 
     def _grow_blocks(self) -> None:
         """Ensure every active slot owns the block its next write
@@ -1815,12 +1864,15 @@ class DecodeEngine:
         return tuple(r.rid if r else -1 for r in slots), slots
 
     def _next_ids(self, sig, slots):
-        """The ids of the decode step about to be dispatched with the
-        batch `slots`, and how they came about. The host knows where each
+        """Where the ids of the decode step about to be dispatched with
+        the batch `slots` come from: (how, the ids or what they are
+        assembled from, the `_assemble` calls still to make on them, each
+        a (program key, first token, index)). The host knows where each
         slot's last token lies without having it: in the row of the
         decode step in flight that carried the slot, in an admission's
         prefill still in flight, or (nothing in flight) in
-        `req.last_token`.
+        `req.last_token`. Nothing here enqueues: `_dispatch` makes the
+        calls, back to back with the step's.
 
         "fed": the batch is the in-flight step's, whose tokens go back
         in as they are. "assembled": the batch changed (an admission, a
@@ -1831,7 +1883,7 @@ class DecodeEngine:
         prev = next((p for p in reversed(self._inflight)
                      if p.snapshot is not None), None)
         if prev is not None and prev.snapshot == sig:
-            return prev.tok_dev, "fed"
+            return "fed", prev.tok_dev, ()
         C = len(slots)
         if prev is None:
             # a drain left every resident's token on the host
@@ -1840,7 +1892,7 @@ class DecodeEngine:
                 if req is not None:
                     src[i] = req.last_token
             if not self._inflight:
-                return src, "host"
+                return "host", src, ()
             row = {req.rid: i for i, req in enumerate(slots)
                    if req is not None}
         else:
@@ -1865,18 +1917,21 @@ class DecodeEngine:
         if late:
             idx[late[0][0]] = n
             first = late[0][1].tok_dev
-        ids = self._assemble[(n, C)](src, first, idx)
+        calls = [((n, C), first, idx)]
         for i, p in late[1:]:
             idx = np.arange(C, dtype=np.int32)
             idx[i] = C
-            ids = self._assemble[(C, C)](ids, p.tok_dev, idx)
-        return ids, "assembled"
+            calls.append(((C, C), p.tok_dev, idx))
+        return "assembled", src, calls
 
     def _dispatch(self, C: int) -> _Pending:
         """Dispatch one decode step at `C` slots behind whatever is in
         flight, and queue its fetch."""
-        sp = _tracing.open_span("decode.dispatch", "decode") \
-            if _tracing.recording else None
+        sp = part = probe = ran = starved = None
+        if _tracing.recording:
+            sp = _tracing.open_span("decode.dispatch", "decode")
+            probe = self._queue_empty()
+            part = _tracing.open_span("decode.dispatch.build", "decode")
         sig, slots = self._snapshot(C)
         kp, vp = self._pools
         positions = np.zeros((C,), np.int32)
@@ -1890,12 +1945,29 @@ class DecodeEngine:
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
         state = (self._state, rows) if self._state_specs else ()
+        how, ids_arg, assemblies = self._next_ids(sig, slots)
+        self._pipeline[how] += 1
+        if part is not None:
+            # everything from here on enqueues
+            part.close()
+            if probe is None:
+                # asked again: the step in flight at the opening may have
+                # ended while the batch was built
+                probe = self._queue_empty()
+            part = _tracing.open_span("decode.dispatch.call", "decode")
         # last, so that an assembly and its step are dispatched back to
         # back: the device may be waiting for just these two
-        ids_arg, how = self._next_ids(sig, slots)
-        self._pipeline[how] += 1
+        for key, first, idx in assemblies:
+            ids_arg = self._assemble[key](ids_arg, first, idx)
+            if probe is not None and ran is None:
+                ran = _tracing.clock()
         tok, kp, vp, stats, *out = self._decode[C](
             self.params, ids_arg, positions, kp, vp, bts, *state)
+        if part is not None:
+            called = part.close()
+            if probe is not None:
+                # the device starts with the first call that returned
+                starved = (called if ran is None else ran) - probe
         self._pools = (kp, vp)
         if out:
             self._state = out[0]
@@ -1909,7 +1981,10 @@ class DecodeEngine:
         self._inflight.append(pending)
         self._step_starts.append(pending.t_dispatch)
         if sp is not None:
-            row = self._close_dispatch(sp, "decode", C, slots, ids=how)
+            row = self._close_dispatch(
+                sp, "decode", C, slots, ids=how,
+                **({"queue_empty": False} if starved is None else
+                   {"queue_empty": True, "starved_s": starved}))
             if stats is not None:
                 # on their way to the host while the step runs on
                 for a in jax.tree_util.tree_leaves(stats):
@@ -1987,6 +2062,13 @@ class DecodeEngine:
                 self._finish(req, reason)
                 finished += 1
         if sp is not None:
+            # the fetch's device array goes HERE, under a name, and not
+            # where `_resolve` drops `pending` after the span: letting go
+            # of it hands the interpreter lock over, 2 ms a turn beside
+            # 128 readers (chip runs of PR 55 and PR 56)
+            rel = _tracing.open_span("decode.resolve.release", "decode")
+            pending.handle = pending.tok_dev = pending.stats = None
+            rel.close()
             # a request's first token is its prefill's, not a step's
             sp.close(tokens=0 if first else emitted,
                      first=emitted if first else 0, finished=finished)
@@ -2028,14 +2110,19 @@ class DecodeEngine:
                         self._cv.wait(timeout=0.5)
                     if self._closed:
                         break
-                # the idle wait above lies outside the turn's span
-                sp = _tracing.open_span("decode.turn", "decode") \
-                    if _tracing.recording else None
+                # the idle wait above lies outside the turn's span; the
+                # CPU time of this thread is read at a turn's edges and
+                # nowhere else (the clock is a system call)
+                sp = None
+                if _tracing.recording:
+                    sp = _tracing.open_span("decode.turn", "decode")
+                    cpu0 = time.thread_time()
                 try:
                     self._turn()
                 finally:
                     if sp is not None:
-                        sp.close(loop="lazy")
+                        sp.close(loop="lazy",
+                                 cpu_s=time.thread_time() - cpu0)
         except BaseException as e:  # scheduler death must not hang clients
             with self._cv:
                 reqs = list(self._active) + list(self._waiting)
@@ -2303,8 +2390,10 @@ class DecodeEngine:
         self._grow_blocks_sync(1)
         if not self._active:
             return
-        sp = _tracing.open_span("decode.dispatch", "decode") \
-            if _tracing.recording else None
+        sp = part = None
+        if _tracing.recording:
+            sp = _tracing.open_span("decode.dispatch", "decode")
+            part = _tracing.open_span("decode.dispatch.build", "decode")
         C = self._slot_config()
         sig, slots = self._snapshot(C)
         ids = np.zeros((C,), np.int32)
@@ -2317,6 +2406,9 @@ class DecodeEngine:
             positions[i] = req.pos
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
+        if part is not None:
+            part.close()
+            part = _tracing.open_span("decode.dispatch.call", "decode")
         t0 = time.perf_counter()
         self._step_starts.append(t0)
         kp, vp = self._pools
@@ -2330,6 +2422,8 @@ class DecodeEngine:
                 self._draft_params, ids, positions, dkp, dvp, bts)
             self._draft_pools = (dkp, dvp)
             STEPS.inc(phase="draft")
+        if part is not None:
+            part.close()
         res, wait, row = self._sync_resolve_spans(sp, "decode", C, slots)
         toks = np.asarray(tok)                 # synchronous resolve
         if wait is not None:
@@ -2363,16 +2457,17 @@ class DecodeEngine:
             res.close(tokens=emitted, finished=finished)
 
     def _close_dispatch(self, sp, kind: str, C: int, slots,
-                        ids: str = "host") -> Dict:
+                        ids: str = "host", **probed) -> Dict:
         """Recording on: the step's record (returned) and the end of its
         open decode.dispatch span `sp`; `ids` says where the step's ids
         came from (`_next_ids`; a synchronous round builds them on the
-        host)."""
+        host), `probed` what the lazy loop saw of the device's queue
+        (`_queue_empty`: `queue_empty`, and `starved_s` where it was)."""
         live = [r for r in slots if r is not None]
         tokens = sum(r.pos for r in live)
         row = self._step_record(kind, sp.t0, C, len(live), tokens)
         sp.close(slots=C, live=len(live), live_tokens=tokens,
-                 blocks_used=self._alloc.used_blocks(), ids=ids)
+                 blocks_used=self._alloc.used_blocks(), ids=ids, **probed)
         return row
 
     def _sync_resolve_spans(self, sp, kind: str, C: int, slots):
@@ -2433,8 +2528,10 @@ class DecodeEngine:
         self._grow_blocks_sync(k + 1)
         if not self._active:
             return
-        sp = _tracing.open_span("decode.dispatch", "decode") \
-            if _tracing.recording else None
+        sp = part = None
+        if _tracing.recording:
+            sp = _tracing.open_span("decode.dispatch", "decode")
+            part = _tracing.open_span("decode.dispatch.build", "decode")
         self._draft_catch_up()
         C = self._slot_config()
         sig, slots = self._snapshot(C)
@@ -2448,6 +2545,10 @@ class DecodeEngine:
             positions[i] = req.pos
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
+        if part is not None:
+            # the draft chain, its sync point and the verification
+            part.close()
+            part = _tracing.open_span("decode.dispatch.call", "decode")
         t0 = time.perf_counter()
         self._step_starts.append(t0)
         # k draft steps, each feeding the previous step's DEVICE token
@@ -2471,6 +2572,8 @@ class DecodeEngine:
                                        kp, vp, bts)
         self._pools = (kp, vp)
         STEPS.inc(phase="verify")
+        if part is not None:
+            part.close()
         res, wait, _ = self._sync_resolve_spans(sp, "verify", C, slots)
         outs = np.asarray(vtok)                # [C, k+1]
         if wait is not None:
@@ -2539,13 +2642,16 @@ class DecodeEngine:
                         self._cv.wait(timeout=0.5)
                     if self._closed:
                         break
-                sp = _tracing.open_span("decode.turn", "decode") \
-                    if _tracing.recording else None
+                sp = None
+                if _tracing.recording:
+                    sp = _tracing.open_span("decode.turn", "decode")
+                    cpu0 = time.thread_time()
                 try:
                     self._turn_sync()
                 finally:
                     if sp is not None:
-                        sp.close(loop="sync")
+                        sp.close(loop="sync",
+                                 cpu_s=time.thread_time() - cpu0)
         except BaseException as e:  # scheduler death must not hang clients
             with self._cv:
                 reqs = (list(self._active) + list(self._prefilling) +

@@ -764,6 +764,9 @@ def test_admissions_are_assembled_on_the_device_and_warmed(model, tmp_path):
         assert [d._recorded_jit_compiles for d in grid] == [0] * n_grid
         disp = sorted((s for s in tracing.get_spans()
                        if s.name == "decode.dispatch"), key=lambda s: s.ts)
+        # dispatches and admissions before which the queue was seen empty
+        starved = sum(s.args["queue_empty"] for s in tracing.get_spans()
+                      if s.name in ("decode.dispatch", "decode.prefill"))
         tracing.clear_spans()
         how = [s.args["ids"] for s in disp]
         joined = [s for prev, s in zip(disp, disp[1:])
@@ -774,7 +777,7 @@ def test_admissions_are_assembled_on_the_device_and_warmed(model, tmp_path):
         assert how[0] == "assembled" and "host" not in how
         assert pipe == {"fed": how.count("fed"),
                         "assembled": how.count("assembled"),
-                        "host": 0, "drains": 0}
+                        "host": 0, "drains": 0, "starved": starved}
         assert pipe["fed"] > pipe["assembled"] >= K + 1
     assert runs["warmup"] == runs["warmstart"] \
         == [_reference(model, p, n) for p, n in jobs]
