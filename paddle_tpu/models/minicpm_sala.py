@@ -607,12 +607,14 @@ class MiniCPMSALAServe(_decoder.ServeModel):
         """Selection, then the walk over the taken blocks: every (slot,
         K/V head) gets a table of its own, the blocks it reads in
         ascending order (a row at or under `dense_len`: its own table),
-        and the position of its newest token in that list. The blocks'
-        scores come from the compressed keys where they lie
-        (`scores_where_they_lie`) where the step's tables carry the
-        pieces of that walk (`Tables.rows`: `rated_tables` found its gate
-        open), else from a gather of every slot's whole table of them;
-        one `take_blocks` either way."""
+        and the position of its newest token in that list; the walk takes
+        a slot's K/V heads together and fetches once what their lists
+        share (`paged_attention.pair_lists`). The blocks' scores come
+        from the compressed keys where they lie (`scores_where_they_lie`)
+        where the step's tables carry the pieces of that walk
+        (`Tables.rows`: `rated_tables` found its gate open), else from a
+        gather of every slot's whole table of them; one `take_blocks`
+        either way."""
         from ..ops.pallas import paged_attention as pa
         from ..serving import kv_cache as kvc
 
@@ -655,9 +657,14 @@ class MiniCPMSALAServe(_decoder.ServeModel):
             newest = jnp.where(
                 sparse, (count - 1) * sb + (positions % sb)[:, None],
                 positions[:, None])
+        # the lists' runs and the entries a slot's K/V heads share (the
+        # forced blocks at least; every entry of a row that reads its own
+        # table): a compare of the lists, which are there
+        lists = pa.pair_lists(tables.reshape(S * G, width),
+                              newest.reshape(S * G), G, bs)
         ctx = pa.paged_sparse_attention(
-            q, k_pool, v_pool, layer, tables.reshape(S * G, width),
-            newest.reshape(S * G), heads=cfg.heads, kv_heads=G)
+            q, k_pool, v_pool, layer, lists, heads=cfg.heads, kv_heads=G,
+            list_tokens=K * sb)
         return self._gated(ctx, gate)
 
     def attend_cached(self, lp, q, keys, vals, pos, extra=()):
@@ -883,19 +890,32 @@ class MiniCPMSALAServe(_decoder.ServeModel):
     def step_counters(self, positions, block_tables):
         """Of one decode step: live rows over `dense_len` (a sparse read a
         sparse layer), the tokens the other live rows read, the blocks a
-        sparse row reads a layer, the compressed keys it scores a layer;
-        all follow from the positions."""
+        sparse row reads a layer, of the live rows' blocks those that
+        every K/V head reads whatever it scores (the first `init_blocks`
+        and the window's: what the walk fetches once for all heads at the
+        least; a row that reads its own table, every block it holds), the
+        compressed keys a sparse row scores a layer; all follow from the
+        positions."""
         cfg = self.cfg
         n = positions + 1
+        sb = cfg.sel_block
         live = block_tables[:, 0] != 0
         sparse = live & (n > cfg.dense_len)
-        blocks = jnp.minimum(cfg.topk, (n - 1) // cfg.sel_block + 1)
+        held = (n - 1) // sb + 1
+        blocks = jnp.minimum(cfg.topk, held)
+        window_from = jnp.maximum(n - cfg.window, 0) // sb
+        forced = jnp.minimum(
+            jnp.minimum(cfg.init_blocks, window_from) + held - window_from,
+            blocks)
         return {
             "sparse_rows": jnp.sum(sparse, dtype=jnp.int32),
             "dense_tokens": jnp.sum(jnp.where(live & ~sparse, n, 0),
                                     dtype=jnp.int32),
             "blocks_selected": jnp.sum(jnp.where(sparse, blocks, 0),
                                        dtype=jnp.int32),
+            "shared_entries": jnp.sum(
+                jnp.where(sparse, forced, jnp.where(live, held, 0)),
+                dtype=jnp.int32),
             "kc_entries": jnp.sum(
                 jnp.where(sparse, n // cfg.kernel_stride - 1, 0),
                 dtype=jnp.int32)}
@@ -911,5 +931,8 @@ class MiniCPMSALAServe(_decoder.ServeModel):
                 # mean blocks a sparse row reads a sparse layer
                 "blocks_selected": float(c["blocks_selected"]) / rows
                 if rows else 0.0,
+                # of the live rows' lists, the selection blocks every K/V
+                # head reads (the walk's both-heads copy), summed
+                "shared_entries": int(c["shared_entries"]),
                 # compressed keys the step's sparse rows score a layer
                 "kc_entries": int(c["kc_entries"])}

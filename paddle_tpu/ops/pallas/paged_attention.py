@@ -89,6 +89,42 @@ the first break as well (`Tables.runs`' second half) and it goes in pieces
 too. `chunk_tokens` is the one number; the wrapper's count, the scratch and
 the allocator's `run_chunk_share` go by it.
 
+A block-sparse layer's LISTS (`paged_sparse_attention`: every (slot, K/V
+head) pair reads `topk` blocks of its own choice, a few thousand tokens
+whatever the context) are walked a SLOT a grid step, its K/V heads
+together (`_sparse_kernel`). The buffers are a pool's full lanes wide and
+a K/V head's tokens lie in its own lanes of them, so the step is the
+grouped-query walk's one block-diagonal product for all query heads. Of a
+slot's lists the first entries and the last are the same in every head's
+(the forced blocks: the first and the newest window's, 33 or 34 of 64 in
+`minicpm_sala.longdoc_sessions`; every entry of a row that reads its own
+table): `pair_lists` counts both by a compare (`PairLists.shared`), and
+those the walk fetches ONCE for all heads, a block one contiguous span of
+its rows at all lanes and the window's run one copy of up to a megabyte a
+pool, by runs counted from the list itself (`PairLists.runs`: of every
+entry how many ids from it on follow each other, so a run is one ladder
+of copies whatever boundary a table's chunks would have put inside it).
+The entries between, a head's own picks, go that head's lanes of a block
+a copy into that head's lanes of the buffer, a block at a time: picks
+seldom follow each other, and a loop that asks costs every pick a branch.
+The chunk is the lists' own number (`sparse_chunk_tokens`: 1024, 2048 or
+4096 tokens, the longest that a sparse row's list fills; the step through
+it in sub-tiles of `_SUB` as a long table's); `chunk_tokens`, the
+allocator and the three other walks do not know it. A chunk's copies are
+waited for by their BYTES, in a few waits of static sizes, and no list is
+read a second time: a turn of the scalar core's loop costs as much as 16 KB
+take to arrive. Measured alone at the cell's shapes (32 slots, lists of
+64 blocks of 64 tokens, sequences of 17k-45k tokens in runs; us a layer;
+PERF.md section 6, PR 57): a pair a grid step, one head's lanes a copy,
+512 tokens a chunk 514 (its copies alone 361, its steps alone 257: they
+add up, both turn on the scalar core); with 2048 tokens a chunk 343; a
+slot a grid step with the shared entries at full width 463 at 512 tokens
+a chunk and 387 at 4096; the waits by bytes 283; the picks a block at a
+time 247, of which the copies alone are 245 (134 MB at 545 GB/s: the
+copy engine bounds it) and the steps alone 107. Without the shared copy
+302, without the runs 275, at 2048 / 1024 / 512 tokens a chunk 248 / 282 /
+344.
+
 An entry stored at a RATE (serving/kv_cache.py `KVCacheConfig.rated`: the
 compressed keys a block-sparse attention scores before it reads any K or V)
 lies a block a ROW, `[L, NB, E * W]`, NB whole tiles of 8 rows, and a row
@@ -122,6 +158,18 @@ from . import attention as _attention
 # it reads that entry ("select_paged": `paged_select_scores` | "select_gather":
 # every slot's whole table gathered); DecodeEngine.status() reports them
 GATE_COUNTS: collections.Counter = collections.Counter()
+# tokens a chunk of a route whose walk has a number of its own (not
+# `chunk_tokens`, which `status()["kv"]["walk_chunk_tokens"]` names): the
+# sparse walk's (`sparse_chunk_tokens`), noted where its call is traced
+WALK_CHUNKS: dict = {}
+
+
+def gate_report() -> dict:
+    """`GATE_COUNTS` for `DecodeEngine.status()["decode_attention"]`, with
+    `<route>_chunk_tokens` of a counted route in `WALK_CHUNKS`."""
+    return {**GATE_COUNTS, **{f"{route}_chunk_tokens": chunk
+                              for route, chunk in WALK_CHUNKS.items()
+                              if GATE_COUNTS.get(route)}}
 
 # tokens a compute step: a multiple of 128, so scores [M, _CHUNK] fill
 # their lanes; K and V double-buffered are 4 * _CHUNK * H*D elements of VMEM
@@ -340,23 +388,14 @@ def use_paged_latent(q: jax.Array, c_pool: jax.Array, r_pool: jax.Array,
 
 
 def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
-          done_ref, zeroed, setup, step, bs, lane_groups: int = 1):
+          done_ref, zeroed, setup, step, bs):
     """One grid step's walk over slot `program_id(0)`'s live blocks of
     layer `layer_ref[0]` of `pools` (`_call_form`; `bs` tokens a block):
     chunk by chunk through the double buffers `bufs` (one `[2, chunk,
     width]` a pool, `sems` `[2, len(pools)]`), one DMA a pool for every
     piece of consecutive blocks (`each_copy`; `lead_ref` `[S, chunks]`:
-    `Tables.runs`), the next chunk (or the next slot's first) in flight
-    while `step(query, c, buf, carry) -> carry` consumes chunk `c` from
-    `bufs[i][buf]`. `setup() -> (query, first carry)` builds the slot's
-    query operands; it runs AFTER the call's first copies are started, so
-    that no DMA waits for it. `zeroed` are the buffers whose stale rows
-    meet exact zero weights and so must be finite from the start. Returns
-    (query, the last carry: the first, for a slot that reads nothing).
-    With `lane_groups` > 1 the grid's slots are (slot, lane group) pairs,
-    the group minor-most, each with a table of its own, and a copy takes
-    its group's share of a pool's lanes alone (a buffer is that wide): a
-    K/V head's walk over the blocks IT selected (`paged_sparse_attention`)."""
+    `Tables.runs`), by `_through_chunks`' discipline (its `setup`, `step`,
+    `zeroed` and result)."""
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
     chunk = bufs[0].shape[1]
@@ -395,20 +434,9 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
                 if take_runs else blk
             rows = pl.ds(pl.multiple_of(j * bs, bs), k * bs)
             for which, (hbm, dst) in enumerate(zip(pools, bufs)):
-                def go(lanes=None, hbm=hbm, dst=dst, which=which):
-                    from_ = hbm.at[layer, src] if lanes is None \
-                        else hbm.at[layer, src, lanes]
-                    getattr(pltpu.make_async_copy(
-                        from_, dst.at[buf, rows], sems.at[buf, which]),
-                        act)()
-
-                if lane_groups == 1:
-                    go()
-                    continue
-                width = dst.shape[2]
-                for g in range(lane_groups):    # static lanes a branch
-                    pl.when(slot % lane_groups == g)(functools.partial(
-                        go, pl.ds(g * width, width)))
+                getattr(pltpu.make_async_copy(
+                    hbm.at[layer, src], dst.at[buf, rows],
+                    sems.at[buf, which]), act)()
 
         def one(j, carry):
             copy(j, 1)
@@ -451,6 +479,22 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
                 done = done + after
             lax.fori_loop(done, n, one, 0)
 
+    return _through_chunks(
+        s, n_slots, lambda slot: (live_blocks(slot) + per_chunk - 1)
+        // per_chunk, each_copy, done_ref, zeroed, setup, step)
+
+
+def _through_chunks(s, n_slots, chunks_of, each_copy, done_ref, zeroed,
+                    setup, step):
+    """Grid step `s` of `n_slots`: slot s's `chunks_of(s)` chunks through
+    the double buffers, the next chunk (or the next slot's first) in
+    flight while `step(query, c, buf, carry) -> carry` consumes chunk `c`
+    from buffer `buf`; `each_copy(slot, c, buf, "start" | "wait")` makes a
+    chunk's copies. `setup() -> (query, first carry)` builds the slot's
+    query operands; it runs AFTER the call's first copies are started, so
+    that no DMA waits for it. `zeroed` are the buffers whose stale rows
+    meet exact zero weights and so must be finite from the start. Returns
+    (query, the last carry: the first, for a slot that reads nothing)."""
     @pl.when(s == 0)
     def _():
         done_ref[0] = 0
@@ -464,7 +508,7 @@ def _walk(layer_ref, tables_ref, pos_ref, lead_ref, pools, bufs, sems,
             each_copy(s + 1, 0, buf, "start")
 
     done = done_ref[0]          # chunks consumed so far: the buffers' turn
-    chunks = (live_blocks(s) + per_chunk - 1) // per_chunk
+    chunks = chunks_of(s)
     query, carry = setup()
 
     @pl.when(chunks == 0)
@@ -512,7 +556,7 @@ def _softmax_init(rows: int, width: int):
             jnp.zeros((rows, width), jnp.float32))
 
 
-def _softmax_chunk(tile, chunk: int, c, pos, carry):
+def _softmax_chunk(tile, chunk: int, c, pos, carry, newest=None):
     """Chunk `c` of the online softmax. `tile(rows) -> (scores [M, rows]
     float32 and scaled, values [rows, width])` of those rows of the chunk's
     buffers. A chunk of `_SUB` tokens at most is one tile, as ever. A
@@ -523,11 +567,14 @@ def _softmax_chunk(tile, chunk: int, c, pos, carry):
     lie in VMEM and nothing waits between them, so the compiler may put the
     one's `q . k^T` beside the other's `p . v`); in a row's LAST chunk, up
     to the sub-tile that holds `pos` and no further, so that no arithmetic
-    is done on dead tokens whatever the chunk's length."""
+    is done on dead tokens whatever the chunk's length. Where `pos` is a
+    position a ROW (the sparse walk's K/V heads may end apart), `newest` is
+    the largest of them."""
     if chunk <= _SUB:
         return _softmax_step(*tile(slice(None)), c, pos, carry)
     n_sub = chunk // _SUB
-    last = (pos - c * chunk) // _SUB        # the sub-tile that holds `pos`
+    # the sub-tile that holds `pos`
+    last = ((pos if newest is None else newest) - c * chunk) // _SUB
 
     def whole(carry):
         nxt = tile(pl.ds(0, _SUB))
@@ -584,40 +631,185 @@ def _kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm, v_hbm,
 
 def _gqa_kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm,
                 v_hbm, o_ref, kbuf, vbuf, sems, done_ref, *, kv_heads: int,
-                scale: float, block_size: int, lane_groups: int = 1):
+                scale: float, block_size: int):
+    def walk(setup, step):
+        return _walk(layer_ref, tables_ref, pos_ref, lead_ref,
+                     (k_hbm, v_hbm), (kbuf, vbuf), sems, done_ref, (vbuf,),
+                     setup, step, block_size)
+
+    _gqa_body(q_ref, o_ref, kbuf, vbuf, walk,
+              lambda: pos_ref[pl.program_id(0)], kv_heads, scale)
+
+
+def _gqa_body(q_ref, o_ref, kbuf, vbuf, walk, newest, kv_heads: int,
+              scale: float):
+    """A slot's grouped-query attention over what `walk(setup, step)`
+    brings into `kbuf` / `vbuf` `[2, chunk, kv_heads * D]`: the query block
+    `[heads, kv_heads * D]`, row h in its K/V head's lanes. `newest()` is
+    the position of the slot's newest token, or one a K/V head (a tuple:
+    `_sparse_kernel`'s lists may end apart; under 0, a head that reads
+    nothing and gets zeros)."""
     heads, head_dim = q_ref.shape
     width = kbuf.shape[2]               # kv_heads * head_dim
     group = heads // kv_heads
 
     def setup():
-        pos = pos_ref[pl.program_id(0)]
+        pos, last = newest(), None
         row = lax.broadcasted_iota(jnp.int32, (heads, width), 0)
         col = lax.broadcasted_iota(jnp.int32, (heads, width), 1)
         own = col // head_dim == row // group
+        if isinstance(pos, tuple):
+            last = functools.reduce(jnp.maximum, pos)
+            pos = functools.reduce(
+                lambda rest, g: jnp.where(row[:, :1] // group == g, pos[g],
+                                          rest), range(1, kv_heads), pos[0])
+            own = own & (pos >= 0)
         q = jnp.concatenate([q_ref[...]] * kv_heads, axis=1)
         q = jnp.where(own, q, jnp.zeros_like(q))
-        return (pos, q, own), _softmax_init(heads, width)
+        return (pos, q, own, last), _softmax_init(heads, width)
 
     def step(query, c, buf, carry):
-        pos, q, _ = query
+        pos, q, _, last = query
 
         def tile(rows):
             sc = lax.dot_general(q, kbuf[buf, rows], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
             return sc, vbuf[buf, rows]
 
-        return _softmax_chunk(tile, kbuf.shape[1], c, pos, carry)
+        return _softmax_chunk(tile, kbuf.shape[1], c, pos, carry, last)
 
-    (_, _, own), (m, l, acc) = _walk(
-        layer_ref, tables_ref, pos_ref, lead_ref, (k_hbm, v_hbm),
-        (kbuf, vbuf), sems, done_ref, (vbuf,), setup, step, block_size,
-        lane_groups)
+    (_, _, own, _), (m, l, acc) = walk(setup, step)
     # row h keeps its own K/V head's lanes; an inactive slot gives zeros
     ctx = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
     out = ctx[:, :head_dim]
     for g in range(1, kv_heads):
         out = out + ctx[:, g * head_dim:(g + 1) * head_dim]
     o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _sparse_kernel(layer_ref, tables_ref, pos_ref, runs_ref, shared_ref,
+                   q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, done_ref, *,
+                   kv_heads: int, scale: float, block_size: int):
+    """One grid step: slot `program_id(0)`'s K/V heads TOGETHER, each over
+    the list of blocks of its own (`PairLists`: rows `s * kv_heads + g` of
+    the tables). The buffers are a pool's full lanes wide, and row t of
+    one holds, a K/V head in its own lanes, the t-th token of THAT head's
+    list: an entry that all the lists share (the first `shared_ref[s, 0]`
+    and the last `shared_ref[s, 1]` of the live ones) is fetched once at
+    full width, a block one contiguous span and a run of them one copy;
+    the others a head at a time, its lanes of the pool into its lanes of
+    the buffer. The step is `paged_gqa_attention`'s on what lies there:
+    one block-diagonal product scores all query heads, each against its
+    own head's tokens."""
+    s, n_slots = pl.program_id(0), pl.num_programs(0)
+    bs, layer = block_size, layer_ref[0]
+    pools, bufs = (k_hbm, v_hbm), (kbuf, vbuf)
+    per_chunk = kbuf.shape[1] // bs
+    width = tables_ref.shape[1]
+    sizes = [1 << i for i in reversed(range(per_chunk.bit_length()))]
+
+    def seen(slot, g):
+        """The newest token's index in the list of K/V head `g`: under 0
+        for a list that starts with the null block."""
+        pair = slot * kv_heads + g
+        return jnp.where(tables_ref[pair, 0] == 0, -1, pos_ref[pair])
+
+    def live(slot, g):
+        return jnp.minimum(seen(slot, g) // bs + 1, width)
+
+    def longest(slot):
+        return functools.reduce(jnp.maximum,
+                                [live(slot, g) for g in range(kv_heads)])
+
+    def each_copy(slot, c, buf, act):
+        """Chunk `c` of `slot`'s lists into buffer `buf`. "start": the
+        shared entries at the lists' start by their runs of consecutive
+        ids (`runs_ref`: from every entry on; a run in the binary pieces
+        of its count, the largest first), every head's own picks a block
+        at a time, the shared entries at the lists' end by their runs.
+        "wait": a copy's semaphore counts BYTES, so the chunk's are waited
+        for in a few waits of static sizes that sum to what was started,
+        whole chunks of one head's lanes first and the rest in the binary
+        pieces of its count, and no list is read a second time (a turn of
+        a scalar loop costs some 90 cycles, as long as the 16 KB it would
+        wait for take to arrive)."""
+        first = c * per_chunk
+        top = longest(slot)
+        lead, trail = shared_ref[slot, 0], shared_ref[slot, 1]
+        a = jnp.clip(lead - first, 0, per_chunk)
+        b = jnp.clip(top - trail - first, a, per_chunk)
+        end = jnp.clip(top - first, 0, per_chunk)
+        ends = [jnp.clip(live(slot, g) - trail - first, a, per_chunk)
+                for g in range(kv_heads)]
+        if act == "wait":
+            # in units of one head's lanes of a block
+            units = kv_heads * (a + end - b) + sum(e - a for e in ends)
+
+            def wait(rows):
+                for which, dst in enumerate(bufs):
+                    to = dst.at[buf, pl.ds(0, rows),
+                                pl.ds(0, dst.shape[2] // kv_heads)]
+                    pltpu.make_async_copy(to, to, sems.at[buf, which]).wait()
+
+            for i in range(kv_heads):
+                pl.when(units >= (i + 1) * per_chunk)(
+                    functools.partial(wait, per_chunk * bs))
+            for k in sizes[1:]:
+                pl.when((units % per_chunk) & k != 0)(
+                    functools.partial(wait, k * bs))
+            return
+
+        def copy(pair, j, k, g=None):
+            """`k` blocks from entry `j` of list `pair` on, one span of the
+            pools' rows: all lanes, or K/V head `g`'s into its own."""
+            blk = tables_ref[pair, first + j]
+            src = pl.ds(pl.multiple_of(blk * bs, bs), k * bs)
+            rows = pl.ds(pl.multiple_of(j * bs, bs), k * bs)
+            for which, (hbm, dst) in enumerate(zip(pools, bufs)):
+                if g is None:
+                    from_, to = hbm.at[layer, src], dst.at[buf, rows]
+                else:
+                    lanes = dst.shape[2] // kv_heads
+                    own = pl.ds(g * lanes, lanes)
+                    from_ = hbm.at[layer, src, own]
+                    to = dst.at[buf, rows, own]
+                pltpu.make_async_copy(from_, to, sems.at[buf, which]).start()
+
+        def shared(x, y):
+            """Entries `x .. y - 1` of the chunk, the same in every list,
+            by their runs."""
+            pair = slot * kv_heads
+
+            def in_pieces(j, r):
+                for k in sizes:
+                    @pl.when(r & k != 0)
+                    def _(k=k):         # larger pieces lie before
+                        copy(pair, j + (r & -(2 * k)), k)
+
+            def run(j):
+                r = jnp.minimum(runs_ref[slot, first + j], y - j)
+                lax.cond(r == 1, lambda: copy(pair, j, 1),
+                         functools.partial(in_pieces, j, r))
+                return j + r
+
+            lax.while_loop(lambda j: j < y, run, x)
+
+        shared(0, a)
+        # a head's own picks go a block at a time: they seldom follow each
+        # other, and a loop that asks costs every pick a branch
+        for g in range(kv_heads):
+            lax.fori_loop(a, ends[g], lambda j, _, g=g: copy(
+                slot * kv_heads + g, j, 1, g), None)
+        shared(b, end)
+
+    def walk(setup, step):
+        return _through_chunks(
+            s, n_slots, lambda slot: (longest(slot) + per_chunk - 1)
+            // per_chunk, each_copy, done_ref, (kbuf, vbuf), setup, step)
+
+    _gqa_body(q_ref, o_ref, kbuf, vbuf, walk,
+              lambda: tuple(seen(s, g) for g in range(kv_heads)), kv_heads,
+              scale)
 
 
 def _latent_kernel(layer_ref, tables_ref, pos_ref, lead_ref, ql_ref, qr_ref,
@@ -771,16 +963,15 @@ def _call_form(kernel, layer, block_tables, positions, *pools):
         p.reshape(p.shape[0], -1, p.shape[3]) for p in pools)
 
 
-def _scratch(k_pool, v_pool, max_blocks: int, lane_groups: int = 1):
-    """The walk's double buffers under tables `max_blocks` wide, a chunk of
-    a pool's lanes each (of ONE lane group's share of them, where a copy
-    takes no more)."""
-    chunk = chunk_tokens(_token_bytes(k_pool, v_pool),
-                         max_blocks * k_pool.shape[2])
-    return [pltpu.VMEM((2, chunk, k_pool.shape[3] // lane_groups),
-                       k_pool.dtype),
-            pltpu.VMEM((2, chunk, v_pool.shape[3] // lane_groups),
-                       v_pool.dtype),
+def _scratch(k_pool, v_pool, max_blocks: int, chunk: Optional[int] = None):
+    """The walk's double buffers under tables `max_blocks` wide, a chunk
+    (`chunk_tokens`, unless the route has a number of its own) of a pool's
+    lanes each."""
+    if chunk is None:
+        chunk = chunk_tokens(_token_bytes(k_pool, v_pool),
+                             max_blocks * k_pool.shape[2])
+    return [pltpu.VMEM((2, chunk, k_pool.shape[3]), k_pool.dtype),
+            pltpu.VMEM((2, chunk, v_pool.shape[3]), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32)]
 
@@ -877,10 +1068,87 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return out.reshape(n_slots, heads * head_dim)
 
 
+class PairLists(NamedTuple):
+    """What the sparse walk reads of a step's (slot, K/V head) pairs
+    (`pair_lists`): `ids` `[S * kv_heads, W]`, pair `s * kv_heads + g`'s
+    blocks in the order its tokens count; `newest` `[S * kv_heads]`, the
+    index in that list of the pair's newest token; `shared` `[S, 2]`, how
+    many of a slot's live entries from the first on, and how many up to
+    the last, are the same in every one of its lists: those the walk
+    fetches once for all K/V heads, a block's lanes whole; `runs` `[S, W]`,
+    of every entry of a slot's FIRST list (which the shared entries are
+    read from) how many ids from it on follow each other (1 at least; the
+    walk takes that many shared blocks in the binary pieces of their
+    count, and never searches a list itself)."""
+
+    ids: jax.Array
+    newest: jax.Array
+    shared: jax.Array
+    runs: jax.Array
+
+
+def pair_lists(tables: jax.Array, positions: jax.Array, kv_heads: int,
+               block_size: int) -> PairLists:
+    """`tables` `[S * kv_heads, W]` and `positions` `[S * kv_heads]`
+    (`paged_sparse_attention`) with what its walk goes by counted: a
+    compare of neighbours for the runs (the lists are there: no search of
+    the block tables), a compare of a slot's lists for the entries they
+    share. Lists of different live lengths share no entry at their ends,
+    nor does a list that starts with the null block share any."""
+    ids = tables.astype(jnp.int32)
+    width = ids.shape[1]
+    at = jnp.arange(width, dtype=jnp.int32)
+    lists = ids.reshape(-1, kv_heads, width)
+    first = lists[:, 0]
+    starts = jnp.pad(first[:, 1:] != first[:, :-1] + 1, ((0, 0), (1, 0)),
+                     constant_values=True)
+    runs = _next_after(starts, width) - at[None, :]
+    live = jnp.where(ids[:, 0] == 0, 0, jnp.minimum(
+        positions.astype(jnp.int32) // block_size + 1, width))
+    live = live.reshape(-1, kv_heads)
+    least, top = jnp.min(live, axis=1), jnp.max(live, axis=1)
+    same = jnp.all(lists == lists[:, :1], axis=1)               # [S, W]
+    # the first live entry that differs, and the last: two reductions
+    lead = jnp.min(jnp.where(same & (at < least[:, None]), width, at),
+                   axis=1)
+    differs = jnp.max(jnp.where(same | (at >= top[:, None]), -1, at), axis=1)
+    trail = jnp.where(least == top, top - 1 - differs, 0)
+    trail = jnp.clip(trail, 0, top - lead)
+    return PairLists(ids, positions.astype(jnp.int32),
+                     jnp.stack([lead, trail], axis=1), runs)
+
+
+# the sparse walk's chunk: a LIST's tokens (a few thousand: `topk` blocks)
+# are scattered blocks and a window's run, and its chunk is its own number,
+# `sparse_chunk_tokens`: the longest of these that a row's usual list fills
+# whole and whose double buffers stay under `_SPARSE_VMEM_BYTES`
+_SPARSE_CHUNKS = (4096, 2048, 1024)
+_SPARSE_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def sparse_chunk_tokens(token_bytes: int, list_tokens: int) -> int:
+    """Tokens the sparse walk fetches a chunk, from static shapes alone:
+    what a token stores in both pools (`token_bytes`, all K/V heads: a
+    chunk's buffers are a pool's lanes wide) and the tokens of a sparse
+    row's list (`list_tokens`: `topk` selection blocks; the table's width
+    where the caller names none). The STEP goes through a chunk in
+    sub-tiles of `_SUB` (`_softmax_chunk`), all of a chunk's copies are
+    started at once and the next chunk's (the next slot's) are in flight
+    meanwhile; a chunk longer than the list would fetch nothing more and
+    hold VMEM for it. This is the LISTS' number: the allocator and the
+    other walks go by `chunk_tokens`."""
+    for chunk in _SPARSE_CHUNKS[:-1]:
+        if chunk <= list_tokens \
+                and 2 * chunk * token_bytes <= _SPARSE_VMEM_BYTES:
+            return chunk
+    return _SPARSE_CHUNKS[-1]
+
+
 def paged_sparse_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, layer: jax.Array,
-                           tables: jax.Array, positions: jax.Array, *,
+                           tables, positions: Optional[jax.Array] = None, *,
                            heads: int, kv_heads: int,
+                           list_tokens: Optional[int] = None,
                            interpret: bool = False) -> jax.Array:
     """Grouped-query decode attention in which every (slot, K/V head) reads
     a list of blocks OF ITS OWN: q `[S, heads*D]` against layer `layer` of
@@ -889,42 +1157,61 @@ def paged_sparse_attention(q: jax.Array, k_pool: jax.Array,
     pair reads, in the order its tokens count, and `positions`
     `[S*kv_heads]` the index IN THAT LIST of the pair's newest token (the
     tokens `0..positions` of the list are attended; the blocks before the
-    last are whole). A block-sparse layer hands over the blocks each K/V
-    head selected; a row that reads everything its own table for every
-    head. The walk is `paged_gqa_attention`'s (`_walk`: runs of
-    consecutive ids in one copy) over ONE K/V head's lanes of the pools,
-    which is all a copy takes; a pair whose list starts with the null
-    block gets zeros. -> `[S, heads*D]` in q's dtype."""
+    last are whole); or `tables` is `PairLists` (`pair_lists`: the same
+    with its runs and shared entries counted). A block-sparse layer hands
+    over the blocks each K/V head selected; a row that reads everything
+    its own table for every head. One grid step a SLOT (`_sparse_kernel`):
+    what a slot's lists share goes once for all heads at a block's full
+    lanes, the rest one K/V head's lanes a copy, runs of consecutive ids
+    in one copy either way, `sparse_chunk_tokens` (of `list_tokens`, a
+    sparse row's list; static) a chunk; a pair whose list starts with the
+    null block gets zeros. -> `[S, heads*D]` in q's dtype."""
+    bs = k_pool.shape[2]
+    lists = tables if isinstance(tables, PairLists) \
+        else pair_lists(tables, positions, kv_heads, bs)
+    chunk = sparse_chunk_tokens(_token_bytes(k_pool, v_pool),
+                                list_tokens or lists.ids.shape[1] * bs)
+    WALK_CHUNKS["paged_sparse"] = chunk
+    # jitted, so that a model's sparse layers, which make the same call,
+    # share ONE trace of the kernel and one lowering (the interpreter's
+    # parameters are no static argument: tests call it as it is)
+    call = _sparse_call if interpret else _sparse_call_jitted
+    return call(q, k_pool, v_pool, layer, lists, heads, kv_heads, chunk,
+                interpret)
+
+
+def _sparse_call(q, k_pool, v_pool, layer, lists: PairLists, heads: int,
+                 kv_heads: int, chunk: int, interpret):
     n_slots = q.shape[0]
+    bs = k_pool.shape[2]
     head_dim = k_pool.shape[3] // kv_heads
-    group = heads // kv_heads
-    per_pair = lambda s, *_: (s, 0, 0)      # noqa: E731
-    kernel, scalars, pools = _call_form(
-        functools.partial(_gqa_kernel, kv_heads=1,
-                          scale=1.0 / math.sqrt(head_dim),
-                          block_size=k_pool.shape[2], lane_groups=kv_heads),
-        layer, tables, positions, k_pool, v_pool)
+    per_slot = lambda s, *_: (s, 0, 0)      # noqa: E731
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_sparse_kernel, kv_heads=kv_heads,
+                          scale=1.0 / math.sqrt(head_dim), block_size=bs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
-            grid=(n_slots * kv_heads,),
+            num_scalar_prefetch=5,
+            grid=(n_slots,),
             in_specs=[
-                pl.BlockSpec((None, group, head_dim), per_pair),
+                pl.BlockSpec((None, heads, head_dim), per_slot),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((None, group, head_dim), per_pair),
-            scratch_shapes=_scratch(k_pool, v_pool, scalars[1].shape[1],
-                                    kv_heads)),
-        out_shape=jax.ShapeDtypeStruct((n_slots * kv_heads, group, head_dim),
-                                       q.dtype),
+            out_specs=pl.BlockSpec((None, heads, head_dim), per_slot),
+            scratch_shapes=_scratch(k_pool, v_pool, lists.ids.shape[1],
+                                    chunk)),
+        out_shape=jax.ShapeDtypeStruct((n_slots, heads, head_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_sparse_attention",
-    )(*scalars, q.reshape(n_slots * kv_heads, group, head_dim), *pools)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lists.ids, lists.newest,
+      lists.runs, lists.shared, q.reshape(n_slots, heads, head_dim),
+      *(p.reshape(p.shape[0], -1, p.shape[3]) for p in (k_pool, v_pool)))
     return out.reshape(n_slots, heads * head_dim)
+
+
+_sparse_call_jitted = jax.jit(_sparse_call, static_argnums=(5, 6, 7, 8))
 
 
 def paged_latent_attention(q_latent: jax.Array, q_rope: jax.Array,

@@ -1324,8 +1324,10 @@ class DecodeEngine:
             "model": self._model.describe(),
             # which route decode attention took, a count a traced decode
             # program of this process ("paged", "paged_latent": a kernel
-            # over the live blocks; "gather": the padded gather)
-            "decode_attention": dict(_paged_attention.GATE_COUNTS),
+            # over the live blocks; "gather": the padded gather), and the
+            # tokens a chunk of a walk that has a number of its own
+            # ("paged_sparse_chunk_tokens")
+            "decode_attention": _paged_attention.gate_report(),
             # which route the expert layers' grouped matmuls took, a count
             # a traced call ("megablox": the kernel; "xla": `ragged_dot`),
             # and the kernel's tiles by the matrix it read, which

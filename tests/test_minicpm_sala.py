@@ -74,7 +74,8 @@ FAMILY = Family(
            _prompts(7, (60, 62)), 40),
     # a short sequence alone: a dense row or none, nothing selected
     counters={"sparse_rows": (0, 0), "dense_tokens": (0, 16),
-              "blocks_selected": (0, 0), "kc_entries": (0, 0)},
+              "blocks_selected": (0, 0), "shared_entries": (0, 2),
+              "kc_entries": (0, 0)},
     scopes=frozenset({"ssm_in", "scan", "ssm_out", "kc_write"}),
     stepping=frozenset({"state_read", "state_write", "select"}))
 
@@ -95,6 +96,15 @@ class TestContract(ServeContract):
             assert f["sparse_rows"] == 1 and f["blocks_selected"] == 4.0 \
                 and f["dense_tokens"] == 0
         assert facts[-1]["kc_entries"] == FAMILY.total // 4 - 1
+        # what a slot's K/V heads read whatever they score: a dense row
+        # its whole list (41 and 48 tokens: 3 blocks of 16), a sparse row
+        # the first block and those of the newest 32 tokens, 3 of them or,
+        # at a block's end (96 tokens), 2: all 4 taken, or 3 of the 4
+        assert [f["shared_entries"] for f in facts] == [3, 3, 4, 4]
+        at_a_blocks_end = sm.step_facts(jax.device_get(
+            programs.served("whole", n, 96).stats))
+        assert at_a_blocks_end["shared_entries"] == 3 \
+            and at_a_blocks_end["blocks_selected"] == 4.0
 
     def test_prefill_and_decode_write_the_same_compressed_keys(self,
                                                                programs):

@@ -20,51 +20,242 @@ from paddle_tpu.ops.pallas import paged_attention as PA
 from paddle_tpu.serving import kv_cache as kvc
 
 
-@pytest.mark.parametrize("bs", [16, 64])
-def test_the_sparse_walk_reads_each_pairs_own_blocks(bs):
-    """(slot, K/V head) pairs with lists of their own: a sparse pair's
-    scattered blocks with a partial newest one, a pair that reads its whole
-    table, a run of consecutive ids, an inactive slot: against plain
-    attention over the tokens the lists name, one K/V head's lanes at a
-    time."""
-    L, S, G, R, D = 2, 3, 2, 16, 128
-    NB, width = 40, 12
-    rng = np.random.default_rng(bs)
-    k_pool = jnp.asarray(rng.normal(size=(L, NB, bs, G * D)), jnp.bfloat16)
-    v_pool = jnp.asarray(rng.normal(size=(L, NB, bs, G * D)), jnp.bfloat16)
+def _walk_case(tables, newest, *, bs, S, G=2, R=16, D=128, NB=None, seed=0,
+               list_tokens=None, layer=1):
+    """`paged_sparse_attention` (the Pallas TPU interpreter) over the pairs'
+    lists `tables` `[S * G, W]` / `newest` `[S * G]`, each pair against
+    plain attention over the tokens ITS list names, one K/V head's lanes
+    at a time; a pair whose list starts with the null block gets zeros.
+    -> the result `[S, G, R, D]` float32."""
+    tables = np.asarray(tables, np.int32)
+    newest = np.asarray(newest, np.int32)
+    NB = NB or int(tables.max()) + 2
+    rng = np.random.default_rng(seed)
+    k_pool = jnp.asarray(rng.normal(size=(2, NB, bs, G * D)), jnp.bfloat16)
+    v_pool = jnp.asarray(rng.normal(size=(2, NB, bs, G * D)), jnp.bfloat16)
     q = jnp.asarray(rng.normal(size=(S, G * R * D)), jnp.bfloat16)
-    tables = np.zeros((S * G, width), np.int32)
-    newest = np.zeros((S * G,), np.int32)
-    tables[0, :5] = [7, 3, 30, 31, 32]          # scattered, then a run
-    newest[0] = 4 * bs + bs // 2
-    tables[1, :12] = np.arange(20, 32)          # one run, all of the width
-    newest[1] = 12 * bs - 1
-    tables[2, :1] = [9]                         # one token
-    newest[2] = 0
-    tables[3, :3] = [2, 39, 1]
-    newest[3] = 2 * bs + 3
-    # pairs 4 and 5: an inactive slot
     got = PA.paged_sparse_attention(
-        q, k_pool, v_pool, jnp.int32(1), jnp.asarray(tables),
+        q, k_pool, v_pool, jnp.int32(layer), jnp.asarray(tables),
         jnp.asarray(newest), heads=G * R, kv_heads=G,
-        interpret=pltpu.InterpretParams())
+        list_tokens=list_tokens, interpret=pltpu.InterpretParams())
     got = np.asarray(got, np.float32).reshape(S, G, R, D)
     qf = np.asarray(q, np.float32).reshape(S, G, R, D)
+    kf = np.asarray(k_pool, np.float32)[layer]
+    vf = np.asarray(v_pool, np.float32)[layer]
     for pair in range(S * G):
         s, g = divmod(pair, G)
         if not tables[pair, 0]:
-            assert not got[s, g].any()
+            assert not got[s, g].any(), pair
             continue
         n = newest[pair] + 1
         blocks = tables[pair, :-(-n // bs)]
-        keys = np.asarray(k_pool, np.float32)[1, blocks].reshape(
-            -1, G, D)[:n, g]
-        vals = np.asarray(v_pool, np.float32)[1, blocks].reshape(
-            -1, G, D)[:n, g]
+        keys = kf[blocks].reshape(-1, G, D)[:n, g]
+        vals = vf[blocks].reshape(-1, G, D)[:n, g]
         sc = qf[s, g] @ keys.T / math.sqrt(D)
         w = np.exp(sc - sc.max(-1, keepdims=True))
         want = (w / w.sum(-1, keepdims=True)) @ vals
-        np.testing.assert_allclose(got[s, g], want, atol=3e-2, rtol=3e-2)
+        np.testing.assert_allclose(got[s, g], want, atol=3e-2, rtol=3e-2,
+                                   err_msg=f"pair {pair}")
+    return got
+
+
+def _sparse_lists(rng, lead, trail, live=64, nb=400, window=None):
+    """Two heads' lists of `live` entries that agree in their first `lead`
+    and last `trail` entries and in nothing between: scattered picks, then
+    the window's blocks (`window`: `(first id, count)` runs; one run of
+    `trail` ids where None)."""
+    if window is None:
+        window = [(nb - trail, trail)] if trail else []
+    tail = np.concatenate([np.arange(a, a + k) for a, k in window]) \
+        if window else np.zeros((0,), np.int64)
+    assert len(tail) == trail
+    free = np.arange(1, nb - trail - 40)
+    head = np.sort(rng.choice(free[::3], lead, replace=False))
+    lists = []
+    for g in range(2):
+        # picks of a head's own: of the ids no other list holds
+        own = np.sort(rng.choice(free[1 + g::3], live - lead - trail,
+                                 replace=False))
+        lists.append(np.concatenate([head, own, tail]))
+    return lists
+
+
+def _tables_of(lists, width):
+    tables = np.zeros((len(lists), width), np.int32)
+    for pair, ids in enumerate(lists):
+        tables[pair, :len(ids)] = ids
+    return tables
+
+
+# (slot, K/V head) pairs' lists: name -> (bs) -> (lists a pair, newest a
+# pair, list_tokens); 128 entries wide like the cell's
+_WALK_CASES = {
+    # the first cases this file had: a sparse pair's scattered blocks with
+    # a partial newest one beside a pair that reads a run of all its width,
+    # one token, an inactive slot: heads that END APART
+    "lists_that_end_apart": lambda bs, rng: (
+        [[7, 3, 30, 31, 32], np.arange(20, 32), [9], [2, 39, 1], [], []],
+        [4 * bs + bs // 2, 12 * bs - 1, 0, 2 * bs + 3, 0, 0], None),
+    # what a step of the cell hands over: entry 0 and the window's 33
+    # blocks the same in both lists, one run; 32 where the position falls
+    # at a block's end
+    "shared_1_and_33": lambda bs, rng: (
+        _sparse_lists(rng, 1, 33) + _sparse_lists(rng, 1, 32),
+        [63 * bs + 5] * 2 + [64 * bs - 1] * 2, 64 * bs),
+    "shared_nothing": lambda bs, rng: (
+        _sparse_lists(rng, 0, 0) + _sparse_lists(rng, 0, 0, live=20),
+        [63 * bs + bs // 2 + 1] * 2 + [19 * bs] * 2, 64 * bs),
+    "shared_1_and_1": lambda bs, rng: (
+        _sparse_lists(rng, 1, 1), [63 * bs + bs - 2] * 2, 64 * bs),
+    "shared_5_and_0": lambda bs, rng: (
+        _sparse_lists(rng, 5, 0), [63 * bs + 1] * 2, 64 * bs),
+    # the window's run broken in two: a prompt's tail and its growth
+    "window_in_two_runs": lambda bs, rng: (
+        _sparse_lists(rng, 1, 33, window=[(300, 20), (350, 13)])
+        + _sparse_lists(rng, 1, 33, window=[(380, 1), (310, 32)]),
+        [63 * bs + 9] * 2 + [63 * bs] * 2, 64 * bs),
+    # no two ids follow each other anywhere, shared or not
+    "no_run_at_all": lambda bs, rng: (
+        (lambda same, a, b: [np.concatenate([same[:1], a, same[1:]]),
+                             np.concatenate([same[:1], b, same[1:]])])(
+            np.arange(2, 70, 2), np.arange(101, 161, 2),
+            np.arange(201, 261, 2)),
+        [63 * bs + 3] * 2, 64 * bs),
+    # rows that read their own table for both heads: at `dense_len`
+    # exactly (all of the width) and under it; every entry is shared
+    "dense_rows": lambda bs, rng: (
+        [np.arange(10, 138)] * 2 + [np.r_[200:230, 150:171]] * 2,
+        [128 * bs - 1] * 2 + [50 * bs + 7] * 2, 64 * bs),
+    # one K/V head's list live and the other's null, either way round
+    "one_head_null": lambda bs, rng: (
+        [_sparse_lists(rng, 1, 33)[0], [], [],
+         _sparse_lists(rng, 1, 33)[1]],
+        [63 * bs + bs - 3, 0, 0, 62 * bs + 4], 64 * bs),
+}
+
+
+@pytest.mark.parametrize("case,bs", [
+    (case, bs) for case in _WALK_CASES for bs in (16, 64)
+    # elsewhere the block's size changes nothing
+    if bs == 16 or case in ("lists_that_end_apart", "shared_1_and_33",
+                            "dense_rows")])
+def test_the_sparse_walk_reads_each_pairs_own_blocks(case, bs):
+    """A slot's K/V heads together, each over the list of ITS blocks:
+    lists that agree in a prefix and a suffix of several lengths (nothing,
+    one entry, the cell's 1 and 33, everything: what agrees is fetched
+    once for both heads), a window in two runs, lists without a run, rows
+    that read their own table, a head that reads nothing beside one that
+    does, lists that end apart; against plain attention over the tokens
+    the lists name."""
+    lists, newest, list_tokens = _WALK_CASES[case](
+        bs, np.random.default_rng(len(case)))
+    width = 12 if case == "lists_that_end_apart" else 128
+    tables = _tables_of(lists, width)
+    shared = np.asarray(PA.pair_lists(
+        jnp.asarray(tables), jnp.asarray(newest, jnp.int32), 2, bs).shared)
+    want = {"shared_1_and_33": [[1, 33], [1, 32]],
+            "shared_nothing": [[0, 0], [0, 0]],
+            "shared_1_and_1": [[1, 1]], "shared_5_and_0": [[5, 0]],
+            "window_in_two_runs": [[1, 33], [1, 33]],
+            "no_run_at_all": [[1, 33]],
+            "dense_rows": [[128, 0], [51, 0]],
+            "one_head_null": [[0, 0], [0, 0]]}.get(case)
+    if want is not None:
+        assert shared.tolist() == want
+    _walk_case(tables, newest, bs=bs, S=len(lists) // 2,
+               list_tokens=list_tokens, seed=bs)
+
+
+@pytest.mark.parametrize("list_tokens,chunk", [(1024, 1024), (2048, 2048),
+                                               (3000, 2048), (4096, 4096),
+                                               (None, 4096)])
+def test_the_sparse_walk_at_every_chunk_it_can_pick(list_tokens, chunk):
+    """`sparse_chunk_tokens`: the longest of 1024 / 2048 / 4096 tokens that
+    a sparse row's list (`list_tokens`; the table's width where none is
+    named) fills; lists of 83 and of 64 blocks of 64 tokens are then 6 /
+    3 / 2 chunks, the last one partly live, the shared entries across the
+    cuts; noted for `status()["decode_attention"]`."""
+    bs = 64
+    assert PA.sparse_chunk_tokens(1024, list_tokens or 128 * bs) == chunk
+    rng = np.random.default_rng(chunk)
+    lists = _sparse_lists(rng, 3, 40, live=83) + _sparse_lists(rng, 1, 33)
+    PA.GATE_COUNTS.clear()
+    _walk_case(_tables_of(lists, 128), [82 * bs + 11] * 2 + [63 * bs + 60] * 2,
+               bs=bs, S=2, list_tokens=list_tokens, seed=chunk)
+    assert PA.WALK_CHUNKS["paged_sparse"] == chunk
+    assert PA.gate_report() == {}
+    PA.GATE_COUNTS["paged_sparse"] += 1
+    assert PA.gate_report() == {"paged_sparse": 1,
+                                "paged_sparse_chunk_tokens": chunk}
+    PA.GATE_COUNTS.clear()
+
+
+def test_a_slots_result_does_not_depend_on_its_neighbours():
+    """`ServeModel`'s contract through the walk: slot 1's lists and query
+    stay, the slots around it change (other lists, other lengths, an idle
+    slot, other shared counts), and slot 1's result is the same BITS."""
+    bs, S = 16, 3
+    rng = np.random.default_rng(5)
+    mine = _sparse_lists(rng, 1, 33)
+    batches = [
+        (_sparse_lists(rng, 1, 32) + mine + _sparse_lists(rng, 0, 0),
+         [64 * bs - 1] * 2 + [63 * bs + 5] * 2 + [63 * bs + 2] * 2),
+        ([[], []] + mine + [np.arange(100, 228)] * 2,
+         [0, 0] + [63 * bs + 5] * 2 + [128 * bs - 1] * 2),
+        (_sparse_lists(rng, 9, 2, live=30) + mine + [[5], []],
+         [29 * bs + 3] * 2 + [63 * bs + 5] * 2 + [0, 0])]
+    got = [_walk_case(_tables_of(lists, 128), newest, bs=bs, S=S, NB=420,
+                      list_tokens=64 * bs, seed=11)[1]
+           for lists, newest in batches]
+    assert (got[0] == got[1]).all() and (got[0] == got[2]).all()
+
+
+def test_the_lists_runs_and_shared_entries_are_counted_by_compares():
+    """`pair_lists` against a count by hand: of every entry of a slot's
+    first list how many ids from it on follow each other; of a slot's live
+    entries how many from
+    the first on and how many up to the last are the same in both lists
+    (none at the ends of lists that end apart, none for a null list; a
+    list that is the same all through counts once, from the front)."""
+    bs = 16
+    lists = [[5, 6, 7, 20, 40, 41, 42, 43], [5, 6, 9, 21, 40, 41, 42, 43],
+             [5, 6, 7, 8], [5, 6, 7, 8],
+             [5, 6, 7, 8, 9], [5, 6, 7, 8],
+             [], [3, 4],
+             [9, 8, 7, 30], [9, 1, 2, 30]]
+    newest = [8 * bs - 1, 7 * bs + 2, 3 * bs, 3 * bs + 5, 4 * bs, 4 * bs - 1,
+              0, bs, 4 * bs - 1, 4 * bs - 1]
+    got = PA.pair_lists(jnp.asarray(_tables_of(lists, 10)),
+                        jnp.asarray(newest, jnp.int32), 2, bs)
+    assert np.asarray(got.shared).tolist() == [
+        [2, 4], [4, 0], [4, 0], [0, 0], [1, 1]]
+    runs = np.asarray(got.runs)         # of a slot's first list
+    assert runs.shape == (5, 10)
+    assert runs[0].tolist() == [3, 2, 1, 1, 4, 3, 2, 1, 1, 1]
+    assert runs[4].tolist() == [1, 1, 1, 1, 1, 1, 1, 1, 1, 1]
+    assert runs[2, :5].tolist() == [5, 4, 3, 2, 1]
+    assert (np.asarray(got.ids) == _tables_of(lists, 10)).all()
+
+
+# (the cache's token bytes in both pools, its table's tokens, its block):
+# the other serve cells' and this cell's own allocator's; what
+# `chunk_tokens` / `blocks_per_chunk` gave before the sparse walk had a
+# number of its own, which the allocator's `per_chunk` and the three other
+# walks still go by
+@pytest.mark.parametrize("cell,token_bytes,table,bs,chunk,per_chunk", [
+    ("gpt2_large", 2 * 1280 * 2, 1024, 16, 256, 16),
+    ("olmoe_1b_7b", 2 * 2048 * 2, 1024, 16, 256, 16),
+    ("nemotron3_nano.reason_closed", 2 * 256 * 2, 2560, 16, 512, 32),
+    ("nemotron3_nano.doc_sessions", 2 * 256 * 2, 9216, 16, 512, 32),
+    ("jamba2_3b", 2 * 128 * 2, 1024, 16, 512, 32),
+    ("joyai_llm_flash", (512 + 128) * 2, 4608, 16, 512, 32),
+    ("xing4_29b_a4b", (512 + 128) * 2, 20480, 16, 1024, 64),
+    ("longcat_flash_chat", (512 + 128) * 2, 1024, 16, 512, 32),
+    ("minicpm_sala", 2 * 256 * 2, 49152, 64, 1024, 16)])
+def test_the_other_walks_chunks_are_what_they_were(cell, token_bytes, table,
+                                                   bs, chunk, per_chunk):
+    assert PA.chunk_tokens(token_bytes, table) == chunk
+    assert PA.blocks_per_chunk(bs, token_bytes, table) == per_chunk
 
 
 def _runs(*spans):

@@ -1106,9 +1106,15 @@ def test_minicpm_sala_serve_program_fits_whatever_the_prompts_length(
 
 
 def test_the_sparse_walk_compiles_for_v5e(v5e, monkeypatch):
-    """The block-sparse walk alone: a copy takes ONE K/V head's 128 lanes
-    of a block's 256, by a static lane slice a branch, which the
-    interpreter cannot refuse and Mosaic can."""
+    """The block-sparse walk alone, at the cell's published shapes (32
+    slots, 2 K/V heads of 128 lanes, lists 128 entries wide of which a
+    sparse row fills 64, blocks of 64 tokens) and at blocks of 16: a copy
+    takes a block's 256 lanes where a slot's heads share the entry and ONE
+    K/V head's 128 lanes, into that head's lanes of the buffer, where they
+    do not: static lane slices of source AND destination, which the
+    interpreter cannot refuse and Mosaic can; with the lists counted
+    beside it (`pair_lists`) and at every chunk the route can pick, the
+    whole list's 4096 tokens (8 MB of double buffers) among them."""
     from paddle_tpu.ops.pallas import paged_attention as PA
 
     one = SingleDeviceSharding(v5e[0])
@@ -1116,12 +1122,18 @@ def test_the_sparse_walk_compiles_for_v5e(v5e, monkeypatch):
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    for bs in (16, 64):
+    for bs, nb, list_tokens, chunk in ((64, 24577, 4096, 4096),
+                                       (64, 24577, 2048, 2048),
+                                       (16, 513, 1024, 1024),
+                                       (16, 513, None, 2048)):
         fn = jax.jit(lambda q, k, v, l, t, p: PA.paged_sparse_attention(
-            q, k, v, l, t, p, heads=32, kv_heads=2))
-        fn.lower(sds((32, 4096)), sds((2, 513, bs, 256)),
-                 sds((2, 513, bs, 256)), sds((), np.int32),
-                 sds((64, 128), np.int32), sds((64,), np.int32)).compile()
+            q, k, v, l, t, p, heads=32, kv_heads=2, list_tokens=list_tokens))
+        text = fn.lower(sds((32, 4096)), sds((2, nb, bs, 256)),
+                        sds((2, nb, bs, 256)), sds((), np.int32),
+                        sds((64, 128), np.int32),
+                        sds((64,), np.int32)).compile().as_text()
+        assert PA.WALK_CHUNKS["paged_sparse"] == chunk
+        assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("dtype,layers,nb,slots,mb", [
