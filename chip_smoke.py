@@ -17,6 +17,7 @@ over. Numbers printed here are information, not benchmark results.
                                     serve_olmoe, serve_joyai, serve_xing4,
                                     serve_nemotron, serve_jamba,
                                     serve_longcat, longcat_experts,
+                                    serve_granite, granite_experts,
                                     paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
@@ -48,6 +49,7 @@ XING4_LOGIT_TOL = 0.55   # benchmarks/configs/xing4_29b_a4b.json argues it
 NEMOTRON_LOGIT_TOL = 0.4  # benchmarks/configs/nemotron3_nano.json argues it
 JAMBA_LOGIT_TOL = 2.5    # benchmarks/configs/jamba2_3b.json argues it
 LONGCAT_LOGIT_TOL = 0.3  # benchmarks/configs/longcat_flash_chat.json argues it
+GRANITE_LOGIT_TOL = 0.03  # benchmarks/configs/granite4_h_small.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
 # many of those its persistent cache answered (a hit still counts as a
@@ -384,6 +386,17 @@ def _nemotron_reference_gaps(params, cfg, prompts, streams):
                                    streams)
 
 
+def _granite_reference_gaps(params, cfg, prompts, streams):
+    """As `_nemotron_reference_gaps`, against the benchmark's plain float32
+    Granite 4.0-H (benchmarks/reference/granite_hybrid_ref.py: a block at a
+    time, the published router's order, no code of
+    models/granite_hybrid.py)."""
+    from benchmarks.reference import granite_hybrid_ref
+
+    return _pattern_reference_gaps(granite_hybrid_ref, params, cfg, prompts,
+                                   streams)
+
+
 def _jamba_reference_gaps(params, cfg, prompts, streams):
     """As `_nemotron_reference_gaps`, against the benchmark's plain float32
     Jamba (benchmarks/reference/jamba_ref.py: the selective recurrence
@@ -669,44 +682,82 @@ def short_attention_phase(info: dict, batch: int = 256, seq: int = 128,
 
 def longcat_experts_phase(info: dict, cfg, rows=(128, 512), layer: int = 1,
                           tol: float = 0.03) -> dict:
-    """LongCat's expert path ALONE against the plain reference's, at the
+    """LongCat's expert path ALONE against the plain reference's
+    (`_held_experts_phase`, under the scope `shortcut_experts`; a decode
+    step's 128 rows, the largest prefill bucket's 512)."""
+    from benchmarks.reference import longcat_ref
+    from paddle_tpu.models import longcat
+
+    model = {"n_experts": cfg.n_experts, "top_k": cfg.top_k,
+             "route_scale": cfg.route_scale,
+             "held": list(cfg.routing.held_range)}
+    return _held_experts_phase(
+        info, cfg, rows, layer, tol, model=model, scope=longcat.SCOPE,
+        experts=longcat._EXPERTS, ref_experts=longcat_ref.experts,
+        weights=lambda lp, y: longcat_ref.route(lp, y, model),
+        make_layer=lambda key, l: longcat.init_layer(key, cfg, l))
+
+
+def granite_experts_phase(info: dict, cfg, rows=(48, 1024), layer: int = 1,
+                          tol: float = 0.03) -> dict:
+    """Granite 4.0-H's expert layer ALONE against the plain reference's
+    (`_held_experts_phase`, under the scope `mlp`): a decode step's 48 rows
+    (480 pairs, not whole row tiles of the grouped matmul: filled up) and a
+    prompt slice's 1024; the shared expert is in both sides."""
+    from benchmarks.reference import granite_hybrid_ref
+    from paddle_tpu.models import granite_hybrid
+
+    model = {"n_experts": cfg.n_experts, "top_k": cfg.top_k,
+             "held": list(cfg.routing.held_range)}
+    return _held_experts_phase(
+        info, cfg, rows, layer, tol, model=model, scope="mlp",
+        experts=tuple("blk." + k for k in granite_hybrid._EXPERTS),
+        ref_experts=granite_hybrid_ref.experts,
+        weights=lambda lp, y: granite_hybrid_ref.route(
+            y @ lp["blk.router"], model),
+        # layer l's experts are block 2l + 1
+        make_layer=lambda key, l: granite_hybrid.init_layer(
+            key, cfg, 2 * l + 1, "E"))
+
+
+def _held_experts_phase(info: dict, cfg, rows, layer: int, tol: float, *,
+                        model, scope, experts, ref_experts, weights,
+                        make_layer) -> dict:
+    """A model's expert path ALONE against its plain reference's, at the
     widths of `cfg`: `moe.expert_mlp` as the serve programs call it (bf16,
     the held experts' stacks of `layer + 1` layers addressed in place at
-    `layer`, under the scope `shortcut_experts`) for `rows` unit-normal
-    rows (a decode step's 128, the largest prefill bucket's 512) against
-    `longcat_ref.experts` in float32 on the SAME bf16-rounded weights and
-    rows, so that both routers see the same logits. What is compared is
-    the HELD experts' part by itself: the program's `m` less the
-    reference's `m` with the held term dropped, against the reference's
-    held term, row by row where a row has a pair on a held expert (a
-    relative L2 distance of `tol` at most: the matmuls round to bf16
-    twice), and the rows with none against the reference outright. The
-    cell's `correct` sees this term through four layers and a head; here
-    nothing stands between the grouped matmuls' tiles and the verdict."""
+    `layer`, under `scope`) for `rows` unit-normal rows against
+    `ref_experts` in float32 on the SAME bf16-rounded weights and rows, so
+    that both routers see the same logits. What is compared is the HELD
+    experts' part by itself: the program's result less the reference's
+    with the held term dropped, against the reference's held term, row by
+    row where a row has a pair on a held expert (a relative L2 distance of
+    `tol` at most: the matmuls round to bf16 twice), and the rows with none
+    against the reference outright. A cell's `correct` sees this term
+    through its layers and a head; here nothing stands between the grouped
+    matmuls' tiles and the verdict. `make_layer(key, l)` gives layer l's
+    expert parameters in float32, `weights(lp, y)` the reference's `[rows,
+    router outputs]` weights."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks.reference import longcat_ref
-    from paddle_tpu.models import longcat, moe
+    from paddle_tpu.models import moe
     from paddle_tpu.ops.pallas import grouped_matmul as gmm
 
-    names = ("blk.router", "blk.router_bias") + tuple(longcat._EXPERTS)
     key = jax.random.key(SEED + 5)
     make = jax.jit(lambda l: {
         k: v.astype(jnp.bfloat16) if v.ndim >= 2 else v
-        for k, v in longcat.init_layer(key, cfg, l).items() if k in names})
+        for k, v in make_layer(key, l).items()
+        if k.split(".")[1].startswith(("router", "shared", "w_"))})
     made = [make(np.int32(l)) for l in range(layer + 1)]
     served = dict(made[layer], **{
-        k: jnp.stack([lp[k] for lp in made]) for k in longcat._EXPERTS})
+        k: jnp.stack([lp[k] for lp in made]) for k in experts})
     exact = {k: v.astype(jnp.float32) for k, v in made[layer].items()}
     del made
-    model = {"n_experts": cfg.n_experts, "top_k": cfg.top_k,
-             "route_scale": cfg.route_scale,
-             "held": list(cfg.routing.held_range)}
     first, past = model["held"]
     run = jax.jit(lambda lp, y: moe.expert_mlp(
-        lp, y, cfg.routing, np.int32(layer), scope=longcat.SCOPE))
+        lp, y, cfg.routing, np.int32(layer), scope=scope))
     gmm.GATE_COUNTS.clear()
     gmm.TILES.clear()
     checked = {"rows": {}}
@@ -716,9 +767,9 @@ def longcat_experts_phase(info: dict, cfg, rows=(128, 512), layer: int = 1,
         got, stats = run(served, y)
         with jax.default_matmul_precision("highest"):
             y32 = y.astype(jnp.float32)
-            w = np.asarray(longcat_ref.route(exact, y32, model))
-            whole = np.asarray(longcat_ref.experts(exact, y32, model))
-            bare = np.asarray(longcat_ref.experts(
+            w = np.asarray(weights(exact, y32))
+            whole = np.asarray(ref_experts(exact, y32, model))
+            bare = np.asarray(ref_experts(
                 exact, y32, dict(model, held_term=False)))
         got = np.asarray(got, np.float32)
         assert np.isfinite(got).all()
@@ -804,8 +855,8 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import (bert, gpt, jamba, joyai, longcat,
-                                   nemotron_h, olmoe, xing4)
+    from paddle_tpu.models import (bert, gpt, granite_hybrid, jamba, joyai,
+                                   longcat, nemotron_h, olmoe, xing4)
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
 
@@ -954,11 +1005,10 @@ def run_one_chip() -> None:
         # same tokens slower and nothing else would say so
         assert checked["decode_attention"] == {"paged_gqa": 1}, info
         assert checked["state"]["update"] == {"kernel": 2}, info
-        # the experts of the three prefill programs through the megablox
-        # kernel, their columns whole (the decode program's 16 slots x 6
-        # experts are 96 rows, under a row tile: `ragged_dot`)
-        assert checked["expert_matmul"]["routes"] == {
-            "megablox": 12, "xla": 4}, info
+        # the experts of the three prefill programs and of the decode
+        # program (16 slots x 6 experts are 96 rows: filled up to a row
+        # tile since PR 58) through the megablox kernel, their columns whole
+        assert checked["expert_matmul"]["routes"] == {"megablox": 16}, info
         assert checked["expert_matmul"]["tiles"] == {
             "1920x2688": [128, 640, 2688],
             "2688x1920": [128, 896, 1920]}, info
@@ -1019,9 +1069,9 @@ def run_one_chip() -> None:
             reference_gaps=_longcat_reference_gaps)
         checked = info["checked"]
         assert checked["decode_attention"] == {"paged_latent": 1}, info
-        # the held experts of the three prefill programs through the
-        # megablox kernel, their columns whole (the decode program's 16
-        # slots x 12 picks are 192 rows, not whole row tiles: `ragged_dot`)
+        # the held experts through the megablox kernel, their columns
+        # whole (the decode program's 16 slots x 12 picks are 192 rows,
+        # filled up to whole row tiles)
         assert checked["expert_matmul"]["tiles"] == {
             "6144x2048": [128, 768, 2048],
             "2048x6144": [128, 256, 6144]}, info
@@ -1042,6 +1092,38 @@ def run_one_chip() -> None:
         assert checked["tiles"] == {
             "6144x2048": [128, 768, 2048],
             "2048x6144": [128, 256, 6144]}, info
+
+    # Granite-4.0-H-Small at its published widths (every layer a mixer
+    # then top-10 of 72 SwiGLU experts under the four multipliers; 128
+    # Mamba-2 heads on ONE B/C group, 32Q/8KV attention at 1/128), layers
+    # 4 to 6 of its pattern (`M*M`) with 8 of the 72 experts held and an
+    # eighth of the vocabulary, prompts walked in slices of 128: the state
+    # update's kernel where a block of heads is PART of the one group, the
+    # grouped-query kernel told the model's scale, and 160 pairs a decode
+    # step filled up to whole row tiles
+    gcfg = granite_hybrid.GraniteHybridConfig(
+        pattern="M*M", held=(0, 8), vocab_size=12544, prompt_slice=128,
+        max_len=1024)
+    prompts = [rng.randint(0, gcfg.vocab_size, n).tolist()
+               for n in (12, 200, 5, 64, 40, 250, 129)]
+    with phase("serve_granite") as info:
+        serve_phase(info, gcfg, DecodeConfig(
+            block_size=16, num_blocks=16 * 64 + 1, decode_slots=(16,),
+            prefill_buckets=(128, 256)), prompts, max_new=24,
+            logit_tol=GRANITE_LOGIT_TOL, model=granite_hybrid,
+            reference_gaps=_granite_reference_gaps)
+        checked = info["checked"]
+        assert checked["decode_attention"] == {"paged_gqa": 1}, info
+        assert checked["state"]["update"] == {"kernel": 2}, info
+        # three matmuls an expert layer, three layers, three programs
+        assert checked["expert_matmul"]["routes"] == {"megablox": 27}, info
+
+    # the expert layer alone at the cell's widths and share (36 of 72 held,
+    # top-10) against the reference's, the held experts' term by itself
+    with phase("granite_experts") as info:
+        granite_experts_phase(info, granite_hybrid.GraniteHybridConfig(
+            pattern="MM", held=(0, 36), vocab_size=12544))
+        assert info["checked"]["routes"] == {"megablox": 6}, info
 
     # Mosaic is not run by the CPU tests: the short kernel alone at the
     # training cells' shape against float32 attention

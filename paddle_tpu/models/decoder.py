@@ -646,11 +646,12 @@ def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
 
 
 def gqa_prompt(q: jax.Array, k: jax.Array, v: jax.Array, heads: int,
-               kv_heads: int) -> jax.Array:
+               kv_heads: int, scale: Optional[float] = None) -> jax.Array:
     """Causal grouped-query attention of whole sequences from their own
     projections: q `[B, T, heads*D]`, k and v `[B, T, kv_heads*D]`; the
     `heads / kv_heads` query heads of a K/V head read it together, scores
-    and softmax in float32 at `1/sqrt(D)` -> `[B, T, heads*D]`."""
+    and softmax in float32 at `scale` (None: `1/sqrt(D)`) -> `[B, T,
+    heads*D]`."""
     B, T = q.shape[:2]
     D = q.shape[-1] // heads
     q = q.reshape(B, T, kv_heads, heads // kv_heads, D)
@@ -658,21 +659,80 @@ def gqa_prompt(q: jax.Array, k: jax.Array, v: jax.Array, heads: int,
     v = v.reshape(B, T, kv_heads, D)
     scores = jnp.einsum("btgrd,bsgd->bgrts", q, k,
                         preferred_element_type=jnp.float32) \
-        * (1.0 / math.sqrt(D))
+        * (1.0 / math.sqrt(D) if scale is None else scale)
     seen = jnp.tril(jnp.ones((T, T), bool))
     att = jax.nn.softmax(jnp.where(seen, scores, -1e9), axis=-1)
     ctx = jnp.einsum("bgrts,bsgd->btgrd", att.astype(v.dtype), v)
     return ctx.reshape(B, T, heads * D)
 
 
+SLICE_KEYS = 1024   # keys a chunk of a prompt slice's walk (`gqa_slice`)
+
+
+def gqa_slice(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, layer,
+              block_table: jax.Array, start, block_size: int, heads: int,
+              kv_heads: int, scale: Optional[float] = None) -> jax.Array:
+    """Grouped-query attention of ONE SLICE of a prompt against the cache so
+    far (an `attend_slice` for plain K and V): the queries q `[1, C,
+    heads*D]` of positions `start .. start + C - 1` against layer `layer` of
+    the pools through the sequence's table, this slice's K and V already in
+    it. The keys are walked in chunks of `SLICE_KEYS` tokens up to the
+    slice's own, each gathered through the table and met by all the
+    slice's queries under an online softmax: float32 scores of `heads x C x
+    SLICE_KEYS` whatever the prompt's length -> `[1, C, heads*D]`."""
+    from ..serving import kv_cache as kvc
+
+    f32 = jnp.float32
+    C = q.shape[1]
+    D = q.shape[-1] // heads
+    G, R = kv_heads, heads // kv_heads
+    chunk = min(SLICE_KEYS, C)
+    if C % chunk or chunk % block_size:
+        raise ValueError(
+            f"a slice of {C} queries walks its keys in whole chunks of "
+            f"{chunk} tokens of whole blocks of {block_size}")
+    per_chunk = chunk // block_size
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
+    qh = q[0].reshape(C, G, R, D)
+    t = start + jnp.arange(C, dtype=jnp.int32)
+
+    def one(i, carry):
+        m, l, acc = carry
+        blocks = jax.lax.dynamic_slice_in_dim(
+            block_table, i * per_chunk, per_chunk)[None]
+        kch = kvc.gather_kv(k_pool, layer, blocks)[0].reshape(chunk, G, D)
+        vch = kvc.gather_kv(v_pool, layer, blocks)[0].reshape(chunk, G, D)
+        sc = jnp.einsum("qgrd,kgd->grqk", qh, kch,
+                        preferred_element_type=f32) * scale
+        tok = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        ok = (tok[None, :] <= t[:, None])[None, None]     # [1, 1, C, chunk]
+        sc = jnp.where(ok, sc, -1e30)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum(
+            "grqk,kgd->grqd", p.astype(vch.dtype), vch,
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, (start + C) // chunk, one,
+        (jnp.full((G, R, C, 1), -1e30, f32), jnp.zeros((G, R, C, 1), f32),
+         jnp.zeros((G, R, C, D), f32)))
+    ctx = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)      # [G, R, C, D]
+    return ctx.transpose(2, 0, 1, 3).reshape(1, C, heads * D)
+
+
 def mha_cached(q: jax.Array, keys: jax.Array, vals: jax.Array,
-               pos: jax.Array, heads: int, kv_heads: Optional[int] = None
-               ) -> jax.Array:
+               pos: jax.Array, heads: int, kv_heads: Optional[int] = None,
+               scale: Optional[float] = None) -> jax.Array:
     """Multi-head attention of query rows over a gathered context (the
     default `ServeModel.attend_cached`): q `[S, W, heads*head_dim]`, keys
     and vals `[S, M, kv_heads*head_dim]`, row (s, w) sees key positions
     `<= pos[s, w]` -> `[S, W, heads*head_dim]`. With fewer K/V heads than
-    query heads, a K/V head's query heads read it together."""
+    query heads, a K/V head's query heads read it together, at `scale`
+    where a model's softmax is not at `1/sqrt(head_dim)`."""
     S, W, width = q.shape
     m = keys.shape[1]
     hd = width // heads
@@ -681,7 +741,7 @@ def mha_cached(q: jax.Array, keys: jax.Array, vals: jax.Array,
         keys = keys.reshape(S, m, kv_heads, hd)
         vals = vals.reshape(S, m, kv_heads, hd)
         scores = jnp.einsum("swgrd,smgd->swgrm", q, keys) \
-            * (1.0 / math.sqrt(hd))
+            * (1.0 / math.sqrt(hd) if scale is None else scale)
         mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
             <= pos[:, :, None]
         scores = jnp.where(mask[:, :, None, None, :], scores, -1e9)

@@ -333,28 +333,33 @@ def _ssm_out(lp, y, z, cfg: NemotronHConfig, dtype):
     return y.astype(dtype) @ lp["blk.out_proj"].astype(dtype)
 
 
-def mamba_prompt(lp, y, length, cfg: NemotronHConfig):
+def mamba_prompt(lp, y, length, cfg: NemotronHConfig, init=None):
     """The Mamba mixer over whole sequences y [B, T, hidden] of true length
     `length` (a scalar, or None: all T count) from a zero state -> (out
     [B, T, hidden], the convolution's tail [B, K-1, conv_dim] and the SSM
     state [B, heads, P, N] float32 AFTER position length - 1). Positions at
     or past `length` leave both as they were: their `dt` is 0, and the
-    tail is taken at `length`."""
+    tail is taken at `length`. `init`: (tail, state) as the part of the
+    sequence BEFORE y left them, where y is a slice of a longer prompt
+    (`length` then counts from y's first position); `cfg` is any
+    configuration with this one's `ssm_*`, `conv_kernel`, `chunk` and
+    `rms_eps` (models/granite_hybrid.py's too)."""
     T = y.shape[1]
     z, xbc, dt = _ssm_inputs(lp, y, cfg)
     if length is None:
         length = T
     else:
         dt = jnp.where((jnp.arange(T) < length)[None, :, None], dt, 0.0)
+    before, state = init if init is not None else (None, None)
     with jax.named_scope("conv"):
-        tail = _ssm.conv_tail(xbc, length, cfg.conv_kernel)
+        tail = _ssm.conv_tail(xbc, length, cfg.conv_kernel, before)
         xbc = jax.nn.silu(_ssm.causal_conv(xbc, lp["blk.conv_w"],
-                                           lp["blk.conv_b"]))
+                                           lp["blk.conv_b"], before))
     x, Bm, Cm = _split_xbc(xbc, cfg)
     with jax.named_scope("scan"):
         out, state = _ssm.ssd_chunked(
             x, dt, -jnp.exp(lp["blk.A_log"].astype(jnp.float32)), Bm, Cm,
-            lp["blk.D"], cfg.chunk)
+            lp["blk.D"], cfg.chunk, state)
     return _ssm_out(lp, out, z, cfg, y.dtype), tail, state
 
 

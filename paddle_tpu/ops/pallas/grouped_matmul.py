@@ -2,8 +2,10 @@
 group and group g's rows are multiplied by `w[g]` — the expert layer of a
 sparse mixture (models/moe.py).
 
-Two routes, one gate, as in `attention.py`. On a TPU, at shapes its tiles
-divide, the Pallas `megablox` kernel that ships with jax; everywhere else
+Two routes, one gate, as in `attention.py`. On a TPU, at widths its tiles
+divide, the Pallas `megablox` kernel that ships with jax (rows that are not
+whole tiles of `ROW_TILE`, 48 decode slots x 10 experts, are filled up with
+rows of no group, which the kernel visits no tile for); everywhere else
 XLA's `jax.lax.ragged_dot`. XLA's own TPU kernel for `ragged_dot` read
 OLMoE's experts at 0.61-0.64 of the HBM roofline where megablox read them
 at 0.73-0.81 (PERF.md section 6, PR 27), and reaches the profile as
@@ -102,11 +104,10 @@ def tiles(k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
 
 
 def _use_megablox(x, w) -> bool:
-    m, k = x.shape
-    n = w.shape[-1]
+    k, n = w.shape[-2:]
     return (_attention._platform(x) == "tpu"
             and _attention._mesh_partitionable(x)
-            and m % ROW_TILE == 0 and k % LANES == 0 and n % LANES == 0)
+            and k % LANES == 0 and n % LANES == 0)
 
 
 def grouped_matmul(x: jax.Array, w: jax.Array,
@@ -120,7 +121,13 @@ def grouped_matmul(x: jax.Array, w: jax.Array,
         k, n = w.shape[-2:]
         tiling = TILES[k, n] = tiles(k, n, w.dtype.itemsize)
         GATE_COUNTS["megablox"] += 1
-        return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
-                   tiling=tiling)
+        # whole tiles of rows: the rows past the groups' sum belong to no
+        # group and come back unwritten
+        m = x.shape[0]
+        if m % ROW_TILE:
+            x = jax.numpy.pad(x, [(0, -m % ROW_TILE), (0, 0)])
+        out = gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+                  tiling=tiling)
+        return out[:m] if m % ROW_TILE else out
     GATE_COUNTS["xla"] += 1
     return jax.lax.ragged_dot(x, w, group_sizes)

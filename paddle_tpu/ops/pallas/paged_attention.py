@@ -1017,13 +1017,15 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         layer: jax.Array, block_tables: jax.Array,
                         positions: jax.Array, *, heads: int, kv_heads: int,
+                        scale: Optional[float] = None,
                         interpret: bool = False) -> jax.Array:
     """Grouped-query decode attention: q `[S, heads*D]` against layer
     `layer` of the pools `[L, NB, BS, kv_heads*D]` through block_tables
     `[S, MB]`; query head h reads K/V head `h // (heads / kv_heads)`. Slot
-    s attends key positions `0..positions[s]` at `1/sqrt(D)`, scores and
-    softmax in float32, and gets `[heads*D]` in q's dtype; a slot whose
-    table starts with the null block gets zeros. Query heads that are not
+    s attends key positions `0..positions[s]` at `scale` (None:
+    `1/sqrt(D)`; a model whose softmax is at another scale hands its own),
+    scores and softmax in float32, and gets `[heads*D]` in q's dtype; a
+    slot whose table starts with the null block gets zeros. Query heads that are not
     whole sublane tiles (one K/V head alone: every query head is of its
     group wherever it sits) are filled up with rows of zeros, whose
     context, a plain mean of V, is dropped."""
@@ -1039,12 +1041,13 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         out = paged_gqa_attention(
             jnp.pad(q, [(0, 0), (0, spare * head_dim)]), k_pool, v_pool,
             layer, block_tables, positions, heads=heads + spare,
-            kv_heads=1, interpret=interpret)
+            kv_heads=1, scale=scale, interpret=interpret)
         return out[:, :heads * head_dim]
     per_slot = lambda s, *_: (s, 0, 0)      # noqa: E731
     kernel, scalars, pools = _call_form(
         functools.partial(_gqa_kernel, kv_heads=kv_heads,
-                          scale=1.0 / math.sqrt(head_dim),
+                          scale=1.0 / math.sqrt(head_dim)
+                          if scale is None else float(scale),
                           block_size=k_pool.shape[2]),
         layer, block_tables, positions, k_pool, v_pool)
     out = pl.pallas_call(

@@ -141,14 +141,18 @@ def use_kernel(x: jax.Array, pool: jax.Array, groups: int) -> bool:
     """Whether the state update takes the kernel: on one TPU, over a
     float32 pool `[L, R, H, P, N]` whose heads are whole `[P, N]` tiles, a
     block of heads whole sublane tiles of the row operands and whole
-    groups."""
+    groups, or a whole part of ONE group (128 heads on one B and C go 32 a
+    block: the body picks a head's group by `head // per_group` and B and
+    C arrive as the slot's whole `[G, N]`, so a block inside a group reads
+    the one row every head of it shares)."""
     if pool.ndim != 5 or pool.dtype != jnp.float32:
         return False
     H, P, N = pool.shape[2:]
     hb = _heads_per_block(pool)
     per_group = H // groups
     return (_pa._on_one_tpu(x) and H % groups == 0 and P % 8 == 0
-            and N % 128 == 0 and hb % per_group == 0)
+            and N % 128 == 0
+            and (hb % per_group == 0 or per_group % hb == 0))
 
 
 def _kernel(layer_ref, rows_ref, decay_ref, dtx_ref, b_ref, c_ref, pool_ref,

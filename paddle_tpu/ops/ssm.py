@@ -92,12 +92,22 @@ def linear_attention_args(slopes: jax.Array, live: jax.Array):
     return dt, -slopes, jnp.zeros_like(slopes)
 
 
-def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def _led(x: jax.Array, K: int, before: Optional[jax.Array]) -> jax.Array:
+    """x [B, T, C] with the K-1 inputs before it in front: zeros, or
+    `before` [B, K-1, C] where x continues a sequence."""
+    if before is None:
+        return jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
+    return jnp.concatenate([before.astype(x.dtype), x], axis=1)
+
+
+def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
+                before: Optional[jax.Array] = None) -> jax.Array:
     """x [B, T, C], w [K, C], b [C] -> [B, T, C]: position t sees inputs
-    t-K+1..t (zeros before the sequence)."""
+    t-K+1..t (zeros before the sequence, or `before` [B, K-1, C]: what
+    `conv_tail` kept of the part of the sequence that came before x)."""
     K = w.shape[0]
     T = x.shape[1]
-    xp = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
+    xp = _led(x, K, before)
     out = b.astype(jnp.float32)
     for k in range(K):
         out = out + xp[:, k:k + T].astype(jnp.float32) \
@@ -105,10 +115,12 @@ def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def conv_tail(x: jax.Array, length: jax.Array, K: int) -> jax.Array:
+def conv_tail(x: jax.Array, length: jax.Array, K: int,
+              before: Optional[jax.Array] = None) -> jax.Array:
     """The last K-1 inputs before position `length` of x [B, T, C] (zeros
-    before the sequence) -> [B, K-1, C]: what `conv_step` continues from."""
-    xp = jnp.pad(x, [(0, 0), (K - 1, 0), (0, 0)])
+    before the sequence, or `before` as in `causal_conv`) -> [B, K-1, C]:
+    what `conv_step`, or the next part's `before`, continues from."""
+    xp = _led(x, K, before)
     return jax.lax.dynamic_slice_in_dim(xp, length, K - 1, axis=1)
 
 

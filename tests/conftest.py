@@ -52,7 +52,10 @@ def pytest_configure(config):
 # per-layer metrics are its ten, and a metric appended after them (the
 # contract puts a new entry at the end of its list) makes that line false;
 # its body runs in tests/benchmarks/test_loop_metrics_as_left.py against
-# the list as PR 56 left it.
+# the list as PR 56 left it. And since PR 58 of
+# tests/benchmarks/test_sparse_shared_entry_share.py (PR 57), which pins
+# `per_layer`'s LAST entry to its one metric: its body runs in
+# tests/benchmarks/test_sparse_shared_entry_share_as_left.py.
 # ---------------------------------------------------------------------------
 
 # (the list of BENCHMARK.json, the name its last entry was pinned to, the
@@ -63,7 +66,10 @@ _PINNED_LAST = (
      "found_with_its_readers_and_the_issues_traffic"),
     ("per_layer", "pause_device_share",
      "tests/benchmarks/test_loop_metrics.py::test_benchmark_json_lists_"
-     "the_ten_under_the_decode_engine"))
+     "the_ten_under_the_decode_engine"),
+    ("per_layer", "sparse_shared_entry_share",
+     "tests/benchmarks/test_sparse_shared_entry_share.py::test_the_"
+     "manifest_lists_it_for_the_sparse_cell_alone"))
 
 
 def pytest_collection_modifyitems(config, items):

@@ -279,6 +279,49 @@ def test_rehearse_longcat_experts(smoke):
         assert checked["rows"][n]["max_rel_l2"] <= checked["tol"]
 
 
+def test_rehearse_serve_granite(smoke):
+    """The serve_granite phase at a tiny size: every layer a mixer then a
+    share of its experts under the four multipliers, prompts walked in
+    slices, state rows beside the blocks, through the same engine and
+    front, the tokens against the benchmark's plain reference (off the chip
+    the gates take the gathered forms and `ragged_dot`)."""
+    from paddle_tpu.models import granite_hybrid
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = granite_hybrid.GraniteHybridConfig.tiny()
+    cfg.dtype = "float32"
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 15, 3)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=33, decode_slots=(4,),
+        prefill_buckets=(8, 16), max_len=32), prompts, max_new=4,
+        logit_tol=smoke.GRANITE_LOGIT_TOL, model=granite_hybrid,
+        reference_gaps=smoke._granite_reference_gaps)
+    checked = info["checked"]
+    assert checked["finished"]["length"] == 3
+    assert checked["compiles_after_warmup"] == 0
+    assert checked["decode_attention"] == {"gather": 1}
+    # three matmuls an expert layer, four layers, three programs
+    assert checked["expert_matmul"] == {"routes": {"xla": 36}, "tiles": {}}
+    assert checked["state"]["update"] == {"xla": 3}
+
+
+def test_rehearse_granite_experts(smoke):
+    """The granite_experts phase at a tiny size: the held experts' term of
+    `moe.expert_mlp` beside a shared expert (stacks of two layers addressed
+    at the second) against the plain reference's, by itself."""
+    from paddle_tpu.models import granite_hybrid
+
+    cfg = granite_hybrid.GraniteHybridConfig.tiny()
+    info = smoke.granite_experts_phase({}, cfg, rows=(24, 64))
+    checked = info["checked"]
+    assert checked["routes"] == {"xla": 6} and checked["tiles"] == {}
+    for n in ("24", "64"):
+        assert checked["rows"][n]["held_rows"] >= int(n) // 8
+        assert checked["rows"][n]["max_rel_l2"] <= checked["tol"]
+
+
 @pytest.mark.parametrize("heads,head_dim", [(12, 64), (4, 128)])
 def test_rehearse_short_attention(smoke, heads, head_dim):
     """The short_attention phase at a small batch, the kernel under the

@@ -211,6 +211,10 @@ _CASES = [
     # 65-row pool of 4 Mamba-2 layers, 64 heads of [64, 128] float32
     pytest.param(_state_update, _STATE, (64, 4, 64, 64, 128, 8),
                  id="ssm-state-update-nemotron-64"),
+    # and where a block of heads is PART of the one group: Granite 4.0-H's
+    # 128 heads on one B and C, 48 slots of the 49-row pool of 9 layers
+    pytest.param(_state_update, _STATE, (48, 9, 128, 64, 128, 1),
+                 id="ssm-state-update-granite-128x1"),
     # the selective recurrence's rows where they lie: 128 slots of the
     # 129-row pool of Jamba2-3B's 26 Mamba-1 layers, [16, 5120] float32 a row;
     # and a slot count that is not whole tiles of eight
@@ -995,6 +999,123 @@ def test_nemotron_serve_program_fits_and_updates_the_state_in_place(
     else:
         assert kvc.PREFILL_WRITE_UNITS == {"blocks": 2}
         assert sum("kv_block_write" in k for k in kernels) == 2, kernels
+
+
+@pytest.mark.parametrize("heads,groups,kernel", [
+    (128, 1, True),     # Granite 4.0-H: a block of 32 heads inside the group
+    (64, 8, True),      # Nemotron-3-Nano: a block of 32 heads is 4 groups
+    (64, 64, True),     # lightning attention: a group a head
+    (96, 2, False),     # 48 heads a group: a block of 32 would straddle two
+])
+def test_the_state_updates_gate_takes_whole_groups_or_a_part_of_one(
+        heads, groups, kernel, monkeypatch):
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.ops.pallas import ssm_update as SU
+
+    monkeypatch.setattr(PA, "_on_one_tpu", lambda x: True)
+    pool = jax.ShapeDtypeStruct((2, 9, heads, 64, 128), jnp.float32)
+    x = jax.ShapeDtypeStruct((8, 256), jnp.bfloat16)
+    assert SU._heads_per_block(pool) == 32
+    assert SU.use_kernel(x, pool, groups) is kernel
+    assert not SU.use_kernel(x, jax.ShapeDtypeStruct(pool.shape,
+                                                     jnp.bfloat16), groups)
+
+
+_GRANITE_SLOTS, _GRANITE_CONTEXT = 48, 8192
+
+
+@pytest.fixture(scope="module")
+def granite_10l(v5e):
+    from paddle_tpu.models import granite_hybrid
+
+    return _described(
+        v5e, granite_hybrid, granite_hybrid.GraniteHybridConfig(
+            pattern="MMMMM*MMMM", held=(0, 36), vocab_size=50176,
+            max_len=_GRANITE_CONTEXT),
+        _GRANITE_SLOTS, _GRANITE_CONTEXT)
+
+
+@pytest.mark.parametrize("program", ["decode@48", "prefill@4096"])
+def test_granite_serve_program_fits_and_updates_the_state_in_place(
+        granite_10l, program, monkeypatch):
+    """One period of Granite-4.0-H-Small as the cell serves it (9 Mamba-2
+    and 1 attention layer, each followed by 36 held of 72 experts, half the
+    vocabulary): 9.51 GB of weights, a K/V pool of ONE layer and 49 state
+    rows of 4.2 MB a layer, all donated and written where they lie. The
+    decode program's state rows cross HBM twice: the kernel of
+    ops/pallas/ssm_update.py in all 9 Mamba layers, at ONE B/C group of 128
+    heads (a block of 32 heads is a PART of the group), and no op holds the
+    slots' states outside the pool (the gathered form would:
+    `f32[48,128,64,128]`, 201 MB a layer, three times). The prefill walks
+    its 4096 tokens in slices of 1024."""
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.ops.pallas import ssm_update as SU
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, state, kv, sds = granite_10l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _GRANITE_CONTEXT // _BLOCK
+    for counts in (gm.GATE_COUNTS, gm.TILES, PA.GATE_COUNTS, SU.GATE_COUNTS,
+                   kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32), state, sds((n,), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32), state, sds((), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4, 6)).lower(params,
+                                                       *args).compile()
+    assert kv.pool_shapes == ((1, 24577, 16, 1024),) * 2
+    assert kv.bytes_per_token() == 4096
+    assert [s.shape for s in state] == [(9, 49, 198, 128),
+                                        (9, 49, 128, 64, 128)]
+    weights = sum(int(np.prod(p.shape)) * 2 for p in params.values())
+    assert weights == 2 * 4_757_211_776, weights
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    print(program, "planned", planned, ma)
+    # weights 9.51 GB + K/V 1.61 GB + state 1.87 GB resident, the rest
+    # temporaries
+    assert 12.9e9 < planned < 14.2e9, ma
+    state_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in state)
+    assert ma.alias_size_in_bytes >= kv.pool_bytes() + state_bytes, ma
+    text = compiled.as_text()
+    for pool in pools:
+        assert not _pool_movers(text, pool.shape)
+    tails, ssm = state
+    moved = collections.Counter(op for op, _ in _pool_movers(text, ssm.shape))
+    if kind == "decode":
+        assert moved == {}, moved
+    # no op makes a layer's slice of an expert stack
+    slices = re.findall(r"= \(?bf16\[36,(?:4096,768|768,4096)\]", text)
+    assert not slices, slices[:3]
+    kernels = _kernels(text)
+    if kind == "decode":
+        # three grouped matmuls an expert layer, the megablox kernel
+        assert gm.GATE_COUNTS == {"megablox": 30}, gm.GATE_COUNTS
+        assert sum("/mlp/experts/" in k for k in kernels) == 30, kernels
+        assert PA.GATE_COUNTS == {"paged_gqa": 1}, PA.GATE_COUNTS
+        assert SU.GATE_COUNTS == {"kernel": 9}, SU.GATE_COUNTS
+        assert sum("/attention/" in k for k in kernels) == 1
+        updates = [k for k in kernels if "/ssm/scan/" in k]
+        assert len(updates) == 9 and all(
+            "ssm_state_update" in k for k in updates), kernels
+        held = re.findall(r"= \(?f32\[48,128,64,128\]", text)
+        assert not held, held[:3]
+        assert ma.temp_size_in_bytes < 0.1e9, ma
+    else:
+        assert ma.temp_size_in_bytes < 1.2e9, ma
 
 
 _SALA_SLOTS, _SALA_CONTEXT, _SALA_BLOCK = 32, 49152, 64
