@@ -3,11 +3,12 @@
 Reference: no TPU counterpart — the reference computes attention from
 unfused matmul/softmax ops (e.g. the BERT graph in
 inference/tests/api/analyzer_bert_tester.cc). TPU-native: the gate
-(_use_short / _use_splash / _multichip_splash_route) picks a Pallas
-kernel route or the XLA einsum+softmax path from the shape, the mesh,
-the platform and FLAGS_flash_attention — and the pick is final: a
-selected kernel that fails to trace or compile raises, it is never
-replaced by another path. Three routes:
+(_use_kernel with _short_shape / _causal_shape, _use_splash,
+_multichip_splash_route) picks a Pallas kernel route or the XLA
+einsum+softmax path from the shape, the mesh, the platform and
+FLAGS_flash_attention — and the pick is final: a selected kernel that fails
+to trace or compile raises, it is never replaced by another path. Four
+routes, tried in this order:
 
 - _short_mha (since PR 54; BERT's route: T = 128, no mask, not causal):
   short bidirectional sequences, T in _SHORT_T. One kernel forward and one
@@ -15,8 +16,18 @@ replaced by another path. Three routes:
   sequences with all their heads, the [T, T] scores live in VMEM in f32,
   and the backward is one pass (no dq / dkv split). Nothing of shape
   [., T, T] or [B, N, T, H] reaches HBM.
-- _splash_mha: long (T >= _SPLASH_MIN_T) or causal sequences, the splash
-  kernel shipped with jax, blocked over T, heads-major operands.
+- _causal_mha (since PR 59; a served prompt's route: GPT-2-large's bucket
+  of 1024): causal self-attention of whole sequences, T in _CAUSAL_T, q, k
+  and v of one shape, no mask. Forward only, over [B, T, heads*head_dim]
+  as it lies: a query block walks the key blocks 0 .. its own, the
+  diagonal block alone under a mask, nothing above the diagonal read;
+  probabilities go to the MXU in v's dtype, sums in f32. Differentiated,
+  it IS _splash_mha, forward and backward.
+- _splash_mha: the other long sequences (T >= _SPLASH_MIN_T: full masks,
+  training's causal passes, heads whose q / k and v differ in width), the
+  splash kernel shipped with jax, blocked over T, heads-major operands
+  (two transposes a call); under a causal mask its forward blocks are
+  small enough that pairs above the diagonal are skipped.
 - _xla_mha: everything else (additive masks, odd shapes, off the chip,
   FLAGS_flash_attention=off). The f32 XLA path is semantically identical to
   the kernels, so tests run on CPU; for bf16 inputs it stores the T x T
@@ -39,7 +50,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Trace-time gate observability: which attention path was selected.
 # Keys: "short" (the short kernel, single-device / manual region),
-# "short_shardmap" (the same under the dp/tp shard_map wrapper), "splash"
+# "short_shardmap" (the same under the dp/tp shard_map wrapper), "causal"
+# (the causal prompt kernel, single-device / manual region), "splash"
 # (single-device / manual region), "splash_shardmap"
 # (dp/tp shard_map wrapper), "ring_splash" (sp ring with splash blocks),
 # "ring_xla" (sp ring, XLA blocks),
@@ -228,9 +240,13 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array,
         scale = 1.0 / math.sqrt(q.shape[-1])
     interpret = _interpret_requested(q)
     short = _short_shape(q, k, mask, causal)
-    if short and _use_short(q):
+    if short and _use_kernel(q):
         out = _short_mha(q, k, v, scale, interpret=interpret)
         GATE_COUNTS["short"] += 1
+        return out
+    if _causal_shape(q, k, v, mask, causal) and _use_kernel(q):
+        out = _causal_mha(q, k, v, scale, interpret)
+        GATE_COUNTS["causal"] += 1
         return out
     if not short and _use_splash(q, k, mask, causal):
         out = _splash_mha(q, k, v, scale, causal, interpret=interpret)
@@ -280,10 +296,12 @@ def _merge_causal(mask, T):
 # SplashAttention (the production TPU attention kernel shipped with jax)
 # ---------------------------------------------------------------------------
 
-# Measured on v5e before PR 21 (fwd+bwd, bf16, 12 heads, head_dim 64):
-# splash with the block sizes below beats the XLA bf16-scores path for
-# T >= _SPLASH_MIN_T on full (bidirectional) masks and at every causal
-# shape.
+# Measured on v5e (fwd+bwd, bf16, bs 8, 12 heads, head_dim 64): splash with
+# the block sizes below beats the XLA bf16-scores path for T >=
+# _SPLASH_MIN_T on full (bidirectional) masks (before PR 21: 17.0 against
+# 37.4 ms at T = 4096) and under a causal mask (PR 59: 2.13 against 3.00 ms
+# at T = 1024, 19.00 against 40.96 at T = 4096). Under `auto` a shorter
+# sequence, causal or not, keeps the XLA route.
 _SPLASH_MIN_T = 1024
 
 
@@ -324,25 +342,41 @@ def _splash_kernel(Tq: int, Tk: int, n_heads: int, causal: bool,
     # is cheap (lazy Full/Causal masks process block-wise in numpy).
     from jax.experimental.pallas.ops.tpu import splash_attention as sa
 
-    # Block sizes tuned on v5e before PR 21 (fwd+bwd, bf16, bs=8):
-    # at T=4096 full-mask this config runs 17.0 ms vs 37.4 ms XLA
-    # bf16-scores and 114 ms with the jax default all-128 blocks; at
-    # T=8192 it is 56 ms where the XLA path cannot even compile (13 GB
-    # of score buffers). Big fwd KV blocks amortize the online-softmax
-    # rescale; bwd q-blocks stay at 512 to fit dq/dkv accumulators in
-    # VMEM.
+    # FULL masks: block sizes tuned on v5e before PR 21 (fwd+bwd, bf16,
+    # bs=8): at T=4096 this config runs 17.0 ms vs 37.4 ms XLA bf16-scores
+    # and 114 ms with the jax default all-128 blocks; at T=8192 it is 56 ms
+    # where the XLA path cannot even compile (13 GB of score buffers). Big
+    # fwd KV blocks amortize the online-softmax rescale; bwd q-blocks stay
+    # at 512 to fit dq/dkv accumulators in VMEM.
     # A block has to divide its sequence (the gate lets in every multiple
     # of 128): the tuned size where it does, as at every power of two, else
     # the largest multiple of 128 under it that does (T=3072: KV blocks of
     # 1536).
-    bq = _block(1024, Tq)
-    bkv = _block(2048, Tk)
+    bq, bkv = _block(1024, Tq), _block(2048, Tk)
+    bkvc = bkv
+    if causal:
+        # CAUSAL masks, the forward's three sizes (PR 59). Splash skips a
+        # (query block, key block) pair only where the mask is empty over
+        # the whole pair, and at (1024, 2048) a prompt of 1024 or 2048 was
+        # ONE column of pairs: every score above the diagonal computed and
+        # masked. Forward alone on a v5e, bf16, 36 calls in one jit, us a
+        # call at (1024, 2048, 2048) -> (512, 512, 512): [1, 1024, 20, 64]
+        # 95.5 -> 77.9; [1, T, 32, 192 | 128] at T = 1024 / 2048 / 3072
+        # 192 -> 160, 725 -> 490, 1370 -> 1070; at T = 4096 2151 -> 1924,
+        # and 1777 at (1024, 1024, 512), which 4096 and longer take. Smaller
+        # blocks skip more and lose it to the grid step: (256, 256, 256)
+        # reads 132 / 240 / 823 / 2059 / 3949. Fwd+bwd at bs 8, 12 heads of
+        # 64 (the backward's sizes as they were): T = 1024 2.16 -> 2.13 ms
+        # (XLA 3.00), T = 4096 20.07 -> 19.00 (XLA 40.96).
+        cap = 1024 if min(Tq, Tk) >= 4096 else 512
+        bq, bkv = _block(cap, Tq), _block(cap, Tk)
+        bkvc = _block(512, bkv)
     bqb = _block(512, Tq)
     # bwd dkv/dq kv-block: 2048 wins at T>=4096 (17.0 vs 19.0 ms), 1024
     # wins at T<=2048 (6.8 vs 9.2 ms at T=2048)
     bkvb = _block(2048 if Tk >= 4096 else 1024, Tk)
     sizes = sa.BlockSizes(
-        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
+        block_q=bq, block_kv=bkv, block_kv_compute=bkvc,
         block_q_dkv=bqb, block_kv_dkv=bkvb, block_kv_dkv_compute=bkvb,
         block_q_dq=bqb, block_kv_dq=bkvb)
     one = (sa.CausalMask((Tq, Tk)) if causal else sa.FullMask((Tq, Tk)))
@@ -413,10 +447,10 @@ def _short_shape(q, k, mask, causal) -> bool:
     return T in _SHORT_T and H in (64, 128) and (N * H) % 128 == 0
 
 
-def _use_short(q) -> bool:
-    """A _short_shape goes to _short_mha on a TPU (or where
-    FLAGS_flash_attention=splash asks for the interpreter); `off` keeps the
-    XLA route."""
+def _use_kernel(q) -> bool:
+    """A _short_shape goes to _short_mha, and a _causal_shape to
+    _causal_mha, on a TPU (or where FLAGS_flash_attention=splash asks for
+    the interpreter); `off` keeps the XLA route."""
     mode = _flag_mode()
     if mode != "splash" and (mode != "auto" or _platform(q) != "tpu"):
         return False
@@ -601,3 +635,152 @@ def _short_mha(q, k, v, scale, causal=False, interpret=False):
     out = _short_attention(q.reshape(flat), k.reshape(flat), v.reshape(flat),
                            N, float(scale), bool(interpret))
     return out.reshape(q.shape)
+
+
+# ---------------------------------------------------------------------------
+# Causal attention of whole prompts: only the blocks on and under the diagonal
+# ---------------------------------------------------------------------------
+
+# Sequence lengths the causal kernel takes: those it was timed at on a v5e
+# (forward, bf16, 36 calls in one jit, us a call at T = 1024 / 2048 / 3072 /
+# 4096: the kernel, splash at its causal blocks below, splash with the full
+# mask's blocks as every causal call had them before PR 59, the last two
+# with their head-view copies): [1, T, 20, 64] 63 / 186 / 371 / 618, 78 /
+# 231 / 466 / 749, 95 / 341 / 625 / 999; [1, T, 16, 128] 55 / 162 / 324 /
+# 538, 65 / 189 / 381 / 604, 77 / 273 / 503 / 804 (PERF.md section 6, PR 59).
+_CAUSAL_T = (1024, 2048, 3072, 4096)
+# Query rows and key rows of a block: a query block walks the key blocks
+# 0 .. its own. Timed at 128 / 256 / 512 rows: 158 / 100 / 66 us a call at
+# [1, 1024, 20, 64], where 36 of 64, 10 of 16 and 3 of 4 pairs are kept: a
+# block's running maximum and the rescaling of its sums are [block, 128]
+# passes whatever the block holds, and the smaller block loses more to them
+# than it saves above the diagonal.
+_CAUSAL_BLOCK = 512
+# What a masked score reads (finite: exp(mask - mask) must not be a NaN).
+_CAUSAL_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def _causal_shape(q, k, v, mask, causal) -> bool:
+    """What the causal kernel can take, from shapes alone: the short
+    kernel's rule with `causal` in place of "not causal" and its own
+    lengths."""
+    if q.ndim != 4 or mask is not None or not causal \
+            or not q.shape == k.shape == v.shape:
+        return False
+    _, T, N, H = q.shape
+    return T in _CAUSAL_T and H in (64, 128) and (N * H) % 128 == 0
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                       scale, head_dim):
+    """One query block of one 128-lane tile (two 64-wide heads, or one of
+    128) of one sequence, against the tile's K and V `[T, 128]`, which stay
+    in VMEM while the tile's query blocks go by. Key blocks under the
+    diagonal go through an online softmax with no mask; the diagonal block
+    alone is compared with an iota; nothing above it is read. A head is
+    taken out of its tile by zeroing the other head's lanes of Q (the MXU
+    pass is half filled either way); its probabilities meet all 128 lanes
+    of V, and the context keeps each head's own lanes. The running sum of a
+    row's probabilities is kept a lane class at a time, `[block, 128]`, and
+    reduced across lanes once, at the end (66 -> 63 us a call)."""
+    block = q_ref.shape[1]
+    i = pl.program_id(2)
+    owns = _own_lanes(block, head_dim)
+    q = q_ref[0]
+    # the scale goes into Q, as _splash_mha folds it (exact for heads of 64)
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    qs = [_only(own, q) for own in owns]
+    m_ref[...] = jnp.full_like(m_ref, _CAUSAL_MASKED)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def one_block(j, diagonal):
+        rows = pl.ds(pl.multiple_of(j * block, block), block)
+        k, v = k_ref[0, rows, :], v_ref[0, rows, :]
+        ctx = alphas = None
+        for h, own in enumerate(owns):
+            s = _dot(qs[h], k, _NT)                           # [block, block]
+            if diagonal:
+                row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(row >= col, s, _CAUSAL_MASKED)
+            m_prev, l_prev = m_ref[h], l_ref[h]               # [block, 128]
+            m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
+            p = jnp.exp(s - jnp.tile(m_next, (1, block // 128)))
+            alpha = jnp.exp(m_prev - m_next)
+            m_ref[h] = m_next
+            l_ref[h] = alpha * l_prev + sum(
+                p[:, t:t + 128] for t in range(0, block, 128))
+            c = _dot(p.astype(v.dtype), v)                    # [block, 128]
+            ctx = c if ctx is None else jnp.where(own, c, ctx)
+            alphas = alpha if alphas is None else jnp.where(own, alpha,
+                                                            alphas)
+        acc_ref[...] = alphas * acc_ref[...] + ctx
+
+    jax.lax.fori_loop(0, i, lambda j, c: one_block(j, False), None)
+    one_block(i, True)
+    inv = None
+    for h, own in enumerate(owns):
+        r = jnp.broadcast_to(1.0 / l_ref[h].sum(axis=-1, keepdims=True),
+                             acc_ref.shape)
+        inv = r if inv is None else jnp.where(own, r, inv)
+    o_ref[0] = (acc_ref[...] * inv).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _causal_call(q, k, v, heads, scale, interpret):
+    """One pallas_call over (sequence, 128-lane tile of its heads, query
+    block), every array `[B, T, heads*head_dim]`. K's and V's block is the
+    tile's whole `[T, 128]` and its index does not name the query block, so
+    it is fetched once a tile. Jitted, as _short_call is: a model's layers
+    share one trace of the kernel and one lowering."""
+    B, T, D = q.shape
+    block = _CAUSAL_BLOCK
+    per = heads * 128 // D
+    rows = pl.BlockSpec((1, block, 128), lambda b, t, i: (b, i, t))
+    whole = pl.BlockSpec((1, T, 128), lambda b, t, i: (b, 0, t))
+    stat = pltpu.VMEM((per, block, 128), jnp.float32)
+    pairs = T // block * (T // block + 1) // 2
+    return pl.pallas_call(
+        functools.partial(_causal_fwd_kernel, scale=scale,
+                          head_dim=D // heads),
+        grid=(B, D // 128, T // block), in_specs=[rows, whole, whole],
+        out_specs=rows, out_shape=jax.ShapeDtypeStruct((B, T, D), q.dtype),
+        scratch_shapes=[stat, stat,
+                        pltpu.VMEM((block, 128), jnp.float32)],
+        name="causal_mha_fwd", interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_SHORT_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * B * heads * pairs * block * block * (D // heads),
+            transcendentals=B * heads * pairs * block * block,
+            bytes_accessed=4 * B * T * D * q.dtype.itemsize),
+    )(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _causal_mha(q, k, v, scale, interpret=False):
+    """[B,T,N,H] causal attention of whole prompts through the kernel above:
+    operands go in as [B, T, N*H], as they lie, and the context comes back
+    the same way. Forward only: under differentiation both passes are
+    _splash_mha's (the rules below), so an inference trace takes this
+    kernel, a `jax.grad` trace takes splash as it did before, and no
+    caller says which it is."""
+    B, T, N, H = q.shape
+    flat = (B, T, N * H)
+    out = _causal_call(q.reshape(flat), k.reshape(flat), v.reshape(flat),
+                       N, float(scale), bool(interpret))
+    return out.reshape(q.shape)
+
+
+def _causal_grad_fwd(q, k, v, scale, interpret):
+    return jax.vjp(lambda q, k, v: _splash_mha(q, k, v, scale, True,
+                                               interpret=interpret), q, k, v)
+
+
+def _causal_grad_bwd(scale, interpret, vjp, dout):
+    return vjp(dout)
+
+
+_causal_mha.defvjp(_causal_grad_fwd, _causal_grad_bwd)

@@ -69,6 +69,7 @@ from ..observability import metrics as _m
 from ..observability import perfwatch as _perfwatch
 from ..observability import telemetry as _telemetry
 from ..observability import tracing as _tracing
+from ..ops.pallas import attention as _attention
 from ..ops.pallas import grouped_matmul as _grouped_matmul
 from ..ops.pallas import paged_attention as _paged_attention
 from ..ops.pallas import ssm_update as _ssm_update
@@ -1328,6 +1329,10 @@ class DecodeEngine:
             # tokens a chunk of a walk that has a number of its own
             # ("paged_sparse_chunk_tokens")
             "decode_attention": _paged_attention.gate_report(),
+            # which route a whole prompt's attention took, a count a traced
+            # `mha` call ("causal": the causal prompt kernel; "splash";
+            # "xla"); {} for a model whose prompts do not go through `mha`
+            "prompt_attention": dict(_attention.GATE_COUNTS),
             # which route the expert layers' grouped matmuls took, a count
             # a traced call ("megablox": the kernel; "xla": `ragged_dot`),
             # and the kernel's tiles by the matrix it read, which

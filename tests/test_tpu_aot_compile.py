@@ -173,6 +173,12 @@ _CASES = [
     # ... and the decode engine's whole-prompt prefill at [1, bucket]
     pytest.param(_splash(True, False), _QKV, (1, 512, 12, 64),
                  id="splash-causal-prefill-T512"),
+    # the causal prompt kernel (PR 59) at GPT-2-large's 1024 bucket, and
+    # one head a tile at the longest length it takes
+    pytest.param(lambda q, k, v: A._causal_mha(q, k, v, 0.125, False), _QKV,
+                 (1, 1024, 20, 64), id="causal-prompt-gpt2-large-T1024"),
+    pytest.param(lambda q, k, v: A._causal_mha(q, k, v, 128 ** -0.5, False),
+                 _QKV, (1, 4096, 16, 128), id="causal-prompt-16x128-T4096"),
     # one ring shard's block (T 4096 over sp=2, 12 heads over tp=2)
     pytest.param(lambda q, k, v: A._splash_block_with_lse(q, k, v), _QKV,
                  (8, 2048, 6, 64), id="splash-block-lse-ringshard"),
@@ -586,9 +592,19 @@ def test_gpt2_large_serve_program_addresses_the_pool_in_place(
     if on_chip:
         monkeypatch.setattr(A, "_platform", lambda q: "tpu")
     kind, n = program.split("@")
+    A.GATE_COUNTS.clear()
     pool, compiled = (_compile_decode if kind == "decode"
                       else _compile_prefill)(gpt2_large, int(n))
     assert pool.shape == (36, 1025, 16, 1280)
+    if kind == "prefill":
+        # a prompt's attention: one trace for the 36 layers of the scan;
+        # the 1024 bucket takes the causal kernel on the chip (PR 59) with
+        # no head-view copy beside it, the 512 bucket XLA's ops as before
+        route = "causal" if on_chip and n == "1024" else "xla"
+        assert A.GATE_COUNTS == {route: 1}, A.GATE_COUNTS
+        if route == "causal":
+            assert not re.findall(r"= bf16\[1,20,1024,64\]",
+                                  compiled.as_text())
     ma = compiled.memory_analysis()
     # parent (pools in xs/ys, [.., 20, 64]): 3.80 GB decode, 3.60 prefill
     assert ma.temp_size_in_bytes < 0.5e9, ma
