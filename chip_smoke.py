@@ -18,6 +18,7 @@ over. Numbers printed here are information, not benchmark results.
                                     serve_nemotron, serve_jamba,
                                     serve_longcat, longcat_experts,
                                     serve_granite, granite_experts,
+                                    serve_trinity, trinity_experts,
                                     paged_attention
     python chip_smoke.py --chips 4  the cross-chip path only: BERT-base
                                     sharded dp x tp=2 vs the same batch on
@@ -50,6 +51,7 @@ NEMOTRON_LOGIT_TOL = 0.4  # benchmarks/configs/nemotron3_nano.json argues it
 JAMBA_LOGIT_TOL = 2.5    # benchmarks/configs/jamba2_3b.json argues it
 LONGCAT_LOGIT_TOL = 0.3  # benchmarks/configs/longcat_flash_chat.json argues it
 GRANITE_LOGIT_TOL = 0.03  # benchmarks/configs/granite4_h_small.json argues it
+TRINITY_LOGIT_TOL = 0.5   # benchmarks/configs/trinity_mini.json argues it
 
 # jax.monitoring feed: how many programs JAX was asked to compile, and how
 # many of those its persistent cache answered (a hit still counts as a
@@ -397,6 +399,23 @@ def _granite_reference_gaps(params, cfg, prompts, streams):
                                    streams)
 
 
+def _trinity_reference_gaps(params, cfg, prompts, streams):
+    """As `_granite_reference_gaps`, against the benchmark's plain float32
+    Trinity (benchmarks/reference/afmoe_ref.py: a block at a time, no
+    cache, no ring, no slices, no code of models/afmoe.py)."""
+    import dataclasses
+
+    from benchmarks.reference import afmoe_ref
+
+    ref = dict(dataclasses.asdict(cfg), q_block=min(256, cfg.prompt_slice))
+    top = {k: v for k, v in params.items()
+           if k.split(".")[0] in ("wte", "ln_f", "head")}
+    width = max(len(p) for p in prompts) + len(streams[0])
+    return afmoe_ref.stream_gaps(
+        top, lambda b: afmoe_ref.layer_of(params, ref, b), ref, prompts,
+        streams, width)
+
+
 def _jamba_reference_gaps(params, cfg, prompts, streams):
     """As `_nemotron_reference_gaps`, against the benchmark's plain float32
     Jamba (benchmarks/reference/jamba_ref.py: the selective recurrence
@@ -720,6 +739,33 @@ def granite_experts_phase(info: dict, cfg, rows=(48, 1024), layer: int = 1,
             key, cfg, 2 * l + 1, "E"))
 
 
+def trinity_experts_phase(info: dict, cfg, rows=(32, 1024), layer: int = 1,
+                          tol: float = 0.03) -> dict:
+    """Trinity's expert layer ALONE against the plain reference's
+    (`_held_experts_phase`, under the scope `mlp`): a decode step's 32 rows
+    (256 pairs, about 2 a held expert) and a prompt slice's 1024; sigmoid
+    scores, a bias that selects, the kept weights normalised and scaled;
+    the shared expert is in both sides."""
+    import jax
+
+    from benchmarks.reference import afmoe_ref
+    from paddle_tpu.models import afmoe
+
+    model = {"n_experts": cfg.n_experts, "top_k": cfg.top_k,
+             "route_scale": cfg.route_scale,
+             "held": list(cfg.routing.held_range)}
+    return _held_experts_phase(
+        info, cfg, rows, layer, tol, model=model, scope="mlp",
+        experts=tuple("blk." + k for k in afmoe._EXPERTS),
+        ref_experts=afmoe_ref.experts,
+        weights=lambda lp, y: afmoe_ref.route(
+            jax.nn.sigmoid(y @ lp["blk.router"]), lp["blk.router_bias"],
+            model),
+        # expert layer l is block 2 (dense_layers + l) + 1
+        make_layer=lambda key, l: afmoe.init_layer(
+            key, cfg, 2 * (cfg.dense_layers + l) + 1, "moe"))
+
+
 def _held_experts_phase(info: dict, cfg, rows, layer: int, tol: float, *,
                         model, scope, experts, ref_experts, weights,
                         make_layer) -> dict:
@@ -855,7 +901,8 @@ def run_one_chip() -> None:
     import numpy as np
 
     import paddle_tpu as pt
-    from paddle_tpu.models import (bert, gpt, granite_hybrid, jamba, joyai,
+    from paddle_tpu.models import (afmoe, bert, gpt, granite_hybrid, jamba,
+                                   joyai,
                                    longcat, nemotron_h, olmoe, xing4)
     from paddle_tpu.parallel import MeshConfig, make_mesh
     from paddle_tpu.serving.decode import DecodeConfig
@@ -1123,6 +1170,38 @@ def run_one_chip() -> None:
     with phase("granite_experts") as info:
         granite_experts_phase(info, granite_hybrid.GraniteHybridConfig(
             pattern="MM", held=(0, 36), vocab_size=12544))
+        assert info["checked"]["routes"] == {"megablox": 6}, info
+
+    # Trinity-Mini at its published widths (32Q/4KV attention behind a
+    # sigmoid output gate and between two norms; `W*`: a sliding-window
+    # layer of 2048 keys with rotary positions over the WINDOW kind's ring,
+    # a full layer without positions over the global kind; a dense MLP,
+    # then 8 held of 128 sigmoid-routed experts), an eighth of the
+    # vocabulary, prompts walked in slices of 1024 to 4096 tokens and
+    # decoded past the ring's wrap (3088 tokens): both kinds through the
+    # grouped-query kernel, the window kind's from its window's first block
+    tcfg = afmoe.AfmoeConfig(
+        pattern="W*", dense_layers=1, held=(0, 8), vocab_size=12512,
+        max_len=8192)
+    prompts = [rng.randint(0, tcfg.vocab_size, n).tolist()
+               for n in (700, 3000, 4096, 2500)]
+    with phase("serve_trinity") as info:
+        serve_phase(info, tcfg, DecodeConfig(
+            block_size=16, num_blocks=4 * 512 + 1, decode_slots=(4,),
+            prefill_buckets=(1024, 4096)), prompts, max_new=40,
+            logit_tol=TRINITY_LOGIT_TOL, model=afmoe,
+            reference_gaps=_trinity_reference_gaps)
+        checked = info["checked"]
+        assert checked["decode_attention"] == {
+            "paged_gqa": 1, "paged_gqa_window": 1}, info
+        # three matmuls, one expert layer, three programs
+        assert checked["expert_matmul"]["routes"] == {"megablox": 9}, info
+
+    # the expert layer alone at the cell's widths and share (64 of 128
+    # held, top-8) against the reference's, the held experts' term by itself
+    with phase("trinity_experts") as info:
+        trinity_experts_phase(info, afmoe.AfmoeConfig(
+            pattern="WW*", dense_layers=1, held=(0, 64), vocab_size=12512))
         assert info["checked"]["routes"] == {"megablox": 6}, info
 
     # Mosaic is not run by the CPU tests: the short kernel alone at the

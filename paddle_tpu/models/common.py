@@ -120,6 +120,20 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return rms(x, scale, eps)
 
 
+def rope_half(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding of `x` [..., heads, head_dim] at `positions` [...]:
+    the rotate-half convention over the whole head dimension, angles and
+    rotation in float32."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 def gelu(x):
     return jax.nn.gelu(x, approximate=True)
 

@@ -35,6 +35,17 @@ The engine owns the pools and hands the programs a row id a sequence, as
 it hands them block tables; the programs carry the pools beside the K/V
 pools and the MODEL reads and writes its rows (`ssm_prompt`, `ssm_token`).
 
+A model may keep TWO KINDS of cache (`ServeModel.window`): layers that see
+the newest `window` keys alone (`W` blocks of a `pattern`) among layers that
+see every key (`*`). The window layers' K/V live in pools of their own,
+`[window_layers, NBw, BS, width]`, under tables of their own
+(`kv_cache.window_table`: a sequence's ring of blocks, repeated), which the
+engine hands the programs beside `state`; the pools ride LAST in `state`.
+Every writer and reader addresses position `p` at block `p // BS` of the
+kind's table as ever; what is new is that a window layer's readers start at
+the window's first key (`gqa_slice`, `mha_cached`, the paged walk's
+`window_tables`).
+
 A model whose prompts are too long for one pass says so with
 `prompt_slice`: its prefill program walks the prompt in slices of that
 many tokens (`prefill_sliced`), each slice through every block, its K/V
@@ -74,9 +85,13 @@ class ServeModel:
     max_len: int            # positions the model can address
     refusal: Optional[str] = None
     # blocks of ONE mixer each: a character a block, `M` a recurrent (state
-    # space) mixer, `E` the `mlp` piece alone, `*` attention alone; None:
-    # every block is attention then `mlp` (`serve_layers` says how each runs)
+    # space) mixer, `E` the `mlp` piece alone, `*` attention alone, `W`
+    # attention over the newest `window` keys alone; None: every block is
+    # attention then `mlp` (`serve_layers` says how each runs)
     pattern: Optional[str] = None
+    # keys a `W` block's query sees, its own among them; None: the model has
+    # no such block and ONE kind of cache
+    window: Optional[int] = None
     # tokens a slice of the prefill program's walk over a prompt
     # (`prefill_sliced`); None: a prompt goes through in one pass
     prompt_slice: Optional[int] = None
@@ -107,6 +122,12 @@ class ServeModel:
     def kv_layers(self) -> int:
         """Layers of the K/V pools: one for every attention sub-layer."""
         return self.layers * self.sub_blocks
+
+    @property
+    def window_layers(self) -> int:
+        """Layers of the WINDOW kind's pools (the `W` blocks); 0 for a model
+        of one cache kind."""
+        return (self.pattern or "").count("W") if self.window else 0
 
     def state_pools(self, rows: int, dtype) -> Tuple:
         """((shape, dtype), ...) of the pools of per-sequence state that is
@@ -172,7 +193,9 @@ class ServeModel:
         width]` of positions 0..M-1, `pos` `[S, W]`: row (s, w) sees the
         positions `<= pos[s, w]` -> `[S, W, ctx]`. `extra`: the rated
         entries gathered through the same tables, `[S, MB, E * width]` each
-        (`kv_cache.gather_rated`), for a model that stores them."""
+        (`kv_cache.gather_rated`), for a model that stores them. A model
+        with `W` blocks also takes `window=` (a `W` block alone is told it:
+        the newest `window` of those positions alone, `mha_cached`)."""
         return mha_cached(q, keys, vals, pos, self.heads, self.kv_heads)
 
     def paged_route(self, x, k_pool, v_pool) -> Optional[str]:
@@ -190,7 +213,10 @@ class ServeModel:
         """One query row a slot, q `[S, ...]`, against the live blocks of
         layer `layer` of the pools through the table -> `[S, ctx]`; only
         where `paged_route` named a kernel. `rated`: the pools of the
-        entries stored at a rate, for a model that stores them."""
+        entries stored at a rate, for a model that stores them. A model
+        with `W` blocks also takes `window=` (a `W` block alone is told it:
+        the pools and tables are then the window kind's, the tables as
+        `paged_attention.window_tables` made them)."""
         from ..ops.pallas import paged_attention as pa
 
         if self.kv_heads != self.heads:
@@ -392,7 +418,7 @@ def pattern_blocks(pattern: str):
 
 
 # a `pattern` block's kind -> what `res_in` / `res_out` call its sub-layer
-_SUB_LAYER = {"M": "ssm", "E": "mlp", "*": "attn"}
+_SUB_LAYER = {"M": "ssm", "E": "mlp", "*": "attn", "W": "attn"}
 
 
 def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
@@ -402,7 +428,10 @@ def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
     the blocks one by one in the pattern's order, `h + mixer(norm(h))`
     each, every kind's parameters addressed in its own stack by the
     block's index AMONG ITS KIND, which is also its layer in the K/V pools
-    (`*`) or the state pools (`M`). `attend` as in `serve_layers`;
+    (`*`), the window kind's pools (`W`) or the state pools (`M`). `attend`
+    as in `serve_layers`; for a `W` block it is called with `windowed=True`
+    and the program's closure takes that kind's pools and tables (they ride
+    last in `rated`);
     `ssm(i, lp, y, state) -> (out, state)` is the program's recurrent
     mixer (a whole prompt, or a token a slot). Returns (x, k_pool, v_pool,
     the `E` blocks' counters stacked or None, state, rated)."""
@@ -420,6 +449,11 @@ def mixer_layers(model: ServeModel, params: Params, x: jax.Array,
             elif kind == "E":
                 out, st = model.mlp(lp, y, params, i)
                 stats.append(model.res_counters(st, kept))
+            elif kind == "W":
+                q, k, v = model.qkv(lp, y, positions, windowed=True)
+                out, k_pool, v_pool, rated = attend(
+                    jnp.int32(i), lp, q, k, v, k_pool, v_pool, rated,
+                    windowed=True)
             else:
                 q, k, v = model.qkv(lp, y, positions)
                 out, k_pool, v_pool, rated = attend(
@@ -500,17 +534,45 @@ def serve_layers(model: ServeModel, params: Params, x: jax.Array,
 
 def _split_state(model: ServeModel, state):
     """The programs' `state` as (the state row pools, the pools of the
-    entries stored at a rate): the engine carries them as one tuple, the
-    rated ones last."""
+    entries stored at a rate, the two pools of the window kind): the engine
+    carries them as one tuple, in that order."""
+    state = tuple(state)
+    w = 2 if model.window else 0
     n = len(model.rated)
-    return (tuple(state[:len(state) - n]), tuple(state[len(state) - n:])) \
-        if n else (tuple(state), ())
+    rest = len(state) - w
+    return state[:rest - n], state[rest - n:rest], state[rest:]
+
+
+def _window_attend(model: ServeModel, attend_one):
+    """A program's `attend` for a model with a window kind, from
+    `attend_one(l, lp, q, k, v, kp, vp, rt, windowed)` over ONE kind's
+    pools: a `W` block's call (`windowed=True`) is made on the window
+    kind's pools, which ride last in `rated` through the layer loop."""
+    def attend(l, lp, q, k, v, kp, vp, rated, windowed=False):
+        *rt, wk, wv = rated
+        if windowed:
+            ctx, wk, wv, rt = attend_one(l, lp, q, k, v, wk, wv, tuple(rt),
+                                         True)
+        else:
+            ctx, kp, vp, rt = attend_one(l, lp, q, k, v, kp, vp, tuple(rt),
+                                         False)
+        return ctx, kp, vp, (*rt, wk, wv)
+
+    return attend
+
+
+def _needs_slices(model: ServeModel):
+    if model.window and not model.prompt_slice:
+        raise ValueError(
+            "a model with a window kind walks its prompts in slices "
+            "(`prompt_slice`): a ring holds the window and ONE slice, and a "
+            "whole prompt's blocks would land on each other")
 
 
 def prefill(model: ServeModel, params: Params, ids: jax.Array,
             length: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-            block_table: jax.Array, state=(), row=None, *, block_size: int,
-            eos_id: int):
+            block_table: jax.Array, state=(), row=None, window_table=None,
+            *, block_size: int, eos_id: int):
     """One prompt through the stack, filling its KV blocks.
 
     ids [1, T] (edge-padded to the prefill bucket T), length = true
@@ -527,14 +589,17 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
     state (`ServeModel.ssm_prompt`: the padded tail leaves the state as
     position `length - 1` left it), and returns (tok, k_pool, v_pool,
     state). A model that walks its prompts in slices (`prompt_slice`) goes
-    through `prefill_sliced`.
+    through `prefill_sliced`; a model with a window kind always does, and
+    also takes the sequence's `window_table` (`state` then ends with that
+    kind's two pools).
     """
     from ..serving import kv_cache as kvc
 
+    _needs_slices(model)
     if model.prompt_slice:
         assert not model.prefill_counters, "a sliced walk returns no counters"
         return prefill_sliced(model, params, ids, length, k_pool, v_pool,
-                              block_table, state, row,
+                              block_table, state, row, window_table,
                               block_size=block_size, eos_id=eos_id)
     B, T = ids.shape
     adt = k_pool.dtype
@@ -569,8 +634,8 @@ def prefill(model: ServeModel, params: Params, ids: jax.Array,
 
 def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
                    length: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                   block_table: jax.Array, state=(), row=None, *,
-                   block_size: int, eos_id: int):
+                   block_table: jax.Array, state=(), row=None,
+                   window_table=None, *, block_size: int, eos_id: int):
     """`prefill` for a model that walks a prompt in SLICES
     (`ServeModel.prompt_slice`): ids [1, T] go through the whole stack C =
     min(T, prompt_slice) tokens at a time, in ONE program. A slice's K and
@@ -581,7 +646,10 @@ def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
     slice before left (`ssm_slice`), so no temporary grows with T: a
     32k-token prompt costs the memory of a 2k one. Only the slices that
     hold real tokens run; the slice of position `length - 1` gives the
-    first token. Returns what `prefill` returns."""
+    first token. A `W` block's K/V go into the window kind's pools (the last
+    two of `state`) through the sequence's `window_table`, a ring repeated:
+    a slice's blocks land on what fell out of every later query's reach.
+    Returns what `prefill` returns."""
     from ..serving import kv_cache as kvc
 
     _, T = ids.shape
@@ -591,32 +659,44 @@ def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
             f"a prompt walked in slices needs a bucket of whole slices of "
             f"whole blocks: bucket {T}, slice {C}, block {block_size}")
     adt = k_pool.dtype
-    rows_state, rated = _split_state(model, state)
+    rows_state, rated, windowed = _split_state(model, state)
     per_slice = C // block_size
     # the blocks a prompt of this bucket can own: what a slice's queries
     # gather and score is bounded by the bucket, not by the table's width
     block_table = block_table[:T // block_size]
+    if windowed:
+        window_table = window_table[:T // block_size]
 
     def one(s, carry):
         kp, vp, st, rt, last_x = carry
         start = s * C
         slice_ids = jax.lax.dynamic_slice_in_dim(ids, start, C, axis=1)
         positions = (start + jnp.arange(C, dtype=jnp.int32))[None]
-        blocks = jax.lax.dynamic_slice_in_dim(
-            block_table, s * per_slice, per_slice)
         with jax.named_scope("embed"):
             x = model.widen(params, model.embed(params, slice_ids, positions)
                             .astype(adt))
 
-        def attend(l, lp, q, k, v, kp, vp, rt):
+        def attend(l, lp, q, k, v, kp, vp, rt, of_window=False):
+            table = window_table if of_window else block_table
+            blocks = jax.lax.dynamic_slice_in_dim(
+                table, s * per_slice, per_slice)
             kp = kvc.write_prefill_kv(kp, l, k[0].reshape(C, *kp.shape[3:]),
                                       blocks, block_size)
             vp = kvc.write_prefill_kv(vp, l, v[0].reshape(C, *vp.shape[3:]),
                                       blocks, block_size)
+            if of_window:
+                with jax.named_scope("window_attention"):
+                    ctx, rt = model.attend_slice(
+                        lp, q, kp, vp, rt, l, table, start, block_size,
+                        window=model.window)
+                return ctx, kp, vp, rt
             with jax.named_scope("attention"):
                 ctx, rt = model.attend_slice(lp, q, kp, vp, rt, l,
                                              block_table, start, block_size)
             return ctx, kp, vp, rt
+
+        if windowed:
+            attend = _window_attend(model, attend)
 
         def ssm(i, lp, y, st):
             return model.ssm_slice(lp, y, start, length, st, i, row)
@@ -626,6 +706,9 @@ def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
         # the last real position lies in the last slice that runs
         at = jnp.clip(jnp.maximum(length, 1) - 1 - start, 0, C - 1)
         return kp, vp, st, rt, x[0, at]
+
+    # the window kind's pools ride last in what the layer loop threads
+    rated = rated + windowed
 
     # what ONE row carries, by the model (`widen`): `[hidden]` by default
     carried = jax.eval_shape(
@@ -645,13 +728,21 @@ def prefill_sliced(model: ServeModel, params: Params, ids: jax.Array,
     return tok, k_pool, v_pool
 
 
+def _window_mask(window: Optional[int], t, tok):
+    """Whether key `tok` is one of the newest `window` that query `t` sees
+    (`t - tok < window`: `window` keys, the query's own among them); True
+    everywhere without a window. The causal `tok <= t` is the caller's."""
+    return True if window is None else t - tok < window
+
+
 def gqa_prompt(q: jax.Array, k: jax.Array, v: jax.Array, heads: int,
-               kv_heads: int, scale: Optional[float] = None) -> jax.Array:
+               kv_heads: int, scale: Optional[float] = None,
+               window: Optional[int] = None) -> jax.Array:
     """Causal grouped-query attention of whole sequences from their own
     projections: q `[B, T, heads*D]`, k and v `[B, T, kv_heads*D]`; the
     `heads / kv_heads` query heads of a K/V head read it together, scores
-    and softmax in float32 at `scale` (None: `1/sqrt(D)`) -> `[B, T,
-    heads*D]`."""
+    and softmax in float32 at `scale` (None: `1/sqrt(D)`), over the newest
+    `window` keys alone where one is given -> `[B, T, heads*D]`."""
     B, T = q.shape[:2]
     D = q.shape[-1] // heads
     q = q.reshape(B, T, kv_heads, heads // kv_heads, D)
@@ -661,6 +752,9 @@ def gqa_prompt(q: jax.Array, k: jax.Array, v: jax.Array, heads: int,
                         preferred_element_type=jnp.float32) \
         * (1.0 / math.sqrt(D) if scale is None else scale)
     seen = jnp.tril(jnp.ones((T, T), bool))
+    if window is not None:
+        at = jnp.arange(T, dtype=jnp.int32)
+        seen = seen & _window_mask(window, at[:, None], at[None, :])
     att = jax.nn.softmax(jnp.where(seen, scores, -1e9), axis=-1)
     ctx = jnp.einsum("bgrts,bsgd->btgrd", att.astype(v.dtype), v)
     return ctx.reshape(B, T, heads * D)
@@ -671,7 +765,8 @@ SLICE_KEYS = 1024   # keys a chunk of a prompt slice's walk (`gqa_slice`)
 
 def gqa_slice(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, layer,
               block_table: jax.Array, start, block_size: int, heads: int,
-              kv_heads: int, scale: Optional[float] = None) -> jax.Array:
+              kv_heads: int, scale: Optional[float] = None,
+              window: Optional[int] = None) -> jax.Array:
     """Grouped-query attention of ONE SLICE of a prompt against the cache so
     far (an `attend_slice` for plain K and V): the queries q `[1, C,
     heads*D]` of positions `start .. start + C - 1` against layer `layer` of
@@ -679,7 +774,10 @@ def gqa_slice(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, layer,
     it. The keys are walked in chunks of `SLICE_KEYS` tokens up to the
     slice's own, each gathered through the table and met by all the
     slice's queries under an online softmax: float32 scores of `heads x C x
-    SLICE_KEYS` whatever the prompt's length -> `[1, C, heads*D]`."""
+    SLICE_KEYS` whatever the prompt's length -> `[1, C, heads*D]`. Under a
+    `window` the walk starts at the chunk of the first key the slice's FIRST
+    query sees (`start - window + 1`) and a query sees its newest `window`
+    keys alone: a 32k prompt's slice computes `window + C` keys, not 32k."""
     from ..serving import kv_cache as kvc
 
     f32 = jnp.float32
@@ -705,7 +803,9 @@ def gqa_slice(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, layer,
         sc = jnp.einsum("qgrd,kgd->grqk", qh, kch,
                         preferred_element_type=f32) * scale
         tok = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
-        ok = (tok[None, :] <= t[:, None])[None, None]     # [1, 1, C, chunk]
+        ok = (tok[None, :] <= t[:, None]) \
+            & _window_mask(window, t[:, None], tok[None, :])
+        ok = ok[None, None]                               # [1, 1, C, chunk]
         sc = jnp.where(ok, sc, -1e30)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -716,8 +816,10 @@ def gqa_slice(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, layer,
             preferred_element_type=f32)
         return m_new, l, acc
 
+    first = 0 if window is None \
+        else jnp.maximum(start - (int(window) - 1), 0) // chunk
     _, l, acc = jax.lax.fori_loop(
-        0, (start + C) // chunk, one,
+        first, (start + C) // chunk, one,
         (jnp.full((G, R, C, 1), -1e30, f32), jnp.zeros((G, R, C, 1), f32),
          jnp.zeros((G, R, C, D), f32)))
     ctx = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)      # [G, R, C, D]
@@ -726,24 +828,31 @@ def gqa_slice(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, layer,
 
 def mha_cached(q: jax.Array, keys: jax.Array, vals: jax.Array,
                pos: jax.Array, heads: int, kv_heads: Optional[int] = None,
-               scale: Optional[float] = None) -> jax.Array:
+               scale: Optional[float] = None,
+               window: Optional[int] = None) -> jax.Array:
     """Multi-head attention of query rows over a gathered context (the
     default `ServeModel.attend_cached`): q `[S, W, heads*head_dim]`, keys
     and vals `[S, M, kv_heads*head_dim]`, row (s, w) sees key positions
     `<= pos[s, w]` -> `[S, W, heads*head_dim]`. With fewer K/V heads than
     query heads, a K/V head's query heads read it together, at `scale`
-    where a model's softmax is not at `1/sqrt(head_dim)`."""
+    where a model's softmax is not at `1/sqrt(head_dim)`; under `window`
+    the newest `window` of those positions alone (the context is then a
+    ring read through its repeated table: what lies at an older position
+    is a newer key's, and masked)."""
     S, W, width = q.shape
     m = keys.shape[1]
     hd = width // heads
+    if window is not None and (kv_heads is None or kv_heads == heads):
+        raise NotImplementedError("a window over multi-head K/V")
     if kv_heads is not None and kv_heads != heads:
         q = q.reshape(S, W, kv_heads, heads // kv_heads, hd)
         keys = keys.reshape(S, m, kv_heads, hd)
         vals = vals.reshape(S, m, kv_heads, hd)
         scores = jnp.einsum("swgrd,smgd->swgrm", q, keys) \
             * (1.0 / math.sqrt(hd) if scale is None else scale)
-        mask = jnp.arange(m, dtype=jnp.int32)[None, None, :] \
-            <= pos[:, :, None]
+        at = jnp.arange(m, dtype=jnp.int32)[None, None, :]
+        mask = (at <= pos[:, :, None]) \
+            & _window_mask(window, pos[:, :, None], at)
         scores = jnp.where(mask[:, :, None, None, :], scores, -1e9)
         att = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
         ctx = jnp.einsum("swgrm,smgd->swgrd", att.astype(keys.dtype), vals)
@@ -762,7 +871,7 @@ def mha_cached(q: jax.Array, keys: jax.Array, vals: jax.Array,
 def cached_attention(attend, q: jax.Array, k_pool: jax.Array,
                      v_pool: jax.Array, layer: jax.Array,
                      block_tables: jax.Array, pos: jax.Array,
-                     rated=()) -> jax.Array:
+                     rated=(), scope: str = "attention") -> jax.Array:
     """Attention of the query rows q `[S, W, ...]` over a gathered copy of
     every slot's whole table: layer `layer` of the pools through
     block_tables `[S, MB]`, key positions `<= pos[s, w]`, by `attend(q,
@@ -779,7 +888,7 @@ def cached_attention(attend, q: jax.Array, k_pool: jax.Array,
     with jax.named_scope("kv_gather"):
         extra = tuple(kvc.gather_rated(p, layer, block_tables)
                       for p in rated)
-    with jax.named_scope("attention"):
+    with jax.named_scope(scope):
         if extra:
             return attend(q, keys, vals, pos, extra)
         return attend(q, keys, vals, pos)
@@ -788,7 +897,8 @@ def cached_attention(attend, q: jax.Array, k_pool: jax.Array,
 def decode_step(model: ServeModel, params: Params, ids: jax.Array,
                 positions: jax.Array, k_pool: jax.Array,
                 v_pool: jax.Array, block_tables: jax.Array, state=(),
-                rows=None, *, block_size: int, eos_id: int):
+                rows=None, window_tables=None, *, block_size: int,
+                eos_id: int):
     """One decode step for S resident slots.
 
     ids [S] (each slot's previous token), positions [S] (where this
@@ -801,8 +911,11 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     takes its `state` pools and each slot's row `rows` [S] (0, the null
     row, for an idle slot), advances the rows in place
     (`ServeModel.ssm_token`) and returns the pools as a fifth result; the
-    pools of entries stored at a rate (`ServeModel.rated`) are the last of
-    `state`, written by `store_token` and read by the attention pieces."""
+    pools of entries stored at a rate (`ServeModel.rated`) follow in
+    `state`, written by `store_token` and read by the attention pieces, and
+    the window kind's two pools (`ServeModel.window`) are its last, written
+    and read through `window_tables` `[S, MB]`: a `W` block's walk starts at
+    its window's first block."""
     from ..ops.pallas import paged_attention as pa
     from ..serving import kv_cache as kvc
 
@@ -820,12 +933,39 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     # what the kernel's walk may fetch in one copy, counted once a step
     # and not once a layer
     tables = pa.with_runs(block_tables, k_pool, v_pool) if route else None
-    rows_state, rated = _split_state(model, state)
+    rows_state, rated, windowed = _split_state(model, state)
     if rated:
         tables = model.rated_tables(x, tables, rated, positions,
                                     block_size)
+    wroute = wtables = None
+    if windowed:
+        # the window kind asks the same gate over its own pools, and its
+        # walk's tables (from each slot's window's first block on) are
+        # counted once a step too
+        wroute = model.paged_route(x, *windowed)
+        pa.GATE_COUNTS[wroute + "_window" if wroute
+                       else "gather_window"] += 1
+        if wroute:
+            wtables = pa.window_tables(window_tables, positions,
+                                       model.window, *windowed)
 
-    def attend(l, lp, q, k, v, kp, vp, rt):
+    def attend(l, lp, q, k, v, kp, vp, rt, of_window=False):
+        if of_window:
+            kp = kvc.write_token_kv(kp, l, k.reshape(S, *kp.shape[3:]),
+                                    window_tables, positions, block_size)
+            vp = kvc.write_token_kv(vp, l, v.reshape(S, *vp.shape[3:]),
+                                    window_tables, positions, block_size)
+            if wroute:
+                with jax.named_scope("window_attention"):
+                    ctx = model.attend_paged(lp, q, kp, vp, l, wtables,
+                                             positions, window=model.window)
+            else:
+                ctx = cached_attention(
+                    functools.partial(model.attend_cached, lp,
+                                      window=model.window), q[:, None], kp,
+                    vp, l, window_tables, positions[:, None],
+                    scope="window_attention")[:, 0]
+            return ctx, kp, vp, rt
         kp = kvc.write_token_kv(kp, l, k.reshape(S, *kp.shape[3:]),
                                 block_tables, positions, block_size)
         vp = kvc.write_token_kv(vp, l, v.reshape(S, *vp.shape[3:]),
@@ -847,9 +987,11 @@ def decode_step(model: ServeModel, params: Params, ids: jax.Array,
     def ssm(i, lp, y, st):
         return model.ssm_token(lp, y, st, i, rows, positions)
 
+    if windowed:
+        attend = _window_attend(model, attend)
     x, k_pool, v_pool, stats, rows_state, rated = serve_layers(
         model, params, x, positions, k_pool, v_pool, attend, rows_state,
-        ssm, rated)
+        ssm, rated + windowed)
     counters = model.step_counters(positions, block_tables)
     if counters is not None:
         stats = (stats, counters)
@@ -884,6 +1026,8 @@ def prefill_chunk(model: ServeModel, params: Params, ids: jax.Array,
     """
     from ..serving import kv_cache as kvc
 
+    if model.window:
+        raise ValueError("a model with a window kind has no chunked prefill")
     _, C = ids.shape
     adt = k_pool.dtype
     pos = start + jnp.arange(C, dtype=jnp.int32)
@@ -936,6 +1080,8 @@ def verify_step(model: ServeModel, params: Params, ids: jax.Array,
     (tokens [S, W], k_pool, v_pool)."""
     from ..serving import kv_cache as kvc
 
+    if model.window:
+        raise ValueError("a model with a window kind has no verify step")
     S, W = ids.shape
     adt = k_pool.dtype
     pos = positions[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]

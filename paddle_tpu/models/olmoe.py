@@ -32,7 +32,8 @@ import jax.numpy as jnp
 
 from ..parallel.sharding import shard
 from . import decoder as _decoder, moe as _moe
-from .common import Params, rms as _rms, rms_norm as _rms_norm
+from .common import Params, rms as _rms, rms_norm as _rms_norm, \
+    rope_half as _rope
 
 
 @dataclasses.dataclass
@@ -147,20 +148,6 @@ def init(rng: jax.Array, cfg: OlmoeConfig, dtype=jnp.float32
 # `qkv` (holding `qk_norm` and `rope`); `proj`; `mlp` (models/moe.py: holding
 # `router`, `moe_route`: sort, gather and weighted combine, and `experts`:
 # the grouped matmuls); `head`. tests/test_layer_scopes.py holds the list.
-
-
-def _rope(x, positions, theta: float):
-    """Rotary embedding of `x` [..., heads, head_dim] at `positions` [...]:
-    the rotate-half convention over the whole head dimension, angles and
-    rotation in float32."""
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[..., None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
 
 
 @jax.named_scope("qkv")

@@ -174,7 +174,9 @@ def gate_report() -> dict:
 # tokens a compute step: a multiple of 128, so scores [M, _CHUNK] fill
 # their lanes; K and V double-buffered are 4 * _CHUNK * H*D elements of VMEM
 _CHUNK = 256
-# a NARROW cache: `_CHUNK` tokens of both pools are under this many bytes
+# a NARROW cache: `_CHUNK` tokens of both pools are this many bytes at most
+# (4 K/V heads of 128 in bf16, a 16 KB block a pool as the latent cache's, are
+# the widest: no cache lay AT the limit before a model of that shape came)
 _NARROW_CHUNK_BYTES = 512 * 1024
 # a narrow cache's walk takes `_SUB` tokens a compute step; over a table of
 # `_LONG_TABLE` tokens or more it fetches `_LONG_CHUNK` a chunk, and the
@@ -245,7 +247,7 @@ def narrow(token_bytes: int) -> bool:
       of 4608 read 192 -> 169; it keeps 512 all the same: a threshold
       that low would move the grouped-query walk of a 9216-token table
       with it, which this PR did not measure."""
-    return _CHUNK * token_bytes < _NARROW_CHUNK_BYTES
+    return _CHUNK * token_bytes <= _NARROW_CHUNK_BYTES
 
 
 def chunk_tokens(token_bytes: int, table_tokens: int) -> int:
@@ -534,13 +536,14 @@ def _through_chunks(s, n_slots, chunks_of, each_copy, done_ref, zeroed,
     return query, carry
 
 
-def _softmax_step(sc, vals, c, pos, carry):
+def _softmax_step(sc, vals, c, pos, carry, first=None):
     """One chunk of the online softmax: scores `sc` [M, chunk] (float32,
-    scaled) of the tokens `c * chunk ...`, of which those `<= pos` count,
-    against `vals` [chunk, width]."""
+    scaled) of the tokens `c * chunk ...`, of which those `<= pos` count
+    (and, under a window, `>= first`), against `vals` [chunk, width]."""
     m, l, acc = carry
     tok = c * sc.shape[1] + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-    sc = jnp.where(tok <= pos, sc, _MASKED)
+    seen = tok <= pos if first is None else (tok <= pos) & (tok >= first)
+    sc = jnp.where(seen, sc, _MASKED)
     m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(sc - m_new)
@@ -556,7 +559,8 @@ def _softmax_init(rows: int, width: int):
             jnp.zeros((rows, width), jnp.float32))
 
 
-def _softmax_chunk(tile, chunk: int, c, pos, carry, newest=None):
+def _softmax_chunk(tile, chunk: int, c, pos, carry, newest=None,
+                   first=None):
     """Chunk `c` of the online softmax. `tile(rows) -> (scores [M, rows]
     float32 and scaled, values [rows, width])` of those rows of the chunk's
     buffers. A chunk of `_SUB` tokens at most is one tile, as ever. A
@@ -569,9 +573,10 @@ def _softmax_chunk(tile, chunk: int, c, pos, carry, newest=None):
     to the sub-tile that holds `pos` and no further, so that no arithmetic
     is done on dead tokens whatever the chunk's length. Where `pos` is a
     position a ROW (the sparse walk's K/V heads may end apart), `newest` is
-    the largest of them."""
+    the largest of them. `first`: the first key a WINDOWED walk may see (the
+    tokens before it in its block are fetched with the block and masked)."""
     if chunk <= _SUB:
-        return _softmax_step(*tile(slice(None)), c, pos, carry)
+        return _softmax_step(*tile(slice(None)), c, pos, carry, first)
     n_sub = chunk // _SUB
     # the sub-tile that holds `pos`
     last = ((pos if newest is None else newest) - c * chunk) // _SUB
@@ -582,13 +587,13 @@ def _softmax_chunk(tile, chunk: int, c, pos, carry, newest=None):
             sc, vals = nxt
             if j + 1 < n_sub:
                 nxt = tile(pl.ds((j + 1) * _SUB, _SUB))
-            carry = _softmax_step(sc, vals, c * n_sub + j, pos, carry)
+            carry = _softmax_step(sc, vals, c * n_sub + j, pos, carry, first)
         return carry
 
     def part(carry):
         def one(j, carry):
             sc, vals = tile(pl.ds(pl.multiple_of(j * _SUB, _SUB), _SUB))
-            return _softmax_step(sc, vals, c * n_sub + j, pos, carry)
+            return _softmax_step(sc, vals, c * n_sub + j, pos, carry, first)
 
         return lax.fori_loop(0, last + 1, one, carry)
 
@@ -631,18 +636,23 @@ def _kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm, v_hbm,
 
 def _gqa_kernel(layer_ref, tables_ref, pos_ref, lead_ref, q_ref, k_hbm,
                 v_hbm, o_ref, kbuf, vbuf, sems, done_ref, *, kv_heads: int,
-                scale: float, block_size: int):
+                scale: float, block_size: int, first_ref=None):
+    """`first_ref` `[S]` (a WINDOWED walk, `window_tables`: the tables, the
+    positions and this count from the window's first block on): the first
+    key of the walked tokens a slot may see."""
     def walk(setup, step):
         return _walk(layer_ref, tables_ref, pos_ref, lead_ref,
                      (k_hbm, v_hbm), (kbuf, vbuf), sems, done_ref, (vbuf,),
                      setup, step, block_size)
 
     _gqa_body(q_ref, o_ref, kbuf, vbuf, walk,
-              lambda: pos_ref[pl.program_id(0)], kv_heads, scale)
+              lambda: pos_ref[pl.program_id(0)], kv_heads, scale,
+              None if first_ref is None
+              else lambda: first_ref[pl.program_id(0)])
 
 
 def _gqa_body(q_ref, o_ref, kbuf, vbuf, walk, newest, kv_heads: int,
-              scale: float):
+              scale: float, oldest=None):
     """A slot's grouped-query attention over what `walk(setup, step)`
     brings into `kbuf` / `vbuf` `[2, chunk, kv_heads * D]`: the query block
     `[heads, kv_heads * D]`, row h in its K/V head's lanes. `newest()` is
@@ -666,19 +676,21 @@ def _gqa_body(q_ref, o_ref, kbuf, vbuf, walk, newest, kv_heads: int,
             own = own & (pos >= 0)
         q = jnp.concatenate([q_ref[...]] * kv_heads, axis=1)
         q = jnp.where(own, q, jnp.zeros_like(q))
-        return (pos, q, own, last), _softmax_init(heads, width)
+        first = None if oldest is None else oldest()
+        return (pos, q, own, last, first), _softmax_init(heads, width)
 
     def step(query, c, buf, carry):
-        pos, q, _, last = query
+        pos, q, _, last, first = query
 
         def tile(rows):
             sc = lax.dot_general(q, kbuf[buf, rows], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
             return sc, vbuf[buf, rows]
 
-        return _softmax_chunk(tile, kbuf.shape[1], c, pos, carry, last)
+        return _softmax_chunk(tile, kbuf.shape[1], c, pos, carry, last,
+                              first)
 
-    (_, _, own, _), (m, l, acc) = walk(setup, step)
+    (_, _, own, _, _), (m, l, acc) = walk(setup, step)
     # row h keeps its own K/V head's lanes; an inactive slot gives zeros
     ctx = jnp.where(own, acc / jnp.where(l > 0, l, 1.0), 0.0)
     out = ctx[:, :head_dim]
@@ -870,6 +882,11 @@ class Tables(NamedTuple):
     runs: Optional[jax.Array]     # None: a wide cache's walk reads none
     rows: Optional[jax.Array] = None    # None: no rated entry is walked
     few: Optional[jax.Array] = None
+    # a WINDOWED walk's (`window_tables`): `ids` are the table from the
+    # window's first block on, `newest` `[S]` the slot's position counted
+    # from that block's first token and `first` `[S]` the first key it sees
+    first: Optional[jax.Array] = None
+    newest: Optional[jax.Array] = None
 
 
 def with_runs(block_tables, k_pool, v_pool) -> Tables:
@@ -899,6 +916,36 @@ def with_runs(block_tables, k_pool, v_pool) -> Tables:
                         == 1, axis=-1, dtype=jnp.int32)
         runs = jnp.concatenate([runs, after], axis=1)
     return Tables(ids, runs)
+
+
+def window_blocks(window: int, block_size: int) -> int:
+    """Blocks the newest `window` keys can lie in, wherever the newest
+    stands in its block: 129 for 2048 keys in blocks of 16."""
+    return (int(window) - 1 + int(block_size) - 1) // int(block_size) + 1
+
+
+def window_tables(block_tables, positions, window: int, k_pool,
+                  v_pool) -> Tables:
+    """`block_tables` `[S, MB]` as a WINDOWED walk takes them: slot s reads
+    the keys `max(0, p - window + 1) .. p` (p = `positions[s]`), so its walk
+    starts at the block of the first of them and is an ordinary walk over
+    the table FROM THAT BLOCK ON, `window_blocks` entries wide whatever MB
+    is, with the position counted from there (`newest`) and the first
+    block's earlier keys masked (`first`). Counted once a step for all the
+    layers of the window kind, as `with_runs` is; the runs are the shifted
+    table's, so a ring's wrap is a break wherever the window crosses it."""
+    ids = block_tables.astype(jnp.int32)
+    mb = ids.shape[1]
+    bs = k_pool.shape[2]
+    pos = positions.astype(jnp.int32)
+    lo = jnp.maximum(pos - (int(window) - 1), 0)
+    fb = lo // bs
+    at = fb[:, None] + jnp.arange(min(mb, window_blocks(window, bs)),
+                                  dtype=jnp.int32)[None, :]
+    # past the table's end nothing is live: the last entry stands in
+    shifted = jnp.take_along_axis(ids, jnp.minimum(at, mb - 1), axis=1)
+    return with_runs(shifted, k_pool, v_pool)._replace(
+        first=lo - fb * bs, newest=pos - fb * bs)
 
 
 def _next_after(marks: jax.Array, none: int) -> jax.Array:
@@ -952,15 +999,30 @@ def _call_form(kernel, layer, block_tables, positions, *pools):
     `lead_ref`. A narrow cache's prefetches the tables' runs as a fourth
     array and takes the pools as their rows `[L, NB*BS, width]`, the same
     bytes (a block is whole tiles): consecutive blocks are one contiguous
-    span of rows, which one copy takes."""
+    span of rows, which one copy takes. WINDOWED tables (`window_tables`)
+    bring their own positions and one more prefetched array, the first key
+    a slot may see, which the body takes as `first_ref`."""
     tables = with_runs(block_tables, *pools)
+    windowed = tables.first is not None
+    if windowed:        # counted from the window's first block on
+        positions = tables.newest
     scalars = (jnp.reshape(layer, (1,)).astype(jnp.int32), tables.ids,
                positions.astype(jnp.int32))
-    if tables.runs is None:
+    wide = tables.runs is None
+    if not wide:
+        scalars = (*scalars, tables.runs)
+        pools = tuple(p.reshape(p.shape[0], -1, p.shape[3]) for p in pools)
+    if windowed:
+        # the window's first key a slot: one more prefetched array, the last
+        n = len(scalars)
+        scalars = (*scalars, tables.first.astype(jnp.int32))
+        return (lambda *refs: kernel(
+            *refs[:3], None if wide else refs[3], *refs[n + 1:],
+            first_ref=refs[n])), scalars, pools
+    if wide:
         return (lambda layer_ref, tables_ref, pos_ref, *refs: kernel(
             layer_ref, tables_ref, pos_ref, None, *refs)), scalars, pools
-    return kernel, (*scalars, tables.runs), tuple(
-        p.reshape(p.shape[0], -1, p.shape[3]) for p in pools)
+    return kernel, scalars, pools
 
 
 def _scratch(k_pool, v_pool, max_blocks: int, chunk: Optional[int] = None):
@@ -1018,6 +1080,7 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         layer: jax.Array, block_tables: jax.Array,
                         positions: jax.Array, *, heads: int, kv_heads: int,
                         scale: Optional[float] = None,
+                        window: Optional[int] = None,
                         interpret: bool = False) -> jax.Array:
     """Grouped-query decode attention: q `[S, heads*D]` against layer
     `layer` of the pools `[L, NB, BS, kv_heads*D]` through block_tables
@@ -1025,13 +1088,20 @@ def paged_gqa_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     s attends key positions `0..positions[s]` at `scale` (None:
     `1/sqrt(D)`; a model whose softmax is at another scale hands its own),
     scores and softmax in float32, and gets `[heads*D]` in q's dtype; a
-    slot whose table starts with the null block gets zeros. Query heads that are not
+    slot whose table starts with the null block gets zeros. Under `window`
+    slot s attends the newest `window` of those keys alone, and its walk
+    starts at their first block (`window_tables`, which a step's caller
+    makes once for all its windowed layers and hands over as the tables).
+    Query heads that are not
     whole sublane tiles (one K/V head alone: every query head is of its
     group wherever it sits) are filled up with rows of zeros, whose
     context, a plain mean of V, is dropped."""
     n_slots = q.shape[0]
     head_dim = k_pool.shape[3] // kv_heads
     spare = -heads % _query_tile(k_pool)
+    if window is not None and getattr(block_tables, "first", None) is None:
+        block_tables = window_tables(block_tables, positions, window,
+                                     k_pool, v_pool)
     if spare:
         if kv_heads != 1:
             raise ValueError(

@@ -76,7 +76,8 @@ from ..ops.pallas import ssm_update as _ssm_update
 from .batcher import QueueFullError, ServerClosed
 from .kv_cache import (NULL_ROW, PREFILL_WRITE_UNITS, BlockAllocator,
                        KVCacheConfig, NoBlocksError, StateRowAllocator,
-                       build_block_table, init_pools, run_chunks)
+                       build_block_table, init_pools, ring_blocks,
+                       run_chunks, window_table)
 from . import kv_reuse as _kvr
 from .kv_reuse import ReuseBlockAllocator
 
@@ -267,7 +268,7 @@ class _Request:
                  "admitted_at", "tctx", "enqueued_at",
                  "prefill_pos", "draft_pos", "n_reused", "hashes",
                  "tenant", "traced", "parent", "arrival", "preempted",
-                 "state_row")
+                 "state_row", "wblocks")
 
     def __init__(self, rid: int, prompt: np.ndarray, max_new: int,
                  tenant: str = "default"):
@@ -308,6 +309,7 @@ class _Request:
         self.last_token = 0
         self.pos = 0                           # next KV write position
         self.blocks: List[int] = []
+        self.wblocks: List[int] = []           # its ring of the window kind
         self.state_row = NULL_ROW              # its row of the state pools
         self.admitted_at = 0.0
         # KV-reuse state (chunked prefill / prefix cache / speculation)
@@ -455,6 +457,31 @@ class DecodeEngine:
                     "a model with recurrent state, or with entries stored "
                     "at a rate beside a token's two, cannot be served with "
                     + "; ".join(refused))
+        if model.window:
+            # a window layer's ring holds the newest keys of ONE sequence
+            refused = [why for on, why in (
+                (self.config.prefix_cache,
+                 "prefix_cache: a shared prefix's blocks would have to hold "
+                 "its window layers' K/V, and a ring keeps the newest "
+                 "window alone, overwritten as the sequence grows: nothing "
+                 "of a prefix is left to share"),
+                (self.prefill_chunk,
+                 "prefill_chunk: a ring is sized for the window and ONE "
+                 "slice of the prefill program's own walk, and the chunk "
+                 "program gathers a sequence's whole table"),
+                (self.spec_k,
+                 "spec_k: a rejected draft token's K/V has already "
+                 "overwritten the ring's oldest key, which the step after "
+                 "still reads"),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    "a model with a window kind of cache cannot be served "
+                    "with " + "; ".join(refused))
+            if not model.prompt_slice:
+                raise ValueError(
+                    "a model with a window kind of cache walks its prompts "
+                    "in slices (`ServeModel.prompt_slice`)")
         if self.config.precision not in ("f32", "bf16"):
             _precision.get_policy(self.config.precision)  # typo => full msg
             raise ValueError(
@@ -485,6 +512,25 @@ class DecodeEngine:
         self._state_specs = self._row_specs + tuple(
             (shape, np.dtype(self._compute_dtype))
             for shape in self.kv_cfg.rated_pool_shapes)
+        # the WINDOW kind (`ServeModel.window`): pools, an allocator and a
+        # table a sequence of its own, sized HERE from the model and the
+        # slots (`num_blocks` is the global kind's): a ring a slot and the
+        # null block. Its two pools ride last in `state`.
+        self._wkv_cfg = None
+        self._ring = 0
+        if model.window:
+            self._ring = min(
+                ring_blocks(model.window, model.prompt_slice,
+                            self.config.block_size),
+                self.kv_cfg.max_blocks_per_seq)
+            self._wkv_cfg = KVCacheConfig(
+                layers=model.window_layers, widths=model.stored,
+                max_len=max_len, block_size=self.config.block_size,
+                num_blocks=max(self.config.decode_slots) * self._ring + 1,
+                dtype=str(np.dtype(self._compute_dtype)))
+            self._state_specs += tuple(
+                (shape, np.dtype(self._compute_dtype))
+                for shape in self._wkv_cfg.pool_shapes)
         # resolved grid lives on the ENGINE, never written back into
         # the caller's config (a DecodeConfig reused across engines
         # must not carry the first engine's derived bucket set)
@@ -636,6 +682,8 @@ class DecodeEngine:
                             for shape, dt in self._state_specs)
         self._state_alloc = StateRowAllocator(rows, self._row_specs) \
             if self._row_specs else None
+        self._walloc = BlockAllocator(self._wkv_cfg) \
+            if self._wkv_cfg is not None else None
         self._draft_pools = init_pools(self._draft_kv_cfg) \
             if draft is not None else None
         # annotated with the reuse subtype so the lock-order analyzer
@@ -932,10 +980,13 @@ class DecodeEngine:
         mb = kv.max_blocks_per_seq
         base = kind[6:] if draft else kind
         state = tuple(sds(shape, dt) for shape, dt in self._state_specs)
+        # a window kind's tables follow the row id(s)
+        wkind = self._wkv_cfg is not None
         if base == "prefill":
             return (p_sds, sds((1, n), np.int32), sds((), np.int32),
                     kpool, vpool, sds((mb,), np.int32)) \
-                + ((state, sds((), np.int32)) if state else ())
+                + ((state, sds((), np.int32)) if state else ()) \
+                + ((sds((mb,), np.int32),) if wkind else ())
         if base == "chunk":
             return (p_sds, sds((1, n), np.int32), sds((), np.int32),
                     sds((), np.int32), kpool, vpool,
@@ -946,7 +997,8 @@ class DecodeEngine:
                     sds((n, mb), np.int32))
         return (p_sds, sds((n,), np.int32), sds((n,), np.int32),
                 kpool, vpool, sds((n, mb), np.int32)) \
-            + ((state, sds((n,), np.int32)) if state else ())
+            + ((state, sds((n,), np.int32)) if state else ()) \
+            + ((sds((n, mb), np.int32),) if wkind else ())
 
     def warmup(self) -> int:
         """AOT-compile (or adopt from the persistent compile cache /
@@ -1297,6 +1349,7 @@ class DecodeEngine:
             prefilling = len(self._prefilling)
             live_tokens = sum(r.pos for r in self._active)
             live_tokens += sum(r.prefill_pos for r in self._prefilling)
+            window_tokens = self._window_tokens(self._active)
             counts = dict(self._counts)
             draining = self._draining
         grid = {"decode_slots": list(self.decode_slots)}
@@ -1315,7 +1368,7 @@ class DecodeEngine:
             "warmed": self.warmed,
             "warmstart_adopted": self.warmstart_adopted,
             "analysis": self.analysis,
-            "kv": self._alloc.stats(live_tokens=live_tokens),
+            "kv": self._kv_status(live_tokens, window_tokens),
             "requests": counts,
             "step_ms": self._step_ms(),
             "step_facts": self._step_facts,
@@ -1394,6 +1447,34 @@ class DecodeEngine:
                 if self._spec_proposed else None,
             }
         return out
+
+    def _window_tokens(self, reqs) -> int:
+        """Keys the window layers hold for `reqs`: the newest `window` of
+        each, or its length; 0 for a model of one cache kind."""
+        w = self._model.window
+        return sum(min(r.pos, w) for r in reqs if r is not None) if w else 0
+
+    def _kv_status(self, live_tokens: int, window_tokens: int) -> Dict:
+        """`status()["kv"]`: the global kind's allocator as ever, and for a
+        model with a window kind `kinds` (each kind's own numbers; the
+        window's `ring_blocks` and `window` beside them) with `pool_bytes`
+        counting every pool."""
+        kv = self._alloc.stats(live_tokens=live_tokens)
+        if self._walloc is None:
+            return kv
+        own = ("blocks_total", "blocks_free", "blocks_used", "live_tokens",
+               "allocated_token_capacity", "run_chunk_share",
+               "walk_chunk_tokens", "pool_bytes", "bytes_per_token_layer")
+        window = self._walloc.stats(live_tokens=window_tokens)
+        kv["kinds"] = {
+            "global": dict({k: kv[k] for k in own},
+                           layers=self.kv_cfg.layers),
+            "window": dict({k: window[k] for k in own},
+                           layers=self._wkv_cfg.layers,
+                           window=int(self._model.window),
+                           ring_blocks=self._ring)}
+        kv["pool_bytes"] += window["pool_bytes"]
+        return kv
 
     def _step_ms(self) -> Optional[Dict]:
         """Start-to-start times of the last (up to 256) decode steps:
@@ -1497,6 +1578,7 @@ class DecodeEngine:
             self._alloc.free(req.blocks)   # reuse allocator: decref;
             req.blocks = []                # cached blocks go to LRU
         self._free_state_row(req)
+        self._free_ring(req)
         if req in self._active:
             self._active.remove(req)
         if req in self._prefilling:
@@ -1513,6 +1595,15 @@ class DecodeEngine:
         if req.state_row != NULL_ROW:
             self._state_alloc.free(req.state_row)
             req.state_row = NULL_ROW
+
+    def _free_ring(self, req: _Request) -> None:
+        """A sequence that leaves gives its window-kind blocks back, as its
+        state row: whoever takes them writes every key before it reads it
+        (a prefill's slices, then a token a step), after every step already
+        dispatched with this sequence in it."""
+        if req.wblocks:
+            self._walloc.free(req.wblocks)
+            req.wblocks = []
 
     def _record_finish(self, req: _Request, reason: str, now: float):
         if req.t_first is not None and len(req.generated) > 1:
@@ -1543,13 +1634,19 @@ class DecodeEngine:
                 "preemptions": req.preempted, "tenant": req.tenant})
 
     def _step_record(self, kind: str, t: float, slots: int, live: int,
-                     live_tokens: int) -> Dict:
+                     live_tokens: int, window_tokens: int = 0) -> Dict:
         """One row per dispatched program (recording on): what ran, how
-        full it was, and the allocator's own count of blocks."""
+        full it was, and the allocator's own count of blocks (`blocks_*`
+        and `live_tokens` are the global kind's; a model with a window kind
+        has `window_*` beside them)."""
         row = {"t": t, "kind": kind, "slots": slots, "live": live,
                "live_tokens": live_tokens,
                "blocks_used": self._alloc.used_blocks(),
                "blocks_usable": self.kv_cfg.usable_blocks}
+        if self._walloc is not None:
+            row["window_blocks_used"] = self._walloc.used_blocks()
+            row["window_blocks_usable"] = self._wkv_cfg.usable_blocks
+            row["window_tokens"] = window_tokens
         if self._state_alloc is not None:
             row["state_rows_used"] = self._state_alloc.used_rows()
             row["state_rows"] = self._state_alloc.rows - 1
@@ -1672,7 +1769,9 @@ class DecodeEngine:
                 idx = self._pick_waiting_locked()
                 req = self._waiting[idx]
                 need = -(-len(req.prompt) // self.kv_cfg.block_size)
-                if not self._alloc.can_alloc(need):
+                if not self._alloc.can_alloc(need) or (
+                        self._walloc is not None and not
+                        self._walloc.can_alloc(min(need, self._ring))):
                     break  # blocks scale with live tokens: defer
                 del self._waiting[idx]
                 QUEUE_DEPTH.set(len(self._waiting))
@@ -1754,10 +1853,17 @@ class DecodeEngine:
             req.state_row = self._state_alloc.alloc()
         if self._state_specs:
             state = (self._state, np.int32(req.state_row))
+        if self._walloc is not None:
+            # its ring, or its own length where that is shorter: one run
+            # where the free extents hold one
+            req.wblocks = self._walloc.alloc(min(need, self._ring))
+            state += (window_table(req.wblocks, self._ring,
+                                   self.kv_cfg.max_blocks_per_seq),)
         t0 = time.perf_counter()
         wait = row = called = None
         if _tracing.recording:
-            row = self._step_record("prefill", t0, 1, 1, plen)
+            row = self._step_record("prefill", t0, 1, 1, plen,
+                                    min(plen, self._model.window or 0))
             wait = _tracing.open_span("decode.prefill.wait", "decode")
         tok, kp, vp, *out = self._prefill[bucket](
             self.params, ids, np.int32(plen), kp, vp, bt, *state)
@@ -1804,14 +1910,16 @@ class DecodeEngine:
             short = None
             for req in self._active:
                 bi = req.pos // self.kv_cfg.block_size
-                while bi >= len(req.blocks):
-                    try:
+                try:
+                    while bi >= len(req.blocks):
                         self._alloc.grow(req.blocks)
                         taken += 1
-                    except NoBlocksError:
-                        short = req
-                        break
-                if short is not None:
+                    # the window kind: up to its ring, and none after
+                    while len(req.wblocks) <= min(bi, self._ring - 1):
+                        self._walloc.grow(req.wblocks)
+                        taken += 1
+                except NoBlocksError:
+                    short = req
                     break
             if short is None:
                 break
@@ -1837,6 +1945,7 @@ class DecodeEngine:
         req.blocks = []                # shared prefix survives for the
         req.prefill_pos = 0            # replay to hit again
         self._free_state_row(req)      # the replay's prefill rebuilds it
+        self._free_ring(req)
         req.draft_pos = 0
         req.n_reused = 0
         req.hashes = None
@@ -1952,6 +2061,13 @@ class DecodeEngine:
             bts[i] = build_block_table(req.blocks,
                                        self.kv_cfg.max_blocks_per_seq)
         state = (self._state, rows) if self._state_specs else ()
+        if self._walloc is not None:
+            wbts = np.zeros_like(bts)
+            for i, req in enumerate(slots):
+                if req is not None:
+                    wbts[i] = window_table(req.wblocks, self._ring,
+                                           self.kv_cfg.max_blocks_per_seq)
+            state += (wbts,)
         how, ids_arg, assemblies = self._next_ids(sig, slots)
         self._pipeline[how] += 1
         if part is not None:
@@ -2472,9 +2588,13 @@ class DecodeEngine:
         (`_queue_empty`: `queue_empty`, and `starved_s` where it was)."""
         live = [r for r in slots if r is not None]
         tokens = sum(r.pos for r in live)
-        row = self._step_record(kind, sp.t0, C, len(live), tokens)
+        row = self._step_record(kind, sp.t0, C, len(live), tokens,
+                                self._window_tokens(live))
+        # the window kind's facts, where the model has one: the row's
+        windows = {k: v for k, v in row.items() if k.startswith("window_")}
         sp.close(slots=C, live=len(live), live_tokens=tokens,
-                 blocks_used=self._alloc.used_blocks(), ids=ids, **probed)
+                 blocks_used=self._alloc.used_blocks(), ids=ids, **windows,
+                 **probed)
         return row
 
     def _sync_resolve_spans(self, sp, kind: str, C: int, slots):

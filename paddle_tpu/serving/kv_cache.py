@@ -53,6 +53,16 @@ and out-of-range table entries all read and write it, so a fixed-shape
 executable needs no validity branches — the attention length mask
 already guarantees nothing read from the null block ever contributes.
 
+A model may keep TWO KINDS of cache (`ServeModel.window`: layers that see
+the newest `window` keys alone, among layers that see every key). Each kind
+has pools, an allocator and a table a sequence of its own. The global kind
+is everything above. The WINDOW kind hands a sequence blocks as it grows up
+to a ring of `ring_blocks(window, slice, block_size)` and none after; its
+table is the ring REPEATED (`window_table`: `table[j] = ring[j mod R]`), so
+every writer here lands position `p` at block `p // BS` of the table
+unchanged and overwrites what fell out of reach, and a sequence shorter
+than the ring holds its own length.
+
 Beside the block pools a model with recurrent layers keeps STATE ROW
 pools (`StateRowAllocator`): a fixed-size row a sequence, allocated at
 admission, overwritten by its prefill, advanced in place by every decode
@@ -74,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["KVCacheConfig", "BlockAllocator", "NoBlocksError", "run_chunks",
+           "ring_blocks", "window_table",
            "StateRowAllocator", "NULL_ROW",
            "init_pools", "init_rated_pools", "write_token_kv",
            "write_prefill_kv", "write_chunk_kv", "write_span_kv",
@@ -688,6 +699,32 @@ def gather_rated(pool: jax.Array, layer: jax.Array,
     whole context, the model's own (`select`) where it reads them to pick
     blocks."""
     return pool[layer, block_tables]
+
+
+def ring_blocks(window: int, prompt_slice: int, block_size: int) -> int:
+    """Blocks of a window-kind sequence's RING: the window and a prompt's
+    slice, and one more. A slice's K/V are written before its first query
+    reads back `window - 1` keys, so the ring holds both; the block more
+    keeps a bucket's padded tail (up to a slice past the newest token) off
+    every key a later step still reads, whatever the position's place in
+    its block."""
+    return (int(window) + int(prompt_slice)) // int(block_size) + 1
+
+
+def window_table(blocks: Sequence[int], ring: int, max_blocks: int
+                 ) -> np.ndarray:
+    """Host helper: a window-kind sequence's table row. Under `ring`
+    blocks it is `build_block_table`'s (the sequence holds its own length);
+    a full ring is REPEATED over the table's width, `row[j] = blocks[j mod
+    ring]`, so position `p` lives in block `p // BS` of the row as in any
+    table, and the block that held position `p - ring * BS` now holds it."""
+    n = len(blocks)
+    if n > ring:
+        raise ValueError(f"{n} blocks exceed the ring of {ring}")
+    if n < ring:
+        return build_block_table(blocks, max_blocks)
+    reps = -(-max_blocks // ring)
+    return np.tile(np.asarray(list(blocks), np.int32), reps)[:max_blocks]
 
 
 def build_block_table(blocks: Sequence[int], max_blocks: int) -> np.ndarray:

@@ -131,10 +131,12 @@ def seeded(module, cfg, seed=0):
 
 
 def stateful(sm) -> bool:
-    """A sequence keeps more than its blocks: the engine refuses
+    """A sequence keeps more than its blocks (state rows, entries at a
+    rate, a ring of a second cache kind): the engine refuses
     `prefill_chunk`, `prefix_cache` and `spec_k` for such a model, and a
     replay has a row to rebuild."""
-    return bool(sm.state_pools(2, np.dtype("float32")) or sm.rated)
+    return bool(sm.state_pools(2, np.dtype("float32")) or sm.rated
+                or sm.window)
 
 
 def pools(sm, num_blocks, max_len, rows=SLOTS + 1, dtype="float32"):
@@ -148,7 +150,16 @@ def pools(sm, num_blocks, max_len, rows=SLOTS + 1, dtype="float32"):
         rated=tuple(sm.rated))
     state = tuple(jnp.zeros(shape, dt) for shape, dt in
                   sm.state_pools(rows, jnp.dtype(dtype)))
-    return kv, kvc.init_pools(kv), state + kvc.init_rated_pools(kv)
+    state += kvc.init_rated_pools(kv)
+    if sm.window:       # the window kind's pools: a ring a slot, last
+        state += kvc.init_pools(dataclasses.replace(
+            kv, layers=sm.window_layers, rated=(),
+            num_blocks=SLOTS * ring_of(sm) + 1))
+    return kv, kvc.init_pools(kv), state
+
+
+def ring_of(sm) -> int:
+    return kvc.ring_blocks(sm.window, sm.prompt_slice, BS)
 
 
 table = kvc.build_block_table     # (blocks, width) -> a padded table row
@@ -221,6 +232,15 @@ class Programs:
         # the sequence's table in its slot, the null table in the others
         self.tables = np.stack(self.alone(self.table,
                                           np.zeros_like(self.table)))
+        # a model with a window kind: the sequence's ring (not in order),
+        # repeated over the table, in its slot of that kind's tables
+        self.wtable = self.wtables = None
+        if self.sm.window:
+            ring = list(range(2, 2 + ring_of(self.sm)))
+            self.wtable = kvc.window_table(ring[3:] + ring[:3], len(ring),
+                                           self.width)
+            self.wtables = np.stack(self.alone(
+                self.wtable, np.zeros_like(self.wtable)))
         # a program is compiled and a walk made once a family, and both go
         # with this object (a cache on the method would keep every
         # family's executables for the worker's life)
@@ -258,6 +278,8 @@ class Programs:
                 jnp.zeros((SLOTS, self.width), i32))
         rows = ((state, i32(0)), (state, jnp.zeros((SLOTS,), i32))) \
             if state else ((), ())
+        if self.sm.window:      # that kind's table(s) follow the row(s)
+            rows = (rows[0] + (one[2],), rows[1] + (many[3],))
         fn, args, head = {
             "prefill": (decoder.prefill, (jnp.zeros(
                 (1, self.family.bucket), i32), i32(1)) + one + rows[0],
@@ -287,6 +309,8 @@ class Programs:
         bt = jnp.asarray(self.table if blocks is None
                          else table(blocks, self.width))
         extra = (cache.state, jnp.int32(row)) if cache.state else ()
+        if self.sm.window:
+            extra += (jnp.asarray(self.wtable),)
         out = list(run(params, self._padded(ids, self.family.bucket),
                        jnp.int32(len(ids)), cache.k, cache.v, bt, *extra))
         if self.sm.prefill_counters:    # the prompt's, after the pools
@@ -304,12 +328,16 @@ class Programs:
         return np.asarray(out[0])[0], cache
 
     def step(self, ids, positions, tables, cache: Cache, rows=None,
-             dtype="float32"):
+             dtype="float32", wtables=None):
         """One decode step of `SLOTS` slots: (logits [SLOTS, vocab], cache,
-        the step's counters)."""
+        the step's counters). `wtables`: the window kind's tables (left
+        out: `tables`, which is right for sequences under a ring)."""
         run, params = self.compiled("decode", dtype)
         i32 = jnp.int32
         extra = (cache.state, jnp.asarray(rows, i32)) if cache.state else ()
+        if self.sm.window:
+            extra += (jnp.asarray(tables if wtables is None else wtables,
+                                  i32),)
         out = run(params, jnp.asarray(ids, i32), jnp.asarray(positions, i32),
                   cache.k, cache.v, jnp.asarray(tables, i32), *extra)
         return out[0], Cache(out[1], out[2], *out[4:]), out[3]
@@ -332,7 +360,7 @@ class Programs:
         for t in range(n, upto):
             logits, cache, stats = self.step(
                 self.alone(self.seq[t]), self.alone(t), self.tables, cache,
-                self.alone(ROW))
+                self.alone(ROW), wtables=self.wtables)
             rows.append(np.asarray(logits)[SLOT])
         return Served(np.stack(rows), prefilled, cache, stats)
 
@@ -514,7 +542,8 @@ class ServeContract:
         streams = [h.result(timeout_s=300) for h in handles]
         assert all(len(s) == f.max_new for s in streams)
         gap, exact = f.reference_gaps(programs.params, programs.model,
-                                      prompts, streams, ENGINE["max_len"])
+                                      prompts, streams,
+                                      {**ENGINE, **f.engine}["max_len"])
         assert gap < f.tol and exact >= len(prompts) * f.max_new - 1
         status = engine.status()
         assert status["kv"]["entry_widths"] == list(programs.sm.stored)
@@ -580,6 +609,9 @@ class ServeContract:
             status = eng.status()
             assert status["requests"]["preempted"] > 0
             assert status["kv"]["blocks_used"] == 0
-            assert status["state"]["used"] == 0
+            if "state" in status:
+                assert status["state"]["used"] == 0
+            if programs.sm.window:
+                assert status["kv"]["kinds"]["window"]["blocks_used"] == 0
         finally:
             eng.stop()

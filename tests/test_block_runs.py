@@ -478,3 +478,88 @@ def test_tokens_do_not_depend_on_which_blocks_hold_them(model):
     assert tables[0] != tables[1]
     assert all(run_chunks(t, 64) == (1, 1) for t in tables[0])
     assert any(run_chunks(t, 64) == (0, 1) for t in tables[1])
+
+
+# -- a second kind: the window kind's rings -----------------------------------
+
+
+def test_a_ring_repeats_over_the_table_and_a_short_one_does_not():
+    assert kv_cache.ring_blocks(2048, 1024, 16) == 193
+    assert kv_cache.window_table([5, 6, 7], 4, 10).tolist() \
+        == [5, 6, 7, 0, 0, 0, 0, 0, 0, 0]
+    assert kv_cache.window_table([5, 6, 7, 9], 4, 10).tolist() \
+        == [5, 6, 7, 9, 5, 6, 7, 9, 5, 6]
+    with pytest.raises(ValueError):
+        kv_cache.window_table([1, 2, 3], 2, 10)
+
+
+@pytest.fixture(scope="module")
+def windowed():
+    from paddle_tpu.models import afmoe
+
+    cfg = afmoe.AfmoeConfig.tiny()      # a window of 32, slices of 16
+    cfg.dtype = "float32"
+    params, _ = afmoe.init(jax.random.key(0), cfg)
+    return params, cfg
+
+
+def _windowed_engine(windowed, **kw):
+    return _engine(windowed, num_blocks=65, prefill_buckets=(16, 64),
+                   max_len=160, **kw)
+
+
+def test_a_window_kind_sequence_holds_a_ring_at_most_and_its_length_below_it(
+        windowed):
+    """Two kinds of pool in the engine: at ANY length a sequence holds at
+    most `R` = (32 + 16) / 8 + 1 = 7 window-kind blocks while its global
+    blocks follow its length; one shorter than the window holds its own
+    length; release returns every block of both kinds."""
+    eng = _windowed_engine(windowed)
+    try:
+        ring = eng.status()["kv"]["kinds"]["window"]["ring_blocks"]
+        assert ring == 7
+        seen = []
+        long = eng.submit(list(range(1, 51)), max_new_tokens=90)
+        short = eng.submit([7, 8, 9], max_new_tokens=12)
+        while eng.status()["active"] or not seen:
+            with eng._cv:
+                for r in list(eng._active):
+                    seen.append((r.prompt_len0, r.pos, len(r.blocks),
+                                 len(r.wblocks)))
+            time.sleep(0.002)
+        assert len(long.result(timeout_s=120)) == 90
+        assert len(short.result(timeout_s=120)) == 12
+        assert max(pos for n, pos, _, _ in seen if n == 50) > 100
+        for n, pos, blocks, wblocks in seen:
+            assert wblocks <= ring
+            assert blocks >= -(-pos // 8)
+            # under the ring a sequence holds its length (a step ahead)
+            assert wblocks == min(blocks, ring), (n, pos, blocks, wblocks)
+        kinds = eng.status()["kv"]["kinds"]
+        assert kinds["global"]["blocks_used"] == 0
+        assert kinds["window"]["blocks_used"] == 0
+        assert kinds["window"]["blocks_free"] == 2 * ring
+    finally:
+        eng.stop()
+
+
+def test_admission_waits_while_either_kind_is_short(windowed):
+    """`can_alloc` is asked of BOTH kinds: with the window kind's pool cut
+    to one ring, a second long prompt waits for the first to finish though
+    the global kind has room for both."""
+    eng = _windowed_engine(windowed)
+    try:
+        eng._walloc = BlockAllocator(KVCacheConfig(
+            layers=3, widths=eng._wkv_cfg.entry_widths, max_len=160,
+            block_size=8, num_blocks=7 + 1, dtype="float32"))
+        first = eng.submit(list(range(1, 61)), max_new_tokens=40)
+        second = eng.submit(list(range(2, 62)), max_new_tokens=5)
+        kv = _kv_with(eng, 1)
+        assert eng.status()["queue_depth"] == 1
+        assert kv["kinds"]["window"]["blocks_free"] == 0
+        assert kv["kinds"]["global"]["blocks_free"] > 8
+        assert len(first.result(timeout_s=120)) == 40
+        assert len(second.result(timeout_s=120)) == 5
+        assert eng.status()["kv"]["kinds"]["window"]["blocks_used"] == 0
+    finally:
+        eng.stop()

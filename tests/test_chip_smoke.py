@@ -432,3 +432,46 @@ def test_tpu_place_does_not_mean_default_backend():
     assert not pt.is_compiled_with_tpu()
     assert isinstance(pt.core.places.default_place(), pt.CPUPlace)
     assert pt.CPUPlace().jax_device().platform == "cpu"
+
+
+def test_rehearse_serve_trinity(smoke):
+    """The serve_trinity phase at a tiny size: sliding-window layers over a
+    ring of the window kind's pool among a full layer over the global
+    kind's, prompts walked in slices and decoded past the ring's wrap,
+    through the same engine and front, the tokens against the benchmark's
+    plain reference (off the chip both kinds take the gathered form)."""
+    from paddle_tpu.models import afmoe
+    from paddle_tpu.serving.decode import DecodeConfig
+
+    cfg = afmoe.AfmoeConfig.tiny()
+    cfg.dtype = "float32"
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist()
+               for n in (6, 50, 33)]
+    info = smoke.serve_phase({}, cfg, DecodeConfig(
+        block_size=8, num_blocks=65, decode_slots=(4,),
+        prefill_buckets=(16, 64), max_len=96), prompts, max_new=24,
+        logit_tol=smoke.TRINITY_LOGIT_TOL, model=afmoe,
+        reference_gaps=smoke._trinity_reference_gaps)
+    checked = info["checked"]
+    assert checked["finished"]["length"] == 3
+    assert checked["compiles_after_warmup"] == 0
+    assert checked["decode_attention"] == {"gather": 1, "gather_window": 1}
+    # three matmuls an expert layer, three expert layers, three programs
+    assert checked["expert_matmul"] == {"routes": {"xla": 27}, "tiles": {}}
+    assert checked["ref_max_logit_gap"] < 1e-3
+
+
+def test_rehearse_trinity_experts(smoke):
+    """The trinity_experts phase at a tiny size: the held experts' term of
+    `moe.expert_mlp` under sigmoid routing with a selection bias, beside a
+    shared expert, against the plain reference's, by itself."""
+    from paddle_tpu.models import afmoe
+
+    cfg = afmoe.AfmoeConfig.tiny()
+    info = smoke.trinity_experts_phase({}, cfg, rows=(24, 64))
+    checked = info["checked"]
+    assert checked["routes"] == {"xla": 6} and checked["tiles"] == {}
+    for n in ("24", "64"):
+        assert checked["rows"][n]["held_rows"] >= int(n) // 8
+        assert checked["rows"][n]["max_rel_l2"] <= checked["tol"]

@@ -656,3 +656,58 @@ def test_engine_status_reports_the_route():
         assert engine.status()["decode_attention"] == {"gather": 1}
     finally:
         engine.stop()
+
+
+# -- a windowed walk (a model with a window kind of cache) -------------------
+
+
+@pytest.mark.parametrize("kv_heads", [2, 4], ids=["2kv", "4kv-at-the-limit"])
+def test_a_windowed_walk_reads_the_window_alone_through_a_repeated_ring(
+        kv_heads):
+    """`paged_gqa_attention(window=...)` against the gathered form under
+    the same mask, at positions under, at and far past the window, through
+    tables that are a ring REPEATED (`kv_cache.window_table`; one ring not
+    in order): the walk starts at the window's first block, its tables are
+    `window_blocks` wide whatever the sequence's are, and the first block's
+    keys before the window are masked. 4 K/V heads of 128 in bf16 are 2048 B
+    a token, AT the narrow limit, and walk runs as 2 do."""
+    heads, d, bs, window = 16, 128, 16, 64
+    ring = kvc.ring_blocks(window, 32, bs)      # 7 blocks, 112 tokens
+    slots, mb, layers = 5, 40, 2
+    rng = np.random.default_rng(kv_heads)
+    shape = (layers, slots * ring + 1, bs, kv_heads * d)
+    kp, vp = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+              for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((slots, heads * d)), jnp.bfloat16)
+    pos = np.array([10, 63, 64, 500, 0], np.int32)
+    tables = np.zeros((slots, mb), np.int32)
+    for s in range(slots - 1):      # the last slot is idle
+        blocks = list(range(1 + s * ring, 1 + (s + 1) * ring))
+        blocks = blocks[:min(pos[s] // bs + 1, ring)]
+        if s == 3:
+            blocks = blocks[3:] + blocks[:3]
+        tables[s] = kvc.window_table(blocks, ring, mb)
+    assert PA.narrow(PA._token_bytes(kp, vp))
+    out = PA.paged_gqa_attention(
+        q, kp, vp, jnp.int32(1), jnp.asarray(tables), jnp.asarray(pos),
+        heads=heads, kv_heads=kv_heads, window=window,
+        interpret=pltpu.InterpretParams())
+    f32 = jnp.float32
+    want = decoder.mha_cached(
+        q.astype(f32)[:, None], kvc.gather_kv(kp, 1, tables).astype(f32),
+        kvc.gather_kv(vp, 1, tables).astype(f32), jnp.asarray(pos)[:, None],
+        heads, kv_heads, window=window)[:, 0]
+    out, want = np.asarray(out, np.float32), np.asarray(want)
+    assert np.abs(out - want)[:4].max() < 0.02
+    assert not out[4].any()         # an idle slot reads nothing
+    # the same keys WITHOUT the window differ: the mask is doing something
+    full = decoder.mha_cached(
+        q.astype(f32)[:, None], kvc.gather_kv(kp, 1, tables).astype(f32),
+        kvc.gather_kv(vp, 1, tables).astype(f32), jnp.asarray(pos)[:, None],
+        heads, kv_heads)[:, 0]
+    assert np.abs(np.asarray(full) - want)[2:4].max() > 0.1
+    t = PA.window_tables(jnp.asarray(tables), jnp.asarray(pos), window, kp,
+                         vp)
+    assert t.ids.shape == (slots, PA.window_blocks(window, bs)) == (5, 5)
+    assert t.first.tolist() == [0, 0, 1, 5, 0]
+    assert t.newest.tolist() == [10, 63, 64, 68, 0]

@@ -1776,3 +1776,136 @@ def test_bert_base_train_step_attends_through_the_short_kernel(bert_step):
     for shape in ((_BERT_BATCH, heads, _BERT_SEQ, _BERT_SEQ),
                   (_BERT_BATCH, heads, _BERT_SEQ, hd)):
         assert "[%s]" % ",".join(map(str, shape)) not in text, shape
+
+
+_TRINITY_SLOTS, _TRINITY_CONTEXT = 32, 40960
+
+
+@pytest.fixture(scope="module")
+def trinity_8l(v5e):
+    """Trinity-Mini as the cell serves it, with the WINDOW kind's pools (6
+    layers, a ring of 193 blocks a slot and the null block) behind the
+    programs' `state`, as `DecodeEngine` sizes them."""
+    from paddle_tpu.models import afmoe
+    from paddle_tpu.serving import kv_cache as kvc
+
+    cfg, params, pools, state, kv, sds = _described(
+        v5e, afmoe, afmoe.AfmoeConfig(
+            pattern="WWW*WWW*", held=(0, 64), vocab_size=100096,
+            max_len=_TRINITY_CONTEXT),
+        _TRINITY_SLOTS, _TRINITY_CONTEXT)
+    sm = cfg.serve_model()
+    ring = kvc.ring_blocks(sm.window, sm.prompt_slice, _BLOCK)
+    wkv = kvc.KVCacheConfig(
+        layers=sm.window_layers, widths=sm.stored, max_len=_TRINITY_CONTEXT,
+        block_size=_BLOCK, num_blocks=_TRINITY_SLOTS * ring + 1)
+    state += tuple(sds(shape, jnp.dtype(wkv.dtype))
+                   for shape in wkv.pool_shapes)
+    return cfg, params, pools, state, kv, wkv, ring, sds
+
+
+@pytest.mark.parametrize("program", ["decode@32", "prefill@32768"])
+def test_trinity_serve_program_walks_both_cache_kinds(trinity_8l, program,
+                                                      monkeypatch):
+    """Two periods of Trinity-Mini as the cell serves it (6 sliding-window
+    and 2 full layers, 2 dense MLPs then 6 layers of 64 held of 128
+    experts, half the vocabulary): 6.32 GB of weights, a GLOBAL pool of 2
+    layers and a WINDOW pool of 6, all donated and written where they lie.
+    The decode program takes the paged grouped-query kernel in all 8
+    attention layers; the six window layers' walks run under tables of 129
+    blocks (`window_blocks`) whatever the sequence's table is wide (2560),
+    from each slot's window's first block. The prefill walks its 32768
+    tokens in slices of 1024."""
+    from paddle_tpu.models import decoder
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    from paddle_tpu.serving import kv_cache as kvc
+
+    monkeypatch.setattr(A, "_platform", lambda q: "tpu")
+    cfg, params, pools, state, kv, wkv, ring, sds = trinity_8l
+    sm = cfg.serve_model()
+    kw = dict(block_size=_BLOCK, eos_id=-1)
+    kind, n = program.split("@")
+    n = int(n)
+    mb = _TRINITY_CONTEXT // _BLOCK
+    for counts in (gm.GATE_COUNTS, gm.TILES, PA.GATE_COUNTS,
+                   kvc.PREFILL_WRITE_UNITS):
+        counts.clear()
+    if kind == "decode":
+        fn, args = decoder.decode_step, (
+            sds((n,), np.int32), sds((n,), np.int32), *pools,
+            sds((n, mb), np.int32), state, sds((n,), np.int32),
+            sds((n, mb), np.int32))
+    else:
+        fn, args = decoder.prefill, (
+            sds((1, n), np.int32), sds((), np.int32), *pools,
+            sds((mb,), np.int32), state, sds((), np.int32),
+            sds((mb,), np.int32))
+    compiled = jax.jit(lambda p, *a: fn(sm, p, *a, **kw),
+                       donate_argnums=(3, 4, 6)).lower(params,
+                                                       *args).compile()
+    assert ring == 193
+    assert kv.pool_shapes == ((2, 81921, 16, 512),) * 2
+    assert wkv.pool_shapes == ((6, 6177, 16, 512),) * 2
+    assert [s.shape for s in state] == [(6, 6177, 16, 512)] * 2
+    weights = sum(int(np.prod(p.shape)) * 2 for p in params.values())
+    assert weights == 2 * 3_158_905_600, weights
+    ma = compiled.memory_analysis()
+    planned = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    print(program, "planned", planned, ma)
+    # weights 6.32 GB + global 5.37 GB + window 1.21 GB resident
+    assert 12.8e9 < planned < 14.5e9, ma
+    assert ma.alias_size_in_bytes >= kv.pool_bytes() + wkv.pool_bytes(), ma
+    text = compiled.as_text()
+    for pool in pools + state:
+        assert not _pool_movers(text, pool.shape)
+    # no op makes a layer's slice of an expert stack
+    slices = re.findall(r"= \(?bf16\[64,(?:2048,1024|1024,2048)\]", text)
+    assert not slices, slices[:3]
+    kernels = _kernels(text)
+    if kind == "decode":
+        # three grouped matmuls an expert layer, the megablox kernel
+        assert gm.GATE_COUNTS == {"megablox": 18}, gm.GATE_COUNTS
+        assert PA.GATE_COUNTS == {"paged_gqa": 1, "paged_gqa_window": 1}, \
+            PA.GATE_COUNTS
+        walks = [k for k in kernels if "paged_gqa_attention" in k]
+        assert sum("/window_attention/" in k for k in walks) == 6, kernels
+        assert sum("/attention/" in k for k in walks) == 2, kernels
+        # a window layer's kernel prefetches tables of 129 blocks, a full
+        # layer's the sequence's 2560
+        assert PA.window_blocks(sm.window, _BLOCK) == 129
+        assert len(re.findall(r"s32\[32,129\]", text)) >= 6
+        assert ma.temp_size_in_bytes < 0.2e9, ma
+    else:
+        assert ma.temp_size_in_bytes < 1.5e9, ma
+
+
+@pytest.mark.parametrize("family", ["nemotron", "granite"])
+def test_a_walk_without_a_window_lowers_as_before(family, monkeypatch):
+    """The start is a table the windowed call alone prefetches: the
+    grouped-query walk of a model of ONE cache kind takes the scalars it
+    took (layer, tables, positions and, narrow, the runs) and no mask of a
+    first key."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    kv_heads = {"nemotron": 2, "granite": 8}[family]
+    pool = jax.ShapeDtypeStruct((1, 65, 16, kv_heads * 128), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((8, 32 * 128), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((8, 64), jnp.int32)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32)
+
+    def lowered(**kw):
+        return jax.jit(lambda q, k, v, t, p: PA.paged_gqa_attention(
+            q, k, v, jnp.int32(0), t, p, heads=32, kv_heads=kv_heads,
+            interpret=True, **kw)).lower(q, pool, pool, tables, pos).as_text()
+
+    plain = lowered()
+    assert "first" not in plain
+    n_scalars = 4 if PA.narrow(PA._token_bytes(pool, pool)) else 3
+    kernel, scalars, _ = PA._call_form(
+        lambda *a, **k: None, jnp.int32(0), jnp.zeros((8, 64), jnp.int32),
+        jnp.zeros((8,), jnp.int32), jnp.zeros(pool.shape, pool.dtype),
+        jnp.zeros(pool.shape, pool.dtype))
+    assert len(scalars) == n_scalars
+    assert lowered(window=64) != plain
